@@ -75,6 +75,7 @@ __all__ = [
     "ledger_enabled",
     "ledgered_jit",
     "note_decode_block",
+    "note_path_choice",
     "note_transfer_violation",
     "reset",
     "steady_scope",
@@ -136,6 +137,14 @@ _decode_blocks = 0
 _auto_steady_armed = False
 _steady_labels: List[str] = []
 _backend_compiles = 0
+_backend_compile_secs = 0.0
+# persistent-cache outcomes (jax/_src/compiler.py events): requests that
+# consulted the cache, and those it answered with a stored executable —
+# the difference really compiled
+_cache_requests = 0
+_cache_hits = 0
+# trace-time dispatch decisions: (site, choice, reason, dims) → traces
+_path_choices: Dict[Tuple[str, str, str, str], int] = {}
 _violations: List[dict] = []
 _violations_by_kind: Dict[str, int] = {}
 _MAX_RECORDS = 4096
@@ -281,12 +290,39 @@ def backend_compiles_total() -> int:
         return _backend_compiles
 
 
+def note_path_choice(site: str, choice: str, reason: str, **dims) -> None:
+    """Record which device program a step was traced into, and why.
+
+    Called from the trace-time forks (`ops.paged_attention._adapt`, the
+    engine's block/per-step decode choice), so it runs once per compiled
+    variant and never on the hot path.  Each distinct decision is logged
+    once; `summary()["path_choices"]` carries all of them for the status
+    server."""
+    key = (site, choice, reason,
+           ",".join(f"{k}={v}" for k, v in sorted(dims.items())))
+    with _LOCK:
+        first = key not in _path_choices
+        _path_choices[key] = _path_choices.get(key, 0) + 1
+    if first:
+        import logging
+
+        logging.getLogger(__name__).info(
+            "path choice: %s -> %s (%s) [%s]", *key)
+
+
 def summary() -> dict:
     with _LOCK:
         return {
             "compiles_total": sum(_compiles_by_fn.values()),
             "by_fn": dict(_compiles_by_fn),
             "backend_compiles": _backend_compiles,
+            "backend_compile_seconds": round(_backend_compile_secs, 3),
+            "cache_hits": _cache_hits,
+            "cache_misses": _cache_requests - _cache_hits,
+            "path_choices": [
+                {"site": s, "choice": c, "reason": r, "dims": d, "traces": n}
+                for (s, c, r, d), n in _path_choices.items()
+            ],
             "decode_blocks": _decode_blocks,
             "trips": [t.format() for t in _trips],
             "transfer_violations": dict(_violations_by_kind),
@@ -296,7 +332,11 @@ def summary() -> dict:
 def reset() -> None:
     """Test isolation: drop all recorded state (steady scopes stay)."""
     global _decode_blocks, _auto_steady_armed, _backend_compiles
+    global _backend_compile_secs, _cache_hits, _cache_requests
     with _LOCK:
+        _path_choices.clear()
+        _backend_compile_secs = 0.0
+        _cache_hits = _cache_requests = 0
         _entries.clear()
         _trips.clear()
         _compiles_by_fn.clear()
@@ -313,10 +353,21 @@ _listener_installed = False
 
 
 def _on_event_duration(event: str, duration: float, **kwargs) -> None:
-    global _backend_compiles
+    global _backend_compiles, _backend_compile_secs
     if "backend_compile" in event:
         with _LOCK:
             _backend_compiles += 1
+            _backend_compile_secs += duration
+
+
+def _on_event(event: str, **kwargs) -> None:
+    global _cache_hits, _cache_requests
+    if event.endswith("/compilation_cache/cache_hits"):
+        with _LOCK:
+            _cache_hits += 1
+    elif event.endswith("/compilation_cache/compile_requests_use_cache"):
+        with _LOCK:
+            _cache_requests += 1
 
 
 def _install_listener() -> None:
@@ -328,6 +379,7 @@ def _install_listener() -> None:
         jax.monitoring.register_event_duration_secs_listener(
             _on_event_duration
         )
+        jax.monitoring.register_event_listener(_on_event)
     # lint: allow(swallowed-exception): monitoring is a best-effort backstop; the attributed ledger works without it
     except Exception:  # noqa: BLE001
         pass
@@ -479,19 +531,12 @@ def thread_role_init() -> None:
         _guard_threads[name] = f"role={role or 'none'} (exempt)"
         return
     installed = install_transfer_guard()
-    native = False
-    try:
-        ctx = jax.transfer_guard_device_to_host("disallow")
-        ctx.__enter__()  # deliberately never exited: guard for the
-        _tls.native_guard = ctx  # thread's whole life
-        native = True
-    # lint: allow(swallowed-exception): older jax without the transfer-guard API — the Python patches still cover the thread
-    except Exception:  # noqa: BLE001
-        pass
+    ctx = jax.transfer_guard_device_to_host("disallow")
+    ctx.__enter__()  # deliberately never exited: guard for the
+    _tls.native_guard = ctx  # thread's whole life
     _guard_threads[name] = (
         f"role={role} d2h=disallow "
-        f"(native={'on' if native else 'off'}, "
-        f"patch={'on' if installed else 'off'})"
+        f"(native=on, patch={'on' if installed else 'off'})"
     )
 
 

@@ -38,7 +38,7 @@ from typing import Any, Dict, List, Optional
 
 from ..runtime import DistributedRuntime
 from ..runtime.transport.wire import unpack
-from .graph import ComponentSpec, GraphSpec
+from .graph import ComponentSpec, GraphSpec, process_env
 
 logger = logging.getLogger(__name__)
 
@@ -64,6 +64,8 @@ class LocalActuator:
         # replicas scaled down but possibly still draining: tracked so a
         # SIGTERM-ignoring worker is still reaped/killed at shutdown
         self._stopping: List[subprocess.Popen] = []
+        # components whose replicas take the accelerator (_chip_room)
+        self._chip_comps: set = set()
 
     def observed(self, comp: ComponentSpec) -> int:
         self._stopping = [p for p in self._stopping if p.poll() is None]
@@ -98,7 +100,33 @@ class LocalActuator:
         procs[:] = [p for p in procs if p.poll() is None]
         return len(procs)
 
+    def _chip_room(self, comp: ComponentSpec, replicas: int) -> int:
+        """One process per chip: of the processes this actuator starts on
+        this host, at most one may take the accelerator.  A request for
+        more is refused loudly and clamped (raising here would abort every
+        reconcile pass and starve the other components)."""
+        if not comp.takes_chip:
+            return replicas
+        self._chip_comps.add(comp.name)
+        others = sum(
+            1 for name in self._chip_comps if name != comp.name
+            for p in (self._procs.get(name, [])
+                      + [r for g in self._groups.get(name, []) for r in g])
+            if p.poll() is None
+        )
+        hosts = comp.multinode.num_hosts if comp.multinode else 1
+        room = 0 if others or hosts > 1 else 1
+        if replicas > room:
+            logger.error(
+                "%s: %d chip-holding replica(s) of %d host process(es) "
+                "wanted, room for %d on this host — a chip belongs to one "
+                "process (use --tp / --dp-ranks inside one worker, or "
+                "`platform: cpu`)", comp.name, replicas, hosts, room,
+            )
+        return min(replicas, room)
+
     def scale_to(self, comp: ComponentSpec, replicas: int) -> None:
+        replicas = self._chip_room(comp, replicas)
         if comp.multinode is not None:
             from .graph import _free_port
 
@@ -110,7 +138,8 @@ class LocalActuator:
                     self.control, coord, namespace=self.namespace
                 ):
                     p = subprocess.Popen(
-                        argv, stdout=self.stdout, stderr=subprocess.STDOUT
+                        argv, stdout=self.stdout, stderr=subprocess.STDOUT,
+                        env=process_env(argv),
                     )
                     group.append(p)
                 groups.append(group)
@@ -131,7 +160,8 @@ class LocalActuator:
         argv = comp.command(self.control, namespace=self.namespace)
         while len(procs) < replicas:
             p = subprocess.Popen(
-                argv, stdout=self.stdout, stderr=subprocess.STDOUT
+                argv, stdout=self.stdout, stderr=subprocess.STDOUT,
+                env=process_env(argv),
             )
             procs.append(p)
             logger.info("%s: spawned replica pid %d", comp.name, p.pid)

@@ -30,6 +30,7 @@ components:
 
 from __future__ import annotations
 
+import os
 import shlex
 import subprocess
 import sys
@@ -45,6 +46,17 @@ _KIND_MODULE = {
     "router": "dynamo_tpu.router",
     "planner": "dynamo_tpu.planner",
 }
+
+
+def process_env(argv: List[str]) -> Dict[str, str]:
+    """Environment for one launched process.  One process per chip: only a
+    worker may take the accelerator, so everything else (frontend, router,
+    planner, control plane — their packages import jax) is held to the CPU
+    backend."""
+    env = dict(os.environ)
+    if _KIND_MODULE["worker"] not in argv:
+        env["JAX_PLATFORMS"] = "cpu"
+    return env
 
 
 @dataclass
@@ -98,6 +110,13 @@ class ComponentSpec:
                     "--host-id", str(i)]
             for i in range(self.multinode.num_hosts)
         ]
+
+    @property
+    def takes_chip(self) -> bool:
+        """Whether a replica's process takes the accelerator: a worker
+        that is neither `mock` nor pinned to `platform: cpu`."""
+        return (self.kind == "worker" and not self.args.get("mock")
+                and self.args.get("platform") != "cpu")
 
     def command(self, control: str, namespace: str = "") -> List[str]:
         """The process argv for one replica (reference: per-service pod
@@ -165,6 +184,25 @@ class GraphSpec:
         with open(path) as f:
             return cls.parse(f.read())
 
+    def check_one_process_per_chip(self) -> None:
+        """Refuse a graph whose local realization would start more than
+        one process that takes the accelerator: a chip belongs to one
+        process, and the second worker would fail or hang on a device the
+        first one holds."""
+        holders = [
+            comp.name for comp in self.components if comp.takes_chip
+            for _ in range(comp.replicas * (
+                comp.multinode.num_hosts if comp.multinode else 1))
+        ]
+        if len(holders) > 1:
+            raise ValueError(
+                f"{len(holders)} worker processes on this host would each "
+                f"take the accelerator ({', '.join(holders)}): run ONE "
+                "chip-holding worker per host (--tp / --dp-ranks spread it "
+                "over the host's chips) or pin the others with "
+                "`platform: cpu`"
+            )
+
     def render_local(self, control: str) -> List[List[str]]:
         """Flat list of argvs, replicas expanded, namespace injected.
         Multinode groups expand to num_hosts ranks each, with a fresh
@@ -196,6 +234,7 @@ class LocalLauncher:
 
     def start(self, stdout=None) -> str:
         """Launch everything; returns the control-plane address."""
+        self.spec.check_one_process_per_chip()
         if not self.control:
             if self.spec.control_plane is None:
                 raise ValueError(
@@ -208,17 +247,19 @@ class LocalLauncher:
             s.bind(("127.0.0.1", 0))
             port = s.getsockname()[1]
             s.close()
+            argv = [sys.executable, "-m", "dynamo_tpu.runtime",
+                    "--host", "127.0.0.1", "--port", str(port)]
             self._control_proc = subprocess.Popen(
-                [sys.executable, "-m", "dynamo_tpu.runtime",
-                 "--host", "127.0.0.1", "--port", str(port)],
-                stdout=stdout, stderr=subprocess.STDOUT,
+                argv, stdout=stdout, stderr=subprocess.STDOUT,
+                env=process_env(argv),
             )
             self.control = f"127.0.0.1:{port}"
             time.sleep(0.5)  # the control plane binds quickly
         for argv in self.spec.render_local(self.control):
-            self.procs.append(
-                subprocess.Popen(argv, stdout=stdout, stderr=subprocess.STDOUT)
-            )
+            self.procs.append(subprocess.Popen(
+                argv, stdout=stdout, stderr=subprocess.STDOUT,
+                env=process_env(argv),
+            ))
         return self.control
 
     def poll(self) -> Dict[str, int]:

@@ -1,26 +1,73 @@
 """Loader for the native (C++) components under native/build/.
 
-Falls back silently when the libs aren't built — every native component
-has a pure-Python twin.  Build with `make -C native`.
+`native/build/` is not tracked, so the libs are built from the tracked
+sources on first use (`make -C native`, which rebuilds what is missing or
+older than its source).  Every native component has a pure-Python twin;
+when the build cannot run the twins are used, and that is said once,
+loudly — which implementation served a request must never depend on an
+untracked directory in silence.
 """
 
 from __future__ import annotations
 
 import ctypes
+import fcntl
+import logging
 import os
-from typing import Optional
+import subprocess
+from typing import Dict, Optional
 
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-_BUILD = os.path.join(_ROOT, "native", "build")
+_NATIVE = os.path.join(_ROOT, "native")
+_BUILD = os.path.join(_NATIVE, "build")
+
+_build_checked = False
+
+
+def _ensure_built() -> None:
+    """Run `make -C native` once per process, under a file lock so that
+    concurrent processes (xdist workers, a fleet of workers starting
+    together) never load a half-written library."""
+    global _build_checked
+    if _build_checked:
+        return
+    _build_checked = True
+    try:
+        os.makedirs(_BUILD, exist_ok=True)
+        with open(os.path.join(_BUILD, ".lock"), "w") as lock:
+            fcntl.flock(lock, fcntl.LOCK_EX)
+            proc = subprocess.run(
+                ["make", "-C", _NATIVE, "all"], capture_output=True,
+                text=True, timeout=600,
+            )
+        err = proc.stderr.strip()[-500:] if proc.returncode else ""
+    except (OSError, subprocess.TimeoutExpired) as e:
+        err = repr(e)
+    if err:
+        logging.getLogger(__name__).warning(
+            "`make -C %s` failed (%s); the PYTHON TWINS of the block "
+            "hasher and the radix index are in use unless a previously "
+            "built library loads", _NATIVE, err,
+        )
+
+
+def status() -> Dict[str, str]:
+    """Which implementation each native component resolved to."""
+    return {
+        "radix_index": "native" if radix_lib() is not None else "python",
+        "block_hash": "native" if tokens_lib() is not None else "python",
+    }
+
 
 _radix_lib: Optional[ctypes.CDLL] = None
 
 
 def radix_lib() -> Optional[ctypes.CDLL]:
-    """The libdynamo_radix.so handle, or None when not built."""
+    """The libdynamo_radix.so handle, or None when it cannot be built."""
     global _radix_lib
     if _radix_lib is not None:
         return _radix_lib
+    _ensure_built()
     path = os.path.join(_BUILD, "libdynamo_radix.so")
     if not os.path.exists(path):
         return None
@@ -59,6 +106,7 @@ def tokens_lib() -> Optional[ctypes.CDLL]:
     global _tokens_lib, _tokens_lib_missing
     if _tokens_lib is not None or _tokens_lib_missing:
         return _tokens_lib
+    _ensure_built()
     path = os.path.join(_BUILD, "libdynamo_tokens.so")
     if not os.path.exists(path):
         _tokens_lib_missing = True

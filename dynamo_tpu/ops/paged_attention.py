@@ -30,6 +30,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from ..analysis import xla_ledger
+
 NEG_INF = -1e30
 
 
@@ -68,9 +70,16 @@ def resolve_attention_impl(impl: str = "auto", meshed: bool = False) -> str:
                 "GSPMD-meshed engine must use attention_impl='xla'"
             )
         return impl
+    backend = jax.default_backend()
     if meshed:
-        return "xla"
-    return "adaptive" if jax.default_backend() == "tpu" else "xla"
+        choice, why = "xla", "GSPMD mesh: XLA partitions the einsum path"
+    elif backend == "tpu":
+        choice, why = "adaptive", "single-device tpu backend"
+    else:
+        choice, why = "xla", (
+            f"backend is {backend!r}: the Pallas kernels are TPU programs")
+    xla_ledger.note_path_choice("attention_impl", choice, why, requested=impl)
+    return choice
 
 
 # cap what "adaptive" sends to the prefill kernel so VMEM (~16MB) is never
@@ -82,13 +91,26 @@ _PALLAS_PREFILL_VMEM_BUDGET = 12 * 1024 * 1024
 
 def _adapt(impl: str, page_table: jax.Array, page_size: int,
            chunk_vmem_bytes: int = 0,
-           min_ctx: int = PALLAS_MIN_CTX_TOKENS) -> str:
-    if impl == "adaptive":
-        ctx = page_table.shape[1] * page_size
-        if chunk_vmem_bytes > _PALLAS_PREFILL_VMEM_BUDGET:
-            return "xla"
-        return "pallas" if ctx >= min_ctx else "xla"
-    return impl
+           min_ctx: int = PALLAS_MIN_CTX_TOKENS,
+           site: str = "decode_attention", chunk: int = 1) -> str:
+    """Resolve "adaptive" for one trace.  Runs at trace time only, so the
+    ledger note below is once per compiled variant: which attention
+    program a (batch, chunk, table-width) step got, and why."""
+    ctx = page_table.shape[1] * page_size
+    if impl != "adaptive":
+        choice, why = impl, f"attention_impl={impl}"
+    elif chunk_vmem_bytes > _PALLAS_PREFILL_VMEM_BUDGET:
+        choice, why = "xla", (
+            f"chunk VMEM estimate {chunk_vmem_bytes} B > "
+            f"{_PALLAS_PREFILL_VMEM_BUDGET} B budget")
+    elif ctx >= min_ctx:
+        choice, why = "pallas", f"table {ctx} tokens >= {min_ctx}"
+    else:
+        choice, why = "xla", f"table {ctx} tokens < {min_ctx}"
+    xla_ledger.note_path_choice(
+        site, choice, why, batch=page_table.shape[0], chunk=chunk,
+        table_tokens=ctx)
+    return choice
 
 
 def write_kv_pages(
@@ -205,7 +227,8 @@ def prefill_attention(
         + 4 * max(1, 128 // page) * page * n_kv * hd * esize  # 2x2 KV bufs
     )
     impl = _adapt(impl, page_table, page, chunk_vmem_bytes=vmem,
-                  min_ctx=PALLAS_MIN_CTX_TOKENS_PREFILL)
+                  min_ctx=PALLAS_MIN_CTX_TOKENS_PREFILL,
+                  site="prefill_attention", chunk=S)
     if impl == "pallas":
         from .pallas_attention import prefill_attention_pallas
 

@@ -245,6 +245,11 @@ def decode_attention_pallas(
 # --------------------------------------------------------------------------- #
 
 
+# query rows per unrolled head tile in the prefill kernel (a chunk that is
+# not a multiple of it — the short buckets — runs as one block)
+_PREFILL_ROW_BLOCK = 128
+
+
 def _prefill_kernel(
     # scalar prefetch
     pt_ref,  # [B, padded_pages] int32
@@ -275,6 +280,7 @@ def _prefill_kernel(
     hd: int,
     nc: int,
     S: int,
+    RB: int,
 ):
     b = pl.program_id(0)
     c = pl.program_id(1)
@@ -313,6 +319,45 @@ def _prefill_kernel(
             for cp in dmas(first, 0):
                 cp.start()
 
+    # The head loop below is unrolled in Python (heads live on lanes, and
+    # Mosaic takes no dynamic lane slice), so what bounds the kernel's
+    # code size — and its compile time — is the tile each unrolled head
+    # works on.  Query rows therefore go through a `fori_loop` in blocks
+    # of RB: every head touches [RB, T] / [RB, RB] score tiles whatever
+    # the chunk length S, and the program no longer grows with S.
+    nrb = S // RB
+
+    def attend_rows(rs, k, v, valid):
+        """Online-softmax update of rows `rs` (all heads) against one key
+        tile.  k, v: [Tk, n_kv*hd]; valid: [RB, Tk] bool."""
+        for kh in range(n_kv):
+            ds = slice(kh * hd, (kh + 1) * hd)
+            k_h, v_h = k[:, ds], v[:, ds]
+            for g in range(groups):
+                h = kh * groups + g
+                hl = slice(h * hd, (h + 1) * hd)
+                s = jax.lax.dot_general(
+                    q_ref[0, rs, hl], k_h,
+                    (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )  # [RB, Tk]
+                s = jnp.where(valid, s, NEG_INF)
+                m_prev = m_scr[rs, h:h + 1]  # [RB, 1]
+                l_prev = l_scr[rs, h:h + 1]
+                m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+                corr = jnp.exp(m_prev - m_new)
+                p = jnp.exp(s - m_new)
+                pv = jax.lax.dot_general(
+                    p.astype(v_h.dtype), v_h,
+                    (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )  # [RB, hd]
+                acc_scr[rs, hl] = acc_scr[rs, hl] * corr + pv
+                m_scr[rs, h:h + 1] = m_new
+                l_scr[rs, h:h + 1] = (
+                    l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
+                )
+
     # ---- streamed prefix pages ---- #
     @pl.when(chunk_start < prefix_len)
     def _():
@@ -326,82 +371,57 @@ def _prefill_kernel(
         for cp in dmas(ch, buf):
             cp.wait()
 
-        k = k_scr[buf].reshape(T, n_kv * hd)
-        v = v_scr[buf].reshape(T, n_kv * hd)
-        # per-row mask: key position validity + sliding window around the
-        # row's global query position (prefix_len + row)
-        rows = jax.lax.broadcasted_iota(jnp.int32, (S, T), 0)
-        tpos = chunk_start + jax.lax.broadcasted_iota(jnp.int32, (S, T), 1)
-        valid = tpos < prefix_len
-        valid &= (window <= 0) | (tpos > prefix_len + rows - window)
+        def row_block(r, carry):
+            r0 = pl.multiple_of(r * RB, RB)
+            # per-row mask: key position validity + sliding window around
+            # the row's global query position (prefix_len + row)
+            rows = r0 + jax.lax.broadcasted_iota(jnp.int32, (RB, T), 0)
+            tpos = chunk_start + jax.lax.broadcasted_iota(
+                jnp.int32, (RB, T), 1)
+            valid = tpos < prefix_len
+            valid &= (window <= 0) | (tpos > prefix_len + rows - window)
+            attend_rows(
+                pl.ds(r0, RB),
+                k_scr[buf].reshape(T, n_kv * hd),
+                v_scr[buf].reshape(T, n_kv * hd),
+                valid,
+            )
+            return carry
 
-        for kh in range(n_kv):
-            ds = slice(kh * hd, (kh + 1) * hd)
-            for g in range(groups):
-                h = kh * groups + g
-                qh = q_ref[0, :, h * hd:(h + 1) * hd]  # [S, hd]
-                s = jax.lax.dot_general(
-                    qh, k[:, ds],
-                    (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )  # [S, T]
-                s = jnp.where(valid, s, NEG_INF)
-                m_prev = m_scr[:, h:h + 1]  # [S, 1]
-                l_prev = l_scr[:, h:h + 1]
-                m_cur = jnp.max(s, axis=1, keepdims=True)
-                m_new = jnp.maximum(m_prev, m_cur)
-                corr = jnp.exp(m_prev - m_new)
-                p = jnp.exp(s - m_new)
-                l_new = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
-                pv = jax.lax.dot_general(
-                    p.astype(v.dtype), v[:, ds],
-                    (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )  # [S, hd]
-                acc_scr[:, h * hd:(h + 1) * hd] = (
-                    acc_scr[:, h * hd:(h + 1) * hd] * corr + pv
-                )
-                m_scr[:, h:h + 1] = m_new
-                l_scr[:, h:h + 1] = l_new
+        jax.lax.fori_loop(0, nrb, row_block, 0)
 
     # ---- the chunk itself (causal), then finalize ---- #
     @pl.when(c == nc - 1)
     def _():
-        i = jax.lax.broadcasted_iota(jnp.int32, (S, S), 0)
-        j = jax.lax.broadcasted_iota(jnp.int32, (S, S), 1)
-        causal = (j <= i) & (j < chunk_len)
-        causal &= (window <= 0) | (j > i - window)
+        def row_block(r, carry):
+            r0 = pl.multiple_of(r * RB, RB)
+            rs = pl.ds(r0, RB)
 
-        for kh in range(n_kv):
-            kn = kn_ref[0, :, kh * hd:(kh + 1) * hd]  # [S, hd]
-            vn = vn_ref[0, :, kh * hd:(kh + 1) * hd]
-            for g in range(groups):
-                h = kh * groups + g
-                qh = q_ref[0, :, h * hd:(h + 1) * hd]
-                s = jax.lax.dot_general(
-                    qh, kn,
-                    (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )  # [S, S]
-                s = jnp.where(causal, s, NEG_INF)
-                m_prev = m_scr[:, h:h + 1]
-                l_prev = l_scr[:, h:h + 1]
-                m_cur = jnp.max(s, axis=1, keepdims=True)
-                m_new = jnp.maximum(m_prev, m_cur)
-                corr = jnp.exp(m_prev - m_new)
-                p = jnp.exp(s - m_new)
-                l_new = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
-                pv = jax.lax.dot_general(
-                    p.astype(vn.dtype), vn,
-                    (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
-                num = acc_scr[:, h * hd:(h + 1) * hd] * corr + pv
+            def key_block(j, carry2):
+                j0 = pl.multiple_of(j * RB, RB)
+                i = r0 + jax.lax.broadcasted_iota(jnp.int32, (RB, RB), 0)
+                jj = j0 + jax.lax.broadcasted_iota(jnp.int32, (RB, RB), 1)
+                causal = (jj <= i) & (jj < chunk_len)
+                causal &= (window <= 0) | (jj > i - window)
+                js = pl.ds(j0, RB)
+                attend_rows(rs, kn_ref[0, js, :], vn_ref[0, js, :], causal)
+                return carry2
+
+            # causal: key blocks past the row block's diagonal hold nothing
+            jax.lax.fori_loop(0, r + 1, key_block, 0)
+
+            for h in range(n_kv * groups):
+                hl = slice(h * hd, (h + 1) * hd)
                 # attention sink: one extra denominator term per row
                 # (NEG_INF sink → exp == 0 → plain softmax)
-                l_fin = l_new + jnp.exp(sink_ref[0, h] - m_new)
+                l_fin = l_scr[rs, h:h + 1] + jnp.exp(
+                    sink_ref[0, h] - m_scr[rs, h:h + 1])
                 denom = jnp.maximum(l_fin, 1e-30)
-                o_ref[0, :, h * hd:(h + 1) * hd] = (num / denom).astype(o_ref.dtype)
+                o_ref[0, rs, hl] = (acc_scr[rs, hl] / denom).astype(
+                    o_ref.dtype)
+            return carry
+
+        jax.lax.fori_loop(0, nrb, row_block, 0)
 
 
 def prefill_attention_pallas(
@@ -463,6 +483,7 @@ def prefill_attention_pallas(
     kernel = functools.partial(
         _prefill_kernel,
         C=C, page=page, n_kv=n_kv, groups=groups, hd=hd, nc=nc, S=S,
+        RB=_PREFILL_ROW_BLOCK if S % _PREFILL_ROW_BLOCK == 0 else S,
     )
     out = pl.pallas_call(
         kernel,

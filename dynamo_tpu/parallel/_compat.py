@@ -1,26 +1,11 @@
-"""jax version compatibility for shard_map (top-level with check_vma on
-jax >= 0.8; jax.experimental with check_rep before)."""
+"""`jax.shard_map` with this repo's default: `check_vma` off unless asked for
+(the step bodies mix manual and GSPMD-partitioned axes)."""
 
 from __future__ import annotations
 
-try:  # jax >= 0.8 exposes shard_map at top level (check_vma kwarg)
-    from jax import shard_map as _shard_map
+from jax import shard_map as _shard_map
 
-    def shard_map(f, **kw):
-        kw.setdefault("check_vma", False)
-        return _shard_map(f, **kw)
-except ImportError:  # pragma: no cover — older jax
-    from jax.experimental.shard_map import shard_map as _shard_map_old
 
-    def shard_map(f, **kw):
-        kw.setdefault("check_rep", False)
-        kw.pop("check_vma", None)
-        # new-API partial-manual axis_names → old-API auto complement
-        if "axis_names" in kw:
-            manual = set(kw.pop("axis_names"))
-            mesh = kw.get("mesh")
-            if manual and mesh is not None:
-                auto = frozenset(set(mesh.axis_names) - manual)
-                if auto:
-                    kw["auto"] = auto
-        return _shard_map_old(f, **kw)
+def shard_map(f, **kw):
+    kw.setdefault("check_vma", False)
+    return _shard_map(f, **kw)

@@ -41,9 +41,9 @@ class ParallelConfig:
         return self.dp * self.tp * self.sp * self.pp
 
     def validate(self, n_devices: int) -> None:
-        if self.world != n_devices:
+        if self.world > n_devices:
             raise ValueError(
-                f"dp*tp*sp*pp = {self.world} != available devices {n_devices}"
+                f"dp*tp*sp*pp = {self.world} > available devices {n_devices}"
             )
         if self.pp > 1 and self.sp > 1:
             raise ValueError(
@@ -53,8 +53,13 @@ class ParallelConfig:
 
 
 def make_mesh(pcfg: ParallelConfig, devices: Optional[Sequence] = None) -> Mesh:
+    """The serving mesh over `devices` (default: all visible).  A mesh
+    smaller than the device set takes the first `world` of them, so
+    `--tp 2` runs on a four-chip host; pass an explicit subset to choose
+    which."""
     devices = list(devices if devices is not None else jax.devices())
     pcfg.validate(len(devices))
+    devices = devices[:pcfg.world]
     if pcfg.pp > 1:
         # tp innermost: a stage's tensor-parallel collectives ride the
         # tightest ICI links; pp ring shifts cross the next ring out

@@ -255,10 +255,16 @@ def _build_engine(args):
     import jax
     import jax.numpy as jnp
 
+    from .. import chip, compile_cache
     from ..engine import EngineConfig, JaxEngine
     from ..models import init_params, tiny_config
     from ..models.config import LLAMA_3_2_1B
     from ..models.loader import load_params
+
+    # a profile is a table of device times: no chip, no table (the
+    # checked-in profiles/*.npz name a v5e)
+    chip.require_tpu("planner.profiler sweep")
+    compile_cache.configure()
 
     maxc = max(args.concurrency)
     if args.model == "tiny":

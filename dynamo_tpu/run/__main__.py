@@ -112,7 +112,10 @@ def _build_engine(args):
     import jax
     import jax.numpy as jnp
 
+    from .. import compile_cache
     from ..engine import EngineConfig, JaxEngine
+
+    compile_cache.configure()
 
     dtype = jnp.bfloat16 if args.dtype == "bfloat16" else jnp.float32
     if args.model == "tiny":

@@ -28,7 +28,10 @@ sys.path.insert(0, ROOT)
 
 
 def _setup_env() -> None:
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    # CPU-only driver: this process imports jax AND starts workers, so
+    # it is pinned to the CPU backend whatever the environment says (one
+    # process per chip; chip_smoke.py is the driver that runs on the chip)
+    os.environ["JAX_PLATFORMS"] = "cpu"
     os.environ["XLA_FLAGS"] = (
         os.environ.get("XLA_FLAGS", "")
         + " --xla_force_host_platform_device_count=8"

@@ -32,7 +32,10 @@ sys.path.insert(0, ROOT)
 
 
 def _setup_env() -> None:
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    # CPU-only driver: this process imports jax AND starts workers, so
+    # it is pinned to the CPU backend whatever the environment says (one
+    # process per chip; chip_smoke.py is the driver that runs on the chip)
+    os.environ["JAX_PLATFORMS"] = "cpu"
     os.environ.setdefault("PYTHONPATH", ROOT)
     os.environ.setdefault("DYN_TPU_TELEMETRY_INTERVAL", "0.3")
 

@@ -43,7 +43,10 @@ def main() -> int:
                     help="disable delta coalescing in the fast arm")
     args = ap.parse_args()
 
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    # CPU-only driver: this process imports jax AND starts workers, so
+    # it is pinned to the CPU backend whatever the environment says (one
+    # process per chip; chip_smoke.py is the driver that runs on the chip)
+    os.environ["JAX_PLATFORMS"] = "cpu"
     from dynamo_tpu.frontend.loadgen import frontend_saturation
 
     out = asyncio.run(frontend_saturation(

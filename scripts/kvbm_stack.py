@@ -44,7 +44,10 @@ SYSTEM = "You are a meticulous support assistant for the Dynamo fleet. Cite the 
 
 
 def _setup_env() -> None:
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    # CPU-only driver: this process imports jax AND starts workers, so
+    # it is pinned to the CPU backend whatever the environment says (one
+    # process per chip; chip_smoke.py is the driver that runs on the chip)
+    os.environ["JAX_PLATFORMS"] = "cpu"
     os.environ.setdefault("PYTHONPATH", ROOT)
     os.environ.setdefault("DYN_TPU_KVBM_SUMMARY_INTERVAL", "0.3")
 

@@ -24,7 +24,8 @@ import time
 import urllib.request
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-ENV = dict(os.environ, PYTHONPATH=ROOT)
+# CPU-only driver (every worker below is also started --platform cpu)
+ENV = dict(os.environ, PYTHONPATH=ROOT, JAX_PLATFORMS="cpu")
 ENV.pop("XLA_FLAGS", None)  # workers set their own device counts
 
 

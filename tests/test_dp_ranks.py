@@ -239,3 +239,38 @@ async def test_dp_rank_routing_e2e():
         await rt_f.shutdown(graceful=False)
         await rt_w.shutdown(graceful=False)
         await control.stop()
+
+
+async def test_flat_engine_honours_its_device():
+    """Replicas of one process each live on the device they are given:
+    parameters, KV pool and step outputs on THAT device, answers equal to
+    an unpinned engine's (worker --dp-ranks on a multi-chip host)."""
+    cfg = tiny_config()
+    params = init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
+    devs = jax.devices()
+    plain = JaxEngine(cfg, params, _ecfg(), kv_dtype=jnp.float32)
+    pinned = [
+        JaxEngine(cfg, params, _ecfg(), kv_dtype=jnp.float32, devices=[d])
+        for d in (devs[2], devs[5])
+    ]
+    p = [1, 2, 3, 4, 5, 6, 7]
+    want = await _gen(plain, p, max_tokens=6)
+    for eng, d in zip(pinned, (devs[2], devs[5])):
+        assert await _gen(eng, p, max_tokens=6) == want
+        assert eng.kv.k.devices() == {d} and eng.kv.v.devices() == {d}
+        assert all(leaf.devices() == {d}
+                   for leaf in jax.tree.leaves(eng.params))
+    for eng in (plain, *pinned):
+        await eng.shutdown()
+
+
+def test_make_mesh_takes_a_subset_of_the_devices():
+    from dynamo_tpu.parallel import ParallelConfig, make_mesh
+
+    devs = jax.devices()
+    mesh = make_mesh(ParallelConfig(tp=2))
+    assert list(mesh.devices.flat) == devs[:2]
+    mesh = make_mesh(ParallelConfig(dp=2, tp=2), devs[4:])
+    assert list(mesh.devices.flat) == devs[4:8]
+    with pytest.raises(ValueError):
+        make_mesh(ParallelConfig(tp=4), devs[:2])

@@ -252,3 +252,68 @@ def test_worker_cli_engine_tuning_flags():
                 p.wait(timeout=5)
             except subprocess.TimeoutExpired:
                 p.kill()
+
+
+# -- one process per chip -------------------------------------------------------- #
+
+_TWO_CHIP_WORKERS = """
+namespace: t
+control_plane: {}
+components:
+  frontend: {kind: frontend}
+  decode: {kind: worker, replicas: 2, args: {model: tiny}}
+"""
+
+
+def test_local_launcher_refuses_two_chip_holding_workers():
+    """A chip belongs to one process: a graph that would start two workers
+    on the accelerator is refused before anything is spawned; mock workers
+    and workers pinned to the CPU do not count."""
+    from dynamo_tpu.deploy.graph import LocalLauncher
+
+    spec = GraphSpec.parse(_TWO_CHIP_WORKERS)
+    launcher = LocalLauncher(spec)
+    with pytest.raises(ValueError, match="one|ONE"):
+        launcher.start()
+    assert launcher.procs == [] and launcher._control_proc is None
+    for pin in ({"platform": "cpu"}, {"mock": True}):
+        doc = yaml.safe_load(_TWO_CHIP_WORKERS)
+        doc["components"]["decode"]["args"].update(pin)
+        GraphSpec.parse(yaml.safe_dump(doc)).check_one_process_per_chip()
+
+
+def test_only_workers_may_see_the_accelerator(monkeypatch):
+    from dynamo_tpu.deploy.graph import process_env
+
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    spec = GraphSpec.parse(_TWO_CHIP_WORKERS)
+    envs = {argv[2]: process_env(argv) for argv in spec.render_local("c:1")}
+    assert envs["dynamo_tpu.frontend"]["JAX_PLATFORMS"] == "cpu"
+    assert "JAX_PLATFORMS" not in envs["dynamo_tpu.worker"]
+
+
+def test_local_actuator_clamps_chip_holding_replicas(monkeypatch):
+    from dynamo_tpu.deploy import controller
+    from dynamo_tpu.deploy.controller import LocalActuator
+
+    spawned = []
+
+    class FakeProc:
+        pid = 1
+
+        def poll(self):
+            return None
+
+        def send_signal(self, sig):
+            pass
+
+    monkeypatch.setattr(controller.subprocess, "Popen",
+                        lambda argv, **kw: spawned.append(argv) or FakeProc())
+    spec = GraphSpec.parse(_TWO_CHIP_WORKERS)
+    decode = next(c for c in spec.components if c.name == "decode")
+    act = LocalActuator("c:1")
+    act.scale_to(decode, 2)
+    assert len(spawned) == 1 and act.observed(decode) == 1
+    decode.args["platform"] = "cpu"
+    act.scale_to(decode, 3)
+    assert len(spawned) == 3

@@ -325,10 +325,13 @@ def test_block_scan_equals_per_step_decode(feature, sampling):
     np.testing.assert_array_equal(
         np.asarray(ys), np.stack(toks_ref))
     np.testing.assert_array_equal(np.asarray(tok_b), toks_ref[-1])
+    # the two paths sum the same f32 terms in different orders, so the KV
+    # they write may differ by reassociation (observed: 1 element of 6656
+    # at rel 2.5e-5); the tokens above stay exactly equal
     np.testing.assert_allclose(
-        np.asarray(kv_blk.k), np.asarray(kv_r.k), rtol=1e-5, atol=1e-6)
+        np.asarray(kv_blk.k), np.asarray(kv_r.k), rtol=1e-4, atol=1e-6)
     np.testing.assert_allclose(
-        np.asarray(kv_blk.v), np.asarray(kv_r.v), rtol=1e-5, atol=1e-6)
+        np.asarray(kv_blk.v), np.asarray(kv_r.v), rtol=1e-4, atol=1e-6)
 
 
 @pytest.mark.parametrize("feature", sorted(FEATURE_CFGS))

@@ -261,3 +261,45 @@ def test_sinks_match_xla():
                 np.asarray(out[b, :n]), np.asarray(ref[b, :n]),
                 atol=2e-5, rtol=2e-5,
             )
+
+
+@pytest.mark.parametrize("window,sinks", [(None, False), (40, True),
+                                          (200, False)])
+def test_prefill_row_blocks_match_xla(window, sinks):
+    """A chunk longer than the kernel's row block (S = 2 x 128): the
+    row-block `fori_loop`, the causal key-block loop under the diagonal
+    and the per-block finalize, with a prefix spanning two streamed
+    chunks, a window that masks whole key blocks, and sinks."""
+    B, H, n_kv, hd, page, S = 2, 4, 2, 64, 16, 256
+    maxp = (160 + S) // page + 2
+    prefix_lens = jnp.array([160, 0], jnp.int32)
+    chunk_lens = jnp.array([S, S - 37], jnp.int32)
+    P = 1 + B * maxp
+    k_pages, v_pages = _make_pool(jax.random.PRNGKey(5), P, page, n_kv, hd,
+                                  jnp.float32)
+    table = _page_table(B, maxp, jnp.full((B,), maxp * page), page)
+    ks = jax.random.split(jax.random.PRNGKey(6), 4)
+    q = jax.random.normal(ks[0], (B, S, H, hd), jnp.float32) * 0.5
+    k_new = jax.random.normal(ks[1], (B, S, n_kv, hd), jnp.float32) * 0.3
+    v_new = jax.random.normal(ks[2], (B, S, n_kv, hd), jnp.float32) * 0.3
+    kw = {}
+    if window is not None:
+        kw["window"] = jnp.int32(window)
+    if sinks:
+        kw["sink"] = jax.random.normal(ks[3], (H,), jnp.float32)
+
+    ref = prefill_attention(
+        q, k_new, v_new, k_pages, v_pages, table, prefix_lens, chunk_lens,
+        **kw,
+    )
+    out = prefill_attention_pallas(
+        q, k_new, v_new, k_pages, v_pages, table, prefix_lens, chunk_lens,
+        interpret=True, **kw,
+    )
+    for b in range(B):
+        n = int(chunk_lens[b])
+        np.testing.assert_allclose(
+            np.asarray(out[b, :n], np.float32),
+            np.asarray(ref[b, :n], np.float32),
+            atol=2e-5, rtol=2e-5,
+        )

@@ -33,8 +33,11 @@ def devices():
 def test_mesh_construction(devices):
     mesh = make_mesh(ParallelConfig(dp=2, tp=4))
     assert mesh.shape == {"dp": 2, "tp": 4}
+    # a smaller mesh takes the first dp*tp devices; a larger one cannot be
+    assert list(make_mesh(ParallelConfig(dp=3, tp=2)).devices.flat) == (
+        devices[:6])
     with pytest.raises(ValueError):
-        make_mesh(ParallelConfig(dp=3, tp=2))
+        make_mesh(ParallelConfig(dp=3, tp=4))
 
 
 def test_tp_sharded_prefill_matches_single_device(devices):

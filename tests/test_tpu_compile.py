@@ -1,0 +1,196 @@
+"""The main path's device programs, given to the TPU compiler for a DESCRIBED
+v5e (2x2), not an attached one: what the chip's compiler would refuse — a
+misaligned slice, too much VMEM, a program that does not fit HBM, a kernel
+that cannot be partitioned — fails here, at no chip time.  Nothing runs, so
+this says nothing about results or speed.
+
+The topology is described inside a module-scoped fixture (never at import:
+only one process may hold libtpu, and every xdist worker imports every test
+file), nothing here starts a child process, and the persistent compile
+cache is off around the compiles (such an executable is written but cannot
+be read back without a chip).  All of it stays in this one file: a second
+file could land on another worker, whose fixture would then skip.
+"""
+
+import os
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from dynamo_tpu.models import KVCache, init_params, kv_cache_pspec, param_pspecs
+from dynamo_tpu.models.config import LLAMA_3_1_8B, LLAMA_3_2_1B
+from dynamo_tpu.ops.pallas_attention import (
+    decode_attention_pallas,
+    prefill_attention_pallas,
+)
+from dynamo_tpu.ops.sampling import SamplingParams
+
+PAGE = 16
+POOL_PAGES = 4096
+# what chip_smoke.py's long prompts drive at the worker's default flags
+SMOKE_CHUNK = 512
+SMOKE_TABLE_PAGES = 128  # 2048 tokens of table under a 512-token chunk
+DECODE_TABLE_PAGES = 256  # 4096 tokens: where "adaptive" takes the kernel
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu here, or it is held
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compile(fn, *args):
+    t0 = time.monotonic()
+    compiled = jax.jit(fn).lower(*args).compile()
+    return compiled, time.monotonic() - t0
+
+
+def _kernel_args(cfg, sh, batch, table_pages, chunk=None):
+    H, KVH, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
+                  cfg.head_dim_)
+    pool = _sds((POOL_PAGES, PAGE, KVH, hd), jnp.bfloat16, sh)
+    table = _sds((batch, table_pages), jnp.int32, sh)
+    lens = _sds((batch,), jnp.int32, sh)
+    if chunk is None:
+        return [_sds((batch, H, hd), jnp.bfloat16, sh), pool, pool, table,
+                lens]
+    new = _sds((batch, chunk, KVH, hd), jnp.bfloat16, sh)
+    return [_sds((batch, chunk, H, hd), jnp.bfloat16, sh), new, new, pool,
+            pool, table, lens, lens]
+
+
+@pytest.mark.parametrize("cfg,extras", [
+    (LLAMA_3_2_1B, False), (LLAMA_3_1_8B, False), (LLAMA_3_2_1B, True),
+], ids=["1b", "8b", "1b-window-sink"])
+def test_decode_kernel_compiles(one_chip, cfg, extras):
+    args = _kernel_args(cfg, one_chip, 8, DECODE_TABLE_PAGES)
+    if extras:
+        args.append(_sds((cfg.num_attention_heads,), jnp.float32, one_chip))
+
+        def fn(q, k, v, t, n, sink):
+            return decode_attention_pallas(q, k, v, t, n, window=128,
+                                           sink=sink)
+    else:
+        fn = decode_attention_pallas
+    compiled, secs = _compile(fn, *args)
+    assert "tpu_custom_call" in compiled.as_text()
+    assert secs < 60, f"decode kernel took {secs:.1f}s to compile"
+
+
+@pytest.mark.parametrize("extras", [False, True], ids=["1b", "1b-window-sink"])
+def test_prefill_kernel_compiles_at_the_smoke_chunk(one_chip, extras):
+    """The worker's default --max-prefill-tokens chunk: under a minute (it
+    was 36-49 s before the head loops went onto row blocks; 4 s after)."""
+    cfg = LLAMA_3_2_1B
+    args = _kernel_args(cfg, one_chip, 1, SMOKE_TABLE_PAGES,
+                        chunk=SMOKE_CHUNK)
+    if extras:
+        args.append(_sds((cfg.num_attention_heads,), jnp.float32, one_chip))
+
+        def fn(q, kn, vn, k, v, t, pre, cl, sink):
+            return prefill_attention_pallas(q, kn, vn, k, v, t, pre, cl,
+                                            window=128, sink=sink)
+    else:
+        fn = prefill_attention_pallas
+    compiled, secs = _compile(fn, *args)
+    assert "tpu_custom_call" in compiled.as_text()
+    assert secs < 60, f"prefill kernel took {secs:.1f}s to compile"
+
+
+# -- whole steps ---------------------------------------------------------------- #
+
+def step_shapes(cfg, batch, table_pages, shardings):
+    """Abstract operands of the engine's decode step (`_build_decode_step`)
+    for `cfg`, placed by `shardings(kind)` — kind is a PartitionSpec tree
+    for "params"/"kv" and None for the replicated batch operands."""
+    params = jax.eval_shape(
+        lambda: init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16))
+    kv = jax.eval_shape(
+        lambda: KVCache.create(cfg, POOL_PAGES, PAGE, jnp.bfloat16))
+
+    def place(tree, specs):
+        return jax.tree.map(
+            lambda x, s: _sds(x.shape, x.dtype, shardings(s)), tree, specs)
+
+    def rep(shape, dtype):
+        return _sds(shape, dtype, shardings(P()))
+
+    samp = SamplingParams(
+        rep((batch,), jnp.float32), rep((batch,), jnp.int32),
+        rep((batch,), jnp.float32), rep((batch,), jnp.float32),
+        rep((batch,), jnp.float32))
+    return (place(params, param_pspecs(cfg)), place(kv, kv_cache_pspec()),
+            rep((batch,), jnp.int32), rep((batch,), jnp.int32),
+            rep((batch,), jnp.int32), rep((batch, table_pages), jnp.int32),
+            samp, rep((batch,), jnp.uint32))
+
+
+def two_layers(cfg):
+    import dataclasses
+
+    return dataclasses.replace(cfg, num_hidden_layers=2)
+
+
+def test_decode_block_step_compiles_on_one_chip(one_chip):
+    """A full-width (two-layer) decode step, both programs `adaptive`
+    picks: the block path under 4096 tokens of table, and the per-step
+    scan around the Pallas kernel from there on."""
+    from dynamo_tpu.engine.engine import _build_decode_step
+
+    cfg = two_layers(LLAMA_3_2_1B)
+    for table_pages, kernel in ((64, False), (DECODE_TABLE_PAGES, True)):
+        step = _build_decode_step(cfg, 4, 4096, greedy=True,
+                                  attn_impl="adaptive")
+        compiled = step.lower(
+            *step_shapes(cfg, 8, table_pages, lambda spec: one_chip)
+        ).compile()
+        assert ("tpu_custom_call" in compiled.as_text()) is kernel
+        mem = compiled.memory_analysis()
+        assert mem.temp_size_in_bytes < 4 << 30
+
+
+def test_decode_block_step_compiles_on_a_tp4_mesh(topo):
+    """The same step as GSPMD partitions it over the four chips (what
+    `worker --tp 4` runs): heads and the KV pool sharded four ways."""
+    from dynamo_tpu.engine.engine import _build_decode_step
+
+    cfg = two_layers(LLAMA_3_2_1B)
+    mesh = Mesh(np.array(topo.devices).reshape(1, 4), ("dp", "tp"))
+    step = _build_decode_step(cfg, 4, 4096, greedy=True, attn_impl="xla")
+    compiled = step.lower(
+        *step_shapes(cfg, 8, 64, lambda spec: NamedSharding(mesh, spec))
+    ).compile()
+    text = compiled.as_text()
+    assert "all-reduce" in text or "reduce-scatter" in text
+    per_chip = compiled.memory_analysis().argument_size_in_bytes
+    whole = sum(
+        x.size * x.dtype.itemsize for x in jax.tree.leaves(
+            step_shapes(cfg, 8, 64, lambda spec: None)[:2]))
+    assert per_chip < 0.4 * whole, (per_chip, whole)
