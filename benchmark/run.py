@@ -1,0 +1,435 @@
+#!/usr/bin/env python3
+"""One run of one benchmark cell on the served path.
+
+    python benchmark/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
+
+A cell is an entry of `workloads` in BENCHMARK.json: a configuration
+(benchmark/configs/<config>.json, found through the entry's `file`) under a
+traffic mix (benchmark/traffic/<mix>.json).  This parent never imports jax.
+It writes the configuration's checkpoint once per checkout, starts
+`python -m dynamo_tpu.runtime`, `python -m dynamo_tpu.worker --model <dir>`
+and `python -m dynamo_tpu.frontend` as children (the worker alone on the
+chip), checks the served logprobs against the plain reference, lets the
+mix's loop (benchmark/loops/<loop>.py) warm up and measure `--seconds` of
+real HTTP traffic against the frontend, stops every child, and prints the
+result as the LAST line of its standard output.
+
+Earlier lines are JSON notes ({"note": ...}) for a reader; see README.md.
+No TPU, an unknown device kind, a dead child before the window or a broken
+harness: exit code 1 and no result line.  `--rehearse-cpu` walks the same
+control flow on the CPU backend with the tiny cells of
+benchmark/tests/data/REHEARSAL.json; it never prints a result line and
+exits 2.
+"""
+
+import time
+
+T_START = time.monotonic()
+
+import argparse  # noqa: E402
+import asyncio  # noqa: E402
+import glob  # noqa: E402
+import hashlib  # noqa: E402
+import json  # noqa: E402
+import os  # noqa: E402
+import subprocess  # noqa: E402
+import sys  # noqa: E402
+
+BENCH = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(BENCH)
+sys.path.insert(0, BENCH)
+
+from lib import checkpoint as ckpt  # noqa: E402
+from lib import collect, loadgen, probes, procs, stats, traffic  # noqa: E402
+from lib import peaks as peaks_table  # noqa: E402
+from lib import trace as trace_lib  # noqa: E402
+from lib.procs import RunFailure  # noqa: E402
+
+CACHE = os.path.join(BENCH, ".cache")  # git-ignored; made from seeds
+
+# Policy stays the program's: a configuration passes the worker sizes only.
+POLICY_FLAGS = (
+    "--decode-steps", "--decode-chain", "--decode-continuous",
+    "--decode-block-ladder", "--mixed-prefill-tokens",
+    "--prefill-chunk-tokens", "--attention-impl", "--fuse-projections",
+    "--max-num-seqs", "--max-prefill-tokens", "--max-model-len",
+    "--speculative-ngram-k", "--quantization", "--no-prefix-caching")
+
+
+def note(what, **fields):
+    print(json.dumps({"note": what, **fields}), flush=True)
+
+
+def by_name(entries, name, what):
+    for e in entries:
+        if e["name"] == name:
+            return e
+    raise RunFailure(f"no {what} named {name!r}")
+
+
+def applies(metric, cell_name):
+    return "workloads" not in metric or cell_name in metric["workloads"]
+
+
+# -- set-up: checkpoint, reference, stack --------------------------------------- #
+
+def child_env(**extra):
+    env = dict(os.environ)
+    env["PYTHONPATH"] = ROOT + (
+        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
+    env.update(extra)
+    return env
+
+
+def require_tpu(chips):
+    """Asked of a child that exits before any worker starts; only a run
+    that has to write the checkpoint pays for it (later runs learn the
+    device from the worker's DEVICE line)."""
+    code = ("import json, jax; d = jax.devices(); print(json.dumps("
+            "{'platform': d[0].platform, 'count': len(d)}))")
+    p = subprocess.run([sys.executable, "-c", code],
+                       env=child_env(JAX_PLATFORMS="tpu"),
+                       capture_output=True, text=True, timeout=300)
+    if p.returncode != 0:
+        raise RunFailure("JAX finds no TPU here: " + p.stderr[-400:])
+    found = json.loads(p.stdout.strip().splitlines()[-1])
+    if found["platform"] != "tpu" or found["count"] < chips:
+        raise RunFailure(f"this cell needs {chips} TPU chip(s); JAX finds "
+                         f"{found}")
+
+
+def ensure_checkpoint(config, config_path, chips, rehearse):
+    path = os.path.join(CACHE, "ckpt",
+                        f"{config['name']}-{ckpt.checkpoint_key(config)}")
+    done = os.path.join(path, ".complete")
+    if os.path.exists(done):
+        return path, 0.0
+    if not rehearse:
+        require_tpu(chips)
+    t0 = time.monotonic()
+    p = subprocess.run(
+        [sys.executable, os.path.join(BENCH, "lib", "checkpoint.py"),
+         config_path, path], env=child_env(JAX_PLATFORMS="cpu"),
+        capture_output=True, text=True)
+    if p.returncode != 0:
+        raise RunFailure("checkpoint writer failed: " + p.stderr[-1500:])
+    return path, time.monotonic() - t0
+
+
+def start_reference(config, config_path, ckpt_dir, depth):
+    """The plain reference's answers to the probes: cached in the checkout,
+    else computed by a child on the host CPU while the worker loads."""
+    h = hashlib.sha256(f"{os.path.basename(ckpt_dir)}:{depth}".encode())
+    for f in (os.path.join(BENCH, "reference", config["reference"] + ".py"),
+              os.path.join(BENCH, "lib", "probes.py"),
+              os.path.join(BENCH, "lib", "reference_child.py")):
+        h.update(ckpt.file_sha(f).encode())
+    out = os.path.join(CACHE, "reference",
+                       f"{config['name']}-{h.hexdigest()[:16]}.json")
+    if os.path.exists(out):
+        return out, None
+    os.makedirs(os.path.dirname(out), exist_ok=True)
+    err = open(out + ".log", "w")
+    child = subprocess.Popen(
+        [sys.executable, os.path.join(BENCH, "lib", "reference_child.py"),
+         config_path, ckpt_dir, out, str(depth)],
+        env=child_env(JAX_PLATFORMS="cpu"), stdout=err,
+        stderr=subprocess.STDOUT)
+    return out, child
+
+
+def worker_flags(config, rehearse):
+    flags = []
+    for flag, value in config.get("worker_flags", {}).items():
+        if flag in POLICY_FLAGS:
+            raise RunFailure(
+                f"{config['name']}: {flag} is policy, not a size; the cells "
+                "measure the worker at its defaults")
+        flags += [flag, str(value)]
+    if rehearse:
+        flags += ["--platform", "cpu", "--dtype", "float32"]
+    return flags
+
+
+def check_device(device, chips, rehearse):
+    if rehearse:
+        return None
+    if device["platform"] != "tpu":
+        raise RunFailure(f"the worker runs on {device['platform']!r}, not a "
+                         "TPU: no CPU time is written under a metric's name")
+    if device["count"] < chips:
+        raise RunFailure(f"the cell needs {chips} chips, the worker sees "
+                         f"{device['count']}")
+    try:
+        return peaks_table.peaks_for(device["kind"])
+    except KeyError as e:
+        raise RunFailure(str(e)) from None
+
+
+# -- correctness ---------------------------------------------------------------- #
+
+async def run_probes(stack, config, ref_path):
+    """(correct, detail): see lib/probes.py."""
+    import aiohttp
+
+    with open(ref_path) as f:
+        ref = json.load(f)
+    texts = probes.probe_texts(config["weights_seed"],
+                               tuple(config["prompt_vocab"]))
+
+    async def ask(http, prompt, n):
+        body = loadgen.completion_body(
+            stack.MODEL_NAME, {"prompt": prompt, "max_tokens": n},
+            logprobs=0, stream=False)
+        async with http.post(stack.base + "/v1/completions",
+                             json=body) as resp:
+            if resp.status != 200:
+                raise RunFailure(f"probe answered {resp.status}: "
+                                 f"{(await resp.text())[:300]}")
+            r = await resp.json()
+        choice = r["choices"][0]
+        if (choice["finish_reason"] != "length"
+                or r["usage"]["completion_tokens"] != n
+                or r["usage"]["prompt_tokens"] != len(prompt)):
+            raise RunFailure(f"probe: {choice['finish_reason']} {r['usage']}")
+        return list(choice["logprobs"]["token_logprobs"])
+
+    tol, margin = ref["tolerance"], ref["tie_margin"]
+    forced, greedy = [], []
+    async with aiohttp.ClientSession() as http:
+        for text, n in zip(texts, probes.PROBE_LENS):
+            forced.append([(await ask(http, text[:n + k], 1))[0]
+                           for k in range(probes.PROBE_STEPS)])
+        for i in ref.get("greedy_probes", ()):
+            greedy.append(await ask(http, texts[i][:probes.PROBE_LENS[i]],
+                                    ref["depth"]))
+    ok, detail = probes.compare_forced(forced, ref["forced"], tol, margin)
+    out = {"tolerance": tol, "tie_margin": margin, "forced_ok": ok,
+           "forced": detail, "greedy_depth": ref["depth"]}
+    if greedy:
+        g_ok, out["greedy"] = probes.compare_greedy(greedy, ref["greedy"],
+                                                    tol, margin)
+        out["greedy_ok"] = g_ok
+        ok = ok and g_ok
+    out["served"] = forced
+    out["reference"] = [[round(s["logprob"], 4) for s in steps]
+                        for steps in ref["forced"]]
+    return ok, out
+
+
+# -- the measured phases -------------------------------------------------------- #
+
+async def measure(stack, config, mix, args):
+    """The mix's loop warms up, then measures the window."""
+    try:
+        loop = ckpt.load_module("loops", mix["loop"])
+    except FileNotFoundError:
+        raise RunFailure(f"no loop benchmark/loops/{mix['loop']}.py"
+                         ) from None
+    run = await loop.run({
+        "url": stack.base + "/v1/completions", "model": stack.MODEL_NAME,
+        "mix": mix, "seed": args.seed, "seconds": args.seconds,
+        "vocab": tuple(config["prompt_vocab"]),
+        "metrics_url": stack.status + "/metrics.json"})
+    note("warmup", **run["warmup"])
+    return run
+
+
+# -- the run -------------------------------------------------------------------- #
+
+def load_reader(kind_dir, metric_name):
+    try:
+        return ckpt.load_module(kind_dir, metric_name).read
+    except FileNotFoundError:
+        raise RunFailure(f"no reader benchmark/{kind_dir}/{metric_name}.py"
+                         ) from None
+
+
+def reduce_trace(xprof_dir, run, log_dir):
+    files = glob.glob(os.path.join(xprof_dir, "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    if not files:
+        raise RunFailure(f"the worker wrote no profiler trace to {xprof_dir}")
+    if run["clock_anchor"] is None:
+        raise RunFailure("no clock anchor from /events.json")
+    wall_ns, mono_ns = run["clock_anchor"]
+    t0, t1 = int(run["t0"] * 1e9), int(run["t1"] * 1e9)
+    compact = trace_lib.load_in_child(
+        max(files, key=os.path.getsize), os.path.join(log_dir, "trace.json"),
+        wall_ns - mono_ns, t0, t1, child_env())
+    note("trace", file_bytes=os.path.getsize(max(files, key=os.path.getsize)),
+         planes=compact["summary"])
+    subprocess.run(["rm", "-rf", xprof_dir], check=False)  # hundreds of MB
+    try:
+        return trace_lib.reduce(compact, t0, t1, run["events"])
+    except RuntimeError as e:
+        raise RunFailure(str(e)) from None
+
+
+async def serve_and_measure(stack, cell, config, mix, args, ckpt_dir,
+                            ref_path, ref_child, xprof_dir):
+    env = {}
+    if not args.rehearse_cpu:
+        env["JAX_PLATFORMS"] = "tpu"
+    if args.trace:
+        # the program's capture starts at the first non-idle engine step and
+        # lasts N steps; N is set beyond any run, so the capture ends when
+        # the worker shuts down, and the reduction cuts it to the window
+        env.update(DYN_TPU_XPROF_STEPS=str(10 ** 9),
+                   DYN_TPU_XPROF_DIR=xprof_dir)
+    device, secs = stack.start_worker(
+        ckpt_dir, worker_flags(config, args.rehearse_cpu), env, timeout=900)
+    peaks = check_device(device, cell["chips"], args.rehearse_cpu)
+    note("worker", seconds_to_ready=secs, device=device)
+    deadline = time.monotonic() + 60
+    while True:
+        models = await collect.get_json(stack.base + "/v1/models")
+        if any(m["id"] == stack.MODEL_NAME for m in models["data"]):
+            break
+        if time.monotonic() > deadline:
+            raise RunFailure("the frontend never listed the model")
+        await asyncio.sleep(0.25)
+    if ref_child is not None:
+        t0 = time.monotonic()
+        while ref_child.poll() is None:
+            await asyncio.sleep(0.5)
+        if ref_child.returncode != 0:
+            raise RunFailure("the reference failed: "
+                             + procs.log_tail(ref_path + ".log"))
+        note("reference", waited_seconds=time.monotonic() - t0)
+    correct, detail = await run_probes(stack, config, ref_path)
+    note("probes", correct=correct, **detail)
+    poller = collect.EventPoller(stack.status)
+    if args.trace:
+        poller.start()
+    run = await measure(stack, config, mix, args)
+    if args.trace:
+        await poller.stop()
+    run.update(correct=correct, device=device, peaks=peaks, config=config,
+               mix=mix, events=poller.events, events_dropped=poller.dropped,
+               clock_anchor=poller.anchor, trace=None)
+    return run
+
+
+def main():
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--seconds", type=float, default=None)
+    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--rehearse-cpu", action="store_true")
+    args = ap.parse_args()
+
+    spec_path = (os.path.join(BENCH, "tests", "data", "REHEARSAL.json")
+                 if args.rehearse_cpu else os.path.join(ROOT, "BENCHMARK.json"))
+    stack = None
+    try:
+        if not os.path.isdir(os.path.join(ROOT, "dynamo_tpu")):
+            raise RunFailure("the system under test (dynamo_tpu/) is not in "
+                             "this checkout")
+        with open(spec_path) as f:
+            spec = json.load(f)
+        cell = by_name(spec["workloads"], args.workload, "workload")
+        entry = by_name(spec["configs"], cell["config"], "configuration")
+        config_path = os.path.join(ROOT, entry["file"])
+        with open(config_path) as f:
+            config = json.load(f)
+        mix = traffic.load_mix(
+            os.path.join(BENCH, "traffic", cell["traffic"] + ".json"))
+        if args.seconds is None:
+            args.seconds = float(spec["run_seconds"])
+        log_dir = os.path.join(
+            CACHE, "logs", f"{args.workload}-{args.seed}-{args.trace}")
+        xprof_dir = os.path.join(log_dir, "xprof")
+        subprocess.run(["rm", "-rf", xprof_dir], check=False)
+        os.makedirs(log_dir, exist_ok=True)
+
+        ckpt_dir, wrote = ensure_checkpoint(config, config_path, cell["chips"],
+                                            args.rehearse_cpu)
+        note("checkpoint", path=os.path.relpath(ckpt_dir, ROOT),
+             seconds_to_write=wrote)
+        ref_path, ref_child = start_reference(
+            config, config_path, ckpt_dir,
+            probes.greedy_depth(traffic.max_output_len(mix)))
+        stack = procs.Stack(log_dir, child_env(),
+                            config.get("router_mode", "round_robin"))
+        run = asyncio.run(serve_and_measure(
+            stack, cell, config, mix, args, ckpt_dir, ref_path, ref_child,
+            xprof_dir))
+        died = stack.ps.dead()
+        end_metrics = run["metrics1"]
+        stack.stop_worker()  # the trace, when armed, is written now
+        stack.close()
+        stack = None
+        if args.trace:
+            try:
+                run["trace"] = reduce_trace(xprof_dir, run, log_dir)
+            except RunFailure as e:
+                if not args.rehearse_cpu:  # the CPU backend has no device plane
+                    raise
+                note("trace", rehearsal_without_device_plane=str(e))
+    except RunFailure as e:
+        print(f"BENCHMARK RUN FAILED: {e}", file=sys.stderr, flush=True)
+        return 1
+    finally:
+        if stack is not None:
+            stack.close()
+
+    w = stats.window(run["records"], run["t0"], run["t1"])
+    attempted, failed = len(w["measured"]), len(w["measured"]) - len(w["ok"])
+    e2e = {"setup_s": run["t0"] - T_START}
+    for m in spec["end_to_end"]:
+        if m["name"] != "setup_s" and applies(m, args.workload):
+            e2e[m["name"]] = load_reader("end_to_end", m["name"])(w)
+    note("window", seconds=args.seconds, attempted=attempted, failed=failed,
+         samples_ok=len(w["ok"]),
+         errors=[r["error"] for r in w["measured"] if r["error"]][:3],
+         requests_since_start=len(run["records"]),
+         prompt_tokens_since_start=sum(r["prompt_len"]
+                                       for r in run["records"]),
+         kv_pool_tokens=config.get("memory", {}).get("kv_pool_tokens"),
+         kv_usage_at_end=end_metrics.get("kv_usage"),
+         events_dropped=run["events_dropped"], children_died=died)
+    note("end_to_end", **e2e)  # every run shows them; only --trace 0 reports
+    metrics = {}
+    if args.trace:
+        with open(os.path.join(log_dir, "events.json"), "w") as f:
+            json.dump({"t0": run["t0"], "t1": run["t1"],
+                       "events": run["events"]}, f)
+        for m in spec["per_layer"]:
+            if applies(m, args.workload):
+                value = load_reader("layer_metrics", m["name"])(run)
+                if value is not None:
+                    metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+    else:
+        for m in spec["end_to_end"]:
+            if applies(m, args.workload):
+                if e2e.get(m["name"]) is None:
+                    print(f"BENCHMARK RUN FAILED: no {m['name']}: "
+                          f"{failed} of {attempted} requests failed",
+                          file=sys.stderr, flush=True)
+                    return 1
+                metrics[m["name"]] = {"value": e2e[m["name"]],
+                                      "unit": m["unit"]}
+    device = dict(run["device"], memory_peak_bytes=max(
+        m["peak_bytes_in_use"] or 0
+        for m in end_metrics["runtime"]["memory"]))
+    result = {"correct": bool(run["correct"]) and not died,
+              "attempted": attempted, "failed": failed, "metrics": metrics,
+              "device": device}
+    if run["trace"] is not None:
+        device.update(busy_s=run["trace"]["busy_s"],
+                      window_s=run["trace"]["window_s"])
+        result["breakdown"] = {"device_ops": run["trace"]["device_ops"],
+                               "idle_gaps": run["trace"]["idle_gaps"]}
+    if args.rehearse_cpu:
+        note("rehearsal", would_print=result)
+        print("rehearsal finished on the CPU backend; this is not a chip run",
+              file=sys.stderr)
+        return 2
+    print(json.dumps(result), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
