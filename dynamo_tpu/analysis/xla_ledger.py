@@ -145,6 +145,7 @@ _cache_requests = 0
 _cache_hits = 0
 # trace-time dispatch decisions: (site, choice, reason, dims) → traces
 _path_choices: Dict[Tuple[str, str, str, str], int] = {}
+_choice_by_dims: Dict[Tuple[str, str], str] = {}  # (site, dims) → choice
 _violations: List[dict] = []
 _violations_by_kind: Dict[str, int] = {}
 _MAX_RECORDS = 4096
@@ -209,8 +210,12 @@ def _record_trace(fn_name: str, signature: str,
 
 
 def ledgered_jit(fn: Callable, *, tags: Optional[Dict[str, Any]] = None,
-                 **jit_kwargs) -> Callable:
+                 name: Optional[str] = None, **jit_kwargs) -> Callable:
     """``jax.jit`` with compile attribution.
+
+    ``name`` names the compiled program (``jit_<name>`` on a profiler
+    trace's "XLA Modules" line); the ledger keeps attributing compiles
+    to the function's qualified name.
 
     Drop-in at the call sites the engine uses
     (``partial(ledgered_jit, donate_argnums=...)`` mirrors
@@ -219,15 +224,16 @@ def ledgered_jit(fn: Callable, *, tags: Optional[Dict[str, Any]] = None,
     costs nothing on the steady-state hit path.  Returns plain
     ``jax.jit(fn)`` when the ledger is disabled, for exact parity.
     """
+    qual = getattr(fn, "__qualname__", getattr(fn, "__name__", repr(fn)))
+    if name is not None:
+        fn.__name__ = name
     if not _LEDGER_ON:
         return jax.jit(fn, **jit_kwargs)
     import functools
 
-    name = getattr(fn, "__qualname__", getattr(fn, "__name__", repr(fn)))
-
     @functools.wraps(fn)
     def probe(*args, **kwargs):
-        _record_trace(name, _fmt_signature(args, kwargs), tags)
+        _record_trace(qual, _fmt_signature(args, kwargs), tags)
         return fn(*args, **kwargs)
 
     return jax.jit(probe, **jit_kwargs)
@@ -303,11 +309,19 @@ def note_path_choice(site: str, choice: str, reason: str, **dims) -> None:
     with _LOCK:
         first = key not in _path_choices
         _path_choices[key] = _path_choices.get(key, 0) + 1
+        _choice_by_dims[(site, key[3])] = choice
     if first:
         import logging
 
         logging.getLogger(__name__).info(
             "path choice: %s -> %s (%s) [%s]", *key)
+
+
+def path_choice(site: str, **dims) -> Optional[str]:
+    """The choice last noted at `site` for exactly these dims, or None."""
+    with _LOCK:
+        return _choice_by_dims.get(
+            (site, ",".join(f"{k}={v}" for k, v in sorted(dims.items()))))
 
 
 def summary() -> dict:
@@ -335,6 +349,7 @@ def reset() -> None:
     global _backend_compile_secs, _cache_hits, _cache_requests
     with _LOCK:
         _path_choices.clear()
+        _choice_by_dims.clear()
         _backend_compile_secs = 0.0
         _cache_hits = _cache_requests = 0
         _entries.clear()

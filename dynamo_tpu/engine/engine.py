@@ -87,7 +87,16 @@ class ForwardPassMetrics:
     ttft_block_wait_ms_total: float = 0.0
     ttft_queue_wait_ms_total: float = 0.0
     ttft_prefill_ms_total: float = 0.0
+    # of prefill_ms: admitted, but waiting for a turn at the device or
+    # between its own chunks (the ring's `first_token.wait_us`)
+    ttft_turn_wait_ms_total: float = 0.0
     ttft_attributed_total: int = 0
+    # pool occupancy with the prefix cache counted: pages held ONLY by the
+    # cache (evictable; `kv_usage` counts them as free), pages on the free
+    # list, and cached pages evicted to make room so far
+    kv_pages_cached: int = 0
+    kv_pages_free: int = 0
+    prefix_evictions_total: int = 0
     # device-resident decode loop: chains run and blocks dispatched by
     # the continuous path (blocks/chains >> decode_chain means the open
     # horizon is actually engaging)
@@ -155,32 +164,53 @@ def _decode_path(attn_impl: str, kv, page_table, n_steps: int) -> str:
     return path
 
 
+def _decode_name(n_steps: int) -> str:
+    """Program name of a decode dispatch: a scan of several steps is a
+    block."""
+    return "decode_step" if n_steps == 1 else "decode_block"
+
+
+@jax.named_scope("pack")
+def _pack(*parts: jax.Array) -> jax.Array:
+    """Concatenate int32 ids and float32 logprobs into ONE int32 array
+    along the last axis (floats ride as their bit patterns).  Integers and
+    not floats on purpose: an id below 2^23 viewed as float32 is a denormal
+    and the TPU flushes denormals to zero, so a float32 pack delivered
+    every token id as 0 on the chip (PERF.md finding 1)."""
+    return jnp.concatenate(
+        [p if p.dtype == jnp.int32
+         else jax.lax.bitcast_convert_type(p, jnp.int32) for p in parts],
+        axis=-1)
+
+
+def _as_f32(bits: np.ndarray) -> np.ndarray:
+    """Host-side inverse of `_pack` for a float32 column range."""
+    return np.ascontiguousarray(bits).view(np.float32)
+
+
 def _pack_out(out: jax.Array, logp: jax.Array, logits=None) -> jax.Array:
     """Pack sampled tokens (int32) + logprobs (float32) — plus top-TOPLP
-    (ids, logprobs) when `logits` is given — into ONE float32 array along
-    the last axis: every host fetch is a device→host sync with a fixed
-    cost whatever its size, so results come back in a single transfer.
+    (ids, logprobs) when `logits` is given — into ONE int32 array along
+    the last axis (`_pack`): every host fetch is a device→host sync with a
+    fixed cost whatever its size, so results come back in a single transfer.
 
     Layout: [tok(B) | logp(B) | top_ids(B*TOPLP) | top_lps(B*TOPLP)].
     """
-    parts = [jax.lax.bitcast_convert_type(out, jnp.float32), logp]
+    parts = [out, logp]
     if logits is not None:
         ids, lps = top_logprobs(logits, TOPLP)  # [B, TOPLP] each
-        parts.append(jax.lax.bitcast_convert_type(ids, jnp.float32).reshape(-1))
-        parts.append(lps.reshape(-1))
-    return jnp.concatenate(parts, axis=-1)
+        parts += [ids.reshape(-1), lps.reshape(-1)]
+    return _pack(*parts)
 
 
 def _unpack_out(packed: np.ndarray, b: int, with_top: bool = False):
     """Inverse of `_pack_out`; returns (toks, logp, top_ids, top_lps)."""
-    toks = np.ascontiguousarray(packed[..., :b]).view(np.int32)
-    logp = packed[..., b : 2 * b]
+    toks = packed[..., :b]
+    logp = _as_f32(packed[..., b : 2 * b])
     if not with_top:
         return toks, logp, None, None
-    ids = np.ascontiguousarray(
-        packed[..., 2 * b : 2 * b + b * TOPLP]
-    ).view(np.int32)
-    lps = packed[..., 2 * b + b * TOPLP :]
+    ids = packed[..., 2 * b : 2 * b + b * TOPLP]
+    lps = _as_f32(packed[..., 2 * b + b * TOPLP :])
     return (
         toks, logp,
         ids.reshape(*packed.shape[:-1], b, TOPLP),
@@ -191,33 +221,29 @@ def _unpack_out(packed: np.ndarray, b: int, with_top: bool = False):
 def _pack_out_cc(out: jax.Array, logp: jax.Array, act: jax.Array,
                  logits=None) -> jax.Array:
     """`_pack_out` plus the device-resident loop's per-row EMITTED flag
-    (1.0 where the row was still active when this step sampled): the
+    (1 where the row was still active when this step sampled): the
     drained buffer is then self-describing — the host learns each row's
     real token count and stop position from the flags instead of
     re-running per-token stop checks.
 
     Layout: [tok(B) | logp(B) | act(B) | top_ids(B*TOPLP) | top_lps]."""
-    parts = [jax.lax.bitcast_convert_type(out, jnp.float32), logp,
-             act.astype(jnp.float32)]
+    parts = [out, logp, act.astype(jnp.int32)]
     if logits is not None:
         ids, lps = top_logprobs(logits, TOPLP)
-        parts.append(jax.lax.bitcast_convert_type(ids, jnp.float32).reshape(-1))
-        parts.append(lps.reshape(-1))
-    return jnp.concatenate(parts, axis=-1)
+        parts += [ids.reshape(-1), lps.reshape(-1)]
+    return _pack(*parts)
 
 
 def _unpack_out_cc(packed: np.ndarray, b: int, with_top: bool = False):
     """Inverse of `_pack_out_cc`; returns (toks, logp, flags, top_ids,
     top_lps) — `flags` is a bool emitted-mask aligned with toks."""
-    toks = np.ascontiguousarray(packed[..., :b]).view(np.int32)
-    logp = packed[..., b : 2 * b]
-    flags = packed[..., 2 * b : 3 * b] > 0.5
+    toks = packed[..., :b]
+    logp = _as_f32(packed[..., b : 2 * b])
+    flags = packed[..., 2 * b : 3 * b] > 0
     if not with_top:
         return toks, logp, flags, None, None
-    ids = np.ascontiguousarray(
-        packed[..., 3 * b : 3 * b + b * TOPLP]
-    ).view(np.int32)
-    lps = packed[..., 3 * b + b * TOPLP :]
+    ids = packed[..., 3 * b : 3 * b + b * TOPLP]
+    lps = _as_f32(packed[..., 3 * b + b * TOPLP :])
     return (
         toks, logp, flags,
         ids.reshape(*packed.shape[:-1], b, TOPLP),
@@ -255,9 +281,9 @@ def _unpack_spec(packed: np.ndarray, b: int, s: int):
     """Inverse of the spec verify step's packing: (tokens [B, S] int32,
     logprobs [B, S] float32, accepted draft count [B] int32)."""
     n = b * s
-    toks = np.ascontiguousarray(packed[:n]).view(np.int32).reshape(b, s)
-    logp = packed[n:2 * n].reshape(b, s)
-    n_acc = np.ascontiguousarray(packed[2 * n:2 * n + b]).view(np.int32)
+    toks = packed[:n].reshape(b, s)
+    logp = _as_f32(packed[n:2 * n]).reshape(b, s)
+    n_acc = packed[2 * n:2 * n + b]
     return toks, logp, n_acc
 
 
@@ -281,7 +307,7 @@ def _build_prefill_step(cfg: ModelConfig, with_top: bool = False,
     kw = ({"out_shardings": _lockstep_out_shardings(lockstep_mesh, P())}
           if lockstep_mesh is not None else {})
 
-    @partial(_ljit, donate_argnums=(1,), **kw)
+    @partial(_ljit, name="prefill_step", donate_argnums=(1,), **kw)
     def step(params, kv, tokens, page_table, prefix_lens, chunk_lens, samp,
              seeds, counters, *mm):
         logits, kv = forward_prefill(
@@ -325,7 +351,7 @@ def _build_prefill_step_sp(cfg: ModelConfig, mesh, with_top: bool = False,
         kw = {}
 
     if pool_axes is None:
-        @partial(_ljit, donate_argnums=(1,), **kw)
+        @partial(_ljit, name="prefill_step_sp", donate_argnums=(1,), **kw)
         def step(params, kv, tokens, page_table, prefix_lens, chunk_lens,
                  samp, seeds, counters, *rest):
             mm, (prefix_table,) = rest[:-1], rest[-1:]
@@ -341,7 +367,7 @@ def _build_prefill_step_sp(cfg: ModelConfig, mesh, with_top: bool = False,
             logp = compute_logprobs(logits, out)
             return _pack_out(out, logp, logits if with_top else None), out, kv
     else:
-        @partial(_ljit, donate_argnums=(1,), **kw)
+        @partial(_ljit, name="prefill_step_sp", donate_argnums=(1,), **kw)
         def step(params, kv, tokens, page_table, prefix_lens, chunk_lens,
                  samp, seeds, counters, *rest):
             del prefix_lens
@@ -384,7 +410,7 @@ def _build_prefill_step_pp(cfg: ModelConfig, mesh, with_top: bool = False,
 
     kw = _pp_lockstep_kw(mesh, 2, pooled) if lockstep else {}
 
-    @partial(_ljit, donate_argnums=(1,), **kw)
+    @partial(_ljit, name="prefill_step_pp", donate_argnums=(1,), **kw)
     def step(params, kv, tokens, page_table, prefix_lens, chunk_lens, samp,
              seeds, counters):
         logits, kv = forward_prefill_pp(
@@ -411,20 +437,19 @@ def _build_decode_step_pp(cfg: ModelConfig, mesh, n_steps: int,
     from ..parallel.pp_engine import forward_decode_pp
 
     def pack(toks, logp, tops):
-        parts = [jax.lax.bitcast_convert_type(toks, jnp.float32), logp]
+        parts = [toks, logp]
         if tops is not None:
             ids, lps = tops  # [T, B, TOPLP] each
             T = ids.shape[0]
-            parts.append(jax.lax.bitcast_convert_type(
-                ids, jnp.float32).reshape(T, -1))
-            parts.append(lps.reshape(T, -1))
-        return jnp.concatenate(parts, axis=-1)
+            parts += [ids.reshape(T, -1), lps.reshape(T, -1)]
+        return _pack(*parts)
 
     top_k = TOPLP if with_top else 0
     if penalized:
         kw = _pp_lockstep_kw(mesh, 5, pooled) if lockstep else {}
 
-        @partial(_ljit, donate_argnums=(1, 5), tags={"rung": n_steps}, **kw)
+        @partial(_ljit, name=_decode_name(n_steps) + "_pp",
+                 donate_argnums=(1, 5), tags={"rung": n_steps}, **kw)
         def step(params, kv, tokens, positions, counters, counts,
                  page_table, samp, seeds):
             toks, logp, tops, counts, kv = forward_decode_pp(
@@ -437,7 +462,8 @@ def _build_decode_step_pp(cfg: ModelConfig, mesh, n_steps: int,
     else:
         kw = _pp_lockstep_kw(mesh, 4, pooled) if lockstep else {}
 
-        @partial(_ljit, donate_argnums=(1,), tags={"rung": n_steps}, **kw)
+        @partial(_ljit, name=_decode_name(n_steps) + "_pp",
+                 donate_argnums=(1,), tags={"rung": n_steps}, **kw)
         def step(params, kv, tokens, positions, counters, page_table,
                  samp, seeds):
             toks, logp, tops, _, kv = forward_decode_pp(
@@ -612,13 +638,15 @@ def _build_decode_step(cfg: ModelConfig, n_steps: int, max_valid_pos: int,
             if lockstep_mesh is not None else {})
 
         if mrope:
-            @partial(_ljit, donate_argnums=(1, 5), tags={"rung": n_steps}, **kw)
+            @partial(_ljit, name=_decode_name(n_steps),
+                     donate_argnums=(1, 5), tags={"rung": n_steps}, **kw)
             def step(params, kv, tokens, positions, counters, counts,
                      page_table, samp, seeds, rope_off):
                 return run(params, kv, tokens, positions, counters, counts,
                            page_table, samp, seeds, rope_off)
         else:
-            @partial(_ljit, donate_argnums=(1, 5), tags={"rung": n_steps}, **kw)
+            @partial(_ljit, name=_decode_name(n_steps),
+                     donate_argnums=(1, 5), tags={"rung": n_steps}, **kw)
             def step(params, kv, tokens, positions, counters, counts,
                      page_table, samp, seeds):
                 return run(params, kv, tokens, positions, counters, counts,
@@ -629,13 +657,15 @@ def _build_decode_step(cfg: ModelConfig, n_steps: int, max_valid_pos: int,
             if lockstep_mesh is not None else {})
 
         if mrope:
-            @partial(_ljit, donate_argnums=(1,), tags={"rung": n_steps}, **kw)
+            @partial(_ljit, name=_decode_name(n_steps),
+                     donate_argnums=(1,), tags={"rung": n_steps}, **kw)
             def step(params, kv, tokens, positions, counters, page_table,
                      samp, seeds, rope_off):
                 return run(params, kv, tokens, positions, counters, None,
                            page_table, samp, seeds, rope_off)
         else:
-            @partial(_ljit, donate_argnums=(1,), tags={"rung": n_steps}, **kw)
+            @partial(_ljit, name=_decode_name(n_steps),
+                     donate_argnums=(1,), tags={"rung": n_steps}, **kw)
             def step(params, kv, tokens, positions, counters, page_table,
                      samp, seeds):
                 return run(params, kv, tokens, positions, counters, None,
@@ -710,7 +740,7 @@ def _make_decode_scan_cc(cfg: ModelConfig, n_steps: int, max_valid_pos: int,
         if penalized:
             cts = cts.at[jnp.arange(out.shape[0]), out].add(emitf)
         logp = compute_logprobs(logits, out)
-        packed = _pack_out_cc(out, logp, emitf,
+        packed = _pack_out_cc(out, logp, emit,
                               logits if with_top else None)
         hit = (out[:, None] == stops).any(axis=-1)
         budget = budget - emit.astype(budget.dtype)
@@ -826,7 +856,8 @@ def _build_decode_step_cc(cfg: ModelConfig, n_steps: int, max_valid_pos: int,
     mrope = bool(cfg.mrope_section)
     if penalized:
         if mrope:
-            @partial(_ljit, donate_argnums=(1, 5), tags={"rung": n_steps})
+            @partial(_ljit, name="decode_block_cc",
+                     donate_argnums=(1, 5), tags={"rung": n_steps})
             def step(params, kv, tokens, positions, counters, counts, act,
                      budget, stops, page_table, samp, seeds, chunk_toks,
                      chunk_rem, chunk_samples, reset, init_pos,
@@ -836,7 +867,8 @@ def _build_decode_step_cc(cfg: ModelConfig, n_steps: int, max_valid_pos: int,
                            chunk_toks, chunk_rem, chunk_samples, reset,
                            init_pos, init_budget, rope_off)
         else:
-            @partial(_ljit, donate_argnums=(1, 5), tags={"rung": n_steps})
+            @partial(_ljit, name="decode_block_cc",
+                     donate_argnums=(1, 5), tags={"rung": n_steps})
             def step(params, kv, tokens, positions, counters, counts, act,
                      budget, stops, page_table, samp, seeds, chunk_toks,
                      chunk_rem, chunk_samples, reset, init_pos,
@@ -847,7 +879,8 @@ def _build_decode_step_cc(cfg: ModelConfig, n_steps: int, max_valid_pos: int,
                            init_pos, init_budget)
     else:
         if mrope:
-            @partial(_ljit, donate_argnums=(1,), tags={"rung": n_steps})
+            @partial(_ljit, name="decode_block_cc",
+                     donate_argnums=(1,), tags={"rung": n_steps})
             def step(params, kv, tokens, positions, counters, act, budget,
                      stops, page_table, samp, seeds, chunk_toks, chunk_rem,
                      chunk_samples, reset, init_pos, init_budget, rope_off):
@@ -856,7 +889,8 @@ def _build_decode_step_cc(cfg: ModelConfig, n_steps: int, max_valid_pos: int,
                            chunk_toks, chunk_rem, chunk_samples, reset,
                            init_pos, init_budget, rope_off)
         else:
-            @partial(_ljit, donate_argnums=(1,), tags={"rung": n_steps})
+            @partial(_ljit, name="decode_block_cc",
+                     donate_argnums=(1,), tags={"rung": n_steps})
             def step(params, kv, tokens, positions, counters, act, budget,
                      stops, page_table, samp, seeds, chunk_toks, chunk_rem,
                      chunk_samples, reset, init_pos, init_budget):
@@ -900,21 +934,17 @@ def _build_spec_verify_step(cfg: ModelConfig, *, greedy: bool = False,
         out, logp = sample_tokens_block(logits, samp, seeds, counters,
                                         greedy)
         n_acc = speculative_accept(out, tokens)
-        packed = jnp.concatenate([
-            jax.lax.bitcast_convert_type(out.reshape(-1), jnp.float32),
-            logp.reshape(-1),
-            jax.lax.bitcast_convert_type(n_acc, jnp.float32),
-        ])
+        packed = _pack(out.reshape(-1), logp.reshape(-1), n_acc)
         return packed, kv
 
     if mrope:
-        @partial(_ljit, donate_argnums=(1,), **kw)
+        @partial(_ljit, name="verify_step", donate_argnums=(1,), **kw)
         def step(params, kv, tokens, positions, page_table, samp, seeds,
                  counters, rope_off):
             return body(params, kv, tokens, positions, page_table, samp,
                         seeds, counters, rope_off)
     else:
-        @partial(_ljit, donate_argnums=(1,), **kw)
+        @partial(_ljit, name="verify_step", donate_argnums=(1,), **kw)
         def step(params, kv, tokens, positions, page_table, samp, seeds,
                  counters):
             return body(params, kv, tokens, positions, page_table, samp,
@@ -986,7 +1016,8 @@ def _build_mixed_step(cfg: ModelConfig, n_steps: int, max_valid_pos: int,
                             with_top, attn_impl, greedy)
     kw = ({"out_shardings": _lockstep_out_shardings(lockstep_mesh, P())}
           if lockstep_mesh is not None else {})
-    return partial(_ljit, donate_argnums=(1,), tags={"rung": n_steps}, **kw)(body)
+    return partial(_ljit, name="mixed_step",
+                   donate_argnums=(1,), tags={"rung": n_steps}, **kw)(body)
 
 
 # -- partitioned-pool (kv_partition) step builders -------------------------- #
@@ -1068,7 +1099,8 @@ def _build_prefill_step_pooled(cfg: ModelConfig, mesh, pool_axes,
         axis_names=set(pool_axes),
     )
     kw = _lockstep_pooled_kw(mesh, pool_axes, out_specs) if lockstep else {}
-    return partial(_ljit, donate_argnums=(1,), **kw)(sm)
+    return partial(_ljit, name="prefill_step_pooled",
+                   donate_argnums=(1,), **kw)(sm)
 
 
 def _build_decode_step_pooled(cfg: ModelConfig, mesh, pool_axes, n_steps: int,
@@ -1110,7 +1142,8 @@ def _build_decode_step_pooled(cfg: ModelConfig, mesh, pool_axes, n_steps: int,
         axis_names=set(pool_axes),
     )
     kw = _lockstep_pooled_kw(mesh, pool_axes, out_specs) if lockstep else {}
-    step = partial(_ljit, donate_argnums=donate, tags={"rung": n_steps}, **kw)(sm)
+    step = partial(_ljit, name=_decode_name(n_steps) + "_pooled",
+                   donate_argnums=donate, tags={"rung": n_steps}, **kw)(sm)
     if penalized:
         return step
     # present the same call shape as _build_decode_step's plain variant
@@ -1150,7 +1183,8 @@ def _build_mixed_step_pooled(cfg: ModelConfig, mesh, pool_axes, n_steps: int,
     )
     kw = (_lockstep_pooled_kw(mesh, pool_axes, out_specs, n_replicated=2)
           if lockstep else {})
-    return partial(_ljit, donate_argnums=(1,), tags={"rung": n_steps}, **kw)(sm)
+    return partial(_ljit, name="mixed_step_pooled",
+                   donate_argnums=(1,), tags={"rung": n_steps}, **kw)(sm)
 
 
 def _build_export_fn_pooled(cfg: ModelConfig, mesh, pool_axes,
@@ -1673,7 +1707,7 @@ class JaxEngine:
         # optional dispatch trace (tests / debugging): set to a list and
         # every device dispatch appends {kind, n_steps, pending}
         self.dispatch_trace: Optional[List[dict]] = None
-        # step-event ring (runtime.events): admit/dispatch/rung/spec/pool
+        # step-event ring (runtime.events): admit/plan/step/rung/spec/pool
         # events with monotonic-ns stamps — dumped by the worker debug
         # endpoint and merged into the Perfetto timeline.  Scheduler and
         # pool record through the same ring so one dump is the whole
@@ -1684,16 +1718,23 @@ class JaxEngine:
         self.scheduler.events = self.events
         for p in getattr(self.pool, "pools", [self.pool]):
             p.events = self.events
-        # env-gated jax.profiler capture: DYN_TPU_XPROF_STEPS=N traces the
-        # next N engine steps into DYN_TPU_XPROF_DIR (default profiles/)
-        # once the pump starts dispatching — the on-chip attribution the
-        # ROADMAP perf items need, off unless asked for
+        # jax.profiler capture of the next N engine steps, armed by
+        # DYN_TPU_XPROF_STEPS=N / DYN_TPU_XPROF_DIR (default profiles/) at
+        # start-up or by `arm_xprof` while serving (the worker's
+        # POST /debug/xprof); starts at the next dispatched step
         from ..runtime.config import env_int, env_str
 
         self._xprof_steps = env_int("DYN_TPU_XPROF_STEPS", 0)
         self._xprof_dir = env_str("DYN_TPU_XPROF_DIR", "profiles")
         self._xprof_started_at: Optional[int] = None
         self._xprof_done = self._xprof_steps <= 0
+        # ring clock (monotonic ns): start of the step the step thread is
+        # in, and the instant the last step returned (the pump's
+        # `loop_yield` slice starts there)
+        self._step_t0_ns: Optional[int] = None
+        self._step_end_ns: Optional[int] = None
+        self._ttft_turn_wait_ms_total = 0.0
+        self._evictions_before_reset = 0
 
     def attach_connector(self, connector) -> None:
         """Attach a KVBM connector (kvbm.KvConnector shape: on_event /
@@ -2184,7 +2225,12 @@ class JaxEngine:
             ttft_block_wait_ms_total=self._ttft_block_wait_ms_total,
             ttft_queue_wait_ms_total=self._ttft_queue_wait_ms_total,
             ttft_prefill_ms_total=self._ttft_prefill_ms_total,
+            ttft_turn_wait_ms_total=self._ttft_turn_wait_ms_total,
             ttft_attributed_total=self._ttft_attributed_total,
+            kv_pages_cached=self.pool.evictable_pages,
+            kv_pages_free=self.pool.free_pages,
+            prefix_evictions_total=(self._evictions_before_reset
+                                    + self.pool.evictions_total),
             decode_cc_blocks_total=self._cc_blocks_total,
             decode_cc_chains_total=self._cc_chains_total,
             decode_cc_fallout_total=dict(self._cc_fallout_by_reason),
@@ -2372,11 +2418,7 @@ class JaxEngine:
         self._closed = True
         self._wake.set()
         if self._xprof_started_at is not None and not self._xprof_done:
-            self._xprof_done = True
-            try:
-                jax.profiler.stop_trace()
-            except Exception:  # lint: allow(swallowed-exception): best-effort profiler flush on exit
-                pass
+            self._xprof_stop()
         if self._pump_task:
             await asyncio.gather(self._pump_task, return_exceptions=True)
         # the pump exits the moment _closed is set, so an abort queued
@@ -2472,6 +2514,11 @@ class JaxEngine:
     async def _pump(self) -> None:
         loop = asyncio.get_running_loop()
         while not self._closed:
+            if self._step_end_ns is not None:
+                # the executor hop back to this thread and the
+                # asyncio.sleep(0) in which the worker's other coroutines ran
+                self.events.record("loop_yield", t0_ns=self._step_end_ns)
+                self._step_end_ns = None
             # drain offload queue (device→host copies, KVBM)
             if self.tiered is not None and self.tiered.pending_offloads:
                 try:
@@ -2490,7 +2537,13 @@ class JaxEngine:
                 except Exception as e:  # noqa: BLE001
                     if not fut.done():
                         fut.set_exception(e)
+            t_plan = self.events.now()
+            admits = self.events.kind_totals.get("admit", 0)
             plan = self._plan_step()
+            self.events.record(
+                "plan", t0_ns=t_plan, waiting=len(self.scheduler.waiting),
+                running=len(self.scheduler.running),
+                admitted=self.events.kind_totals.get("admit", 0) - admits)
             for seq in self.scheduler.drain_errored():
                 self._deliver(seq, [], "error")
             for seq in self.scheduler.drain_shed():
@@ -2523,24 +2576,25 @@ class JaxEngine:
                     # shutdown
                     if self._closed:
                         break
+                    t_idle = self.events.now()
                     self._wake.clear()
                     await self._wake.wait()
+                    self.events.record("idle_wait", t0_ns=t_idle)
                 else:
                     await asyncio.sleep(0)
                 continue
             if not self._xprof_done:
                 # lint: allow(blocking-in-async): one-time profiler capture setup, not steady-state
                 self._xprof_start()
+            if plan.kind == "prefill":
+                step = ("prefill_chunk", self._run_prefill, plan.prefill)
+            elif plan.kind == "mixed":
+                step = ("mixed_step", self._run_mixed, plan)
+            else:
+                step = ("decode_block", self._run_decode, plan.decode)
             try:
-                if plan.kind == "prefill":
-                    await loop.run_in_executor(
-                        self._executor, self._run_prefill, plan.prefill)
-                elif plan.kind == "mixed":
-                    await loop.run_in_executor(
-                        self._executor, self._run_mixed, plan)
-                else:
-                    await loop.run_in_executor(
-                        self._executor, self._run_decode, plan.decode)
+                await loop.run_in_executor(
+                    self._executor, self._run_step, *step)
             except Exception:  # noqa: BLE001
                 logger.exception("engine step failed; resetting KV state")
                 self._recover_after_error()
@@ -2549,20 +2603,44 @@ class JaxEngine:
                 self._xprof_stop_if_due()
             await asyncio.sleep(0)
 
-    # -- xprof capture (DYN_TPU_XPROF_STEPS) --------------------------------- #
+    # -- xprof capture (DYN_TPU_XPROF_STEPS, POST /debug/xprof) --------------- #
+
+    def arm_xprof(self, steps: int, directory: str) -> bool:
+        """Arm a capture of the next `steps` engine steps into `directory`
+        while serving.  False when one is armed or running already (the
+        profiler is one per process)."""
+        if not self._xprof_done or steps <= 0:
+            return False
+        self._xprof_steps, self._xprof_dir = int(steps), directory
+        self._xprof_started_at = None
+        self._xprof_done = False
+        return True
 
     def _xprof_start(self) -> None:
         """First non-idle plan with capture armed: start the jax.profiler
         trace.  A failed start disables capture for the engine's lifetime
-        (profiling must never take down serving)."""
+        (profiling must never take down serving).
+
+        The options switch off JAX's Python tracer (it hooks every Python
+        call of every thread: hundreds of MB a run and a host several
+        times slower, so a traced run measured the tracer) and keep the
+        host tracer at 1: the device planes and `profile_start_time` are
+        written at 0 already, 1 adds the `TraceAnnotation` of each step."""
         if self._xprof_started_at is not None:
             return
         try:
             import os as _os
 
             _os.makedirs(self._xprof_dir, exist_ok=True)
-            jax.profiler.start_trace(self._xprof_dir)
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
+            options.host_tracer_level = 1
+            wall_ns, mono_ns = time.time_ns(), time.monotonic_ns()
+            jax.profiler.start_trace(self._xprof_dir,
+                                     profiler_options=options)
             self._xprof_started_at = self._step_count
+            self.events.record("xprof_start", wall_ns=wall_ns,
+                               mono_ns=mono_ns, steps=self._xprof_steps)
             logger.info("xprof: tracing %d engine step(s) into %s",
                         self._xprof_steps, self._xprof_dir)
         except Exception:  # noqa: BLE001
@@ -2570,15 +2648,22 @@ class JaxEngine:
             logger.exception("xprof start failed; capture disabled")
 
     def _xprof_stop_if_due(self) -> None:
-        if (self._xprof_started_at is None
-                or self._step_count - self._xprof_started_at
-                < self._xprof_steps):
-            return
+        if (self._xprof_started_at is not None
+                and self._step_count - self._xprof_started_at
+                >= self._xprof_steps):
+            self._xprof_stop()
+
+    def _xprof_stop(self) -> None:
         self._xprof_done = True
         try:
+            wall_ns, mono_ns = time.time_ns(), time.monotonic_ns()
             jax.profiler.stop_trace()
+            self.events.record(
+                "xprof_stop", wall_ns=wall_ns, mono_ns=mono_ns,
+                steps=self._step_count - self._xprof_started_at)
             logger.info("xprof: capture complete (%d steps) in %s",
-                        self._xprof_steps, self._xprof_dir)
+                        self._step_count - self._xprof_started_at,
+                        self._xprof_dir)
         except Exception:  # noqa: BLE001
             logger.exception("xprof stop failed")
 
@@ -2785,14 +2870,13 @@ class JaxEngine:
                        blocks: int = 1) -> None:
         """Account one device dispatch: rung histogram (decode-bearing
         kinds; a chained run counts once per block) + the optional
-        dispatch trace."""
+        dispatch trace.  The ring's record of the dispatch is the step
+        slice that follows."""
         if n_steps:
             self._rung_dispatches[n_steps] = (
                 self._rung_dispatches.get(n_steps, 0) + blocks
             )
             xla_ledger.note_decode_block(blocks)
-        self.events.record("dispatch", step=kind, n_steps=n_steps,
-                           blocks=blocks)
         if self.dispatch_trace is not None:
             self.dispatch_trace.append({
                 "kind": kind, "n_steps": n_steps, "blocks": blocks,
@@ -2800,9 +2884,62 @@ class JaxEngine:
                 "t": time.monotonic(),
             })
 
+    def _run_step(self, kind: str, run, arg) -> None:
+        """One engine step on the step thread, under a profiler annotation
+        named like its ring slice: the host line of a capture then shows
+        the step that the device line's program belongs to."""
+        try:
+            with jax.profiler.TraceAnnotation(kind):
+                run(arg)
+        finally:
+            self._step_t0_ns = None
+            self._step_end_ns = time.monotonic_ns()
+
+    def _attn_of(self, site: str, batch: int, chunk: int,
+                 width: int) -> str:
+        """The attention program ("pallas" | "xla") the step of this shape
+        was traced into (`ops.paged_attention._adapt` notes it per shape);
+        the configured implementation where no choice was noted."""
+        return xla_ledger.path_choice(
+            site, batch=batch, chunk=chunk,
+            table_tokens=width * self.cfg.page_size) or self._attn_impl
+
+    @staticmethod
+    def _credit_own(seqs, ns: int) -> None:
+        """A step's slice goes to the working time of each of its sequences
+        that still waits for its first token (`first_token.own_us`)."""
+        for s in seqs:
+            if s.t_first_token is None:
+                s.own_ns += ns
+                s.own_steps += 1
+
+    def _step_phases(self, seqs, t0: int, t_call: Optional[int],
+                     t_fetch: Optional[int], t_got: Optional[int]) -> dict:
+        """What the host did inside the step slice [t0, now], for the ONE
+        ring record of the step: integer microseconds on the ring's clock —
+        `build_us` (t0 to the jitted call), `dispatch_us` (the call, its
+        input transfers and what was dispatched behind it: a cache miss or
+        a blocked transfer shows here), `fetch_us` (the device_get: device
+        wait plus copy), `deliver_us` (unpack, token accounting, queues).
+        Phases are attributes and not nested slices: a reader that labels
+        a device gap by the slice spanning it would lose a nested one
+        behind its parent.  A phase the step never reached reads 0.
+
+        Also credits the slice to its sequences (`_credit_own`)."""
+        t_end = time.monotonic_ns()
+        t_got = t_end if t_got is None else t_got
+        t_fetch = t_got if t_fetch is None else t_fetch
+        t_call = t_fetch if t_call is None else t_call
+        self._credit_own(seqs, t_end - t0)
+        return {"build_us": (t_call - t0) // 1000,
+                "dispatch_us": (t_fetch - t_call) // 1000,
+                "fetch_us": (t_got - t_fetch) // 1000,
+                "deliver_us": (t_end - t_got) // 1000}
+
     @affine("step")
     def _run_prefill(self, items: List[PrefillItem]) -> None:
-        t0_ev = self.events.now()
+        t0_ev = self._step_t0_ns = self.events.now()
+        t_call = t_fetch = t_got = None
         self._note_dispatch("prefill")
         item_rows = self._prefill_rows(items)
         B = len(item_rows)
@@ -2842,6 +2979,7 @@ class JaxEngine:
                 "mm": [np.asarray(m) for m in mm] if mm else None,
                 "greedy": greedy,
             })
+        t_call = self.events.now()
         packed_d, tok_d = self._dispatch_prefill(
             tokens, table, prefix, chunk, samp, seeds, counters, with_top,
             mm=mm, owner=owner, greedy=greedy,
@@ -2871,10 +3009,12 @@ class JaxEngine:
         deferred = [] if fused else None
         self.scheduler.deferred_free = deferred
         try:
+            t_fetch = self.events.now()
+            # lint: allow(device-get): prefill results are consumed on-step by design — decode, not prefill, is the latency path
+            packed = np.asarray(jax.device_get(packed_d))
+            t_got = self.events.now()
             out, logp, tids, tlps = self._unpack_rows(
-                # lint: allow(device-get): prefill results are consumed on-step by design — decode, not prefill, is the latency path
-                np.asarray(jax.device_get(packed_d)), B, with_top,
-                blocks=self._prefill_blocks,
+                packed, B, with_top, blocks=self._prefill_blocks,
             )
             for i, it in enumerate(item_rows):
                 if it is None:
@@ -2894,10 +3034,21 @@ class JaxEngine:
             self.scheduler.deferred_free = None
             if deferred:
                 self.pool.free(deferred)
+            attrs = {}
+            if len(items) == 1:
+                attrs["rid"] = items[0].seq.request_id
+            if fused:
+                attrs["n_steps"] = int(fused[0].shape[0])
             self.events.record(
                 "prefill_chunk", t0_ns=t0_ev, batch=len(items),
                 tokens=int(sum(it.chunk_len for it in items)),
                 fused_blocks=len(fused) if fused else 0,
+                ctx=int((prefix + chunk).max()), pages=table.shape[1],
+                bucket=chunk_bucket,
+                attn=self._attn_of("prefill_attention", B, chunk_bucket,
+                                   table.shape[1]),
+                **attrs,
+                **self._step_phases(seqs, t0_ev, t_call, t_fetch, t_got),
             )
 
     def _maybe_fuse_decode(self, items, B, tok_d, samp, seeds, counters,
@@ -2975,9 +3126,13 @@ class JaxEngine:
             greedy=self._is_greedy(samp), n_steps=T,
         )
 
-    def _consume_decode(self, dispatches, rows, Bb, with_top) -> None:
+    def _consume_decode(self, dispatches, rows, Bb, with_top,
+                        clock=None) -> None:
         """Fetch + account a decode chain's outputs over a row layout
         (callers manage deferred frees around in-flight dispatches).
+        `clock` (a list) receives the first block's fetch start and end on
+        the ring's clock, even if accounting raises later; the further
+        blocks of a chain count as delivery.
 
         Rows that provably cannot stop inside the block take a BATCH
         path: one extend + one page commit + one delivery for the whole
@@ -2986,10 +3141,13 @@ class JaxEngine:
         the per-token loop (check_stop + queue item each) was a
         measurable share of serving throughput on real chips."""
         for packed_d in dispatches:
+            t_fetch = time.monotonic_ns()
+            # lint: allow(device-get): per-block fetch overlaps host consume with the next in-flight block; the cc path drains async
+            packed = np.asarray(jax.device_get(packed_d))
+            if clock is not None and not clock:
+                clock[:] = t_fetch, time.monotonic_ns()
             out, logp, tids, tlps = self._unpack_rows(
-                # lint: allow(device-get): per-block fetch overlaps host consume with the next in-flight block; the cc path drains async
-                np.asarray(jax.device_get(packed_d)), Bb, with_top,
-                blocks=self._decode_blocks,
+                packed, Bb, with_top, blocks=self._decode_blocks,
             )  # [T, B] each
             T = out.shape[0]
             for i, s in enumerate(rows):
@@ -3076,13 +3234,14 @@ class JaxEngine:
         plan).  Decode rows' pages were reserved preemptively at planning;
         prefill rows extended non-preemptively, so the two sides cannot
         invalidate each other."""
-        t0_ev = self.events.now()
+        t0_ev = self._step_t0_ns = self.events.now()
         items, dseqs = plan.prefill, plan.decode
         # prefill side (same array construction as _run_prefill)
         item_rows = self._prefill_rows(items)
         Bp = len(item_rows)
         pseq_rows = [it.seq if it else None for it in item_rows]
-        p_tokens, p_prefix, p_chunk, _ = self._prefill_arrays(item_rows)
+        p_tokens, p_prefix, p_chunk, chunk_bucket = self._prefill_arrays(
+            item_rows)
         pseqs = [it.seq for it in items]
         p_table = self._table_array(pseq_rows)
         p_seeds, p_ctr = self._seed_arrays(pseq_rows)
@@ -3122,6 +3281,7 @@ class JaxEngine:
                 "greedy": greedy_m,
                 "n_steps": T,
             })
+        t_call = self.events.now()
         p_packed_d, d_packed_d = self._dispatch_mixed(
             p_tokens, p_table, p_prefix, p_chunk, p_samp, p_seeds, p_ctr,
             d_tokens, d_pos, d_ctr, counts, d_table, d_samp, d_seeds,
@@ -3133,10 +3293,12 @@ class JaxEngine:
         for it in items:
             if it.seq.status == "running":
                 it.seq.num_computed += it.chunk_len
+        t_fetch = self.events.now()
+        # lint: allow(device-get): mixed-step prefill half, consumed on-step like _run_prefill
+        p_packed = np.asarray(jax.device_get(p_packed_d))
+        t_got = self.events.now()
         p_out, p_logp, p_tids, p_tlps = self._unpack_rows(
-            # lint: allow(device-get): mixed-step prefill half, consumed on-step like _run_prefill
-            np.asarray(jax.device_get(p_packed_d)), Bp, with_top,
-            blocks=self._prefill_blocks,
+            p_packed, Bp, with_top, blocks=self._prefill_blocks,
         )
         for i, it in enumerate(item_rows):
             if it is None:
@@ -3151,9 +3313,19 @@ class JaxEngine:
                     _tops_for(s, p_tids, p_tlps, i),
                 )
         self._consume_decode([d_packed_d], d_rows, Bd, with_top)
-        self.events.record("mixed_step", t0_ns=t0_ev, rung=T,
-                           prefill_batch=len(items),
-                           decode_batch=len(dseqs))
+        self.events.record(
+            "mixed_step", t0_ns=t0_ev, rung=T, n_steps=T, blocks=1,
+            prefill_batch=len(items), decode_batch=len(dseqs),
+            prefill_tokens=int(sum(it.chunk_len for it in items)),
+            decode_rows=len(dseqs),
+            ctx=int(max((p_prefix + p_chunk).max(), d_pos.max() + T)),
+            pages=max(p_table.shape[1], d_table.shape[1]),
+            bucket=chunk_bucket,
+            attn=self._attn_of("prefill_attention", Bp, chunk_bucket,
+                               p_table.shape[1]),
+            **self._step_phases(pseqs + dseqs, t0_ev, t_call, t_fetch,
+                                t_got),
+        )
 
     def _dispatch_mixed(self, p_tokens, p_table, p_prefix, p_chunk, p_samp,
                         p_seeds, p_ctr, d_tokens, d_pos, d_ctr, d_counts,
@@ -3537,7 +3709,7 @@ class JaxEngine:
         fetch and are consumed through the ordinary per-token stop
         path (variable acceptance == variable tokens per dispatch)."""
         k = self.cfg.speculative_ngram_k
-        t0_ev = self.events.now()
+        t0_ev = self._step_t0_ns = self.events.now()
         self._note_dispatch("spec")
         rows = self._decode_rows(seqs)
         B = len(rows)
@@ -3566,14 +3738,16 @@ class JaxEngine:
                            *[np.asarray(a) for a in samp], seeds],
                 "rope_off": rope_off,
             })
+        t_call = self.events.now()
         packed_d = self._dispatch_spec(
             tokens, positions, counters, table, samp, seeds, greedy,
             rope_off=rope_off,
         )
-        out, logp, n_acc = _unpack_spec(
-            # lint: allow(device-get): spec verify needs accept counts on host to commit tokens; one packed fetch per dispatch
-            np.asarray(jax.device_get(packed_d)), B, k + 1
-        )
+        t_fetch = self.events.now()
+        # lint: allow(device-get): spec verify needs accept counts on host to commit tokens; one packed fetch per dispatch
+        packed = np.asarray(jax.device_get(packed_d))
+        t_got = self.events.now()
+        out, logp, n_acc = _unpack_spec(packed, B, k + 1)
         self._spec_dispatch_total += 1
         drafted = accepted = 0
         live: List[tuple] = []
@@ -3600,9 +3774,12 @@ class JaxEngine:
                 self._append_token(s, int(out[i, t]), float(logp[i, t]))
                 if s.status != "running":
                     break  # stop hit inside the accepted run; rest discarded
-        self.events.record("spec_round", t0_ns=t0_ev, k=k,
-                           batch=len(seqs), drafted=drafted,
-                           accepted=accepted)
+        self.events.record(
+            "spec_round", t0_ns=t0_ev, k=k,
+            batch=len(seqs), drafted=drafted, accepted=accepted,
+            ctx=int(positions.max()) + k + 1, pages=table.shape[1],
+            bucket=B,
+            **self._step_phases(seqs, t0_ev, t_call, t_fetch, t_got))
 
     def _dispatch_spec(self, tokens, positions, counters, table, samp,
                        seeds, greedy, rope_off=None):
@@ -3645,7 +3822,7 @@ class JaxEngine:
         # full blocks while the prompt queue is empty, the shortest rung
         # (chaining suppressed) while prompts are pending, so a waiting
         # prompt rides the next mixed dispatch within one short block
-        t0_ev = self.events.now()
+        t0_ev = self._step_t0_ns = self.events.now()
         T, allow_chain = self.scheduler.select_decode_rung()
         if allow_chain and self._cc_ok():
             # device-resident loop: rungs stay the scan lengths — the
@@ -3691,6 +3868,7 @@ class JaxEngine:
                 "greedy": self._is_greedy(samp),
                 "n_steps": T,
             })
+        t_call = self.events.now()
         dispatches = self._dispatch_decode(
             tokens, positions, counters, counts, table, samp, seeds,
             penalized, with_top, chain_len, rope_off=rope_off,
@@ -3702,14 +3880,24 @@ class JaxEngine:
         # state right after their finish_reason arrives)
         deferred = [] if len(dispatches) > 1 else None
         self.scheduler.deferred_free = deferred
+        clock = []  # the first block's fetch: [start, end]
         try:
-            self._consume_decode(dispatches, rows, Bb, with_top)
+            self._consume_decode(dispatches, rows, Bb, with_top, clock)
         finally:
             self.scheduler.deferred_free = None
             if deferred:
                 self.pool.free(deferred)
-            self.events.record("decode_block", t0_ns=t0_ev, rung=T,
-                               batch=len(seqs), chain=chain_len)
+            self.events.record(
+                "decode_block", t0_ns=t0_ev, rung=T, n_steps=T,
+                blocks=chain_len, batch=len(seqs), chain=chain_len,
+                ctx=int(positions.max()) + T * chain_len,
+                pages=table.shape[1], bucket=Bb,
+                attn=self._attn_of("decode_attention", Bb, 1,
+                                   table.shape[1]),
+                **({"rid": seqs[0].request_id} if len(seqs) == 1 else {}),
+                **self._step_phases(seqs, t0_ev, t_call,
+                                    *(clock or (None, None))),
+            )
 
     def _dispatch_decode(self, tokens, positions, counters, counts, table,
                          samp, seeds, penalized, with_top, chain_len,
@@ -4132,7 +4320,7 @@ class JaxEngine:
                     # splices/feeds may have grown page lists
                     table_d = self._put(self._table_array(rows),
                                         self._bax, None)
-                t_iter = self.events.now()
+                t_iter = self._step_t0_ns = self.events.now()
                 if penalized:
                     (packed_d, tok_d, pos_d, ctr_d, act_d, budget_d,
                      cts_d, self.kv) = step(
@@ -4151,6 +4339,7 @@ class JaxEngine:
                     packed_d.copy_to_host_async()
                 except Exception:  # lint: allow(swallowed-exception): copy_to_host_async optional; fetch path device_gets anyway
                     pass
+                t_sent = self.events.now()
                 blocks += 1
                 allowance -= 1
                 # live per-dispatch count: a reader polling metrics()
@@ -4168,10 +4357,13 @@ class JaxEngine:
                                   with_top)))
                 # double buffer: with two blocks undrained, consume the
                 # older one (its device_get overlapped this dispatch)
+                wait_ns = 0
                 while len(inflight) >= 2:
                     rows_snap, fut = inflight.popleft()
-                    self._consume_cc_block(fut.result(), rows_snap,
-                                           with_top)
+                    t_wait = self.events.now()
+                    fetched = fut.result()
+                    wait_ns += self.events.now() - t_wait
+                    self._consume_cc_block(fetched, rows_snap, with_top)
                 fallout = splice_fall or self._cc_fall_out(
                     seqs, splice=splice_on)
                 # one decode_block slice per ITERATION (dispatch + drain
@@ -4185,9 +4377,19 @@ class JaxEngine:
                     attrs["splice"] = True
                 if chunk_rows:
                     attrs["chunk_rows"] = chunk_rows
-                self.events.record("decode_block", t0_ns=t_iter, rung=T,
-                                   batch=len(seqs), chain=blocks,
-                                   continuous=True, **attrs)
+                # phases as on every step slice; the splice and feed work
+                # above t_iter is outside the slice, so build_us is 0, and
+                # fetch_us is the wait for the drain thread's device_get
+                t_end = self.events.now()
+                self._credit_own(seqs, t_end - t_iter)
+                self.events.record(
+                    "decode_block", t0_ns=t_iter, rung=T, n_steps=T,
+                    blocks=1, batch=len(seqs), chain=blocks,
+                    continuous=True, build_us=0,
+                    dispatch_us=(t_sent - t_iter) // 1000,
+                    fetch_us=wait_ns // 1000,
+                    deliver_us=(t_end - t_sent - wait_ns) // 1000,
+                    pages=table_d.shape[1], bucket=Bb, **attrs)
                 if fallout is not None:
                     break
                 if allowance < 1:
@@ -4974,6 +5176,7 @@ class JaxEngine:
             # keep followers lockstep: they rebuild their KV shards too
             self._lockstep_send({"kind": "recover"})
         self.kv = self._make_kv()
+        self._evictions_before_reset += self.pool.evictions_total
         self.pool = self._make_pool()
         for p in getattr(self.pool, "pools", [self.pool]):
             p.events = self.events
@@ -5004,10 +5207,26 @@ class JaxEngine:
         delta of a stream is always consumed)."""
         if seq.t_first_token is not None or seq.t_arrival is None:
             return
-        now = time.monotonic()
+        now_ns = time.monotonic_ns()
+        now = now_ns / 1e9
         seq.t_first_token = now
         seen = seq.t_seen if seq.t_seen is not None else seq.t_arrival
         admitted = seq.t_admitted if seq.t_admitted is not None else seen
+        # waiting apart from working: the request's own steps (the ones
+        # that computed its tokens, the running one up to now) against
+        # the rest of admission-to-first-token, in which it was admitted
+        # and waited for its turn or between its own chunks
+        total_us = max(0, int((now - seq.t_arrival) * 1e6))
+        queue_us = min(total_us, max(0, int((admitted - seq.t_arrival) * 1e6)))
+        own_ns, steps = seq.own_ns, seq.own_steps
+        if self._step_t0_ns is not None:
+            own_ns, steps = own_ns + now_ns - self._step_t0_ns, steps + 1
+        own_us = min(own_ns // 1000, total_us - queue_us)
+        wait_us = total_us - queue_us - own_us
+        self.events.record(
+            "first_token", rid=seq.request_id, prompt_len=seq.prompt_len,
+            cached=seq.num_cached, total_us=total_us, queue_us=queue_us,
+            own_us=own_us, steps=steps, wait_us=wait_us)
         attr = {
             "block_wait_ms": max(0.0, (seen - seq.t_arrival) * 1e3),
             "queue_wait_ms": max(0.0, (admitted - seen) * 1e3),
@@ -5017,6 +5236,7 @@ class JaxEngine:
         self._ttft_block_wait_ms_total += attr["block_wait_ms"]
         self._ttft_queue_wait_ms_total += attr["queue_wait_ms"]
         self._ttft_prefill_ms_total += attr["prefill_ms"]
+        self._ttft_turn_wait_ms_total += wait_us / 1e3
         self._ttft_attributed_total += 1
         # milestone spans reconstructed from the attribution timestamps,
         # exported under the request's adopted trace so the engine's TTFT
@@ -5031,6 +5251,12 @@ class JaxEngine:
             export_span("engine.queue_wait", seq.trace,
                         wall(seen), wall(admitted),
                         queue_wait_ms=round(attr["queue_wait_ms"], 3))
+            # placed at admission; its length is the SUM of the waits (for
+            # the first turn and between the request's own chunks)
+            export_span("engine.turn_wait", seq.trace, wall(admitted),
+                        wall(admitted + wait_us / 1e6),
+                        turn_wait_ms=wait_us / 1e3,
+                        own_ms=own_us / 1e3, steps=steps)
             export_span("engine.prefill", seq.trace,
                         wall(admitted), wall(now),
                         prefill_ms=round(attr["prefill_ms"], 3),

@@ -55,6 +55,7 @@ class PagePool:
         # unreferenced cached pages in LRU order (evictable)
         self._lru: "OrderedDict[int, None]" = OrderedDict()
         self._event_sink = event_sink
+        self.evictions_total = 0  # cached pages evicted to make room
         # optional StepEventRecorder (runtime.events): alloc/free land on
         # the engine step timeline; None-checked so the hot path stays a
         # single attribute load when unwired
@@ -110,6 +111,7 @@ class PagePool:
 
     def _evict_one(self) -> int:
         page, _ = self._lru.popitem(last=False)
+        self.evictions_total += 1
         h = self._page_hash.pop(page)
         del self._cached[h]
         self._emit(KvEvent("removed", [h]))
@@ -303,6 +305,10 @@ class ShardedPagePool:
     @property
     def available_pages(self) -> int:
         return sum(p.available_pages for p in self.pools)
+
+    @property
+    def evictions_total(self) -> int:
+        return sum(p.evictions_total for p in self.pools)
 
     def usage(self) -> float:
         usable = self.ranks * (self.num_pages - 1)

@@ -115,6 +115,10 @@ class Sequence:
         self.t_admitted: Optional[float] = None
         self.t_first_token: Optional[float] = None
         self.ttft_attr: Optional[dict] = None
+        # until the first token: the summed slices (ns) and the count of
+        # the engine steps that computed this request's tokens
+        self.own_ns = 0
+        self.own_steps = 0
         # forensics: mid-stream incidents (preemption park/resume, prefix
         # onboard) accumulated here and attached to the next delivered
         # delta, so the frontend's per-request waterfall sees stalls that

@@ -489,20 +489,21 @@ def _layer_prefill(
     nh, nkv, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim_
     k_pages, v_pages = kv_layer
 
-    attn_in = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
     dt = x.dtype
-    q, k, v = _qkv_proj(attn_in, lp, cfg, "bsh,hd->bsd")
-    q = q.astype(dt).reshape(B, S, nh, hd)
-    k = k.astype(dt).reshape(B, S, nkv, hd)
-    v = v.astype(dt).reshape(B, S, nkv, hd)
-    if rope_pos is not None:
-        from ..ops import apply_mrope
+    with jax.named_scope("attn.qkv"):
+        attn_in = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
+        q, k, v = _qkv_proj(attn_in, lp, cfg, "bsh,hd->bsd")
+        q = q.astype(dt).reshape(B, S, nh, hd)
+        k = k.astype(dt).reshape(B, S, nkv, hd)
+        v = v.astype(dt).reshape(B, S, nkv, hd)
+        if rope_pos is not None:
+            from ..ops import apply_mrope
 
-        q = apply_mrope(q, rope_pos, inv_freq, cfg.mrope_section)
-        k = apply_mrope(k, rope_pos, inv_freq, cfg.mrope_section)
-    else:
-        q = apply_rope(q, positions, inv_freq, scale=rope_scale)
-        k = apply_rope(k, positions, inv_freq, scale=rope_scale)
+            q = apply_mrope(q, rope_pos, inv_freq, cfg.mrope_section)
+            k = apply_mrope(k, rope_pos, inv_freq, cfg.mrope_section)
+        else:
+            q = apply_rope(q, positions, inv_freq, scale=rope_scale)
+            k = apply_rope(k, positions, inv_freq, scale=rope_scale)
 
     attn = prefill_attention(
         q, k, v, k_pages, v_pages, page_table, prefix_lens, chunk_lens,
@@ -511,16 +512,18 @@ def _layer_prefill(
     k_pages, v_pages = write_kv_pages(
         k_pages, v_pages, k, v, page_table, prefix_lens, chunk_lens
     )
-    attn_out = matmul_any(
-        attn.reshape(B, S, nh * hd), lp["wo"], "bsd,dh->bsh"
-    ).astype(x.dtype)
-    if "bo" in lp:  # gpt-oss carries an o_proj bias
-        attn_out = attn_out + lp["bo"].astype(x.dtype)
-    x = x + attn_out
+    with jax.named_scope("attn.out"):
+        attn_out = matmul_any(
+            attn.reshape(B, S, nh * hd), lp["wo"], "bsd,dh->bsh"
+        ).astype(x.dtype)
+        if "bo" in lp:  # gpt-oss carries an o_proj bias
+            attn_out = attn_out + lp["bo"].astype(x.dtype)
+        x = x + attn_out
 
-    mlp_in = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
-    mlp_out = _moe(lp, mlp_in, cfg) if cfg.is_moe else _mlp(lp, mlp_in)
-    return x + mlp_out, (k_pages, v_pages)
+    with jax.named_scope("mlp"):
+        mlp_in = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
+        mlp_out = _moe(lp, mlp_in, cfg) if cfg.is_moe else _mlp(lp, mlp_in)
+        return x + mlp_out, (k_pages, v_pages)
 
 
 def _layer_decode(
@@ -544,15 +547,16 @@ def _layer_decode(
     nh, nkv, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim_
     k_pages, v_pages = kv_layer
 
-    attn_in = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
     dt = x.dtype
-    q, k, v = _qkv_proj(attn_in, lp, cfg, "bh,hd->bd")
-    q = q.astype(dt).reshape(B, 1, nh, hd)
-    k = k.astype(dt).reshape(B, 1, nkv, hd)
-    v = v.astype(dt).reshape(B, 1, nkv, hd)
-    rp = positions if rope_pos is None else rope_pos
-    q = apply_rope(q, rp[:, None], inv_freq, scale=rope_scale)[:, 0]
-    k = apply_rope(k, rp[:, None], inv_freq, scale=rope_scale)
+    with jax.named_scope("attn.qkv"):
+        attn_in = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
+        q, k, v = _qkv_proj(attn_in, lp, cfg, "bh,hd->bd")
+        q = q.astype(dt).reshape(B, 1, nh, hd)
+        k = k.astype(dt).reshape(B, 1, nkv, hd)
+        v = v.astype(dt).reshape(B, 1, nkv, hd)
+        rp = positions if rope_pos is None else rope_pos
+        q = apply_rope(q, rp[:, None], inv_freq, scale=rope_scale)[:, 0]
+        k = apply_rope(k, rp[:, None], inv_freq, scale=rope_scale)
 
     if defer_write:
         # deferred-write path: attend to the OLD pool + an explicit self
@@ -579,19 +583,21 @@ def _layer_decode(
             window=window, sink=lp.get("sinks"),
         )
         kv_out = (k_pages, v_pages)
-    attn_out = matmul_any(
-        attn.reshape(B, nh * hd), lp["wo"], "bd,dh->bh"
-    ).astype(x.dtype)
-    if "bo" in lp:  # gpt-oss carries an o_proj bias
-        attn_out = attn_out + lp["bo"].astype(x.dtype)
-    x = x + attn_out
+    with jax.named_scope("attn.out"):
+        attn_out = matmul_any(
+            attn.reshape(B, nh * hd), lp["wo"], "bd,dh->bh"
+        ).astype(x.dtype)
+        if "bo" in lp:  # gpt-oss carries an o_proj bias
+            attn_out = attn_out + lp["bo"].astype(x.dtype)
+        x = x + attn_out
 
-    mlp_in = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
-    if cfg.is_moe:
-        mlp_out = _moe(lp, mlp_in[:, None], cfg)[:, 0]
-    else:
-        mlp_out = _mlp(lp, mlp_in[:, None])[:, 0]
-    return x + mlp_out, kv_out
+    with jax.named_scope("mlp"):
+        mlp_in = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
+        if cfg.is_moe:
+            mlp_out = _moe(lp, mlp_in[:, None], cfg)[:, 0]
+        else:
+            mlp_out = _mlp(lp, mlp_in[:, None])[:, 0]
+        return x + mlp_out, kv_out
 
 
 def _window_xs(cfg: ModelConfig):
@@ -604,6 +610,7 @@ def _window_xs(cfg: ModelConfig):
     return (jnp.asarray(cfg.layer_windows(), jnp.int32),)
 
 
+@jax.named_scope("head")
 def _lm_logits(params: Params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     head = params.get("lm_head")  # quantization adds one even when tied
@@ -734,9 +741,11 @@ def forward_prefill(
     which is exact for text-only prompts."""
     B, S = tokens.shape
     positions = prefix_lens[:, None] + jnp.arange(S)[None, :]
-    x = params["embed"][tokens]  # [B, S, h]
-    if extra_embeds is not None:
-        x = jnp.where(extra_mask[..., None], extra_embeds.astype(x.dtype), x)
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens]  # [B, S, h]
+        if extra_embeds is not None:
+            x = jnp.where(extra_mask[..., None],
+                          extra_embeds.astype(x.dtype), x)
     x, kv = prefill_layers(
         params["layers"], cfg, kv, x, positions, page_table, prefix_lens,
         chunk_lens, attn_impl,
@@ -802,7 +811,8 @@ def forward_decode(
     # position = slot + delta; KV slots stay raw token indices)
 ) -> Tuple[jax.Array, KVCache]:
     """One decode step for the whole batch; returns logits [B, V]."""
-    x = params["embed"][tokens]  # [B, h]
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens]  # [B, h]
     x, kv = decode_layers(
         params["layers"], cfg, kv, x, positions, page_table, attn_impl,
         rope_offset=rope_offset,
@@ -906,8 +916,9 @@ def decode_block_scan(
     scale = 1.0 / jnp.sqrt(hd).astype(jnp.float32)
 
     # 1. one gather of the block's cached context (loop-invariant)
-    kg = kv.k[:, page_table].reshape(L, B, W * page, nkv, hd)
-    vg = kv.v[:, page_table].reshape(L, B, W * page, nkv, hd)
+    with jax.named_scope("kv.gather"):
+        kg = kv.k[:, page_table].reshape(L, B, W * page, nkv, hd)
+        vg = kv.v[:, page_table].reshape(L, B, W * page, nkv, hd)
     S = W * page
     spos = jnp.arange(S)[None, :]  # cached slot positions
     len0 = positions  # [B] cached tokens at block start
@@ -966,33 +977,38 @@ def decode_block_scan(
         ok = pos < max_valid_pos
         safe_pos = jnp.where(ok, pos, 0)
         rp = safe_pos if rope_offset is None else safe_pos + rope_offset
-        x = params["embed"][tok].astype(dt)
+        with jax.named_scope("embed"):
+            x = params["embed"][tok].astype(dt)
 
         def layer(h, xs):
             lp, kg_l, vg_l, rk_l, rv_l = xs[:5]
             window = xs[5] if wins else None
-            attn_in = rms_norm(h, lp["attn_norm"], cfg.rms_norm_eps)
-            q, k, v = _qkv_proj(attn_in, lp, cfg, "bh,hd->bd")
-            q = q.astype(dt).reshape(B, 1, nh, hd)
-            k = k.astype(dt).reshape(B, 1, nkv, hd)
-            v = v.astype(dt).reshape(B, 1, nkv, hd)
-            q = apply_rope(q, rp[:, None], inv_freq, scale=rs)[:, 0]
-            k = apply_rope(k, rp[:, None], inv_freq, scale=rs)[:, 0]
-            v = v[:, 0]
-            attn = attn_one(lp, kg_l, vg_l, rk_l, rv_l, q, k, v,
-                            safe_pos, t, window)
-            attn_out = matmul_any(
-                attn.reshape(B, nh * hd), lp["wo"], "bd,dh->bh"
-            ).astype(h.dtype)
-            if "bo" in lp:
-                attn_out = attn_out + lp["bo"].astype(h.dtype)
-            h = h + attn_out
-            mlp_in = rms_norm(h, lp["mlp_norm"], cfg.rms_norm_eps)
-            if cfg.is_moe:
-                mlp_out = _moe(lp, mlp_in[:, None], cfg)[:, 0]
-            else:
-                mlp_out = _mlp(lp, mlp_in[:, None])[:, 0]
-            return h + mlp_out, (k, v)
+            with jax.named_scope("attn.qkv"):
+                attn_in = rms_norm(h, lp["attn_norm"], cfg.rms_norm_eps)
+                q, k, v = _qkv_proj(attn_in, lp, cfg, "bh,hd->bd")
+                q = q.astype(dt).reshape(B, 1, nh, hd)
+                k = k.astype(dt).reshape(B, 1, nkv, hd)
+                v = v.astype(dt).reshape(B, 1, nkv, hd)
+                q = apply_rope(q, rp[:, None], inv_freq, scale=rs)[:, 0]
+                k = apply_rope(k, rp[:, None], inv_freq, scale=rs)[:, 0]
+                v = v[:, 0]
+            with jax.named_scope("attn.core"):
+                attn = attn_one(lp, kg_l, vg_l, rk_l, rv_l, q, k, v,
+                                safe_pos, t, window)
+            with jax.named_scope("attn.out"):
+                attn_out = matmul_any(
+                    attn.reshape(B, nh * hd), lp["wo"], "bd,dh->bh"
+                ).astype(h.dtype)
+                if "bo" in lp:
+                    attn_out = attn_out + lp["bo"].astype(h.dtype)
+                h = h + attn_out
+            with jax.named_scope("mlp"):
+                mlp_in = rms_norm(h, lp["mlp_norm"], cfg.rms_norm_eps)
+                if cfg.is_moe:
+                    mlp_out = _moe(lp, mlp_in[:, None], cfg)[:, 0]
+                else:
+                    mlp_out = _mlp(lp, mlp_in[:, None])[:, 0]
+                return h + mlp_out, (k, v)
 
         x, (ks, vs) = jax.lax.scan(layer, x, (layers, kg, vg, rk, rv,
                                               *wins))
@@ -1026,13 +1042,27 @@ def decode_block_scan(
             None, length=T)
 
     # 3. one batched scatter of the whole block's KV into the pool
+    return eng, ys, tok, pos, _scatter_block(
+        kv, rk, rv, positions, page_table, max_valid_pos,
+        jnp.swapaxes(acts, 0, 1) if masked else None)
+
+
+@jax.named_scope("kv.write")
+def _scatter_block(kv: KVCache, rk, rv, positions, page_table,
+                   max_valid_pos: int, acts) -> KVCache:
+    """Land a decode block's ring buffers ([L, B, T, nkv, hd]) in the pool
+    in one batched scatter; `acts` ([B, T] bool, or None) masks the steps a
+    row was frozen in."""
+    L, P, page, nkv, hd = kv.k.shape
+    B, W = page_table.shape
+    T = rk.shape[2]
     tpos = positions[:, None] + jnp.arange(T)[None, :]  # [B, T]
     ok = tpos < max_valid_pos
-    if masked:
+    if acts is not None:
         # a frozen row's emitted prefix is contiguous from its initial
         # position, so the uniform tpos formula holds exactly where the
         # per-step mask is true; everything after the stop lands in trash
-        ok &= jnp.swapaxes(acts, 0, 1)
+        ok &= acts
     page_idx = jnp.clip(tpos // page, 0, W - 1)
     page_ids = jnp.take_along_axis(page_table, page_idx, axis=1)
     slot = jnp.where(ok, page_ids * page + tpos % page, 0).reshape(-1)
@@ -1043,5 +1073,4 @@ def decode_block_scan(
         rk.reshape(L, B * T, nkv, hd).astype(kf.dtype), mode="drop")
     vf = vf.at[:, slot].set(
         rv.reshape(L, B * T, nkv, hd).astype(vf.dtype), mode="drop")
-    kv = KVCache(kf.reshape(kv.k.shape), vf.reshape(kv.v.shape))
-    return eng, ys, tok, pos, kv
+    return KVCache(kf.reshape(kv.k.shape), vf.reshape(kv.v.shape))

@@ -113,6 +113,9 @@ def _adapt(impl: str, page_table: jax.Array, page_size: int,
     return choice
 
 
+# Scope names (jax.named_scope) are what a profiler trace's device ops are
+# found by: docs/observability.md lists them.
+@jax.named_scope("kv.write")
 def write_kv_pages(
     k_pages: jax.Array,  # [P, page, n_kv, hd]
     v_pages: jax.Array,
@@ -148,6 +151,7 @@ def write_kv_pages(
     )
 
 
+@jax.named_scope("kv.gather")
 def gather_kv(
     k_pages: jax.Array,  # [P, page, n_kv, hd]
     v_pages: jax.Array,
@@ -201,6 +205,7 @@ def _sink_softmax(scores: jax.Array, sink) -> jax.Array:
     )[..., :-1]
 
 
+@jax.named_scope("attn.core")
 def prefill_attention(
     q: jax.Array,  # [B, S, n_heads, hd] — the new chunk
     k_new: jax.Array,  # [B, S, n_kv, hd]
@@ -267,6 +272,7 @@ def prefill_attention(
     return out
 
 
+@jax.named_scope("attn.core")
 def decode_attention(
     q: jax.Array,  # [B, n_heads, hd] — one new token per sequence
     k_pages: jax.Array,  # [P, page, n_kv, hd] (new token already written,

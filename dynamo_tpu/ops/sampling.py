@@ -56,6 +56,7 @@ class SamplingParams(NamedTuple):
         )
 
 
+@jax.named_scope("sample")
 def sample_tokens_maybe_greedy(logits, params, seeds, counters,
                                greedy: bool = False):
     """`sample_tokens`, or a STATICALLY greedy argmax when the caller
@@ -135,6 +136,7 @@ def _sample_nongreedy(logits, greedy, params, seeds, counters, K):
     return jnp.where(params.temperature <= 0.0, greedy, sampled)
 
 
+@jax.named_scope("sample")
 def sample_tokens_block(
     logits: jax.Array,  # [B, S, V] — one distribution per chunk position
     params: SamplingParams,  # [B] each
@@ -163,6 +165,7 @@ def sample_tokens_block(
     return out.reshape(B, S), logp.reshape(B, S)
 
 
+@jax.named_scope("sample")
 def speculative_accept(
     sampled: jax.Array,  # [B, S] — per-position verify samples
     fed: jax.Array,  # [B, S] — [last accepted token | S-1 draft tokens]
@@ -181,12 +184,14 @@ def speculative_accept(
     return jnp.cumprod(match, axis=1).sum(axis=1)
 
 
+@jax.named_scope("sample")
 def compute_logprobs(logits: jax.Array, tokens: jax.Array) -> jax.Array:
     """Log-probability of `tokens` [B] under `logits` [B, V]."""
     logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
     return jnp.take_along_axis(logp, tokens[:, None], axis=1)[:, 0]
 
 
+@jax.named_scope("sample")
 def apply_penalties(
     logits: jax.Array,  # [B, V]
     counts: jax.Array,  # [B, V] float — output-token occurrence counts
@@ -206,6 +211,7 @@ def apply_penalties(
     )
 
 
+@jax.named_scope("sample")
 def top_logprobs(logits: jax.Array, k: int):
     """Top-k (ids, logprobs) per row for OpenAI `top_logprobs` responses."""
     logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)

@@ -2,7 +2,7 @@
 
 The spans in `runtime.tracing` answer "where did THIS request's time go";
 this recorder answers "what was the ENGINE doing, step by step" — admit,
-dispatch, rung selection, spec accept, pool alloc/free, disagg handoff —
+plan, step, rung selection, spec accept, pool alloc/free, disagg handoff —
 at monotonic-ns resolution with near-zero overhead, so a TTFT outlier or
 a chaos-scenario failure can be replayed as a timeline instead of
 inferred from aggregate counters (reference analog: the KV-event
@@ -27,7 +27,7 @@ recorded event is also mirrored into fixed-size mmap-backed binary
 segments in that directory. The mmap pages are shared with the page
 cache, so a SIGKILL leaves whatever was already written readable — the
 black box that the in-memory ring (gone with the process) cannot
-provide. Each 128-byte record slot carries a trailing commit marker
+provide. Each 256-byte record slot carries a trailing commit marker
 written LAST, so a reader treats a torn final record as a clean prefix
 end, never as garbage (`load_flight_dir` / `scripts/postmortem.py`)."""
 
@@ -52,14 +52,14 @@ DEFAULT_CAPACITY = 4096
 FLIGHT_MAGIC = b"DYNFLTR1"
 FLIGHT_VERSION = 1
 FLIGHT_HEADER_SIZE = 4096
-FLIGHT_RECORD_SIZE = 128
+FLIGHT_RECORD_SIZE = 256  # a step slice with its phases and a request id fits
 _FLIGHT_COMMIT = 0xA5  # written to the slot's LAST byte after the payload
 _HDR = struct.Struct("<8sIIIIqqH")  # magic ver rec_size n_slots pid wall mono service_len
 _REC = struct.Struct("<qqHH")  # t_ns dur_ns kind_len attr_len
 _REC_PAYLOAD_MAX = FLIGHT_RECORD_SIZE - _REC.size - 1  # minus commit byte
 _SEG_RE = re.compile(r"^flight-(\d+)-(\d+)\.seg$")
 
-DEFAULT_FLIGHT_SLOTS = 4096  # ~512 KiB/segment
+DEFAULT_FLIGHT_SLOTS = 4096  # ~1 MiB/segment
 DEFAULT_FLIGHT_KEEP = 4
 
 # one shared encoder: json.dumps with non-default kwargs constructs a
@@ -68,16 +68,20 @@ _ATTR_ENCODE = json.JSONEncoder(separators=(",", ":"), default=str).encode
 
 
 def _encode_attrs(attrs: Dict[str, Any]) -> bytes:
-    """Compact-JSON attr bytes, with a manual fast path for the all-int
-    dicts the decode hot path records (rung/batch/chain) — ~0.8µs
+    """Compact-JSON attr bytes, with a manual fast path for the dicts the
+    step hot path records: integers (rung/batch/chain, the `*_us` phases)
+    and plain identifiers such as `attn` or a request id — ~0.8µs
     cheaper per event than even a cached JSONEncoder.  Keys come from
     `record(**attrs)` kwargs, so they are identifiers needing no
-    escaping; any non-int value falls back to the real encoder (which
+    escaping; any other value falls back to the real encoder (which
     `default=str`s anything unserializable)."""
     parts = []
     for k, v in attrs.items():
         if type(v) is int:  # exact: bool is a subclass, floats can be NaN
             parts.append('"%s":%d' % (k, v))
+        elif type(v) is str and v.isascii() \
+                and v.replace("-", "").replace("_", "").isalnum():
+            parts.append('"%s":"%s"' % (k, v))  # nothing to escape
         else:
             try:
                 return _ATTR_ENCODE(attrs).encode("utf-8")

@@ -21,6 +21,7 @@ class SystemStatusServer:
         health_fn: Callable[[], Awaitable[dict]] | None = None,
         stats_fn: Callable[[], dict] | None = None,
         events_fn: Callable[..., dict] | None = None,
+        xprof_fn: Callable[[int, str | None], str | None] | None = None,
         host: str = "0.0.0.0",
         port: int = 0,
     ):
@@ -28,6 +29,7 @@ class SystemStatusServer:
         self.health_fn = health_fn
         self.stats_fn = stats_fn
         self.events_fn = events_fn
+        self.xprof_fn = xprof_fn
         self.host = host
         self.port = port
         self._runner: web.AppRunner | None = None
@@ -39,6 +41,7 @@ class SystemStatusServer:
         app.router.add_get("/metrics", self._metrics)
         app.router.add_get("/metrics.json", self._metrics_json)
         app.router.add_get("/events.json", self._events_json)
+        app.router.add_post("/debug/xprof", self._debug_xprof)
         self._runner = web.AppRunner(app, access_log=None)
         await self._runner.setup()
         site = web.TCPSite(self._runner, self.host, self.port)
@@ -104,3 +107,25 @@ class SystemStatusServer:
         return web.Response(
             text=json.dumps(body), content_type="application/json"
         )
+
+    async def _debug_xprof(self, request: web.Request) -> web.Response:
+        """Arm a profiler capture of the engine's next `?steps=N` steps
+        (`&dir=` overrides the directory) while serving; answers with the
+        directory the `.xplane.pb` lands in, 409 while a capture is armed
+        or running, 404 when the process has no engine to trace."""
+        def reply(status, **body):
+            return web.Response(text=json.dumps(body), status=status,
+                                content_type="application/json")
+
+        if self.xprof_fn is None:
+            return reply(404, error="no engine to trace in this process")
+        try:
+            steps = int(request.query.get("steps", ""))
+        except ValueError:
+            steps = 0
+        if steps <= 0:
+            return reply(400, error="steps=N (a positive integer) is needed")
+        directory = self.xprof_fn(steps, request.query.get("dir"))
+        if directory is None:
+            return reply(409, error="a capture is armed or running already")
+        return reply(200, steps=steps, dir=directory)

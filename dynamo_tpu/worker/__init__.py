@@ -97,6 +97,14 @@ class DpRankEngine:
                 m.ttft_queue_wait_ms_total for m in per
             ),
             ttft_prefill_ms_total=sum(m.ttft_prefill_ms_total for m in per),
+            ttft_turn_wait_ms_total=sum(
+                m.ttft_turn_wait_ms_total for m in per
+            ),
+            kv_pages_cached=sum(m.kv_pages_cached for m in per),
+            kv_pages_free=sum(m.kv_pages_free for m in per),
+            prefix_evictions_total=sum(
+                m.prefix_evictions_total for m in per
+            ),
             ttft_attributed_total=sum(m.ttft_attributed_total for m in per),
             decode_cc_blocks_total=sum(
                 m.decode_cc_blocks_total for m in per

@@ -529,14 +529,18 @@ async def _run(args) -> None:
 
         scope.registry.register(ProcessStatsCollector())
 
+        def _inner_engine():
+            inner = engine
+            while not hasattr(inner, "events") and hasattr(inner, "engine"):
+                inner = inner.engine  # unwrap disagg/encode handlers
+            return inner
+
         def _events(since_ns=None):
             """Step-event ring dump(s) for /events.json — the engine(s)
             behind this endpoint, keyed so the timeline merger can place
             each ring on its own track (dp ranks dump separately).
             `since_ns` is the poller's cursor (dump watermark_ns)."""
-            inner = engine
-            while not hasattr(inner, "events") and hasattr(inner, "engine"):
-                inner = inner.engine  # unwrap disagg/encode handlers
+            inner = _inner_engine()
             if hasattr(inner, "engines"):  # DpRankEngine
                 return {
                     f"rank{r}": e.events.dump(since_ns=since_ns)
@@ -547,11 +551,25 @@ async def _run(args) -> None:
                 return {"engine": inner.events.dump(since_ns=since_ns)}
             return {}
 
+        def _xprof(steps, directory=None):
+            """POST /debug/xprof: arm a capture on the first engine behind
+            this endpoint (the profiler is one per process, so one rank's
+            step count bounds it); the directory, or None when busy."""
+            inner = _inner_engine()
+            inner = getattr(inner, "engines", [inner])[0]
+            if not hasattr(inner, "arm_xprof"):
+                return None
+            directory = _os.path.abspath(
+                directory or _os.environ.get("DYN_TPU_XPROF_DIR")
+                or "profiles")
+            return directory if inner.arm_xprof(steps, directory) else None
+
         status = await SystemStatusServer(
             metrics=scope,
             health_fn=lambda: _async_health(health),
             stats_fn=_stats_json,
             events_fn=_events,
+            xprof_fn=None if args.mock else _xprof,
             port=args.status_port,
         ).start()
         print(f"STATUS http://0.0.0.0:{status.port}", flush=True)

@@ -289,7 +289,7 @@ async def test_status_events_json_since_ns_cursor():
 
 
 async def test_engine_records_step_events_and_status_dump():
-    """A served generation leaves admit/dispatch/rung/decode/pool events
+    """A served generation leaves admit/plan/rung/decode/pool events
     on the engine ring, and the worker debug endpoint dumps them."""
     import urllib.request
 
@@ -316,8 +316,9 @@ async def test_engine_records_step_events_and_status_dump():
             out.extend(d.get("token_ids", []))
         assert len(out) == 8
         kinds = {e[2] for e in engine.events.snapshot()}
-        assert {"admit", "dispatch", "rung_select", "decode_block",
-                "prefill_chunk", "pool_alloc"} <= kinds, kinds
+        assert {"admit", "plan", "loop_yield", "first_token", "rung_select",
+                "decode_block", "prefill_chunk", "pool_alloc"} <= kinds, kinds
+        assert "dispatch" not in kinds  # its n_steps/blocks ride the slice
         decode = [e for e in engine.events.snapshot()
                   if e[2] == "decode_block"]
         assert decode and all("rung" in e[3] and "batch" in e[3]

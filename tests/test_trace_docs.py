@@ -35,7 +35,8 @@ def test_emitters_enumerate_known_names():
             "router.schedule", "migration.reissue"} <= spans
     assert not any(n.startswith("<dynamic") for n in spans)
     kinds = emitted_event_kinds()
-    assert {"admit", "dispatch", "decode_block", "decode_chain",
+    assert {"admit", "plan", "loop_yield", "first_token", "decode_block",
+            "decode_chain",
             "spec_round", "kvbm_offload", "kvbm_onboard"} <= kinds
     assert not any(k.startswith("<dynamic") for k in kinds)
 
@@ -60,7 +61,7 @@ def test_drift_detected_both_directions(tmp_path):
     mutated = (
         text
         .replace("| `spec_round` | slice | `k`, `batch`, `drafted`, "
-                 "`accepted` |\n", "")
+                 "`accepted`, step attributes without `attn` |\n", "")
         .replace("## Span map\n",
                  "## Span map\n\n| Span | Emitted by | Attributes |\n"
                  "|---|---|---|\n| `ghost.span` | nobody | |\n")
