@@ -7,8 +7,13 @@ Architecture (not a torch translation):
   regardless of depth (80-layer 70B compiles as fast as a 2-layer test
   model).
 - KV cache is the page pool from ``ops.paged_attention``, stacked per layer:
-  ``k_pages/v_pages: [L, P, page, n_kv, hd]`` — scanned alongside the
-  params, so cache updates ride the same scan.
+  ``k_pages/v_pages: [L, P, page, n_kv, hd]``.  The prefill-type layer
+  loops (`prefill_layers`) leave it where it is: the scan runs over the
+  params and a layer index, attention reads the pool by (layer, page), and
+  one scatter after the loop lands every layer's new tokens in the donated
+  pool (``ops.paged_attention.write_kv_layers``).  The per-step decode
+  loop (`decode_layers`) still scans the pool beside the params and defers
+  only the write; it moves off the scanned pool with a cell that decodes.
 - All matmuls are bf16 with fp32 accumulation (``preferred_element_type``),
   sized for the MXU; no data-dependent control flow anywhere.
 - MoE (Mixtral-style) uses one-hot dispatch einsums — expert-parallel
@@ -33,6 +38,7 @@ from ..ops import (
     rms_norm,
     rope_attention_scale,
     rope_frequencies,
+    write_kv_layers,
     write_kv_pages,
 )
 from .config import ModelConfig
@@ -472,7 +478,8 @@ def _moe_capacity(lp: Params, x: jax.Array, cfg: ModelConfig) -> jax.Array:
 
 def _layer_prefill(
     lp: Params,
-    kv_layer: Tuple[jax.Array, jax.Array],
+    kv: KVCache,  # the WHOLE pool, read only (and only by page)
+    layer,  # scalar layer index (traced: the loop's counter)
     x: jax.Array,  # [B, S, h]
     positions: jax.Array,  # [B, S]
     page_table: jax.Array,
@@ -485,9 +492,13 @@ def _layer_prefill(
     rope_pos=None,  # [B, 3, S] mrope streams (Qwen2-VL); None = standard
     rope_scale: float = 1.0,  # yarn amplitude factor
 ):
+    """One decoder layer over a chunk.  Returns (x, (k, v)): the chunk's
+    own keys and values [B, S, n_kv, hd], NOT a pool.  Attention reads the
+    OLD pool's pages of `layer` plus the chunk itself, and layer l+1 never
+    reads what layer l wrote, so the caller lands every layer's (k, v) in
+    one scatter after the loop (`write_kv_layers`)."""
     B, S, h = x.shape
     nh, nkv, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim_
-    k_pages, v_pages = kv_layer
 
     dt = x.dtype
     with jax.named_scope("attn.qkv"):
@@ -506,11 +517,8 @@ def _layer_prefill(
             k = apply_rope(k, positions, inv_freq, scale=rope_scale)
 
     attn = prefill_attention(
-        q, k, v, k_pages, v_pages, page_table, prefix_lens, chunk_lens,
-        impl=attn_impl, window=window, sink=lp.get("sinks"),
-    )
-    k_pages, v_pages = write_kv_pages(
-        k_pages, v_pages, k, v, page_table, prefix_lens, chunk_lens
+        q, k, v, kv.k, kv.v, page_table, prefix_lens, chunk_lens,
+        impl=attn_impl, window=window, sink=lp.get("sinks"), layer=layer,
     )
     with jax.named_scope("attn.out"):
         attn_out = matmul_any(
@@ -523,7 +531,7 @@ def _layer_prefill(
     with jax.named_scope("mlp"):
         mlp_in = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
         mlp_out = _moe(lp, mlp_in, cfg) if cfg.is_moe else _mlp(lp, mlp_in)
-        return x + mlp_out, (k_pages, v_pages)
+        return x + mlp_out, (k, v)
 
 
 def _layer_decode(
@@ -561,9 +569,12 @@ def _layer_decode(
     if defer_write:
         # deferred-write path: attend to the OLD pool + an explicit self
         # column; the caller lands every layer's (k, v) in ONE batched
-        # scatter after the layer scan (a per-layer scatter + pool read
-        # makes XLA copy the pool each layer-step — ~1.8ms/step at
-        # 1B/batch-8; see decode_attention self_kv + decode_layers)
+        # scatter after the layer scan (`write_kv_layers`, the helper the
+        # prefill loop writes through too).  A per-layer scatter + pool
+        # read makes XLA copy the pool each layer-step — ~1.8ms/step at
+        # 1B/batch-8; see decode_attention self_kv + decode_layers.  The
+        # pool is still a scanned operand here (a slab is sliced out per
+        # layer); `prefill_layers` shows the loop without that.
         attn = decode_attention(
             q, k_pages, v_pages, page_table, seq_lens, impl=attn_impl,
             window=window, sink=lp.get("sinks"),
@@ -640,25 +651,35 @@ def prefill_layers(
 ) -> Tuple[jax.Array, KVCache]:
     """Scan a STACK of decoder layers over an embedded chunk (the body of
     `forward_prefill`, exposed so pipeline stages can run their local
-    layer slice — parallel/pp_engine.py)."""
+    layer slice — parallel/pp_engine.py).
+
+    The pool stays where it is: the scan runs over the layers' weights and
+    a layer index, the body closes over `kv` and reads it by (layer, page),
+    and the only `ys` are the chunk's own keys and values ([L, B, S, n_kv,
+    hd]), which ONE scatter lands in the donated pool after the loop.  Do
+    not scan the pool itself: XLA then slices a layer's slab out, stacks a
+    new slab back and copies the whole pool at the loop's edge on every
+    step (PERF.md, finding 10)."""
     inv_freq = rope_frequencies(cfg.head_dim_, cfg.rope_theta, cfg.rope_scaling)
     rs = rope_attention_scale(cfg.rope_scaling)
     if wins is None:
         wins = _window_xs(cfg)
 
-    def body(carry, xs):
-        h = carry
-        lp, k_pages, v_pages = xs[:3]
-        h, (k_pages, v_pages) = _layer_prefill(
-            lp, (k_pages, v_pages), h, positions, page_table,
-            prefix_lens, chunk_lens, cfg, inv_freq, attn_impl,
-            window=xs[3] if wins else None, rope_pos=rope_pos,
+    def body(h, xs):
+        lp, layer = xs[:2]
+        return _layer_prefill(
+            lp, kv, layer, h, positions, page_table, prefix_lens,
+            chunk_lens, cfg, inv_freq, attn_impl,
+            window=xs[2] if wins else None, rope_pos=rope_pos,
             rope_scale=rs,
         )
-        return h, (k_pages, v_pages)
 
-    x, (k_new, v_new) = jax.lax.scan(body, x, (layers, kv.k, kv.v, *wins))
-    return x, KVCache(k_new, v_new)
+    n_layers = kv.k.shape[0]
+    x, (k_new, v_new) = jax.lax.scan(
+        body, x, (layers, jnp.arange(n_layers, dtype=jnp.int32), *wins))
+    valid = jnp.arange(x.shape[1])[None, :] < chunk_lens[:, None]
+    return x, KVCache(*write_kv_layers(
+        kv.k, kv.v, k_new, v_new, page_table, prefix_lens, valid))
 
 
 def decode_layers(
@@ -704,16 +725,10 @@ def decode_layers(
         return x, KVCache(k_new, v_new)
     # ONE batched scatter lands every layer's new token ([L, B, kv, hd]);
     # out-of-window rows carry an all-trash table row, so their slot is
-    # inside trash page 0 (duplicate trash slots may race — by design)
-    Lk, P, page = kv.k.shape[0], kv.k.shape[1], kv.k.shape[2]
-    page_idx = jnp.clip(positions // page, 0, page_table.shape[1] - 1)
-    slot = (jnp.take_along_axis(page_table, page_idx[:, None], axis=1)[:, 0]
-            * page + positions % page)  # [B]
-    kf = kv.k.reshape(Lk, P * page, *kv.k.shape[3:])
-    vf = kv.v.reshape(Lk, P * page, *kv.v.shape[3:])
-    kf = kf.at[:, slot].set(k_new.astype(kf.dtype), mode="drop")
-    vf = vf.at[:, slot].set(v_new.astype(vf.dtype), mode="drop")
-    return x, KVCache(kf.reshape(kv.k.shape), vf.reshape(kv.v.shape))
+    # inside trash page 0
+    return x, KVCache(*write_kv_layers(
+        kv.k, kv.v, k_new[:, :, None], v_new[:, :, None], page_table,
+        positions, jnp.ones((positions.shape[0], 1), bool)))
 
 
 def forward_prefill(
@@ -764,31 +779,28 @@ def forward_embed(
 ) -> jax.Array:
     """Sequence embeddings: mean-pooled final hidden states over valid
     tokens (decoder-as-embedder, the common llama-embedding recipe).
-    Cache-free: attention runs over a throwaway in-call page pool."""
+    Cache-free: nothing is cached before the one chunk (prefix 0) and what
+    the chunk computes is thrown away, so the pool `_layer_prefill` reads is
+    a one-slot stand-in that attention masks out whole."""
     B, S = tokens.shape
-    page_size = min(S, 128)
-    pages_per_seq = -(-S // page_size)
-    kv = KVCache.create(cfg, 1 + B * pages_per_seq, page_size, jnp.float32)
-    table = (
-        jnp.arange(B * pages_per_seq, dtype=jnp.int32).reshape(B, pages_per_seq)
-        + 1
-    )
+    kv = KVCache.create(cfg, 1, 1, jnp.float32)
+    table = jnp.zeros((B, 1), jnp.int32)
     inv_freq = rope_frequencies(cfg.head_dim_, cfg.rope_theta, cfg.rope_scaling)
     positions = jnp.arange(S)[None, :].repeat(B, 0)
     prefix = jnp.zeros((B,), jnp.int32)
     x = params["embed"][tokens]
     wins = _window_xs(cfg)
 
-    def body(carry, xs):
-        h = carry
-        lp, k_pages, v_pages = xs[:3]
-        h, (k_pages, v_pages) = _layer_prefill(
-            lp, (k_pages, v_pages), h, positions, table, prefix, lens,
-            cfg, inv_freq, window=xs[3] if wins else None,
+    def body(h, xs):
+        lp, layer = xs[:2]
+        h, _ = _layer_prefill(
+            lp, kv, layer, h, positions, table, prefix, lens, cfg, inv_freq,
+            window=xs[2] if wins else None,
         )
-        return h, (k_pages, v_pages)
+        return h, None
 
-    x, _ = jax.lax.scan(body, x, (params["layers"], kv.k, kv.v, *wins))
+    layer_ids = jnp.arange(cfg.num_hidden_layers, dtype=jnp.int32)
+    x, _ = jax.lax.scan(body, x, (params["layers"], layer_ids, *wins))
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     mask = (jnp.arange(S)[None, :] < lens[:, None]).astype(jnp.float32)
     pooled = (x.astype(jnp.float32) * mask[..., None]).sum(1)
@@ -1047,30 +1059,17 @@ def decode_block_scan(
         jnp.swapaxes(acts, 0, 1) if masked else None)
 
 
-@jax.named_scope("kv.write")
 def _scatter_block(kv: KVCache, rk, rv, positions, page_table,
                    max_valid_pos: int, acts) -> KVCache:
     """Land a decode block's ring buffers ([L, B, T, nkv, hd]) in the pool
     in one batched scatter; `acts` ([B, T] bool, or None) masks the steps a
     row was frozen in."""
-    L, P, page, nkv, hd = kv.k.shape
-    B, W = page_table.shape
     T = rk.shape[2]
-    tpos = positions[:, None] + jnp.arange(T)[None, :]  # [B, T]
-    ok = tpos < max_valid_pos
+    ok = positions[:, None] + jnp.arange(T)[None, :] < max_valid_pos  # [B, T]
     if acts is not None:
         # a frozen row's emitted prefix is contiguous from its initial
-        # position, so the uniform tpos formula holds exactly where the
+        # position, so the uniform position formula holds exactly where the
         # per-step mask is true; everything after the stop lands in trash
         ok &= acts
-    page_idx = jnp.clip(tpos // page, 0, W - 1)
-    page_ids = jnp.take_along_axis(page_table, page_idx, axis=1)
-    slot = jnp.where(ok, page_ids * page + tpos % page, 0).reshape(-1)
-    kf = kv.k.reshape(L, P * page, nkv, hd)
-    vf = kv.v.reshape(L, P * page, nkv, hd)
-    # ring [L, B, T] → [L, B*T] rows aligned with slot
-    kf = kf.at[:, slot].set(
-        rk.reshape(L, B * T, nkv, hd).astype(kf.dtype), mode="drop")
-    vf = vf.at[:, slot].set(
-        rv.reshape(L, B * T, nkv, hd).astype(vf.dtype), mode="drop")
-    return KVCache(kf.reshape(kv.k.shape), vf.reshape(kv.v.shape))
+    return KVCache(*write_kv_layers(
+        kv.k, kv.v, rk, rv, page_table, positions, ok))

@@ -10,6 +10,7 @@ from .paged_attention import (
     decode_attention,
     gather_kv,
     prefill_attention,
+    write_kv_layers,
     write_kv_pages,
 )
 from .rotary import (apply_mrope, apply_rope,
@@ -36,5 +37,6 @@ __all__ = [
     "rope_frequencies",
     "sample_tokens",
     "top_logprobs",
+    "write_kv_layers",
     "write_kv_pages",
 ]

@@ -3,7 +3,9 @@
 Design (TPU-first, not a CUDA translation):
 
 - The KV cache is a pool of fixed-size *pages* per layer:
-  ``[num_pages, page_size, n_kv_heads, head_dim]``.  A sequence owns an
+  ``[num_pages, page_size, n_kv_heads, head_dim]``, stacked over layers
+  (``[L, ...]``; the functions below take one layer's pool, or the whole
+  pool with a layer index, and never cut a slab out).  A sequence owns an
   ordered list of page ids (its *page table* row).  Page id 0 is reserved as
   the trash page: padding tokens scatter there, so every shape stays static
   and no masking is needed on the write path.
@@ -41,9 +43,10 @@ NEG_INF = -1e30
 # at ctx 2272/batch 4 on v5e the xla+deferred path runs 9.6ms/step vs
 # the kernel's 15.8 (the kernel still requires write-first), so the
 # decode crossover moved out past 4k; each table-width bucket is its own
-# jit trace, so the choice is static per compiled step.  PREFILL still
-# uses the write-first layout the old measurement covered (streaming
-# kernel 3.2x at 4k, winning from ~1k), so it keeps its own threshold.
+# jit trace, so the choice is static per compiled step.  PREFILL keeps
+# the threshold of the old measurement (streaming kernel 3.2x at 4k,
+# winning from ~1k); it was taken before the prefill loop stopped moving
+# the pool (PERF.md, PR 26) and has not been re-swept since.
 PALLAS_MIN_CTX_TOKENS = 4096
 PALLAS_MIN_CTX_TOKENS_PREFILL = 1024
 
@@ -116,6 +119,37 @@ def _adapt(impl: str, page_table: jax.Array, page_size: int,
 # Scope names (jax.named_scope) are what a profiler trace's device ops are
 # found by: docs/observability.md lists them.
 @jax.named_scope("kv.write")
+def write_kv_layers(
+    k_pool: jax.Array,  # [L, P, page, n_kv, hd] — every layer's pool
+    v_pool: jax.Array,
+    k_new: jax.Array,  # [L, B, S, n_kv, hd] — every layer's new tokens
+    v_new: jax.Array,
+    page_table: jax.Array,  # [B, max_pages] int32
+    write_pos: jax.Array,  # [B] int32 — seq offset of each row's first token
+    valid: jax.Array,  # [B, S] bool — tokens that are not padding
+) -> Tuple[jax.Array, jax.Array]:
+    """ONE scatter lands every layer's new tokens in the (donated) pool, in
+    place: a step writes its own tokens, never a layer's slab.  The layer
+    loops attend to the OLD pool plus the new tokens themselves and call
+    this once, after the loop.  Row b's token s sits at sequence position
+    write_pos[b] + s; padding goes to slot 0 inside trash page 0, so every
+    shape stays static (duplicate trash slots may race, by design)."""
+    L, P, page_size, n_kv, hd = k_pool.shape
+    pos = write_pos[:, None] + jnp.arange(valid.shape[1])[None, :]  # [B, S]
+    page_idx = jnp.clip(pos // page_size, 0, page_table.shape[1] - 1)
+    page_ids = jnp.take_along_axis(page_table, page_idx, axis=1)
+    slot = jnp.where(valid, page_ids * page_size + pos % page_size,
+                     0).reshape(-1)  # [B*S] flat slots of a layer's pool
+
+    def land(pool, new):
+        flat = pool.reshape(L, P * page_size, n_kv, hd)
+        flat = flat.at[:, slot].set(
+            new.reshape(L, -1, n_kv, hd).astype(pool.dtype), mode="drop")
+        return flat.reshape(pool.shape)
+
+    return land(k_pool, k_new), land(v_pool, v_new)
+
+
 def write_kv_pages(
     k_pages: jax.Array,  # [P, page, n_kv, hd]
     v_pages: jax.Array,
@@ -125,41 +159,26 @@ def write_kv_pages(
     write_pos: jax.Array,  # [B] int32 — seq offset where this chunk starts
     chunk_lens: jax.Array,  # [B] int32 — valid tokens in this chunk
 ) -> Tuple[jax.Array, jax.Array]:
-    """Scatter a new KV chunk into the page pool. Padding → trash page 0."""
-    P, page_size, n_kv, hd = k_pages.shape
-    B, S = k_new.shape[:2]
-    pos = write_pos[:, None] + jnp.arange(S)[None, :]  # [B, S]
-    valid = jnp.arange(S)[None, :] < chunk_lens[:, None]
-    page_idx = pos // page_size
-    page_off = pos % page_size
-    # page table lookup per token; invalid tokens → trash page 0
-    page_idx = jnp.clip(page_idx, 0, page_table.shape[1] - 1)
-    page_ids = jnp.take_along_axis(page_table, page_idx, axis=1)  # [B, S]
-    slot = jnp.where(valid, page_ids * page_size + page_off, 0)  # [B, S]
-    slot = slot.reshape(-1)
-    k_flat = k_pages.reshape(P * page_size, n_kv, hd)
-    v_flat = v_pages.reshape(P * page_size, n_kv, hd)
-    k_flat = k_flat.at[slot].set(
-        k_new.reshape(B * S, n_kv, hd), mode="drop", unique_indices=False
-    )
-    v_flat = v_flat.at[slot].set(
-        v_new.reshape(B * S, n_kv, hd), mode="drop", unique_indices=False
-    )
-    return (
-        k_flat.reshape(P, page_size, n_kv, hd),
-        v_flat.reshape(P, page_size, n_kv, hd),
-    )
+    """`write_kv_layers` for ONE layer's pool. Padding → trash page 0."""
+    valid = jnp.arange(k_new.shape[1])[None, :] < chunk_lens[:, None]
+    k_pool, v_pool = write_kv_layers(
+        k_pages[None], v_pages[None], k_new[None], v_new[None], page_table,
+        write_pos, valid)
+    return k_pool[0], v_pool[0]
 
 
 @jax.named_scope("kv.gather")
 def gather_kv(
-    k_pages: jax.Array,  # [P, page, n_kv, hd]
+    k_pages: jax.Array,  # [P, page, n_kv, hd], or [L, P, ...] with `layer`
     v_pages: jax.Array,
     page_table: jax.Array,  # [B, max_pages]
+    layer=None,  # scalar layer index (traced OK) into a whole pool
 ) -> Tuple[jax.Array, jax.Array]:
-    """Materialize each sequence's KV: [B, max_pages*page, n_kv, hd]."""
-    k = k_pages[page_table]  # [B, max_pages, page, n_kv, hd]
-    v = v_pages[page_table]
+    """Materialize each sequence's KV: [B, max_pages*page, n_kv, hd].  With
+    `layer`, ONE gather indexed by (layer, page) reads the table's pages
+    straight out of the whole pool: no slab is sliced out first."""
+    at = page_table if layer is None else (layer, page_table)
+    k, v = k_pages[at], v_pages[at]  # [B, max_pages, page, n_kv, hd]
     B, mp, page, n_kv, hd = k.shape
     return k.reshape(B, mp * page, n_kv, hd), v.reshape(B, mp * page, n_kv, hd)
 
@@ -210,7 +229,8 @@ def prefill_attention(
     q: jax.Array,  # [B, S, n_heads, hd] — the new chunk
     k_new: jax.Array,  # [B, S, n_kv, hd]
     v_new: jax.Array,
-    k_pages: jax.Array,  # [P, page, n_kv, hd] — pool (already containing prefix)
+    k_pages: jax.Array,  # [P, page, n_kv, hd] — pool (already containing
+    # prefix), or every layer's [L, P, page, n_kv, hd] with `layer`
     v_pages: jax.Array,
     page_table: jax.Array,  # [B, max_pages]
     prefix_lens: jax.Array,  # [B] — tokens already in cache before this chunk
@@ -218,11 +238,15 @@ def prefill_attention(
     impl: str = "xla",
     window=None,  # scalar int (traced OK); <= 0 → full attention
     sink=None,  # [n_heads] learnable sink logits; None → plain softmax
+    layer=None,  # scalar layer index (traced OK) into a whole pool
 ) -> jax.Array:
     """Chunk attends to cached prefix + itself (causal; optionally only
-    the last `window` positions). Returns [B,S,H,hd]."""
+    the last `window` positions). Returns [B,S,H,hd].  The pool is only
+    read, and only by page: given the whole pool and `layer` (the layer
+    loops), layer `layer`'s pages are fetched by (layer, page) and no slab
+    is cut out of the pool."""
     B, S, n_heads, hd = q.shape
-    n_kv, page = k_pages.shape[2], k_pages.shape[1]
+    page, n_kv = k_pages.shape[-3], k_pages.shape[-2]
     esize = jnp.dtype(q.dtype).itemsize
     vmem = (
         2 * S * n_heads * hd * esize        # q + o blocks
@@ -239,11 +263,12 @@ def prefill_attention(
 
         return prefill_attention_pallas(
             q, k_new, v_new, k_pages, v_pages, page_table, prefix_lens,
-            chunk_lens, window=window, sink=sink,
+            chunk_lens, window=window, sink=sink, layer=layer,
         )
     scale = 1.0 / jnp.sqrt(hd).astype(jnp.float32)
 
-    k_pre, v_pre = gather_kv(k_pages, v_pages, page_table)  # [B, Lp, n_kv, hd]
+    # [B, Lp, n_kv, hd]
+    k_pre, v_pre = gather_kv(k_pages, v_pages, page_table, layer)
     Lp = k_pre.shape[1]
     i = jnp.arange(S)[None, None, :, None]
     # global query positions: prefix + row index within the chunk

@@ -9,15 +9,24 @@ double-buffered async DMA and accumulate flash-attention style (online
 softmax), so attention traffic is the true KV footprint and nothing else.
 
 Layout notes:
-- The page pool is ``[P, page, n_kv, hd]`` (see
-  ``paged_attention.write_kv_pages``).  In-kernel we view it as
-  ``[P, page, n_kv*hd]`` — for Llama-class shapes (n_kv*hd = 512..1024)
-  the VMEM scratch tile is then exactly (16, 128) for bf16 with zero
-  padding, whereas the 4-D view would pad n_kv up to the sublane count and
-  waste half of VMEM and DMA bandwidth.
-- Prefill flattens heads onto lanes the same way (``[S, H*hd]``) and keeps
-  the online-softmax scalars as ``[S, H]`` so scratch stays tile-exact at
-  any chunk size.
+- The page pool is ``[L, P, page, n_kv, hd]`` (see
+  ``paged_attention.write_kv_layers``), and the head loops want a streamed
+  chunk as ``[T, n_kv*hd]``: heads side by side on lanes, so a head is a
+  static lane slice and the scratch tile is exactly (16, 128) for bf16.
+  That is NOT how the pool is stored: in HBM one token's ``[n_kv, hd]`` is
+  its own tile, so ``reshape(P, page, n_kv*hd)`` outside the kernel is a
+  relayout copy of the whole slab (1.7 s of a 40 s window, PERF.md PR 26).
+- The PREFILL kernel therefore takes the whole pool and a layer index,
+  fetches page ``pid`` of layer ``l`` as it is stored (``k_hbm.at[l,
+  pid]``, a page comes whole: Mosaic slices no single head out of a tile)
+  and puts the heads side by side in VMEM once per streamed chunk.  Heads
+  narrower than 128 lanes cannot be fetched that way (the minor dimension
+  is padded in HBM) and keep one slice + relayout of the layer's slab.
+- The DECODE kernel still gets one layer's slab in the ``[P, page,
+  n_kv*hd]`` view from the scanned decode loop.
+- Prefill flattens the query heads onto lanes the same way (``[S, H*hd]``)
+  and keeps the online-softmax scalars as ``[S, H]`` so scratch stays
+  tile-exact at any chunk size.
 
 The reference delegates attention kernels to vLLM/TRT-LLM (SURVEY.md §2.6);
 this module is the TPU-native equivalent of their CUDA paged-attention
@@ -48,17 +57,21 @@ def _sink_arr(sink, H: int) -> jax.Array:
     return sink.astype(jnp.float32).reshape(1, H)
 
 
-def _page_dmas(pt_ref, b, chunk_idx, buf, k_hbm, v_hbm, k_scr, v_scr, sems, C):
+def _page_dmas(pt_ref, b, chunk_idx, buf, k_hbm, v_hbm, k_scr, v_scr, sems, C,
+               layer=None):
     """The 2C async copies bringing chunk `chunk_idx`'s pages into buffer
-    `buf`. Returned (not started) so callers can .start() or .wait()."""
+    `buf`. Returned (not started) so callers can .start() or .wait().
+    With `layer`, the HBM refs are the whole pool [L, P, ...] and page
+    `pid` of that layer is fetched."""
     copies = []
     for i in range(C):
         pid = pt_ref[b, chunk_idx * C + i]
+        at = (pid,) if layer is None else (layer, pid)
         copies.append(
-            pltpu.make_async_copy(k_hbm.at[pid], k_scr.at[buf, i], sems.at[buf, 0, i])
+            pltpu.make_async_copy(k_hbm.at[at], k_scr.at[buf, i], sems.at[buf, 0, i])
         )
         copies.append(
-            pltpu.make_async_copy(v_hbm.at[pid], v_scr.at[buf, i], sems.at[buf, 1, i])
+            pltpu.make_async_copy(v_hbm.at[at], v_scr.at[buf, i], sems.at[buf, 1, i])
         )
     return copies
 
@@ -256,23 +269,24 @@ def _prefill_kernel(
     pre_ref,  # [B] int32 prefix lengths (tokens already in cache)
     cl_ref,  # [B] int32 chunk lengths (valid tokens in the new chunk)
     win_ref,  # [1] int32 sliding window (0 = full attention)
+    layer_ref,  # [1] int32 — which layer's pages of the pool to read
     # inputs (heads flattened onto lanes)
     q_ref,  # [1, S, H*hd] VMEM (pre-scaled)
     sink_ref,  # [1, H] f32 — per-head sink logits (NEG_INF = no sink)
     kn_ref,  # [1, S, n_kv*hd] VMEM — the chunk's own K
     vn_ref,
-    k_hbm,  # [P, page, n_kv*hd] HBM
-    v_hbm,
+    k_hbm,  # [L, P, page, n_kv, hd] HBM — the whole pool, as it is stored
+    v_hbm,  # (not `as_stored`: one layer's [1, P, page, n_kv*hd])
     # outputs
     o_ref,  # [1, S, H*hd]
     # scratch
-    k_scr,  # [2, C, page, n_kv*hd]
+    k_scr,  # [2, C, *page shape] — double-buffered pages
     v_scr,
-    m_scr,  # [S, H] f32 — running max per (query row, head)
-    l_scr,  # [S, H] f32
-    acc_scr,  # [S, H*hd] f32
-    sems,
-    *,
+    *scratch,  # as_stored: kf_scr, vf_scr [T, n_kv*hd] — the current chunk
+    # with heads side by side; then always:
+    # m_scr [S, H] f32 — running max per (query row, head), l_scr [S, H]
+    # f32, acc_scr [S, H*hd] f32, sems
+    as_stored: bool,
     C: int,
     page: int,
     n_kv: int,
@@ -282,6 +296,7 @@ def _prefill_kernel(
     S: int,
     RB: int,
 ):
+    m_scr, l_scr, acc_scr, sems = scratch[-4:]
     b = pl.program_id(0)
     c = pl.program_id(1)
     T = C * page
@@ -301,7 +316,8 @@ def _prefill_kernel(
 
     def dmas(chunk_idx, buf):
         return _page_dmas(
-            pt_ref, b, chunk_idx, buf, k_hbm, v_hbm, k_scr, v_scr, sems, C
+            pt_ref, b, chunk_idx, buf, k_hbm, v_hbm, k_scr, v_scr, sems, C,
+            layer=layer_ref[0],
         )
 
     @pl.when(c == 0)
@@ -371,6 +387,24 @@ def _prefill_kernel(
         for cp in dmas(ch, buf):
             cp.wait()
 
+        if as_stored:
+            # a page arrives as it is stored, [page, n_kv, hd]: one
+            # [n_kv, hd] per token.  Put the heads side by side on lanes
+            # ONCE per streamed chunk (not per row block and head), in the
+            # form the head loop slices; the pool itself is never
+            # re-laid-out in HBM.
+            kf_scr, vf_scr = scratch[:2]
+            for kh in range(n_kv):
+                ds = slice(kh * hd, (kh + 1) * hd)
+                kf_scr[:, ds] = k_scr[buf, :, :, kh, :].reshape(T, hd)
+                vf_scr[:, ds] = v_scr[buf, :, :, kh, :].reshape(T, hd)
+
+        def tiles():  # this chunk's K and V, [T, n_kv*hd] each
+            if as_stored:
+                return kf_scr[...], vf_scr[...]
+            return (k_scr[buf].reshape(T, n_kv * hd),
+                    v_scr[buf].reshape(T, n_kv * hd))
+
         def row_block(r, carry):
             r0 = pl.multiple_of(r * RB, RB)
             # per-row mask: key position validity + sliding window around
@@ -380,12 +414,7 @@ def _prefill_kernel(
                 jnp.int32, (RB, T), 1)
             valid = tpos < prefix_len
             valid &= (window <= 0) | (tpos > prefix_len + rows - window)
-            attend_rows(
-                pl.ds(r0, RB),
-                k_scr[buf].reshape(T, n_kv * hd),
-                v_scr[buf].reshape(T, n_kv * hd),
-                valid,
-            )
+            attend_rows(pl.ds(r0, RB), *tiles(), valid)
             return carry
 
         jax.lax.fori_loop(0, nrb, row_block, 0)
@@ -428,7 +457,7 @@ def prefill_attention_pallas(
     q: jax.Array,  # [B, S, H, hd]
     k_new: jax.Array,  # [B, S, n_kv, hd]
     v_new: jax.Array,
-    k_pages: jax.Array,  # [P, page, n_kv, hd]
+    k_pages: jax.Array,  # [P, page, n_kv, hd], or [L, P, ...] with `layer`
     v_pages: jax.Array,
     page_table: jax.Array,  # [B, max_pages]
     prefix_lens: jax.Array,  # [B]
@@ -436,12 +465,27 @@ def prefill_attention_pallas(
     *,
     window=None,  # scalar int; None/<=0 → full attention
     sink=None,  # [H] per-head sink logits; None → plain softmax
+    layer=None,  # scalar layer index (traced OK) into a whole pool
     interpret: bool = False,
 ) -> jax.Array:
     """Chunked-prefill flash attention: streamed prefix pages + causal self
     block. Returns [B, S, H, hd]."""
     B, S, H, hd = q.shape
-    P, page, n_kv, _ = k_pages.shape
+    if layer is None:  # one layer's pool: a pool of one layer
+        k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
+    _, P, page, n_kv, _ = k_pages.shape
+    # A page can be fetched as it is stored only if a head fills whole
+    # lanes: HBM pads the minor dimension to 128, and a DMA takes no part
+    # of a tile.  Narrower heads (hd 64) keep ONE slice + relayout of the
+    # layer's slab per layer, read-only (the stored layout: ROADMAP D3).
+    as_stored = hd % 128 == 0
+    if as_stored:
+        page_shape = (page, n_kv, hd)
+    else:
+        page_shape = (page, n_kv * hd)
+        k_pages = k_pages[layer].reshape(1, P, *page_shape)
+        v_pages = v_pages[layer].reshape(1, P, *page_shape)
+        layer = 0
     groups = H // n_kv
     C = max(1, 128 // page)
     maxp = page_table.shape[1]
@@ -454,13 +498,11 @@ def prefill_attention_pallas(
     qs = (q.astype(jnp.float32) * scale).astype(q.dtype).reshape(B, S, H * hd)
     kn = k_new.reshape(B, S, n_kv * hd)
     vn = v_new.reshape(B, S, n_kv * hd)
-    k_r = k_pages.reshape(P, page, n_kv * hd)
-    v_r = v_pages.reshape(P, page, n_kv * hd)
 
     win = jnp.full((1,), 0 if window is None else window, jnp.int32)
     sink_arr = _sink_arr(sink, H)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
+        num_scalar_prefetch=5,
         grid=(B, nc),
         in_specs=[
             pl.BlockSpec((1, S, H * hd), lambda b, c, *_: (b, 0, 0)),
@@ -472,8 +514,11 @@ def prefill_attention_pallas(
         ],
         out_specs=pl.BlockSpec((1, S, H * hd), lambda b, c, *_: (b, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((2, C, page, n_kv * hd), k_pages.dtype),
-            pltpu.VMEM((2, C, page, n_kv * hd), v_pages.dtype),
+            pltpu.VMEM((2, C, *page_shape), k_pages.dtype),
+            pltpu.VMEM((2, C, *page_shape), v_pages.dtype),
+            *([pltpu.VMEM((C * page, n_kv * hd), k_pages.dtype),
+               pltpu.VMEM((C * page, n_kv * hd), v_pages.dtype)]
+              if as_stored else []),
             pltpu.VMEM((S, H), jnp.float32),
             pltpu.VMEM((S, H), jnp.float32),
             pltpu.VMEM((S, H * hd), jnp.float32),
@@ -484,6 +529,7 @@ def prefill_attention_pallas(
         _prefill_kernel,
         C=C, page=page, n_kv=n_kv, groups=groups, hd=hd, nc=nc, S=S,
         RB=_PREFILL_ROW_BLOCK if S % _PREFILL_ROW_BLOCK == 0 else S,
+        as_stored=as_stored,
     )
     out = pl.pallas_call(
         kernel,
@@ -495,6 +541,7 @@ def prefill_attention_pallas(
         prefix_lens.astype(jnp.int32),
         chunk_lens.astype(jnp.int32),
         win,
-        qs, sink_arr, kn, vn, k_r, v_r,
+        jnp.asarray(layer, jnp.int32).reshape(1),
+        qs, sink_arr, kn, vn, k_pages, v_pages,
     )
     return out.reshape(B, S, H, hd)

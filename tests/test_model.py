@@ -378,3 +378,99 @@ def test_verify_matches_per_step_decode(feature, k):
         np.asarray(kv_v.k), np.asarray(kv_r.k), rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(
         np.asarray(kv_v.v), np.asarray(kv_r.v), rtol=1e-5, atol=1e-6)
+
+
+# -- the prefill layer loop leaves the pool where it is (ISSUE 26) -------------- #
+
+def _layer_by_layer_prefill(params, cfg, kv, tokens, table, prefix_lens,
+                            chunk_lens):
+    """The semantics the scan over the pool had, as a plain Python loop:
+    layer l attends, then its slab of the pool is rewritten BEFORE layer
+    l+1 runs."""
+    from dynamo_tpu.models.llama import _layer_prefill, _lm_logits
+    from dynamo_tpu.ops import (rope_attention_scale, rope_frequencies,
+                                write_kv_pages)
+
+    inv_freq = rope_frequencies(cfg.head_dim_, cfg.rope_theta,
+                                cfg.rope_scaling)
+    positions = prefix_lens[:, None] + jnp.arange(tokens.shape[1])[None, :]
+    wins = cfg.layer_windows() if cfg.sliding_window else None
+    x = params["embed"][tokens]
+    for l in range(cfg.num_hidden_layers):
+        lp = jax.tree.map(lambda w: w[l], params["layers"])
+        x, (k, v) = _layer_prefill(
+            lp, kv, l, x, positions, table, prefix_lens, chunk_lens, cfg,
+            inv_freq, window=None if wins is None else jnp.int32(wins[l]),
+            rope_scale=rope_attention_scale(cfg.rope_scaling))
+        k_l, v_l = write_kv_pages(kv.k[l], kv.v[l], k, v, table,
+                                  prefix_lens, chunk_lens)
+        kv = KVCache(kv.k.at[l].set(k_l), kv.v.at[l].set(v_l))
+    last = jnp.maximum(chunk_lens - 1, 0)
+    x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
+    return _lm_logits(params, cfg, x_last), kv
+
+
+@pytest.mark.parametrize("feature",
+                         ["plain", "swa", "moe_sinks_windows"])
+def test_prefill_loop_equals_layer_by_layer_writes(feature):
+    """Chunked prefill through `prefill_layers` (pool read by (layer,
+    page) inside the scan, ONE scatter after it) against a reference that
+    writes layer by layer: same logits, same pool.  Two rows with
+    different prefix and chunk lengths; row 0's padding goes to trash
+    page 0 and nowhere else."""
+    cfg = FEATURE_CFGS[feature]()
+    params = init_params(cfg, jax.random.PRNGKey(3), dtype=jnp.float32)
+    B, S, page, pages_per = 2, 8, 4, 5
+    P = 1 + B * pages_per + 2  # two pages no table names
+    table = make_table(B, pages_per)
+    toks = jnp.asarray(np.random.RandomState(9).randint(
+        1, cfg.vocab_size, (2, B, S)), jnp.int32)
+    chunks = [  # (prefix_lens, chunk_lens) of two consecutive chunks
+        (jnp.asarray([0, 0], jnp.int32), jnp.asarray([7, 3], jnp.int32)),
+        (jnp.asarray([7, 3], jnp.int32), jnp.asarray([5, 8], jnp.int32)),
+    ]
+    kv_a = KVCache.create(cfg, P, page, jnp.float32)
+    kv_b = KVCache(kv_a.k, kv_a.v)
+    for t, (pre, cl) in zip(toks, chunks):
+        logits_a, kv_a = forward_prefill(params, cfg, kv_a, t, table, pre, cl)
+        logits_b, kv_b = _layer_by_layer_prefill(params, cfg, kv_b, t, table,
+                                                 pre, cl)
+        np.testing.assert_allclose(logits_a, logits_b, rtol=1e-5, atol=1e-5)
+    for a, b in zip(kv_a, kv_b):
+        a, b = np.asarray(a), np.asarray(b)
+        # page 0 takes the padding (duplicate slots may land in any order)
+        np.testing.assert_allclose(a[:, 1:], b[:, 1:], rtol=1e-6, atol=1e-6)
+        assert np.abs(a[:, 0]).max() > 0  # padding did land in the trash
+        assert not a[:, -2:].any()  # pages of no table stay untouched
+        # row 0 holds 12 tokens and row 1 holds 11: nothing past them
+        flat = a.reshape(a.shape[0], P * page, *a.shape[3:])
+        for row, n in ((0, 12), (1, 11)):
+            first = int(table[row, 0]) * page
+            assert np.abs(flat[:, first:first + n]).min(axis=(-1, -2)).all()
+            assert not flat[:, first + n:first + pages_per * page].any()
+
+
+def test_prefill_loop_does_not_scan_the_pool():
+    """In the jaxpr of a prefill the layer scan may CLOSE over the pool
+    (a read-only constant of the loop) but neither scans it, carries it nor
+    returns anything of its size: no xs, carry or ys has the page-count
+    dimension.  The pool's only writer is the scatter after the loop."""
+    cfg = tiny_config()
+    params = init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
+    B, S, P, page = 2, 8, 37, 4  # 37: no other dimension has that size
+    kv = KVCache.create(cfg, P, page, jnp.float32)
+    jaxpr = jax.make_jaxpr(
+        lambda kv, t: forward_prefill(
+            params, cfg, kv, t, make_table(B, 3), jnp.zeros(B, jnp.int32),
+            jnp.full((B,), S, jnp.int32)))(kv, jnp.zeros((B, S), jnp.int32))
+    scans = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "scan"]
+    assert len(scans) == 1
+    (scan,) = scans
+    moving = scan.invars[scan.params["num_consts"]:] + scan.outvars
+    assert all(P not in v.aval.shape for v in moving), [
+        v.aval for v in moving if P in v.aval.shape]
+    # the scan does read the pool: it is among the loop's constants
+    assert any(v.aval.shape == kv.k.shape
+               for v in scan.invars[:scan.params["num_consts"]])
+    # and the one thing written back is a scatter outside the loop
+    assert [e.primitive.name for e in jaxpr.jaxpr.eqns].count("scatter") == 2

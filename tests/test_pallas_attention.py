@@ -303,3 +303,57 @@ def test_prefill_row_blocks_match_xla(window, sinks):
             np.asarray(ref[b, :n], np.float32),
             atol=2e-5, rtol=2e-5,
         )
+
+
+@pytest.mark.parametrize("window,sinks", [(None, False), (40, True)],
+                         ids=["plain", "window-sinks"])
+def test_prefill_reads_the_whole_pool_by_layer_and_page(window, sinks):
+    """The layer loops hand the kernel every layer's pool [L, P, ...] and
+    a TRACED layer index: it must fetch page `pid` of that layer itself
+    (pages of a layer other than 0, inside jit, as the scan calls it) and
+    equal the XLA path's (layer, page) gather AND the per-slab kernel."""
+    L, layer = 3, 2
+    B, H, n_kv, hd, page, S, maxp = 2, 4, 2, 64, 16, 32, 10
+    prefix_lens = jnp.array([130, 5], jnp.int32)  # two streamed chunks, one
+    chunk_lens = jnp.array([S, S - 9], jnp.int32)
+    P = 1 + B * maxp
+    pools = [_make_pool(jax.random.PRNGKey(20 + i), P, page, n_kv, hd,
+                        jnp.float32) for i in range(L)]
+    k_pool = jnp.stack([k for k, _ in pools])
+    v_pool = jnp.stack([v for _, v in pools])
+    table = _page_table(B, maxp, jnp.full((B,), maxp * page), page)
+    ks = jax.random.split(jax.random.PRNGKey(8), 4)
+    q = jax.random.normal(ks[0], (B, S, H, hd), jnp.float32) * 0.5
+    k_new = jax.random.normal(ks[1], (B, S, n_kv, hd), jnp.float32) * 0.3
+    v_new = jax.random.normal(ks[2], (B, S, n_kv, hd), jnp.float32) * 0.3
+    kw = {}
+    if window is not None:
+        kw["window"] = jnp.int32(window)
+    if sinks:
+        kw["sink"] = jax.random.normal(ks[3], (H,), jnp.float32)
+
+    @jax.jit
+    def both(lyr):
+        return (
+            prefill_attention(q, k_new, v_new, k_pool, v_pool, table,
+                              prefix_lens, chunk_lens, layer=lyr, **kw),
+            prefill_attention_pallas(q, k_new, v_new, k_pool, v_pool, table,
+                                     prefix_lens, chunk_lens, layer=lyr,
+                                     interpret=True, **kw),
+        )
+
+    ref, out = both(jnp.int32(layer))
+    slab = prefill_attention_pallas(
+        q, k_new, v_new, k_pool[layer], v_pool[layer], table, prefix_lens,
+        chunk_lens, interpret=True, **kw)
+    other = prefill_attention(q, k_new, v_new, k_pool[0], v_pool[0], table,
+                              prefix_lens, chunk_lens, **kw)
+    for b in range(B):
+        n = int(chunk_lens[b])
+        np.testing.assert_allclose(np.asarray(out[b, :n]),
+                                   np.asarray(ref[b, :n]),
+                                   atol=2e-5, rtol=2e-5)
+        np.testing.assert_array_equal(np.asarray(out[b, :n]),
+                                      np.asarray(slab[b, :n]))
+        # and it IS that layer's pages: layer 0's give another answer
+        assert np.abs(np.asarray(ref[b, :n] - other[b, :n])).max() > 1e-3

@@ -23,7 +23,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from dynamo_tpu.models import KVCache, init_params, kv_cache_pspec, param_pspecs
-from dynamo_tpu.models.config import LLAMA_3_1_8B, LLAMA_3_2_1B
+from dynamo_tpu.models.config import LLAMA_3_1_8B, LLAMA_3_2_1B, QWEN2_5_7B
 from dynamo_tpu.ops.pallas_attention import (
     decode_attention_pallas,
     prefill_attention_pallas,
@@ -126,14 +126,14 @@ def test_prefill_kernel_compiles_at_the_smoke_chunk(one_chip, extras):
 
 # -- whole steps ---------------------------------------------------------------- #
 
-def step_shapes(cfg, batch, table_pages, shardings):
+def step_shapes(cfg, batch, table_pages, shardings, pool_pages=POOL_PAGES):
     """Abstract operands of the engine's decode step (`_build_decode_step`)
     for `cfg`, placed by `shardings(kind)` — kind is a PartitionSpec tree
     for "params"/"kv" and None for the replicated batch operands."""
     params = jax.eval_shape(
         lambda: init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16))
     kv = jax.eval_shape(
-        lambda: KVCache.create(cfg, POOL_PAGES, PAGE, jnp.bfloat16))
+        lambda: KVCache.create(cfg, pool_pages, PAGE, jnp.bfloat16))
 
     def place(tree, specs):
         return jax.tree.map(
@@ -194,3 +194,95 @@ def test_decode_block_step_compiles_on_a_tp4_mesh(topo):
         x.size * x.dtype.itemsize for x in jax.tree.leaves(
             step_shapes(cfg, 8, 64, lambda spec: None)[:2]))
     assert per_chip < 0.4 * whole, (per_chip, whole)
+
+
+# -- a prefill step leaves the KV pool where it is (ISSUE 26) ------------------- #
+
+# the benchmark's one configuration (benchmark/configs/qwen2.5-7b-h14.json)
+CELL_LAYERS = 14
+CELL_POOL_PAGES = 6912
+# ops that move an array without computing on it
+_MOVERS = ("copy", "dynamic-slice", "dynamic-update-slice", "reshape",
+           "transpose")
+
+
+def prefill_step_shapes(cfg, chunk, table_pages, shardings, pool_pages):
+    """Operands of the engine's `prefill_step` (`_build_prefill_step`):
+    the decode step's, with a [B, chunk] token block, prefix and chunk
+    lengths, seeds and counters."""
+    params, kv, _, _, _, table, samp, seeds = step_shapes(
+        cfg, 1, table_pages, shardings, pool_pages)
+    lens = _sds((1,), jnp.int32, shardings(P()))
+    return (params, kv, _sds((1, chunk), jnp.int32, shardings(P())), table,
+            lens, lens, samp, seeds, lens)
+
+
+def pool_sized_movers(hlo_text, kv_shape):
+    """(op, result shape) of every data-moving op of the optimised HLO
+    (fused computations included) whose result has as many elements as
+    the pool or as one layer's slab of it."""
+    import math
+    import re
+
+    sizes = (math.prod(kv_shape), math.prod(kv_shape[1:]))
+    pat = re.compile(r"= \(?\w+\[([\d,]+)\]\S* (%s)\(" % "|".join(_MOVERS))
+    found = []
+    for line in hlo_text.splitlines():
+        m = pat.search(line)
+        if m and math.prod(map(int, m.group(1).split(","))) in sizes:
+            found.append((m.group(2), m.group(1)))
+    return found
+
+
+@pytest.mark.parametrize("chunk,table_pages,kernel", [
+    (512, 128, False), (512, 256, False), (64, 128, True),
+], ids=["xla-512x128", "xla-512x256", "pallas-64x128"])
+def test_prefill_step_leaves_the_pool_where_it_is(one_chip, chunk,
+                                                  table_pages, kernel):
+    """The benchmark cell's `prefill_step` programs (Qwen2.5-7B widths, 14
+    layers, 6912 pages of 16, batch 1, pool donated) on both attention
+    paths: the compiler's temporaries stay under ONE of k or v (they held
+    the pool again: 3.6-3.7 GB), and nothing in the optimised HLO copies,
+    slices, updates or re-lays-out the pool or a layer's slab of it.
+    Counts and bytes, never a time."""
+    import dataclasses
+
+    from dynamo_tpu.engine.engine import _build_prefill_step
+
+    cfg = dataclasses.replace(QWEN2_5_7B, num_hidden_layers=CELL_LAYERS)
+    step = _build_prefill_step(cfg, attn_impl="adaptive", greedy=True)
+    args = prefill_step_shapes(cfg, chunk, table_pages, lambda spec: one_chip,
+                               CELL_POOL_PAGES)
+    compiled = step.lower(*args).compile()
+    text = compiled.as_text()
+    assert ("tpu_custom_call" in text) is kernel
+    k = args[1].k
+    one_of_kv = k.size * k.dtype.itemsize  # 1.585 GB
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < one_of_kv, mem.temp_size_in_bytes
+    assert mem.alias_size_in_bytes >= 2 * one_of_kv  # written in place
+    assert pool_sized_movers(text, k.shape) == []
+
+
+def test_prefill_step_partitions_on_a_tp4_mesh(topo):
+    """The (layer, page) gather and the all-layer scatter under GSPMD with
+    the pool sharded on the kv-head axis (`worker --tp 4`): each chip
+    holds a quarter of the pool, and no chip copies its share.  (8B
+    widths: a pool of 64-wide heads is STORED pages-minor by the TPU
+    compiler and is re-laid-out around any access by page, before this
+    loop and after it: ROADMAP D3.)"""
+    from dynamo_tpu.engine.engine import _build_prefill_step
+
+    cfg = two_layers(LLAMA_3_1_8B)
+    mesh = Mesh(np.array(topo.devices).reshape(1, 4), ("dp", "tp"))
+    step = _build_prefill_step(cfg, attn_impl="xla", greedy=True)
+    args = prefill_step_shapes(
+        cfg, SMOKE_CHUNK, SMOKE_TABLE_PAGES,
+        lambda spec: NamedSharding(mesh, spec), POOL_PAGES)
+    compiled = step.lower(*args).compile()
+    text = compiled.as_text()
+    assert "all-reduce" in text or "reduce-scatter" in text
+    k = args[1].k
+    shard = (*k.shape[:3], k.shape[3] // 4, k.shape[4])
+    assert pool_sized_movers(text, shard) == []
+    assert "scatter" in text
