@@ -1,5 +1,6 @@
 """Kernels: the least time the window's prefill steps can take on this chip
-(lib/roofline.py: the layers' weights read once a step over the HBM peak, or
+(the family's count, benchmark/roofline/<family>.py through lib/roofline.py:
+for a dense model the layers' weights read once a step over the HBM peak, or
 two operations per weight per token over the bf16 peak; the larger) over the
 device time of the prefill program in the profiler trace, summed over the
 window's `prefill_chunk` steps.  %."""
@@ -13,6 +14,6 @@ def read(run):
     if not device:
         return None
     model, peaks = run["config"]["model"], run["peaks"]
-    floor = sum(roofline.prefill_step_floor_s(model, peaks, e["tokens"])[0]
-                for e, _ in timed)
+    floor_s = roofline.family(run["config"]).prefill_step_floor_s
+    floor = sum(floor_s(model, peaks, e["tokens"])[0] for e, _ in timed)
     return 100.0 * floor / device

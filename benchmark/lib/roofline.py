@@ -1,33 +1,37 @@
-"""Bytes and operations a prefill step needs, from shapes alone.  Kept with
-the benchmark so that no PR that claims a gain can change the yardstick.
+"""The roofline count belongs to the architecture family: the file
+benchmark/roofline/<family>.py, found by the configuration's `roofline` key
+or, without one, its `checkpoint` key.  Kept with the benchmark so that no PR
+that claims a gain can change the yardstick.
 
-Counted: every layer's projection and MLP weights, read once per step
-whatever the chunk, and two operations per such weight per token of the
-chunk.  NOT counted: attention's QK^T and PV (the step events carry no
-context length; about 5% of the dense operations at 2048 tokens of context),
-the keys and values read, the output head (only a prompt's last chunk
-samples), the embedding gather, activations, page tables.  So the figure is
-a floor, and a share of it cannot pass 100% by over-counting."""
+The file's contract (README.md has it in full): `prefill_step_floor_s(model,
+peaks, tokens) -> (seconds, "memory" | "compute")`, the least time one
+prefill step over `tokens` prompt tokens can take on this chip, from the
+bytes that EVERY such step must read and the operations that EVERY token
+must do, never more.  What only some steps or some tokens need is left out,
+so the figure is a floor and a share of it cannot pass 100% by
+over-counting."""
 
-BF16 = 2
+import os
 
-
-def head_dim(model):
-    return model.get("head_dim") or (
-        model["hidden_size"] // model["num_attention_heads"])
-
-
-def layer_weight_params(model):
-    H, I = model["hidden_size"], model["intermediate_size"]
-    q = model["num_attention_heads"] * head_dim(model)
-    kv = model["num_key_value_heads"] * head_dim(model)
-    return H * q + 2 * H * kv + q * H + 3 * H * I
+from . import checkpoint
+from .procs import RunFailure
 
 
-def prefill_step_floor_s(model, peaks, tokens):
-    """The least time one prefill step over `tokens` prompt tokens can take
-    on this chip, and which bound sets it."""
-    params = model["num_hidden_layers"] * layer_weight_params(model)
-    t_mem = BF16 * params / peaks["hbm_bytes_per_s"]
-    t_flop = 2 * tokens * params / peaks["bf16_flops_per_s"]
-    return max(t_mem, t_flop), ("memory" if t_mem >= t_flop else "compute")
+def family_name(config):
+    return config.get("roofline", config["checkpoint"])
+
+
+def family(config):
+    """The family's module; a `RunFailure` that names the file it lacks."""
+    name = family_name(config)
+    try:
+        mod = checkpoint.load_module("roofline", name)
+    except FileNotFoundError:
+        raise RunFailure(
+            f"{config.get('name', 'the configuration')}: no roofline count "
+            f"benchmark/roofline/{name}.py for its family (contract: "
+            "benchmark/README.md)") from None
+    if not callable(getattr(mod, "prefill_step_floor_s", None)):
+        raise RunFailure(f"benchmark/roofline/{name}.py has no "
+                         "prefill_step_floor_s(model, peaks, tokens)")
+    return mod

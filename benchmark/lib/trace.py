@@ -23,7 +23,6 @@ tracer, which is what makes the file hundreds of MB."""
 import json
 import os
 import re
-import subprocess
 import sys
 
 OPS_LINE = "XLA Ops"
@@ -71,15 +70,15 @@ def load_xplane(path, wall_minus_mono_ns, t0_ns, t1_ns):
             "summary": summary, "profile_start_unix_ns": base}
 
 
-def load_in_child(path, out_path, wall_minus_mono_ns, t0_ns, t1_ns, env):
-    """Parent-side: run `load_xplane` in a child held to the CPU backend."""
-    env = dict(env, JAX_PLATFORMS="cpu")
-    p = subprocess.run(
+def load_in_child(ps, path, out_path, wall_minus_mono_ns, t0_ns, t1_ns):
+    """Parent-side: run `load_xplane` in a child of the run's process owner
+    (lib/procs.py `ProcSet`), held to the CPU backend."""
+    rc, _, err = ps.run(
         [sys.executable, os.path.abspath(__file__), path, out_path,
-         str(wall_minus_mono_ns), str(t0_ns), str(t1_ns)],
-        env=env, capture_output=True, text=True, timeout=900)
-    if p.returncode != 0:
-        raise RuntimeError(f"trace reduction failed: {p.stderr[-1500:]}")
+         str(wall_minus_mono_ns), str(t0_ns), str(t1_ns)], "trace-loader",
+        {"JAX_PLATFORMS": "cpu"}, timeout=900)
+    if rc != 0:
+        raise RuntimeError(f"trace reduction failed: {err[-1500:]}")
     with open(out_path) as f:
         return json.load(f)
 

@@ -15,9 +15,12 @@ real HTTP traffic against the frontend, stops every child, and prints the
 result as the LAST line of its standard output.
 
 Earlier lines are JSON notes ({"note": ...}) for a reader; see README.md.
-No TPU, an unknown device kind, a dead child before the window or a broken
-harness: exit code 1 and no result line.  `--rehearse-cpu` walks the same
-control flow on the CPU backend with the tiny cells of
+No TPU, an unknown device kind, a dead child before the window, a
+configuration the program cannot load or a broken harness: exit code 1, one
+`BENCHMARK RUN FAILED:` line and no result line.  Every child is started
+through one `lib/procs.py` `ProcSet`, and none outlives this process,
+whether it ends, fails, is signalled or is killed.  `--rehearse-cpu` walks
+the same control flow on the CPU backend with the tiny cells of
 benchmark/tests/data/REHEARSAL.json; it never prints a result line and
 exits 2.
 """
@@ -32,15 +35,17 @@ import glob  # noqa: E402
 import hashlib  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
-import subprocess  # noqa: E402
+import shutil  # noqa: E402
 import sys  # noqa: E402
+import traceback  # noqa: E402
 
 BENCH = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH)
 sys.path.insert(0, BENCH)
 
 from lib import checkpoint as ckpt  # noqa: E402
-from lib import collect, loadgen, probes, procs, stats, traffic  # noqa: E402
+from lib import collect, loadgen, probes, procs, roofline, stats  # noqa: E402
+from lib import traffic  # noqa: E402
 from lib import peaks as peaks_table  # noqa: E402
 from lib import trace as trace_lib  # noqa: E402
 from lib.procs import RunFailure  # noqa: E402
@@ -81,44 +86,56 @@ def child_env(**extra):
     return env
 
 
-def require_tpu(chips):
+def family_files(config):
+    """What the configuration's family brings, each found by name; a missing
+    one fails the run here, before any child, and not after the window."""
+    for kind_dir, name in (("checkpoints", config["checkpoint"]),
+                           ("reference", config["reference"])):
+        if not os.path.exists(os.path.join(BENCH, kind_dir, name + ".py")):
+            raise RunFailure(f"{config['name']}: its family has no "
+                             f"benchmark/{kind_dir}/{name}.py")
+    roofline.family(config)
+
+
+def require_tpu(ps, chips):
     """Asked of a child that exits before any worker starts; only a run
     that has to write the checkpoint pays for it (later runs learn the
     device from the worker's DEVICE line)."""
     code = ("import json, jax; d = jax.devices(); print(json.dumps("
             "{'platform': d[0].platform, 'count': len(d)}))")
-    p = subprocess.run([sys.executable, "-c", code],
-                       env=child_env(JAX_PLATFORMS="tpu"),
-                       capture_output=True, text=True, timeout=300)
-    if p.returncode != 0:
-        raise RunFailure("JAX finds no TPU here: " + p.stderr[-400:])
-    found = json.loads(p.stdout.strip().splitlines()[-1])
+    rc, out, err = ps.run([sys.executable, "-c", code], "device-probe",
+                          {"JAX_PLATFORMS": "tpu"}, timeout=300)
+    if rc != 0:
+        raise RunFailure("JAX finds no TPU here: " + err[-400:])
+    found = json.loads(out.strip().splitlines()[-1])
     if found["platform"] != "tpu" or found["count"] < chips:
         raise RunFailure(f"this cell needs {chips} TPU chip(s); JAX finds "
                          f"{found}")
 
 
-def ensure_checkpoint(config, config_path, chips, rehearse):
+def ensure_checkpoint(ps, config, config_path, chips, rehearse):
     path = os.path.join(CACHE, "ckpt",
                         f"{config['name']}-{ckpt.checkpoint_key(config)}")
     done = os.path.join(path, ".complete")
     if os.path.exists(done):
         return path, 0.0
     if not rehearse:
-        require_tpu(chips)
+        require_tpu(ps, chips)
     t0 = time.monotonic()
-    p = subprocess.run(
+    rc, _, err = ps.run(
         [sys.executable, os.path.join(BENCH, "lib", "checkpoint.py"),
-         config_path, path], env=child_env(JAX_PLATFORMS="cpu"),
-        capture_output=True, text=True)
-    if p.returncode != 0:
-        raise RunFailure("checkpoint writer failed: " + p.stderr[-1500:])
+         config_path, path], "checkpoint-writer", {"JAX_PLATFORMS": "cpu"},
+        timeout=1800)
+    if rc != 0:
+        raise RunFailure("checkpoint writer failed: " + err[-1500:])
     return path, time.monotonic() - t0
 
 
-def start_reference(config, config_path, ckpt_dir, depth):
+def start_reference(ps, config, config_path, ckpt_dir, depth):
     """The plain reference's answers to the probes: cached in the checkout,
-    else computed by a child on the host CPU while the worker loads."""
+    else computed by a child on the host CPU while the worker loads.  The
+    child writes the file by `os.replace` at its end, so a child that is
+    stopped with a failed run leaves nothing a later run would trust."""
     h = hashlib.sha256(f"{os.path.basename(ckpt_dir)}:{depth}".encode())
     for f in (os.path.join(BENCH, "reference", config["reference"] + ".py"),
               os.path.join(BENCH, "lib", "probes.py"),
@@ -128,13 +145,10 @@ def start_reference(config, config_path, ckpt_dir, depth):
                        f"{config['name']}-{h.hexdigest()[:16]}.json")
     if os.path.exists(out):
         return out, None
-    os.makedirs(os.path.dirname(out), exist_ok=True)
-    err = open(out + ".log", "w")
-    child = subprocess.Popen(
+    child = ps.spawn(
         [sys.executable, os.path.join(BENCH, "lib", "reference_child.py"),
-         config_path, ckpt_dir, out, str(depth)],
-        env=child_env(JAX_PLATFORMS="cpu"), stdout=err,
-        stderr=subprocess.STDOUT)
+         config_path, ckpt_dir, out, str(depth)], "reference", out + ".log",
+        {"JAX_PLATFORMS": "cpu"})
     return out, child
 
 
@@ -245,7 +259,7 @@ def load_reader(kind_dir, metric_name):
                          ) from None
 
 
-def reduce_trace(xprof_dir, run, log_dir):
+def reduce_trace(ps, xprof_dir, run, log_dir):
     files = glob.glob(os.path.join(xprof_dir, "plugins", "profile", "*",
                                    "*.xplane.pb"))
     if not files:
@@ -254,13 +268,14 @@ def reduce_trace(xprof_dir, run, log_dir):
         raise RunFailure("no clock anchor from /events.json")
     wall_ns, mono_ns = run["clock_anchor"]
     t0, t1 = int(run["t0"] * 1e9), int(run["t1"] * 1e9)
-    compact = trace_lib.load_in_child(
-        max(files, key=os.path.getsize), os.path.join(log_dir, "trace.json"),
-        wall_ns - mono_ns, t0, t1, child_env())
-    note("trace", file_bytes=os.path.getsize(max(files, key=os.path.getsize)),
-         planes=compact["summary"])
-    subprocess.run(["rm", "-rf", xprof_dir], check=False)  # hundreds of MB
     try:
+        compact = trace_lib.load_in_child(
+            ps, max(files, key=os.path.getsize),
+            os.path.join(log_dir, "trace.json"), wall_ns - mono_ns, t0, t1)
+        note("trace",
+             file_bytes=os.path.getsize(max(files, key=os.path.getsize)),
+             planes=compact["summary"])
+        shutil.rmtree(xprof_dir, ignore_errors=True)  # hundreds of MB
         return trace_lib.reduce(compact, t0, t1, run["events"])
     except RuntimeError as e:
         raise RunFailure(str(e)) from None
@@ -277,8 +292,13 @@ async def serve_and_measure(stack, cell, config, mix, args, ckpt_dir,
         # the worker shuts down, and the reduction cuts it to the window
         env.update(DYN_TPU_XPROF_STEPS=str(10 ** 9),
                    DYN_TPU_XPROF_DIR=xprof_dir)
-    device, secs = stack.start_worker(
-        ckpt_dir, worker_flags(config, args.rehearse_cpu), env, timeout=900)
+    flags = worker_flags(config, args.rehearse_cpu)
+    try:
+        device, secs = stack.start_worker(ckpt_dir, flags, env, timeout=900)
+    except RunFailure as e:
+        raise RunFailure(
+            f"cell {cell['name']}, configuration {config['name']}: the "
+            f"program's worker did not come up on it: {e}") from None
     peaks = check_device(device, cell["chips"], args.rehearse_cpu)
     note("worker", seconds_to_ready=secs, device=device)
     deadline = time.monotonic() + 60
@@ -293,6 +313,7 @@ async def serve_and_measure(stack, cell, config, mix, args, ckpt_dir,
         t0 = time.monotonic()
         while ref_child.poll() is None:
             await asyncio.sleep(0.5)
+        stack.ps.forget(ref_child)
         if ref_child.returncode != 0:
             raise RunFailure("the reference failed: "
                              + procs.log_tail(ref_path + ".log"))
@@ -311,69 +332,86 @@ async def serve_and_measure(stack, cell, config, mix, args, ckpt_dir,
     return run
 
 
-def main():
+def parse_args():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--seconds", type=float, default=None)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     ap.add_argument("--rehearse-cpu", action="store_true")
-    args = ap.parse_args()
+    return ap.parse_args()
 
-    spec_path = (os.path.join(BENCH, "tests", "data", "REHEARSAL.json")
-                 if args.rehearse_cpu else os.path.join(ROOT, "BENCHMARK.json"))
-    stack = None
+
+def main():
+    """Exit code 0 and a result line, or one failure line and exit code 1
+    (a rehearsal: 2); by either way, and by a signal, with no child left."""
+    args = parse_args()
+    ps = procs.ProcSet(child_env())
     try:
-        if not os.path.isdir(os.path.join(ROOT, "dynamo_tpu")):
-            raise RunFailure("the system under test (dynamo_tpu/) is not in "
-                             "this checkout")
-        with open(spec_path) as f:
-            spec = json.load(f)
-        cell = by_name(spec["workloads"], args.workload, "workload")
-        entry = by_name(spec["configs"], cell["config"], "configuration")
-        config_path = os.path.join(ROOT, entry["file"])
-        with open(config_path) as f:
-            config = json.load(f)
-        mix = traffic.load_mix(
-            os.path.join(BENCH, "traffic", cell["traffic"] + ".json"))
-        if args.seconds is None:
-            args.seconds = float(spec["run_seconds"])
-        log_dir = os.path.join(
-            CACHE, "logs", f"{args.workload}-{args.seed}-{args.trace}")
-        xprof_dir = os.path.join(log_dir, "xprof")
-        subprocess.run(["rm", "-rf", xprof_dir], check=False)
-        os.makedirs(log_dir, exist_ok=True)
-
-        ckpt_dir, wrote = ensure_checkpoint(config, config_path, cell["chips"],
-                                            args.rehearse_cpu)
-        note("checkpoint", path=os.path.relpath(ckpt_dir, ROOT),
-             seconds_to_write=wrote)
-        ref_path, ref_child = start_reference(
-            config, config_path, ckpt_dir,
-            probes.greedy_depth(traffic.max_output_len(mix)))
-        stack = procs.Stack(log_dir, child_env(),
-                            config.get("router_mode", "round_robin"))
-        run = asyncio.run(serve_and_measure(
-            stack, cell, config, mix, args, ckpt_dir, ref_path, ref_child,
-            xprof_dir))
-        died = stack.ps.dead()
-        end_metrics = run["metrics1"]
-        stack.stop_worker()  # the trace, when armed, is written now
-        stack.close()
-        stack = None
-        if args.trace:
-            try:
-                run["trace"] = reduce_trace(xprof_dir, run, log_dir)
-            except RunFailure as e:
-                if not args.rehearse_cpu:  # the CPU backend has no device plane
-                    raise
-                note("trace", rehearsal_without_device_plane=str(e))
+        with ps.guard():
+            return run_cell(args, ps)
     except RunFailure as e:
         print(f"BENCHMARK RUN FAILED: {e}", file=sys.stderr, flush=True)
-        return 1
-    finally:
-        if stack is not None:
-            stack.close()
+    except Exception as e:  # a broken harness or reader is a run without a
+        # result like any other; its traceback goes beside the logs
+        os.makedirs(os.path.join(CACHE, "logs"), exist_ok=True)
+        where = os.path.join(CACHE, "logs", "last_failure.txt")
+        with open(where, "w") as f:
+            traceback.print_exc(file=f)
+        frame = traceback.extract_tb(e.__traceback__)[-1]
+        print(f"BENCHMARK RUN FAILED: {type(e).__name__}: {e} at "
+              f"{os.path.relpath(frame.filename, ROOT)}:{frame.lineno} "
+              f"(traceback: {os.path.relpath(where, ROOT)})",
+              file=sys.stderr, flush=True)
+    return 1
+
+
+def run_cell(args, ps):
+    spec_path = (os.path.join(BENCH, "tests", "data", "REHEARSAL.json")
+                 if args.rehearse_cpu else os.path.join(ROOT, "BENCHMARK.json"))
+    if not os.path.isdir(os.path.join(ROOT, "dynamo_tpu")):
+        raise RunFailure("the system under test (dynamo_tpu/) is not in "
+                         "this checkout")
+    with open(spec_path) as f:
+        spec = json.load(f)
+    cell = by_name(spec["workloads"], args.workload, "workload")
+    entry = by_name(spec["configs"], cell["config"], "configuration")
+    config_path = os.path.join(ROOT, entry["file"])
+    with open(config_path) as f:
+        config = json.load(f)
+    family_files(config)
+    mix = traffic.load_mix(
+        os.path.join(BENCH, "traffic", cell["traffic"] + ".json"))
+    if args.seconds is None:
+        args.seconds = float(spec["run_seconds"])
+    log_dir = os.path.join(
+        CACHE, "logs", f"{args.workload}-{args.seed}-{args.trace}")
+    xprof_dir = os.path.join(log_dir, "xprof")
+    shutil.rmtree(xprof_dir, ignore_errors=True)
+    os.makedirs(log_dir, exist_ok=True)
+
+    ckpt_dir, wrote = ensure_checkpoint(ps, config, config_path,
+                                        cell["chips"], args.rehearse_cpu)
+    note("checkpoint", path=os.path.relpath(ckpt_dir, ROOT),
+         seconds_to_write=wrote)
+    ref_path, ref_child = start_reference(
+        ps, config, config_path, ckpt_dir,
+        probes.greedy_depth(traffic.max_output_len(mix)))
+    stack = procs.Stack(ps, log_dir, config.get("router_mode", "round_robin"))
+    run = asyncio.run(serve_and_measure(
+        stack, cell, config, mix, args, ckpt_dir, ref_path, ref_child,
+        xprof_dir))
+    died = ps.dead()
+    end_metrics = run["metrics1"]
+    stack.stop_worker()  # the trace, when armed, is written now
+    ps.stop()
+    if args.trace:
+        try:
+            run["trace"] = reduce_trace(ps, xprof_dir, run, log_dir)
+        except RunFailure as e:
+            if not args.rehearse_cpu:  # the CPU backend has no device plane
+                raise
+            note("trace", rehearsal_without_device_plane=str(e))
 
     w = stats.window(run["records"], run["t0"], run["t1"])
     attempted, failed = len(w["measured"]), len(w["measured"]) - len(w["ok"])
@@ -405,10 +443,8 @@ def main():
         for m in spec["end_to_end"]:
             if applies(m, args.workload):
                 if e2e.get(m["name"]) is None:
-                    print(f"BENCHMARK RUN FAILED: no {m['name']}: "
-                          f"{failed} of {attempted} requests failed",
-                          file=sys.stderr, flush=True)
-                    return 1
+                    raise RunFailure(f"no {m['name']}: {failed} of "
+                                     f"{attempted} requests failed")
                 metrics[m["name"]] = {"value": e2e[m["name"]],
                                       "unit": m["unit"]}
     device = dict(run["device"], memory_peak_bytes=max(

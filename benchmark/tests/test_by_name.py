@@ -31,9 +31,11 @@ def test_every_entry_of_the_spec_has_its_files():
         assert cfg["name"] == c["name"] and cfg["source"] == c["source"]
         assert sorted(cfg["reduced"]) == sorted(c["reduced"])
         for kind, key in (("checkpoints", "checkpoint"),
-                          ("reference", "reference")):
+                          ("reference", "reference"),
+                          ("roofline", "checkpoint")):
             assert os.path.exists(
-                os.path.join(BENCH, kind, cfg[key] + ".py"))
+                os.path.join(BENCH, kind, cfg.get(kind, cfg[key]) + ".py"))
+        bench_run.family_files(cfg)
         assert not set(cfg["worker_flags"]) & set(bench_run.POLICY_FLAGS)
     for w in s["workloads"]:
         assert os.path.exists(
@@ -72,7 +74,10 @@ def test_an_unknown_device_kind_is_an_error():
 def test_a_new_config_mix_loop_metric_and_cell_need_no_edit(tmp_path):
     """In a scratch copy: add a configuration, a mix, a loop, an end-to-end
     metric, a per-layer metric and a cell as new files plus entries of
-    BENCHMARK.json; the harness finds them."""
+    BENCHMARK.json; the harness finds them.  Then a second FAMILY: a
+    configuration without `intermediate_size` (an expert model has only an
+    expert width) with its own checkpoints/, reference/ and roofline/ file,
+    read by the roofline reader that is there."""
     bench = tmp_path / "benchmark"
     shutil.copytree(BENCH, bench, ignore=shutil.ignore_patterns(
         ".cache", "__pycache__"))
@@ -92,7 +97,42 @@ def test_a_new_config_mix_loop_metric_and_cell_need_no_edit(tmp_path):
         "def read(run):\n    return float(len(run['records']))\n")
     (bench / "end_to_end" / "requests_per_s.py").write_text(
         "def read(w):\n    return len(w['ok']) / (w['t1'] - w['t0'])\n")
+    expert = json.loads(json.dumps(cfg))
+    expert.update(name="scratch-experts", checkpoint="expert_like",
+                  reference="expert_like")
+    del expert["model"]["intermediate_size"]
+    expert["model"].update(model_type="scratch_moe", moe_intermediate_size=768,
+                           num_experts=64, num_experts_per_tok=6)
+    (bench / "configs" / "scratch-experts.json").write_text(json.dumps(expert))
+    (bench / "checkpoints" / "expert_like.py").write_text(
+        "def tensors(model):\n"
+        "    yield ('model.embed_tokens.weight', (model['vocab_size'],"
+        " model['hidden_size']), 'random')\n")
+    (bench / "reference" / "expert_like.py").write_text(
+        "LOGPROB_TOL, TIE_MARGIN = 0.06, 0.06\n\n"
+        "def tail_logprobs(read, model, batches, n_last):\n"
+        "    raise NotImplementedError\n")
+    (bench / "roofline" / "expert_like.py").write_text(
+        "def prefill_step_floor_s(model, peaks, tokens):\n"
+        "    # the experts a token uses, the experts a step can touch\n"
+        "    H, E = model['hidden_size'], model['moe_intermediate_size']\n"
+        "    used = min(model['num_experts'],"
+        " tokens * model['num_experts_per_tok'])\n"
+        "    t_mem = 2 * model['num_hidden_layers'] * used * 3 * H * E"
+        " / peaks['hbm_bytes_per_s']\n"
+        "    t_flop = 2 * tokens * model['num_hidden_layers']"
+        " * model['num_experts_per_tok'] * 3 * H * E"
+        " / peaks['bf16_flops_per_s']\n"
+        "    return max(t_mem, t_flop), 'memory' if t_mem >= t_flop"
+        " else 'compute'\n")
     s = spec()
+    s["configs"].append({"name": "scratch-experts", "source": cfg["source"],
+                         "file": "benchmark/configs/scratch-experts.json",
+                         "reduced": ["num_hidden_layers"], "why": "scratch"})
+    s["workloads"].append({"name": "scratch-experts.docqa-1tok",
+                           "config": "scratch-experts",
+                           "traffic": "docqa-1tok", "chips": 1,
+                           "why": "scratch"})
     s["configs"].append({"name": "scratch-model", "source": cfg["source"],
                          "file": "benchmark/configs/scratch-model.json",
                          "reduced": ["num_hidden_layers"], "why": "scratch"})
@@ -120,11 +160,37 @@ def test_a_new_config_mix_loop_metric_and_cell_need_no_edit(tmp_path):
         "sent = asyncio.run(loop.run({'mix': mix}))['sent']\n"
         "v = run.load_reader('layer_metrics', 'loadgen.sends')({'records': [0] * n})\n"
         "e = run.load_reader('end_to_end', 'requests_per_s')({'ok': [0] * n, 't0': 0, 't1': 10})\n"
-        "print(json.dumps([cfg['name'], n, sent, v, e]))\n")
+        "print(json.dumps([cfg['name'], n, sent, v, e]))\n"
+        # the second family: every per-layer metric without a `workloads`
+        # list is read in its cell too, the roofline one from its own count
+        "from lib import peaks, roofline\n"
+        "cell = run.by_name(s['workloads'], 'scratch-experts.docqa-1tok', 'w')\n"
+        "entry = run.by_name(s['configs'], cell['config'], 'configuration')\n"
+        "cfg = json.load(open(entry['file']))\n"
+        "run.family_files(cfg)\n"
+        "mods = [[(10, 50_000_010, 'jit_prefill_step')]]\n"
+        "fake = {'t0': 0.0, 't1': 1.0, 'config': cfg, 'records': [],\n"
+        "        'peaks': peaks.peaks_for('TPU v5 lite'),\n"
+        "        'trace': {'modules': mods}, 'events': [\n"
+        "    {'kind': 'prefill_chunk', 't_ns': 0, 'dur_ns': 60_000_000,\n"
+        "     'batch': 1, 'tokens': 512, 'ring': 'engine'}]}\n"
+        "read = [m['name'] for m in s['per_layer']\n"
+        "        if run.applies(m, cell['name'])\n"
+        "        and run.load_reader('layer_metrics', m['name'])(fake) is not None]\n"
+        "share = run.load_reader('layer_metrics', 'kernel.prefill_step_roofline')(fake)\n"
+        "print(json.dumps([cfg['name'], roofline.family_name(cfg), read, share]))\n")
     out = subprocess.run([sys.executable, "-c", code], cwd=tmp_path,
                          capture_output=True, text=True, check=True)
-    assert json.loads(out.stdout.splitlines()[-1]) == [
-        "scratch-model", 20, 20, 20.0, 2.0]
+    lines = out.stdout.splitlines()
+    assert json.loads(lines[-2]) == ["scratch-model", 20, 20, 20.0, 2.0]
+    name, fam, read, share = json.loads(lines[-1])
+    assert (name, fam) == ("scratch-experts", "expert_like")
+    assert "kernel.prefill_step_roofline" in read
+    # 512 tokens x 6 experts each can touch all 64: 14 layers x 64 experts
+    # x 3 x 3584 x 768 weights in bf16 over 819 GB/s (18.1 ms; the operations
+    # of 6 experts a token take 3.6), against 50 ms on the device
+    want = 2 * 14 * 64 * 3 * 3584 * 768 / 819e9
+    assert 0 < share < 100 and share == pytest.approx(100 * want / 50e-3)
     after = {p: checkpoint.file_sha(os.path.join(dp, p))
              for dp, _, fs in os.walk(bench) for p in fs if p in before}
     assert after == before  # no existing file was touched

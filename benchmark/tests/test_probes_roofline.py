@@ -108,10 +108,24 @@ def test_reference_tail_matches_one_position_at_a_time():
     assert np.allclose(np.exp(tb).sum(-1), 1.0, atol=1e-4)
 
 
+def old_prefill_step_floor_s(model, peaks, tokens):
+    """lib/roofline.py's count as it stood before the count moved behind
+    benchmark/roofline/<family>.py (PR 29), frozen here."""
+    H, I = model["hidden_size"], model["intermediate_size"]
+    hd = model.get("head_dim") or H // model["num_attention_heads"]
+    q = model["num_attention_heads"] * hd
+    kv = model["num_key_value_heads"] * hd
+    params = model["num_hidden_layers"] * (
+        H * q + 2 * H * kv + q * H + 3 * H * I)
+    t_mem = 2 * params / peaks["hbm_bytes_per_s"]
+    t_flop = 2 * tokens * params / peaks["bf16_flops_per_s"]
+    return max(t_mem, t_flop), ("memory" if t_mem >= t_flop else "compute")
+
+
 def test_layer_weights_from_shapes():
     cfg = model("qwen2.5-7b-h14")
     m = cfg["model"]
-    assert roofline.layer_weight_params(m) == 233046016
+    assert roofline.family(cfg).layer_weight_params(m) == 233046016
     # the checkpoint = the layers' matrices + their small vectors + embedding
     # and head; the floor counts the matrices alone
     vectors = 14 * (2 * 3584 + 3584 + 2 * 512) + 3584
@@ -122,10 +136,47 @@ def test_layer_weights_from_shapes():
 @pytest.mark.parametrize("tokens,bound", [(16, "memory"), (64, "memory"),
                                           (512, "compute")])
 def test_prefill_floor_from_shapes(tokens, bound):
-    m = model("qwen2.5-7b-h14")["model"]
+    cfg = model("qwen2.5-7b-h14")
+    m = cfg["model"]
     p = peaks.peaks_for("TPU v5 lite")
-    secs, which = roofline.prefill_step_floor_s(m, p, tokens)
+    secs, which = roofline.family(cfg).prefill_step_floor_s(m, p, tokens)
     params = 14 * 233046016
     assert which == bound
     assert secs == pytest.approx(max(2 * params / 819e9,
                                      2 * tokens * params / 197e12))
+
+
+def test_the_familys_file_gives_the_old_float_for_every_step():
+    """The count moved, the number did not: for every chunk the cell's
+    steps can have (1..4096 tokens) the family's file returns the float the
+    old lib/roofline.py returned, and the reader, over a run made of the
+    recorded v5e trace's program executions taken as prefill steps of every
+    bucket, the same share to the last bit."""
+    cfg = model("qwen2.5-7b-h14")
+    m, p = cfg["model"], peaks.peaks_for("TPU v5 lite")
+    assert roofline.family_name(cfg) == "llama_like"
+    new = roofline.family(cfg).prefill_step_floor_s
+    for tokens in range(1, 4097):
+        assert new(m, p, tokens) == old_prefill_step_floor_s(m, p, tokens)
+
+    from lib import checkpoint, trace
+    with open(os.path.join(BENCH, "tests", "data",
+                           "recorded_trace.json")) as f:
+        rec = json.load(f)
+    t0, t1 = rec["window_ns"]
+    steps = [e for e in rec["step_events"] if t0 <= e["t_ns"]
+             and e["t_ns"] + e["dur_ns"] <= t1]
+    buckets = (16, 32, 64, 128, 256, 512)
+    events = [dict(e, kind="prefill_chunk", batch=1,
+                   tokens=buckets[i % len(buckets)] - (i % 3))
+              for i, e in enumerate(steps)]
+    run = {"t0": t0 / 1e9, "t1": t1 / 1e9, "events": events, "config": cfg,
+           "peaks": p, "trace": trace.reduce(rec, t0, t1, events)}
+    read = checkpoint.load_module(
+        "layer_metrics", "kernel.prefill_step_roofline").read
+    from lib import runview
+    timed = runview.prefill_steps(run)
+    assert len(timed) >= 10
+    want = 100.0 * sum(old_prefill_step_floor_s(m, p, e["tokens"])[0]
+                       for e, _ in timed) / sum(s for _, s in timed)
+    assert read(run) == want
