@@ -97,6 +97,10 @@ class ForwardPassMetrics:
     kv_pages_cached: int = 0
     kv_pages_free: int = 0
     prefix_evictions_total: int = 0
+    # plain prefill steps dispatched, and those of them dispatched while
+    # the step before was still unfetched (`_run_prefill`)
+    prefill_steps_total: int = 0
+    prefill_steps_overlapped_total: int = 0
     # device-resident decode loop: chains run and blocks dispatched by
     # the continuous path (blocks/chains >> decode_chain means the open
     # horizon is actually engaging)
@@ -124,6 +128,24 @@ class ForwardPassMetrics:
     resumed_total: int = 0
     parked_seqs: int = 0
     parked_pages: int = 0
+
+
+@dataclass
+class _PrefillStep:
+    """A prefill step between its two halves (`JaxEngine._run_prefill`):
+    what `_prefill_dispatch` committed and `_prefill_consume` needs."""
+
+    items: List[PrefillItem]
+    item_rows: List[Optional[PrefillItem]]
+    seq_rows: List[Optional[Sequence]]
+    seqs: List[Sequence]
+    with_top: bool
+    packed_d: Any  # the packed result, on the device
+    fused: list  # decode dispatches fused behind it ([]: none)
+    t0_ns: int  # ring clock: slice start, the jitted call, dispatch done
+    t_call_ns: int
+    t_sent_ns: int
+    attrs: dict  # the slice's attributes known at dispatch
 
 
 # static top-k width for OpenAI `top_logprobs` responses (API max is 20)
@@ -1768,6 +1790,15 @@ class JaxEngine:
         # `loop_yield` slice starts there)
         self._step_t0_ns: Optional[int] = None
         self._step_end_ns: Optional[int] = None
+        # the end of the last step slice recorded: `first_token.own_us`
+        # counts a step from here on where its slice opened earlier
+        self._slice_end_ns = 0
+        # the one prefill step dispatched and not fetched (`_run_prefill`).
+        # Written on the step thread, read by the pump between executor
+        # calls (the two never run at once)
+        self._inflight: Optional[_PrefillStep] = None
+        self.prefill_steps_total = 0
+        self.prefill_steps_overlapped_total = 0
         self._ttft_turn_wait_ms_total = 0.0
         self._evictions_before_reset = 0
 
@@ -2266,6 +2297,9 @@ class JaxEngine:
             kv_pages_free=self.pool.free_pages,
             prefix_evictions_total=(self._evictions_before_reset
                                     + self.pool.evictions_total),
+            prefill_steps_total=self.prefill_steps_total,
+            prefill_steps_overlapped_total=(
+                self.prefill_steps_overlapped_total),
             decode_cc_blocks_total=self._cc_blocks_total,
             decode_cc_chains_total=self._cc_chains_total,
             decode_cc_fallout_total=dict(self._cc_fallout_by_reason),
@@ -2456,10 +2490,11 @@ class JaxEngine:
     async def shutdown(self) -> None:
         self._closed = True
         self._wake.set()
+        if self._pump_task:
+            # the pump consumes a step in flight on its way out
+            await asyncio.gather(self._pump_task, return_exceptions=True)
         if self._xprof_started_at is not None and not self._xprof_done:
             self._xprof_stop()
-        if self._pump_task:
-            await asyncio.gather(self._pump_task, return_exceptions=True)
         # the pump exits the moment _closed is set, so an abort queued
         # during teardown (generate()'s finally on a cancelled stream)
         # never reaches the scheduler and its sequence keeps its page
@@ -2550,6 +2585,20 @@ class JaxEngine:
                         self._deliver(seq, [], "cancelled")
         return self.scheduler.schedule()
 
+    async def _consume_inflight(self, loop) -> None:
+        """Fetch and deliver the prefill step in flight, if there is one:
+        before anything that is not a further plain prefill step."""
+        step = self._inflight
+        if step is None:
+            return
+        try:
+            await loop.run_in_executor(
+                self._executor, self._run_step, "prefill_chunk",
+                self._prefill_consume, step)
+        except Exception:  # noqa: BLE001
+            logger.exception("engine step failed; resetting KV state")
+            self._recover_after_error()
+
     async def _pump(self) -> None:
         loop = asyncio.get_running_loop()
         while not self._closed:
@@ -2558,6 +2607,9 @@ class JaxEngine:
                 # asyncio.sleep(0) in which the worker's other coroutines ran
                 self.events.record("loop_yield", t0_ns=self._step_end_ns)
                 self._step_end_ns = None
+            if self._pending_ops or (self.tiered is not None
+                                     and self.tiered.pending_offloads):
+                await self._consume_inflight(loop)
             # drain offload queue (device→host copies, KVBM)
             if self.tiered is not None and self.tiered.pending_offloads:
                 try:
@@ -2597,6 +2649,15 @@ class JaxEngine:
                                "without admission; retry later",
                     "retry_after_s": retry,
                 })
+            if self._inflight is not None and plan.kind != "prefill":
+                # nothing to put behind the step in flight: its result
+                # first, then a plan that knows it (this one was made
+                # without the step's tokens and without the pages its
+                # finished sequences give back; what it admitted or
+                # reserved stays with the sequences)
+                await self._consume_inflight(loop)
+                await asyncio.sleep(0)
+                continue
             if plan.kind == "idle":
                 if not (self.scheduler.has_work or self._pending_adds
                         or self._pending_aborts):
@@ -2622,7 +2683,9 @@ class JaxEngine:
                 else:
                     await asyncio.sleep(0)
                 continue
-            if not self._xprof_done:
+            if not self._xprof_done and self._xprof_started_at is None:
+                # a capture starts between whole steps
+                await self._consume_inflight(loop)
                 # lint: allow(blocking-in-async): one-time profiler capture setup, not steady-state
                 self._xprof_start()
             if plan.kind == "prefill":
@@ -2638,9 +2701,11 @@ class JaxEngine:
                 logger.exception("engine step failed; resetting KV state")
                 self._recover_after_error()
             self._step_count += 1
-            if not self._xprof_done:
-                self._xprof_stop_if_due()
+            if not self._xprof_done and self._xprof_due():
+                await self._consume_inflight(loop)  # and ends between them
+                self._xprof_stop()
             await asyncio.sleep(0)
+        await self._consume_inflight(loop)
 
     # -- xprof capture (DYN_TPU_XPROF_STEPS, POST /debug/xprof) --------------- #
 
@@ -2686,11 +2751,10 @@ class JaxEngine:
             self._xprof_done = True
             logger.exception("xprof start failed; capture disabled")
 
-    def _xprof_stop_if_due(self) -> None:
-        if (self._xprof_started_at is not None
+    def _xprof_due(self) -> bool:
+        return (self._xprof_started_at is not None
                 and self._step_count - self._xprof_started_at
-                >= self._xprof_steps):
-            self._xprof_stop()
+                >= self._xprof_steps)
 
     def _xprof_stop(self) -> None:
         self._xprof_done = True
@@ -2807,11 +2871,16 @@ class JaxEngine:
         )
 
     @staticmethod
-    def _is_greedy(samp: SamplingParams) -> bool:
+    def _is_greedy(rows: List[Optional[Sequence]]) -> bool:
         """True when every row is temperature-0: the dispatch compiles
         the STATIC greedy step variant (the runtime all-greedy cond
-        still costs ~0.9ms/step at a 128k vocab — ops/sampling.py)."""
-        return bool(np.all(np.asarray(samp.temperature) <= 0.0))
+        still costs ~0.9ms/step at a 128k vocab — ops/sampling.py).
+        Decided from the host's own values, rounded as `_samp_arrays`
+        rounds them: reading the temperatures back from the device waits
+        behind the program in flight (a whole step, where a prefill step
+        stays in flight while the next one is built)."""
+        return all(s is None or np.float32(s.opts.temperature) <= 0.0
+                   for s in rows)
 
     def _rope_array(self, rows: List[Optional[Sequence]]):
         """Per-row mrope rope-offset operand ([B] int32), or None for
@@ -2965,7 +3034,8 @@ class JaxEngine:
                 s.own_steps += 1
 
     def _step_phases(self, seqs, t0: int, t_call: Optional[int],
-                     t_fetch: Optional[int], t_got: Optional[int]) -> dict:
+                     t_fetch: Optional[int], t_got: Optional[int],
+                     t_sent: Optional[int] = None) -> dict:
         """What the host did inside the step slice [t0, now], for the ONE
         ring record of the step: integer microseconds on the ring's clock —
         `build_us` (t0 to the jitted call), `dispatch_us` (the call, its
@@ -2976,21 +3046,85 @@ class JaxEngine:
         a device gap by the slice spanning it would lose a nested one
         behind its parent.  A phase the step never reached reads 0.
 
-        Also credits the slice to its sequences (`_credit_own`)."""
+        A prefill step gives `t_sent`, the end of its dispatch half, and
+        gets `overlap_us` (t_sent to its own fetch) between `dispatch_us`
+        and `fetch_us`: the time its program ran, or waited behind the
+        one before it, while the step thread and the pump were busy with
+        OTHER steps: the older step's fetch and delivery, the plan, the
+        next step's build and dispatch.  About 0 for a step consumed at
+        once.
+
+        Also credits the slice to its sequences (`_credit_own`), from the
+        end of the last slice on where the two overlap, so that the
+        credits of consecutive steps never count an instant twice."""
         t_end = time.monotonic_ns()
         t_got = t_end if t_got is None else t_got
         t_fetch = t_got if t_fetch is None else t_fetch
         t_call = t_fetch if t_call is None else t_call
-        self._credit_own(seqs, t_end - t0)
-        return {"build_us": (t_call - t0) // 1000,
-                "dispatch_us": (t_fetch - t_call) // 1000,
-                "fetch_us": (t_got - t_fetch) // 1000,
-                "deliver_us": (t_end - t_got) // 1000}
+        self._credit_own(seqs, t_end - max(t0, self._slice_end_ns))
+        self._slice_end_ns = t_end
+        # the slice ends at this reading (`StepEventRecorder.record`), so
+        # the parts add up to it: a step thread that loses the CPU between
+        # here and the record call leaves no hole in the step's account
+        phases = {"t1_ns": t_end,
+                  "build_us": (t_call - t0) // 1000,
+                  "dispatch_us": (t_fetch - t_call) // 1000,
+                  "fetch_us": (t_got - t_fetch) // 1000,
+                  "deliver_us": (t_end - t_got) // 1000}
+        if t_sent is not None:
+            phases["dispatch_us"] = (t_sent - t_call) // 1000
+            phases["overlap_us"] = (t_fetch - t_sent) // 1000
+        return phases
 
     @affine("step")
     def _run_prefill(self, items: List[PrefillItem]) -> None:
+        """One prefill step in two halves: `_prefill_dispatch` builds the
+        inputs and commits the program, `_prefill_consume` fetches and
+        delivers its result.  Between them the step may stay IN FLIGHT
+        (`self._inflight`, at most one): the pump plans again, and if that
+        plan is a further prefill step, its first half runs here before
+        the older step's second half, so the device goes from one program
+        to the next while the host fetches, delivers, plans and builds.
+
+        One stream on one chip (or one program over a single-process mesh)
+        runs programs in dispatch order and threads the KV pool through
+        them (`self.kv`), and that order is what makes a step in flight
+        safe for the PAGES: the next chunk of a sequence reads what the
+        chunk in flight writes; a page freed under the program in flight
+        and handed to a later step is written after the program is done
+        with it; an export, an offload or an import is a later program on
+        the same stream.  It covers nothing on the HOST: a sequence whose
+        sampling chunk is in flight has no token (the scheduler keeps it
+        out of decode, mixed and fused plans: `Scheduler.in_flight`); a
+        result is applied only to a sequence still running (an abort or
+        a preemption after planning drops the row); pages enter the prefix
+        cache when the step that wrote them is fetched; the pages of a
+        sequence aborted under its step in flight go back to the pool
+        then too (`deferred_free`); and a failed fetch takes the newer
+        step down with it (`_recover_after_error`)."""
+        step = self._prefill_dispatch(items)
+        older, self._inflight = self._inflight, step
+        self.scheduler.in_flight = tuple(step.seqs)
+        if older is not None:
+            self._prefill_consume(older)
+        if step.fused or not self._can_hold_step():
+            self._prefill_consume(step)
+
+    def _can_hold_step(self) -> bool:
+        """May a dispatched prefill step wait for its fetch while the next
+        one is planned?  Every single-process engine (flat, pooled, sp,
+        pp): one process issues every program, so dispatch order is the
+        devices' order.  Not a multihost leader: its next plan reaches the
+        followers through `broadcast_plan`, a device collective that
+        queues behind the program in flight on every rank, so the leader
+        would wait out the step inside the broadcast and the followers
+        (which replay host arrays in order and never fetch) would gain
+        nothing; a recover must also find leader and followers agreeing on
+        which steps ran."""
+        return not self._multihost
+
+    def _prefill_dispatch(self, items: List[PrefillItem]) -> "_PrefillStep":
         t0_ev = self._step_t0_ns = self.events.now()
-        t_call = t_fetch = t_got = None
         self._note_dispatch("prefill")
         item_rows = self._prefill_rows(items)
         B = len(item_rows)
@@ -3018,7 +3152,7 @@ class JaxEngine:
             for i, it in enumerate(item_rows):
                 if it is not None:
                     owner[i] = it.seq.kv_rank % self._sp
-        greedy = self._is_greedy(samp)
+        greedy = self._is_greedy(seq_rows)
         if self._multihost:
             self._lockstep_send({
                 "kind": "prefill", "with_top": with_top,
@@ -3044,8 +3178,9 @@ class JaxEngine:
         except Exception:  # lint: allow(swallowed-exception): copy_to_host_async optional; fetch path device_gets anyway
             pass
         # the dispatch is committed: account the computed tokens NOW so a
-        # fused decode chain plans from current positions (errors reset
-        # all state via _recover_after_error anyway).  Planned items may
+        # fused decode chain, and the next plan if this step stays in
+        # flight, start from current positions (errors reset all state via
+        # _recover_after_error anyway).  Planned items may
         # have been PREEMPTED by a later item's page reservation in the
         # same schedule() pass — those rows compute into the trash page
         # and must not be accounted (their num_computed was reset)
@@ -3054,54 +3189,93 @@ class JaxEngine:
                 it.seq.num_computed += it.chunk_len
         fused = self._maybe_fuse_decode(items, B, tok_d, samp, seeds,
                                         counters, with_top)
-        # frees must be deferred while the fused chain's dispatches are in
-        # flight: a prefill-token EOS finishing a sequence must not hand
-        # its pages back under an in-flight decode table
-        deferred = [] if fused else None
-        self.scheduler.deferred_free = deferred
-        moe_attrs = {}
-        try:
-            t_fetch = self.events.now()
-            # lint: allow(device-get): prefill results are consumed on-step by design — decode, not prefill, is the latency path
-            packed = np.asarray(jax.device_get(packed_d))
-            t_got = self.events.now()
-            moe_attrs = self._note_moe(packed)
-            out, logp, tids, tlps = self._unpack_rows(
-                packed, B, with_top, blocks=self._prefill_blocks,
-            )
-            for i, it in enumerate(item_rows):
-                if it is None:
-                    continue
-                s = it.seq
-                if s.status != "running":  # preempted after planning
-                    continue
-                self.scheduler.commit_full_pages(s)
-                if it.samples:
-                    self._append_token(
-                        s, int(out[i]), float(logp[i]),
-                        _tops_for(s, tids, tlps, i),
-                    )
-            if fused:
-                self._consume_decode(fused, seq_rows, B, with_top)
-        finally:
-            self.scheduler.deferred_free = None
-            if deferred:
-                self.pool.free(deferred)
-            attrs = dict(moe_attrs)
-            if len(items) == 1:
-                attrs["rid"] = items[0].seq.request_id
-            if fused:
-                attrs["n_steps"] = int(fused[0].shape[0])
-            self.events.record(
-                "prefill_chunk", t0_ns=t0_ev, batch=len(items),
+        # frees are deferred while this dispatch is unfetched: a sequence
+        # aborted while its step is in flight keeps its pages until that
+        # step is consumed (and a prefill-token EOS must not hand pages
+        # back under a fused decode table: `_prefill_consume`)
+        if self.scheduler.deferred_free is None:
+            self.scheduler.deferred_free = []
+        overlapped = int(self._inflight is not None)
+        self.prefill_steps_total += 1
+        self.prefill_steps_overlapped_total += overlapped
+        return _PrefillStep(
+            items=items, item_rows=item_rows, seq_rows=seq_rows, seqs=seqs,
+            with_top=with_top, packed_d=packed_d, fused=fused,
+            t0_ns=t0_ev, t_call_ns=t_call, t_sent_ns=self.events.now(),
+            attrs=dict(
+                batch=len(items),
                 tokens=int(sum(it.chunk_len for it in items)),
                 fused_blocks=len(fused) if fused else 0,
                 ctx=int((prefix + chunk).max()), pages=table.shape[1],
                 bucket=chunk_bucket,
                 attn=self._attn_of("prefill_attention", B, chunk_bucket,
                                    table.shape[1]),
-                **attrs,
-                **self._step_phases(seqs, t0_ev, t_call, t_fetch, t_got),
+                overlapped=overlapped),
+        )
+
+    @affine("step")
+    def _prefill_consume(self, step: "_PrefillStep") -> None:
+        """Second half of a prefill step: fetch, account, deliver, record.
+        Its slice runs from the step's own `t0_ns` to here."""
+        self._step_t0_ns = step.t0_ns
+        B = len(step.item_rows)
+        t_fetch = t_got = None
+        moe_attrs = {}
+        # what was deferred while the step was in flight (sequences aborted
+        # by a plan made meanwhile) waits for the fetch below.  A sequence
+        # that finishes ON this result frees its pages at once, before its
+        # last delta goes out, as in a step that was never held: its own
+        # program is done, and it is in no newer step's table.  Only a
+        # fused decode chain keeps them back, as before
+        aborted = self.scheduler.deferred_free or []
+        self.scheduler.deferred_free = [] if step.fused else None
+        try:
+            t_fetch = self.events.now()
+            # lint: allow(device-get): prefill results are consumed on-step by design — decode, not prefill, is the latency path
+            packed = np.asarray(jax.device_get(step.packed_d))
+            t_got = self.events.now()
+            moe_attrs = self._note_moe(packed)
+            out, logp, tids, tlps = self._unpack_rows(
+                packed, B, step.with_top, blocks=self._prefill_blocks,
+            )
+            for i, it in enumerate(step.item_rows):
+                if it is None:
+                    continue
+                s = it.seq
+                if s.status != "running":  # preempted after planning
+                    continue
+                # no further than this step wrote: the sequence's next
+                # chunk may be dispatched already
+                self.scheduler.commit_full_pages(
+                    s, it.chunk_start + it.chunk_len)
+                if it.samples:
+                    self._append_token(
+                        s, int(out[i]), float(logp[i]),
+                        _tops_for(s, tids, tlps, i),
+                    )
+            if step.fused:
+                self._consume_decode(step.fused, step.seq_rows, B,
+                                     step.with_top)
+        finally:
+            if self._inflight is step:
+                self._inflight = None
+                self.scheduler.in_flight = ()
+            # none of these is in the table of a newer step in flight: they
+            # finished here, or were aborted before that step was planned
+            deferred = aborted + (self.scheduler.deferred_free or [])
+            self.scheduler.deferred_free = (
+                None if self._inflight is None else [])
+            if deferred:
+                self.pool.free(deferred)
+            attrs = dict(step.attrs, **moe_attrs)
+            if len(step.items) == 1:
+                attrs["rid"] = step.items[0].seq.request_id
+            if step.fused:
+                attrs["n_steps"] = int(step.fused[0].shape[0])
+            self.events.record(
+                "prefill_chunk", t0_ns=step.t0_ns, **attrs,
+                **self._step_phases(step.seqs, step.t0_ns, step.t_call_ns,
+                                    t_fetch, t_got, t_sent=step.t_sent_ns),
             )
 
     def _maybe_fuse_decode(self, items, B, tok_d, samp, seeds, counters,
@@ -3176,7 +3350,7 @@ class JaxEngine:
         return self._dispatch_decode(
             tok_d, positions, decode_ctr, None, table, samp, seeds,
             False, with_top, chain_len, rope_off=rope_off,
-            greedy=self._is_greedy(samp), n_steps=T,
+            greedy=self._is_greedy(seqs), n_steps=T,
         )
 
     def _consume_decode(self, dispatches, rows, Bb, with_top,
@@ -3312,7 +3486,7 @@ class JaxEngine:
         d_samp = self._samp_arrays(d_rows)
         counts = self._counts_array(d_rows) if penalized else None
         d_rope = self._rope_array(d_rows)
-        greedy_m = self._is_greedy(p_samp) and self._is_greedy(d_samp)
+        greedy_m = self._is_greedy(pseq_rows) and self._is_greedy(d_rows)
         # a mixed plan means prompts are pending by construction, so the
         # ladder policy picks the shortest rung — the prefill side's NEXT
         # chunk (or the next waiting prompt) rides the following dispatch
@@ -3785,7 +3959,7 @@ class JaxEngine:
         table = self._table_array(rows)
         samp = self._samp_arrays(rows)
         rope_off = self._rope_array(rows)
-        greedy = self._is_greedy(samp)
+        greedy = self._is_greedy(rows)
         if self._multihost:
             self._lockstep_send({
                 "kind": "spec", "greedy": greedy,
@@ -3921,14 +4095,14 @@ class JaxEngine:
                            *[np.asarray(a) for a in samp], seeds],
                 "counts_sparse": sparse,
                 "rope_off": rope_off,
-                "greedy": self._is_greedy(samp),
+                "greedy": self._is_greedy(rows),
                 "n_steps": T,
             })
         t_call = self.events.now()
         dispatches = self._dispatch_decode(
             tokens, positions, counters, counts, table, samp, seeds,
             penalized, with_top, chain_len, rope_off=rope_off,
-            greedy=self._is_greedy(samp), n_steps=T,
+            greedy=self._is_greedy(rows), n_steps=T,
         )
         # page frees deferred until the whole chain drains: an in-flight
         # dispatch must never see its table's pages reallocated (unchained
@@ -4280,7 +4454,7 @@ class JaxEngine:
         samp = self._samp_arrays(rows)
         counts = self._counts_array(rows) if penalized else None
         rope_off = self._rope_array(rows)
-        greedy = self._is_greedy(samp)
+        greedy = self._is_greedy(rows)
         budget = self._budget_array(rows)
         active = np.array([s is not None and budget[i] > 0
                            for i, s in enumerate(rows)])
@@ -5225,6 +5399,15 @@ class JaxEngine:
         """A failed jitted step may have consumed the donated KV buffers;
         rebuild device state so the engine survives (reference behavior:
         engine death → watchdog restart; we recover in-process)."""
+        # a step in flight goes with everything else: its sequences are
+        # among the running ones, its result is never fetched, and what
+        # was deferred for it is freed with the rest
+        self._inflight = None
+        self.scheduler.in_flight = ()
+        deferred, self.scheduler.deferred_free = (
+            self.scheduler.deferred_free, None)
+        if deferred:
+            self.pool.free(deferred)
         for seq in list(self.scheduler.running):
             self.scheduler.finish(seq, "error")
             self._deliver(seq, [], "error")
@@ -5276,7 +5459,8 @@ class JaxEngine:
         queue_us = min(total_us, max(0, int((admitted - seq.t_arrival) * 1e6)))
         own_ns, steps = seq.own_ns, seq.own_steps
         if self._step_t0_ns is not None:
-            own_ns, steps = own_ns + now_ns - self._step_t0_ns, steps + 1
+            own_ns += now_ns - max(self._step_t0_ns, self._slice_end_ns)
+            steps += 1
         own_us = min(own_ns // 1000, total_us - queue_us)
         wait_us = total_us - queue_us - own_us
         self.events.record(

@@ -150,6 +150,12 @@ class Sequence:
         return -(-upto_tokens // page_size)
 
 
+class _StepInFlight(Exception):
+    """Raised inside planning where the plan would take pages from a
+    running sequence (preempt it, or park it for an interactive head)
+    while a step is in flight (`Scheduler.in_flight`)."""
+
+
 @dataclass
 class PrefillItem:
     seq: Sequence
@@ -161,6 +167,8 @@ class PrefillItem:
 @dataclass
 class StepPlan:
     kind: str  # "prefill" | "decode" | "mixed" | "idle"
+    # (a plan abandoned for the step in flight is "idle" too: see
+    # `Scheduler.in_flight`)
     prefill: List[PrefillItem] = field(default_factory=list)
     decode: List[Sequence] = field(default_factory=list)
 
@@ -178,6 +186,18 @@ class Scheduler:
         # instead of freeing — freed pages must not be reallocated while
         # chained dispatches referencing them are still in flight
         self.deferred_free: Optional[List[int]] = None
+        # the sequences of the ONE prefill step the engine has dispatched
+        # and not fetched yet (engine `_run_prefill`; () at any other
+        # time).  Each is either mid-prompt (its `num_computed` counts the
+        # chunk in flight, so its next chunk can be planned behind it) or
+        # has its sampling chunk in flight: `prefill_done` with no token,
+        # so NOT decodable, no reason for a mixed or decode plan, and not
+        # to be fused or drafted for.  While the tuple is non-empty the
+        # plan takes no pages from a running sequence either: a plan that
+        # would have to preempt (or give up on) a sequence is abandoned
+        # and made again once the step's result is known, which may well
+        # finish sequences and free the pages
+        self.in_flight: Tuple[Sequence, ...] = ()
         # optional multi-tier onboarding hook (KVBM): called with the hash
         # run missed by the device cache, returns onboarded page ids.
         # `onboard_trace` carries the admitting request's TraceContext
@@ -602,6 +622,12 @@ class Scheduler:
 
     @affine("step", "loop")
     def schedule(self) -> StepPlan:
+        try:
+            return self._schedule()
+        except _StepInFlight:
+            return StepPlan("idle")
+
+    def _schedule(self) -> StepPlan:
         self._try_admit()
         if not self.running:
             return StepPlan("idle")
@@ -619,7 +645,7 @@ class Scheduler:
         )
         mixed_budget = self.cfg.mixed_prefill_tokens
         if has_pending_prefill and mixed_budget > 0 and any(
-            s.prefill_done for s in self.running
+            s.prefill_done and s not in self.in_flight for s in self.running
         ) and not any(
             s.mm_embeds is not None or s.mm_pixels is not None
             or s.mm_patches is not None
@@ -703,7 +729,8 @@ class Scheduler:
         hard_cap = self.cfg.hard_cap
         decodable: List[Sequence] = []
         for seq in list(self.running):
-            if seq.status != "running" or not seq.prefill_done:
+            if (seq.status != "running" or not seq.prefill_done
+                    or seq in self.in_flight):  # its token is not here yet
                 continue
             target = min(seq.num_computed + self.cfg.decode_advance, hard_cap)
             if not self._ensure_pages(seq, target):
@@ -723,6 +750,8 @@ class Scheduler:
                 seq.pages.extend(self.pool.allocate_on(seq.kv_rank, need))
                 return True
             except NoPagesError:
+                if self.in_flight:
+                    raise _StepInFlight() from None
                 victim = self._pick_victim(exclude=seq, rank=seq.kv_rank)
                 if victim is None:
                     # nothing left to evict: with the pool to itself the
@@ -832,6 +861,13 @@ class Scheduler:
         if self.park_fn is None or seq.priority != "interactive":
             return False
         rank = self._rank_for(seq)
+        if self.in_flight:
+            # answered like a plan that runs out of pages: the step's
+            # result first, then the victim is parked by a plan that
+            # knows it (the head waits one fetch, not a run of prefills)
+            if self._park_candidate(rank) is not None:
+                raise _StepInFlight()
+            return False
         parked_any = False
         for _ in range(len(self.running)):
             if (len(self.running) < self.cfg.max_num_seqs
@@ -905,12 +941,19 @@ class Scheduler:
     # -- completion ---------------------------------------------------------- #
 
     @affine("step", "loop")
-    def commit_full_pages(self, seq: Sequence) -> None:
-        """Register newly-filled pages in the prefix cache (emits KV events)."""
+    def commit_full_pages(self, seq: Sequence,
+                          upto_tokens: Optional[int] = None) -> None:
+        """Register newly-filled pages in the prefix cache (emits KV
+        events).  `upto_tokens` bounds it to what a fetched step wrote:
+        `num_computed` may already count the sequence's next chunk, which
+        is dispatched and not fetched."""
         if not self.cfg.enable_prefix_caching:
             return
         ps = self.cfg.page_size
-        full = seq.num_computed // ps
+        computed = seq.num_computed
+        if upto_tokens is not None:
+            computed = min(computed, upto_tokens)
+        full = computed // ps
         if full <= seq.committed_pages:
             return
         tokens = seq.all_tokens()

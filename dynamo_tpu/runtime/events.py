@@ -326,12 +326,15 @@ class StepEventRecorder:
         return time.monotonic_ns()
 
     def record(self, kind: str, t0_ns: Optional[int] = None,
-               **attrs: Any) -> None:
+               t1_ns: Optional[int] = None, **attrs: Any) -> None:
         """Record one event.  With `t0_ns` (a prior `now()`), the event is
-        a duration slice [t0_ns, now]; without, an instant."""
+        a duration slice [t0_ns, now]; without, an instant.  `t1_ns` (the
+        caller's own last `now()`) ends the slice there instead: parts
+        the caller measured up to that reading then add up to the slice,
+        whatever happens to the thread before it gets here."""
         if not self.enabled:
             return
-        t = time.monotonic_ns()
+        t = time.monotonic_ns() if t1_ns is None else t1_ns
         if t0_ns is not None:
             ev = (t0_ns, t - t0_ns, kind, attrs)
         else:

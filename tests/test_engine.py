@@ -563,7 +563,14 @@ async def test_chunked_prefill_splice_matches_fallout_engine(engine_setup):
     got = await _drive_mid_chain_arrival(unified, base, arrival)
     ev = unified.events.snapshot()
     m = unified.metrics()
-    released = unified.pool.free_pages + unified.pool.evictable_pages
+    # a fused prefill→decode step keeps its finished sequences' pages until
+    # its chain is consumed (`deferred_free`), after the last delta is
+    # posted: wait for the step thread to return them
+    for _ in range(400):
+        released = unified.pool.free_pages + unified.pool.evictable_pages
+        if released == unified.pool.num_pages - 1:
+            break
+        await asyncio.sleep(0.005)
     await unified.shutdown()
 
     # the chunk rows actually rode the chain: splice-tagged decode

@@ -24,6 +24,7 @@ from dynamo_tpu.runtime.events import StepEventRecorder, _encode_attrs
 STEP_KINDS = ("prefill_chunk", "decode_block", "mixed_step", "spec_round")
 PUMP_KINDS = ("plan", "loop_yield", "idle_wait")
 PHASES = ("build_us", "dispatch_us", "fetch_us", "deliver_us")
+# a prefill step's slice has `overlap_us` between dispatch and fetch besides
 
 
 def tiny_engine(**over):
@@ -58,9 +59,12 @@ async def served_ring(n_requests=3, **over):
             generate(engine, list(range(1 + i, 41 + i)), 4, rid=f"req-{i}")
             for i in range(n_requests)))
         assert all(len(o) == 4 for o in outs)
-        return engine.events.dump()["events"], engine.metrics()
+        metrics = engine.metrics()
     finally:
         await engine.shutdown()
+    # read after the pump has gone: a step posts its last delta before it
+    # writes its record, so a stream may close ahead of its last step's slice
+    return engine.events.dump()["events"], metrics
 
 
 async def test_step_slices_carry_phases_that_sum_to_the_slice():
@@ -71,18 +75,28 @@ async def test_step_slices_carry_phases_that_sum_to_the_slice():
     # riding mixed steps once a request decodes
     assert len(chunks) >= 3 and len(steps) >= 9
     for e in steps:
-        assert all(type(e[p]) is int and e[p] >= 0 for p in PHASES), e
-        parts = sum(e[p] for p in PHASES)
+        # a prefill step may stay in flight while the next one is planned,
+        # built and dispatched: `overlap_us`, between its dispatch and its
+        # own fetch, is among its parts; the other kinds have no such part
+        assert ("overlap_us" in e) == (e["kind"] == "prefill_chunk"), e
+        phases = PHASES + ("overlap_us",) * (e["kind"] == "prefill_chunk")
+        assert all(type(e[p]) is int and e[p] >= 0 for p in phases), e
+        parts = sum(e[p] for p in phases)
         # contiguous parts up to the record call: a little under the slice
         assert e["dur_ns"] // 1000 - 100 <= parts <= e["dur_ns"] // 1000, e
         assert e["pages"] >= 1 and e["bucket"] >= 1 and e["ctx"] >= 1
     for e in chunks:
+        assert e["overlapped"] in (0, 1)
         assert e["attn"] in ("pallas", "xla")
         assert e["ctx"] >= e["tokens"] and e["bucket"] >= e["tokens"]
         if e["batch"] == 1:
             assert e["rid"].startswith("req-")
+    # three requests arrive together: some chunk was dispatched behind an
+    # unfetched one, and spent time in flight that the host gave to others
+    assert any(e["overlapped"] and e["overlap_us"] > 0 for e in chunks)
     total = sum(e["dur_ns"] for e in steps) / 1000
-    assert abs(sum(e[p] for e in steps for p in PHASES) - total) <= (
+    assert abs(sum(e[p] for e in steps for p in PHASES)
+               + sum(e["overlap_us"] for e in chunks) - total) <= (
         0.02 * total + 100 * len(steps))
     assert not [e for e in events if e["kind"] == "dispatch"]
     decode = [e for e in steps if e["kind"] == "decode_block"]
@@ -92,22 +106,34 @@ async def test_step_slices_carry_phases_that_sum_to_the_slice():
 
 async def test_pump_slices_tile_the_time_between_steps():
     events, _ = await served_ring()
-    steps = sorted((e["t_ns"], e["t_ns"] + e["dur_ns"]) for e in events
-                   if e["kind"] in STEP_KINDS)
+    steps = [e for e in events if e["kind"] in STEP_KINDS]
     pump = [e for e in events if e["kind"] in PUMP_KINDS]
     assert {"plan", "loop_yield"} <= {e["kind"] for e in pump}
-    for e in pump:  # no pump slice overlaps a step slice
+    inside = 0
+    for e in pump:
+        # a pump slice lies outside every step slice, or inside a prefill
+        # step that was in flight meanwhile: then wholly inside the part of
+        # the slice between its dispatch and its own fetch (`overlap_us`)
         a, b = e["t_ns"], e["t_ns"] + e["dur_ns"]
-        assert not [s for s in steps if s[0] < b and a < s[1]], e
+        for s in steps:
+            if not (s["t_ns"] < b and a < s["t_ns"] + s["dur_ns"]):
+                continue
+            assert s["kind"] == "prefill_chunk", (e, s)
+            sent = s["t_ns"] + 1000 * (s["build_us"] + s["dispatch_us"])
+            assert sent <= a and b <= sent + 1000 * (s["overlap_us"] + 1), (
+                e, s)
+            inside += 1
+    assert inside  # the plan behind a step in flight
     ordered = sorted(pump, key=lambda e: e["t_ns"])
     for x, y in zip(ordered, ordered[1:]):  # nor another pump slice
         assert x["t_ns"] + x["dur_ns"] <= y["t_ns"]
     plans = [e for e in pump if e["kind"] == "plan"]
     assert sum(e["admitted"] for e in plans) == 3
     assert all(e["waiting"] >= 0 and e["running"] >= 0 for e in plans)
-    # at most one record more a step than before: `dispatch` went,
-    # `plan` and `loop_yield` came
-    assert len([e for e in pump if e["kind"] == "loop_yield"]) <= len(steps)
+    # one `loop_yield` for each return of the step thread, a plan after each:
+    # a step, or the fetch of a step in flight on its own
+    yields = [e for e in pump if e["kind"] == "loop_yield"]
+    assert len(yields) <= len(plans) and len(yields) <= 2 * len(steps)
 
 
 async def test_first_token_separates_waiting_from_working():
@@ -232,9 +258,10 @@ def test_step_slice_record_stays_under_5us():
     plain identifiers): they stay on the fast path of `_encode_attrs`."""
     from dynamo_tpu.analysis import contracts
 
-    attrs = dict(build_us=1234, dispatch_us=2345, fetch_us=61234,
-                 deliver_us=345, batch=1, tokens=512, fused_blocks=0,
-                 ctx=2048, pages=128, bucket=512, attn="pallas",
+    attrs = dict(build_us=1234, dispatch_us=2345, overlap_us=9876,
+                 fetch_us=61234, deliver_us=345, batch=1, tokens=512,
+                 fused_blocks=0, ctx=2048, pages=128, bucket=512,
+                 attn="pallas", overlapped=1,
                  rid="0a1b2c3d-e5f6-7890-abcd-ef0123456789")
     encoded = _encode_attrs(attrs)
     assert json.loads(encoded) == attrs
@@ -243,12 +270,17 @@ def test_step_slice_record_stays_under_5us():
         "why": 'a "quoted" reason'}
     budget = 5e-6 if contracts.checks_mode() == "off" else 100e-6
     rec = StepEventRecorder(capacity=4096)
-    n, best = 5_000, float("inf")
-    for _ in range(4):
+    # judged on the best of many short batches: under a loaded host (six
+    # xdist workers) a batch of 5,000 never runs undisturbed, one of 500
+    # (about a millisecond) does
+    n, best = 500, float("inf")
+    for _ in range(60):
         t0 = time.perf_counter()
         for _ in range(n):
             rec.record("prefill_chunk", t0_ns=1, **attrs)
         best = min(best, (time.perf_counter() - t0) / n)
+        if best < budget / 2:
+            break
     assert best < budget, f"{best * 1e6:.2f}µs/event"
 
 
