@@ -1,9 +1,9 @@
 """Which device this process runs on — said, never assumed.
 
 A measurement path that finds no chip fails; it does not fall back to the
-CPU and write CPU times under a chip's name.  `bench.py` and the planner's
-profiler sweep call `require_tpu` before their first device phase and put
-`device_identity()` into what they record.
+CPU and write CPU times under a chip's name.  The planner's profiler sweep
+calls `require_tpu` before its first device phase and puts
+`device_identity()` into what it records.
 """
 
 from __future__ import annotations

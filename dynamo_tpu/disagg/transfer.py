@@ -171,7 +171,7 @@ class KvTransferSource:
         )
         self._held[tid] = held
         register_local(tid, self)
-        if getattr(self.engine, "mesh", None) is None:
+        if self.engine.layout.mesh is None:
             from .device_transfer import _pow2, dma_server
 
             if dma_server(self.host) is not None:
@@ -183,7 +183,7 @@ class KvTransferSource:
                 padded[:n] = pages
 
                 def gather():
-                    k, v = engine._export_fn(  # noqa: SLF001
+                    k, v = engine.layout.export_fn(
                         engine.kv, jnp.asarray(padded)
                     )
                     return k[:, :n], v[:, :n]
@@ -461,7 +461,7 @@ class KvTransferClient:
             probe_jax_transfer,
         )
 
-        if not probe_jax_transfer() or getattr(self.engine, "mesh", None) is not None:
+        if not probe_jax_transfer() or self.engine.layout.mesh is not None:
             return None
         import jax
         import jax.numpy as jnp

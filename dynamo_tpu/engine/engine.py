@@ -20,35 +20,31 @@ WorkerMetricsPublisher).
 from __future__ import annotations
 
 import asyncio
-import contextlib
-import dataclasses
 import logging
 import time
 from dataclasses import dataclass, field
 from functools import partial
-from typing import Any, AsyncIterator, Callable, Dict, List, Optional, Tuple
+from typing import Any, AsyncIterator, Callable, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..analysis import affine, leak_ledger, xla_ledger
-from ..models import KVCache, ModelConfig, forward_decode, forward_prefill
-from ..models.llama import forward_embed
-from ..ops import (
-    SamplingParams,
-    apply_penalties,
-    compute_logprobs,
-    top_logprobs,
-)
-from ..ops.sampling import sample_tokens_maybe_greedy
-from ..ops.paged_attention import resolve_attention_impl
+from ..models import ModelConfig
+from ..ops import SamplingParams
 from ..runtime.engine import Context
 from ..tokens import compute_block_hash_for_seq
 from .config import EngineConfig, bucket_for
-from .page_pool import KvEvent, NoPagesError, PagePool
+from .layout import Layout
+from .page_pool import KvEvent, NoPagesError
 from .scheduler import PrefillItem, SamplingOptions, Scheduler, Sequence, StepPlan
+from .steps import (
+    MOE_STATS,
+    _unpack_out,
+    _unpack_out_cc,
+    _unpack_spec,
+)
 
 # jax.jit with compile attribution (analysis/xla_ledger.py): every jit
 # cache miss in the engine lands in the ledger as (fn, signature, rung)
@@ -148,148 +144,6 @@ class _PrefillStep:
     attrs: dict  # the slice's attributes known at dispatch
 
 
-# static top-k width for OpenAI `top_logprobs` responses (API max is 20)
-TOPLP = 20
-
-# materialized-KV HBM cap for the decode BLOCK path (plain and
-# continuous scans read the SAME constant, so the block/per-step
-# crossover can never drift between them; module-level so tests can
-# force the per-step fallback): kg+vg live across the whole step scan
-# (~2*L*B*S*nkv*hd bytes) — past ~2GB the per-step path's
-# layer-at-a-time gathers are the safer footprint
-_BLOCK_KV_BYTE_BUDGET = 2 << 30
-
-
-def _decode_path(attn_impl: str, kv, page_table, n_steps: int) -> str:
-    """"block" (`decode_block_scan`: one pool gather per block) or
-    "per-step" (a scan of `forward_decode`) for one traced decode step,
-    noted in the compile ledger with the reason — the Pallas decode kernel
-    reads pages itself and needs the per-step write-first layout, and a
-    block whose gathered KV would pass the byte budget stays per-step."""
-    from ..ops.paged_attention import _adapt
-
-    blk_bytes = (2 * kv.k.shape[0] * page_table.shape[0]
-                 * page_table.shape[1] * kv.k.shape[2]
-                 * kv.k.shape[3] * kv.k.shape[4] * kv.k.dtype.itemsize)
-    if _adapt(attn_impl, page_table, kv.k.shape[2]) == "pallas":
-        path, why = "per-step", "pallas decode kernel (write-first layout)"
-    elif blk_bytes > _BLOCK_KV_BYTE_BUDGET:
-        path, why = "per-step", (
-            f"block KV {blk_bytes} B > {_BLOCK_KV_BYTE_BUDGET} B budget")
-    else:
-        path, why = "block", (
-            f"block KV {blk_bytes} B <= {_BLOCK_KV_BYTE_BUDGET} B budget")
-    xla_ledger.note_path_choice(
-        "decode_step", path, why, batch=page_table.shape[0],
-        n_steps=n_steps,
-        table_tokens=page_table.shape[1] * kv.k.shape[2])
-    return path
-
-
-def _decode_name(n_steps: int) -> str:
-    """Program name of a decode dispatch: a scan of several steps is a
-    block."""
-    return "decode_step" if n_steps == 1 else "decode_block"
-
-
-@jax.named_scope("pack")
-def _pack(*parts: jax.Array) -> jax.Array:
-    """Concatenate int32 ids and float32 logprobs into ONE int32 array
-    along the last axis (floats ride as their bit patterns).  Integers and
-    not floats on purpose: an id below 2^23 viewed as float32 is a denormal
-    and the TPU flushes denormals to zero, so a float32 pack delivered
-    every token id as 0 on the chip (PERF.md finding 1)."""
-    return jnp.concatenate(
-        [p if p.dtype == jnp.int32
-         else jax.lax.bitcast_convert_type(p, jnp.int32) for p in parts],
-        axis=-1)
-
-
-def _as_f32(bits: np.ndarray) -> np.ndarray:
-    """Host-side inverse of `_pack` for a float32 column range."""
-    return np.ascontiguousarray(bits).view(np.float32)
-
-
-MOE_STATS = 3  # int32 columns an expert model's step appends to its pack
-
-
-def _carries_moe_stats(cfg: ModelConfig) -> bool:
-    """The steps that run the prefill layer path (prefill, the prefill
-    side of a mixed step, speculative verify) of an expert model append
-    `models.llama.moe_step_stats` (assignments, experts touched summed
-    over layers, largest per-expert row count) to the int32 pack they
-    already return: no second fetch.  The capacity dispatch routes per
-    group and carries none."""
-    return cfg.is_moe and cfg.moe_impl != "capacity"
-
-
-def _pack_out(out: jax.Array, logp: jax.Array, logits=None,
-              moe=None) -> jax.Array:
-    """Pack sampled tokens (int32) + logprobs (float32) — plus top-TOPLP
-    (ids, logprobs) when `logits` is given — into ONE int32 array along
-    the last axis (`_pack`): every host fetch is a device→host sync with a
-    fixed cost whatever its size, so results come back in a single transfer.
-
-    Layout: [tok(B) | logp(B) | top_ids(B*TOPLP) | top_lps(B*TOPLP)
-    | moe(MOE_STATS)], the last only from an expert model's step.
-    """
-    parts = [out, logp]
-    if logits is not None:
-        ids, lps = top_logprobs(logits, TOPLP)  # [B, TOPLP] each
-        parts += [ids.reshape(-1), lps.reshape(-1)]
-    if moe is not None:
-        parts.append(moe)
-    return _pack(*parts)
-
-
-def _unpack_out(packed: np.ndarray, b: int, with_top: bool = False):
-    """Inverse of `_pack_out`; returns (toks, logp, top_ids, top_lps)."""
-    toks = packed[..., :b]
-    logp = _as_f32(packed[..., b : 2 * b])
-    if not with_top:
-        return toks, logp, None, None
-    ids = packed[..., 2 * b : 2 * b + b * TOPLP]
-    lps = _as_f32(packed[..., 2 * b + b * TOPLP : 2 * b + 2 * b * TOPLP])
-    return (
-        toks, logp,
-        ids.reshape(*packed.shape[:-1], b, TOPLP),
-        lps.reshape(*packed.shape[:-1], b, TOPLP),
-    )
-
-
-def _pack_out_cc(out: jax.Array, logp: jax.Array, act: jax.Array,
-                 logits=None) -> jax.Array:
-    """`_pack_out` plus the device-resident loop's per-row EMITTED flag
-    (1 where the row was still active when this step sampled): the
-    drained buffer is then self-describing — the host learns each row's
-    real token count and stop position from the flags instead of
-    re-running per-token stop checks.
-
-    Layout: [tok(B) | logp(B) | act(B) | top_ids(B*TOPLP) | top_lps]."""
-    parts = [out, logp, act.astype(jnp.int32)]
-    if logits is not None:
-        ids, lps = top_logprobs(logits, TOPLP)
-        parts += [ids.reshape(-1), lps.reshape(-1)]
-    return _pack(*parts)
-
-
-def _unpack_out_cc(packed: np.ndarray, b: int, with_top: bool = False):
-    """Inverse of `_pack_out_cc`; returns (toks, logp, flags, top_ids,
-    top_lps) — `flags` is a bool emitted-mask aligned with toks."""
-    toks = packed[..., :b]
-    logp = _as_f32(packed[..., b : 2 * b])
-    flags = packed[..., 2 * b : 3 * b] > 0
-    if not with_top:
-        return toks, logp, flags, None, None
-    ids = packed[..., 3 * b : 3 * b + b * TOPLP]
-    lps = _as_f32(packed[..., 3 * b + b * TOPLP :])
-    return (
-        toks, logp, flags,
-        ids.reshape(*packed.shape[:-1], b, TOPLP),
-        lps.reshape(*packed.shape[:-1], b, TOPLP),
-    )
-
-
 def _ngram_draft(tokens: List[int], k: int, min_match: int,
                  max_match: int = 4, history: int = 256) -> List[int]:
     """Prompt-lookup / n-gram draft (host side, no draft model): propose
@@ -314,1053 +168,6 @@ def _ngram_draft(tokens: List[int], k: int, min_match: int,
             return (cont + [cont[-1]] * k)[:k]
     last = int(tokens[-1]) if tokens else 0
     return [last] * k
-
-
-def _unpack_spec(packed: np.ndarray, b: int, s: int):
-    """Inverse of the spec verify step's packing: (tokens [B, S] int32,
-    logprobs [B, S] float32, accepted draft count [B] int32)."""
-    n = b * s
-    toks = packed[:n].reshape(b, s)
-    logp = _as_f32(packed[n:2 * n]).reshape(b, s)
-    n_acc = packed[2 * n:2 * n + b]
-    return toks, logp, n_acc
-
-
-def _lockstep_out_shardings(mesh, *extra):
-    """jit out_shardings for multihost lockstep: the packed sample output
-    comes back REPLICATED (cross-process shards are not addressable, so
-    the leader could not read a dp-sharded result), the KV keeps its
-    serving layout, extras keep their stated specs."""
-    from ..models import kv_cache_pspec
-
-    rep = NamedSharding(mesh, P())
-    kv = jax.tree.map(lambda s: NamedSharding(mesh, s), kv_cache_pspec())
-    return (rep, *[
-        jax.tree.map(lambda s: NamedSharding(mesh, s), e) for e in extra
-    ], kv)
-
-
-def _build_prefill_step(cfg: ModelConfig, with_top: bool = False,
-                        attn_impl: str = "xla", lockstep_mesh=None,
-                        with_embeds: bool = False, greedy: bool = False):
-    kw = ({"out_shardings": _lockstep_out_shardings(lockstep_mesh, P())}
-          if lockstep_mesh is not None else {})
-
-    @partial(_ljit, name="prefill_step", donate_argnums=(1,), **kw)
-    def step(params, kv, tokens, page_table, prefix_lens, chunk_lens, samp,
-             seeds, counters, *mm):
-        logits, kv, *moe = forward_prefill(
-            params, cfg, kv, tokens, page_table, prefix_lens, chunk_lens,
-            attn_impl=attn_impl,
-            extra_embeds=mm[0] if with_embeds else None,
-            extra_mask=mm[1] if with_embeds else None,
-            # mrope models ship the (t, h, w) streams as a third array
-            mm_positions=mm[2] if with_embeds and len(mm) > 2 else None,
-            moe_stats=_carries_moe_stats(cfg),
-        )
-        out = sample_tokens_maybe_greedy(logits, samp, seeds, counters,
-                                         greedy)
-        logp = compute_logprobs(logits, out)
-        # `out` rides back as a separate device int32 so a fused decode
-        # chain can consume it without waiting for the packed host fetch
-        return _pack_out(out, logp, logits if with_top else None,
-                         *moe), out, kv
-
-    return step
-
-
-def _build_prefill_step_sp(cfg: ModelConfig, mesh, with_top: bool = False,
-                           lockstep: bool = False, pool_axes=None,
-                           with_embeds: bool = False, greedy: bool = False):
-    """Sequence-parallel whole-prompt prefill (parallel/sp_prefill.py):
-    the prompt is sharded over the sp axis and attention runs as ring
-    attention; sampling happens on the gathered last-position logits.
-    With `pool_axes` the KV pool is partitioned over (dp, sp): the step
-    takes an extra per-row `owner` array (the sp slot owning the row's
-    pages) and tables carry local ids."""
-    from ..models import kv_cache_pspec
-    from ..parallel.sp_prefill import forward_prefill_sp
-
-    if lockstep:
-        rep = NamedSharding(mesh, P())
-        kvsh = jax.tree.map(
-            lambda s: NamedSharding(mesh, s),
-            kv_cache_pspec(pool_axes=pool_axes),
-        )
-        kw = {"out_shardings": (rep, rep, kvsh)}
-    else:
-        kw = {}
-
-    if pool_axes is None:
-        @partial(_ljit, name="prefill_step_sp", donate_argnums=(1,), **kw)
-        def step(params, kv, tokens, page_table, prefix_lens, chunk_lens,
-                 samp, seeds, counters, *rest):
-            mm, (prefix_table,) = rest[:-1], rest[-1:]
-            logits, kv = forward_prefill_sp(
-                params, cfg, kv, tokens, page_table, chunk_lens, mesh,
-                prefix_lens=prefix_lens, prefix_table=prefix_table,
-                extra_embeds=mm[0] if with_embeds else None,
-                extra_mask=mm[1] if with_embeds else None,
-                mm_positions=mm[2] if with_embeds and len(mm) > 2 else None,
-            )
-            out = sample_tokens_maybe_greedy(logits, samp, seeds, counters,
-                                         greedy)
-            logp = compute_logprobs(logits, out)
-            return _pack_out(out, logp, logits if with_top else None), out, kv
-    else:
-        @partial(_ljit, name="prefill_step_sp", donate_argnums=(1,), **kw)
-        def step(params, kv, tokens, page_table, prefix_lens, chunk_lens,
-                 samp, seeds, counters, *rest):
-            del prefix_lens
-            mm, (owner,) = rest[:-1], rest[-1:]
-            logits, kv = forward_prefill_sp(
-                params, cfg, kv, tokens, page_table, chunk_lens, mesh,
-                owner=owner, pool_axes=pool_axes,
-                extra_embeds=mm[0] if with_embeds else None,
-                extra_mask=mm[1] if with_embeds else None,
-                mm_positions=mm[2] if with_embeds and len(mm) > 2 else None,
-            )
-            out = sample_tokens_maybe_greedy(logits, samp, seeds, counters,
-                                         greedy)
-            logp = compute_logprobs(logits, out)
-            return _pack_out(out, logp, logits if with_top else None), out, kv
-
-    return step
-
-
-def _pp_lockstep_kw(mesh, n_replicated: int, pooled: bool = False):
-    """jit out_shardings for a pp step under multihost lockstep: the
-    packed/chained outputs come back REPLICATED (cross-process shards
-    are not addressable, so the leader could not read them otherwise)
-    and the KV keeps its pp-staged layout."""
-    from ..parallel.pp_engine import kv_pspec_pp
-
-    rep = NamedSharding(mesh, P())
-    kvsh = jax.tree.map(lambda s: NamedSharding(mesh, s),
-                        kv_pspec_pp(pooled))
-    return {"out_shardings": (*([rep] * n_replicated), kvsh)}
-
-
-def _build_prefill_step_pp(cfg: ModelConfig, mesh, with_top: bool = False,
-                           attn_impl: str = "xla", lockstep: bool = False,
-                           pooled: bool = False, greedy: bool = False):
-    """Prefill through the GPipe-staged pipeline (parallel/pp_engine.py);
-    sampling happens at the jit level on the replicated last-position
-    logits (dp-sharded when the pool is partitioned)."""
-    from ..parallel.pp_engine import forward_prefill_pp
-
-    kw = _pp_lockstep_kw(mesh, 2, pooled) if lockstep else {}
-
-    @partial(_ljit, name="prefill_step_pp", donate_argnums=(1,), **kw)
-    def step(params, kv, tokens, page_table, prefix_lens, chunk_lens, samp,
-             seeds, counters):
-        logits, kv = forward_prefill_pp(
-            params, cfg, kv, tokens, page_table, prefix_lens, chunk_lens,
-            mesh, attn_impl, pooled=pooled,
-        )
-        out = sample_tokens_maybe_greedy(logits, samp, seeds, counters,
-                                         greedy)
-        logp = compute_logprobs(logits, out)
-        return _pack_out(out, logp, logits if with_top else None), out, kv
-
-    return step
-
-
-def _build_decode_step_pp(cfg: ModelConfig, mesh, n_steps: int,
-                          max_valid_pos: int, penalized: bool = False,
-                          with_top: bool = False, attn_impl: str = "xla",
-                          lockstep: bool = False, pooled: bool = False,
-                          greedy: bool = False):
-    """Multi-token decode with the pipeline kept full (the ring schedule
-    of parallel/pp_engine.py); packs per-step rows in the `_unpack_out`
-    layout ([T, 2B], or [T, B*(2+2*TOPLP)] with top-logprobs).  Penalty
-    histograms thread through the ring's last stage."""
-    from ..parallel.pp_engine import forward_decode_pp
-
-    def pack(toks, logp, tops):
-        parts = [toks, logp]
-        if tops is not None:
-            ids, lps = tops  # [T, B, TOPLP] each
-            T = ids.shape[0]
-            parts += [ids.reshape(T, -1), lps.reshape(T, -1)]
-        return _pack(*parts)
-
-    top_k = TOPLP if with_top else 0
-    if penalized:
-        kw = _pp_lockstep_kw(mesh, 5, pooled) if lockstep else {}
-
-        @partial(_ljit, name=_decode_name(n_steps) + "_pp",
-                 donate_argnums=(1, 5), tags={"rung": n_steps}, **kw)
-        def step(params, kv, tokens, positions, counters, counts,
-                 page_table, samp, seeds):
-            toks, logp, tops, counts, kv = forward_decode_pp(
-                params, cfg, kv, tokens, positions, page_table, samp,
-                seeds, counters, n_steps, max_valid_pos, mesh, attn_impl,
-                counts=counts, top_k=top_k, pooled=pooled, greedy=greedy,
-            )
-            return (pack(toks, logp, tops), toks[-1], positions + n_steps,
-                    counters + n_steps, counts, kv)
-    else:
-        kw = _pp_lockstep_kw(mesh, 4, pooled) if lockstep else {}
-
-        @partial(_ljit, name=_decode_name(n_steps) + "_pp",
-                 donate_argnums=(1,), tags={"rung": n_steps}, **kw)
-        def step(params, kv, tokens, positions, counters, page_table,
-                 samp, seeds):
-            toks, logp, tops, _, kv = forward_decode_pp(
-                params, cfg, kv, tokens, positions, page_table, samp,
-                seeds, counters, n_steps, max_valid_pos, mesh, attn_impl,
-                top_k=top_k, pooled=pooled, greedy=greedy,
-            )
-            return (pack(toks, logp, tops), toks[-1], positions + n_steps,
-                    counters + n_steps, kv)
-
-    return step
-
-
-def _build_export_fn(replicate_mesh=None):
-    """`replicate_mesh` (multihost lockstep): gather the result to every
-    process — the leader could not read a tp-sharded export whose shards
-    live on other hosts."""
-    kw = {}
-    if replicate_mesh is not None:
-        rep = NamedSharding(replicate_mesh, P())
-        kw["out_shardings"] = (rep, rep)
-
-    @partial(_ljit, **kw)
-    def export(kv, pages):  # pages [N] int32 → (k,v) [L, N, page, n_kv, hd]
-        return kv.k[:, pages], kv.v[:, pages]
-
-    return export
-
-
-def _build_import_fn():
-    @partial(_ljit, donate_argnums=(0,))
-    def imp(kv, k_blob, v_blob, pages):
-        # padding rows point at trash page 0 — harmless overwrite
-        return type(kv)(
-            kv.k.at[:, pages].set(k_blob), kv.v.at[:, pages].set(v_blob)
-        )
-
-    return imp
-
-
-def _make_decode_scan(cfg: ModelConfig, n_steps: int, max_valid_pos: int,
-                      penalized: bool, with_top: bool, attn_impl: str,
-                      greedy: bool = False):
-    """The traced decode-block body shared by the pure decode step and the
-    mixed (prefill+decode) step: scans `n_steps` forward+sample steps,
-    returning per-step packed outputs plus the carries.
-
-    On the xla/deferred path the whole block runs through
-    `decode_block_scan` (models/llama.py): the pool gathers ONCE per
-    block, in-block tokens ride ring buffers, and one batched scatter
-    lands the block's KV — per-step paged gathers were ~1.2ms/step of
-    scattered-DMA at 1B/batch-8 (r5 ablations).  The Pallas long-context
-    path keeps the per-step layout (the kernel reads pages directly)."""
-    from ..models.llama import decode_block_scan
-
-    def sample_tail(logits, cts, samp, seeds, ctr):
-        """ONE sampling tail for both the per-step and block paths:
-        penalties → sample → counts update → logprobs → pack."""
-        if penalized:
-            logits = apply_penalties(
-                logits, cts, samp.frequency_penalty, samp.presence_penalty)
-        out = sample_tokens_maybe_greedy(logits, samp, seeds, ctr, greedy)
-        if penalized:
-            cts = cts.at[jnp.arange(out.shape[0]), out].add(1.0)
-        logp = compute_logprobs(logits, out)
-        packed = _pack_out(out, logp, logits if with_top else None)
-        return out, cts, packed
-
-    def block_scan(params, kv, tokens, positions, counters, counts,
-                   page_table, samp, seeds, rope_off=None):
-        def sample_step(eng, logits, tok_prev, t):
-            ctr, cts = eng
-            out, cts, packed = sample_tail(logits, cts, samp, seeds, ctr)
-            return (ctr + 1, cts), out, packed
-
-        cts0 = counts if penalized else jnp.zeros((), jnp.float32)
-        (ctr, cts), packed, tok, pos, kv = decode_block_scan(
-            params, cfg, kv, tokens, positions, page_table, n_steps,
-            max_valid_pos, sample_step, (counters, cts0),
-            rope_offset=rope_off,
-        )
-        if penalized:
-            return packed, tok, pos, ctr, cts, kv
-        return packed, tok, pos, ctr, kv
-
-    def body_common(kv, tok, pos, ctr, counts, page_table, samp, seeds,
-                    params, rope_off=None):
-        ok = pos < max_valid_pos
-        safe_pos = jnp.where(ok, pos, 0)
-        # out-of-window rows use an all-trash table row
-        table = jnp.where(ok[:, None], page_table, 0)
-        logits, kv = forward_decode(
-            params, cfg, kv, tok, safe_pos, table, attn_impl=attn_impl,
-            rope_offset=rope_off,
-        )
-        out, counts, packed = sample_tail(logits, counts, samp, seeds, ctr)
-        return kv, out, counts, packed
-
-    if penalized:
-        def scan(params, kv, tokens, positions, counters, counts,
-                 page_table, samp, seeds, rope_off=None):
-            if _decode_path(attn_impl, kv, page_table, n_steps) == "block":
-                return block_scan(params, kv, tokens, positions, counters,
-                                  counts, page_table, samp, seeds,
-                                  rope_off)
-
-            def body(carry, _):
-                kv, tok, pos, ctr, cts = carry
-                kv, out, cts, packed = body_common(
-                    kv, tok, pos, ctr, cts, page_table, samp, seeds,
-                    params, rope_off,
-                )
-                return (kv, out, pos + 1, ctr + 1, cts), packed
-
-            (kv, tok, pos, ctr, cts), packed = jax.lax.scan(
-                body, (kv, tokens, positions, counters, counts),
-                None, length=n_steps,
-            )
-            return packed, tok, pos, ctr, cts, kv
-    else:
-        def scan(params, kv, tokens, positions, counters, counts,
-                 page_table, samp, seeds, rope_off=None):
-            del counts
-            if _decode_path(attn_impl, kv, page_table, n_steps) == "block":
-                return block_scan(params, kv, tokens, positions, counters,
-                                  None, page_table, samp, seeds, rope_off)
-
-            def body(carry, _):
-                kv, tok, pos, ctr = carry
-                kv, out, _, packed = body_common(
-                    kv, tok, pos, ctr, None, page_table, samp, seeds,
-                    params, rope_off,
-                )
-                return (kv, out, pos + 1, ctr + 1), packed
-
-            (kv, tok, pos, ctr), packed = jax.lax.scan(
-                body, (kv, tokens, positions, counters), None, length=n_steps
-            )
-            return packed, tok, pos, ctr, kv
-
-    return scan
-
-
-def _build_decode_step(cfg: ModelConfig, n_steps: int, max_valid_pos: int,
-                       *, greedy: bool = False,
-                       penalized: bool = False, with_top: bool = False,
-                       attn_impl: str = "xla", lockstep_mesh=None):
-    """Decode `n_steps` tokens per dispatch: lax.scan keeps the whole block
-    on-device, so host→device latency is paid once per block, not per
-    token (the TPU analog of multi-step scheduling).
-
-    Steps whose position reaches `max_valid_pos` (the model window) write
-    to the trash page instead of clamping into a real page — those tokens
-    are discarded host-side anyway.
-
-    The carry state (last token, positions, counters, penalty counts) is
-    returned so a chained dispatch can consume block k's device-side
-    outputs directly — introducing any fresh host buffer between chained
-    dispatches serializes the pipeline on the host→device transfer.
-
-    Variants (compiled lazily, cached per engine): `penalized` threads a
-    [B, V] output-token count array through the scan for frequency/
-    presence penalties; `with_top` packs top-TOPLP logprobs per step.
-    """
-    run = _make_decode_scan(cfg, n_steps, max_valid_pos, penalized,
-                            with_top, attn_impl, greedy)
-    dp = P("dp")
-    mrope = bool(cfg.mrope_section)  # +rope_off operand (qwen2_vl)
-    if penalized:
-        kw = ({"out_shardings": _lockstep_out_shardings(
-            lockstep_mesh, dp, dp, dp, P("dp", None))}
-            if lockstep_mesh is not None else {})
-
-        if mrope:
-            @partial(_ljit, name=_decode_name(n_steps),
-                     donate_argnums=(1, 5), tags={"rung": n_steps}, **kw)
-            def step(params, kv, tokens, positions, counters, counts,
-                     page_table, samp, seeds, rope_off):
-                return run(params, kv, tokens, positions, counters, counts,
-                           page_table, samp, seeds, rope_off)
-        else:
-            @partial(_ljit, name=_decode_name(n_steps),
-                     donate_argnums=(1, 5), tags={"rung": n_steps}, **kw)
-            def step(params, kv, tokens, positions, counters, counts,
-                     page_table, samp, seeds):
-                return run(params, kv, tokens, positions, counters, counts,
-                           page_table, samp, seeds)
-    else:
-        kw = ({"out_shardings": _lockstep_out_shardings(
-            lockstep_mesh, dp, dp, dp)}
-            if lockstep_mesh is not None else {})
-
-        if mrope:
-            @partial(_ljit, name=_decode_name(n_steps),
-                     donate_argnums=(1,), tags={"rung": n_steps}, **kw)
-            def step(params, kv, tokens, positions, counters, page_table,
-                     samp, seeds, rope_off):
-                return run(params, kv, tokens, positions, counters, None,
-                           page_table, samp, seeds, rope_off)
-        else:
-            @partial(_ljit, name=_decode_name(n_steps),
-                     donate_argnums=(1,), tags={"rung": n_steps}, **kw)
-            def step(params, kv, tokens, positions, counters, page_table,
-                     samp, seeds):
-                return run(params, kv, tokens, positions, counters, None,
-                           page_table, samp, seeds)
-
-    return step
-
-
-def _make_decode_scan_cc(cfg: ModelConfig, n_steps: int, max_valid_pos: int,
-                         penalized: bool, with_top: bool, attn_impl: str,
-                         greedy: bool = False):
-    """The device-resident decode-block body (`_make_decode_scan` with
-    ON-DEVICE stop detection): an active-row mask rides the scan carry —
-    each step a row emits only while active, and the mask latches off at
-    the first stop/eos-token hit or when its token budget (max-token +
-    model-window headroom, computed host-side) runs out.  Frozen rows
-    stop advancing their position and PRNG counter, write KV only to the
-    trash page, and stay inert for every later block of an open-ended
-    chain, so their pool pages may be freed as soon as the stop drains.
-
-    Extra operands vs the plain scan: `act [B]` bool (active at block
-    start), `budget [B]` int32 (tokens the row may still emit), `stops
-    [B, K]` int32 (-1-padded per-row stop/eos ids).  The packed output
-    carries the per-step emitted flags (`_pack_out_cc`); the carries
-    (tok, pos, ctr, act, budget, counts) all return as device arrays so
-    block k+1 consumes block k's outputs with zero host round-trip.
-
-    CHUNK ROWS (docs/device_loop.md "chunk rows"): prefill chunks ride
-    the same block as extra operands — `chunk_toks [B, T]` (prompt
-    tokens to feed, row-major from the row's resume point), `chunk_rem
-    [B]` (how many of them this block feeds; 0 = pure decode row) and
-    `chunk_samples [B]` (True when the last fed token completes the
-    prompt, so that step samples the first output).  While a row feeds
-    it is ACTIVE (KV written, position advancing) but emits nothing:
-    its PRNG counter, penalty counts and budget are untouched, so the
-    sampled stream is token-identical to a split prefill+decode.  A row
-    whose chunk runs out mid-prompt goes dormant until the next block's
-    operands feed it again.  `reset [B]` + `init_pos [B]` +
-    `init_budget [B]` splice a NEW request into a slot in-step (a
-    `jnp.where` overlay on the carried pos/ctr/counts/budget), so
-    admission rides the SAME compiled program — zero steady-state
-    compiles.  Within a block, active steps stay a contiguous prefix
-    per row (dormancy only at chunk end, revival only in the prologue),
-    which is what keeps `decode_block_scan`'s uniform KV scatter and
-    ring-attention masks exact.
-
-    DRIFT TRIPWIRE: this deliberately forks `_make_decode_scan`'s
-    sample tail / per-step body / block-path gate (the mask threading
-    touches every line, and the meshed variants must stay untouched) —
-    any fix to the plain scan (penalty order, the blk_bytes HBM budget,
-    the pallas `_adapt` gate) MUST be mirrored here, and vice versa; the
-    continuous-vs-per-step equivalence matrix in tests/test_engine.py +
-    tests/test_block_ladder.py is what catches a drift."""
-    from ..models.llama import decode_block_scan
-
-    def sample_tail(logits, cts, samp, seeds, ctr, act, budget, stops,
-                    cidx, chunk_toks, chunk_rem, chunk_samples):
-        """Sample + freeze + feed: counters/penalty counts/budget
-        advance only for rows that EMIT this step (active decode rows,
-        plus a chunk row's prompt-completing step); feeding steps
-        discard the sample and load the next prompt token instead.  The
-        returned mask governs the NEXT step."""
-        if penalized:
-            logits = apply_penalties(
-                logits, cts, samp.frequency_penalty, samp.presence_penalty)
-        out = sample_tokens_maybe_greedy(logits, samp, seeds, ctr, greedy)
-        feeding = cidx < chunk_rem
-        completing = feeding & (cidx + 1 == chunk_rem) & chunk_samples
-        emit = act & (~feeding | completing)
-        emitf = emit.astype(jnp.float32)
-        ctr = ctr + emit.astype(ctr.dtype)
-        if penalized:
-            cts = cts.at[jnp.arange(out.shape[0]), out].add(emitf)
-        logp = compute_logprobs(logits, out)
-        packed = _pack_out_cc(out, logp, emit,
-                              logits if with_top else None)
-        hit = (out[:, None] == stops).any(axis=-1)
-        budget = budget - emit.astype(budget.dtype)
-        cidx_next = cidx + feeding.astype(cidx.dtype)
-        tok_next = jnp.where(
-            cidx_next < chunk_rem,
-            jnp.take_along_axis(
-                chunk_toks,
-                jnp.clip(cidx_next, 0, chunk_toks.shape[1] - 1)[:, None],
-                axis=1)[:, 0],
-            out)
-        # emitting rows follow the stop/budget latch; feeding rows stay
-        # active while prompt tokens remain this block, then go dormant
-        # until the next block's operands feed them again
-        act_next = jnp.where(emit, act & ~hit & (budget > 0),
-                             act & (cidx_next < chunk_rem))
-        return tok_next, ctr, cts, packed, act_next, budget, cidx_next
-
-    def block_scan(params, kv, tokens, positions, counters, counts, act,
-                   budget, stops, page_table, samp, seeds, chunk_toks,
-                   chunk_rem, chunk_samples, rope_off=None):
-        def sample_step(eng, logits, tok_prev, t, act_in):
-            ctr, cts, bud, cidx, _ = eng
-            tok_next, ctr, cts, packed, act_next, bud, cidx = sample_tail(
-                logits, cts, samp, seeds, ctr, act_in, bud, stops,
-                cidx, chunk_toks, chunk_rem, chunk_samples)
-            # act duplicated into the engine carry so the final mask
-            # returns as a chainable device array
-            return (ctr, cts, bud, cidx, act_next), tok_next, packed, act_next
-
-        cts0 = counts if penalized else jnp.zeros((), jnp.float32)
-        cidx0 = jnp.zeros_like(chunk_rem)
-        (ctr, cts, bud, _, act_out), packed, tok, pos, kv = decode_block_scan(
-            params, cfg, kv, tokens, positions, page_table, n_steps,
-            max_valid_pos, sample_step, (counters, cts0, budget, cidx0, act),
-            rope_offset=rope_off, active_init=act,
-        )
-        if penalized:
-            return packed, tok, pos, ctr, act_out, bud, cts, kv
-        return packed, tok, pos, ctr, act_out, bud, kv
-
-    def body_common(kv, tok, pos, ctr, cts, act, budget, stops, page_table,
-                    samp, seeds, params, cidx, chunk_toks, chunk_rem,
-                    chunk_samples, rope_off=None):
-        ok = (pos < max_valid_pos) & act
-        safe_pos = jnp.where(pos < max_valid_pos, pos, 0)
-        # frozen and out-of-window rows write through an all-trash table
-        table = jnp.where(ok[:, None], page_table, 0)
-        logits, kv = forward_decode(
-            params, cfg, kv, tok, safe_pos, table, attn_impl=attn_impl,
-            rope_offset=rope_off,
-        )
-        return (kv,) + sample_tail(logits, cts, samp, seeds, ctr, act,
-                                   budget, stops, cidx, chunk_toks,
-                                   chunk_rem, chunk_samples)
-
-    def scan(params, kv, tokens, positions, counters, counts, act, budget,
-             stops, page_table, samp, seeds, chunk_toks, chunk_rem,
-             chunk_samples, reset, init_pos, init_budget, rope_off=None):
-        # splice/chunk prologue: spliced rows reset their carried
-        # pos/ctr/counts/budget in-step (a jnp.where overlay, so
-        # admission rides the SAME compiled program), and rows with
-        # prompt tokens to feed this block load their first chunk token
-        # and (re)activate.  Runs before the block/per-step fork so both
-        # paths see identical row state.
-        positions = jnp.where(reset, init_pos, positions)
-        counters = jnp.where(reset, 0, counters)
-        budget = jnp.where(reset, init_budget, budget)
-        if penalized:
-            counts = jnp.where(reset[:, None], 0.0, counts)
-        act = act | (chunk_rem > 0)
-        tokens = jnp.where(chunk_rem > 0, chunk_toks[:, 0], tokens)
-
-        if _decode_path(attn_impl, kv, page_table, n_steps) == "block":
-            return block_scan(params, kv, tokens, positions, counters,
-                              counts, act, budget, stops, page_table,
-                              samp, seeds, chunk_toks, chunk_rem,
-                              chunk_samples, rope_off)
-
-        def body(carry, _):
-            kv, tok, pos, ctr, cts, a, bud, cidx = carry
-            kv, tok_next, ctr, cts, packed, a_next, bud, cidx = body_common(
-                kv, tok, pos, ctr, cts, a, bud, stops, page_table,
-                samp, seeds, params, cidx, chunk_toks, chunk_rem,
-                chunk_samples, rope_off,
-            )
-            return (kv, tok_next, pos + a.astype(pos.dtype), ctr, cts,
-                    a_next, bud, cidx), packed
-
-        cts0 = counts if penalized else jnp.zeros((), jnp.float32)
-        cidx0 = jnp.zeros_like(chunk_rem)
-        (kv, tok, pos, ctr, cts, act, budget, _), packed = jax.lax.scan(
-            body, (kv, tokens, positions, counters, cts0, act, budget,
-                   cidx0),
-            None, length=n_steps,
-        )
-        if penalized:
-            return packed, tok, pos, ctr, act, budget, cts, kv
-        return packed, tok, pos, ctr, act, budget, kv
-
-    return scan
-
-
-def _build_decode_step_cc(cfg: ModelConfig, n_steps: int, max_valid_pos: int,
-                          *, greedy: bool = False, penalized: bool = False,
-                          with_top: bool = False, attn_impl: str = "xla"):
-    """The continuous-chain decode step (flat single-process engines
-    only): one compiled program per (penalized, with_top, greedy, rung)
-    like the plain variants, with the stop mask / budget carries riding
-    as device arrays so an open-ended chain never rebuilds host inputs."""
-    run = _make_decode_scan_cc(cfg, n_steps, max_valid_pos, penalized,
-                               with_top, attn_impl, greedy)
-    mrope = bool(cfg.mrope_section)
-    if penalized:
-        if mrope:
-            @partial(_ljit, name="decode_block_cc",
-                     donate_argnums=(1, 5), tags={"rung": n_steps})
-            def step(params, kv, tokens, positions, counters, counts, act,
-                     budget, stops, page_table, samp, seeds, chunk_toks,
-                     chunk_rem, chunk_samples, reset, init_pos,
-                     init_budget, rope_off):
-                return run(params, kv, tokens, positions, counters, counts,
-                           act, budget, stops, page_table, samp, seeds,
-                           chunk_toks, chunk_rem, chunk_samples, reset,
-                           init_pos, init_budget, rope_off)
-        else:
-            @partial(_ljit, name="decode_block_cc",
-                     donate_argnums=(1, 5), tags={"rung": n_steps})
-            def step(params, kv, tokens, positions, counters, counts, act,
-                     budget, stops, page_table, samp, seeds, chunk_toks,
-                     chunk_rem, chunk_samples, reset, init_pos,
-                     init_budget):
-                return run(params, kv, tokens, positions, counters, counts,
-                           act, budget, stops, page_table, samp, seeds,
-                           chunk_toks, chunk_rem, chunk_samples, reset,
-                           init_pos, init_budget)
-    else:
-        if mrope:
-            @partial(_ljit, name="decode_block_cc",
-                     donate_argnums=(1,), tags={"rung": n_steps})
-            def step(params, kv, tokens, positions, counters, act, budget,
-                     stops, page_table, samp, seeds, chunk_toks, chunk_rem,
-                     chunk_samples, reset, init_pos, init_budget, rope_off):
-                return run(params, kv, tokens, positions, counters, None,
-                           act, budget, stops, page_table, samp, seeds,
-                           chunk_toks, chunk_rem, chunk_samples, reset,
-                           init_pos, init_budget, rope_off)
-        else:
-            @partial(_ljit, name="decode_block_cc",
-                     donate_argnums=(1,), tags={"rung": n_steps})
-            def step(params, kv, tokens, positions, counters, act, budget,
-                     stops, page_table, samp, seeds, chunk_toks, chunk_rem,
-                     chunk_samples, reset, init_pos, init_budget):
-                return run(params, kv, tokens, positions, counters, None,
-                           act, budget, stops, page_table, samp, seeds,
-                           chunk_toks, chunk_rem, chunk_samples, reset,
-                           init_pos, init_budget)
-
-    return step
-
-
-def _build_spec_verify_step(cfg: ModelConfig, *, greedy: bool = False,
-                            attn_impl: str = "xla", lockstep_mesh=None):
-    """Fused draft-verify decode step (self-speculative decoding): one
-    forward scores k+1 positions — the last accepted token plus k
-    host-drafted tokens — through the PREFILL layer path
-    (`forward_verify`), then an on-device verify tail samples every
-    position from its own (seed, counter) PRNG stream and counts the
-    accepted draft prefix.  One weight read buys up to k+1 tokens.
-
-    KV pages for all k+1 positions are written; rejected positions are
-    logically rolled back by position masking (never attended,
-    overwritten as decode advances) — the same trash-page/table
-    discipline every other step relies on.  Packed result:
-    [tok(B*(k+1)) | logp(B*(k+1)) | n_accepted(B)] in one fetch."""
-    from ..models import forward_verify
-    from ..ops.sampling import sample_tokens_block, speculative_accept
-
-    kw = ({"out_shardings": _lockstep_out_shardings(lockstep_mesh)}
-          if lockstep_mesh is not None else {})
-    mrope = bool(cfg.mrope_section)  # +rope_off operand (qwen2_vl)
-
-    def body(params, kv, tokens, positions, page_table, samp, seeds,
-             counters, rope_off=None):
-        B, S = tokens.shape  # S == k + 1
-        logits, kv, *moe = forward_verify(
-            params, cfg, kv, tokens, page_table, positions,
-            jnp.full((B,), S, jnp.int32), attn_impl=attn_impl,
-            rope_offset=rope_off, moe_stats=_carries_moe_stats(cfg),
-        )  # [B, S, V]
-        out, logp = sample_tokens_block(logits, samp, seeds, counters,
-                                        greedy)
-        n_acc = speculative_accept(out, tokens)
-        packed = _pack(out.reshape(-1), logp.reshape(-1), n_acc, *moe)
-        return packed, kv
-
-    if mrope:
-        @partial(_ljit, name="verify_step", donate_argnums=(1,), **kw)
-        def step(params, kv, tokens, positions, page_table, samp, seeds,
-                 counters, rope_off):
-            return body(params, kv, tokens, positions, page_table, samp,
-                        seeds, counters, rope_off)
-    else:
-        @partial(_ljit, name="verify_step", donate_argnums=(1,), **kw)
-        def step(params, kv, tokens, positions, page_table, samp, seeds,
-                 counters):
-            return body(params, kv, tokens, positions, page_table, samp,
-                        seeds, counters)
-
-    return step
-
-
-def _make_mixed_body(cfg: ModelConfig, n_steps: int, max_valid_pos: int,
-                     penalized: bool, with_top: bool, attn_impl: str,
-                     greedy: bool = False, moe_stats: bool = False):
-    """The traced mixed-step body shared by the flat and pooled builders:
-    the prefill side runs first (its page writes are disjoint from the
-    decode rows'), then the decode scan; both packed outputs return in
-    one fetch."""
-    run = _make_decode_scan(cfg, n_steps, max_valid_pos, penalized,
-                            with_top, attn_impl, greedy)
-
-    def common(params, kv, p_tokens, p_table, p_prefix, p_chunk, p_samp,
-               p_seeds, p_ctr, d_tokens, d_pos, d_ctr, d_counts, d_table,
-               d_samp, d_seeds, d_rope=None):
-        # the scheduler excludes mm-carrying sequences from mixed plans,
-        # so the prefill side ropes text-style (mm_positions=None) even
-        # on mrope models; the decode side still needs each row's delta
-        logits, kv, *moe = forward_prefill(
-            params, cfg, kv, p_tokens, p_table, p_prefix, p_chunk,
-            attn_impl=attn_impl, moe_stats=moe_stats,
-        )
-        p_out = sample_tokens_maybe_greedy(logits, p_samp, p_seeds, p_ctr,
-                                           greedy)
-        p_logp = compute_logprobs(logits, p_out)
-        p_packed = _pack_out(p_out, p_logp, logits if with_top else None,
-                             *moe)
-        d_packed, *_, kv = run(
-            params, kv, d_tokens, d_pos, d_ctr, d_counts, d_table,
-            d_samp, d_seeds, d_rope,
-        )
-        return p_packed, d_packed, kv
-
-    if cfg.mrope_section:
-        def body(params, kv,
-                 p_tokens, p_table, p_prefix, p_chunk, p_samp, p_seeds,
-                 p_ctr, d_tokens, d_pos, d_ctr, d_counts, d_table, d_samp,
-                 d_seeds, d_rope):
-            return common(params, kv, p_tokens, p_table, p_prefix, p_chunk,
-                          p_samp, p_seeds, p_ctr, d_tokens, d_pos, d_ctr,
-                          d_counts, d_table, d_samp, d_seeds, d_rope)
-    else:
-        def body(params, kv,
-                 p_tokens, p_table, p_prefix, p_chunk, p_samp, p_seeds,
-                 p_ctr, d_tokens, d_pos, d_ctr, d_counts, d_table, d_samp,
-                 d_seeds):
-            return common(params, kv, p_tokens, p_table, p_prefix, p_chunk,
-                          p_samp, p_seeds, p_ctr, d_tokens, d_pos, d_ctr,
-                          d_counts, d_table, d_samp, d_seeds)
-
-    return body
-
-
-def _build_mixed_step(cfg: ModelConfig, n_steps: int, max_valid_pos: int,
-                      penalized: bool = False, with_top: bool = False,
-                      attn_impl: str = "xla", lockstep_mesh=None,
-                      greedy: bool = False):
-    """One dispatch = one bounded prefill chunk + one decode block
-    (chunked-prefill interleave, the TPU form: both forwards live in one
-    XLA program, so running decodes pay zero extra host round-trips for
-    a concurrent prompt's prefill — reference behavior: vLLM mixed
-    batches / mocker watermark scheduler, scheduler.rs:240)."""
-    body = _make_mixed_body(cfg, n_steps, max_valid_pos, penalized,
-                            with_top, attn_impl, greedy,
-                            moe_stats=_carries_moe_stats(cfg))
-    kw = ({"out_shardings": _lockstep_out_shardings(lockstep_mesh, P())}
-          if lockstep_mesh is not None else {})
-    return partial(_ljit, name="mixed_step",
-                   donate_argnums=(1,), tags={"rung": n_steps}, **kw)(body)
-
-
-# -- partitioned-pool (kv_partition) step builders -------------------------- #
-# The pool's page axis is sharded over the mesh's (dp, sp) shards; batches
-# arrive as R contiguous per-rank row blocks with LOCAL page tables, so the
-# whole step runs under a shard_map that is MANUAL over the pool axes and
-# AUTO (GSPMD) over tp — every page gather/scatter stays device-local while
-# tp keeps its megatron collectives (scaling-book layout; reference
-# capability: engines shard KV over their ranks, disagg_serving.md:110).
-
-
-def _pool_linear_index(mesh, pool_axes):
-    idx = jax.lax.axis_index(pool_axes[0])
-    for ax in pool_axes[1:]:
-        idx = idx * mesh.shape[ax] + jax.lax.axis_index(ax)
-    return idx
-
-
-def _pooled_specs(pool_axes):
-    kvs = P(None, pool_axes, None, None, None)
-    return KVCache(kvs, kvs), P(pool_axes), P(pool_axes, None)
-
-
-def _lockstep_pooled_kw(mesh, pool_axes, out_specs, n_replicated: int = 1):
-    """jit out_shardings for a pooled lockstep step: the first
-    `n_replicated` outputs (packed results the leader must read) come
-    back replicated, the rest keep their stated specs, the trailing KV
-    keeps the pooled layout."""
-    from ..models import kv_cache_pspec
-
-    def shard(s):
-        return jax.tree.map(lambda sp: NamedSharding(mesh, sp), s)
-
-    rep = NamedSharding(mesh, P())
-    rest = [shard(s) for s in out_specs[n_replicated:-1]]
-    kv = shard(kv_cache_pspec(pool_axes=pool_axes))
-    return {"out_shardings": (*[rep] * n_replicated, *rest, kv)}
-
-
-def _build_prefill_step_pooled(cfg: ModelConfig, mesh, pool_axes,
-                               with_top: bool = False, attn_impl: str = "xla",
-                               lockstep: bool = False,
-                               with_embeds: bool = False,
-                               greedy: bool = False):
-    from ..parallel._compat import shard_map
-
-    kvspec, bx, bx2 = _pooled_specs(pool_axes)
-
-    def body(params, kv, tokens, page_table, prefix_lens, chunk_lens, samp,
-             seeds, counters, *mm):
-        logits, kv = forward_prefill(
-            params, cfg, kv, tokens, page_table, prefix_lens, chunk_lens,
-            attn_impl=attn_impl,
-            # vision embeds shard over the same per-rank batch blocks as
-            # the tokens (vision × kv_partition)
-            extra_embeds=mm[0] if with_embeds else None,
-            extra_mask=mm[1] if with_embeds else None,
-            # mrope models ship the (t, h, w) streams as a third array
-            mm_positions=mm[2] if with_embeds and len(mm) > 2 else None,
-        )
-        out = sample_tokens_maybe_greedy(logits, samp, seeds, counters,
-                                         greedy)
-        logp = compute_logprobs(logits, out)
-        return _pack_out(out, logp, logits if with_top else None), out, kv
-
-    # the packed result is 1-D PER SHARD ([tok|logp|...] over local rows),
-    # so the global array is a concatenation of per-rank blocks — the
-    # host unpacks with `_unpack_rows(..., blocks=R)`
-    out_specs = (bx, bx, kvspec)
-    mm_specs = ()
-    if with_embeds:
-        mm_specs = (P(pool_axes, None, None), bx2)
-        if cfg.mrope_section:  # [B, 3, chunk] rope streams ride as mm[2]
-            mm_specs += (P(pool_axes, None, None),)
-    sm = shard_map(
-        body, mesh=mesh,
-        in_specs=(P(), kvspec, bx2, bx2, bx, bx, bx, bx, bx, *mm_specs),
-        out_specs=out_specs,
-        axis_names=set(pool_axes),
-    )
-    kw = _lockstep_pooled_kw(mesh, pool_axes, out_specs) if lockstep else {}
-    return partial(_ljit, name="prefill_step_pooled",
-                   donate_argnums=(1,), **kw)(sm)
-
-
-def _build_decode_step_pooled(cfg: ModelConfig, mesh, pool_axes, n_steps: int,
-                              max_valid_pos: int, penalized: bool = False,
-                              with_top: bool = False, attn_impl: str = "xla",
-                              lockstep: bool = False, greedy: bool = False):
-    from ..parallel._compat import shard_map
-
-    run = _make_decode_scan(cfg, n_steps, max_valid_pos, penalized,
-                            with_top, attn_impl, greedy)
-    kvspec, bx, bx2 = _pooled_specs(pool_axes)
-    # per-step packed results are 1-D per shard → [T, R * local] global
-    packed_spec = P(None, pool_axes)
-    mrope = bool(cfg.mrope_section)  # +rope_off operand (qwen2_vl)
-
-    if mrope:
-        def body(params, kv, tokens, positions, counters, counts, table,
-                 samp, seeds, rope_off):
-            return run(params, kv, tokens, positions, counters, counts,
-                       table, samp, seeds, rope_off)
-    else:
-        def body(params, kv, tokens, positions, counters, counts, table,
-                 samp, seeds):
-            return run(params, kv, tokens, positions, counters, counts,
-                       table, samp, seeds)
-
-    rope_specs = (bx,) if mrope else ()
-    if penalized:
-        out_specs = (packed_spec, bx, bx, bx, bx2, kvspec)
-        donate = (1, 5)
-    else:
-        out_specs = (packed_spec, bx, bx, bx, kvspec)
-        donate = (1,)
-    sm = shard_map(
-        body, mesh=mesh,
-        in_specs=(P(), kvspec, bx, bx, bx, bx2 if penalized else P(),
-                  bx2, bx, bx, *rope_specs),
-        out_specs=out_specs,
-        axis_names=set(pool_axes),
-    )
-    kw = _lockstep_pooled_kw(mesh, pool_axes, out_specs) if lockstep else {}
-    step = partial(_ljit, name=_decode_name(n_steps) + "_pooled",
-                   donate_argnums=donate, tags={"rung": n_steps}, **kw)(sm)
-    if penalized:
-        return step
-    # present the same call shape as _build_decode_step's plain variant
-    return lambda params, kv, tokens, positions, counters, table, samp, \
-        seeds, *rope: step(params, kv, tokens, positions, counters, None,
-                           table, samp, seeds, *rope)
-
-
-def _build_mixed_step_pooled(cfg: ModelConfig, mesh, pool_axes, n_steps: int,
-                             max_valid_pos: int, penalized: bool = False,
-                             with_top: bool = False, attn_impl: str = "xla",
-                             lockstep: bool = False, greedy: bool = False):
-    """Mixed (prefill chunk + decode block) step over a PARTITIONED pool:
-    the whole program runs manual-over-(dp, sp) — both sides' batches
-    arrive as R uniform per-rank row blocks with LOCAL page tables, so
-    every page gather/scatter stays on the shard owning the row's pages
-    while tp stays auto/GSPMD.  This is what lets the north-star decode
-    topology (dp×tp, kv_partition) keep its ITL flat under concurrent
-    prefills instead of falling back to prefill-stalls-decode
-    (reference analog: vLLM mixed batches / mocker scheduler.rs:240)."""
-    from ..parallel._compat import shard_map
-
-    body = _make_mixed_body(cfg, n_steps, max_valid_pos, penalized,
-                            with_top, attn_impl, greedy)
-    kvspec, bx, bx2 = _pooled_specs(pool_axes)
-    d_packed_spec = P(None, pool_axes)  # [T, R*local]
-    out_specs = (bx, d_packed_spec, kvspec)
-    rope_specs = (bx,) if cfg.mrope_section else ()
-    sm = shard_map(
-        body, mesh=mesh,
-        in_specs=(P(), kvspec,
-                  bx2, bx2, bx, bx, bx, bx, bx,
-                  bx, bx, bx, bx2 if penalized else P(), bx2, bx, bx,
-                  *rope_specs),
-        out_specs=out_specs,
-        axis_names=set(pool_axes),
-    )
-    kw = (_lockstep_pooled_kw(mesh, pool_axes, out_specs, n_replicated=2)
-          if lockstep else {})
-    return partial(_ljit, name="mixed_step_pooled",
-                   donate_argnums=(1,), tags={"rung": n_steps}, **kw)(sm)
-
-
-def _build_export_fn_pooled(cfg: ModelConfig, mesh, pool_axes,
-                            replicate_out: bool = False):
-    """Export LOCAL page ids from ONE pool rank: every shard gathers its
-    local candidates, the owner's survive a mask + psum, and the result
-    comes back replicated over the pool axes (still tp-sharded on
-    kv-heads; single-process callers can device_get it directly —
-    multihost lockstep sets `replicate_out` to gather tp too)."""
-    from ..parallel._compat import shard_map
-
-    kvspec, _, _ = _pooled_specs(pool_axes)
-
-    def body(kv, pages, rank):
-        r = _pool_linear_index(mesh, pool_axes)
-        m = (r == rank)
-        k = jnp.where(m, kv.k[:, pages], 0)
-        v = jnp.where(m, kv.v[:, pages], 0)
-        return (jax.lax.psum(k, pool_axes), jax.lax.psum(v, pool_axes))
-
-    sm = shard_map(
-        body, mesh=mesh,
-        in_specs=(kvspec, P(), P()),
-        out_specs=(P(), P()),
-        axis_names=set(pool_axes),
-    )
-    kw = {}
-    if replicate_out:
-        rep = NamedSharding(mesh, P())
-        kw["out_shardings"] = (rep, rep)
-    return _ljit(sm, **kw)
-
-
-def _build_export_fn_pp_pooled(cfg: ModelConfig, mesh,
-                               replicate_out: bool = False):
-    """Export LOCAL page ids from ONE dp rank of a pp×kv_partition pool:
-    the owner's page gathers are stage-local layer SLICES — a psum over
-    dp keeps the owner's values, then an all_gather over pp stitches the
-    stage slices back into full-layer blobs (the layout every consumer —
-    disagg transfer, KVBM host pool — expects)."""
-    from ..parallel._compat import shard_map
-    from ..parallel.pp_engine import _manual_only, kv_pspec_pp
-
-    kv_in = _manual_only(kv_pspec_pp(True).k, keep=("pp", "dp"))
-
-    def body(kv_k, kv_v, pages, rank):
-        m = (jax.lax.axis_index("dp") == rank)
-        k = jax.lax.psum(jnp.where(m, kv_k[:, pages], 0), "dp")
-        v = jax.lax.psum(jnp.where(m, kv_v[:, pages], 0), "dp")
-        return (jax.lax.all_gather(k, "pp", axis=0, tiled=True),
-                jax.lax.all_gather(v, "pp", axis=0, tiled=True))
-
-    sm = shard_map(
-        body, mesh=mesh, in_specs=(kv_in, kv_in, P(), P()),
-        out_specs=(P(), P()), axis_names={"pp", "dp"},
-    )
-    kw = {}
-    if replicate_out:
-        rep = NamedSharding(mesh, P())
-        kw["out_shardings"] = (rep, rep)
-    fn = _ljit(lambda kv, pages, rank: sm(kv.k, kv.v, pages, rank), **kw)
-    return fn
-
-
-def _build_import_fn_pp_pooled(cfg: ModelConfig, mesh,
-                               sharded_blob: bool = False):
-    """Write a full-layer (k, v) blob into ONE dp rank's local pages of a
-    pp×kv_partition pool: each pp stage slices its layer range out of
-    the blob, and only the owning dp rank's pages change.  With
-    `sharded_blob` the blob's PAGE axis arrives dp-sharded (multihost
-    per-shard fetch layout — non-owner blocks are zeros)."""
-    from ..parallel._compat import shard_map
-    from ..parallel.pp_engine import _manual_only, kv_pspec_pp
-
-    kv_in = _manual_only(kv_pspec_pp(True).k, keep=("pp", "dp"))
-    blob_spec = P(None, "dp", None, None, None) if sharded_blob else P()
-
-    def body(kv_k, kv_v, k_blob, v_blob, pages, rank):
-        s = jax.lax.axis_index("pp")
-        l_local = kv_k.shape[0]
-        kb = jax.lax.dynamic_slice_in_dim(k_blob, s * l_local, l_local, 0)
-        vb = jax.lax.dynamic_slice_in_dim(v_blob, s * l_local, l_local, 0)
-        m = (jax.lax.axis_index("dp") == rank)
-        k_new = jnp.where(m, kb.astype(kv_k.dtype), kv_k[:, pages])
-        v_new = jnp.where(m, vb.astype(kv_v.dtype), kv_v[:, pages])
-        return (kv_k.at[:, pages].set(k_new),
-                kv_v.at[:, pages].set(v_new))
-
-    sm = shard_map(
-        body, mesh=mesh,
-        in_specs=(kv_in, kv_in, blob_spec, blob_spec, P(), P()),
-        out_specs=(kv_in, kv_in), axis_names={"pp", "dp"},
-    )
-
-    @partial(_ljit, donate_argnums=(0,))
-    def imp(kv, k_blob, v_blob, pages, rank):
-        k_new, v_new = sm(kv.k, kv.v, k_blob, v_blob, pages, rank)
-        return type(kv)(k_new, v_new)
-
-    return imp
-
-
-def _build_import_fn_pooled(cfg: ModelConfig, mesh, pool_axes,
-                            sharded_blob: bool = False):
-    """Write a (k, v) blob into ONE pool rank's local pages; other ranks
-    rewrite their current values (padding rows hit each rank's local
-    trash page 0).  `sharded_blob` takes the blob's page axis SHARDED
-    over the pool axes (global [L, R*width, ...], real data only in the
-    owner rank's block) — the multihost per-shard-fetch layout where
-    non-owner hosts contribute zeros they never fetched; the default
-    replicated layout serves single-process imports."""
-    from ..parallel._compat import shard_map
-
-    kvspec, _, _ = _pooled_specs(pool_axes)
-    blob_spec = (P(None, pool_axes, None, None, None) if sharded_blob
-                 else P())
-
-    def body(kv, k_blob, v_blob, pages, rank):
-        r = _pool_linear_index(mesh, pool_axes)
-        m = (r == rank)
-        k_new = jnp.where(m, k_blob.astype(kv.k.dtype), kv.k[:, pages])
-        v_new = jnp.where(m, v_blob.astype(kv.v.dtype), kv.v[:, pages])
-        return type(kv)(
-            kv.k.at[:, pages].set(k_new), kv.v.at[:, pages].set(v_new)
-        )
-
-    sm = shard_map(
-        body, mesh=mesh,
-        in_specs=(kvspec, blob_spec, blob_spec, P(), P()),
-        out_specs=kvspec,
-        axis_names=set(pool_axes),
-    )
-    return partial(_ljit, donate_argnums=(0,))(sm)
 
 
 # -- multihost lockstep plan codec ----------------------------------------- #
@@ -1426,31 +233,11 @@ class JaxEngine:
         # job passes False and pins its devices)
     ):
         self.model_cfg = model_cfg
-        self.cfg = engine_cfg or EngineConfig()
         self.eos_token_ids = eos_token_ids or []
         self._kv_dtype = kv_dtype
-        # -- serving mesh (M3): params TP-sharded, KV sharded on kv-heads,
-        # batch sharded over dp.  XLA/GSPMD inserts the ICI collectives
-        # (the TPU-native replacement for the reference's engine-delegated
-        # `--tp/--dp` flags, SURVEY.md §2.6).
-        self.mesh = None
-        self._dp = 1
-        self._sp = 1
-        # kv_partition: pool pages sharded over the mesh's (dp, sp)
-        # shards — capacity scales with the mesh (engine.page_pool
-        # ShardedPagePool); steps run manual-over-(dp,sp) via shard_map
-        self._pooled = False
-        self._pool_ranks = 1
-        self._bax = "dp"  # batch-axis spec entry ("dp" | ("dp","sp"))
-        # multihost lockstep: rank 0 leads, others replay (follower_loop)
-        self._multihost = (jax.process_count() > 1 if multihost is None
-                           else multihost)
-        self._lockstep_leader = jax.process_index() == 0
-        if self._multihost and (parallel is None or parallel.world <= 1):
-            raise ValueError(
-                "multihost requires a ParallelConfig spanning the global "
-                "device set (dp*tp*sp == jax.device_count())"
-            )
+        # where everything lives, and the config this layout really runs
+        self.layout, self.cfg = Layout.resolve(
+            model_cfg, engine_cfg, parallel, devices, multihost, vision)
         # multihost blob staging (per-shard KV import fetch): lazy server
         # on the leader, cached fetch clients on followers
         self._blob_stage_srv = None
@@ -1458,217 +245,34 @@ class JaxEngine:
         self._blob_bytes_fetched = 0  # survive server/client close (stats)
         self._blob_bytes_staged = 0
         self._blob_bytes_served = 0
-        self._import_fn_sharded = None
-        self._pp = 1
-        # an expert model's prefill-path steps append their moe stats to
-        # the pack (flat and GSPMD engines; pp, sp and the partitioned
-        # pool build their steps elsewhere and carry none)
-        self._moe_stats = False
+        # what an expert model's prefill-path steps append to their pack
+        # (`Layout.carries_moe_stats`)
         self.moe_assignments_total = 0
         self.moe_experts_hit_total = 0
         self.moe_steps_total = 0
-        # a flat engine given a device lives on it: parameters, the KV
-        # pool and every host→device put are committed there (replicas of
-        # one process each take their own chip — worker --dp-ranks)
-        self._device = None
-        if devices is not None and (parallel is None or parallel.world <= 1):
-            self._device = list(devices)[0]
-        if parallel is not None and parallel.world > 1:
-            from ..parallel import make_mesh
-
-            self.mesh = make_mesh(parallel, devices)
-            self._dp = parallel.dp
-            self._sp = parallel.sp
-            self._pp = parallel.pp
-            if self._pp > 1 or self._sp > 1:
-                from ..models.llama import require_flat_layer_scan
-
-                require_flat_layer_scan(
-                    model_cfg, f"pp={self._pp}, sp={self._sp}")
-            if self._pp > 1:
-                if model_cfg.num_hidden_layers % self._pp:
-                    raise ValueError(
-                        f"pp={self._pp} must divide num_hidden_layers="
-                        f"{model_cfg.num_hidden_layers}"
-                    )
-                if self.cfg.kv_partition and parallel.sp > 1:
-                    raise ValueError(
-                        "pp×kv_partition partitions pages over dp only "
-                        "(sp within a stage is future work)"
-                    )
-                if vision is not None:
-                    raise ValueError(
-                        "pp does not support the vision tower yet"
-                    )
-                if parallel.tp > 1:
-                    bad = [k for k, v in {
-                        "q heads": model_cfg.num_attention_heads,
-                        "kv heads": model_cfg.num_key_value_heads,
-                        "vocab_size": model_cfg.vocab_size,
-                    }.items() if v % parallel.tp]
-                    if bad:
-                        raise ValueError(
-                            f"tp={parallel.tp} must evenly divide "
-                            f"{', '.join(bad)} for pp×tp serving"
-                        )
-                # decode microbatches the batch into pp groups, and the
-                # fused/mixed fast paths assume the flat dispatch shape.
-                # kv_partition buckets are PER-RANK (rows arrive as dp
-                # blocks), so they round to pp only; global buckets round
-                # to dp*pp
-                round_to = (self._pp if self.cfg.kv_partition
-                            else self._dp * self._pp)
-                self.cfg = dataclasses.replace(
-                    self.cfg,
-                    fuse_prefill_decode=False,
-                    mixed_prefill_tokens=0,
-                    decode_batch_buckets=sorted({
-                        -(-b // round_to) * round_to
-                        for b in self.cfg.decode_batch_buckets
-                    }),
-                )
-            if self._sp > 1:
-                # sp prefill is whole-remainder ring attention: no
-                # chunking (mixed dispatches would chunk), buckets
-                # divisible by sp.  Cached prefixes ARE supported (the
-                # ring starts at the prefix boundary) — except with a
-                # partitioned pool, whose prefix pages live on one
-                # (dp, sp) shard only and cannot feed the other shards'
-                # ring blocks
-                self.cfg = dataclasses.replace(
-                    self.cfg, mixed_prefill_tokens=0
-                )
-                if self.cfg.enable_prefix_caching and self.cfg.kv_partition:
-                    raise ValueError(
-                        "sp > 1 with kv_partition requires "
-                        "enable_prefix_caching=False (prefix pages are "
-                        "owner-shard-local)"
-                    )
-                if (self.cfg.max_prefill_tokens
-                        < self.cfg.max_model_len * self.cfg.prefill_batch_size):
-                    raise ValueError(
-                        "sp > 1 requires max_prefill_tokens >= "
-                        "max_model_len * prefill_batch_size — the step "
-                        "budget is shared across co-planned prompts and "
-                        "none may be split into chunks"
-                    )
-                bad = [b for b in self.cfg.chunk_buckets if b % self._sp]
-                if bad:
-                    raise ValueError(
-                        f"chunk buckets {bad} not divisible by sp={self._sp}"
-                    )
-                if (parallel.tp > 1 and model_cfg.is_moe
-                        and (model_cfg.moe_impl not in ("auto", "ragged", "a2a")
-                             or model_cfg.num_experts % parallel.tp)):
-                    raise ValueError(
-                        "sp×tp MoE requires moe_impl='auto'|'ragged'|'a2a' and "
-                        "num_experts divisible by tp"
-                    )
-                # moe_impl='a2a' composes with prefix caching: capacity
-                # drops are per-token-per-peer (a pure function of the
-                # token's own routing — parallel/wide_ep.py), so cached
-                # KV is reproducible across batch compositions
-                # the sp shard_map's param specs shard heads, the vocab,
-                # and (dense models) the ffn dim over tp — catch uneven
-                # splits here with a clear message instead of an opaque
-                # shard_map shape error at first prefill.  MoE shards the
-                # EXPERT dim instead (checked above), so its ffn width
-                # need not divide
-                uneven = {
-                    "q heads": model_cfg.num_attention_heads,
-                    "kv heads": model_cfg.num_key_value_heads,
-                    "vocab_size": model_cfg.vocab_size,
-                }
-                if not model_cfg.is_moe:
-                    uneven["intermediate_size"] = model_cfg.intermediate_size
-                bad_dims = [k for k, v in uneven.items() if v % parallel.tp]
-                if bad_dims:
-                    raise ValueError(
-                        f"tp={parallel.tp} must evenly divide "
-                        f"{', '.join(bad_dims)} for sp×tp prefill"
-                    )
-            if self.cfg.kv_partition:
-                # sharded pool: one partition per (dp, sp) shard; batches
-                # are laid out as R uniform per-rank blocks (buckets stay
-                # PER-RANK, so no dp-divisibility rounding).  The FUSED
-                # fast path stays off (it reuses prefill rows as decode
-                # rows, which only works on the identity layout) but
-                # MIXED dispatches run: the pooled mixed step takes the
-                # same per-rank block layouts both sides already use
-                self._pooled = True
-                self._pool_ranks = self._dp * self._sp
-                if self._sp > 1:
-                    self._bax = ("dp", "sp")
-                self.cfg = dataclasses.replace(
-                    self.cfg, fuse_prefill_decode=False,
-                )
-                if max(self.cfg.decode_batch_buckets) < self.cfg.max_num_seqs:
-                    # bucket_for clamps to buckets[-1]: a per-rank decode
-                    # group wider than the largest bucket would break the
-                    # R-uniform-blocks layout and land rows on the wrong
-                    # pool shard — reject the config instead
-                    raise ValueError(
-                        f"kv_partition requires max(decode_batch_buckets)"
-                        f"={max(self.cfg.decode_batch_buckets)} >= "
-                        f"max_num_seqs={self.cfg.max_num_seqs}"
-                    )
-            else:
-                # every batch shape must divide dp (rows beyond the real
-                # batch are trash-page padding)
-                self.cfg = dataclasses.replace(
-                    self.cfg,
-                    decode_batch_buckets=sorted(
-                        {-(-b // self._dp) * self._dp
-                         for b in self.cfg.decode_batch_buckets}
-                    ),
-                )
-        elif self.cfg.kv_partition:
-            raise ValueError(
-                "kv_partition requires a serving mesh (ParallelConfig "
-                "with dp*sp > 1)"
-            )
-        self._attn_impl = resolve_attention_impl(
-            self.cfg.attention_impl, meshed=self.mesh is not None
-        )
-        self._moe_stats = (_carries_moe_stats(model_cfg) and self._pp == 1
-                           and self._sp == 1 and not self._pooled)
         if self.cfg.quantization == "int8":
             from ..models.quantization import quantize_params
 
             params = quantize_params(params)
         if self.cfg.fuse_projections:
-            if self.mesh is not None:
-                raise ValueError(
-                    "fuse_projections is single-device only (the fused "
-                    "output axis does not carry the megatron tp specs)"
-                )
             from ..models.llama import fuse_projections
 
             params = fuse_projections(params)
         # vision tower (multimodal): embeds computed engine-side at first
-        # prefill of the sequence, injected in place of placeholder tokens
+        # prefill of the sequence, injected in place of placeholder tokens.
+        # Composes with multihost (the tower runs leader-local and the
+        # resulting embeds ride the lockstep prefill plan), with
+        # kv_partition (embeds shard with the per-rank batch blocks), and
+        # with sp (embeds/mask shard their sequence axis over the ring
+        # exactly like the tokens)
         self.vision = vision
         self._encode_fn = None
-        self._embed_fn = None
-        # vision composes with multihost (the tower runs leader-local and
-        # the resulting embeds ride the lockstep prefill plan), with
-        # kv_partition (embeds shard with the per-rank batch blocks),
-        # and with sp (embeds/mask shard their sequence axis over the
-        # ring exactly like the tokens)
-        if model_cfg.mrope_section:
-            # M-RoPE (qwen2_vl): decode ropes at slot + per-seq delta.
-            # r5: the rope-offset operand threads through the fused,
-            # mixed, pooled (kv_partition) and sp-ring step variants, so
-            # qwen2-vl serves on meshed engines with mixed scheduling on
-            # (VERDICT r4 item 5).  pp stages don't carry it yet.
-            if self._pp > 1:
-                raise ValueError("mrope models do not serve under pp yet")
-        self.params = self._shard_params(params)
-        self.kv = self._make_kv()
+        self.params = self.layout.shard_params(params)
+        self.kv = self.layout.make_kv(kv_dtype)
         self._extra_event_sinks: List[Callable[[KvEvent], None]] = []
         if event_sink:
             self._extra_event_sinks.append(event_sink)
-        self.pool = self._make_pool()
+        self.pool = self.layout.make_pool(self._emit_event)
         self.scheduler = Scheduler(self.cfg, self.pool)
         # preemption parking lot (overload control): batch-class victims
         # preempted mid-decode export byte-exact KV here and resume
@@ -1682,31 +286,6 @@ class JaxEngine:
         self.scheduler.park_fn = self._park_seq
         self.scheduler.resume_fn = self._resume_parked
         self.scheduler.unpark_fn = self._unpark_seq
-        # step variants compiled lazily: (penalized, with_top) for decode,
-        # with_top for prefill
-        self._prefill_steps: Dict[bool, Callable] = {}
-        self._decode_steps: Dict[tuple, Callable] = {}
-        self._mixed_steps: Dict[tuple, Callable] = {}
-        if self._pooled and self._pp > 1:
-            self._export_fn = _build_export_fn_pp_pooled(
-                self.model_cfg, self.mesh, replicate_out=self._multihost,
-            )
-            self._import_fn = _build_import_fn_pp_pooled(
-                self.model_cfg, self.mesh,
-            )
-        elif self._pooled:
-            self._export_fn = _build_export_fn_pooled(
-                self.model_cfg, self.mesh, self._pool_axes,
-                replicate_out=self._multihost,
-            )
-            self._import_fn = _build_import_fn_pooled(
-                self.model_cfg, self.mesh, self._pool_axes
-            )
-        else:
-            self._export_fn = _build_export_fn(
-                self.mesh if self._multihost else None
-            )
-            self._import_fn = _build_import_fn()
         # device ops queued by the loop thread, executed by the pump between
         # steps (self.kv is only ever touched between steps)
         self._pending_ops: List = []
@@ -1862,7 +441,7 @@ class JaxEngine:
                 pages.append(page)
         if not pages:
             return []
-        if self._pooled:
+        if self.layout.pooled:
             # a batch of cached hashes may span pool ranks; the export
             # jit masks to ONE rank per call — group into chunks
             by_rank: Dict[int, List[tuple]] = {}
@@ -2018,238 +597,20 @@ class JaxEngine:
         drop its lot entry (credits the ledger's parked_pages)."""
         self.parking.discard(seq.request_id)
 
-    # -- sharding helpers ---------------------------------------------------- #
-
-    def _shard_params(self, params):
-        if self.mesh is None:
-            if self._device is not None:
-                return jax.device_put(params, self._device)
-            return params
-        if self._pp > 1:
-            from ..parallel.pp_engine import shard_params_pp
-
-            return shard_params_pp(params, self.model_cfg, self.mesh)
-        from ..parallel import shard_params
-
-        return shard_params(params, self.model_cfg, self.mesh)
-
-    def _make_pool(self):
-        if self._pooled:
-            from .page_pool import ShardedPagePool
-
-            return ShardedPagePool(
-                self._pool_ranks, self.cfg.num_pages, self.cfg.page_size,
-                event_sink=self._emit_event,
-            )
-        return PagePool(
-            self.cfg.num_pages, self.cfg.page_size, event_sink=self._emit_event
-        )
-
-    @property
-    def _pool_axes(self):
-        return ("dp", "sp") if self._sp > 1 else ("dp",)
-
-    def _make_kv(self) -> KVCache:
-        # a pinned flat engine allocates its pool on its own device from
-        # the start (never a transient copy on the default device)
-        with (jax.default_device(self._device) if self._device is not None
-              else contextlib.nullcontext()):
-            kv = KVCache.create(
-                self.model_cfg, self._pool_ranks * self.cfg.num_pages,
-                self.cfg.page_size, self._kv_dtype,
-            )
-        if self.mesh is None:
-            if self._device is not None:
-                kv = jax.device_put(kv, self._device)
-            return kv
-        if self._pp > 1:
-            from ..parallel.multihost import host_array_to_global
-            from ..parallel.pp_engine import kv_pspec_pp
-
-            return jax.tree.map(
-                lambda x, s: host_array_to_global(self.mesh, s, x),
-                kv, kv_pspec_pp(pooled=self._pooled),
-            )
-        from ..parallel import shard_kv_cache
-
-        return shard_kv_cache(
-            kv, self.mesh,
-            pool_axes=self._pool_axes if self._pooled else None,
-        )
-
-    def _put(self, arr, *axes):
-        """Host array → device, batch axis sharded over dp when meshed.
-        Multihost: every process passes the same logical array and
-        contributes the shards its local devices own."""
-        if self.mesh is None:
-            if self._device is not None:
-                return jax.device_put(arr, self._device)
-            return jnp.asarray(arr)
-        if self._multihost:
-            from ..parallel.multihost import host_array_to_global
-
-            return host_array_to_global(self.mesh, P(*axes), np.asarray(arr))
-        return jax.device_put(arr, NamedSharding(self.mesh, P(*axes)))
-
-    def _put_samp(self, samp: SamplingParams, axes=None) -> SamplingParams:
-        if self.mesh is None:
-            return samp
-        axes = axes if axes is not None else self._bax
-        if self._multihost:
-            return jax.tree.map(lambda a: self._put(np.asarray(a), axes), samp)
-        return jax.device_put(samp, NamedSharding(self.mesh, P(axes)))
-
-    def _pad_batch(self, n: int) -> int:
-        """Round a batch size up to a dp multiple (pad rows hit the trash
-        page)."""
-        return -(-n // self._dp) * self._dp
-
-    # -- step variants -------------------------------------------------------- #
-
-    def _get_prefill_step(self, with_top: bool, with_mm: bool = False,
-                          greedy: bool = False):
-        key = (with_top, with_mm, greedy)
-        if key not in self._prefill_steps:
-            if self._sp > 1:
-                self._prefill_steps[key] = _build_prefill_step_sp(
-                    self.model_cfg, self.mesh, with_top,
-                    lockstep=self._multihost,
-                    pool_axes=self._pool_axes if self._pooled else None,
-                    with_embeds=with_mm, greedy=greedy,
-                )
-            elif self._pp > 1:
-                self._prefill_steps[key] = _build_prefill_step_pp(
-                    self.model_cfg, self.mesh, with_top=with_top,
-                    attn_impl=self._attn_impl, lockstep=self._multihost,
-                    pooled=self._pooled, greedy=greedy,
-                )
-            elif self._pooled:
-                self._prefill_steps[key] = _build_prefill_step_pooled(
-                    self.model_cfg, self.mesh, self._pool_axes,
-                    with_top=with_top, attn_impl=self._attn_impl,
-                    lockstep=self._multihost, with_embeds=with_mm,
-                    greedy=greedy,
-                )
-            else:
-                self._prefill_steps[key] = _build_prefill_step(
-                    self.model_cfg, with_top, attn_impl=self._attn_impl,
-                    lockstep_mesh=self.mesh if self._multihost else None,
-                    with_embeds=with_mm, greedy=greedy,
-                )
-        return self._prefill_steps[key]
-
-    def _get_decode_step(self, penalized: bool, with_top: bool,
-                         greedy: bool = False,
-                         n_steps: Optional[int] = None):
-        """The decode-block step for one (variant, n_steps) key.
-        `n_steps` is the block-ladder rung (None → `decode_steps`): each
-        rung is its own compiled program, cached alongside the variant
-        flags, so the scheduler can switch block sizes per dispatch with
-        zero retraces after warmup."""
-        n_steps = n_steps or self.cfg.decode_steps
-        key = (penalized, with_top, greedy, n_steps)
-        if key not in self._decode_steps:
-            if self._pp > 1:
-                self._decode_steps[key] = _build_decode_step_pp(
-                    self.model_cfg, self.mesh, n_steps,
-                    self.cfg.hard_cap, penalized=penalized,
-                    with_top=with_top, attn_impl=self._attn_impl,
-                    lockstep=self._multihost, pooled=self._pooled,
-                    greedy=greedy,
-                )
-            elif self._pooled:
-                self._decode_steps[key] = _build_decode_step_pooled(
-                    self.model_cfg, self.mesh, self._pool_axes,
-                    n_steps, self.cfg.hard_cap,
-                    penalized=penalized, with_top=with_top,
-                    attn_impl=self._attn_impl, lockstep=self._multihost,
-                    greedy=greedy,
-                )
-            else:
-                self._decode_steps[key] = _build_decode_step(
-                    self.model_cfg, n_steps, self.cfg.hard_cap,
-                    penalized=penalized, with_top=with_top,
-                    attn_impl=self._attn_impl,
-                    lockstep_mesh=self.mesh if self._multihost else None,
-                    greedy=greedy,
-                )
-        return self._decode_steps[key]
-
-    def _get_spec_step(self, greedy: bool = False):
-        """The draft-verify decode variant, cached beside the plain
-        variants under a `spec` key (one compile per greedy flag; jit
-        shape-caches the batch/table buckets)."""
-        key = ("spec", greedy)
-        if key not in self._decode_steps:
-            self._decode_steps[key] = _build_spec_verify_step(
-                self.model_cfg, greedy=greedy, attn_impl=self._attn_impl,
-                lockstep_mesh=self.mesh if self._multihost else None,
-            )
-        return self._decode_steps[key]
-
-    def _get_cc_step(self, penalized: bool, with_top: bool,
-                     greedy: bool = False, n_steps: Optional[int] = None):
-        """The continuous-chain decode variant, cached beside the plain
-        rung programs under a "cc" key (flat engines only — `_cc_ok`
-        gates dispatch)."""
-        n_steps = n_steps or self.cfg.decode_steps
-        key = ("cc", penalized, with_top, greedy, n_steps)
-        if key not in self._decode_steps:
-            self._decode_steps[key] = _build_decode_step_cc(
-                self.model_cfg, n_steps, self.cfg.hard_cap,
-                penalized=penalized, with_top=with_top,
-                attn_impl=self._attn_impl, greedy=greedy,
-            )
-        return self._decode_steps[key]
-
-    def _get_mixed_step(self, penalized: bool, with_top: bool,
-                        greedy: bool = False,
-                        n_steps: Optional[int] = None):
-        n_steps = n_steps or self.cfg.decode_steps
-        key = (penalized, with_top, greedy, n_steps)
-        if key not in self._mixed_steps:
-            if self._pooled:
-                self._mixed_steps[key] = _build_mixed_step_pooled(
-                    self.model_cfg, self.mesh, self._pool_axes,
-                    n_steps, self.cfg.hard_cap,
-                    penalized=penalized, with_top=with_top,
-                    attn_impl=self._attn_impl, lockstep=self._multihost,
-                    greedy=greedy,
-                )
-            else:
-                self._mixed_steps[key] = _build_mixed_step(
-                    self.model_cfg, n_steps, self.cfg.hard_cap,
-                    penalized=penalized, with_top=with_top,
-                    attn_impl=self._attn_impl,
-                    lockstep_mesh=self.mesh if self._multihost else None,
-                    greedy=greedy,
-                )
-        return self._mixed_steps[key]
-
     @property
     def compiled_variants(self) -> Dict[str, List]:
         """Public view of the compiled step-variant cache keys per step
-        family ({"prefill": [...], "decode": [...], "mixed": [...]}).
-        Prefill keys are (with_top, with_mm, greedy); decode/mixed keys
-        are (penalized, with_top, greedy, n_steps) — plus ("spec",
-        greedy) for the draft-verify variant.  Benchmarks and warmup
+        family (`Layout.compiled_variants`).  Benchmarks and warmup
         harnesses key off this instead of the private caches (e.g. "has
         the mixed program compiled yet", "is every ladder rung warm")."""
-        return {
-            "prefill": sorted(self._prefill_steps, key=repr),
-            "decode": sorted(self._decode_steps, key=repr),
-            "mixed": sorted(self._mixed_steps, key=repr),
-        }
+        return self.layout.compiled_variants
 
     @property
     def compiled_decode_rungs(self) -> set:
         """Block-ladder rungs with a compiled decode OR mixed program
         (ladder-aware warmup checks coverage against
         `cfg.block_ladder`)."""
-        return {
-            k[3] for k in (*self._decode_steps, *self._mixed_steps)
-            if isinstance(k, tuple) and len(k) == 4
-        }
+        return self.layout.compiled_decode_rungs
 
     @property
     def rung_histogram(self) -> Dict[int, int]:
@@ -2320,7 +681,7 @@ class JaxEngine:
         # consumers (/metrics.json, the worker Prometheus collector)
         for rung, n in sorted(self._rung_dispatches.items()):
             setattr(m, f"decode_rung{rung}_dispatches_total", n)
-        if self._moe_stats:  # an expert model's prefill-path steps
+        if self.layout.carries_moe_stats:  # an expert model's prefill path
             m.moe_assignments_total = self.moe_assignments_total
             m.moe_experts_hit_total = self.moe_experts_hit_total
             m.moe_steps_total = self.moe_steps_total
@@ -2509,7 +870,7 @@ class JaxEngine:
         if self.scheduler.deferred_free:
             self.pool.free(self.scheduler.deferred_free)
             self.scheduler.deferred_free = None
-        if self._multihost and self._lockstep_leader:
+        if self.layout.lockstep and self.layout.is_leader:
             # release follower ranks blocked in follower_loop — even when
             # the engine never served a request (no step executor yet)
             await asyncio.get_running_loop().run_in_executor(
@@ -2793,20 +1154,6 @@ class JaxEngine:
         tlps = np.concatenate([p[3] for p in parts], axis=-2)
         return toks, logp, tids, tlps
 
-    @property
-    def _prefill_blocks(self) -> int:
-        """Packed-layout block count for prefill results (sp and pp
-        variants sample at the jit level, so their layout is flat)."""
-        return (self._pool_ranks
-                if (self._pooled and self._sp == 1 and self._pp == 1)
-                else 1)
-
-    @property
-    def _decode_blocks(self) -> int:
-        """pp packs [T, B] at the jit level (global row order), so its
-        layout is flat even on a partitioned pool."""
-        return self._pool_ranks if (self._pooled and self._pp == 1) else 1
-
     # Batch ROW LAYOUTS: every per-step array builder takes a `rows` list
     # (Sequence | None, None = padding row).  Unpartitioned engines use
     # the identity layout (live rows first, pad tail); a partitioned pool
@@ -2815,10 +1162,11 @@ class JaxEngine:
     # owns its pages.
 
     def _decode_rows(self, seqs: List[Sequence]) -> List[Optional[Sequence]]:
-        if not self._pooled:
+        if not self.layout.pooled:
             Bb = bucket_for(len(seqs), self.cfg.decode_batch_buckets)
             return list(seqs) + [None] * (Bb - len(seqs))
-        by_rank: List[List[Sequence]] = [[] for _ in range(self._pool_ranks)]
+        by_rank: List[List[Sequence]] = [
+            [] for _ in range(self.layout.pool_ranks)]
         for s in seqs:
             by_rank[s.kv_rank].append(s)
         widest = max([1] + [len(g) for g in by_rank])
@@ -2837,24 +1185,19 @@ class JaxEngine:
         return rows
 
     def _prefill_rows(self, items: List[PrefillItem]) -> List[Optional[PrefillItem]]:
-        if not self._pooled:
+        if not self.layout.pooled:
             # pad to the CONSTANT prefill_batch_size: each distinct row
             # count is otherwise its own prefill/mixed program (a compile
             # of seconds, landing mid-measurement on whichever request
             # first brings a fresh row count); padding rows run a 1-token
             # chunk into the trash page
-            B = self._pad_batch(max(len(items), self.cfg.prefill_batch_size))
+            B = self.layout.pad_batch(
+                max(len(items), self.cfg.prefill_batch_size))
             return list(items) + [None] * (B - len(items))
-        if self._sp > 1:
-            # sp ring prefill shards ROWS over dp only (the sequence axis
-            # rides sp): group by dp shard; each row's sp slot goes in
-            # the per-row `owner` array instead of the layout
-            groups, key = self._dp, (lambda it: it.seq.kv_rank // self._sp)
-        else:
-            groups, key = self._pool_ranks, (lambda it: it.seq.kv_rank)
-        by_rank: List[List[PrefillItem]] = [[] for _ in range(groups)]
+        by_rank: List[List[PrefillItem]] = [
+            [] for _ in range(self.layout.prefill_groups)]
         for it in items:
-            by_rank[key(it)].append(it)
+            by_rank[self.layout.prefill_slot(it.seq.kv_rank)[0]].append(it)
         Br = max([1] + [len(g) for g in by_rank])
         rows: List[Optional[PrefillItem]] = []
         for g in by_rank:
@@ -2893,6 +1236,23 @@ class JaxEngine:
                 out[i] = s.rope_delta
         return out
 
+    def _rope_operand(self, rope_off, like: np.ndarray) -> tuple:
+        """The trailing `rope_off` operand of a decode-side step: mrope
+        models only (qwen2_vl)."""
+        if not self.model_cfg.mrope_section:
+            return ()
+        return (self.layout.put_rows(
+            np.zeros_like(like) if rope_off is None else rope_off),)
+
+    @staticmethod
+    def _start_host_copy(arr) -> None:
+        """Start a result's device→host copy now, so that it rides back
+        behind its own program and not behind what is dispatched next."""
+        try:
+            arr.copy_to_host_async()
+        except Exception:  # lint: allow(swallowed-exception): copy_to_host_async optional; fetch path device_gets anyway
+            pass
+
     def _table_array(self, rows: List[Optional[Sequence]]) -> np.ndarray:
         """Page-table batch, width bucketed to the longest sequence present
         (attention/gather cost scales with width, so short-context batches
@@ -2906,7 +1266,7 @@ class JaxEngine:
             if s is None:
                 continue
             n = min(len(s.pages), width)
-            if self._pooled:
+            if self.layout.pooled:
                 table[i, :n] = [p % npp for p in s.pages[:n]]
             else:
                 table[i, :n] = s.pages[:n]
@@ -3005,9 +1365,9 @@ class JaxEngine:
 
     def _note_moe(self, packed: np.ndarray) -> dict:
         """The moe stats an expert model's prefill-path step appended to
-        its pack (`_carries_moe_stats`): added to the engine's counters,
+        its pack (`steps.carries_moe_stats`): added to the engine's counters,
         returned as the step slice's attributes.  {} for any other step."""
-        if not self._moe_stats:
+        if not self.layout.carries_moe_stats:
             return {}
         assigned, hit, load = (int(v) for v in packed[-MOE_STATS:])
         self.moe_assignments_total += assigned
@@ -3022,7 +1382,7 @@ class JaxEngine:
         the configured implementation where no choice was noted."""
         return xla_ledger.path_choice(
             site, batch=batch, chunk=chunk,
-            table_tokens=width * self.cfg.page_size) or self._attn_impl
+            table_tokens=width * self.cfg.page_size) or self.layout.attn_impl
 
     @staticmethod
     def _credit_own(seqs, ns: int) -> None:
@@ -3107,21 +1467,8 @@ class JaxEngine:
         self.scheduler.in_flight = tuple(step.seqs)
         if older is not None:
             self._prefill_consume(older)
-        if step.fused or not self._can_hold_step():
+        if step.fused or not self.layout.holds_step_in_flight:
             self._prefill_consume(step)
-
-    def _can_hold_step(self) -> bool:
-        """May a dispatched prefill step wait for its fetch while the next
-        one is planned?  Every single-process engine (flat, pooled, sp,
-        pp): one process issues every program, so dispatch order is the
-        devices' order.  Not a multihost leader: its next plan reaches the
-        followers through `broadcast_plan`, a device collective that
-        queues behind the program in flight on every rank, so the leader
-        would wait out the step inside the broadcast and the followers
-        (which replay host arrays in order and never fetch) would gain
-        nothing; a recover must also find leader and followers agreeing on
-        which steps ran."""
-        return not self._multihost
 
     def _prefill_dispatch(self, items: List[PrefillItem]) -> "_PrefillStep":
         t0_ev = self._step_t0_ns = self.events.now()
@@ -3131,7 +1478,7 @@ class JaxEngine:
         seq_rows = [it.seq if it else None for it in item_rows]
         tokens, prefix, chunk, chunk_bucket = self._prefill_arrays(item_rows)
         seqs = [it.seq for it in items]
-        if (self._sp > 1 and prefix.any()
+        if (self.layout.sp > 1 and prefix.any()
                 and not self.cfg.enable_prefix_caching):
             # cannot happen with prefix caching off + whole-prompt chunks;
             # guards scheduler regressions from silently corrupting sp runs
@@ -3147,13 +1494,13 @@ class JaxEngine:
         if any(s.mm_embeds is not None for s in seqs):
             mm = self._mm_arrays(item_rows, B, chunk_bucket)
         owner = None
-        if self._pooled and self._sp > 1:
+        if self.layout.names_owner:
             owner = np.zeros((B,), np.int32)
             for i, it in enumerate(item_rows):
                 if it is not None:
-                    owner[i] = it.seq.kv_rank % self._sp
+                    owner[i] = self.layout.prefill_slot(it.seq.kv_rank)[1]
         greedy = self._is_greedy(seq_rows)
-        if self._multihost:
+        if self.layout.lockstep:
             self._lockstep_send({
                 "kind": "prefill", "with_top": with_top,
                 "arrays": [tokens, table, prefix, chunk,
@@ -3173,10 +1520,7 @@ class JaxEngine:
         # decode dispatches enqueue: on a FIFO-ish transfer path the copy
         # then rides right behind the prefill, keeping TTFT at prefill
         # latency instead of the whole fused chain's
-        try:
-            packed_d.copy_to_host_async()
-        except Exception:  # lint: allow(swallowed-exception): copy_to_host_async optional; fetch path device_gets anyway
-            pass
+        self._start_host_copy(packed_d)
         # the dispatch is committed: account the computed tokens NOW so a
         # fused decode chain, and the next plan if this step stays in
         # flight, start from current positions (errors reset all state via
@@ -3236,7 +1580,7 @@ class JaxEngine:
             t_got = self.events.now()
             moe_attrs = self._note_moe(packed)
             out, logp, tids, tlps = self._unpack_rows(
-                packed, B, step.with_top, blocks=self._prefill_blocks,
+                packed, B, step.with_top, blocks=self.layout.prefill_blocks,
             )
             for i, it in enumerate(step.item_rows):
                 if it is None:
@@ -3292,7 +1636,7 @@ class JaxEngine:
             not self.cfg.fuse_prefill_decode
             or self.cfg.speculative_ngram_k > 0  # spec drafts need the
             # fetched prefill token; the verify path starts next dispatch
-            or self._multihost  # followers replay from host arrays only
+            or self.layout.lockstep  # followers replay from host arrays only
             or not items
             or not all(it.samples for it in items)
             or any(s.status != "running" for s in seqs)  # preempted rows
@@ -3374,7 +1718,7 @@ class JaxEngine:
             if clock is not None and not clock:
                 clock[:] = t_fetch, time.monotonic_ns()
             out, logp, tids, tlps = self._unpack_rows(
-                packed, Bb, with_top, blocks=self._decode_blocks,
+                packed, Bb, with_top, blocks=self.layout.decode_blocks,
             )  # [T, B] each
             T = out.shape[0]
             for i, s in enumerate(rows):
@@ -3493,7 +1837,7 @@ class JaxEngine:
         # one short block from now
         T, _ = self.scheduler.select_decode_rung()
         self._note_dispatch("mixed", T)
-        if self._multihost:
+        if self.layout.lockstep:
             sparse = (self._encode_counts_sparse(d_rows)
                       if penalized else None)
             self._lockstep_send({
@@ -3526,7 +1870,7 @@ class JaxEngine:
         t_got = self.events.now()
         moe_attrs = self._note_moe(p_packed)
         p_out, p_logp, p_tids, p_tlps = self._unpack_rows(
-            p_packed, Bp, with_top, blocks=self._prefill_blocks,
+            p_packed, Bp, with_top, blocks=self.layout.prefill_blocks,
         )
         for i, it in enumerate(item_rows):
             if it is None:
@@ -3562,29 +1906,20 @@ class JaxEngine:
                         rope_off=None, greedy=False, n_steps=None):
         """Issue the jitted mixed step (identical on leader and followers);
         returns the two packed device outputs."""
-        step = self._get_mixed_step(penalized, with_top, greedy, n_steps)
-        cts_d = self._put(d_counts, self._bax, None) if penalized else None
-        rope = ()
-        if self.model_cfg.mrope_section:
-            if rope_off is None:
-                rope_off = np.zeros_like(d_pos)
-            rope = (self._put(rope_off, self._bax),)
+        step = self.layout.mixed_step(penalized, with_top, greedy, n_steps)
+        put = self.layout.put_rows
+        cts_d = put(d_counts) if penalized else None
+        rope = self._rope_operand(rope_off, d_pos)
         p_packed, d_packed, self.kv = step(
             self.params, self.kv,
-            self._put(p_tokens, self._bax, None), self._put(p_table, self._bax, None),
-            self._put(p_prefix, self._bax), self._put(p_chunk, self._bax),
-            self._put_samp(p_samp), self._put(p_seeds, self._bax),
-            self._put(p_ctr, self._bax),
-            self._put(d_tokens, self._bax), self._put(d_pos, self._bax),
-            self._put(d_ctr, self._bax), cts_d, self._put(d_table, self._bax, None),
-            self._put_samp(d_samp), self._put(d_seeds, self._bax),
+            put(p_tokens), put(p_table), put(p_prefix), put(p_chunk),
+            self.layout.put_samp(p_samp), put(p_seeds), put(p_ctr),
+            put(d_tokens), put(d_pos), put(d_ctr), cts_d, put(d_table),
+            self.layout.put_samp(d_samp), put(d_seeds),
             *rope,
         )
-        for a in (p_packed, d_packed):
-            try:  # start both host copies; they ride back in fetch order
-                a.copy_to_host_async()
-            except Exception:  # lint: allow(swallowed-exception): copy_to_host_async optional; fetch path device_gets anyway
-                pass
+        for a in (p_packed, d_packed):  # they ride back in fetch order
+            self._start_host_copy(a)
         return p_packed, d_packed
 
     def _attach_mm(self, seq, request) -> Optional[str]:
@@ -3840,38 +2175,20 @@ class JaxEngine:
         the sampled tokens as a device int32 carry.  `owner` rides along
         only for partitioned-pool sp prefill (rows shard over dp; the
         owner array names each row's sp slot)."""
-        extra = ()
-        # sp prefill shards batch ROWS over dp only (the sequence axis
-        # rides sp), so pooled-sp prefill arrays must not demand a
-        # (dp, sp)-divisible batch
-        bax = "dp" if self._sp > 1 else self._bax
-        if self._pooled and self._sp > 1:
-            extra = (self._put(owner, "dp"),)
-        elif self._sp > 1:
-            # cached-prefix pages, width-bucketed to the batch's LONGEST
-            # prefix (width 0 → the prefix path compiles out entirely)
-            maxp = int(prefix.max()) if prefix.size else 0
-            wp = (0 if maxp == 0 else bucket_for(
-                -(-maxp // self.cfg.page_size),
-                self.cfg.table_width_buckets,
-            ))
-            wp = min(wp, table.shape[1])
-            extra = (self._put(np.ascontiguousarray(table[:, :wp]),
-                               "dp", None),)
-        packed_d, tok_d, kv = self._get_prefill_step(
+        put = partial(self.layout.put_rows, prefill=True)
+        packed_d, tok_d, kv = self.layout.prefill_step(
             with_top, bool(mm), greedy)(
             self.params,
             self.kv,
-            self._put(tokens, bax, None),
-            self._put(table, bax, None),
-            self._put(prefix, bax),
-            self._put(chunk, bax),
-            self._put_samp(samp, axes=bax),
-            self._put(seeds, bax),
-            self._put(counters, bax),
-            *(self._put(m, bax, None) if m.ndim == 2
-              else self._put(m, bax, None, None) for m in mm),
-            *extra,
+            put(tokens),
+            put(table),
+            put(prefix),
+            put(chunk),
+            self.layout.put_samp(samp, prefill=True),
+            put(seeds),
+            put(counters),
+            *map(put, mm),
+            *self.layout.prefill_tail(table, prefix, owner),
         )
         self.kv = kv
         return packed_d, tok_d
@@ -3923,7 +2240,7 @@ class JaxEngine:
         packed layout, and rows within k+1 tokens of the context cap
         would write drafts past their page-table horizon."""
         k = self.cfg.speculative_ngram_k
-        if k <= 0 or self._pooled or self._pp > 1 or self._sp > 1:
+        if k <= 0 or not self.layout.runs_spec:
             return False
         if any(s.opts.penalized or s.opts.top_logprobs > 0 for s in seqs):
             return False
@@ -3960,7 +2277,7 @@ class JaxEngine:
         samp = self._samp_arrays(rows)
         rope_off = self._rope_array(rows)
         greedy = self._is_greedy(rows)
-        if self._multihost:
+        if self.layout.lockstep:
             self._lockstep_send({
                 "kind": "spec", "greedy": greedy,
                 "arrays": [tokens, positions, counters, table,
@@ -4015,26 +2332,20 @@ class JaxEngine:
                        seeds, greedy, rope_off=None):
         """Issue the jitted draft-verify step (identical on leader and
         followers); returns the packed device output."""
-        step = self._get_spec_step(greedy)
-        rope = ()
-        if self.model_cfg.mrope_section:
-            if rope_off is None:
-                rope_off = np.zeros_like(positions)
-            rope = (self._put(rope_off, self._bax),)
+        step = self.layout.spec_step(greedy)
+        put = self.layout.put_rows
+        rope = self._rope_operand(rope_off, positions)
         packed_d, self.kv = step(
             self.params, self.kv,
-            self._put(tokens, self._bax, None),
-            self._put(positions, self._bax),
-            self._put(table, self._bax, None),
-            self._put_samp(samp),
-            self._put(seeds, self._bax),
-            self._put(counters, self._bax),
+            put(tokens),
+            put(positions),
+            put(table),
+            self.layout.put_samp(samp),
+            put(seeds),
+            put(counters),
             *rope,
         )
-        try:  # start the host copy early
-            packed_d.copy_to_host_async()
-        except Exception:  # lint: allow(swallowed-exception): copy_to_host_async optional; fetch path device_gets anyway
-            pass
+        self._start_host_copy(packed_d)
         return packed_d
 
     @affine("step")
@@ -4054,7 +2365,8 @@ class JaxEngine:
         # prompt rides the next mixed dispatch within one short block
         t0_ev = self._step_t0_ns = self.events.now()
         T, allow_chain = self.scheduler.select_decode_rung()
-        if allow_chain and self._cc_ok():
+        if (allow_chain and self.cfg.decode_continuous
+                and self.layout.runs_continuous):
             # device-resident loop: rungs stay the scan lengths — the
             # ladder's quiet-ramp top rung is where open-ended chaining
             # engages; short rungs (prompts pending) keep the per-
@@ -4082,7 +2394,7 @@ class JaxEngine:
         # histograms updated on-device within and across chained blocks
         counts = self._counts_array(rows) if penalized else None
         rope_off = self._rope_array(rows)
-        if self._multihost:
+        if self.layout.lockstep:
             # penalized plans carry the output tokens SPARSELY (flat list +
             # row offsets) — broadcasting the dense [B, vocab] histogram
             # would put ~4MB/step on the plan channel at a 128k vocab
@@ -4134,50 +2446,27 @@ class JaxEngine:
                          rope_off=None, greedy=False, n_steps=None):
         """Issue the chained decode dispatches (identical on leader and
         followers); returns the per-block packed outputs."""
-        step = self._get_decode_step(penalized, with_top, greedy, n_steps)
-        tok_d = self._put(tokens, self._bax)
-        pos_d = self._put(positions, self._bax)
-        ctr_d = self._put(counters, self._bax)
-        table_d = self._put(table, self._bax, None)
-        samp_d = self._put_samp(samp)
-        seeds_d = self._put(seeds, self._bax)
-        mrope = bool(self.model_cfg.mrope_section)
-        rope = ()
-        if mrope:
-            if rope_off is None:
-                rope_off = np.zeros_like(positions)
-            rope = (self._put(rope_off, self._bax),)
-        if penalized:
-            cts_d = self._put(counts, self._bax, None)
+        step = self.layout.decode_step(penalized, with_top, greedy, n_steps)
+        put = self.layout.put_rows
+        tok_d = put(tokens)
+        pos_d = put(positions)
+        ctr_d = put(counters)
+        table_d = put(table)
+        samp_d = self.layout.put_samp(samp)
+        seeds_d = put(seeds)
+        rope = self._rope_operand(rope_off, positions)
+        cts_d = put(counts) if penalized else None
         dispatches = []
         for _ in range(chain_len):
-            if penalized:
-                packed_d, tok_d, pos_d, ctr_d, cts_d, self.kv = step(
-                    self.params, self.kv, tok_d, pos_d, ctr_d, cts_d,
-                    table_d, samp_d, seeds_d, *rope,
-                )
-            else:
-                packed_d, tok_d, pos_d, ctr_d, self.kv = step(
-                    self.params, self.kv, tok_d, pos_d, ctr_d,
-                    table_d, samp_d, seeds_d, *rope,
-                )
-            try:  # start the host copy early; overlaps later blocks' compute
-                packed_d.copy_to_host_async()
-            except Exception:  # lint: allow(swallowed-exception): copy_to_host_async optional; fetch path device_gets anyway
-                pass
+            packed_d, tok_d, pos_d, ctr_d, cts_d, self.kv = step(
+                self.params, self.kv, tok_d, pos_d, ctr_d, cts_d,
+                table_d, samp_d, seeds_d, *rope,
+            )
+            self._start_host_copy(packed_d)  # overlaps later blocks' compute
             dispatches.append(packed_d)
         return dispatches
 
     # -- device-resident decode loop (continuous chaining) -------------------- #
-
-    def _cc_ok(self) -> bool:
-        """May decode take the device-resident continuous loop?  Flat
-        single-process engines only: the pooled/pp/sp step layouts and
-        the multihost plan channel keep their existing chained paths
-        (and stay token-identical — the loop is output-invisible)."""
-        return (self.cfg.decode_continuous and self.mesh is None
-                and not self._multihost and self._pp == 1
-                and self._sp == 1 and not self._pooled)
 
     def _ensure_drain_pool(self):
         if self._drain_pool is None:
@@ -4458,35 +2747,31 @@ class JaxEngine:
         budget = self._budget_array(rows)
         active = np.array([s is not None and budget[i] > 0
                            for i, s in enumerate(rows)])
-        step = self._get_cc_step(penalized, with_top, greedy, T)
+        step = self.layout.cc_step(penalized, with_top, greedy, T)
+        put = self.layout.put_rows
         drain = self._ensure_drain_pool()
         splice_on = self.cfg.prefill_chunk_tokens > 0
-        mrope = bool(self.model_cfg.mrope_section)
         # _plan_decode reserved decode_advance (>= T) preemptively, so
         # the first block always fits even when the watermark blocks
         # further growth
         allowance = max(1, self._cc_reserve(seqs, T))
-        table_d = self._put(self._table_array(rows), self._bax, None)
-        tok_d = self._put(tokens, self._bax)
-        pos_d = self._put(positions, self._bax)
-        ctr_d = self._put(counters, self._bax)
-        act_d = self._put(active, self._bax)
-        budget_d = self._put(budget, self._bax)
-        stops_d = self._put(self._stop_arrays(rows), self._bax, None)
-        samp_d = self._put_samp(samp)
-        seeds_d = self._put(seeds, self._bax)
-        cts_d = self._put(counts, self._bax, None) if penalized else None
-        rope = ()
-        if mrope:
-            if rope_off is None:
-                rope_off = np.zeros_like(positions)
-            rope = (self._put(rope_off, self._bax),)
+        table_d = put(self._table_array(rows))
+        tok_d = put(tokens)
+        pos_d = put(positions)
+        ctr_d = put(counters)
+        act_d = put(active)
+        budget_d = put(budget)
+        stops_d = put(self._stop_arrays(rows))
+        samp_d = self.layout.put_samp(samp)
+        seeds_d = put(seeds)
+        cts_d = put(counts) if penalized else None
+        rope = self._rope_operand(rope_off, positions)
         # quiet-block chunk operands, put ONCE and reused: a steady
         # block ships no fresh host buffer (a fresh buffer mid-chain
         # serializes the chain on its upload)
-        z_toks_d = self._put(np.zeros((Bb, T), np.int32), self._bax, None)
-        z_i32_d = self._put(np.zeros((Bb,), np.int32), self._bax)
-        z_bool_d = self._put(np.zeros((Bb,), bool), self._bax)
+        z_toks_d = put(np.zeros((Bb, T), np.int32))
+        z_i32_d = put(np.zeros((Bb,), np.int32))
+        z_bool_d = put(np.zeros((Bb,), bool))
         quiet_chunk = (z_toks_d, z_i32_d, z_bool_d, z_bool_d, z_i32_d,
                        z_i32_d)
         needs_reset: set = set()  # guarded-by: step thread (chain-local)
@@ -4519,28 +2804,23 @@ class JaxEngine:
                         # per-row operands now cover the new rows; the
                         # carried device state is reset in-step by the
                         # reset overlay on their first fed block
-                        samp_d = self._put_samp(self._samp_arrays(rows))
-                        seeds_d = self._put(
-                            self._seed_arrays(rows)[0], self._bax)
-                        stops_d = self._put(
-                            self._stop_arrays(rows), self._bax, None)
-                        if mrope:
-                            ro = self._rope_array(rows)
-                            if ro is None:
-                                ro = np.zeros_like(positions)
-                            rope = (self._put(ro, self._bax),)
+                        samp_d = self.layout.put_samp(self._samp_arrays(rows))
+                        seeds_d = put(self._seed_arrays(rows)[0])
+                        stops_d = put(self._stop_arrays(rows))
+                        rope = self._rope_operand(
+                            self._rope_array(rows), positions)
                 feed = (self._cc_plan_feed(rows, T, needs_reset,
                                            fed_complete)
                         if splice_on else None)
                 if feed is not None:
                     toks, rem, smp, rst, ipos, ibud = feed
                     chunk_ops = (
-                        self._put(toks, self._bax, None),
-                        self._put(rem, self._bax),
-                        self._put(smp, self._bax),
-                        self._put(rst, self._bax),
-                        self._put(ipos, self._bax),
-                        self._put(ibud, self._bax),
+                        put(toks),
+                        put(rem),
+                        put(smp),
+                        put(rst),
+                        put(ipos),
+                        put(ibud),
                     )
                     chunk_rows = int((rem > 0).sum())
                 else:
@@ -4548,27 +2828,15 @@ class JaxEngine:
                     chunk_rows = 0
                 if spliced or feed is not None:
                     # splices/feeds may have grown page lists
-                    table_d = self._put(self._table_array(rows),
-                                        self._bax, None)
+                    table_d = put(self._table_array(rows))
                 t_iter = self._step_t0_ns = self.events.now()
-                if penalized:
-                    (packed_d, tok_d, pos_d, ctr_d, act_d, budget_d,
-                     cts_d, self.kv) = step(
-                        self.params, self.kv, tok_d, pos_d, ctr_d, cts_d,
-                        act_d, budget_d, stops_d, table_d, samp_d, seeds_d,
-                        *chunk_ops, *rope,
-                    )
-                else:
-                    (packed_d, tok_d, pos_d, ctr_d, act_d, budget_d,
-                     self.kv) = step(
-                        self.params, self.kv, tok_d, pos_d, ctr_d,
-                        act_d, budget_d, stops_d, table_d, samp_d, seeds_d,
-                        *chunk_ops, *rope,
-                    )
-                try:
-                    packed_d.copy_to_host_async()
-                except Exception:  # lint: allow(swallowed-exception): copy_to_host_async optional; fetch path device_gets anyway
-                    pass
+                (packed_d, tok_d, pos_d, ctr_d, act_d, budget_d, cts_d,
+                 self.kv) = step(
+                    self.params, self.kv, tok_d, pos_d, ctr_d, cts_d,
+                    act_d, budget_d, stops_d, table_d, samp_d, seeds_d,
+                    *chunk_ops, *rope,
+                )
+                self._start_host_copy(packed_d)
                 t_sent = self.events.now()
                 blocks += 1
                 allowance -= 1
@@ -4635,8 +2903,7 @@ class JaxEngine:
                         fallout = ("admission" if self.scheduler.waiting
                                    else "pages")
                         break
-                    table_d = self._put(self._table_array(rows),
-                                        self._bax, None)
+                    table_d = put(self._table_array(rows))
         finally:
             err = None
             while inflight:
@@ -4733,18 +3000,14 @@ class JaxEngine:
         return counts
 
     def _lockstep_send(self, desc: Dict[str, Any]) -> None:
-        from ..parallel.multihost import broadcast_plan
-
-        broadcast_plan(_plan_pack(desc))
+        self.layout.broadcast(_plan_pack(desc))
 
     def follower_loop(self) -> None:
         """Replay the leader's dispatches on this follower rank (blocking;
         returns when the leader broadcasts shutdown).  Every rank of a
         multihost group except rank 0 runs this instead of serving."""
-        if not self._multihost or self._lockstep_leader:
+        if not self.layout.lockstep or self.layout.is_leader:
             raise RuntimeError("follower_loop is for multihost ranks > 0")
-        from ..parallel.multihost import broadcast_plan
-
         samp_n = len(SamplingParams._fields)
         # a follower-local dispatch failure leaves this rank's KV shards
         # diverged from the leader's; the ONLY consistent continuation is
@@ -4753,13 +3016,13 @@ class JaxEngine:
         # rather than stream silently-wrong collectives.
         poisoned = False
         while True:
-            desc = _plan_unpack(broadcast_plan(b""))
+            desc = _plan_unpack(self.layout.broadcast(b""))
             kind = desc["kind"]
             if kind == "shutdown":
                 self._close_blob_channels()
                 return
             if kind == "recover":
-                self.kv = self._make_kv()
+                self.kv = self.layout.make_kv(self._kv_dtype)
                 poisoned = False
                 continue
             if poisoned:
@@ -4859,7 +3122,7 @@ class JaxEngine:
             lens[i] = len(t)
 
         def op():
-            if self._multihost:
+            if self.layout.lockstep:
                 self._lockstep_send(
                     {"kind": "embed", "tokens": tokens, "lens": lens}
                 )
@@ -4922,15 +3185,8 @@ class JaxEngine:
     def _embed_replay(self, tokens: np.ndarray, lens: np.ndarray) -> np.ndarray:
         """The device half of an embed op (leader and followers run this
         identically; multihost gathers the result to every process)."""
-        if self._embed_fn is None:
-            cfg = self.model_cfg
-            kw = ({"out_shardings": NamedSharding(self.mesh, P())}
-                  if self._multihost else {})
-            self._embed_fn = _ljit(
-                lambda p, tok, ln: forward_embed(p, cfg, tok, ln), **kw
-            )
-        out = self._embed_fn(
-            self.params, self._put(tokens), self._put(lens)
+        out = self.layout.embed_step()(
+            self.params, self.layout.put(tokens), self.layout.put(lens)
         )
         return np.asarray(jax.device_get(out))
 
@@ -4974,27 +3230,32 @@ class JaxEngine:
         broadcast so every rank issues the same jit (disagg composes with
         multihost — reference: disagg_serving.md:110-120)."""
         width = width or self._pow2_width(len(pages))
-        padded = np.zeros((width,), np.int32)
-        if self._pooled:
-            rank = self.pool.rank_of(pages[0]) if pages else 0
-            padded[: len(pages)] = [p % self.cfg.num_pages for p in pages]
-        else:
-            rank = None
-            padded[: len(pages)] = pages
-        if self._multihost:
+        padded, rank = self._local_pages(pages, width)
+        if self.layout.lockstep:
             self._lockstep_send(
                 {"kind": "kv_export", "padded": padded, "rank": rank}
             )
         return self._export_replay(padded, rank)
 
+    def _local_pages(self, pages: List[int], width: int):
+        """(ids padded to `width`, owning rank): a partitioned pool takes
+        LOCAL ids + the rank (a sequence's pages always share one), any
+        other pool the ids as they are and no rank."""
+        padded = np.zeros((width,), np.int32)
+        if not self.layout.pooled:
+            padded[: len(pages)] = pages
+            return padded, None
+        padded[: len(pages)] = [p % self.cfg.num_pages for p in pages]
+        return padded, self.pool.rank_of(pages[0]) if pages else 0
+
+    def _rank_operand(self, rank: Optional[int]) -> tuple:
+        return () if rank is None else (self.layout.put(np.int32(rank)),)
+
     def _export_replay(self, padded: np.ndarray, rank: Optional[int]):
         """The device half of an export (leader and followers run this
         identically)."""
-        if rank is not None:
-            return self._export_fn(
-                self.kv, self._put(padded), self._put(np.int32(rank))
-            )
-        return self._export_fn(self.kv, self._put(padded))
+        return self.layout.export_fn(
+            self.kv, self.layout.put(padded), *self._rank_operand(rank))
 
     def _import_dev(self, pages: List[int], kpad, vpad) -> None:
         """jit import of padded (k, v) blobs into the given page ids
@@ -5003,15 +3264,8 @@ class JaxEngine:
         descriptor — each host pulls the byte ranges its devices' KV
         shards need (per-shard fetch, engine/blob_stage.py) instead of
         every host receiving the whole blob."""
-        width = kpad.shape[1]
-        padded = np.zeros((width,), np.int32)
-        if self._pooled:
-            rank = self.pool.rank_of(pages[0]) if pages else 0
-            padded[: len(pages)] = [p % self.cfg.num_pages for p in pages]
-        else:
-            rank = None
-            padded[: len(pages)] = pages
-        if self._multihost:
+        padded, rank = self._local_pages(pages, kpad.shape[1])
+        if self.layout.lockstep:
             if isinstance(kpad, jax.Array):
                 # lint: allow(device-get): lockstep blob staging needs host bytes; one batched fetch for both planes
                 kpad, vpad = map(np.asarray, jax.device_get((kpad, vpad)))
@@ -5060,16 +3314,6 @@ class JaxEngine:
         fetches nothing) — then run the import jit.  Aggregate DCN
         traffic is O(1× blob) instead of O(hosts × blob)."""
         shape = tuple(desc["shape"])  # [L, width, page, kvh, hd]
-        dtype = np.dtype(desc["dtype"])
-        L, width, ps, kvh, hd = shape
-        if self._pooled:
-            R = self._pool_ranks
-            gshape = (L, R * width, ps, kvh, hd)
-            spec = P(None, self._pool_axes, None, "tp", None)
-        else:
-            gshape = shape
-            spec = P(None, None, None, "tp", None)
-        sharding = NamedSharding(self.mesh, spec)
         client = None if local is not None else self._blob_client(desc["addr"])
         cache: Dict[tuple, np.ndarray] = {}
 
@@ -5083,57 +3327,16 @@ class JaxEngine:
                     cache[key] = client.fetch(desc["tid"], name, lo, hi)
             return cache[key]
 
-        def build(name: str) -> jax.Array:
-            idx_map = sharding.addressable_devices_indices_map(gshape)
-            arrays = []
-            for dev, index in idx_map.items():
-                pg, hds = index[1], index[3]
-                pg_lo = pg.start or 0
-                pg_hi = gshape[1] if pg.stop is None else pg.stop
-                h_lo = hds.start or 0
-                h_hi = kvh if hds.stop is None else hds.stop
-                shard_shape = (L, pg_hi - pg_lo, ps, h_hi - h_lo, hd)
-                if self._pooled:
-                    blk_lo, blk_hi = rank * width, (rank + 1) * width
-                    if pg_lo <= blk_lo and pg_hi >= blk_hi:
-                        data = np.zeros(shard_shape, dtype)
-                        data[:, blk_lo - pg_lo: blk_hi - pg_lo] = (
-                            src_slice(name, h_lo, h_hi)
-                        )
-                    elif pg_hi <= blk_lo or pg_lo >= blk_hi:
-                        # non-owner shard: zeros, nothing fetched
-                        data = np.zeros(shard_shape, dtype)
-                    else:  # shards are width-aligned by construction
-                        raise AssertionError("unaligned pool shard")
-                else:
-                    data = src_slice(name, h_lo, h_hi)
-                arrays.append(jax.device_put(data, dev))
-            return jax.make_array_from_single_device_arrays(
-                gshape, sharding, arrays
-            )
-
-        k_blob, v_blob = build("k"), build("v")
-        pages_d = self._put(padded)
-        if self._pooled:
-            if self._import_fn_sharded is None:
-                if self._pp > 1:
-                    # pp×kv_partition: the KV layer axis is pp-sharded —
-                    # the dp-only pooled import would reshard every
-                    # stage's cache to full layers (pp× HBM spike)
-                    self._import_fn_sharded = _build_import_fn_pp_pooled(
-                        self.model_cfg, self.mesh, sharded_blob=True,
-                    )
-                else:
-                    self._import_fn_sharded = _build_import_fn_pooled(
-                        self.model_cfg, self.mesh, self._pool_axes,
-                        sharded_blob=True,
-                    )
-            self.kv = self._import_fn_sharded(
-                self.kv, k_blob, v_blob, pages_d,
-                self._put(np.int32(rank)),
-            )
-        else:
-            self.kv = self._import_fn(self.kv, k_blob, v_blob, pages_d)
+        k_blob, v_blob = (
+            self.layout.import_blob(shape, np.dtype(desc["dtype"]), rank,
+                                    partial(src_slice, name))
+            for name in ("k", "v"))
+        # pp×kv_partition: the KV layer axis is pp-sharded, and the
+        # layout's import slices the blob by stage (a dp-only pooled import
+        # would reshard every stage's cache to full layers: pp× HBM spike)
+        self.kv = self.layout.import_fn(sharded_blob=self.layout.pooled)(
+            self.kv, k_blob, v_blob, self.layout.put(padded),
+            *self._rank_operand(rank))
         if client is not None:
             client.ack(desc["tid"])
 
@@ -5142,16 +3345,10 @@ class JaxEngine:
         if isinstance(kpad, jax.Array):
             k_d, v_d = kpad, vpad  # colocated device lane (single-process)
         else:
-            k_d, v_d = self._put(kpad), self._put(vpad)
-        if rank is not None:
-            self.kv = self._import_fn(
-                self.kv, k_d, v_d, self._put(padded),
-                self._put(np.int32(rank)),
-            )
-        else:
-            self.kv = self._import_fn(
-                self.kv, k_d, v_d, self._put(padded)
-            )
+            k_d, v_d = self.layout.put(kpad), self.layout.put(vpad)
+        self.kv = self.layout.import_fn()(
+            self.kv, k_d, v_d, self.layout.put(padded),
+            *self._rank_operand(rank))
 
     async def export_pages(self, pages: List[int]):
         """Copy the given pages device->host: ([L,n,page,kv,hd], same) —
@@ -5196,14 +3393,7 @@ class JaxEngine:
                 # never staging through host numpy
                 mine = set(self.kv.k.devices())
                 if set(kpad.devices()) != mine:
-                    if self.mesh is not None:
-                        # shard kv-heads like the pool so the cross-mesh
-                        # copy moves 1/tp of the blob per device
-                        spec = (P(None, None, None, "tp", None)
-                                if "tp" in self.mesh.axis_names else P())
-                        target = NamedSharding(self.mesh, spec)
-                    else:
-                        target = next(iter(mine))
+                    target = self.layout.foreign_blob_target(mine)
                     kpad = jax.device_put(kpad, target)
                     vpad = jax.device_put(vpad, target)
                 self._import_dev(pages, kpad, vpad)
@@ -5350,7 +3540,7 @@ class JaxEngine:
 
         seq.trace = current_trace()  # the disagg handoff's adopted trace
         seq.pages = pages
-        if self._pooled and pages:
+        if self.layout.pooled and pages:
             seq.kv_rank = self.pool.rank_of(pages[0])
         seq.num_computed = len(prompt)
         seq.num_cached = len(prompt)
@@ -5411,12 +3601,12 @@ class JaxEngine:
         for seq in list(self.scheduler.running):
             self.scheduler.finish(seq, "error")
             self._deliver(seq, [], "error")
-        if self._multihost:
+        if self.layout.lockstep:
             # keep followers lockstep: they rebuild their KV shards too
             self._lockstep_send({"kind": "recover"})
-        self.kv = self._make_kv()
+        self.kv = self.layout.make_kv(self._kv_dtype)
         self._evictions_before_reset += self.pool.evictions_total
-        self.pool = self._make_pool()
+        self.pool = self.layout.make_pool(self._emit_event)
         for p in getattr(self.pool, "pools", [self.pool]):
             p.events = self.events
         self._emit_event(KvEvent("cleared", []))

@@ -29,8 +29,8 @@ the harness is built from three pieces:
 `frontend_saturation()` ramps rungs of concurrent streams until delta
 p99 crosses `knee_ms`, then A/Bs the batched zero-copy writer against
 the legacy per-delta writer (`sse_legacy`) at the max rung to report the
-CPU-per-token ratio.  Results feed BENCH_full.json and the compact
-stdout summary (see docs/frontend_dataplane.md).
+CPU-per-token ratio (see docs/frontend_dataplane.md;
+`scripts/frontend_saturation.py` prints the result).
 """
 
 from __future__ import annotations

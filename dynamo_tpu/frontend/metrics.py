@@ -196,7 +196,8 @@ class FrontendMetrics:
 
         self.registry.register(TracingSpanCollector())
         # live SLO window (frontend/slo.py): per-request goodput/slo_met
-        # accounting with bench.py's definitions, exposed as gauges at
+        # accounting (SLO-met iff TTFT and mean ITL are under the target;
+        # goodput counts SLO-met requests' tokens), exposed as gauges at
         # scrape time and published to the fleet telemetry plane
         from .slo import SLOAccountant, SLOWindowCollector
 

@@ -326,8 +326,8 @@ class HttpService:
 
     async def fleet_json(self, request: web.Request) -> web.Response:
         """Debug surface for the live telemetry plane: this frontend's
-        per-model SLO windows (same definitions bench.py computes
-        offline) plus, when a fleet watcher is attached, the joined
+        per-model SLO windows (`frontend/slo.py`: a request meets its SLO
+        iff TTFT and mean ITL are both under the target) plus, when a fleet watcher is attached, the joined
         worker capacity snapshots and online knee estimates
         (docs/observability.md documents the schema)."""
         body = {
@@ -897,7 +897,7 @@ class HttpService:
             incidents=incidents, ntokens=ntokens, status=int(status),
         )
         # live SLO window: the whole HTTP request is one accounting unit
-        # (bench.poisson_goodput's per-request TTFT + mean-ITL predicate,
+        # (the per-request TTFT + mean-ITL predicate of frontend/slo.py,
         # applied post-hoc in slo.observe_stream — never on the delivery
         # loop). A stream the client saw FAIL can never be SLO-met.
         if status != "429":  # sheds are offered-only, never window failures

@@ -1,6 +1,6 @@
 """Overload-control bench phase: mixed-class Poisson load past the knee.
 
-bench.py's `overload_phase` answers: past the saturation knee, do
+`overload_phase` answers: past the saturation knee, do
 priority classes + admission shedding + decode preemption
 (docs/overload_control.md) actually protect interactive latency, and
 what does that cost batch?  Two arms run the SAME arrival schedule and
@@ -20,7 +20,7 @@ test are the production code paths:
   deadline, shed with a structured ``overloaded`` error at the knee,
   parked mid-decode when an interactive head needs the slot).
 
-Accounting uses bench.py's goodput definitions: a request is SLO-met
+Accounting: a request is SLO-met
 when TTFT and mean ITL both land under the target; goodput counts
 tokens from SLO-met requests only.  Shed requests count in the offered
 rate but are excluded from SLO scoring — a clean 429 is load control

@@ -1,15 +1,13 @@
 """Live per-request SLO accounting — the frontend half of the fleet
 telemetry plane.
 
-bench.py computes slo_met / goodput OFFLINE from per-request TTFT and
-mean ITL; this module computes the SAME definitions live, per model, over
-a sliding window, so bench's offline numbers and the serving fleet's
-`/metrics` + `/fleet.json` surfaces are cross-checkable (bench asserts
-agreement after every goodput phase):
+slo_met and goodput are defined HERE, from per-request TTFT and mean
+ITL, and computed live, per model, over a sliding window, for the serving
+fleet's `/metrics` + `/fleet.json` surfaces (`frontend/overload.py` scores
+its offline arms with the same predicate):
 
 - a request MEETS its SLO iff ``ttft_ms <= slo.ttft_ms`` and its mean
-  inter-token latency ``itl_ms <= slo.itl_ms`` (bench.poisson_goodput's
-  `ok` predicate);
+  inter-token latency ``itl_ms <= slo.itl_ms``;
 - ``goodput`` counts only tokens from SLO-met requests; ``attained``
   counts all tokens; both divide by the covered window duration.
 
@@ -42,7 +40,7 @@ __all__ = [
 ]
 
 # default SLO class when neither the model card nor the environment says
-# otherwise (interactive chat — bench.py's SLO_8B shape)
+# otherwise (interactive chat on an 8B-class model)
 DEFAULT_TTFT_MS = 2000.0
 DEFAULT_ITL_MS = 100.0
 
@@ -398,7 +396,7 @@ class SLOAccountant:
                 priority: Optional[str] = None,
                 exemplar: Optional[dict] = None) -> bool:
         """Account one COMPLETED request; returns whether it met its SLO
-        (bench.poisson_goodput's predicate, applied live).  When a
+        (TTFT and mean ITL both under the target).  When a
         `priority` class is given the request ALSO lands in that class's
         window — the model window keeps scoring every request, so the
         existing surfaces don't change.  `exemplar` (a waterfall summary

@@ -12,7 +12,7 @@ layout (`gate_up_proj` [E, h, 2f], `down_proj` [E, f, h]).
 
 Compute stays bf16 on TPU: dequantize-on-load keeps checkpoint fidelity
 without an fp4 kernel (native-MXFP4 matmul is a stretch goal —
-docs/ROADMAP.md)."""
+ROADMAP.md, "Known gaps")."""
 
 from __future__ import annotations
 

@@ -83,6 +83,8 @@ class PerfProfile:
         if ok.all():
             return float(conc[-1])
         i = int(np.where(ok)[0][-1])
+        if i + 1 >= len(conc):  # a measured grid need not be monotone
+            return float(conc[-1])
         x0, x1 = conc[i], conc[i + 1]
         y0, y1 = itls[i], itls[i + 1]
         if y1 == y0:

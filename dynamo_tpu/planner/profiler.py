@@ -278,7 +278,7 @@ def _build_engine(args):
     elif args.model == "llama-8b":
         # 8B fits a 16GB chip only as int8 (~8GB weights); init the
         # quantized tree directly on device — a bf16 intermediate would
-        # OOM (same path bench.py measures)
+        # OOM
         from ..models.config import LLAMA_3_1_8B
         from ..models.quantization import random_int8_params
 

@@ -24,8 +24,8 @@ On top of the join, :meth:`FleetTelemetryWatcher.sample` runs the online
 estimators the SLA planner consumes:
 
 - **knee estimation**: a rolling fit of offered rate vs slo_met per
-  model → ``knee_rate_rps`` (bench.py's contiguous-passing-prefix knee,
-  computed from live windows instead of an offline ladder);
+  model → ``knee_rate_rps`` (the top of the contiguous prefix of rate bins that
+  pass, computed from live windows);
 - **observed PerfProfile**: (per-worker prefill load, TTFT p95) and
   (per-worker decode concurrency, ITL p95) observations accumulated into
   the monotone curves :class:`~dynamo_tpu.planner.perf_model.PerfProfile`
@@ -88,9 +88,8 @@ class KneeEstimator:
 
     Samples bin into geometric rate buckets; the knee is the top of the
     CONTIGUOUS prefix of bins whose weighted slo_met clears the
-    threshold — the same definition bench.py's offline ladder uses
-    (`_goodput_pass`), so the live estimate and the bench knee are the
-    same quantity."""
+    threshold: a bin passes only if every lower rate passed too, so one
+    lucky window above the knee does not move it."""
 
     def __init__(self, threshold: float = 0.9, maxlen: int = 512,
                  bin_ratio: float = 1.25):

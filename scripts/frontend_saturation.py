@@ -5,7 +5,7 @@
     python scripts/frontend_saturation.py --rungs 2500,10000 --tokens 4
     python scripts/frontend_saturation.py --mock-speedup 1000
 
-Runs bench.py's ``frontend_saturation`` phase by itself — concurrent
+Runs ``frontend.loadgen.frontend_saturation`` — concurrent
 mock SSE streams against the REAL frontend write path (preprocess →
 postprocess_stream → StreamEgress), no device, no control plane — and
 prints the result as one JSON line.  See docs/frontend_dataplane.md.

@@ -478,7 +478,7 @@ async def test_continuous_decode_per_step_fallback_path(engine_setup):
     """The continuous loop's per-step scan fallback (Pallas / giant-KV
     engines that cannot materialize the block) stays token-identical:
     force it by zeroing the block-KV byte budget."""
-    import dynamo_tpu.engine.engine as eng_mod
+    import dynamo_tpu.engine.steps as eng_mod
 
     plain = make_engine(engine_setup)
     want = [await collect(plain, req([1, 2, 3, 4, 5], max_tokens=13))]

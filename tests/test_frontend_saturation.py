@@ -4,14 +4,15 @@ Two acceptance bars from the egress data-plane work, run at reduced
 duration so they fit tier-1:
 
 - 10k concurrent mock SSE streams against ONE real frontend process
-  with delta p99 under the 5 ms knee and zero tokens lost,
+  with zero tokens lost (the 5 ms delta-p99 knee is a time, and
+  `scripts/frontend_saturation.py` reports it on a quiet machine),
 - the batched/coalescing writer cuts frontend CPU per streamed token
   >= 3x vs the legacy per-delta writer on a burst shape where
-  backpressure engages (same A/B arms bench.py's frontend_saturation
-  phase reports into BENCH_full.json).
+  backpressure engages (the A/B arms of `scripts/frontend_saturation.py`;
+  not measured on the chip: no benchmark cell streams more than one token).
 
 Pure asyncio — no device, no control plane.  The full ramp lives in
-scripts/frontend_saturation.py / the bench phase.
+scripts/frontend_saturation.py.
 """
 
 import asyncio
@@ -20,30 +21,17 @@ from dynamo_tpu.frontend.loadgen import run_rung
 
 
 async def test_10k_streams_under_knee():
-    # The host scheduler stalls the whole guest for 10-40ms at random
-    # (measured on an otherwise-IDLE event loop), and sustained CPU
-    # drains a host-side burst budget so back-to-back runs degrade
-    # monotonically while in-guest CPU/objects/timers stay flat.  One
-    # stall delays every in-flight delta and can sink a single run's
-    # p99 on its own.  Best of three attempts with an idle gap before
-    # each retry (lets the budget refill) — the claim under test is
-    # repeatable capability, not one draw from a noisy host.
-    best = None
-    for attempt in range(3):
-        if attempt:
-            await asyncio.sleep(10)
-        r = await run_rung(streams=10_000, n=16, interval_s=4.0, tokens=4)
-        assert r["streams"] >= 10_000
-        assert r["tokens_lost"] == 0
-        if best is None or r["delta_p99_ms"] < best["delta_p99_ms"]:
-            best = r
-        if best["delta_p99_ms"] < 5.0:
-            break
-    assert best["delta_p99_ms"] < 5.0, best
+    """Counts only: every stream is served and no token is lost.  The knee
+    itself (`delta_p99_ms < 5`) is a wall-clock reading that six busy test
+    workers sink at random (ROADMAP D0): `scripts/frontend_saturation.py`
+    reports it on a quiet machine, and tier-1 asserts no time."""
+    r = await run_rung(streams=10_000, n=16, interval_s=4.0, tokens=4)
+    assert r["streams"] >= 10_000
+    assert r["tokens_lost"] == 0
     # at this gentle per-stream rate queues rarely back up, so frames
     # may equal writes — batching economics are asserted by the burst
     # A/B test below, not here
-    assert best["egress_frames"] >= best["egress_writes"]
+    assert r["egress_frames"] >= r["egress_writes"]
 
 
 async def test_burst_ab_cpu_per_token_ratio():

@@ -330,12 +330,13 @@ async def test_shutdown_with_pending_offloads_does_not_deadlock(model_setup):
 
 
 async def test_tier_hit_ttft_ladder(model_setup):
-    """The KVBM latency contract on the CPU tier-1 box: a warm-prefix
-    TTFT served from the DRAM tier is ≤ 2× the device(HBM)-cache-hit
-    TTFT, and ≥ 5× better than a cold prefill (ISSUE 8 acceptance).
-    Medians of 3 keep scheduler jitter out of the gate."""
-    import time as _time
-
+    """The KVBM latency contract, on the clock a CPU run has: PREFILL
+    STEPS, not seconds (ROADMAP D0: a wall-clock ratio read 2.7 under six
+    busy workers and over 5 alone).  A warm prefix served from the DRAM
+    tier costs at most twice the steps of a device(HBM)-cache hit and at
+    most a fifth of a cold prefill's (ISSUE 8 acceptance), and its blocks
+    came from the host tier.  The times themselves are not measured on the
+    chip: no benchmark cell offloads."""
     tiered = TieredKvCache(HostBlockPool(capacity_bytes=256 << 20))
     engine = make_engine(model_setup, tiered=tiered, num_pages=128,
                          max_prefill_tokens=32, max_model_len=448)
@@ -344,12 +345,11 @@ async def test_tier_hit_ttft_ladder(model_setup):
     prompt = [(i * 7) % 250 + 1 for i in range(384)]
 
     async def ttft(tokens):
-        r = req(tokens, max_tokens=2)
-        t0 = _time.perf_counter()
-        first = None
-        async for d in engine.generate(r):
+        """Prefill steps dispatched up to the first token."""
+        before, first = engine.prefill_steps_total, None
+        async for d in engine.generate(req(tokens, max_tokens=2)):
             if d["token_ids"] and first is None:
-                first = _time.perf_counter() - t0
+                first = engine.prefill_steps_total - before
         return first
 
     async def drain():
@@ -367,7 +367,9 @@ async def test_tier_hit_ttft_ladder(model_setup):
         hbm.append(await ttft(salted))  # device cache holds the blocks
         await drain()
         engine.clear_kv_blocks()  # only copy now in DRAM
+        onboarded = tiered.onboarded_blocks
         dram.append(await ttft(salted))
+        assert tiered.onboarded_blocks > onboarded
 
     cold_m, hbm_m, dram_m = (sorted(x)[1] for x in (cold, hbm, dram))
     assert dram_m <= 2.0 * hbm_m, (cold_m, hbm_m, dram_m)
@@ -376,8 +378,8 @@ async def test_tier_hit_ttft_ladder(model_setup):
 
 
 async def test_zipf_multi_tenant_goodput_offload_ab(model_setup):
-    """The CPU-scale version of bench.py's `kvbm_zipf` phase (ISSUE 8
-    acceptance): a Zipf-distributed multi-tenant prefix workload whose
+    """A CPU-scale Zipf offload A/B (ISSUE 8 acceptance; not measured on
+    the chip: no benchmark cell's tenant set outgrows the device pool): a Zipf-distributed multi-tenant prefix workload whose
     tenant set dwarfs the device pool.  With offload ON, HBM-evicted
     system prefixes onboard from the DRAM tier; with offload OFF they
     re-prefill cold.  Aggregate goodput (identical seeded schedule, so
@@ -427,7 +429,7 @@ async def test_zipf_multi_tenant_goodput_offload_ab(model_setup):
     warm_engine = mk(tiered)
     # TWO warm waves: the first fills the DRAM tier, the second compiles
     # every onboard-import width bucket (the jit cache the measured wave
-    # runs against — same off-the-clock warmup discipline as bench.py)
+    # runs against: warmed off the clock)
     for _ in range(2):
         await wave(warm_engine)
         deadline = asyncio.get_running_loop().time() + 15
@@ -584,7 +586,7 @@ async def test_kvbm_on_partitioned_pool(model_setup, tmp_path):
         eos_token_ids=[], kv_dtype=jnp.float32, tiered=tiered,
         parallel=ParallelConfig(dp=4, tp=2),
     )
-    assert engine._pooled
+    assert engine.layout.pooled
     # several prompts spread across partitions (admission balances)
     prompts = [[(13 * i + j) % 90 + 1 for j in range(40)] for i in range(4)]
     want = await asyncio.gather(*[collect(engine, req(p)) for p in prompts])

@@ -463,7 +463,7 @@ ecfg = EngineConfig(page_size=8, num_pages=64, max_num_seqs=4,
 tiered = TieredKvCache(HostBlockPool(capacity_bytes=64 << 20)) if rank == 0 else None
 mh = JaxEngine(cfg, params, ecfg, kv_dtype=jnp.float32,
                parallel=ParallelConfig(dp=2, tp=2), tiered=tiered)
-assert mh._pooled and mh._pool_ranks == 2
+assert mh.layout.pooled and mh.layout.pool_ranks == 2
 
 def req(p, n=6):
     return {"token_ids": p, "sampling_options": {"temperature": 0.0},

@@ -62,7 +62,7 @@ mh = JaxEngine(
                  kv_partition=True),
     kv_dtype=jnp.float32, parallel=ParallelConfig(dp=4, tp=2),
 )
-assert mh._pooled and mh.cfg.mixed_prefill_tokens > 0
+assert mh.layout.pooled and mh.cfg.mixed_prefill_tokens > 0
 
 def req(p, n=8):
     return {"token_ids": p, "sampling_options": {"temperature": 0.0},
@@ -87,7 +87,7 @@ if rank == 0:
         kv_dtype=jnp.float32, parallel=ParallelConfig(dp=1, sp=2, tp=2),
         multihost=False, devices=jax.local_devices()[:4],
     )
-    assert pre._sp == 2
+    assert pre.layout.sp == 2
 
     plans = []
     orig = mh.scheduler.schedule

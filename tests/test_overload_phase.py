@@ -10,8 +10,9 @@ The two acceptance bars from the overload-control work
 
 Pure asyncio against the MockEngine (which reuses the REAL scheduler,
 so class-aware admission, deadline shedding, and park/resume preemption
-are the production code paths).  The full phase lives in bench.py's
-`overload_phase`.
+are the production code paths).  The full phase is
+`frontend.overload.overload_phase`; not measured on the chip (no
+benchmark cell runs past the knee).
 """
 
 import asyncio

@@ -130,7 +130,7 @@ async def test_engine_sp_sequence_parallel_prefill():
         cfg, params, ecfg(), kv_dtype=jnp.float32,
         parallel=ParallelConfig(dp=2, sp=4),
     )
-    assert par._sp == 4
+    assert par.layout.sp == 4
     out_par = await _collect(par, prompts)
     await par.shutdown()
 

@@ -46,6 +46,14 @@ def test_perf_profile_interpolation():
     # ITL SLO below the floor → no sustainable concurrency
     assert prof.max_decode_concurrency_under(1e-6) == 0.0
     assert prof.ttft_at(0.0) >= 0.1
+    # a MEASURED grid need not be monotone (a loaded machine reads the
+    # first point slowest): the last point meeting the SLO is the answer
+    import dataclasses
+
+    noisy = dataclasses.replace(prof, decode_concurrency=[1.0, 2.0],
+                                itl_s=[0.012, 0.006],
+                                decode_throughput=[77.0, 246.0])
+    assert noisy.max_decode_concurrency_under(0.009) == 2.0
 
 
 class FakeConnector:

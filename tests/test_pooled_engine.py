@@ -76,7 +76,7 @@ async def test_pooled_dp_tp_matches_single_device(setup):
 
     eng = make_engine(setup, parallel=ParallelConfig(dp=4, tp=2),
                       kv_partition=True)
-    assert eng._pooled and eng._pool_ranks == 4
+    assert eng.layout.pooled and eng.layout.pool_ranks == 4
     got = await _run_all(eng, PROMPTS)
     await eng.shutdown()
     assert got == want
@@ -97,7 +97,7 @@ async def test_pooled_dp_sp_ring_prefill_matches_single_device(setup):
         max_prefill_tokens=8 * 128, prefill_batch_size=2,
         max_model_len=128,
     )
-    assert eng._pooled and eng._pool_ranks == 4
+    assert eng.layout.pooled and eng.layout.pool_ranks == 4
     got = await _run_all(eng, PROMPTS)
     await eng.shutdown()
     assert got == want
@@ -182,12 +182,12 @@ async def test_pooled_mixed_scheduling_matches_unmixed(setup):
                 num_pages=128)
     mixed = make_engine(setup, parallel=ParallelConfig(dp=4, tp=2),
                         kv_partition=True, **over)
-    assert mixed._pooled and mixed.cfg.mixed_prefill_tokens > 0
+    assert mixed.layout.pooled and mixed.cfg.mixed_prefill_tokens > 0
     plans = _spy_plans(mixed)
     got = await _staggered(mixed, MIX_PROMPTS)
     await mixed.shutdown()
     assert "mixed" in plans, f"no mixed plan on the pooled engine: {set(plans)}"
-    assert mixed._mixed_steps, "mixed dispatches never compiled"
+    assert mixed.compiled_variants["mixed"], "mixed dispatches never compiled"
 
     unmixed = make_engine(setup, parallel=ParallelConfig(dp=4, tp=2),
                           kv_partition=True, mixed_prefill_tokens=0, **over)

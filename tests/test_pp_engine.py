@@ -69,7 +69,7 @@ async def test_pp_matches_single_device(setup):
     await ref.shutdown()
 
     eng = make_engine(setup, parallel=ParallelConfig(pp=2, dp=4))
-    assert eng._pp == 2
+    assert eng.layout.pp == 2
     got = await _run_all(eng)
     await eng.shutdown()
     assert got == want
@@ -131,7 +131,7 @@ async def test_pp_tp_matches_single_device(setup):
     await ref.shutdown()
 
     eng = make_engine(setup, parallel=ParallelConfig(dp=2, pp=2, tp=2))
-    assert eng._pp == 2
+    assert eng.layout.pp == 2
     from jax.sharding import PartitionSpec as P
 
     assert eng.kv.k.sharding.spec == P("pp", None, None, "tp", None)
@@ -156,7 +156,8 @@ async def test_pp_kv_partition_matches_and_scales(setup):
 
     eng = make_engine(setup, parallel=ParallelConfig(pp=2, dp=2, tp=2),
                       kv_partition=True)
-    assert eng._pp == 2 and eng._pooled and eng._pool_ranks == 2
+    assert (eng.layout.pp == 2 and eng.layout.pooled
+            and eng.layout.pool_ranks == 2)
     assert eng.kv.k.sharding.spec == P("pp", "dp", None, "tp", None)
     got = await _run_all(eng)
     await eng.shutdown()

@@ -32,7 +32,7 @@ def tiny_engine(**over):
 def consume_at_once(engine):
     """The same engine with no step ever left in flight: the parent's order
     (build, dispatch, fetch, deliver, then plan)."""
-    engine._can_hold_step = lambda: False  # noqa: SLF001
+    engine.layout.holds_step_in_flight = False
     return engine
 
 
@@ -447,7 +447,9 @@ async def test_a_failed_fetch_takes_the_step_behind_it_down_and_recovers(
 
 
 def test_a_multihost_leader_consumes_at_once():
+    from dynamo_tpu.engine.layout import Layout
+
     engine = tiny_engine()
-    assert engine._can_hold_step()  # noqa: SLF001
-    engine._multihost = True  # noqa: SLF001
-    assert not engine._can_hold_step()  # noqa: SLF001
+    assert engine.layout.holds_step_in_flight
+    leader = Layout(engine.model_cfg, engine.cfg, multihost=True)
+    assert not leader.holds_step_in_flight

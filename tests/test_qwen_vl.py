@@ -660,7 +660,7 @@ async def test_engine_qwen_vl_pooled_and_sp_match_flat():
         kv_dtype=jnp.float32, vision=(vparams, vcfg),
         parallel=ParallelConfig(dp=4, tp=2),
     )
-    assert pooled._pooled and pooled.cfg.mixed_prefill_tokens > 0, (
+    assert pooled.layout.pooled and pooled.cfg.mixed_prefill_tokens > 0, (
         "mrope no longer zeroes mixed scheduling")
     got = [await _gen(pooled, r) for r in reqs]
     assert got == want, "pooled dp×tp diverged from flat"

@@ -18,6 +18,8 @@ import pytest
 
 from dynamo_tpu.engine import EngineConfig, JaxEngine
 from dynamo_tpu.engine import engine as eng
+from dynamo_tpu.engine import steps
+from dynamo_tpu.engine.layout import Layout
 from dynamo_tpu.models import init_params, tiny_config
 from dynamo_tpu.runtime.events import StepEventRecorder, _encode_attrs
 
@@ -301,29 +303,29 @@ def test_packed_results_are_int32_and_round_trip_bit_exact(pair):
         toks = np.stack([IDS, IDS[::-1]], axis=1)  # [B, S=2]
         lps = np.stack([LOGP, LOGP[::-1]], axis=1)
         n_acc = np.arange(b, dtype=np.int32) % 2
-        packed = jax.jit(lambda t, l, a: eng._pack(
+        packed = jax.jit(lambda t, l, a: steps._pack(
             t.reshape(-1), l.reshape(-1), a))(toks, lps, n_acc)
         assert packed.dtype == jnp.int32
-        t, l, a = eng._unpack_spec(np.asarray(packed), b, 2)
+        t, l, a = steps._unpack_spec(np.asarray(packed), b, 2)
         assert (t == toks).all() and (a == n_acc).all()
         assert l.tobytes() == lps.tobytes()
         return
     if pair.startswith("cc"):
         act = np.array([1, 0, 1, 1, 0, 1], bool)
-        packed = jax.jit(lambda o, l, a, x: eng._pack_out_cc(
+        packed = jax.jit(lambda o, l, a, x: steps._pack_out_cc(
             o, l, a, x if top else None))(IDS, LOGP, act, logits)
-        toks, logp, flags, tids, tlps = eng._unpack_out_cc(
+        toks, logp, flags, tids, tlps = steps._unpack_out_cc(
             np.asarray(packed), b, top)
         assert (flags == act).all()
     else:
-        packed = jax.jit(lambda o, l, x: eng._pack_out(
+        packed = jax.jit(lambda o, l, x: steps._pack_out(
             o, l, x if top else None))(IDS, LOGP, logits)
-        toks, logp, tids, tlps = eng._unpack_out(np.asarray(packed), b, top)
+        toks, logp, tids, tlps = steps._unpack_out(np.asarray(packed), b, top)
     assert packed.dtype == jnp.int32
     assert toks.dtype == np.int32 and (toks == IDS).all()
     assert logp.dtype == np.float32 and logp.tobytes() == LOGP.tobytes()
     if top:
-        ids, lps = eng.top_logprobs(logits, eng.TOPLP)
+        ids, lps = steps.top_logprobs(logits, steps.TOPLP)
         assert (tids == np.asarray(ids)).all()
         assert tlps.tobytes() == np.asarray(lps).tobytes()
     else:
@@ -370,7 +372,9 @@ def test_programs_and_scopes_are_found_by_name():
     kv = KVCache.create(cfg, num_pages=8, page_size=8, dtype=jnp.float32)
     samp = eng.SamplingParams.make([0.0], [0], [1.0], [0.0], [0.0])
     one = np.zeros((1,), np.int32)
-    step = eng._build_prefill_step(cfg, greedy=True)
+    layout, _ = Layout.resolve(
+        cfg, EngineConfig(decode_steps=2, max_model_len=128))
+    step = layout.prefill_step(False, greedy=True)
     lowered = step.lower(
         params, kv, np.zeros((1, 16), np.int32), np.ones((1, 2), np.int32),
         one, one + 16, samp, one.astype(np.uint32), one)
@@ -379,7 +383,7 @@ def test_programs_and_scopes_are_found_by_name():
     for scope in ("embed", "attn.qkv", "kv.write", "kv.gather", "attn.core",
                   "attn.out", "mlp", "head", "sample", "pack"):
         assert f"/{scope}/" in text or f"{scope}/" in text, scope
-    assert eng._decode_name(1) == "decode_step"
-    assert eng._decode_name(8) == "decode_block"
-    mixed = eng._build_mixed_step(cfg, 2, 128, greedy=True)
+    assert steps.decode_name(1) == "decode_step"
+    assert steps.decode_name(8) == "decode_block"
+    mixed = layout.mixed_step(False, False, greedy=True, n_steps=2)
     assert mixed.__name__ == "mixed_step"

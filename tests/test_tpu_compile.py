@@ -127,8 +127,8 @@ def test_prefill_kernel_compiles_at_the_smoke_chunk(one_chip, extras):
 # -- whole steps ---------------------------------------------------------------- #
 
 def step_shapes(cfg, batch, table_pages, shardings, pool_pages=POOL_PAGES):
-    """Abstract operands of the engine's decode step (`_build_decode_step`)
-    for `cfg`, placed by `shardings(kind)` — kind is a PartitionSpec tree
+    """Abstract operands of the engine's decode step (`Layout.decode_step`;
+    no penalty counts) for `cfg`, placed by `shardings(kind)` — kind is a PartitionSpec tree
     for "params"/"kv" and None for the replicated batch operands."""
     params = jax.eval_shape(
         lambda: init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16))
@@ -148,8 +148,20 @@ def step_shapes(cfg, batch, table_pages, shardings, pool_pages=POOL_PAGES):
         rep((batch,), jnp.float32))
     return (place(params, param_pspecs(cfg)), place(kv, kv_cache_pspec()),
             rep((batch,), jnp.int32), rep((batch,), jnp.int32),
-            rep((batch,), jnp.int32), rep((batch, table_pages), jnp.int32),
+            rep((batch,), jnp.int32), None,
+            rep((batch, table_pages), jnp.int32),
             samp, rep((batch,), jnp.uint32))
+
+
+def flat_layout(cfg, attn_impl):
+    """The layout of a flat engine (GSPMD partitions its programs by their
+    operands' shardings) with blocks of 4 steps under a 4096-token cap."""
+    from dynamo_tpu.engine import EngineConfig
+    from dynamo_tpu.engine.layout import Layout
+
+    return Layout.resolve(cfg, EngineConfig(
+        attention_impl=attn_impl, decode_steps=4, max_model_len=4096,
+        max_pages_per_seq=4096 // PAGE))[0]
 
 
 def two_layers(cfg):
@@ -162,12 +174,10 @@ def test_decode_block_step_compiles_on_one_chip(one_chip):
     """A full-width (two-layer) decode step, both programs `adaptive`
     picks: the block path under 4096 tokens of table, and the per-step
     scan around the Pallas kernel from there on."""
-    from dynamo_tpu.engine.engine import _build_decode_step
-
     cfg = two_layers(LLAMA_3_2_1B)
     for table_pages, kernel in ((64, False), (DECODE_TABLE_PAGES, True)):
-        step = _build_decode_step(cfg, 4, 4096, greedy=True,
-                                  attn_impl="adaptive")
+        step = flat_layout(cfg, "adaptive").decode_step(
+            False, False, greedy=True)
         compiled = step.lower(
             *step_shapes(cfg, 8, table_pages, lambda spec: one_chip)
         ).compile()
@@ -179,11 +189,9 @@ def test_decode_block_step_compiles_on_one_chip(one_chip):
 def test_decode_block_step_compiles_on_a_tp4_mesh(topo):
     """The same step as GSPMD partitions it over the four chips (what
     `worker --tp 4` runs): heads and the KV pool sharded four ways."""
-    from dynamo_tpu.engine.engine import _build_decode_step
-
     cfg = two_layers(LLAMA_3_2_1B)
     mesh = Mesh(np.array(topo.devices).reshape(1, 4), ("dp", "tp"))
-    step = _build_decode_step(cfg, 4, 4096, greedy=True, attn_impl="xla")
+    step = flat_layout(cfg, "xla").decode_step(False, False, greedy=True)
     compiled = step.lower(
         *step_shapes(cfg, 8, 64, lambda spec: NamedSharding(mesh, spec))
     ).compile()
@@ -207,10 +215,10 @@ _MOVERS = ("copy", "dynamic-slice", "dynamic-update-slice", "reshape",
 
 
 def prefill_step_shapes(cfg, chunk, table_pages, shardings, pool_pages):
-    """Operands of the engine's `prefill_step` (`_build_prefill_step`):
+    """Operands of the engine's `prefill_step` (`Layout.prefill_step`):
     the decode step's, with a [B, chunk] token block, prefix and chunk
     lengths, seeds and counters."""
-    params, kv, _, _, _, table, samp, seeds = step_shapes(
+    params, kv, _, _, _, _, table, samp, seeds = step_shapes(
         cfg, 1, table_pages, shardings, pool_pages)
     lens = _sds((1,), jnp.int32, shardings(P()))
     return (params, kv, _sds((1, chunk), jnp.int32, shardings(P())), table,
@@ -247,10 +255,8 @@ def test_prefill_step_leaves_the_pool_where_it_is(one_chip, chunk,
     Counts and bytes, never a time."""
     import dataclasses
 
-    from dynamo_tpu.engine.engine import _build_prefill_step
-
     cfg = dataclasses.replace(QWEN2_5_7B, num_hidden_layers=CELL_LAYERS)
-    step = _build_prefill_step(cfg, attn_impl="adaptive", greedy=True)
+    step = flat_layout(cfg, "adaptive").prefill_step(False, greedy=True)
     args = prefill_step_shapes(cfg, chunk, table_pages, lambda spec: one_chip,
                                CELL_POOL_PAGES)
     compiled = step.lower(*args).compile()
@@ -271,11 +277,9 @@ def test_prefill_step_partitions_on_a_tp4_mesh(topo):
     widths: a pool of 64-wide heads is STORED pages-minor by the TPU
     compiler and is re-laid-out around any access by page, before this
     loop and after it: ROADMAP D3.)"""
-    from dynamo_tpu.engine.engine import _build_prefill_step
-
     cfg = two_layers(LLAMA_3_1_8B)
     mesh = Mesh(np.array(topo.devices).reshape(1, 4), ("dp", "tp"))
-    step = _build_prefill_step(cfg, attn_impl="xla", greedy=True)
+    step = flat_layout(cfg, "xla").prefill_step(False, greedy=True)
     args = prefill_step_shapes(
         cfg, SMOKE_CHUNK, SMOKE_TABLE_PAGES,
         lambda spec: NamedSharding(mesh, spec), POOL_PAGES)
@@ -298,7 +302,6 @@ def test_expert_prefill_step_reads_the_expert_stacks_in_place(one_chip):
     import math
     import re
 
-    from dynamo_tpu.engine.engine import _build_prefill_step
     from dynamo_tpu.models import ModelConfig
 
     cfg = ModelConfig.from_hf_config({
@@ -309,7 +312,7 @@ def test_expert_prefill_step_reads_the_expert_stacks_in_place(one_chip):
         "rms_norm_eps": 1e-06, "rope_layout": [0, 1], "rope_theta": 1500000,
         "sliding_window_layout": [0, 1], "sliding_window_size": 4096,
         "vocab_size": 151936})
-    step = _build_prefill_step(cfg, attn_impl="adaptive", greedy=True)
+    step = flat_layout(cfg, "adaptive").prefill_step(False, greedy=True)
     args = prefill_step_shapes(cfg, 512, 128, lambda spec: one_chip, 1024)
     compiled = step.lower(*args).compile()
     stack = 64 * 2560 * 768

@@ -344,7 +344,7 @@ async def test_import_dev_fetches_both_planes_in_one_device_get(setup, monkeypat
         return real_get(x)
 
     try:
-        monkeypatch.setattr(engine, "_multihost", True)
+        monkeypatch.setattr(engine.layout, "lockstep", True)
         monkeypatch.setattr(engine, "_stage_blob",
                             lambda k, v: ("tid", ("127.0.0.1", 1)))
         monkeypatch.setattr(engine, "_lockstep_send", lambda msg: None)
