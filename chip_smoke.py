@@ -20,8 +20,8 @@ The device identity comes from the worker (its `DEVICE` line beside
 One chip (the default):
   phase A   /v1/models, unary and SSE chat + completions at the worker's
             default flags, greedy: status, usage, finish reason, SSE == unary.
-  phase B   prompts long enough to reach BOTH Pallas kernels (prefill with
-            >= 1024 tokens of table, decode with >= 4096), then the same
+  phase B   prompts long enough to reach BOTH Pallas kernels (prefill in
+            512-token chunks, decode with >= 4096 tokens of table), then the same
             requests against a second worker (`--attention-impl xla`)
             started after the first has exited; top logprobs agree within
             LOGPROB_TOL.  The KV pool takes 4 GiB of the chip's 16 GB (see
@@ -345,9 +345,9 @@ def phase_a(base):
 
 def long_prompts(seed):
     """Two chat prompts of 2060 bytes (one token per byte with the test
-    tokenizer, plus the template): five prefill chunks of <= 512 whose
-    page tables reach 1024 tokens from the second on, and a decode whose
-    table is 4096 tokens wide.  The second shares its first 1540 bytes
+    tokenizer, plus the template): five prefill chunks of <= 512 (the
+    512-token ones run the Pallas prefill kernel under any table), and a
+    decode whose table is 4096 tokens wide.  The second shares its first 1540 bytes
     with the first — a prefix-cache hit whose remainder prefills against
     >= 1024 cached tokens."""
     import random

@@ -37,26 +37,35 @@ from ..analysis import xla_ledger
 NEG_INF = -1e30
 
 
-# contexts at least this wide use the Pallas kernels under "adaptive".
-# r5 re-measured the crossover AFTER the deferred-write decode fix (the
-# old per-layer scatter+gather pool copy had been taxing the xla path):
-# at ctx 2272/batch 4 on v5e the xla+deferred path runs 9.6ms/step vs
-# the kernel's 15.8 (the kernel still requires write-first), so the
+# DECODE contexts at least this wide use the Pallas kernel under
+# "adaptive".  r5 re-measured the crossover AFTER the deferred-write decode
+# fix (the old per-layer scatter+gather pool copy had been taxing the xla
+# path): at ctx 2272/batch 4 on v5e the xla+deferred path runs 9.6ms/step
+# vs the kernel's 15.8 (the kernel still requires write-first), so the
 # decode crossover moved out past 4k; each table-width bucket is its own
-# jit trace, so the choice is static per compiled step.  PREFILL keeps
-# the threshold of the old measurement (streaming kernel 3.2x at 4k,
-# winning from ~1k); it was taken before the prefill loop stopped moving
-# the pool (PERF.md, PR 26) and has not been re-swept since.
+# jit trace, so the choice is static per compiled step.
 PALLAS_MIN_CTX_TOKENS = 4096
-PALLAS_MIN_CTX_TOKENS_PREFILL = 1024
+
+# PREFILL steps whose XLA attention would materialise at least this many
+# f32 scores a head, chunk x (table + chunk), use the Pallas kernel under
+# "adaptive".  Whole `prefill_step` programs timed on a v5e, both forms
+# forced, chunks 16-512 under tables of 512-4096 tokens at two models'
+# widths (PERF.md, PR 34): XLA's cost follows the TABLE (the gather and the
+# scores) and climbs steeply once the scores pass some 60 MB, the kernel's
+# follows the prefix that is there, at a higher price a key.  From 2^19
+# scores the kernel wins or ties every shape measured (a 512-token chunk
+# under any table: by 2% at 512 tokens of table, by half at 4096); below,
+# XLA does (a 16-64-token chunk under any table up to 4096: by 1-13%).
+PALLAS_MIN_PREFILL_SCORES = 1 << 19
 
 
 def resolve_attention_impl(impl: str = "auto", meshed: bool = False) -> str:
     """Pick the attention implementation.
 
     "adaptive" — per-trace choice: the Pallas streaming kernels
-    (``ops.pallas_attention``) when the page-table bucket addresses at
-    least ``PALLAS_MIN_CTX_TOKENS``, the einsum path for short contexts.
+    (``ops.pallas_attention``) where they were measured to win (decode:
+    a page-table bucket of at least ``PALLAS_MIN_CTX_TOKENS``; prefill:
+    ``PALLAS_MIN_PREFILL_SCORES``), the einsum path elsewhere.
     Chosen on real TPU when the engine is single-device (the kernels are
     per-shard programs; under a GSPMD mesh the einsum path lets XLA
     partition freely).
@@ -85,31 +94,38 @@ def resolve_attention_impl(impl: str = "auto", meshed: bool = False) -> str:
     return choice
 
 
-# cap what "adaptive" sends to the prefill kernel so VMEM (~16MB) is never
-# oversubscribed at big chunk sizes; the estimate below counts every VMEM
-# resident: q/o blocks, the chunk's own kn/vn, f32 accumulator + m/l
-# scalars, and the double-buffered KV page scratch
-_PALLAS_PREFILL_VMEM_BUDGET = 12 * 1024 * 1024
+def _decode_rule(ctx: int):
+    if ctx >= PALLAS_MIN_CTX_TOKENS:
+        return "pallas", f"table {ctx} tokens >= {PALLAS_MIN_CTX_TOKENS}"
+    return "xla", f"table {ctx} tokens < {PALLAS_MIN_CTX_TOKENS}"
+
+
+def _prefill_rule(chunk: int, ctx: int, query_block):
+    """(choice, why) for a prefill step of `chunk` tokens under `ctx` tokens
+    of table; `query_block` is the kernel's own answer to whether any
+    block of this chunk fits its VMEM (None: none does)."""
+    scores = chunk * (ctx + chunk)
+    if query_block is None:
+        return "xla", f"no query block of a {chunk}-token chunk fits VMEM"
+    if scores >= PALLAS_MIN_PREFILL_SCORES:
+        return "pallas", (f"{chunk} x ({ctx} + {chunk}) scores a head >= "
+                          f"{PALLAS_MIN_PREFILL_SCORES}")
+    return "xla", (f"{chunk} x ({ctx} + {chunk}) scores a head < "
+                   f"{PALLAS_MIN_PREFILL_SCORES}")
 
 
 def _adapt(impl: str, page_table: jax.Array, page_size: int,
-           chunk_vmem_bytes: int = 0,
-           min_ctx: int = PALLAS_MIN_CTX_TOKENS,
-           site: str = "decode_attention", chunk: int = 1) -> str:
-    """Resolve "adaptive" for one trace.  Runs at trace time only, so the
-    ledger note below is once per compiled variant: which attention
-    program a (batch, chunk, table-width) step got, and why."""
+           rule=_decode_rule, site: str = "decode_attention",
+           chunk: int = 1) -> str:
+    """Resolve "adaptive" for one trace: `rule(table tokens)` says which
+    program and why.  Runs at trace time only, so the ledger note below is
+    once per compiled variant: which attention program a (batch, chunk,
+    table-width) step got, and why."""
     ctx = page_table.shape[1] * page_size
     if impl != "adaptive":
         choice, why = impl, f"attention_impl={impl}"
-    elif chunk_vmem_bytes > _PALLAS_PREFILL_VMEM_BUDGET:
-        choice, why = "xla", (
-            f"chunk VMEM estimate {chunk_vmem_bytes} B > "
-            f"{_PALLAS_PREFILL_VMEM_BUDGET} B budget")
-    elif ctx >= min_ctx:
-        choice, why = "pallas", f"table {ctx} tokens >= {min_ctx}"
     else:
-        choice, why = "xla", f"table {ctx} tokens < {min_ctx}"
+        choice, why = rule(ctx)
     xla_ledger.note_path_choice(
         site, choice, why, batch=page_table.shape[0], chunk=chunk,
         table_tokens=ctx)
@@ -247,17 +263,15 @@ def prefill_attention(
     is cut out of the pool."""
     B, S, n_heads, hd = q.shape
     page, n_kv = k_pages.shape[-3], k_pages.shape[-2]
-    esize = jnp.dtype(q.dtype).itemsize
-    vmem = (
-        2 * S * n_heads * hd * esize        # q + o blocks
-        + 2 * S * n_kv * hd * esize         # kn + vn blocks
-        + S * n_heads * hd * 4              # f32 accumulator
-        + 2 * S * n_heads * 4               # m + l
-        + 4 * max(1, 128 // page) * page * n_kv * hd * esize  # 2x2 KV bufs
-    )
-    impl = _adapt(impl, page_table, page, chunk_vmem_bytes=vmem,
-                  min_ctx=PALLAS_MIN_CTX_TOKENS_PREFILL,
-                  site="prefill_attention", chunk=S)
+
+    def rule(ctx):
+        from .pallas_attention import prefill_query_block
+
+        return _prefill_rule(S, ctx, prefill_query_block(
+            S, n_heads, n_kv, hd, page, q.dtype, k_pages.dtype))
+
+    impl = _adapt(impl, page_table, page, rule, site="prefill_attention",
+                  chunk=S)
     if impl == "pallas":
         from .pallas_attention import prefill_attention_pallas
 

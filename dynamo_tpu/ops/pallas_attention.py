@@ -25,8 +25,11 @@ Layout notes:
 - The DECODE kernel still gets one layer's slab in the ``[P, page,
   n_kv*hd]`` view from the scanned decode loop.
 - Prefill flattens the query heads onto lanes the same way (``[S, H*hd]``)
-  and keeps the online-softmax scalars as ``[S, H]`` so scratch stays
-  tile-exact at any chunk size.
+  and walks the chunk in QUERY BLOCKS on a grid axis of its own: q, o, the
+  online-softmax scalars (``[QB, H]``) and the f32 accumulator are QB rows
+  whatever the chunk, so the VMEM footprint stops growing with it.  QB
+  comes from ``prefill_query_block``, the one function that also sizes the
+  scratch.
 
 The reference delegates attention kernels to vLLM/TRT-LLM (SURVEY.md §2.6);
 this module is the TPU-native equivalent of their CUDA paged-attention
@@ -262,6 +265,95 @@ def decode_attention_pallas(
 # not a multiple of it — the short buckets — runs as one block)
 _PREFILL_ROW_BLOCK = 128
 
+# prefix tokens streamed per grid step of the prefill kernel: the key tile
+# every unrolled head works on.  Whole `prefill_step` programs on the chip
+# (PERF.md, PR 34): 256 takes a third off the kernel's time over the prefix
+# against 128 (one read-modify-write of a head's accumulator per 256 keys),
+# 512 no more.
+_PREFILL_STREAM_TOKENS = 256
+
+# What one grid step of the prefill kernel may keep in VMEM: the compiler's
+# scoped limit on a v5e.  `prefill_resident_bytes` counts the pipeline's
+# second buffer of EVERY blocked operand, so it is an upper bound: the
+# compiler's own figure (read from forced failures) is the scratch alone
+# where XLA has put q, o and the chunk's K and V into VMEM itself (batch
+# 1), and the scratch plus both buffers of q, kn and vn at batch 2, where
+# the chunk whole (17-18 MiB) is refused on the chip and two blocks pass.
+_PREFILL_VMEM_BUDGET = 16 * 1024 * 1024
+
+
+def _prefill_pages_per_step(page: int) -> int:
+    return max(1, _PREFILL_STREAM_TOKENS // page)
+
+
+def _vmem_bytes(shape, dtype) -> int:
+    """Bytes an array takes in VMEM, as the compiler's scoped figures
+    show them: lanes padded to 128, rows to what shares a 32-bit sublane
+    (two bf16 rows; a [page, 4, 128] bf16 page is not padded to a tile)."""
+    itemsize = jnp.dtype(dtype).itemsize
+    sub = max(1, 4 // itemsize)
+    *lead, rows, lanes = shape
+    return (math.prod(lead) * -(-rows // sub) * sub * -(-lanes // 128) * 128
+            * itemsize)
+
+
+def _prefill_residents(QB: int, S: int, H: int, n_kv: int, hd: int,
+                       page: int, dtype, pool_dtype=None):
+    """(blocked, scratch): shape and dtype of everything one grid step of
+    the prefill kernel holds in VMEM at a query block of QB rows, in the
+    order `prefill_attention_pallas` hands them to `pallas_call`.  Only q,
+    o, m / l and the accumulator follow QB; the chunk's own K and V stay
+    whole and the page buffers follow the page (and the pool's dtype, where
+    that is not the chunk's)."""
+    C = _prefill_pages_per_step(page)
+    f32 = jnp.float32
+    pool_dtype = pool_dtype or dtype
+    as_stored = hd % 128 == 0
+    page_shape = (page, n_kv, hd) if as_stored else (page, n_kv * hd)
+    blocked = [
+        ((1, QB, H * hd), dtype),  # q
+        ((1, H), f32),  # sink
+        ((1, S, n_kv * hd), dtype),  # kn
+        ((1, S, n_kv * hd), dtype),  # vn
+        ((1, QB, H * hd), dtype),  # o
+    ]
+    scratch = [
+        ((2, C, *page_shape), pool_dtype),  # k_scr
+        ((2, C, *page_shape), pool_dtype),  # v_scr
+        *([((C * page, n_kv * hd), pool_dtype)] * 2 if as_stored else []),
+        ((QB, H), f32),  # m_scr
+        ((QB, H), f32),  # l_scr
+        ((QB, H * hd), f32),  # acc_scr
+    ]
+    return blocked, scratch
+
+
+def prefill_resident_bytes(QB: int, *geom) -> int:
+    """VMEM bytes of `_prefill_residents(QB, *geom)`: every blocked operand
+    twice (the pipeline fetches the next block while this one is computed
+    on)."""
+    blocked, scratch = _prefill_residents(QB, *geom)
+    return (2 * sum(_vmem_bytes(*a) for a in blocked)
+            + sum(_vmem_bytes(*a) for a in scratch))
+
+
+def prefill_query_block(S: int, H: int, n_kv: int, hd: int, page: int,
+                        dtype, pool_dtype=None):
+    """Query rows per grid step of the prefill kernel for a chunk of S
+    tokens: the largest multiple of the row block that divides S and whose
+    residents fit the budget; a chunk that is no multiple of the row block
+    (the short buckets) has itself as its only candidate.  None where
+    nothing fits: the caller keeps that shape off the kernel."""
+    RB = _PREFILL_ROW_BLOCK
+    blocks = [S]
+    if S % RB == 0:
+        blocks = [q for q in range(S, 0, -RB) if S % q == 0]
+    for QB in blocks:
+        if prefill_resident_bytes(QB, S, H, n_kv, hd, page, dtype,
+                                  pool_dtype) <= _PREFILL_VMEM_BUDGET:
+            return QB
+    return None
+
 
 def _prefill_kernel(
     # scalar prefetch
@@ -271,21 +363,21 @@ def _prefill_kernel(
     win_ref,  # [1] int32 sliding window (0 = full attention)
     layer_ref,  # [1] int32 — which layer's pages of the pool to read
     # inputs (heads flattened onto lanes)
-    q_ref,  # [1, S, H*hd] VMEM (pre-scaled)
+    q_ref,  # [1, QB, H*hd] VMEM (pre-scaled) — this grid row's query block
     sink_ref,  # [1, H] f32 — per-head sink logits (NEG_INF = no sink)
-    kn_ref,  # [1, S, n_kv*hd] VMEM — the chunk's own K
+    kn_ref,  # [1, S, n_kv*hd] VMEM — the chunk's own K, whole
     vn_ref,
     k_hbm,  # [L, P, page, n_kv, hd] HBM — the whole pool, as it is stored
     v_hbm,  # (not `as_stored`: one layer's [1, P, page, n_kv*hd])
     # outputs
-    o_ref,  # [1, S, H*hd]
+    o_ref,  # [1, QB, H*hd]
     # scratch
     k_scr,  # [2, C, *page shape] — double-buffered pages
     v_scr,
     *scratch,  # as_stored: kf_scr, vf_scr [T, n_kv*hd] — the current chunk
     # with heads side by side; then always:
-    # m_scr [S, H] f32 — running max per (query row, head), l_scr [S, H]
-    # f32, acc_scr [S, H*hd] f32, sems
+    # m_scr [QB, H] f32 — running max per (query row, head), l_scr [QB, H]
+    # f32, acc_scr [QB, H*hd] f32, sems
     as_stored: bool,
     C: int,
     page: int,
@@ -293,22 +385,24 @@ def _prefill_kernel(
     groups: int,
     hd: int,
     nc: int,
-    S: int,
+    QB: int,
     RB: int,
 ):
     m_scr, l_scr, acc_scr, sems = scratch[-4:]
     b = pl.program_id(0)
-    c = pl.program_id(1)
+    q0 = pl.program_id(1) * QB  # this query block's first row of the chunk
+    c = pl.program_id(2)
     T = C * page
     prefix_len = pre_ref[b]
     chunk_len = cl_ref[b]
     window = win_ref[0]
-    # sliding window: the earliest query row (global position prefix_len)
-    # attends keys > prefix_len - window, so prefix chunks wholly before
-    # that are skipped — stream and compute scale with the window
+    # sliding window: the block's earliest query row (global position
+    # prefix_len + q0) attends keys > prefix_len + q0 - window, so prefix
+    # chunks wholly before that are skipped — stream and compute scale
+    # with the window
     first = jnp.where(
         window > 0,
-        jnp.maximum(prefix_len - window + 1, 0) // T,
+        jnp.maximum(prefix_len + q0 - window + 1, 0) // T,
         0,
     )
     ch = c + first
@@ -340,8 +434,10 @@ def _prefill_kernel(
     # code size — and its compile time — is the tile each unrolled head
     # works on.  Query rows therefore go through a `fori_loop` in blocks
     # of RB: every head touches [RB, T] / [RB, RB] score tiles whatever
-    # the chunk length S, and the program no longer grows with S.
-    nrb = S // RB
+    # the chunk length S, and the program no longer grows with S.  Rows
+    # `rs` are the block's own (0..QB); masks use the row's place in the
+    # chunk, q0 + row.
+    nrb = QB // RB
 
     def attend_rows(rs, k, v, valid):
         """Online-softmax update of rows `rs` (all heads) against one key
@@ -409,7 +505,7 @@ def _prefill_kernel(
             r0 = pl.multiple_of(r * RB, RB)
             # per-row mask: key position validity + sliding window around
             # the row's global query position (prefix_len + row)
-            rows = r0 + jax.lax.broadcasted_iota(jnp.int32, (RB, T), 0)
+            rows = q0 + r0 + jax.lax.broadcasted_iota(jnp.int32, (RB, T), 0)
             tpos = chunk_start + jax.lax.broadcasted_iota(
                 jnp.int32, (RB, T), 1)
             valid = tpos < prefix_len
@@ -428,7 +524,8 @@ def _prefill_kernel(
 
             def key_block(j, carry2):
                 j0 = pl.multiple_of(j * RB, RB)
-                i = r0 + jax.lax.broadcasted_iota(jnp.int32, (RB, RB), 0)
+                i = q0 + r0 + jax.lax.broadcasted_iota(
+                    jnp.int32, (RB, RB), 0)
                 jj = j0 + jax.lax.broadcasted_iota(jnp.int32, (RB, RB), 1)
                 causal = (jj <= i) & (jj < chunk_len)
                 causal &= (window <= 0) | (jj > i - window)
@@ -437,7 +534,7 @@ def _prefill_kernel(
                 return carry2
 
             # causal: key blocks past the row block's diagonal hold nothing
-            jax.lax.fori_loop(0, r + 1, key_block, 0)
+            jax.lax.fori_loop(0, q0 // RB + r + 1, key_block, 0)
 
             for h in range(n_kv * groups):
                 hl = slice(h * hd, (h + 1) * hd)
@@ -479,15 +576,12 @@ def prefill_attention_pallas(
     # of a tile.  Narrower heads (hd 64) keep ONE slice + relayout of the
     # layer's slab per layer, read-only (the stored layout: ROADMAP D3).
     as_stored = hd % 128 == 0
-    if as_stored:
-        page_shape = (page, n_kv, hd)
-    else:
-        page_shape = (page, n_kv * hd)
-        k_pages = k_pages[layer].reshape(1, P, *page_shape)
-        v_pages = v_pages[layer].reshape(1, P, *page_shape)
+    if not as_stored:
+        k_pages = k_pages[layer].reshape(1, P, page, n_kv * hd)
+        v_pages = v_pages[layer].reshape(1, P, page, n_kv * hd)
         layer = 0
     groups = H // n_kv
-    C = max(1, 128 // page)
+    C = _prefill_pages_per_step(page)
     maxp = page_table.shape[1]
     padded = -(-maxp // C) * C
     if padded != maxp:
@@ -501,33 +595,37 @@ def prefill_attention_pallas(
 
     win = jnp.full((1,), 0 if window is None else window, jnp.int32)
     sink_arr = _sink_arr(sink, H)
+    # the grid's middle axis walks the chunk in query blocks: q, o and the
+    # softmax state are QB rows whatever S is; the prefix is streamed once
+    # per query block, the chunk's own K and V once per row of the batch
+    geom = (S, H, n_kv, hd, page, q.dtype, k_pages.dtype)
+    QB = prefill_query_block(*geom)
+    if QB is None:
+        raise ValueError(
+            f"no query block of a {S}-token chunk (H={H}, n_kv={n_kv}, "
+            f"hd={hd}, page={page}) fits {_PREFILL_VMEM_BUDGET} B of VMEM")
+    blocked, scratch = _prefill_residents(QB, *geom)
+    q_blk, sink_blk, kn_blk, vn_blk, o_blk = (blk for blk, _ in blocked)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
-        grid=(B, nc),
+        grid=(B, S // QB, nc),
         in_specs=[
-            pl.BlockSpec((1, S, H * hd), lambda b, c, *_: (b, 0, 0)),
-            pl.BlockSpec((1, H), lambda b, c, *_: (0, 0)),
-            pl.BlockSpec((1, S, n_kv * hd), lambda b, c, *_: (b, 0, 0)),
-            pl.BlockSpec((1, S, n_kv * hd), lambda b, c, *_: (b, 0, 0)),
+            pl.BlockSpec(q_blk, lambda b, i, c, *_: (b, i, 0)),
+            pl.BlockSpec(sink_blk, lambda b, i, c, *_: (0, 0)),
+            pl.BlockSpec(kn_blk, lambda b, i, c, *_: (b, 0, 0)),
+            pl.BlockSpec(vn_blk, lambda b, i, c, *_: (b, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, S, H * hd), lambda b, c, *_: (b, 0, 0)),
+        out_specs=pl.BlockSpec(o_blk, lambda b, i, c, *_: (b, i, 0)),
         scratch_shapes=[
-            pltpu.VMEM((2, C, *page_shape), k_pages.dtype),
-            pltpu.VMEM((2, C, *page_shape), v_pages.dtype),
-            *([pltpu.VMEM((C * page, n_kv * hd), k_pages.dtype),
-               pltpu.VMEM((C * page, n_kv * hd), v_pages.dtype)]
-              if as_stored else []),
-            pltpu.VMEM((S, H), jnp.float32),
-            pltpu.VMEM((S, H), jnp.float32),
-            pltpu.VMEM((S, H * hd), jnp.float32),
+            *(pltpu.VMEM(shape, dtype) for shape, dtype in scratch),
             pltpu.SemaphoreType.DMA((2, 2, C)),
         ],
     )
     kernel = functools.partial(
         _prefill_kernel,
-        C=C, page=page, n_kv=n_kv, groups=groups, hd=hd, nc=nc, S=S,
+        C=C, page=page, n_kv=n_kv, groups=groups, hd=hd, nc=nc, QB=QB,
         RB=_PREFILL_ROW_BLOCK if S % _PREFILL_ROW_BLOCK == 0 else S,
         as_stored=as_stored,
     )

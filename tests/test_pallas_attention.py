@@ -15,9 +15,13 @@ from dynamo_tpu.ops.paged_attention import (
     prefill_attention,
     write_kv_pages,
 )
+from dynamo_tpu.ops import pallas_attention
 from dynamo_tpu.ops.pallas_attention import (
+    _vmem_bytes,
     decode_attention_pallas,
     prefill_attention_pallas,
+    prefill_query_block,
+    prefill_resident_bytes,
 )
 
 
@@ -263,17 +267,44 @@ def test_sinks_match_xla():
             )
 
 
-@pytest.mark.parametrize("window,sinks", [(None, False), (40, True),
-                                          (200, False)])
-def test_prefill_row_blocks_match_xla(window, sinks):
-    """A chunk longer than the kernel's row block (S = 2 x 128): the
+def _force_query_block(monkeypatch, QB, *geom):
+    """Make `prefill_query_block` choose QB for this geometry: the budget
+    becomes exactly what QB's residents take, so any larger block is over
+    it (no switch in the kernel: the budget is what sizes it)."""
+    monkeypatch.setattr(pallas_attention, "_PREFILL_VMEM_BUDGET",
+                        prefill_resident_bytes(QB, *geom))
+    assert prefill_query_block(*geom) == QB
+
+
+@pytest.mark.parametrize("S,QB,prefix,hd,window,sinks", [
+    (256, None, 160, 64, None, False),
+    (256, None, 160, 64, 40, True),
+    (256, None, 160, 64, 200, False),
+    # across QUERY blocks: the chunk's grid axis, the prefix streamed once
+    # per block, the causal loop reaching back into earlier blocks' keys
+    (512, 256, 0, 128, None, False),
+    (512, 256, 500, 128, None, False),
+    (512, 256, 1536, 128, None, False),
+    (512, 128, 500, 128, None, False),
+    (512, 128, 1536, 128, 40, True),
+    (512, 256, 500, 128, 40, True),
+    (512, 256, 500, 64, 40, True),
+    (512, 128, 0, 64, None, False),
+])
+def test_prefill_row_blocks_match_xla(monkeypatch, S, QB, prefix, hd, window,
+                                      sinks):
+    """A chunk longer than the kernel's row block (S = 2 or 4 x 128): the
     row-block `fori_loop`, the causal key-block loop under the diagonal
-    and the per-block finalize, with a prefix spanning two streamed
-    chunks, a window that masks whole key blocks, and sinks."""
-    B, H, n_kv, hd, page, S = 2, 4, 2, 64, 16, 256
-    maxp = (160 + S) // page + 2
-    prefix_lens = jnp.array([160, 0], jnp.int32)
-    chunk_lens = jnp.array([S, S - 37], jnp.int32)
+    and the per-block finalize, with a prefix spanning several streamed
+    chunks, a window that masks whole key blocks, and sinks.  With QB the
+    chunk also crosses query blocks (two of 256, four of 128), one row of
+    the batch shorter than a query block, heads fetched as stored (hd
+    128) and through the slab view (hd 64)."""
+    B, H, n_kv, page = 2, 4, 2, 16
+    maxp = (prefix + S) // page + 2
+    prefix_lens = jnp.array([prefix, prefix // 3], jnp.int32)
+    chunk_lens = jnp.array([S, S - 37] if QB is None else [S, 100],
+                           jnp.int32)
     P = 1 + B * maxp
     k_pages, v_pages = _make_pool(jax.random.PRNGKey(5), P, page, n_kv, hd,
                                   jnp.float32)
@@ -287,6 +318,9 @@ def test_prefill_row_blocks_match_xla(window, sinks):
         kw["window"] = jnp.int32(window)
     if sinks:
         kw["sink"] = jax.random.normal(ks[3], (H,), jnp.float32)
+    if QB is not None:
+        _force_query_block(monkeypatch, QB, S, H, n_kv, hd, page,
+                           jnp.float32)
 
     ref = prefill_attention(
         q, k_new, v_new, k_pages, v_pages, table, prefix_lens, chunk_lens,
@@ -303,6 +337,90 @@ def test_prefill_row_blocks_match_xla(window, sinks):
             np.asarray(ref[b, :n], np.float32),
             atol=2e-5, rtol=2e-5,
         )
+
+
+def _call_residents(S, H, n_kv, hd, page, dtype):
+    """VMEM bytes of the `pallas_call` the wrapper really builds: every
+    blocked operand twice, every VMEM scratch once."""
+    B, maxp = 1, 8
+    q = jnp.zeros((B, S, H, hd), dtype)
+    new = jnp.zeros((B, S, n_kv, hd), dtype)
+    pool = jnp.zeros((4, page, n_kv, hd), dtype)
+    table = jnp.zeros((B, maxp), jnp.int32)
+    lens = jnp.zeros((B,), jnp.int32)
+    jaxpr = jax.make_jaxpr(lambda: prefill_attention_pallas(
+        q, new, new, pool, pool, table, lens, lens, interpret=True))()
+    (call,) = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call"]
+    gm = call.params["grid_mapping"]
+    blocked = [bm.block_aval for bm in gm.block_mappings
+               if "<any>" not in str(bm.block_aval)]
+    scratch = [v.aval for v in call.params["jaxpr"].invars[
+        -gm.num_scratch_operands:] if "<vmem>" in str(v.aval)]
+    assert len(blocked) == 5 and len(scratch) in (5, 7)
+    return gm.grid, (
+        2 * sum(_vmem_bytes(a.shape, a.dtype) for a in blocked)
+        + sum(_vmem_bytes(a.shape, a.dtype) for a in scratch))
+
+
+@pytest.mark.parametrize("H,n_kv,hd,dtype", [
+    (28, 4, 128, jnp.bfloat16),  # the benchmark cells' attention
+    (32, 8, 128, jnp.bfloat16),  # Llama-3.1-8B
+    (32, 8, 64, jnp.bfloat16),  # Llama-3.2-1B: not fetched as stored
+    (4, 2, 64, jnp.float32),
+], ids=["qwen7b", "llama8b", "llama1b", "tiny-f32"])
+def test_prefill_query_block_sizes_what_the_call_holds(H, n_kv, hd, dtype):
+    """ONE function sizes the kernel: for every chunk bucket it returns a
+    divisor of the chunk (a multiple of the row block where the chunk is
+    one), its byte count IS the blocked operands (twice: the pipeline's
+    second buffer) and VMEM scratch of the call the wrapper builds from
+    it, that count is within the budget, and the next larger block is
+    over it."""
+    page = 16
+    geom = lambda S: (S, H, n_kv, hd, page, dtype)  # noqa: E731
+    for S in (16, 32, 64, 128, 256, 512):
+        QB = prefill_query_block(*geom(S))
+        assert QB is not None and S % QB == 0
+        assert QB == S or QB % pallas_attention._PREFILL_ROW_BLOCK == 0
+        grid, held = _call_residents(*geom(S))
+        assert grid[1] == S // QB
+        assert held == prefill_resident_bytes(QB, *geom(S))
+        assert held <= pallas_attention._PREFILL_VMEM_BUDGET
+        larger = [q for q in range(QB + 128, S + 1, 128) if S % q == 0]
+        assert all(prefill_resident_bytes(q, *geom(S))
+                   > pallas_attention._PREFILL_VMEM_BUDGET for q in larger)
+    # the cells' 512-token chunk runs as two blocks of the 256 bucket's size
+    if (H, n_kv, hd) == (28, 4, 128):
+        assert prefill_query_block(*geom(512)) == 256
+
+
+def test_adaptive_falls_back_only_where_no_query_block_fits(monkeypatch):
+    """`_adapt` asks the sizing function whether ANY block fits: under a
+    budget that holds no 128-row block a 512-token chunk is traced into
+    XLA attention with that reason; under the real one it is not."""
+    from dynamo_tpu.analysis import xla_ledger
+
+    B, S, H, n_kv, hd, page, maxp = 1, 512, 4, 2, 64, 16, 128
+    q = jnp.zeros((B, S, H, hd), jnp.float32)
+    new = jnp.zeros((B, S, n_kv, hd), jnp.float32)
+    pool = jnp.zeros((4, page, n_kv, hd), jnp.float32)
+    table = jnp.zeros((B, maxp), jnp.int32)
+    lens = jnp.zeros((B,), jnp.int32)
+    dims = dict(batch=B, chunk=S, table_tokens=maxp * page)
+
+    def trace():
+        jax.make_jaxpr(lambda: prefill_attention(
+            q, new, new, pool, pool, table, lens, lens, impl="adaptive"))()
+        return xla_ledger.path_choice("prefill_attention", **dims)
+
+    assert trace() == "pallas"
+    monkeypatch.setattr(pallas_attention, "_PREFILL_VMEM_BUDGET", 1 << 16)
+    assert prefill_query_block(S, H, n_kv, hd, page, jnp.float32) is None
+    assert trace() == "xla"
+    (why,) = [c["reason"] for c in xla_ledger.summary()["path_choices"]
+              if c["site"] == "prefill_attention" and c["choice"] == "xla"
+              and c["dims"] == ",".join(
+                  f"{k}={v}" for k, v in sorted(dims.items()))]
+    assert "no query block" in why
 
 
 @pytest.mark.parametrize("window,sinks", [(None, False), (40, True)],

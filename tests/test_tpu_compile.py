@@ -124,6 +124,32 @@ def test_prefill_kernel_compiles_at_the_smoke_chunk(one_chip, extras):
     assert secs < 60, f"prefill kernel took {secs:.1f}s to compile"
 
 
+@pytest.mark.parametrize("table_pages", [32, 64, 128, 256])
+def test_prefill_kernel_compiles_at_the_cells_chunk(one_chip, table_pages):
+    """The benchmark cells' attention (28 heads over 4 of 128, bf16) at the
+    512-token chunk, reading the whole 14-layer pool by (layer, page), under
+    every table a 512-token step meets there: the chunk goes through the
+    kernel in two query blocks, so q, o and the accumulator are the 256
+    bucket's and Mosaic's scoped VMEM takes it."""
+    from dynamo_tpu.ops.pallas_attention import prefill_query_block
+
+    cfg = QWEN2_5_7B
+    q, new, _, pool, _, table, lens, _ = _kernel_args(
+        cfg, one_chip, 1, table_pages, chunk=512)
+    pool = _sds((CELL_LAYERS, *pool.shape), pool.dtype, one_chip)
+    assert prefill_query_block(512, q.shape[2], new.shape[2], q.shape[3],
+                               PAGE, q.dtype) == 256
+
+    def fn(q, kn, vn, k, v, t, pre, cl, layer):
+        return prefill_attention_pallas(q, kn, vn, k, v, t, pre, cl,
+                                        layer=layer)
+
+    compiled, secs = _compile(fn, q, new, new, pool, pool, table, lens, lens,
+                              _sds((), jnp.int32, one_chip))
+    assert "tpu_custom_call" in compiled.as_text()
+    assert secs < 60, f"prefill kernel took {secs:.1f}s to compile"
+
+
 # -- whole steps ---------------------------------------------------------------- #
 
 def step_shapes(cfg, batch, table_pages, shardings, pool_pages=POOL_PAGES):
@@ -242,26 +268,41 @@ def pool_sized_movers(hlo_text, kv_shape):
     return found
 
 
-@pytest.mark.parametrize("chunk,table_pages,kernel", [
-    (512, 128, False), (512, 256, False), (64, 128, True),
-], ids=["xla-512x128", "xla-512x256", "pallas-64x128"])
+@pytest.mark.parametrize("chunk,table_pages,impl,choice", [
+    (512, 128, "xla", "xla"), (512, 256, "xla", "xla"),
+    (64, 128, "pallas", "pallas"), (512, 128, "adaptive", "pallas"),
+    (512, 256, "adaptive", "pallas"), (64, 128, "adaptive", "xla"),
+], ids=["xla-512x128", "xla-512x256", "pallas-64x128", "pallas-512x128",
+        "pallas-512x256", "xla-64x128-by-rule"])
 def test_prefill_step_leaves_the_pool_where_it_is(one_chip, chunk,
-                                                  table_pages, kernel):
+                                                  table_pages, impl, choice):
     """The benchmark cell's `prefill_step` programs (Qwen2.5-7B widths, 14
     layers, 6912 pages of 16, batch 1, pool donated) on both attention
     paths: the compiler's temporaries stay under ONE of k or v (they held
     the pool again: 3.6-3.7 GB), and nothing in the optimised HLO copies,
     slices, updates or re-lays-out the pool or a layer's slab of it.
-    Counts and bytes, never a time."""
+    "adaptive" is what the cells run: by `_adapt`'s measured rule it takes
+    the kernel at the 512-token chunk (then no score-shaped
+    f32[1,28,chunk,*] temporary is left in the program) and XLA attention
+    at a 64-token one.  Counts and bytes, never a time."""
     import dataclasses
+    import re
+
+    from dynamo_tpu.analysis import xla_ledger
 
     cfg = dataclasses.replace(QWEN2_5_7B, num_hidden_layers=CELL_LAYERS)
-    step = flat_layout(cfg, "adaptive").prefill_step(False, greedy=True)
+    step = flat_layout(cfg, impl).prefill_step(False, greedy=True)
     args = prefill_step_shapes(cfg, chunk, table_pages, lambda spec: one_chip,
                                CELL_POOL_PAGES)
     compiled = step.lower(*args).compile()
     text = compiled.as_text()
+    kernel = choice == "pallas"
+    assert xla_ledger.path_choice(
+        "prefill_attention", batch=1, chunk=chunk,
+        table_tokens=table_pages * PAGE) == choice
     assert ("tpu_custom_call" in text) is kernel
+    scores = re.findall(r"f32\[1,28,%d,\d+\]" % chunk, text)
+    assert bool(scores) is not kernel, scores[:3]
     k = args[1].k
     one_of_kv = k.size * k.dtype.itemsize  # 1.585 GB
     mem = compiled.memory_analysis()
