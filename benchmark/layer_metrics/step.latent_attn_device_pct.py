@@ -1,0 +1,17 @@
+"""Model step: share of the prefill programs' device time that latent
+attention takes: self time of the device ops of the query bottleneck, the
+latent and rotary-key projection, the absorption of the latents'
+up-projection, attention over the gathered latent pages and the output
+projection (how an op is placed: lib/latent_trace.py) over the device time
+of the prefill program, summed over the window's `prefill_chunk` steps.
+%."""
+
+from lib import latent_trace
+
+
+def read(run):
+    found = latent_trace.prefill_group_seconds(run)
+    if found is None or not found[0]:
+        return None
+    program_s, by_group, _ = found
+    return 100.0 * by_group["latent_attn"] / program_s

@@ -67,12 +67,18 @@ class KvLayout:
 
     @classmethod
     def of_engine(cls, engine) -> "KvLayout":
+        from ..models.llama import require_plain_cache
+
         mc = engine.model_cfg
+        # two planes of [heads, width] a page: what `bytes_per_page` and
+        # the wire format below assume
+        require_plain_cache(mc, "disagg KV transfer")
+        spec = mc.cache_spec
         return cls(
             layers=mc.num_hidden_layers,
             page_size=engine.cfg.page_size,
-            n_kv_heads=mc.num_key_value_heads,
-            head_dim=mc.head_dim_,
+            n_kv_heads=spec.heads,
+            head_dim=spec.width,
             dtype=np.dtype(engine._kv_dtype).name,
         )
 

@@ -39,8 +39,8 @@ from .config import EngineConfig, bucket_for
 from .layout import Layout
 from .page_pool import KvEvent, NoPagesError
 from .scheduler import PrefillItem, SamplingOptions, Scheduler, Sequence, StepPlan
+from ..models.llama import moe_stats_width, require_plain_cache
 from .steps import (
-    MOE_STATS,
     _unpack_out,
     _unpack_out_cc,
     _unpack_spec,
@@ -248,6 +248,7 @@ class JaxEngine:
         # what an expert model's prefill-path steps append to their pack
         # (`Layout.carries_moe_stats`)
         self.moe_assignments_total = 0
+        self.moe_local_assignments_total = 0  # that chose a HELD expert
         self.moe_experts_hit_total = 0
         self.moe_steps_total = 0
         if self.cfg.quantization == "int8":
@@ -267,8 +268,11 @@ class JaxEngine:
         # exactly like the tokens)
         self.vision = vision
         self._encode_fn = None
+        if self.cfg.park_max_pages:  # parked pages are (k, v) host blobs
+            require_plain_cache(model_cfg, "preemption parking")
         self.params = self.layout.shard_params(params)
         self.kv = self.layout.make_kv(kv_dtype)
+        logger.info("cache: %s", self.cache_report())
         self._extra_event_sinks: List[Callable[[KvEvent], None]] = []
         if event_sink:
             self._extra_event_sinks.append(event_sink)
@@ -381,6 +385,21 @@ class JaxEngine:
         self._ttft_turn_wait_ms_total = 0.0
         self._evictions_before_reset = 0
 
+    def cache_report(self) -> dict:
+        """The page pool by its one description (`ModelConfig.cache_spec`):
+        the kind of pages, what a token leaves a layer, what it is stored
+        as, and the pool's size (the worker's `CACHE` start-up line)."""
+        spec, L = self.model_cfg.cache_spec, self.model_cfg.num_hidden_layers
+        per_token = L * spec.bytes_per_token_layer(
+            jnp.dtype(self._kv_dtype).itemsize)
+        tokens = (self.layout.pool_ranks * self.cfg.num_pages
+                  * self.cfg.page_size)
+        return {"kind": spec.kind, "values_per_token_layer": spec.values,
+                "planes": [list(d) for d in spec.plane_dims],
+                "dtype": jnp.dtype(self._kv_dtype).name, "layers": L,
+                "bytes_per_token": per_token, "pool_tokens": tokens,
+                "pool_bytes": tokens * per_token}
+
     def attach_connector(self, connector) -> None:
         """Attach a KVBM connector (kvbm.KvConnector shape: on_event /
         pump_offloads / onboard).  The engine pumps its offload queue and
@@ -391,6 +410,7 @@ class JaxEngine:
         channel like every other device op; the host/disk tiers stay
         leader-local) and with kv_partition (onboarded pages land on
         the admitting sequence's pool rank)."""
+        require_plain_cache(self.model_cfg, "a KVBM tier")
         self.tiered = connector
         self.add_event_sink(connector.on_event)
 
@@ -683,6 +703,9 @@ class JaxEngine:
             setattr(m, f"decode_rung{rung}_dispatches_total", n)
         if self.layout.carries_moe_stats:  # an expert model's prefill path
             m.moe_assignments_total = self.moe_assignments_total
+            if self.model_cfg.moe_ep_size > 1:
+                m.moe_local_assignments_total = (
+                    self.moe_local_assignments_total)
             m.moe_experts_hit_total = self.moe_experts_hit_total
             m.moe_steps_total = self.moe_steps_total
         if self.pool.ranks > 1:
@@ -1369,11 +1392,16 @@ class JaxEngine:
         returned as the step slice's attributes.  {} for any other step."""
         if not self.layout.carries_moe_stats:
             return {}
-        assigned, hit, load = (int(v) for v in packed[-MOE_STATS:])
+        width = moe_stats_width(self.model_cfg)
+        assigned, hit, load, *local = (int(v) for v in packed[-width:])
         self.moe_assignments_total += assigned
         self.moe_experts_hit_total += hit
         self.moe_steps_total += 1
-        return {"experts_hit": hit, "moe_max_load": load}
+        attrs = {"experts_hit": hit, "moe_max_load": load}
+        if local:  # a share of each layer's experts is held here
+            self.moe_local_assignments_total += local[0]
+            attrs["moe_local"] = local[0]
+        return attrs
 
     def _attn_of(self, site: str, batch: int, chunk: int,
                  width: int) -> str:

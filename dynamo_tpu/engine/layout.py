@@ -30,6 +30,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..analysis import xla_ledger
 from ..models import KVCache, ModelConfig, forward_prefill, kv_cache_pspec
+from ..models.llama import require_plain_cache
 from ..ops import SamplingParams
 from ..ops.paged_attention import resolve_attention_impl
 from . import steps
@@ -86,6 +87,15 @@ class Layout:
         self.pool_ranks = self.dp * self.sp if self.pooled else 1
         self.attn_impl = resolve_attention_impl(
             cfg.attention_impl, meshed=self.mesh is not None)
+        if model_cfg.is_latent and self.attn_impl != "xla":
+            # the kernels read [n_kv, hd] pages of k and v
+            if cfg.attention_impl == "pallas":
+                raise ValueError("attention_impl='pallas': no Pallas kernel "
+                                 "reads latent pages yet")
+            self.attn_impl = "xla"
+            xla_ledger.note_path_choice(
+                "attention_impl", "xla", "latent pages: no kernel reads them",
+                requested=cfg.attention_impl)
         self._prefill_steps: Dict[tuple, Callable] = {}
         self._decode_steps: Dict[tuple, Callable] = {}
         self._mixed_steps: Dict[tuple, Callable] = {}
@@ -116,6 +126,13 @@ class Layout:
                 "device set (dp*tp*sp == jax.device_count())"
             )
         mesh = device = None
+        for what, on in (("a serving mesh (--tp/--dp/--sp/--pp)", meshed),
+                         ("kv_partition", cfg.kv_partition),
+                         ("fuse_projections", cfg.fuse_projections),
+                         ("int8 quantization",
+                          cfg.quantization == "int8")):
+            if on:
+                require_plain_cache(model_cfg, what)
         if meshed:
             from ..parallel import make_mesh
 
@@ -169,7 +186,7 @@ class Layout:
             if tp > 1:
                 bad = [k for k, v in {
                     "q heads": model_cfg.num_attention_heads,
-                    "kv heads": model_cfg.num_key_value_heads,
+                    "kv heads": model_cfg.cache_spec.heads,
                     "vocab_size": model_cfg.vocab_size,
                 }.items() if v % tp]
                 if bad:
@@ -237,7 +254,7 @@ class Layout:
             # need not divide
             uneven = {
                 "q heads": model_cfg.num_attention_heads,
-                "kv heads": model_cfg.num_key_value_heads,
+                "kv heads": model_cfg.cache_spec.heads,
                 "vocab_size": model_cfg.vocab_size,
             }
             if not model_cfg.is_moe:

@@ -41,9 +41,11 @@ def _decode_path(attn_impl: str, kv, page_table, n_steps: int) -> str:
     block whose gathered KV would pass the byte budget stays per-step."""
     from ..ops.paged_attention import _adapt
 
-    blk_bytes = (2 * kv.k.shape[0] * page_table.shape[0]
-                 * page_table.shape[1] * kv.k.shape[2]
-                 * kv.k.shape[3] * kv.k.shape[4] * kv.k.dtype.itemsize)
+    # both planes' rows (a latent pool's differ in width)
+    blk_bytes = sum(p.shape[0] * page_table.shape[0]
+                    * page_table.shape[1] * p.shape[2]
+                    * p.shape[3] * p.shape[4] * p.dtype.itemsize
+                    for p in (kv.k, kv.v))
     if _adapt(attn_impl, page_table, kv.k.shape[2]) == "pallas":
         path, why = "per-step", "pallas decode kernel (write-first layout)"
     elif blk_bytes > _BLOCK_KV_BYTE_BUDGET:
@@ -85,9 +87,6 @@ def _as_f32(bits: np.ndarray) -> np.ndarray:
     return np.ascontiguousarray(bits).view(np.float32)
 
 
-MOE_STATS = 3  # int32 columns an expert model's step appends to its pack
-
-
 def carries_moe_stats(cfg: ModelConfig) -> bool:
     """The steps that run the prefill layer path (prefill, the prefill
     side of a mixed step, speculative verify) of an expert model append
@@ -106,7 +105,8 @@ def _pack_out(out: jax.Array, logp: jax.Array, logits=None,
     fixed cost whatever its size, so results come back in a single transfer.
 
     Layout: [tok(B) | logp(B) | top_ids(B*TOPLP) | top_lps(B*TOPLP)
-    | moe(MOE_STATS)], the last only from an expert model's step.
+    | moe(`models.llama.moe_stats_width`)], the last only from an expert
+    model's step.
     """
     parts = [out, logp]
     if logits is not None:
@@ -561,16 +561,16 @@ def mixed_body(cfg: ModelConfig, forward, n_steps: int, max_valid_pos: int,
 # -- KV pages in and out (disaggregation, offload, parking) ------------------ #
 
 def gather_pages(own=None):
-    """Export: pages [N] int32 → (k, v) [L, N, page, n_kv, hd].  On a
-    partitioned pool the ids are LOCAL to ONE rank, a trailing `rank`
+    """Export: pages [N] int32 → (k, v) [L, N, page, n_kv, hd] (a latent
+    pool's planes: the shared rotary key, the latent).  On a partitioned pool the ids are LOCAL to ONE rank, a trailing `rank`
     operand names it, and the layout's `own(x, rank)` keeps the owner's
     gather and drops every other shard's."""
 
     def body(kv, pages, *rank):
-        k, v = kv.k[:, pages], kv.v[:, pages]
+        blobs = jax.tree.map(lambda plane: plane[:, pages], kv)
         if own is not None:
-            k, v = own(k, *rank), own(v, *rank)
-        return k, v
+            blobs = jax.tree.map(lambda b: own(b, *rank), blobs)
+        return tuple(blobs)
 
     return body
 
@@ -582,11 +582,11 @@ def set_pages(mine=None):
     the blob on the owning rank, the pages' current values elsewhere."""
 
     def body(kv, k_blob, v_blob, pages, *rank):
-        if mine is not None:
-            k_blob = mine(k_blob, kv.k, pages, *rank)
-            v_blob = mine(v_blob, kv.v, pages, *rank)
-        return type(kv)(
-            kv.k.at[:, pages].set(k_blob), kv.v.at[:, pages].set(v_blob)
-        )
+        def put(plane, blob):
+            if mine is not None:
+                blob = mine(blob, plane, pages, *rank)
+            return plane.at[:, pages].set(blob)
+
+        return jax.tree.map(put, kv, type(kv)(k_blob, v_blob))
 
     return body

@@ -10,12 +10,65 @@ building its ModelDeploymentCard, /root/reference/lib/llm/src/model_card.rs:118)
 from __future__ import annotations
 
 import json
+import math
 import os
 from dataclasses import dataclass
 from typing import Optional
 
 
 MOE_ACTS = ("silu", "gpt_oss_glu", "relu_glu")
+MOE_SCORINGS = ("softmax", "sigmoid")
+
+
+@dataclass(frozen=True)
+class CacheSpec:
+    """What one token leaves in the page pool, a layer: the ONE description
+    every allocator, exporter and log reads.  The pool is two arrays [L, P,
+    page, *dims], one per entry of `plane_dims`:
+
+    "kv"      keys and values, [heads, width] each.
+    "latent"  latent attention stores no per-head keys or values: the
+              rotated rotary key all heads share (`key_width` values), and
+              the normalised latent (`width` values) from which every
+              head's key and value are computed when they are attended to.
+
+    A latent plane is stored as whole 128-lane tiles, a power of two and
+    at least two of them a token ([4, 128] for a 512-wide latent, [2, 128]
+    for a 64-wide key and 192 zeros): the geometry of a [heads, 128] key
+    page, which the TPU compiler keeps where it is.  ONE plane of [1, 576]
+    rows, or of [5, 128], it stores in another order than its scatter and
+    its gather want and re-lays out around both (four copies of the whole
+    pool a step); a [1, 128] key plane likewise, twice a step (AOT for a
+    v5e, PR 35).  `bytes_per_token_layer` counts what is stored."""
+
+    kind: str  # "kv" | "latent"
+    heads: int
+    width: int
+    key_width: int = 0  # latent: the shared rotary key's values
+
+    @property
+    def values(self) -> int:
+        """Values a token leaves a layer, padding apart."""
+        if self.kind == "latent":
+            return self.key_width + self.width
+        return 2 * self.heads * self.width
+
+    @property
+    def plane_dims(self) -> tuple:
+        """The trailing [*, *] of the pool's two arrays (k's, v's)."""
+        if self.kind != "latent":
+            return ((self.heads, self.width),) * 2
+
+        def tiles(n):
+            t = 2
+            while t * 128 < n:
+                t *= 2
+            return (t, 128)
+
+        return tiles(self.key_width), tiles(self.width)
+
+    def bytes_per_token_layer(self, itemsize: int) -> int:
+        return sum(a * b for a, b in self.plane_dims) * itemsize
 
 
 @dataclass(frozen=True)
@@ -88,6 +141,41 @@ class ModelConfig:
     # 0 = no positions at all in that layer.  None = every layer rotates,
     # and the layer scans carry no extra operand.
     rope_layout: Optional[tuple] = None
+    # latent attention (deepseek_v3: `kv_lora_rank` > 0).  Queries pass a
+    # rank-`q_lora_rank` bottleneck with a norm; keys and values come from
+    # ONE normalised latent of `kv_lora_rank` values a token beside ONE
+    # rotary key of `qk_rope_head_dim` shared by all heads, and those two
+    # are all the cache holds (`cache_spec`).  Scores are
+    # `qk_nope_head_dim + qk_rope_head_dim` wide, values `v_head_dim`.
+    kv_lora_rank: int = 0
+    q_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # leading layers that carry a dense feed-forward of `intermediate_size`
+    # in a model whose other layers are expert layers
+    # (`first_k_dense_replace`): the params carry them as a second stack,
+    # `dense_layers`, ahead of `layers`
+    first_k_dense: int = 0
+    # experts every token passes beside the routed ones (one SwiGLU of
+    # width n_shared_experts * moe_intermediate_size)
+    n_shared_experts: int = 0
+    # the router: "softmax" = top-k on the logits, softmax over the chosen;
+    # "sigmoid" = deepseek_v3's `noaux_tc`: sigmoid scores, a bias added
+    # for CHOOSING only, the choice limited to the `moe_topk_group` best of
+    # `moe_n_group` groups (a group's score: its two largest biased
+    # scores), weights from the unbiased scores normalised over the chosen
+    # and multiplied by `moe_routed_scale`
+    moe_scoring: str = "softmax"
+    moe_n_group: int = 1
+    moe_topk_group: int = 1
+    moe_routed_scale: float = 1.0
+    # the chip's share of an expert layer: `num_experts` are HELD here,
+    # rank `moe_ep_rank` of `moe_ep_size` chips that share each layer; the
+    # router keeps its full width `num_experts * moe_ep_size`, and the
+    # layer computes its own experts' part of the result.  1 / 0: all held
+    moe_ep_size: int = 1
+    moe_ep_rank: int = 0
     # identity
     model_type: str = "llama"
     name: str = "llama"
@@ -102,6 +190,20 @@ class ModelConfig:
             raise ValueError(
                 f"rope_layout has {len(self.rope_layout)} entries for "
                 f"{L} layers")
+        if self.moe_scoring not in MOE_SCORINGS:
+            raise ValueError(f"moe_scoring must be one of {MOE_SCORINGS}, "
+                             f"got {self.moe_scoring!r}")
+        if not 0 <= self.moe_ep_rank < self.moe_ep_size:
+            raise ValueError(f"moe_ep_rank {self.moe_ep_rank} is not one of "
+                             f"{self.moe_ep_size} ranks")
+        if self.first_k_dense and not 0 < self.first_k_dense < L:
+            raise ValueError(
+                f"first_k_dense {self.first_k_dense} must leave an expert "
+                f"layer among {L}")
+        if self.is_moe and self.router_width % self.moe_n_group:
+            raise ValueError(
+                f"moe_n_group {self.moe_n_group} must divide the router's "
+                f"{self.router_width} outputs")
 
     @property
     def head_dim_(self) -> int:
@@ -114,6 +216,47 @@ class ModelConfig:
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def is_latent(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def router_width(self) -> int:
+        """The router's outputs: every expert of the layer, held or not."""
+        return self.num_experts * self.moe_ep_size
+
+    @property
+    def first_expert(self) -> int:
+        """Global index of the first expert held here."""
+        return self.num_experts * self.moe_ep_rank
+
+    @property
+    def num_moe_layers(self) -> int:
+        return (self.num_hidden_layers - self.first_k_dense
+                if self.is_moe else 0)
+
+    @property
+    def cache_spec(self) -> CacheSpec:
+        if self.is_latent:
+            return CacheSpec("latent", 1, self.kv_lora_rank,
+                             self.qk_rope_head_dim)
+        return CacheSpec("kv", self.num_key_value_heads, self.head_dim_)
+
+    @property
+    def latent_softmax_scale(self) -> float:
+        """Latent attention's score scale: 1/sqrt(score width), times the
+        square of yarn's `mscale_all_dim` amplitude (deepseek_v3 folds it
+        into the scale: `ops.rope_attention_scale` is the cos/sin factor,
+        mscale / mscale_all_dim, and does not cover it)."""
+        scale = (self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5
+        rs = self.rope_scaling or {}
+        all_dim = rs.get("mscale_all_dim")
+        factor = float(rs.get("factor", 1.0))
+        if all_dim and factor > 1.0:
+            m = 0.1 * float(all_dim) * math.log(factor) + 1.0
+            scale *= m * m
+        return scale
 
     def layer_windows(self) -> list:
         """Per-layer attention window (0 = full attention)."""
@@ -135,17 +278,29 @@ class ModelConfig:
     def num_params(self) -> int:
         """Approximate parameter count (for memory planning)."""
         h, v, l = self.hidden_size, self.vocab_size, self.num_hidden_layers
-        hd = self.head_dim_
-        attn = h * (self.num_attention_heads * hd) + 2 * h * (
-            self.num_key_value_heads * hd
-        ) + (self.num_attention_heads * hd) * h
-        if self.is_moe:
-            ffn_inter = self.moe_intermediate_size or self.intermediate_size
-            mlp = self.num_experts * 3 * h * ffn_inter + h * self.num_experts
+        hd, nh = self.head_dim_, self.num_attention_heads
+        if self.is_latent:
+            qr, r = self.q_lora_rank, self.kv_lora_rank
+            nope, pe, vd = (self.qk_nope_head_dim, self.qk_rope_head_dim,
+                            self.v_head_dim)
+            attn = (h * qr + qr + qr * nh * (nope + pe) + h * (r + pe) + r
+                    + r * nh * (nope + vd) + nh * vd * h)
         else:
-            mlp = 3 * h * self.intermediate_size
+            attn = h * (nh * hd) + 2 * h * (
+                self.num_key_value_heads * hd) + (nh * hd) * h
+        dense = 3 * h * self.intermediate_size
+        if not self.is_moe:
+            return l * (attn + dense + 2 * h) + v * h * (
+                1 if self.tie_word_embeddings else 2) + h
+        # the experts HELD here, the router's full width (and its choosing
+        # bias under the sigmoid router), the shared expert
+        ffn_inter = self.moe_intermediate_size or self.intermediate_size
+        mlp = (self.num_experts * 3 * h * ffn_inter + h * self.router_width
+               + (self.router_width if self.moe_scoring == "sigmoid" else 0)
+               + self.n_shared_experts * 3 * h * ffn_inter)
         emb = v * h * (1 if self.tie_word_embeddings else 2)
-        return l * (attn + mlp + 2 * h) + emb + h
+        k = self.first_k_dense
+        return (l * (attn + 2 * h) + k * dense + (l - k) * mlp + emb + h)
 
     @staticmethod
     def from_hf_config(d: dict, name: str = "") -> "ModelConfig":
@@ -153,6 +308,13 @@ class ModelConfig:
         num_experts = d.get("num_local_experts", d.get("n_routed_experts", 0)) or 0
         if d.get("model_type") == "smallthinker":
             return ModelConfig(**_smallthinker_fields(d, name))
+        if d.get("model_type") == "deepseek_v3":
+            return ModelConfig(**_deepseek_v3_fields(d, name))
+        if d.get("kv_lora_rank"):
+            raise ValueError(
+                f"model_type {d.get('model_type')!r} asks for latent "
+                "attention (kv_lora_rank) and only deepseek_v3's is "
+                "implemented: the llama branch would build another model")
         return ModelConfig(
             vocab_size=d["vocab_size"],
             hidden_size=d["hidden_size"],
@@ -269,6 +431,78 @@ def _smallthinker_fields(d: dict, name: str) -> dict:
         rope_layout=tuple(_layout(d, "rope_layout", L)),
         model_type="smallthinker",
         name=name or d.get("_name_or_path", "smallthinker"),
+    )
+
+
+def _deepseek_v3_fields(d: dict, name: str) -> dict:
+    """DeepSeek-V3's architecture (config.json of deepseek_v3 checkpoints:
+    DeepSeek-V3/R1, GigaChat3, Kimi-K2 ...): latent attention behind a
+    query bottleneck, `first_k_dense_replace` dense layers and then expert
+    layers under the `noaux_tc` grouped sigmoid router with a shared
+    expert.  `n_routed_experts` counts the experts HELD by this rank
+    (`ep_rank`, default 0) of `ep_size` ranks; the router is
+    `n_routed_experts * ep_size` wide.  What is not implemented is refused
+    by the key that asks for it."""
+    def refuse(key, why):
+        raise ValueError(f"deepseek_v3: {key} {d.get(key)!r} {why}")
+
+    if not d.get("q_lora_rank"):
+        refuse("q_lora_rank", "the full-rank query projection is not "
+               "implemented")
+    if not d.get("kv_lora_rank"):
+        refuse("kv_lora_rank", "this family has latent attention")
+    if d.get("topk_method", "noaux_tc") != "noaux_tc":
+        refuse("topk_method", "only noaux_tc is implemented")
+    if d.get("scoring_func", "sigmoid") != "sigmoid":
+        refuse("scoring_func", "only sigmoid is implemented")
+    if not d.get("norm_topk_prob", True):
+        refuse("norm_topk_prob", "only normalised weights are implemented")
+    if d.get("moe_layer_freq", 1) != 1:
+        refuse("moe_layer_freq", "only an expert layer in every layer "
+               "after the dense ones is implemented")
+    if d.get("attention_bias"):
+        refuse("attention_bias", "is not implemented for latent attention")
+    if d.get("hidden_act", "silu") != "silu":
+        refuse("hidden_act", "only silu is implemented")
+    rs = d.get("rope_scaling")
+    if rs and rs.get("rope_type", rs.get("type")) != "yarn":
+        refuse("rope_scaling", "only yarn is implemented")
+    L, k = d["num_hidden_layers"], d.get("first_k_dense_replace", 0)
+    if not 0 <= k < L:
+        refuse("first_k_dense_replace", f"must leave an expert layer "
+               f"among {L}")
+    return dict(
+        vocab_size=d["vocab_size"],
+        hidden_size=d["hidden_size"],
+        intermediate_size=d["intermediate_size"],
+        num_hidden_layers=L,
+        num_attention_heads=d["num_attention_heads"],
+        # every head has its own key and value, computed from the latent
+        num_key_value_heads=d["num_attention_heads"],
+        head_dim=d["qk_nope_head_dim"] + d["qk_rope_head_dim"],
+        max_position_embeddings=d.get("max_position_embeddings", 4096),
+        rms_norm_eps=d.get("rms_norm_eps", 1e-6),
+        rope_theta=float(d.get("rope_theta", 10000.0)),
+        rope_scaling=rs,
+        tie_word_embeddings=d.get("tie_word_embeddings", False),
+        kv_lora_rank=d["kv_lora_rank"],
+        q_lora_rank=d["q_lora_rank"],
+        qk_nope_head_dim=d["qk_nope_head_dim"],
+        qk_rope_head_dim=d["qk_rope_head_dim"],
+        v_head_dim=d["v_head_dim"],
+        first_k_dense=k,
+        num_experts=d["n_routed_experts"],
+        num_experts_per_tok=d["num_experts_per_tok"],
+        moe_intermediate_size=d["moe_intermediate_size"],
+        n_shared_experts=d.get("n_shared_experts", 0),
+        moe_scoring="sigmoid",
+        moe_n_group=d.get("n_group", 1),
+        moe_topk_group=d.get("topk_group", 1),
+        moe_routed_scale=float(d.get("routed_scaling_factor", 1.0)),
+        moe_ep_size=d.get("ep_size", 1),
+        moe_ep_rank=d.get("ep_rank", 0),
+        model_type="deepseek_v3",
+        name=name or d.get("_name_or_path", "deepseek_v3"),
     )
 
 

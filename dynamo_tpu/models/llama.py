@@ -31,6 +31,12 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from ..ops.latent_attention import (
+    decode_parts,
+    latent_attention,
+    prefill_parts,
+    rows_of,
+)
 from ..ops import (
     apply_rope,
     decode_attention,
@@ -48,7 +54,11 @@ Params = dict
 
 
 class KVCache(NamedTuple):
-    """Paged KV pool for all layers: [L, P, page, n_kv, hd]."""
+    """Paged pool for all layers, as `ModelConfig.cache_spec` describes it:
+    keys and values [L, P, page, n_kv, hd] each, or, for latent attention,
+    no per-head keys or values at all: in `k` the rotary key all heads
+    share, in `v` the latent ([L, P, page, tiles, 128] each, whole lane
+    tiles a token)."""
 
     k: jax.Array
     v: jax.Array
@@ -65,14 +75,9 @@ class KVCache(NamedTuple):
     def create(
         cfg: ModelConfig, num_pages: int, page_size: int, dtype=jnp.bfloat16
     ) -> "KVCache":
-        shape = (
-            cfg.num_hidden_layers,
-            num_pages,
-            page_size,
-            cfg.num_key_value_heads,
-            cfg.head_dim_,
-        )
-        return KVCache(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
+        lead = (cfg.num_hidden_layers, num_pages, page_size)
+        return KVCache(*(jnp.zeros((*lead, *dims), dtype)
+                         for dims in cfg.cache_spec.plane_dims))
 
 
 # --------------------------------------------------------------------------- #
@@ -91,6 +96,8 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
         scale = scale or (1.0 / jnp.sqrt(shape[-2] if len(shape) > 1 else h))
         return (jax.random.normal(k, shape, jnp.float32) * scale).astype(dtype)
 
+    if cfg.is_latent or cfg.first_k_dense:
+        return _init_params_stacks(cfg, key, dtype, w)
     layers = {
         "wq": w(next(ks), L, h, nh * hd),
         "wk": w(next(ks), L, h, nkv * hd),
@@ -149,6 +156,97 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
     return params
 
 
+def _init_params_stacks(cfg: ModelConfig, key, dtype, w) -> Params:
+    """`init_params` of a latent-attention model and of one with leading
+    dense layers: the stack `dense_layers` ahead of the expert stack
+    `layers` (`layer_stacks`)."""
+    if not cfg.is_latent:
+        raise ValueError("first_k_dense is implemented with latent "
+                         "attention only (deepseek_v3)")
+    h, nh, L = cfg.hidden_size, cfg.num_attention_heads, cfg.num_hidden_layers
+    r, pe = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+    nope, vd, qr = cfg.qk_nope_head_dim, cfg.v_head_dim, cfg.q_lora_rank
+    fm, E = cfg.moe_intermediate_size, cfg.num_experts
+    ks = iter(jax.random.split(key, 40))
+
+    def attn(n):
+        return {
+            "attn_norm": jnp.ones((n, h), dtype),
+            "mlp_norm": jnp.ones((n, h), dtype),
+            "wq_a": w(next(ks), n, h, qr),
+            "q_norm": jnp.ones((n, qr), dtype),
+            "wq_b": w(next(ks), n, qr, nh * (nope + pe)),
+            "wkv_a": w(next(ks), n, h, r + pe),
+            "kv_norm": jnp.ones((n, r), dtype),
+            "w_uk": w(next(ks), n, nh, nope, r, scale=r ** -0.5),
+            "w_uv": w(next(ks), n, nh, r, vd),
+            "wo": w(next(ks), n, nh * vd, h),
+        }
+
+    def dense(n):
+        f = cfg.intermediate_size
+        return {"w_gate": w(next(ks), n, h, f), "w_up": w(next(ks), n, h, f),
+                "w_down": w(next(ks), n, f, h)}
+
+    k = cfg.first_k_dense
+    params = {
+        "embed": w(next(ks), cfg.vocab_size, h, scale=0.02),
+        "final_norm": jnp.ones((h,), dtype),
+    }
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = w(next(ks), h, cfg.vocab_size)
+    if not cfg.is_moe:
+        params["layers"] = {**attn(L), **dense(L)}
+        return params
+    if k:
+        params["dense_layers"] = {**attn(k), **dense(k)}
+    n = L - k
+    layers = {
+        **attn(n),
+        "router": w(next(ks), n, h, cfg.router_width),
+        "w_gate": w(next(ks), n, E, h, fm),
+        "w_up": w(next(ks), n, E, h, fm),
+        "w_down": w(next(ks), n, E, fm, h),
+    }
+    if cfg.moe_scoring == "sigmoid":
+        layers["router_bias"] = (0.02 * jax.random.normal(
+            next(ks), (n, cfg.router_width), jnp.float32))
+    if cfg.n_shared_experts:
+        fs = cfg.n_shared_experts * fm
+        layers.update({"ws_gate": w(next(ks), n, h, fs),
+                       "ws_up": w(next(ks), n, h, fs),
+                       "ws_down": w(next(ks), n, fs, h)})
+    params["layers"] = layers
+    return params
+
+
+def layer_stacks(params: Params) -> Tuple[Params, ...]:
+    """The model's stacks of layers, in layer order: the leading dense
+    stack (`first_k_dense`) where the params carry one, then `layers`."""
+    if "dense_layers" in params:
+        return params["dense_layers"], params["layers"]
+    return (params["layers"],)
+
+
+def _scan_stacks(body, x, stacks, xs):
+    """`lax.scan(body, x, (stack, *xs))` over each stack of layers in turn,
+    the per-layer operands `xs` ([L, ...] each) cut to the stack's layers
+    and the ys joined again.  One stack is the one scan it always was."""
+    if not isinstance(stacks, tuple):
+        stacks = (stacks,)
+    if len(stacks) == 1:
+        return jax.lax.scan(body, x, (stacks[0], *xs))
+    at, outs = 0, []
+    for stack in stacks:
+        n = jax.tree.leaves(stack)[0].shape[0]
+        x, ys = jax.lax.scan(
+            body, x,
+            (stack, *jax.tree.map(lambda a: a[at:at + n], tuple(xs))))
+        outs.append(ys)
+        at += n
+    return x, jax.tree.map(lambda *a: jnp.concatenate(a), *outs)
+
+
 def param_pspecs(cfg: ModelConfig, tp_axis: str = "tp", ep_axis: str = "tp") -> Params:
     """PartitionSpec tree matching `init_params` (megatron-style TP).
 
@@ -156,6 +254,7 @@ def param_pspecs(cfg: ModelConfig, tp_axis: str = "tp", ep_axis: str = "tp") -> 
     and down on its input; embeddings shard on vocab.  Layer-stacked arrays
     keep axis 0 (layers) replicated.
     """
+    require_plain_cache(cfg, "a serving mesh")
     layers = {
         "wq": P(None, None, tp_axis),
         "wk": P(None, None, tp_axis),
@@ -328,8 +427,49 @@ def _route(lp: Params, rx: jax.Array, cfg: ModelConfig):
     router sits before attention (`cfg.moe_router_pre_attn`)."""
     with jax.named_scope("moe.router"):
         logits = moe_router_logits(lp, rx, "...h,he->...e")
+        if cfg.moe_scoring == "sigmoid":
+            return _route_grouped_sigmoid(lp, logits, cfg)
         weights, selected = jax.lax.top_k(logits, cfg.num_experts_per_tok)
         return jax.nn.softmax(weights, axis=-1), selected
+
+
+def _route_grouped_sigmoid(lp: Params, logits: jax.Array, cfg: ModelConfig):
+    """deepseek_v3's `noaux_tc` choice over the router's float32 logits
+    [..., W]: scores sigmoid(logits); the bias joins them for CHOOSING
+    only; a group's score is the sum of its two largest biased scores and
+    only the `moe_topk_group` best groups stay; the k largest biased scores
+    among those are chosen; their weights are the UNBIASED scores,
+    normalised over the chosen and scaled by `moe_routed_scale`."""
+    W, G = cfg.router_width, cfg.moe_n_group
+    scores = jax.nn.sigmoid(logits)
+    biased = scores + lp["router_bias"]
+    grouped = biased.reshape(*biased.shape[:-1], G, W // G)
+    group_score = jax.lax.top_k(grouped, 2)[0].sum(-1)  # [..., G]
+    _, best = jax.lax.top_k(group_score, cfg.moe_topk_group)
+    keep = jax.nn.one_hot(best, G, dtype=jnp.bool_).any(-2)  # [..., G]
+    masked = jnp.where(keep[..., None], grouped, -jnp.inf).reshape(
+        biased.shape)
+    _, selected = jax.lax.top_k(masked, cfg.num_experts_per_tok)
+    chosen = jnp.take_along_axis(scores, selected, axis=-1)
+    weights = chosen / chosen.sum(-1, keepdims=True) * cfg.moe_routed_scale
+    return weights, selected
+
+
+def _held(selected: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """Chosen experts' ids among the `num_experts` HELD here; an expert on
+    another rank of the layer's share falls outside [0, num_experts)."""
+    return selected - cfg.first_expert if cfg.first_expert else selected
+
+
+@jax.named_scope("moe.shared")
+def _moe_shared(lp: Params, x: jax.Array) -> jax.Array:
+    """The shared expert every token passes (one SwiGLU), computed on every
+    rank of the layer's share alike."""
+    gate = matmul_any(x, lp["ws_gate"], "bsh,hf->bsf")
+    up = matmul_any(x, lp["ws_up"], "bsh,hf->bsf")
+    act = jax.nn.silu(gate) * up
+    return matmul_any(act.astype(x.dtype), lp["ws_down"],
+                      "bsf,fh->bsh").astype(x.dtype)
 
 
 def _moe_dense(lp: Params, x: jax.Array, cfg: ModelConfig,
@@ -342,7 +482,9 @@ def _moe_dense(lp: Params, x: jax.Array, cfg: ModelConfig,
     weights, selected = routed or _route(
         lp, x if router_x is None else router_x, cfg)  # [B,S,k]
     with jax.named_scope("moe.dispatch"):
-        onehot = jax.nn.one_hot(selected, E, dtype=x.dtype)  # [B,S,k,E]
+        # an expert held elsewhere is out of range: an all-zero row
+        onehot = jax.nn.one_hot(_held(selected, cfg), E,
+                                dtype=x.dtype)  # [B,S,k,E]
         combine = jnp.einsum("bsk,bske->bse", weights.astype(x.dtype),
                              onehot)  # [B,S,E]
     with jax.named_scope("moe.experts"):
@@ -379,12 +521,19 @@ def _moe_ragged(lp: Params, x: jax.Array, cfg: ModelConfig,
         lp, x if router_x is None else router_x, cfg)
     weights, selected = weights.reshape(T, k), selected.reshape(T, k)
 
+    share = cfg.moe_ep_size > 1
     with jax.named_scope("moe.dispatch"):
-        expert_of = selected.reshape(A)  # assignment → expert
+        expert_of = _held(selected, cfg).reshape(A)  # assignment → expert
+        if share:
+            # assignments to experts held elsewhere sort behind every
+            # group: rows no group covers, zeroed after the last matmul
+            expert_of = jnp.where((expert_of >= 0) & (expert_of < E),
+                                  expert_of, E)
         order = jnp.argsort(expert_of, stable=True)  # group by expert
         token_of = order // k  # assignment a (row-major [T, k]) is token a // k
         xs = xf[token_of]  # [A, h] rows sorted by expert
-        group_sizes = jnp.bincount(expert_of, length=E)
+        group_sizes = (jnp.bincount(expert_of, length=E + 1)[:E] if share
+                       else jnp.bincount(expert_of, length=E))
         expert_sorted = expert_of[order]  # bias rows per sorted assignment
 
     with jax.named_scope("moe.experts"):
@@ -406,6 +555,8 @@ def _moe_ragged(lp: Params, x: jax.Array, cfg: ModelConfig,
         )  # [A, h]
         if "b_down" in lp:
             ys = ys + lp["b_down"][expert_sorted]
+        if share:
+            ys = jnp.where((expert_sorted < E)[:, None], ys, 0.0)
 
     with jax.named_scope("moe.combine"):
         wf = weights.reshape(A)[order].astype(jnp.float32)
@@ -414,25 +565,43 @@ def _moe_ragged(lp: Params, x: jax.Array, cfg: ModelConfig,
         return out.reshape(B, S, h).astype(x.dtype)
 
 
+def moe_stats_width(cfg: ModelConfig) -> int:
+    """int32 columns of `moe_step_stats`: a chip's share of the layer adds
+    a fourth, the assignments that chose an expert held here."""
+    return 4 if cfg.moe_ep_size > 1 else 3
+
+
 def moe_step_stats(selected: jax.Array, num_experts: int,
-                   valid: Optional[jax.Array] = None) -> jax.Array:
+                   valid: Optional[jax.Array] = None,
+                   width: Optional[int] = None) -> jax.Array:
     """int32 [3] of one expert layer over one step's tokens: assignments
     (token, expert pairs), distinct experts touched, and the largest
-    per-expert row count.  `selected` [..., k]; `valid` [...] masks padded
-    rows out (a bucket's padding all routes alike and would read as one
-    hot expert)."""
+    per-expert row count.  `selected` [..., k]: ids among the experts HELD
+    (`_held`); `valid` [...] masks padded rows out (a bucket's padding all
+    routes alike and would read as one hot expert).  With `width` 4 (a
+    share of the layer: ids out of range are experts held elsewhere) the
+    assignments count every choice and a fourth column those that chose a
+    held expert."""
     onehot = jax.nn.one_hot(selected, num_experts, dtype=jnp.int32)
-    if valid is not None:
-        onehot = onehot * valid[..., None, None].astype(jnp.int32)
+    ok = None if valid is None else valid[..., None, None].astype(jnp.int32)
+    if ok is not None:
+        onehot = onehot * ok
     counts = onehot.reshape(-1, num_experts).sum(0)
-    return jnp.stack([counts.sum(), (counts > 0).sum(), counts.max()])
+    stats = [counts.sum(), (counts > 0).sum(), counts.max()]
+    if width == 4:
+        every = jnp.ones(selected.shape + (1,), jnp.int32)
+        stats = [(every if ok is None else every * ok).sum(), *stats[1:],
+                 counts.sum()]
+    return jnp.stack(stats)
 
 
 def merge_moe_stats(per_layer: jax.Array) -> jax.Array:
-    """[..., 3] stats of several layers (or steps) -> [3]: assignments and
-    experts touched add up, the largest load is the largest anywhere."""
-    flat = per_layer.reshape(-1, 3)
-    return jnp.stack([flat[:, 0].sum(), flat[:, 1].sum(), flat[:, 2].max()])
+    """[..., 3 | 4] stats of several layers (or steps) -> [3 | 4]:
+    assignments and experts touched add up, the largest load is the largest
+    anywhere."""
+    flat = per_layer.reshape(-1, per_layer.shape[-1])
+    return jnp.stack([flat[:, i].max() if i == 2 else flat[:, i].sum()
+                      for i in range(flat.shape[1])])
 
 
 # `moe_impl="auto"`: the most tokens of one step (batch x chunk) for which the
@@ -485,9 +654,12 @@ def _moe(lp: Params, x: jax.Array, cfg: ModelConfig,
         # inside an explicit expert-sharded shard_map; outside one the
         # dropless ragged dispatch is the same math on one shard
         out = _moe_ragged(lp, x, cfg, routed=routed)
+    if "ws_gate" in lp:
+        out = out + _moe_shared(lp, x)
     if not stats:
         return out
-    return out, moe_step_stats(routed[1], cfg.num_experts, valid)
+    return out, moe_step_stats(_held(routed[1], cfg), cfg.num_experts, valid,
+                               moe_stats_width(cfg))
 
 
 def _moe_capacity(lp: Params, x: jax.Array, cfg: ModelConfig,
@@ -567,6 +739,95 @@ def _moe_capacity(lp: Params, x: jax.Array, cfg: ModelConfig,
     return out.reshape(B, S, h).astype(x.dtype)
 
 
+def _latent_qkv(lp: Params, x: jax.Array, positions: jax.Array,
+                cfg: ModelConfig, inv_freq: jax.Array, rope_scale: float):
+    """Latent attention's projections of x [B, S, h] (deepseek_v3).
+    Returns (q_abs [B, S, nh, rank]: each head's query with `W_uk` folded
+    in, q_pe [B, S, nh, pe]: its rotated rotary part, and what the cache
+    holds of each token: k_pe [B, S, pe], the rotated shared key, and c_kv
+    [B, S, rank], the normalised latent)."""
+    B, S, _ = x.shape
+    nh, r = cfg.num_attention_heads, cfg.kv_lora_rank
+    nope, pe = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    dt = x.dtype
+    with jax.named_scope("attn.q_lora"):
+        attn_in = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
+        c_q = rms_norm(
+            matmul_any(attn_in, lp["wq_a"], "bsh,hr->bsr").astype(dt),
+            lp["q_norm"], cfg.rms_norm_eps)
+        q = matmul_any(c_q, lp["wq_b"], "bsr,rd->bsd").astype(dt).reshape(
+            B, S, nh, nope + pe)
+    with jax.named_scope("attn.kv_latent"):
+        ckv = matmul_any(attn_in, lp["wkv_a"], "bsh,hr->bsr").astype(dt)
+        c_kv = rms_norm(ckv[..., :r], lp["kv_norm"], cfg.rms_norm_eps)
+        q_pe = apply_rope(q[..., nope:], positions, inv_freq,
+                          scale=rope_scale)
+        k_pe = apply_rope(ckv[..., None, r:], positions, inv_freq,
+                          scale=rope_scale)[:, :, 0]
+    with jax.named_scope("attn.kv_up"):
+        q_abs = jnp.einsum("bshd,hdr->bshr", q[..., :nope], lp["w_uk"],
+                           preferred_element_type=jnp.float32).astype(dt)
+    return q_abs, q_pe, k_pe, c_kv
+
+
+def _latent_out(lp: Params, attn: jax.Array, x: jax.Array) -> jax.Array:
+    """x + the output projection of attention over latents: `attn` [B, S,
+    nh, rank] takes each head's `W_uv` first."""
+    B, S, nh, _ = attn.shape
+    with jax.named_scope("attn.kv_up"):
+        o = jnp.einsum("bshr,hrv->bshv", attn, lp["w_uv"],
+                       preferred_element_type=jnp.float32).astype(x.dtype)
+    with jax.named_scope("attn.out"):
+        return x + matmul_any(o.reshape(B, S, -1), lp["wo"],
+                              "bsd,dh->bsh").astype(x.dtype)
+
+
+def _feed_forward(lp: Params, x: jax.Array, x_in: jax.Array,
+                  cfg: ModelConfig, chunk_lens=None, moe_stats: bool = False):
+    """x [B, S, h] + its feed-forward: the layer's experts where it has a
+    router, its dense SwiGLU otherwise (a leading layer of a
+    dense-then-expert model).  -> (x, *stats): with `moe_stats` the layer's
+    `moe_step_stats` over the rows below `chunk_lens` [B] (None: every
+    row), zeros from a dense layer."""
+    with jax.named_scope("mlp"):
+        mlp_in = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
+        if "router" not in lp:
+            zeros = (jnp.zeros((moe_stats_width(cfg),), jnp.int32),
+                     ) if moe_stats else ()
+            return (x + _mlp(lp, mlp_in), *zeros)
+        router_x = x_in if cfg.moe_router_pre_attn else None
+        if not moe_stats:
+            return (x + _moe(lp, mlp_in, cfg, router_x),)
+        valid = (None if chunk_lens is None else
+                 jnp.arange(x.shape[1])[None, :] < chunk_lens[:, None])
+        mlp_out, st = _moe(lp, mlp_in, cfg, router_x, valid, stats=True)
+        return x + mlp_out, st
+
+
+def _layer_prefill_latent(lp, kv, layer, x, positions, page_table,
+                          prefix_lens, chunk_lens, cfg, inv_freq, rope_scale,
+                          moe_stats):
+    """`_layer_prefill` over latent pages: -> (x, (k_pe, c_kv, *stats)),
+    the chunk's own cache rows as the pool stores them [B, S, tiles, 128]."""
+    q_abs, q_pe, k_pe, c_kv = _latent_qkv(lp, x, positions, cfg, inv_freq,
+                                          rope_scale)
+    attn = latent_attention(
+        q_abs, q_pe, prefill_parts(kv.k, kv.v, k_pe, c_kv, page_table,
+                                   prefix_lens, chunk_lens, layer),
+        cfg.latent_softmax_scale)
+    h = _latent_out(lp, attn, x)
+    h, *st = _feed_forward(lp, h, x, cfg, chunk_lens, moe_stats)
+    return h, (_stored(k_pe, kv.k), _stored(c_kv, kv.v), *st)
+
+
+def _stored(rows: jax.Array, pool: jax.Array) -> jax.Array:
+    """[..., width] rows as the pool stores them: [..., tiles, 128], zeros
+    after the row's own values."""
+    tiles, lanes = pool.shape[-2:]
+    pad = [(0, 0)] * (rows.ndim - 1) + [(0, tiles * lanes - rows.shape[-1])]
+    return jnp.pad(rows, pad).reshape(*rows.shape[:-1], tiles, lanes)
+
+
 def _layer_prefill(
     lp: Params,
     kv: KVCache,  # the WHOLE pool, read only (and only by page)
@@ -590,6 +851,10 @@ def _layer_prefill(
     OLD pool's pages of `layer` plus the chunk itself, and layer l+1 never
     reads what layer l wrote, so the caller lands every layer's (k, v) in
     one scatter after the loop (`write_kv_layers`)."""
+    if cfg.is_latent:
+        return _layer_prefill_latent(
+            lp, kv, layer, x, positions, page_table, prefix_lens, chunk_lens,
+            cfg, inv_freq, rope_scale, moe_stats)
     B, S, h = x.shape
     nh, nkv, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim_
 
@@ -621,16 +886,8 @@ def _layer_prefill(
             attn_out = attn_out + lp["bo"].astype(x.dtype)
         x = x + attn_out
 
-    with jax.named_scope("mlp"):
-        mlp_in = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
-        if not cfg.is_moe:
-            return x + _mlp(lp, mlp_in), (k, v)
-        router_x = x_in if cfg.moe_router_pre_attn else None
-        if not moe_stats:
-            return x + _moe(lp, mlp_in, cfg, router_x), (k, v)
-        valid = jnp.arange(S)[None, :] < chunk_lens[:, None]
-        mlp_out, st = _moe(lp, mlp_in, cfg, router_x, valid, stats=True)
-        return x + mlp_out, (k, v, st)
+    x, *st = _feed_forward(lp, x, x_in, cfg, chunk_lens, moe_stats)
+    return x, (k, v, *st)
 
 
 def _layer_decode(
@@ -652,6 +909,20 @@ def _layer_decode(
     rope_on=None,  # per-layer rotary switch (scalar; 0 → no positions)
     moe_stats: bool = False,  # returns (x, kv_out, `moe_step_stats`)
 ):
+    if cfg.is_latent:
+        # always the deferred write: attend to the OLD pool's rows plus the
+        # token's own row, which the caller lands after the loop
+        q_abs, q_pe, k_pe, c_kv = _latent_qkv(lp, x[:, None], (
+            positions if rope_pos is None else rope_pos)[:, None], cfg,
+            inv_freq, rope_scale)
+        attn = latent_attention(
+            q_abs, q_pe, decode_parts(*kv_layer, k_pe, c_kv, page_table,
+                                      seq_lens),
+            cfg.latent_softmax_scale)
+        h, *st = _feed_forward(lp, _latent_out(lp, attn, x[:, None]),
+                               x[:, None], cfg, None, moe_stats)
+        return (h[:, 0], (_stored(k_pe[:, 0], kv_layer[0]),
+                          _stored(c_kv[:, 0], kv_layer[1])), *st)
     B, h = x.shape
     nh, nkv, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim_
     k_pages, v_pages = kv_layer
@@ -704,15 +975,15 @@ def _layer_decode(
             attn_out = attn_out + lp["bo"].astype(x.dtype)
         x = x + attn_out
 
-    with jax.named_scope("mlp"):
-        mlp_in = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
-        if not cfg.is_moe:
-            return x + _mlp(lp, mlp_in[:, None])[:, 0], kv_out
-        router_x = x_in[:, None] if cfg.moe_router_pre_attn else None
-        if not moe_stats:
-            return x + _moe(lp, mlp_in[:, None], cfg, router_x)[:, 0], kv_out
-        mlp_out, st = _moe(lp, mlp_in[:, None], cfg, router_x, stats=True)
-        return x + mlp_out[:, 0], kv_out, st
+    x, *st = _feed_forward(lp, x[:, None], x_in[:, None], cfg, None,
+                           moe_stats)
+    return (x[:, 0], kv_out, *st)
+
+
+def _rope_dim(cfg: ModelConfig) -> int:
+    """Width of the rotated part of a head: all of it, or latent
+    attention's separate rotary key."""
+    return cfg.qk_rope_head_dim if cfg.is_latent else cfg.head_dim_
 
 
 def _window_xs(cfg: ModelConfig):
@@ -758,6 +1029,18 @@ def require_flat_layer_scan(cfg: ModelConfig, layout: str) -> None:
             "flat or with --tp")
 
 
+def require_plain_cache(cfg: ModelConfig, what: str) -> None:
+    """Latent pages (one array a layer and no `v`) and the dense-then-expert
+    stacks are carried by the flat engine's own programs only: every path
+    with its own copy of the layer body, the pool's spec or the page blobs
+    refuses such a family at start-up."""
+    if cfg.is_latent or cfg.first_k_dense:
+        raise ValueError(
+            f"{what} does not carry latent pages or a dense-then-expert "
+            f"layer stack yet ({cfg.model_type}): serve this family flat on "
+            "one chip (replicas: --dp-ranks)")
+
+
 @jax.named_scope("head")
 def _lm_logits(params: Params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
@@ -799,7 +1082,8 @@ def prefill_layers(
     not scan the pool itself: XLA then slices a layer's slab out, stacks a
     new slab back and copies the whole pool at the loop's edge on every
     step (PERF.md, finding 10)."""
-    inv_freq = rope_frequencies(cfg.head_dim_, cfg.rope_theta, cfg.rope_scaling)
+    inv_freq = rope_frequencies(_rope_dim(cfg), cfg.rope_theta,
+                                cfg.rope_scaling)
     rs = rope_attention_scale(cfg.rope_scaling)
     if wins is None:
         wins = _window_xs(cfg)
@@ -816,9 +1100,9 @@ def prefill_layers(
         )
 
     n_layers = kv.k.shape[0]
-    x, ys = jax.lax.scan(
-        body, x,
-        (layers, jnp.arange(n_layers, dtype=jnp.int32), *wins, *ropes))
+    x, ys = _scan_stacks(
+        body, x, layers,
+        (jnp.arange(n_layers, dtype=jnp.int32), *wins, *ropes))
     valid = jnp.arange(x.shape[1])[None, :] < chunk_lens[:, None]
     kv = KVCache(*write_kv_layers(
         kv.k, kv.v, ys[0], ys[1], page_table, prefix_lens, valid))
@@ -841,7 +1125,8 @@ def decode_layers(
     """Scan a STACK of decoder layers for one decode step (the body of
     `forward_decode`, exposed for pipeline stages).  Returns (x, kv), and
     with `moe_stats` (x, kv, int32 [3])."""
-    inv_freq = rope_frequencies(cfg.head_dim_, cfg.rope_theta, cfg.rope_scaling)
+    inv_freq = rope_frequencies(_rope_dim(cfg), cfg.rope_theta,
+                                cfg.rope_scaling)
     rs = rope_attention_scale(cfg.rope_scaling)
     seq_lens = positions + 1
     if wins is None:
@@ -854,6 +1139,8 @@ def decode_layers(
     from ..ops.paged_attention import _adapt
 
     defer = _adapt(attn_impl, page_table, kv.k.shape[2]) != "pallas"
+    if cfg.is_latent and not defer:
+        raise ValueError("no Pallas kernel reads latent pages")
     ropes = _rope_xs(cfg)
 
     def body(carry, xs):
@@ -867,7 +1154,7 @@ def decode_layers(
         )
         return h, (*kv_out, *st)
 
-    x, ys = jax.lax.scan(body, x, (layers, kv.k, kv.v, *wins, *ropes))
+    x, ys = _scan_stacks(body, x, layers, (kv.k, kv.v, *wins, *ropes))
     k_new, v_new = ys[:2]
     if defer:
         # ONE batched scatter lands every layer's new token ([L, B, kv,
@@ -915,7 +1202,7 @@ def forward_prefill(
             x = jnp.where(extra_mask[..., None],
                           extra_embeds.astype(x.dtype), x)
     x, kv, *st = prefill_layers(
-        params["layers"], cfg, kv, x, positions, page_table, prefix_lens,
+        layer_stacks(params), cfg, kv, x, positions, page_table, prefix_lens,
         chunk_lens, attn_impl,
         rope_pos=mm_positions if cfg.mrope_section else None,
         moe_stats=moe_stats,
@@ -939,7 +1226,8 @@ def forward_embed(
     B, S = tokens.shape
     kv = KVCache.create(cfg, 1, 1, jnp.float32)
     table = jnp.zeros((B, 1), jnp.int32)
-    inv_freq = rope_frequencies(cfg.head_dim_, cfg.rope_theta, cfg.rope_scaling)
+    inv_freq = rope_frequencies(_rope_dim(cfg), cfg.rope_theta,
+                                cfg.rope_scaling)
     positions = jnp.arange(S)[None, :].repeat(B, 0)
     prefix = jnp.zeros((B,), jnp.int32)
     x = params["embed"][tokens]
@@ -955,8 +1243,8 @@ def forward_embed(
         return h, None
 
     layer_ids = jnp.arange(cfg.num_hidden_layers, dtype=jnp.int32)
-    x, _ = jax.lax.scan(body, x,
-                        (params["layers"], layer_ids, *wins, *ropes))
+    x, _ = _scan_stacks(body, x, layer_stacks(params),
+                        (layer_ids, *wins, *ropes))
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     mask = (jnp.arange(S)[None, :] < lens[:, None]).astype(jnp.float32)
     pooled = (x.astype(jnp.float32) * mask[..., None]).sum(1)
@@ -983,7 +1271,7 @@ def forward_decode(
     with jax.named_scope("embed"):
         x = params["embed"][tokens]  # [B, h]
     x, kv, *st = decode_layers(
-        params["layers"], cfg, kv, x, positions, page_table, attn_impl,
+        layer_stacks(params), cfg, kv, x, positions, page_table, attn_impl,
         rope_offset=rope_offset, moe_stats=moe_stats,
     )
     return (_lm_logits(params, cfg, x), kv, *st)
@@ -1025,7 +1313,7 @@ def forward_verify(
         positions = positions + rope_offset[:, None]
     x = params["embed"][tokens]  # [B, S, h]
     x, kv, *st = prefill_layers(
-        params["layers"], cfg, kv, x, positions, page_table, prefix_lens,
+        layer_stacks(params), cfg, kv, x, positions, page_table, prefix_lens,
         chunk_lens, attn_impl, moe_stats=moe_stats,
     )
     return (_lm_logits(params, cfg, x), kv, *st)
@@ -1071,14 +1359,19 @@ def decode_block_scan(
     here, and vice versa; the engine golden/greedy-equality suites
     (gpt-oss, qwen-vl, swa, pooled) run through THIS path on CPU and on
     short-context TPU, which is what catches a drift."""
-    layers = params["layers"]
+    layers = layer_stacks(params)
     L = kv.k.shape[0]
     P, page = kv.k.shape[1], kv.k.shape[2]
     B, W = page_table.shape
-    nh, nkv, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
-                   cfg.head_dim_)
+    latent = cfg.is_latent
+    nh = cfg.num_attention_heads
+    # a latent pool's `k` holds the shared rotary key and its `v` the
+    # latent, each as [tiles, 128] (the gathers, rings and the final
+    # scatter below carry them as any k and v)
+    nkv, hd = kv.k.shape[3], kv.k.shape[4]
+    vdims = kv.v.shape[3:]
     T = n_steps
-    inv_freq = rope_frequencies(cfg.head_dim_, cfg.rope_theta,
+    inv_freq = rope_frequencies(_rope_dim(cfg), cfg.rope_theta,
                                 cfg.rope_scaling)
     rs = rope_attention_scale(cfg.rope_scaling)
     wins, ropes = _window_xs(cfg), _rope_xs(cfg)
@@ -1088,7 +1381,7 @@ def decode_block_scan(
     # 1. one gather of the block's cached context (loop-invariant)
     with jax.named_scope("kv.gather"):
         kg = kv.k[:, page_table].reshape(L, B, W * page, nkv, hd)
-        vg = kv.v[:, page_table].reshape(L, B, W * page, nkv, hd)
+        vg = kv.v[:, page_table].reshape(L, B, W * page, *vdims)
     S = W * page
     spos = jnp.arange(S)[None, :]  # cached slot positions
     len0 = positions  # [B] cached tokens at block start
@@ -1150,6 +1443,23 @@ def decode_block_scan(
         with jax.named_scope("embed"):
             x = params["embed"][tok].astype(dt)
 
+        def layer_latent(h, xs):
+            lp, kg_l, vg_l, rk_l, rv_l = xs[:5]
+            q_abs, q_pe, k_pe, c_kv = _latent_qkv(
+                lp, h[:, None], rp[:, None], cfg, inv_freq, rs)
+            parts = [
+                (rows_of(kg_l), rows_of(vg_l),
+                 (spos < len0[:, None])[:, None]),
+                (rows_of(rk_l), rows_of(rv_l), jnp.broadcast_to(
+                    (jnp.arange(T) < t)[None, None], (B, 1, T))),
+                (k_pe, c_kv, jnp.ones((B, 1, 1), bool))]
+            attn = latent_attention(q_abs, q_pe, parts,
+                                    cfg.latent_softmax_scale)
+            h, = _feed_forward(lp, _latent_out(lp, attn, h[:, None]),
+                               h[:, None], cfg)
+            return h[:, 0], (_stored(k_pe[:, 0], kv.k),
+                             _stored(c_kv[:, 0], kv.v))
+
         def layer(h, xs):
             lp, kg_l, vg_l, rk_l, rv_l = xs[:5]
             window = xs[5] if wins else None
@@ -1173,19 +1483,11 @@ def decode_block_scan(
                 if "bo" in lp:
                     attn_out = attn_out + lp["bo"].astype(h.dtype)
                 h = h + attn_out
-            with jax.named_scope("mlp"):
-                mlp_in = rms_norm(h, lp["mlp_norm"], cfg.rms_norm_eps)
-                if cfg.is_moe:
-                    mlp_out = _moe(
-                        lp, mlp_in[:, None], cfg,
-                        h_in[:, None] if cfg.moe_router_pre_attn else None,
-                    )[:, 0]
-                else:
-                    mlp_out = _mlp(lp, mlp_in[:, None])[:, 0]
-                return h + mlp_out, (k, v)
+            h, = _feed_forward(lp, h[:, None], h_in[:, None], cfg)
+            return h[:, 0], (k, v)
 
-        x, (ks, vs) = jax.lax.scan(layer, x, (layers, kg, vg, rk, rv,
-                                              *wins, *ropes))
+        x, (ks, vs) = _scan_stacks(layer_latent if latent else layer, x,
+                                   layers, (kg, vg, rk, rv, *wins, *ropes))
         # land this step's tokens in the rings (tiny update)
         rk = jax.lax.dynamic_update_slice(
             rk, ks[:, :, None].astype(rk.dtype), (0, 0, t, 0, 0))
@@ -1204,7 +1506,7 @@ def decode_block_scan(
         return (eng, tok_next, pos + 1, t + 1, rk, rv), ys
 
     rk0 = jnp.zeros((L, B, T, nkv, hd), kv.k.dtype)
-    rv0 = jnp.zeros((L, B, T, nkv, hd), kv.v.dtype)
+    rv0 = jnp.zeros((L, B, T, *vdims), kv.v.dtype)
     if masked:
         (eng, tok, pos, _, _, rk, rv), (ys, acts) = jax.lax.scan(
             step, (carry_init, tokens, positions, jnp.int32(0),

@@ -75,6 +75,8 @@ def load_params(path: str, cfg: ModelConfig, dtype=jnp.bfloat16,
         raise RuntimeError("safetensors not available")
     r = reader or _ShardReader(path)
     L = cfg.num_hidden_layers
+    if cfg.model_type == "deepseek_v3":
+        return _load_deepseek_v3(r, cfg, dtype, prefix)
 
     def stack(fmt: str, transpose: bool = True) -> jnp.ndarray:
         return stack_layers(r, L, fmt, transpose=transpose, dtype=dtype)
@@ -222,4 +224,97 @@ def load_params(path: str, cfg: ModelConfig, dtype=jnp.bfloat16,
             params["lm_head"] = jnp.asarray(r.get(prefix + "lm_head.weight").T, dtype)
         else:
             params["lm_head"] = params["embed"].T
+    return params
+
+
+def _load_deepseek_v3(r: "_ShardReader", cfg: ModelConfig, dtype,
+                      prefix: str = ""):
+    """deepseek_v3 tensor names -> `llama.init_params`'s latent layout: the
+    leading dense layers as the stack `dense_layers`, the expert layers as
+    `layers`.  Only the experts HELD here are read, under their global
+    indices (`cfg.first_expert` on).  The prediction module's tensors
+    (`model.layers.{num_hidden_layers}...`) draft tokens and are not read.
+
+    Two rewrites, both exact: the rotary columns of `q_b_proj` and
+    `kv_a_proj_with_mqa` are stored as interleaved pairs (2i, 2i + 1) and
+    are permuted to halves (i, i + pe/2), the layout `ops.apply_rope`
+    rotates (q.k is the same sum in any common order); `kv_b_proj` is cut
+    into each head's `w_uk` [nh, nope, rank] and `w_uv` [nh, rank, vd], the
+    forms the absorbed attention multiplies by."""
+    L, k, nh = cfg.num_hidden_layers, cfg.first_k_dense, cfg.num_attention_heads
+    r_, pe = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+    nope, vd = cfg.qk_nope_head_dim, cfg.v_head_dim
+    halves = np.concatenate([np.arange(0, pe, 2), np.arange(1, pe, 2)])
+
+    def get(i, name):
+        return r.get(prefix + f"model.layers.{i}.{name}")
+
+    def stack(ids, name, fn=lambda w: w.T, dt=dtype):
+        return jnp.asarray(np.stack([fn(get(i, name)) for i in ids]), dt)
+
+    def q_b(w):  # [nh * (nope + pe), qr] -> [qr, nh * (nope + pe)]
+        w = w.reshape(nh, nope + pe, -1)
+        w = np.concatenate([w[:, :nope], w[:, nope:][:, halves]], axis=1)
+        return w.reshape(nh * (nope + pe), -1).T
+
+    def kv_a(w):  # [rank + pe, h] -> [h, rank + pe]
+        return np.concatenate([w[:r_], w[r_:][halves]], axis=0).T
+
+    def attn(ids):
+        a = "self_attn."
+        return {
+            "attn_norm": stack(ids, "input_layernorm.weight", lambda w: w),
+            "mlp_norm": stack(ids, "post_attention_layernorm.weight",
+                              lambda w: w),
+            "wq_a": stack(ids, a + "q_a_proj.weight"),
+            "q_norm": stack(ids, a + "q_a_layernorm.weight", lambda w: w),
+            "wq_b": stack(ids, a + "q_b_proj.weight", q_b),
+            "wkv_a": stack(ids, a + "kv_a_proj_with_mqa.weight", kv_a),
+            "kv_norm": stack(ids, a + "kv_a_layernorm.weight", lambda w: w),
+            "w_uk": stack(ids, a + "kv_b_proj.weight", lambda w: w.reshape(
+                nh, nope + vd, r_)[:, :nope]),
+            "w_uv": stack(ids, a + "kv_b_proj.weight", lambda w: w.reshape(
+                nh, nope + vd, r_)[:, nope:].transpose(0, 2, 1)),
+            "wo": stack(ids, a + "o_proj.weight"),
+        }
+
+    def ffn(ids, at, keys=("w_gate", "w_up", "w_down")):
+        return {key: stack(ids, f"{at}{proj}_proj.weight")
+                for key, proj in zip(keys, ("gate", "up", "down"))}
+
+    params = {
+        "embed": jnp.asarray(r.get(prefix + "model.embed_tokens.weight"),
+                             dtype),
+        "final_norm": jnp.asarray(r.get(prefix + "model.norm.weight"), dtype),
+    }
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = jnp.asarray(
+            r.get(prefix + "lm_head.weight").T, dtype)
+    if not cfg.is_moe:
+        ids = range(L)
+        params["layers"] = {**attn(ids), **ffn(ids, "mlp.")}
+        return params
+    if k:
+        params["dense_layers"] = {**attn(range(k)), **ffn(range(k), "mlp.")}
+    ids = range(k, L)
+    held = range(cfg.first_expert, cfg.first_expert + cfg.num_experts)
+
+    def experts(proj):
+        return jnp.asarray(np.stack([np.stack([
+            get(i, f"mlp.experts.{e}.{proj}_proj.weight").T for e in held])
+            for i in ids]), dtype)
+
+    layers = {
+        **attn(ids),
+        "router": stack(ids, "mlp.gate.weight"),
+        # the choosing bias joins float32 scores: kept float32
+        "router_bias": stack(ids, "mlp.gate.e_score_correction_bias",
+                             lambda w: w.astype(np.float32), jnp.float32),
+        "w_gate": experts("gate"), "w_up": experts("up"),
+        "w_down": experts("down"),
+    }
+    if cfg.n_shared_experts:
+        layers.update(ffn(ids, "mlp.shared_experts.",
+                          ("ws_gate", "ws_up", "ws_down")))
+    params["layers"] = layers
     return params

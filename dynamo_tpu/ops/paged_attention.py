@@ -137,7 +137,7 @@ def _adapt(impl: str, page_table: jax.Array, page_size: int,
 @jax.named_scope("kv.write")
 def write_kv_layers(
     k_pool: jax.Array,  # [L, P, page, n_kv, hd] — every layer's pool
-    v_pool: jax.Array,
+    v_pool: jax.Array,  # its own [n_kv, hd] (a latent pool's planes differ)
     k_new: jax.Array,  # [L, B, S, n_kv, hd] — every layer's new tokens
     v_new: jax.Array,
     page_table: jax.Array,  # [B, max_pages] int32
@@ -158,9 +158,10 @@ def write_kv_layers(
                      0).reshape(-1)  # [B*S] flat slots of a layer's pool
 
     def land(pool, new):
-        flat = pool.reshape(L, P * page_size, n_kv, hd)
+        dims = pool.shape[3:]
+        flat = pool.reshape(L, P * page_size, *dims)
         flat = flat.at[:, slot].set(
-            new.reshape(L, -1, n_kv, hd).astype(pool.dtype), mode="drop")
+            new.reshape(L, -1, *dims).astype(pool.dtype), mode="drop")
         return flat.reshape(pool.shape)
 
     return land(k_pool, k_new), land(v_pool, v_new)

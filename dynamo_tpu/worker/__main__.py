@@ -633,6 +633,8 @@ async def _run(args) -> None:
 
         # what this worker really runs on, beside READY: a launcher that
         # must stay off JAX (one process per chip) reads it from here
+        if hasattr(engine, "cache_report"):  # page kind, bytes a token
+            print("CACHE " + _json.dumps(engine.cache_report()), flush=True)
         print("DEVICE " + _json.dumps(chip.device_identity()), flush=True)
     print(f"READY worker {mdc.name}", flush=True)
     stop = asyncio.Event()

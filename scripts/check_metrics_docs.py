@@ -78,6 +78,7 @@ def representative_engine_stats() -> dict:
     stats["kv_usage_aggregate"] = 0.0           # ShardedPagePool
     # expert models (engine.metrics() when the steps carry moe stats)
     stats["moe_assignments_total"] = 0
+    stats["moe_local_assignments_total"] = 0  # a share of the experts
     stats["moe_experts_hit_total"] = 0
     stats["moe_steps_total"] = 0
     # KVBM tiers (engine.metrics() with a connector attached)

@@ -1,0 +1,94 @@
+"""Whole `prefill_step` programs of one configuration, timed on the chip
+this process holds: for each chunk size, the flat engine's jitted step over
+random weights at the configuration's widths, batch 1, under a fixed table,
+median of N runs after a warm-up.  One JSON line per (chunk, moe_impl).
+
+    python scripts/time_prefill_steps.py benchmark/configs/<config>.json \\
+        [--chunks 512,256,64,16] [--table-pages 256] [--impls auto,dense,ragged]
+
+Single process, no children; a measurement needs a TPU (refuses the CPU)."""
+
+import argparse
+import dataclasses
+import json
+import os
+import statistics
+import sys
+import time
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+
+def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("config")
+    ap.add_argument("--chunks", default="512,256,64,16")
+    ap.add_argument("--table-pages", type=int, default=256)
+    ap.add_argument("--impls", default="auto,dense,ragged")
+    ap.add_argument("--runs", type=int, default=15)
+    ap.add_argument("--rehearse-cpu", action="store_true",
+                    help="walk the control flow on the CPU in float32; "
+                    "its times mean nothing")
+    args = ap.parse_args()
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from dynamo_tpu.engine import EngineConfig
+    from dynamo_tpu.engine.layout import Layout
+    from dynamo_tpu.models import KVCache, ModelConfig, init_params
+    from dynamo_tpu.ops.sampling import SamplingParams
+
+    dev = jax.devices()[0]
+    if dev.platform != "tpu" and not args.rehearse_cpu:
+        raise SystemExit(f"no TPU here ({dev.platform}): nothing to time")
+    dtype = jnp.float32 if args.rehearse_cpu else jnp.bfloat16
+    with open(args.config) as f:
+        run = json.load(f)
+    base = ModelConfig.from_hf_config(run["model"])
+    pages = run["worker_flags"]["--num-pages"]
+    params = jax.jit(lambda: init_params(base, jax.random.PRNGKey(0),
+                                         dtype))()
+    ones = jnp.ones((1,), jnp.float32)
+    samp = SamplingParams(ones, jnp.zeros((1,), jnp.int32), ones, ones, ones)
+    for impl in args.impls.split(","):
+        cfg = dataclasses.replace(base, moe_impl=impl)
+        layout = Layout.resolve(cfg, EngineConfig(
+            num_pages=pages, max_model_len=4096))[0]
+        step = layout.prefill_step(False, greedy=True)
+        kv = KVCache.create(cfg, pages, 16, dtype)
+        table = jnp.arange(1, 1 + args.table_pages, dtype=jnp.int32)[None]
+        for chunk in (int(c) for c in args.chunks.split(",")):
+            prefix = jnp.asarray([args.table_pages * 16 - chunk], jnp.int32)
+            toks = jnp.asarray(np.random.default_rng(0).integers(
+                4, 260, (1, chunk)), jnp.int32)
+            lens = jnp.asarray([chunk], jnp.int32)
+            zeros = jnp.zeros((1,), jnp.int32)
+            times = []
+            try:
+                for i in range(args.runs + 2):
+                    t0 = time.perf_counter()
+                    packed, _, kv = step(
+                        params, kv, toks, table, prefix, lens, samp,
+                        jnp.zeros((1,), jnp.uint32), zeros)
+                    packed.block_until_ready()
+                    if i >= 2:
+                        times.append((time.perf_counter() - t0) * 1e3)
+            except Exception as e:  # noqa: BLE001 — a form that does not fit
+                print(json.dumps({"config": run["name"], "moe_impl": impl,
+                                  "chunk": chunk, "error": str(e)[:300]}),
+                      flush=True)
+                kv = KVCache.create(cfg, pages, 16, dtype)  # was donated
+                continue
+            print(json.dumps({
+                "config": run["name"], "device": dev.device_kind,
+                "moe_impl": impl, "chunk": chunk,
+                "table_tokens": args.table_pages * 16,
+                "prefix": int(prefix[0]), "ms_median": statistics.median(times),
+                "ms_min": min(times), "runs": len(times)}), flush=True)
+        del kv
+
+
+if __name__ == "__main__":
+    main()
