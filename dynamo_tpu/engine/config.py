@@ -6,6 +6,15 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Optional, Sequence
 
+# Rows of a prefill step that short chunks share.  From whole steps timed on
+# a v5e at three configurations (PERF.md findings 17 and 19): up to four
+# rows of the 64-token bucket a step stays bound by reading the weights.
+# Not 3, although `[3,64]` is 1.4-7.7 ms cheaper than `[4,64]` and the
+# benchmark's closed loop of four clients never fills a fourth row: that is
+# the loop's size, not the device's; a second row count is the general cure
+# for padding two rows to four (PERF.md 7 (s)).
+SHARED_PREFILL_ROWS = 4
+
 
 @dataclass
 class EngineConfig:
@@ -17,7 +26,11 @@ class EngineConfig:
     # batching
     max_num_seqs: int = 8  # max concurrent sequences in decode
     max_prefill_tokens: int = 256  # chunked-prefill chunk cap per step
-    prefill_batch_size: int = 1  # sequences prefilled per step
+    # most SHORT chunks that share one prefill step (`short_chunk_bucket`,
+    # `Scheduler._plan_prefill`); 1: every step is one sequence's, which
+    # is what `Layout.resolve` makes of it on every layout but the flat
+    # single-process one
+    prefill_batch_size: int = SHARED_PREFILL_ROWS
     watermark: float = 0.05  # fraction of pages kept free at admission
 
     # buckets (powers of two up to the caps) — static shapes for XLA
@@ -292,6 +305,20 @@ class EngineConfig:
         if not self.decode_block_ladder:
             return (self.decode_steps,)
         return tuple(self.decode_block_ladder)
+
+    @property
+    def short_chunk_bucket(self) -> int:
+        """The ONE chunk bucket of a prefill step that several sequences
+        share: the largest with `prefill_batch_size` rows of it inside half
+        of `max_prefill_tokens`, where a step is still bound by reading the
+        weights and a row more is nearly free (every row is padded to the
+        step's bucket, so a short row never rides beside a long one).
+        0: no step is shared."""
+        rows = self.prefill_batch_size
+        if rows < 2:
+            return 0
+        return max((b for b in self.chunk_buckets
+                    if rows * b <= self.max_prefill_tokens // 2), default=0)
 
     @property
     def cc_horizon_blocks(self) -> int:

@@ -93,9 +93,11 @@ class ForwardPassMetrics:
     kv_pages_cached: int = 0
     kv_pages_free: int = 0
     prefix_evictions_total: int = 0
-    # plain prefill steps dispatched, and those of them dispatched while
+    # plain prefill steps dispatched, the sequences in them (more than one
+    # where short chunks shared a step), and the steps dispatched while
     # the step before was still unfetched (`_run_prefill`)
     prefill_steps_total: int = 0
+    prefill_rows_total: int = 0
     prefill_steps_overlapped_total: int = 0
     # device-resident decode loop: chains run and blocks dispatched by
     # the continuous path (blocks/chains >> decode_chain means the open
@@ -381,7 +383,11 @@ class JaxEngine:
         # calls (the two never run at once)
         self._inflight: Optional[_PrefillStep] = None
         self.prefill_steps_total = 0
+        self.prefill_rows_total = 0
         self.prefill_steps_overlapped_total = 0
+        # (top logprobs, greedy, table width) of every short prefill step
+        # met so far: `_meet_short_prefill`
+        self._short_prefill_met: set = set()
         self._ttft_turn_wait_ms_total = 0.0
         self._evictions_before_reset = 0
 
@@ -679,6 +685,7 @@ class JaxEngine:
             prefix_evictions_total=(self._evictions_before_reset
                                     + self.pool.evictions_total),
             prefill_steps_total=self.prefill_steps_total,
+            prefill_rows_total=self.prefill_rows_total,
             prefill_steps_overlapped_total=(
                 self.prefill_steps_overlapped_total),
             decode_cc_blocks_total=self._cc_blocks_total,
@@ -1209,13 +1216,13 @@ class JaxEngine:
 
     def _prefill_rows(self, items: List[PrefillItem]) -> List[Optional[PrefillItem]]:
         if not self.layout.pooled:
-            # pad to the CONSTANT prefill_batch_size: each distinct row
-            # count is otherwise its own prefill/mixed program (a compile
-            # of seconds, landing mid-measurement on whichever request
-            # first brings a fresh row count); padding rows run a 1-token
-            # chunk into the trash page
+            # one sequence, or the CONSTANT row count of a shared step:
+            # each distinct row count is otherwise its own program (a
+            # compile of seconds, landing mid-measurement on whichever
+            # requests first meet in that number); padding rows run a
+            # 1-token chunk into the trash page
             B = self.layout.pad_batch(
-                max(len(items), self.cfg.prefill_batch_size))
+                1 if len(items) == 1 else self.cfg.prefill_batch_size)
             return list(items) + [None] * (B - len(items))
         by_rank: List[List[PrefillItem]] = [
             [] for _ in range(self.layout.prefill_groups)]
@@ -1304,14 +1311,18 @@ class JaxEngine:
             [s.opts.presence_penalty if s else 0.0 for s in rows],
         )
 
-    def _prefill_arrays(self, item_rows: List[Optional[PrefillItem]]):
+    def _prefill_arrays(self, item_rows: List[Optional[PrefillItem]],
+                        short: bool = False):
         """(tokens [B, chunk_bucket], prefix [B], chunk [B]) for a prefill
         row layout.  Pad rows run a 1-token chunk into the trash page (a
-        fully masked row would softmax over -inf only)."""
+        fully masked row would softmax over -inf only).  A `short` step
+        (`PrefillItem.short`: every row could share it) runs at the ONE
+        short bucket whatever its rows' lengths, so that its programs
+        differ by row count and table width alone."""
         B = len(item_rows)
-        chunk_bucket = bucket_for(
-            max(it.chunk_len for it in item_rows if it), self.cfg.chunk_buckets
-        )
+        chunk_bucket = (self.cfg.short_chunk_bucket if short else bucket_for(
+            max(it.chunk_len for it in item_rows if it),
+            self.cfg.chunk_buckets))
         tokens = np.zeros((B, chunk_bucket), np.int32)
         prefix = np.zeros((B,), np.int32)
         chunk = np.ones((B,), np.int32)
@@ -1504,7 +1515,9 @@ class JaxEngine:
         item_rows = self._prefill_rows(items)
         B = len(item_rows)
         seq_rows = [it.seq if it else None for it in item_rows]
-        tokens, prefix, chunk, chunk_bucket = self._prefill_arrays(item_rows)
+        short = items[0].short  # the plan's: one row or several, all short
+        tokens, prefix, chunk, chunk_bucket = self._prefill_arrays(
+            item_rows, short)
         seqs = [it.seq for it in items]
         if (self.layout.sp > 1 and prefix.any()
                 and not self.cfg.enable_prefix_caching):
@@ -1549,6 +1562,9 @@ class JaxEngine:
         # then rides right behind the prefill, keeping TTFT at prefill
         # latency instead of the whole fused chain's
         self._start_host_copy(packed_d)
+        if short:
+            self._meet_short_prefill(len(items), with_top, greedy,
+                                     table.shape[1])
         # the dispatch is committed: account the computed tokens NOW so a
         # fused decode chain, and the next plan if this step stays in
         # flight, start from current positions (errors reset all state via
@@ -1569,6 +1585,7 @@ class JaxEngine:
             self.scheduler.deferred_free = []
         overlapped = int(self._inflight is not None)
         self.prefill_steps_total += 1
+        self.prefill_rows_total += len(items)
         self.prefill_steps_overlapped_total += overlapped
         return _PrefillStep(
             items=items, item_rows=item_rows, seq_rows=seq_rows, seqs=seqs,
@@ -1584,6 +1601,31 @@ class JaxEngine:
                                    table.shape[1]),
                 overlapped=overlapped),
         )
+
+    def _meet_short_prefill(self, rows: int, with_top: bool, greedy: bool,
+                            width: int) -> None:
+        """Behind the FIRST short step of a (program variant, table width):
+        run the short-step program of the OTHER row count, once, on pad
+        rows alone (1-token chunks into the trash page).  A short step is
+        one sequence's, or the constant row count of a step that several
+        share (`_prefill_rows`), always at the short bucket
+        (`_prefill_arrays`): two programs a variant and table width.  Which
+        of the two a chunk gets is a matter of timing (who else is ready at
+        its plan); that a short chunk of this width was served is not.  So
+        whoever has served one has both, and none is compiled because two
+        requests happened to arrive together, or happened not to."""
+        key = (with_top, greedy, width)
+        if key in self._short_prefill_met:
+            return
+        self._short_prefill_met.add(key)
+        pad = [None] * self.layout.pad_batch(
+            self.cfg.prefill_batch_size if rows == 1 else 1)
+        self._dispatch_prefill(
+            np.zeros((len(pad), self.cfg.short_chunk_bucket), np.int32),
+            np.zeros((len(pad), width), np.int32),
+            np.zeros((len(pad),), np.int32), np.ones((len(pad),), np.int32),
+            self._samp_arrays(pad), *self._seed_arrays(pad), with_top,
+            greedy=greedy)
 
     @affine("step")
     def _prefill_consume(self, step: "_PrefillStep") -> None:

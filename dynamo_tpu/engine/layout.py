@@ -120,6 +120,13 @@ class Layout:
         if multihost is None:
             multihost = jax.process_count() > 1
         meshed = parallel is not None and parallel.world > 1
+        if meshed or multihost:
+            # short prefill chunks that are ready at one plan share a step
+            # (`Scheduler._plan_prefill`) on the flat single-process
+            # engine, where whole steps were timed.  GSPMD, pooled, pp, sp
+            # and multihost layouts keep one sequence a step until someone
+            # times them
+            cfg = dataclasses.replace(cfg, prefill_batch_size=1)
         if multihost and not meshed:
             raise ValueError(
                 "multihost requires a ParallelConfig spanning the global "
@@ -222,13 +229,10 @@ class Layout:
                     "enable_prefix_caching=False (prefix pages are "
                     "owner-shard-local)"
                 )
-            if (cfg.max_prefill_tokens
-                    < cfg.max_model_len * cfg.prefill_batch_size):
+            if cfg.max_prefill_tokens < cfg.max_model_len:
                 raise ValueError(
-                    "sp > 1 requires max_prefill_tokens >= "
-                    "max_model_len * prefill_batch_size — the step "
-                    "budget is shared across co-planned prompts and "
-                    "none may be split into chunks"
+                    "sp > 1 requires max_prefill_tokens >= max_model_len "
+                    "— no prompt may be split into chunks"
                 )
             bad = [b for b in cfg.chunk_buckets if b % sp]
             if bad:

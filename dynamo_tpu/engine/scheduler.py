@@ -162,6 +162,10 @@ class PrefillItem:
     chunk_start: int
     chunk_len: int
     samples: bool  # True when this chunk completes the prompt
+    # True when the chunk may share its step (`Scheduler.shares_step`, in a
+    # plain prefill plan): the step then runs at the short bucket and holds
+    # such rows alone, one or several
+    short: bool = False
 
 
 @dataclass
@@ -666,19 +670,58 @@ class Scheduler:
             return StepPlan("decode", decode=decodable)
         return StepPlan("idle")
 
+    def shares_step(self, seq: Sequence, chunk: int) -> bool:
+        """May this chunk run in a prefill step beside other sequences'?
+        Only a WHOLE remaining prompt no longer than the short bucket
+        (`EngineConfig.short_chunk_bucket`: a cached question's fresh
+        tokens, a document's last remainder): every row of a step is padded
+        to the step's chunk bucket, so a short row beside a long one would
+        be computed at the long one's length.  Vision inputs keep their own
+        step (their embeds are one more program variant)."""
+        return (0 < chunk <= self.cfg.short_chunk_bucket
+                and chunk == seq.prompt_len - seq.num_computed
+                and seq.mm_embeds is None and seq.mm_pixels is None
+                and seq.mm_patches is None)
+
+    @staticmethod
+    def step_variant(seq: Sequence) -> Tuple[bool, bool]:
+        """(top logprobs, greedy): the `prefill_step` program a sequence's
+        row asks for.  Rows share a step only with their own kind, so the
+        short steps' programs are two per variant and table width (one
+        sequence, or the shared row count), both run at the first short
+        step of their kind (`JaxEngine._meet_short_prefill`) and neither
+        found by two rows meeting."""
+        return seq.opts.top_logprobs > 0, seq.opts.temperature <= 0.0
+
     def _plan_prefill(self, budget: int, preempt: bool) -> List[PrefillItem]:
-        """Plan prefill chunks under a token budget (iterate a copy:
-        preemptive page growth may preempt members)."""
+        """Plan one prefill step under a token budget (iterate a copy:
+        preemptive page growth may preempt members).  The first sequence in
+        `running` whose prompt is not done is always in it: first in first
+        out.  Where its chunk is short (`shares_step`), further sequences
+        in `running` order whose chunk is short too, and of the head's
+        `step_variant`, ride in the same step as further rows, up to
+        `prefill_batch_size`: such a step is bound by reading the weights,
+        which it then reads once for all of them.  A row that joins may
+        pass a long chunk that stands before it; nothing passes the head.
+        A row joins without preempting anyone.  A mixed step (`preempt`
+        False) keeps one prefill sequence: its programs are a product of
+        buckets already."""
         items: List[PrefillItem] = []
         for seq in list(self.running):
-            if seq.prefill_done or budget <= 0:
-                continue
-            if len(items) >= self.cfg.prefill_batch_size:
-                break
+            if seq.prefill_done or budget <= 0 or seq.status != "running":
+                continue  # (the head's page growth may have preempted it)
             chunk = min(seq.prompt_len - seq.num_computed, budget)
-            if preempt:
+            short = preempt and self.shares_step(seq, chunk)
+            if items and not (
+                    short and self.step_variant(seq)
+                    == self.step_variant(items[0].seq)):
+                continue
+            if preempt and not items:
                 if not self._ensure_pages(seq, seq.num_computed + chunk):
                     continue  # seq may have been preempted/errored
+            elif preempt:
+                if not self.try_extend_pages(seq, seq.num_computed + chunk):
+                    continue  # pool tight: its own turn will come
             else:
                 need = seq.pages_needed(
                     seq.num_computed + chunk, self.cfg.page_size
@@ -711,9 +754,13 @@ class Scheduler:
                     seq.num_computed,
                     chunk,
                     samples=(seq.num_computed + chunk >= seq.prompt_len),
+                    short=short,
                 )
             )
             budget -= chunk
+            # (a long head is alone; whoever joined a short one is short)
+            if not short or len(items) >= self.cfg.prefill_batch_size:
+                break
         return items
 
     def _plan_decode(self) -> List[Sequence]:

@@ -245,6 +245,16 @@ async def sweep_disagg(pre_engine, dec_engine,
 
 
 def _build_engine(args):
+    """The engine a sweep measures.  `prefill_batch_size` 4 changed meaning
+    in PR 36: it used to co-plan up to four prompts of ANY length under the
+    step budget and pad every prefill step to four rows; it now caps the
+    whole remaining prompts of at most `EngineConfig.short_chunk_bucket`
+    tokens that share one step, and every other step runs one row.  So a
+    sweep's prefill points at `--isl` above that bucket (an eighth of
+    `--isl`: 64 tokens at 512) are taken at one row, not four
+    padded ones: a profile saved before PR 36 reads its prefill TTFT
+    higher than one taken now, and the two are not comparable there; the
+    decode (ITL) tables are untouched."""
     if args.mock:
         from ..mocker import MockEngine, MockEngineArgs
 

@@ -1,15 +1,19 @@
 """Whole `prefill_step` programs of one configuration, timed on the chip
-this process holds: for each chunk size, the flat engine's jitted step over
-random weights at the configuration's widths, batch 1, under a fixed table,
-median of N runs after a warm-up.  One JSON line per (chunk, moe_impl).
+this process holds: for each row count, table width and chunk size, the
+flat engine's jitted step over random weights at the configuration's
+widths, every row a full table of its own pages, median of N runs after a
+warm-up.  One JSON line per (moe_impl, attention, batch, table, chunk).
 
     python scripts/time_prefill_steps.py benchmark/configs/<config>.json \\
-        [--chunks 512,256,64,16] [--table-pages 256] [--impls auto,dense,ragged]
+        [--chunks 512,256,64,16] [--table-pages 256[,128,64]] \\
+        [--batch 1[,2,4]] [--impls auto,dense,ragged] \\
+        [--attention auto[,pallas,xla]]
 
 Single process, no children; a measurement needs a TPU (refuses the CPU)."""
 
 import argparse
 import dataclasses
+import itertools
 import json
 import os
 import statistics
@@ -23,8 +27,15 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("config")
     ap.add_argument("--chunks", default="512,256,64,16")
-    ap.add_argument("--table-pages", type=int, default=256)
+    ap.add_argument("--table-pages", default="256")
+    ap.add_argument("--batch", default="1",
+                    help="row counts: the rows of a step that short chunks "
+                    "share (engine/config.py SHARED_PREFILL_ROWS)")
     ap.add_argument("--impls", default="auto,dense,ragged")
+    ap.add_argument("--attention", default="auto",
+                    help="attention_impl: auto is the engine's own rule by "
+                    "shape (ops/paged_attention.py _adapt), pallas and xla "
+                    "force one form")
     ap.add_argument("--runs", type=int, default=15)
     ap.add_argument("--rehearse-cpu", action="store_true",
                     help="walk the control flow on the CPU in float32; "
@@ -50,43 +61,48 @@ def main():
     pages = run["worker_flags"]["--num-pages"]
     params = jax.jit(lambda: init_params(base, jax.random.PRNGKey(0),
                                          dtype))()
-    ones = jnp.ones((1,), jnp.float32)
-    samp = SamplingParams(ones, jnp.zeros((1,), jnp.int32), ones, ones, ones)
-    for impl in args.impls.split(","):
+    shapes = [(int(b), int(t), int(c)) for b in args.batch.split(",")
+              for t in args.table_pages.split(",")
+              for c in args.chunks.split(",")]
+    for impl, attention in itertools.product(args.impls.split(","),
+                                             args.attention.split(",")):
         cfg = dataclasses.replace(base, moe_impl=impl)
         layout = Layout.resolve(cfg, EngineConfig(
-            num_pages=pages, max_model_len=4096))[0]
+            num_pages=pages, max_model_len=4096,
+            attention_impl=attention))[0]
         step = layout.prefill_step(False, greedy=True)
         kv = KVCache.create(cfg, pages, 16, dtype)
-        table = jnp.arange(1, 1 + args.table_pages, dtype=jnp.int32)[None]
-        for chunk in (int(c) for c in args.chunks.split(",")):
-            prefix = jnp.asarray([args.table_pages * 16 - chunk], jnp.int32)
+        for batch, table_pages, chunk in shapes:
+            ones = jnp.ones((batch,), jnp.float32)
+            zeros = jnp.zeros((batch,), jnp.int32)
+            samp = SamplingParams(ones, zeros, ones, ones, ones)
+            table = jnp.arange(1, 1 + batch * table_pages,
+                               dtype=jnp.int32).reshape(batch, table_pages)
+            prefix = jnp.full((batch,), table_pages * 16 - chunk, jnp.int32)
             toks = jnp.asarray(np.random.default_rng(0).integers(
-                4, 260, (1, chunk)), jnp.int32)
-            lens = jnp.asarray([chunk], jnp.int32)
-            zeros = jnp.zeros((1,), jnp.int32)
+                4, 260, (batch, chunk)), jnp.int32)
+            lens = jnp.full((batch,), chunk, jnp.int32)
+            shape = {"config": run["name"], "moe_impl": impl,
+                     "attention": attention, "batch": batch,
+                     "chunk": chunk, "table_tokens": table_pages * 16}
             times = []
             try:
                 for i in range(args.runs + 2):
                     t0 = time.perf_counter()
                     packed, _, kv = step(
                         params, kv, toks, table, prefix, lens, samp,
-                        jnp.zeros((1,), jnp.uint32), zeros)
+                        jnp.zeros((batch,), jnp.uint32), zeros)
                     packed.block_until_ready()
                     if i >= 2:
                         times.append((time.perf_counter() - t0) * 1e3)
             except Exception as e:  # noqa: BLE001 — a form that does not fit
-                print(json.dumps({"config": run["name"], "moe_impl": impl,
-                                  "chunk": chunk, "error": str(e)[:300]}),
-                      flush=True)
+                print(json.dumps(dict(shape, error=str(e)[:300])), flush=True)
                 kv = KVCache.create(cfg, pages, 16, dtype)  # was donated
                 continue
-            print(json.dumps({
-                "config": run["name"], "device": dev.device_kind,
-                "moe_impl": impl, "chunk": chunk,
-                "table_tokens": args.table_pages * 16,
-                "prefix": int(prefix[0]), "ms_median": statistics.median(times),
-                "ms_min": min(times), "runs": len(times)}), flush=True)
+            print(json.dumps(dict(
+                shape, device=dev.device_kind, prefix=int(prefix[0]),
+                ms_median=statistics.median(times), ms_min=min(times),
+                runs=len(times))), flush=True)
         del kv
 
 

@@ -573,9 +573,15 @@ async def test_share_steps_carry_their_stats_and_counters(cfg, params):
     counts the local assignments beside all of them."""
     engine = engine_of(cfg, params)
     try:
+        import asyncio
+
         await generate(engine, prompt(40, 9), 1)
-        chunks = [e for e in engine.events.dump()["events"]
-                  if e["kind"] == "prefill_chunk"]
+        for _ in range(200):  # a slice is recorded AFTER its token's delivery
+            chunks = [e for e in engine.events.dump()["events"]
+                      if e["kind"] == "prefill_chunk"]
+            if len(chunks) == 3:
+                break
+            await asyncio.sleep(0.01)
         assert len(chunks) == 3
         Lm, E, k = cfg.num_moe_layers, cfg.num_experts, cfg.num_experts_per_tok
         assert (Lm, E, k) == (2, 4, 4)

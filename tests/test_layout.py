@@ -77,7 +77,10 @@ def _rounded(to):
 
 @pytest.mark.parametrize("parallel,over,want", [
     (None, {}, {}),
+    (None, {"prefill_batch_size": 2}, {}),
     (ParallelConfig(dp=2, tp=2), {}, {"decode_batch_buckets": _rounded(2)}),
+    (ParallelConfig(dp=2), {"kv_partition": True, "prefill_batch_size": 2},
+     {"fuse_prefill_decode": False}),
     (ParallelConfig(dp=2, tp=2), {"kv_partition": True},
      {"fuse_prefill_decode": False}),
     (ParallelConfig(pp=2, dp=2), {},
@@ -88,10 +91,15 @@ def _rounded(to):
       "decode_batch_buckets": _rounded(2)}),
     (ParallelConfig(sp=2, dp=2), {},
      {"mixed_prefill_tokens": 0, "decode_batch_buckets": _rounded(2)}),
-], ids=["flat", "dpxtp", "pooled", "pp", "pp-pooled", "sp"])
+], ids=["flat", "flat-rows-given", "dpxtp", "pooled-rows-given", "pooled",
+        "pp", "pp-pooled", "sp"])
 def test_resolve_rewrites_the_config_as_init_did(parallel, over, want):
     given = EngineConfig(**BASE, **over)
     layout, cfg = Layout.resolve(tiny_config(), given, parallel)
+    # short chunks share a step on the flat single-process engine alone;
+    # every other layout keeps one sequence a step whatever it was told
+    if parallel is not None:
+        want = {**want, "prefill_batch_size": 1}
     assert cfg == dataclasses.replace(given, **want)
     assert layout.cfg is cfg
     assert layout.pooled == bool(over.get("kv_partition"))
@@ -119,9 +127,8 @@ MROPE = tiny_config(mrope_section=(2, 3, 3))
      "sp > 1 with kv_partition requires enable_prefix_caching=False "
      "(prefix pages are owner-shard-local)"),
     (tiny_config(), ParallelConfig(sp=2), {"max_prefill_tokens": 32}, {},
-     "sp > 1 requires max_prefill_tokens >= max_model_len * "
-     "prefill_batch_size — the step budget is shared across co-planned "
-     "prompts and none may be split into chunks"),
+     "sp > 1 requires max_prefill_tokens >= max_model_len — no prompt may "
+     "be split into chunks"),
     (tiny_config(), ParallelConfig(sp=4), {"chunk_buckets": [2, 64]}, {},
      "chunk buckets [2] not divisible by sp=4"),
     (tiny_moe_config(moe_impl="capacity"), ParallelConfig(sp=2, tp=2), {},

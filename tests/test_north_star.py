@@ -82,7 +82,7 @@ if rank == 0:
     pre = JaxEngine(
         cfg, params,
         EngineConfig(page_size=8, num_pages=96, max_num_seqs=8,
-                     max_prefill_tokens=8 * 128, prefill_batch_size=2,
+                     max_prefill_tokens=8 * 128,
                      max_model_len=128, enable_prefix_caching=False),
         kv_dtype=jnp.float32, parallel=ParallelConfig(dp=1, sp=2, tp=2),
         multihost=False, devices=jax.local_devices()[:4],

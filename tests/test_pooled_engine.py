@@ -86,7 +86,7 @@ async def test_pooled_dp_sp_ring_prefill_matches_single_device(setup):
     """dp×sp×tp pooled: ring-attention prefill writes each row's KV only
     on its owner shard; decode reads it locally."""
     ref = make_engine(setup, enable_prefix_caching=False,
-                      max_prefill_tokens=8 * 128, prefill_batch_size=2,
+                      max_prefill_tokens=8 * 128,
                       max_model_len=128)
     want = await _run_all(ref, PROMPTS)
     await ref.shutdown()
@@ -94,7 +94,7 @@ async def test_pooled_dp_sp_ring_prefill_matches_single_device(setup):
     eng = make_engine(
         setup, parallel=ParallelConfig(dp=2, sp=2, tp=2),
         kv_partition=True, enable_prefix_caching=False,
-        max_prefill_tokens=8 * 128, prefill_batch_size=2,
+        max_prefill_tokens=8 * 128,
         max_model_len=128,
     )
     assert eng.layout.pooled and eng.layout.pool_ranks == 4

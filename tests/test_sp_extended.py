@@ -16,7 +16,7 @@ from dynamo_tpu.parallel import ParallelConfig
 def ecfg(**over):
     defaults = dict(
         page_size=8, num_pages=96, max_num_seqs=8,
-        max_prefill_tokens=8 * 128, prefill_batch_size=2,
+        max_prefill_tokens=8 * 128,
         max_model_len=128, enable_prefix_caching=False,
     )
     defaults.update(over)
