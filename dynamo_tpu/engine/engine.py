@@ -39,7 +39,8 @@ from .config import EngineConfig, bucket_for
 from .layout import Layout
 from .page_pool import KvEvent, NoPagesError
 from .scheduler import PrefillItem, SamplingOptions, Scheduler, Sequence, StepPlan
-from ..models.llama import moe_stats_width, require_plain_cache
+from ..models.llama import (moe_stats_columns, moe_stats_width,
+                            require_plain_cache)
 from .steps import (
     _unpack_out,
     _unpack_out_cc,
@@ -253,6 +254,9 @@ class JaxEngine:
         self.moe_local_assignments_total = 0  # that chose a HELD expert
         self.moe_experts_hit_total = 0
         self.moe_steps_total = 0
+        # hyper-connections: the largest distance of a step's mixing
+        # matrices from doubly stochastic so far, parts per million
+        self.hc_res_err_ppm_max = 0
         if self.cfg.quantization == "int8":
             from ..models.quantization import quantize_params
 
@@ -715,6 +719,8 @@ class JaxEngine:
                     self.moe_local_assignments_total)
             m.moe_experts_hit_total = self.moe_experts_hit_total
             m.moe_steps_total = self.moe_steps_total
+            if self.model_cfg.hc_mult:
+                m.hc_res_err_ppm_max = self.hc_res_err_ppm_max
         if self.pool.ranks > 1:
             m.kv_usage_aggregate = self.pool.usage()
         if self.tiered is not None:
@@ -1403,8 +1409,8 @@ class JaxEngine:
         returned as the step slice's attributes.  {} for any other step."""
         if not self.layout.carries_moe_stats:
             return {}
-        width = moe_stats_width(self.model_cfg)
-        assigned, hit, load, *local = (int(v) for v in packed[-width:])
+        stats = [int(v) for v in packed[-moe_stats_width(self.model_cfg):]]
+        assigned, hit, load, *local = stats[:moe_stats_columns(self.model_cfg)]
         self.moe_assignments_total += assigned
         self.moe_experts_hit_total += hit
         self.moe_steps_total += 1
@@ -1412,6 +1418,9 @@ class JaxEngine:
         if local:  # a share of each layer's experts is held here
             self.moe_local_assignments_total += local[0]
             attrs["moe_local"] = local[0]
+        if self.model_cfg.hc_mult:  # the stats' last column
+            attrs["hc_res_err_ppm"] = stats[-1]
+            self.hc_res_err_ppm_max = max(self.hc_res_err_ppm_max, stats[-1])
         return attrs
 
     def _attn_of(self, site: str, batch: int, chunk: int,

@@ -176,6 +176,16 @@ class ModelConfig:
     # layer computes its own experts' part of the result.  1 / 0: all held
     moe_ep_size: int = 1
     moe_ep_rank: int = 0
+    # hyper-connections (xing4_0: `hc_mult` > 0): the residual is
+    # `hc_mult` streams [..., hc_mult, hidden], every layer half reads a
+    # learned mix of them and writes back through a `hc_mult` x `hc_mult`
+    # matrix that `hc_sinkhorn_iters` Sinkhorn steps drive towards the
+    # doubly stochastic ones from logits clipped to `hc_res_clamp`
+    # (`ops/hyper_connections.py`).  0: the residual is x + f(x)
+    hc_mult: int = 0
+    hc_sinkhorn_iters: int = 0
+    hc_eps: float = 0.0
+    hc_res_clamp: tuple = (0.0, 0.0)
     # identity
     model_type: str = "llama"
     name: str = "llama"
@@ -204,6 +214,9 @@ class ModelConfig:
             raise ValueError(
                 f"moe_n_group {self.moe_n_group} must divide the router's "
                 f"{self.router_width} outputs")
+        if self.hc_mult and not self.is_latent:
+            raise ValueError("hyper-connections (hc_mult) are implemented "
+                             "around latent attention only (xing4_0)")
 
     @property
     def head_dim_(self) -> int:
@@ -235,6 +248,21 @@ class ModelConfig:
     def num_moe_layers(self) -> int:
         return (self.num_hidden_layers - self.first_k_dense
                 if self.is_moe else 0)
+
+    @property
+    def hc_mixer_width(self) -> int:
+        """Outputs of a layer half's mixer: pre and post weights of the
+        `hc_mult` streams and the matrix between them."""
+        return self.hc_mult * (self.hc_mult + 2)
+
+    @property
+    def residual_report(self) -> dict:
+        """What the layer loops carry (the worker's RESIDUAL line)."""
+        if not self.hc_mult:
+            return {"kind": "add", "streams": 1}
+        return {"kind": "hyper_connections", "streams": self.hc_mult,
+                "sinkhorn_iters": self.hc_sinkhorn_iters,
+                "res_clamp": list(self.hc_res_clamp)}
 
     @property
     def cache_spec(self) -> CacheSpec:
@@ -300,7 +328,14 @@ class ModelConfig:
                + self.n_shared_experts * 3 * h * ffn_inter)
         emb = v * h * (1 if self.tie_word_embeddings else 2)
         k = self.first_k_dense
-        return (l * (attn + 2 * h) + k * dense + (l - k) * mlp + emb + h)
+        # hyper-connections: two mixers a layer (phi, 3 scales, a base) and
+        # the head's (phi, 1 scale, a base)
+        n = self.hc_mult
+        mixers = (l * 2 * (n * h * self.hc_mixer_width + 3
+                           + self.hc_mixer_width)
+                  + n * h * n + 1 + n) if n else 0
+        return (l * (attn + 2 * h) + k * dense + (l - k) * mlp + emb + h
+                + mixers)
 
     @staticmethod
     def from_hf_config(d: dict, name: str = "") -> "ModelConfig":
@@ -310,10 +345,18 @@ class ModelConfig:
             return ModelConfig(**_smallthinker_fields(d, name))
         if d.get("model_type") == "deepseek_v3":
             return ModelConfig(**_deepseek_v3_fields(d, name))
+        if d.get("model_type") == "xing4_0":
+            return ModelConfig(**_xing4_0_fields(d, name))
         if d.get("kv_lora_rank"):
             raise ValueError(
                 f"model_type {d.get('model_type')!r} asks for latent "
-                "attention (kv_lora_rank) and only deepseek_v3's is "
+                "attention (kv_lora_rank) and only deepseek_v3's and "
+                "xing4_0's are implemented: the llama branch would build "
+                "another model")
+        if d.get("hc_mult"):
+            raise ValueError(
+                f"model_type {d.get('model_type')!r} asks for "
+                "hyper-connections (hc_mult) and only xing4_0's are "
                 "implemented: the llama branch would build another model")
         return ModelConfig(
             vocab_size=d["vocab_size"],
@@ -434,7 +477,43 @@ def _smallthinker_fields(d: dict, name: str) -> dict:
     )
 
 
-def _deepseek_v3_fields(d: dict, name: str) -> dict:
+def _xing4_0_fields(d: dict, name: str) -> dict:
+    """Xing4.0's architecture (`model_type` "xing4_0"): deepseek_v3's
+    layers (latent attention, leading dense layers, the `noaux_tc` router
+    with a shared expert) around a residual of `hc_mult` streams mixed by
+    manifold-constrained hyper-connections.  Every hyper-connection key is
+    needed, and what is not implemented is refused by the key that asks
+    for it."""
+    def refuse(key, why):
+        raise ValueError(f"xing4_0: {key} {d.get(key)!r} {why}")
+
+    fields = _deepseek_v3_fields(d, name, family="xing4_0")
+    for key in ("hc_mult", "hc_sinkhorn_iters", "hc_eps",
+                "mhc_h_res_clamp_min", "mhc_h_res_clamp_max"):
+        if d.get(key) is None:
+            refuse(key, "is needed: this family's residual is "
+                   "hyper-connections")
+    if not isinstance(d["hc_mult"], int) or d["hc_mult"] < 2:
+        refuse("hc_mult", "must be a whole number of streams, 2 or more")
+    if not isinstance(d["hc_sinkhorn_iters"], int) or (
+            d["hc_sinkhorn_iters"] < 1):
+        refuse("hc_sinkhorn_iters", "must be 1 or more: the first step is "
+               "the softmax's column normalisation")
+    if not d["hc_eps"] > 0:
+        refuse("hc_eps", "must be positive")
+    lo, hi = d["mhc_h_res_clamp_min"], d["mhc_h_res_clamp_max"]
+    if not lo < hi:
+        refuse("mhc_h_res_clamp_min", f"must lie below "
+               f"mhc_h_res_clamp_max {hi!r}")
+    fields.update(hc_mult=d["hc_mult"],
+                  hc_sinkhorn_iters=d["hc_sinkhorn_iters"],
+                  hc_eps=float(d["hc_eps"]),
+                  hc_res_clamp=(float(lo), float(hi)))
+    return fields
+
+
+def _deepseek_v3_fields(d: dict, name: str, family: str = "deepseek_v3"
+                        ) -> dict:
     """DeepSeek-V3's architecture (config.json of deepseek_v3 checkpoints:
     DeepSeek-V3/R1, GigaChat3, Kimi-K2 ...): latent attention behind a
     query bottleneck, `first_k_dense_replace` dense layers and then expert
@@ -444,7 +523,7 @@ def _deepseek_v3_fields(d: dict, name: str) -> dict:
     `n_routed_experts * ep_size` wide.  What is not implemented is refused
     by the key that asks for it."""
     def refuse(key, why):
-        raise ValueError(f"deepseek_v3: {key} {d.get(key)!r} {why}")
+        raise ValueError(f"{family}: {key} {d.get(key)!r} {why}")
 
     if not d.get("q_lora_rank"):
         refuse("q_lora_rank", "the full-rank query projection is not "
@@ -501,8 +580,8 @@ def _deepseek_v3_fields(d: dict, name: str) -> dict:
         moe_routed_scale=float(d.get("routed_scaling_factor", 1.0)),
         moe_ep_size=d.get("ep_size", 1),
         moe_ep_rank=d.get("ep_rank", 0),
-        model_type="deepseek_v3",
-        name=name or d.get("_name_or_path", "deepseek_v3"),
+        model_type=family,
+        name=name or d.get("_name_or_path", family),
     )
 
 

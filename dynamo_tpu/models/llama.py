@@ -37,6 +37,7 @@ from ..ops.latent_attention import (
     prefill_parts,
     rows_of,
 )
+from ..ops import hyper_connections as hc
 from ..ops import (
     apply_rope,
     decode_attention,
@@ -167,10 +168,25 @@ def _init_params_stacks(cfg: ModelConfig, key, dtype, w) -> Params:
     r, pe = cfg.kv_lora_rank, cfg.qk_rope_head_dim
     nope, vd, qr = cfg.qk_nope_head_dim, cfg.v_head_dim, cfg.q_lora_rank
     fm, E = cfg.moe_intermediate_size, cfg.num_experts
-    ks = iter(jax.random.split(key, 40))
+    ks = iter(jax.random.split(key, 60))
+    streams, M = cfg.hc_mult, cfg.hc_mixer_width
+
+    def mixer(half, *lead, width=M, scales=3):
+        """One hyper-connection mixer, float32: unit-scale logits."""
+        f32 = jnp.float32
+        return {
+            half + "_phi": jax.random.normal(
+                next(ks), (*lead, streams, h, width), f32)
+            * (streams * h) ** -0.5,
+            half + "_scale": jnp.ones((*lead, scales), f32),
+            half + "_base": jax.random.normal(next(ks), (*lead, width), f32),
+        }
 
     def attn(n):
+        mixers = ({**mixer("hc_attn", n), **mixer("hc_mlp", n)}
+                  if streams else {})
         return {
+            **mixers,
             "attn_norm": jnp.ones((n, h), dtype),
             "mlp_norm": jnp.ones((n, h), dtype),
             "wq_a": w(next(ks), n, h, qr),
@@ -195,6 +211,8 @@ def _init_params_stacks(cfg: ModelConfig, key, dtype, w) -> Params:
     }
     if not cfg.tie_word_embeddings:
         params["lm_head"] = w(next(ks), h, cfg.vocab_size)
+    if streams:
+        params.update(mixer("hc_head", width=streams, scales=1))
     if not cfg.is_moe:
         params["layers"] = {**attn(L), **dense(L)}
         return params
@@ -566,8 +584,15 @@ def _moe_ragged(lp: Params, x: jax.Array, cfg: ModelConfig,
 
 
 def moe_stats_width(cfg: ModelConfig) -> int:
-    """int32 columns of `moe_step_stats`: a chip's share of the layer adds
-    a fourth, the assignments that chose an expert held here."""
+    """int32 columns a prefill-path step's stats carry: `moe_step_stats`'s
+    three, a fourth under a chip's share of the layer (the assignments that
+    chose an expert held here), and LAST, under hyper-connections, how far
+    the step's mixing matrices are from doubly stochastic (`_hc_err_ppm`)."""
+    return 3 + (cfg.moe_ep_size > 1) + (cfg.hc_mult > 0)
+
+
+def moe_stats_columns(cfg: ModelConfig) -> int:
+    """Columns of `moe_step_stats` itself among `moe_stats_width`'s."""
     return 4 if cfg.moe_ep_size > 1 else 3
 
 
@@ -595,12 +620,13 @@ def moe_step_stats(selected: jax.Array, num_experts: int,
     return jnp.stack(stats)
 
 
-def merge_moe_stats(per_layer: jax.Array) -> jax.Array:
-    """[..., 3 | 4] stats of several layers (or steps) -> [3 | 4]:
-    assignments and experts touched add up, the largest load is the largest
-    anywhere."""
+def merge_moe_stats(per_layer: jax.Array, hc_err: bool = False) -> jax.Array:
+    """[..., W] stats of several layers (or steps) -> [W]: assignments and
+    experts touched add up, the largest load is the largest anywhere, and so
+    is the trailing hyper-connection column (`hc_err`: the stats have one)."""
     flat = per_layer.reshape(-1, per_layer.shape[-1])
-    return jnp.stack([flat[:, i].max() if i == 2 else flat[:, i].sum()
+    largest = {2, flat.shape[1] - 1} if hc_err else {2}
+    return jnp.stack([flat[:, i].max() if i in largest else flat[:, i].sum()
                       for i in range(flat.shape[1])])
 
 
@@ -659,7 +685,7 @@ def _moe(lp: Params, x: jax.Array, cfg: ModelConfig,
     if not stats:
         return out
     return out, moe_step_stats(_held(routed[1], cfg), cfg.num_experts, valid,
-                               moe_stats_width(cfg))
+                               moe_stats_columns(cfg))
 
 
 def _moe_capacity(lp: Params, x: jax.Array, cfg: ModelConfig,
@@ -770,38 +796,94 @@ def _latent_qkv(lp: Params, x: jax.Array, positions: jax.Array,
     return q_abs, q_pe, k_pe, c_kv
 
 
-def _latent_out(lp: Params, attn: jax.Array, x: jax.Array) -> jax.Array:
-    """x + the output projection of attention over latents: `attn` [B, S,
-    nh, rank] takes each head's `W_uv` first."""
+def _latent_out(lp: Params, attn: jax.Array, dt) -> jax.Array:
+    """The output projection of attention over latents, [B, S, h] in `dt`
+    (the residual is the caller's): `attn` [B, S, nh, rank] takes each
+    head's `W_uv` first."""
     B, S, nh, _ = attn.shape
     with jax.named_scope("attn.kv_up"):
         o = jnp.einsum("bshr,hrv->bshv", attn, lp["w_uv"],
-                       preferred_element_type=jnp.float32).astype(x.dtype)
+                       preferred_element_type=jnp.float32).astype(dt)
     with jax.named_scope("attn.out"):
-        return x + matmul_any(o.reshape(B, S, -1), lp["wo"],
-                              "bsd,dh->bsh").astype(x.dtype)
+        return matmul_any(o.reshape(B, S, -1), lp["wo"],
+                          "bsd,dh->bsh").astype(dt)
 
 
 def _feed_forward(lp: Params, x: jax.Array, x_in: jax.Array,
                   cfg: ModelConfig, chunk_lens=None, moe_stats: bool = False):
-    """x [B, S, h] + its feed-forward: the layer's experts where it has a
-    router, its dense SwiGLU otherwise (a leading layer of a
-    dense-then-expert model).  -> (x, *stats): with `moe_stats` the layer's
-    `moe_step_stats` over the rows below `chunk_lens` [B] (None: every
-    row), zeros from a dense layer."""
+    """The feed-forward half of a layer as a function of its input x [B, S,
+    h] alone (the residual is the caller's, `_residual`): the layer's
+    experts where it has a router, its dense SwiGLU otherwise (a leading
+    layer of a dense-then-expert model).  -> (y, *stats): with `moe_stats`
+    the layer's `moe_step_stats` over the rows below `chunk_lens` [B]
+    (None: every row), zeros from a dense layer."""
     with jax.named_scope("mlp"):
         mlp_in = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
         if "router" not in lp:
-            zeros = (jnp.zeros((moe_stats_width(cfg),), jnp.int32),
+            zeros = (jnp.zeros((moe_stats_columns(cfg),), jnp.int32),
                      ) if moe_stats else ()
-            return (x + _mlp(lp, mlp_in), *zeros)
+            return (_mlp(lp, mlp_in), *zeros)
         router_x = x_in if cfg.moe_router_pre_attn else None
         if not moe_stats:
-            return (x + _moe(lp, mlp_in, cfg, router_x),)
-        valid = (None if chunk_lens is None else
-                 jnp.arange(x.shape[1])[None, :] < chunk_lens[:, None])
-        mlp_out, st = _moe(lp, mlp_in, cfg, router_x, valid, stats=True)
-        return x + mlp_out, st
+            return (_moe(lp, mlp_in, cfg, router_x),)
+        return _moe(lp, mlp_in, cfg, router_x, _valid_rows(x, chunk_lens),
+                    stats=True)
+
+
+def _valid_rows(x: jax.Array, chunk_lens) -> Optional[jax.Array]:
+    """[B, S] bool: the rows of a chunk below `chunk_lens` [B] (None: every
+    row counts and no mask is built)."""
+    return (None if chunk_lens is None else
+            jnp.arange(x.shape[1])[None, :] < chunk_lens[:, None])
+
+
+def _residual(cfg: ModelConfig, lp: Params, half: str, x: jax.Array, f):
+    """One half of a layer around the residual.  `f(u) -> (y, *aux)` is the
+    half as a function of its input alone (its own norm inside).  A plain
+    residual: u = x [B, S, h] and x' = x + y.  Hyper-connections (`half`
+    names the mixer, "hc_attn" | "hc_mlp"): x [B, S, n, h], u the mixer's
+    read of the streams, x' its write back (`ops/hyper_connections.py`).
+    -> (x', aux, err): err [B, S] the mix's distance from doubly stochastic,
+    None from a plain residual."""
+    if not cfg.hc_mult:
+        y, *aux = f(x)
+        return x + y, aux, None
+    m = hc.mix(x, lp[half + "_phi"], lp[half + "_scale"], lp[half + "_base"],
+               iters=cfg.hc_sinkhorn_iters, eps=cfg.hc_eps,
+               clamp=cfg.hc_res_clamp, rms_eps=cfg.rms_norm_eps)
+    y, *aux = f(hc.pre(x, m.pre))
+    return hc.post(x, y, m.post, m.res), aux, m.err
+
+
+def _hc_err_ppm(errs, valid) -> jax.Array:
+    """int32 [1]: the largest |row or column sum of R - 1| over a layer's
+    two mixes and the rows `valid` marks (None: all), parts per million."""
+    err = jnp.maximum(*errs)
+    if valid is not None:
+        err = jnp.where(valid, err, 0.0)
+    return jnp.round(err.max() * 1e6).astype(jnp.int32)[None]
+
+
+def _latent_layer(lp, x, cfg, attend, chunk_lens=None, moe_stats=False):
+    """The ONE latent-attention layer body of every step kind, around
+    either residual.  `attend(u) -> (attn [B, S, nh, rank], k_pe, c_kv)`
+    is the step's attention over its key sets, from the half's input.
+    -> (x', k_pe, c_kv, *stats): the tokens' own cache rows, and with
+    `moe_stats` the layer's stats (`moe_stats_width` columns)."""
+    dt = x.dtype
+
+    def attention(u):
+        attn, k_pe, c_kv = attend(u)
+        return _latent_out(lp, attn, dt), k_pe, c_kv
+
+    h, (k_pe, c_kv), e_attn = _residual(cfg, lp, "hc_attn", x, attention)
+    h, st, e_mlp = _residual(
+        cfg, lp, "hc_mlp", h,
+        lambda u: _feed_forward(lp, u, x, cfg, chunk_lens, moe_stats))
+    if moe_stats and cfg.hc_mult:
+        st = [jnp.concatenate([st[0], _hc_err_ppm(
+            (e_attn, e_mlp), _valid_rows(x, chunk_lens))])]
+    return (h, k_pe, c_kv, *st)
 
 
 def _layer_prefill_latent(lp, kv, layer, x, positions, page_table,
@@ -809,14 +891,16 @@ def _layer_prefill_latent(lp, kv, layer, x, positions, page_table,
                           moe_stats):
     """`_layer_prefill` over latent pages: -> (x, (k_pe, c_kv, *stats)),
     the chunk's own cache rows as the pool stores them [B, S, tiles, 128]."""
-    q_abs, q_pe, k_pe, c_kv = _latent_qkv(lp, x, positions, cfg, inv_freq,
-                                          rope_scale)
-    attn = latent_attention(
-        q_abs, q_pe, prefill_parts(kv.k, kv.v, k_pe, c_kv, page_table,
-                                   prefix_lens, chunk_lens, layer),
-        cfg.latent_softmax_scale)
-    h = _latent_out(lp, attn, x)
-    h, *st = _feed_forward(lp, h, x, cfg, chunk_lens, moe_stats)
+    def attend(u):
+        q_abs, q_pe, k_pe, c_kv = _latent_qkv(lp, u, positions, cfg,
+                                              inv_freq, rope_scale)
+        return latent_attention(
+            q_abs, q_pe, prefill_parts(kv.k, kv.v, k_pe, c_kv, page_table,
+                                       prefix_lens, chunk_lens, layer),
+            cfg.latent_softmax_scale), k_pe, c_kv
+
+    h, k_pe, c_kv, *st = _latent_layer(lp, x, cfg, attend, chunk_lens,
+                                       moe_stats)
     return h, (_stored(k_pe, kv.k), _stored(c_kv, kv.v), *st)
 
 
@@ -886,8 +970,8 @@ def _layer_prefill(
             attn_out = attn_out + lp["bo"].astype(x.dtype)
         x = x + attn_out
 
-    x, *st = _feed_forward(lp, x, x_in, cfg, chunk_lens, moe_stats)
-    return x, (k, v, *st)
+    y, *st = _feed_forward(lp, x, x_in, cfg, chunk_lens, moe_stats)
+    return x + y, (k, v, *st)
 
 
 def _layer_decode(
@@ -912,15 +996,17 @@ def _layer_decode(
     if cfg.is_latent:
         # always the deferred write: attend to the OLD pool's rows plus the
         # token's own row, which the caller lands after the loop
-        q_abs, q_pe, k_pe, c_kv = _latent_qkv(lp, x[:, None], (
-            positions if rope_pos is None else rope_pos)[:, None], cfg,
-            inv_freq, rope_scale)
-        attn = latent_attention(
-            q_abs, q_pe, decode_parts(*kv_layer, k_pe, c_kv, page_table,
-                                      seq_lens),
-            cfg.latent_softmax_scale)
-        h, *st = _feed_forward(lp, _latent_out(lp, attn, x[:, None]),
-                               x[:, None], cfg, None, moe_stats)
+        def attend(u):
+            q_abs, q_pe, k_pe, c_kv = _latent_qkv(lp, u, (
+                positions if rope_pos is None else rope_pos)[:, None], cfg,
+                inv_freq, rope_scale)
+            return latent_attention(
+                q_abs, q_pe, decode_parts(*kv_layer, k_pe, c_kv, page_table,
+                                          seq_lens),
+                cfg.latent_softmax_scale), k_pe, c_kv
+
+        h, k_pe, c_kv, *st = _latent_layer(lp, x[:, None], cfg, attend,
+                                           None, moe_stats)
         return (h[:, 0], (_stored(k_pe[:, 0], kv_layer[0]),
                           _stored(c_kv[:, 0], kv_layer[1])), *st)
     B, h = x.shape
@@ -975,9 +1061,9 @@ def _layer_decode(
             attn_out = attn_out + lp["bo"].astype(x.dtype)
         x = x + attn_out
 
-    x, *st = _feed_forward(lp, x[:, None], x_in[:, None], cfg, None,
+    y, *st = _feed_forward(lp, x[:, None], x_in[:, None], cfg, None,
                            moe_stats)
-    return (x[:, 0], kv_out, *st)
+    return (x + y[:, 0], kv_out, *st)
 
 
 def _rope_dim(cfg: ModelConfig) -> int:
@@ -1030,20 +1116,39 @@ def require_flat_layer_scan(cfg: ModelConfig, layout: str) -> None:
 
 
 def require_plain_cache(cfg: ModelConfig, what: str) -> None:
-    """Latent pages (one array a layer and no `v`) and the dense-then-expert
-    stacks are carried by the flat engine's own programs only: every path
-    with its own copy of the layer body, the pool's spec or the page blobs
-    refuses such a family at start-up."""
+    """Latent pages (one array a layer and no `v`), the dense-then-expert
+    stacks and a residual of several streams (hyper-connections, which come
+    with latent attention only: `ModelConfig.__post_init__`) are carried by
+    the flat engine's own programs only: every path with its own copy of
+    the layer body, the pool's spec or the page blobs refuses such a family
+    at start-up."""
     if cfg.is_latent or cfg.first_k_dense:
         raise ValueError(
-            f"{what} does not carry latent pages or a dense-then-expert "
-            f"layer stack yet ({cfg.model_type}): serve this family flat on "
-            "one chip (replicas: --dp-ranks)")
+            f"{what} does not carry latent pages, a dense-then-expert "
+            f"layer stack or a multi-stream residual yet ({cfg.model_type}): "
+            "serve this family flat on one chip (replicas: --dp-ranks)")
+
+
+def _streams(cfg: ModelConfig, x: jax.Array) -> jax.Array:
+    """Embedded tokens [..., h] as the residual the layer loops carry:
+    themselves, or under hyper-connections every stream alike [..., n, h]."""
+    return hc.widen(x, cfg.hc_mult) if cfg.hc_mult else x
+
+
+def _final_norm(params: Params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
+    """The final norm of the residual after the last layer [..., h]; under
+    hyper-connections [..., n, h] the head's reduction of the streams
+    comes first."""
+    if cfg.hc_mult:
+        x = hc.head_reduce(
+            x, params["hc_head_phi"], params["hc_head_scale"],
+            params["hc_head_base"], eps=cfg.hc_eps, rms_eps=cfg.rms_norm_eps)
+    return rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
 
 
 @jax.named_scope("head")
 def _lm_logits(params: Params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
-    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+    x = _final_norm(params, cfg, x)
     head = params.get("lm_head")  # quantization adds one even when tied
     if head is None:
         if not cfg.tie_word_embeddings:
@@ -1106,7 +1211,8 @@ def prefill_layers(
     valid = jnp.arange(x.shape[1])[None, :] < chunk_lens[:, None]
     kv = KVCache(*write_kv_layers(
         kv.k, kv.v, ys[0], ys[1], page_table, prefix_lens, valid))
-    return (x, kv, merge_moe_stats(ys[2])) if moe_stats else (x, kv)
+    return ((x, kv, merge_moe_stats(ys[2], cfg.hc_mult > 0)) if moe_stats
+            else (x, kv))
 
 
 def decode_layers(
@@ -1164,7 +1270,8 @@ def decode_layers(
             kv.k, kv.v, k_new[:, :, None], v_new[:, :, None], page_table,
             positions, jnp.ones((positions.shape[0], 1), bool))
     kv = KVCache(k_new, v_new)
-    return (x, kv, merge_moe_stats(ys[2])) if moe_stats else (x, kv)
+    return ((x, kv, merge_moe_stats(ys[2], cfg.hc_mult > 0)) if moe_stats
+            else (x, kv))
 
 
 def forward_prefill(
@@ -1201,6 +1308,7 @@ def forward_prefill(
         if extra_embeds is not None:
             x = jnp.where(extra_mask[..., None],
                           extra_embeds.astype(x.dtype), x)
+        x = _streams(cfg, x)
     x, kv, *st = prefill_layers(
         layer_stacks(params), cfg, kv, x, positions, page_table, prefix_lens,
         chunk_lens, attn_impl,
@@ -1208,7 +1316,8 @@ def forward_prefill(
         moe_stats=moe_stats,
     )
     last = jnp.maximum(chunk_lens - 1, 0)
-    x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]  # [B, h]
+    at = last[:, None, None, None] if cfg.hc_mult else last[:, None, None]
+    x_last = jnp.take_along_axis(x, at, axis=1)[:, 0]  # [B, h] | [B, n, h]
     return (_lm_logits(params, cfg, x_last), kv, *st)
 
 
@@ -1230,7 +1339,7 @@ def forward_embed(
                                 cfg.rope_scaling)
     positions = jnp.arange(S)[None, :].repeat(B, 0)
     prefix = jnp.zeros((B,), jnp.int32)
-    x = params["embed"][tokens]
+    x = _streams(cfg, params["embed"][tokens])
     wins, ropes = _window_xs(cfg), _rope_xs(cfg)
 
     def body(h, xs):
@@ -1245,7 +1354,7 @@ def forward_embed(
     layer_ids = jnp.arange(cfg.num_hidden_layers, dtype=jnp.int32)
     x, _ = _scan_stacks(body, x, layer_stacks(params),
                         (layer_ids, *wins, *ropes))
-    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+    x = _final_norm(params, cfg, x)
     mask = (jnp.arange(S)[None, :] < lens[:, None]).astype(jnp.float32)
     pooled = (x.astype(jnp.float32) * mask[..., None]).sum(1)
     pooled = pooled / jnp.maximum(lens[:, None].astype(jnp.float32), 1.0)
@@ -1269,7 +1378,7 @@ def forward_decode(
 ):
     """One decode step for the whole batch; returns (logits [B, V], kv)."""
     with jax.named_scope("embed"):
-        x = params["embed"][tokens]  # [B, h]
+        x = _streams(cfg, params["embed"][tokens])  # [B, h]
     x, kv, *st = decode_layers(
         layer_stacks(params), cfg, kv, x, positions, page_table, attn_impl,
         rope_offset=rope_offset, moe_stats=moe_stats,
@@ -1311,7 +1420,7 @@ def forward_verify(
         # addressed by prefix/chunk), so the mrope delta rides here —
         # exactly `_layer_decode`'s rope_pos = slot + delta
         positions = positions + rope_offset[:, None]
-    x = params["embed"][tokens]  # [B, S, h]
+    x = _streams(cfg, params["embed"][tokens])  # [B, S, h]
     x, kv, *st = prefill_layers(
         layer_stacks(params), cfg, kv, x, positions, page_table, prefix_lens,
         chunk_lens, attn_impl, moe_stats=moe_stats,
@@ -1441,22 +1550,24 @@ def decode_block_scan(
         safe_pos = jnp.where(ok, pos, 0)
         rp = safe_pos if rope_offset is None else safe_pos + rope_offset
         with jax.named_scope("embed"):
-            x = params["embed"][tok].astype(dt)
+            x = _streams(cfg, params["embed"][tok].astype(dt))
 
         def layer_latent(h, xs):
             lp, kg_l, vg_l, rk_l, rv_l = xs[:5]
-            q_abs, q_pe, k_pe, c_kv = _latent_qkv(
-                lp, h[:, None], rp[:, None], cfg, inv_freq, rs)
-            parts = [
-                (rows_of(kg_l), rows_of(vg_l),
-                 (spos < len0[:, None])[:, None]),
-                (rows_of(rk_l), rows_of(rv_l), jnp.broadcast_to(
-                    (jnp.arange(T) < t)[None, None], (B, 1, T))),
-                (k_pe, c_kv, jnp.ones((B, 1, 1), bool))]
-            attn = latent_attention(q_abs, q_pe, parts,
-                                    cfg.latent_softmax_scale)
-            h, = _feed_forward(lp, _latent_out(lp, attn, h[:, None]),
-                               h[:, None], cfg)
+
+            def attend(u):
+                q_abs, q_pe, k_pe, c_kv = _latent_qkv(
+                    lp, u, rp[:, None], cfg, inv_freq, rs)
+                parts = [
+                    (rows_of(kg_l), rows_of(vg_l),
+                     (spos < len0[:, None])[:, None]),
+                    (rows_of(rk_l), rows_of(rv_l), jnp.broadcast_to(
+                        (jnp.arange(T) < t)[None, None], (B, 1, T))),
+                    (k_pe, c_kv, jnp.ones((B, 1, 1), bool))]
+                return latent_attention(
+                    q_abs, q_pe, parts, cfg.latent_softmax_scale), k_pe, c_kv
+
+            h, k_pe, c_kv = _latent_layer(lp, h[:, None], cfg, attend)
             return h[:, 0], (_stored(k_pe[:, 0], kv.k),
                              _stored(c_kv[:, 0], kv.v))
 
@@ -1483,8 +1594,8 @@ def decode_block_scan(
                 if "bo" in lp:
                     attn_out = attn_out + lp["bo"].astype(h.dtype)
                 h = h + attn_out
-            h, = _feed_forward(lp, h[:, None], h_in[:, None], cfg)
-            return h[:, 0], (k, v)
+            y, = _feed_forward(lp, h[:, None], h_in[:, None], cfg)
+            return h + y[:, 0], (k, v)
 
         x, (ks, vs) = _scan_stacks(layer_latent if latent else layer, x,
                                    layers, (kg, vg, rk, rv, *wins, *ropes))

@@ -75,7 +75,7 @@ def load_params(path: str, cfg: ModelConfig, dtype=jnp.bfloat16,
         raise RuntimeError("safetensors not available")
     r = reader or _ShardReader(path)
     L = cfg.num_hidden_layers
-    if cfg.model_type == "deepseek_v3":
+    if cfg.is_latent:  # deepseek_v3, and xing4_0 around its layers
         return _load_deepseek_v3(r, cfg, dtype, prefix)
 
     def stack(fmt: str, transpose: bool = True) -> jnp.ndarray:
@@ -229,7 +229,8 @@ def load_params(path: str, cfg: ModelConfig, dtype=jnp.bfloat16,
 
 def _load_deepseek_v3(r: "_ShardReader", cfg: ModelConfig, dtype,
                       prefix: str = ""):
-    """deepseek_v3 tensor names -> `llama.init_params`'s latent layout: the
+    """deepseek_v3 tensor names (and xing4_0's, which adds its mixers'
+    to them) -> `llama.init_params`'s latent layout: the
     leading dense layers as the stack `dense_layers`, the expert layers as
     `layers`.  Only the experts HELD here are read, under their global
     indices (`cfg.first_expert` on).  The prediction module's tensors
@@ -260,9 +261,33 @@ def _load_deepseek_v3(r: "_ShardReader", cfg: ModelConfig, dtype,
     def kv_a(w):  # [rank + pe, h] -> [h, rank + pe]
         return np.concatenate([w[:r_], w[r_:][halves]], axis=0).T
 
+    def mixer(key, read):
+        """One hyper-connection mixer (xing4_0) under the keys `key`_phi,
+        _scale, _base, float32 whatever the file's dtype.  `read(part, to)`
+        reads its tensor `part` through `to`: `fn` [M, n * hidden] (the
+        streams' concatenation in, as a Linear stores it) -> phi [n,
+        hidden, M]; `scale` and `base` as they are."""
+        def f32(w):
+            return np.asarray(w, np.float32)
+
+        return {key + "_phi": read("fn", lambda w: f32(w).T.reshape(
+                    cfg.hc_mult, cfg.hidden_size, -1)),
+                key + "_scale": read("scale", f32),
+                key + "_base": read("base", f32)}
+
+    def mixers(ids):
+        """The two mixers a layer, `hc_attn` and `hc_ffn`."""
+        out = {}
+        if cfg.hc_mult:
+            for key, half in (("hc_attn", "hc_attn."), ("hc_mlp", "hc_ffn.")):
+                out.update(mixer(key, lambda part, to: stack(
+                    ids, half + part, to, jnp.float32)))
+        return out
+
     def attn(ids):
         a = "self_attn."
         return {
+            **mixers(ids),
             "attn_norm": stack(ids, "input_layernorm.weight", lambda w: w),
             "mlp_norm": stack(ids, "post_attention_layernorm.weight",
                               lambda w: w),
@@ -290,6 +315,9 @@ def _load_deepseek_v3(r: "_ShardReader", cfg: ModelConfig, dtype,
     if not cfg.tie_word_embeddings:
         params["lm_head"] = jnp.asarray(
             r.get(prefix + "lm_head.weight").T, dtype)
+    if cfg.hc_mult:  # the head's reduction of the streams
+        params.update(mixer("hc_head", lambda part, to: jnp.asarray(
+            to(r.get(prefix + "model.hc_head." + part)))))
     if not cfg.is_moe:
         ids = range(L)
         params["layers"] = {**attn(ids), **ffn(ids, "mlp.")}

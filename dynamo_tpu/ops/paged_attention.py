@@ -132,6 +132,23 @@ def _adapt(impl: str, page_table: jax.Array, page_size: int,
     return choice
 
 
+_SUBLANES = 8
+
+
+def _layers_would_move_to_sublanes(n_layers: int, plane: tuple) -> bool:
+    """Whether the TPU compiler re-lays a pool [L, slots, *plane] out around
+    `write_kv_layers`' scatter over the layer axis: where a token's plane
+    has fewer rows than a tile has sublanes and the LAYERS fill them (a
+    multiple of 8), it moves the layer axis under the lanes, and copies the
+    whole pool there before the scatter and back after it, in every step
+    (AOT compile for a described v5e, PR 37: a latent pool of 8 layers,
+    planes [2, 128] and [4, 128], 2.4 GB copied twice a prefill step; at 7
+    layers, and at the 12 and 14 of the per-head pools, it leaves the pool
+    where it is).  By shapes alone, so every other pool traces the scatter
+    it traced."""
+    return n_layers % _SUBLANES == 0 and plane[0] < _SUBLANES
+
+
 # Scope names (jax.named_scope) are what a profiler trace's device ops are
 # found by: docs/observability.md lists them.
 @jax.named_scope("kv.write")
@@ -159,6 +176,15 @@ def write_kv_layers(
 
     def land(pool, new):
         dims = pool.shape[3:]
+        if _layers_would_move_to_sublanes(L, dims):
+            # the same slots as rows of ONE axis, a layer after the other:
+            # no layer axis is left for the compiler to re-lay out
+            rows = pool.reshape(L * P * page_size, *dims)
+            at = (jnp.arange(L)[:, None] * (P * page_size)
+                  + slot[None, :]).reshape(-1)
+            rows = rows.at[at].set(
+                new.reshape(-1, *dims).astype(pool.dtype), mode="drop")
+            return rows.reshape(pool.shape)
         flat = pool.reshape(L, P * page_size, *dims)
         flat = flat.at[:, slot].set(
             new.reshape(L, -1, *dims).astype(pool.dtype), mode="drop")

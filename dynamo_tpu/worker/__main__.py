@@ -635,6 +635,10 @@ async def _run(args) -> None:
         # must stay off JAX (one process per chip) reads it from here
         if hasattr(engine, "cache_report"):  # page kind, bytes a token
             print("CACHE " + _json.dumps(engine.cache_report()), flush=True)
+            # what the layer loops carry: x + f(x), or the streams of
+            # hyper-connections
+            print("RESIDUAL " + _json.dumps(
+                engine.model_cfg.residual_report), flush=True)
         print("DEVICE " + _json.dumps(chip.device_identity()), flush=True)
     print(f"READY worker {mdc.name}", flush=True)
     stop = asyncio.Event()

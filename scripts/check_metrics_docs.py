@@ -81,6 +81,7 @@ def representative_engine_stats() -> dict:
     stats["moe_local_assignments_total"] = 0  # a share of the experts
     stats["moe_experts_hit_total"] = 0
     stats["moe_steps_total"] = 0
+    stats["hc_res_err_ppm_max"] = 0  # a residual of several streams
     # KVBM tiers (engine.metrics() with a connector attached)
     stats["kvbm_host_blocks"] = 0
     stats["kvbm_pending_offloads"] = 0

@@ -677,17 +677,23 @@ def lowering_digest(model_cfg):
     return hashlib.sha256(text.encode()).hexdigest()
 
 
-@pytest.mark.parametrize("family", ["qwen2", "smallthinker"])
+@pytest.mark.parametrize("family", ["qwen2", "smallthinker", "deepseek_v3"])
 def test_a_model_without_leading_dense_layers_traces_what_it_traced(family):
     """The prefill step of a tiny Qwen2 (dense, biased) and of a tiny
     SmallThinker (experts, pre-attention router, rotary switch, windows)
     lowers to the text it lowered to before the layer loop learned of
-    stacks, latent pages and shares (digests taken on the parent commit by
-    this same function; regenerate with the container's jax if it moves)."""
+    stacks, latent pages and shares, and that of the tiny deepseek_v3 (latent
+    pages, a dense layer, a share, its stats) to the text it lowered to
+    before the residual add left the layer's halves (PR 37).  Digests taken
+    on the parent commit by this same function; regenerate with the
+    container's jax if it moves."""
     from test_smallthinker import TINY as ST
 
-    model_cfg = (tiny_config(attention_bias=True, model_type="qwen2")
-                 if family == "qwen2" else ModelConfig.from_hf_config(ST))
+    model_cfg = {"qwen2": lambda: tiny_config(attention_bias=True,
+                                              model_type="qwen2"),
+                 "smallthinker": lambda: ModelConfig.from_hf_config(ST),
+                 "deepseek_v3": lambda: ModelConfig.from_hf_config(
+                     TINY, name="tiny-deepseek-v3")}[family]()
     with open(GOLDEN) as f:
         golden = json.load(f)
     assert lowering_digest(model_cfg) == golden[family]
