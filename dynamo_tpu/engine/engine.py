@@ -24,7 +24,7 @@ import logging
 import time
 from dataclasses import dataclass, field
 from functools import partial
-from typing import Any, AsyncIterator, Callable, Dict, List, Optional
+from typing import Any, AsyncIterator, Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -95,11 +95,13 @@ class ForwardPassMetrics:
     kv_pages_free: int = 0
     prefix_evictions_total: int = 0
     # plain prefill steps dispatched, the sequences in them (more than one
-    # where short chunks shared a step), and the steps dispatched while
-    # the step before was still unfetched (`_run_prefill`)
+    # where short chunks shared a step), the steps dispatched while the
+    # step before was still unfetched (`_run_prefill`), and the steps in
+    # which no row sampled, which skipped the output head (`_sampling_rows`)
     prefill_steps_total: int = 0
     prefill_rows_total: int = 0
     prefill_steps_overlapped_total: int = 0
+    prefill_steps_headless_total: int = 0
     # device-resident decode loop: chains run and blocks dispatched by
     # the continuous path (blocks/chains >> decode_chain means the open
     # horizon is actually engaging)
@@ -389,6 +391,7 @@ class JaxEngine:
         self.prefill_steps_total = 0
         self.prefill_rows_total = 0
         self.prefill_steps_overlapped_total = 0
+        self.prefill_steps_headless_total = 0
         # (top logprobs, greedy, table width) of every short prefill step
         # met so far: `_meet_short_prefill`
         self._short_prefill_met: set = set()
@@ -692,6 +695,7 @@ class JaxEngine:
             prefill_rows_total=self.prefill_rows_total,
             prefill_steps_overlapped_total=(
                 self.prefill_steps_overlapped_total),
+            prefill_steps_headless_total=self.prefill_steps_headless_total,
             decode_cc_blocks_total=self._cc_blocks_total,
             decode_cc_chains_total=self._cc_chains_total,
             decode_cc_fallout_total=dict(self._cc_fallout_by_reason),
@@ -1484,6 +1488,18 @@ class JaxEngine:
             phases["overlap_us"] = (t_fetch - t_sent) // 1000
         return phases
 
+    def _sampling_rows(self, item_rows) -> Tuple[Optional[np.ndarray], int]:
+        """The `samples` operand of a prefill step (or of a mixed step's
+        prefill side) and the step's `head` attribute: [B] bool, the rows
+        whose chunk ends a prompt (a pad row's does not), and 1 if the
+        step runs the output head, which it does where any row samples
+        (`models.llama.forward_prefill`).  (None, 1) on a layout whose
+        program runs the head on every step (`Layout.heads_by_rows`)."""
+        if not self.layout.heads_by_rows:
+            return None, 1
+        rows = np.array([it is not None and it.samples for it in item_rows])
+        return rows, int(rows.any())
+
     @affine("step")
     def _run_prefill(self, items: List[PrefillItem]) -> None:
         """One prefill step in two halves: `_prefill_dispatch` builds the
@@ -1550,6 +1566,7 @@ class JaxEngine:
                 if it is not None:
                     owner[i] = self.layout.prefill_slot(it.seq.kv_rank)[1]
         greedy = self._is_greedy(seq_rows)
+        samples, head = self._sampling_rows(item_rows)
         if self.layout.lockstep:
             self._lockstep_send({
                 "kind": "prefill", "with_top": with_top,
@@ -1564,7 +1581,7 @@ class JaxEngine:
         t_call = self.events.now()
         packed_d, tok_d = self._dispatch_prefill(
             tokens, table, prefix, chunk, samp, seeds, counters, with_top,
-            mm=mm, owner=owner, greedy=greedy,
+            mm=mm, owner=owner, greedy=greedy, samples=samples,
         )
         # start the host copy of the prefill result BEFORE the fused
         # decode dispatches enqueue: on a FIFO-ish transfer path the copy
@@ -1596,6 +1613,7 @@ class JaxEngine:
         self.prefill_steps_total += 1
         self.prefill_rows_total += len(items)
         self.prefill_steps_overlapped_total += overlapped
+        self.prefill_steps_headless_total += 1 - head
         return _PrefillStep(
             items=items, item_rows=item_rows, seq_rows=seq_rows, seqs=seqs,
             with_top=with_top, packed_d=packed_d, fused=fused,
@@ -1608,7 +1626,7 @@ class JaxEngine:
                 bucket=chunk_bucket,
                 attn=self._attn_of("prefill_attention", B, chunk_bucket,
                                    table.shape[1]),
-                overlapped=overlapped),
+                overlapped=overlapped, head=head),
         )
 
     def _meet_short_prefill(self, rows: int, with_top: bool, greedy: bool,
@@ -1634,7 +1652,7 @@ class JaxEngine:
             np.zeros((len(pad), width), np.int32),
             np.zeros((len(pad),), np.int32), np.ones((len(pad),), np.int32),
             self._samp_arrays(pad), *self._seed_arrays(pad), with_top,
-            greedy=greedy)
+            greedy=greedy, samples=self._sampling_rows(pad)[0])
 
     @affine("step")
     def _prefill_consume(self, step: "_PrefillStep") -> None:
@@ -1910,6 +1928,7 @@ class JaxEngine:
         counts = self._counts_array(d_rows) if penalized else None
         d_rope = self._rope_array(d_rows)
         greedy_m = self._is_greedy(pseq_rows) and self._is_greedy(d_rows)
+        p_samples, head = self._sampling_rows(item_rows)
         # a mixed plan means prompts are pending by construction, so the
         # ladder policy picks the shortest rung — the prefill side's NEXT
         # chunk (or the next waiting prompt) rides the following dispatch
@@ -1936,7 +1955,7 @@ class JaxEngine:
             p_tokens, p_table, p_prefix, p_chunk, p_samp, p_seeds, p_ctr,
             d_tokens, d_pos, d_ctr, counts, d_table, d_samp, d_seeds,
             penalized, with_top, rope_off=d_rope, greedy=greedy_m,
-            n_steps=T,
+            n_steps=T, p_samples=p_samples,
         )
         # dispatch committed: account prefill chunks now (consume order
         # below matches the device program: prefill first, then decode)
@@ -1974,7 +1993,7 @@ class JaxEngine:
             bucket=chunk_bucket,
             attn=self._attn_of("prefill_attention", Bp, chunk_bucket,
                                p_table.shape[1]),
-            **moe_attrs,
+            head=head, **moe_attrs,
             **self._step_phases(pseqs + dseqs, t0_ev, t_call, t_fetch,
                                 t_got),
         )
@@ -1982,9 +2001,11 @@ class JaxEngine:
     def _dispatch_mixed(self, p_tokens, p_table, p_prefix, p_chunk, p_samp,
                         p_seeds, p_ctr, d_tokens, d_pos, d_ctr, d_counts,
                         d_table, d_samp, d_seeds, penalized, with_top,
-                        rope_off=None, greedy=False, n_steps=None):
+                        rope_off=None, greedy=False, n_steps=None,
+                        p_samples=None):
         """Issue the jitted mixed step (identical on leader and followers);
-        returns the two packed device outputs."""
+        returns the two packed device outputs.  `p_samples` is the prefill
+        side's `_sampling_rows`; None: every row samples."""
         step = self.layout.mixed_step(penalized, with_top, greedy, n_steps)
         put = self.layout.put_rows
         cts_d = put(d_counts) if penalized else None
@@ -1993,6 +2014,7 @@ class JaxEngine:
             self.params, self.kv,
             put(p_tokens), put(p_table), put(p_prefix), put(p_chunk),
             self.layout.put_samp(p_samp), put(p_seeds), put(p_ctr),
+            None if p_samples is None else self.layout.put(p_samples),
             put(d_tokens), put(d_pos), put(d_ctr), cts_d, put(d_table),
             self.layout.put_samp(d_samp), put(d_seeds),
             *rope,
@@ -2248,12 +2270,13 @@ class JaxEngine:
 
     def _dispatch_prefill(self, tokens, table, prefix, chunk, samp, seeds,
                           counters, with_top, mm=(), owner=None,
-                          greedy=False):
+                          greedy=False, samples=None):
         """Issue the jitted prefill (identical on leader and followers).
         Returns (packed_d, tok_d): the packed host-fetchable result and
         the sampled tokens as a device int32 carry.  `owner` rides along
         only for partitioned-pool sp prefill (rows shard over dp; the
-        owner array names each row's sp slot)."""
+        owner array names each row's sp slot).  `samples` is
+        `_sampling_rows`'s; None: every row samples."""
         put = partial(self.layout.put_rows, prefill=True)
         packed_d, tok_d, kv = self.layout.prefill_step(
             with_top, bool(mm), greedy)(
@@ -2266,6 +2289,7 @@ class JaxEngine:
             self.layout.put_samp(samp, prefill=True),
             put(seeds),
             put(counters),
+            None if samples is None else self.layout.put(samples),
             *map(put, mm),
             *self.layout.prefill_tail(table, prefix, owner),
         )

@@ -79,6 +79,14 @@ class Layout:
         # nothing; a recover must also find leader and followers agreeing on
         # which steps ran
         self.holds_step_in_flight = not multihost
+        # does a prefill step (and a mixed step's prefill side) take which
+        # of its rows sample as an operand, and run the output head only
+        # where one does (`steps.prefill_body`)?  Where the forward is
+        # `forward_prefill` and the engine's own arrays reach the program:
+        # the pp and sp forwards keep an unconditional head, and a lockstep
+        # follower replays a step from the plan's host arrays, which do not
+        # carry the rows
+        self.heads_by_rows = self.pp == 1 and self.sp == 1 and not multihost
         # kv_partition: pool pages sharded over the mesh's (dp, sp)
         # shards — capacity scales with the mesh (engine.page_pool
         # ShardedPagePool); steps run manual-over-(dp,sp) via shard_map
@@ -656,7 +664,7 @@ class Layout:
                     mm_specs += (self._rows(None, None),)
             name += "_pooled"
             manual = ((P(), self._kv_manual, bx2, bx2, bx, bx, bx, bx, bx,
-                       *mm_specs), (bx, bx, self._kv_manual))
+                       P(), *mm_specs), (bx, bx, self._kv_manual))
             lock = self._lockstep(1, bx)
         else:
             forward = self._forward_prefill()
@@ -771,7 +779,7 @@ class Layout:
             bx, bx2 = self._rows(), self._rows(None)
             rope = (bx,) if self.model_cfg.mrope_section else ()
             manual = ((P(), self._kv_manual,
-                       bx2, bx2, bx, bx, bx, bx, bx,
+                       bx2, bx2, bx, bx, bx, bx, bx, P(),
                        bx, bx, bx, bx2 if penalized else P(), bx2, bx, bx,
                        *rope),
                       (bx, P(None, self.pool_axes), self._kv_manual))

@@ -108,13 +108,19 @@ def _pack_out(out: jax.Array, logp: jax.Array, logits=None,
     | moe(`models.llama.moe_stats_width`)], the last only from an expert
     model's step.
     """
+    parts = _out_parts(out, logp, logits)
+    if moe is not None:
+        parts.append(moe)
+    return _pack(*parts)
+
+
+def _out_parts(out: jax.Array, logp: jax.Array, logits=None) -> list:
+    """What a sampled step packs for its rows, in `_pack_out`'s order."""
     parts = [out, logp]
     if logits is not None:
         ids, lps = top_logprobs(logits, TOPLP)  # [B, TOPLP] each
         parts += [ids.reshape(-1), lps.reshape(-1)]
-    if moe is not None:
-        parts.append(moe)
-    return _pack(*parts)
+    return parts
 
 
 def _unpack_out(packed: np.ndarray, b: int, with_top: bool = False):
@@ -185,13 +191,27 @@ def prefill_body(cfg: ModelConfig, forward, *, with_top: bool = False,
     `forward(params, cfg, kv, tokens, page_table, prefix_lens, chunk_lens,
     **kw) -> (logits, kv, *moe)` is the layout's: `forward_prefill` (flat,
     partitioned pool), `forward_prefill_pp`, `forward_prefill_sp`.  After
-    `counters` come the optional operands: the `mm` triple (vision embeds,
-    their mask and, on an mrope model, the (t, h, w) streams) and ONE
-    trailing operand the layout names (`tail`: sp's "prefix_table" or
-    "owner"), each passed to `forward` under its keyword."""
+    `counters` comes `samples`, then the optional operands: the `mm` triple
+    (vision embeds, their mask and, on an mrope model, the (t, h, w)
+    streams) and ONE trailing operand the layout names (`tail`: sp's
+    "prefix_table" or "owner"), each passed to `forward` under its keyword.
+
+    `samples` ([B] bool: the rows whose chunk ends a prompt) goes to the
+    forward together with the sampling below, and `forward_prefill` runs
+    the output head and them under one conditional on "any row samples": a
+    step in which none does packs zeros where its rows' results were.
+    None (where `Layout.heads_by_rows` is false: pp, sp, lockstep) means
+    every row samples: the head is unconditional and the forward sees
+    neither."""
 
     def body(params, kv, tokens, page_table, prefix_lens, chunk_lens, samp,
-             seeds, counters, *rest):
+             seeds, counters, samples=None, *rest):
+        def sample(logits):
+            out = sample_tokens_maybe_greedy(logits, samp, seeds, counters,
+                                             greedy)
+            logp = compute_logprobs(logits, out)
+            return _out_parts(out, logp, logits if with_top else None)
+
         kw = {}
         if tail is not None:
             kw[tail], rest = rest[-1], rest[:-1]
@@ -200,16 +220,16 @@ def prefill_body(cfg: ModelConfig, forward, *, with_top: bool = False,
                       mm_positions=rest[2] if len(rest) > 2 else None)
         if moe_stats:
             kw["moe_stats"] = True
-        logits, kv, *moe = forward(
+        if samples is not None:
+            kw.update(samples=samples, then=sample)
+        parts, kv, *moe = forward(
             params, cfg, kv, tokens, page_table, prefix_lens, chunk_lens,
             **kw)
-        out = sample_tokens_maybe_greedy(logits, samp, seeds, counters,
-                                         greedy)
-        logp = compute_logprobs(logits, out)
+        if samples is None:
+            parts = sample(parts)  # the forward gave logits
         # `out` rides back as a separate device int32 so a fused decode
         # chain can consume it without waiting for the packed host fetch
-        return _pack_out(out, logp, logits if with_top else None,
-                         *moe), out, kv
+        return _pack(*parts, *moe), parts[0], kv
 
     return body
 
@@ -546,10 +566,10 @@ def mixed_body(cfg: ModelConfig, forward, n_steps: int, max_valid_pos: int,
                          attn_impl, greedy)
 
     def body(params, kv, p_tokens, p_table, p_prefix, p_chunk, p_samp,
-             p_seeds, p_ctr, d_tokens, d_pos, d_ctr, d_counts, d_table,
-             d_samp, d_seeds, *d_rope):
+             p_seeds, p_ctr, p_samples, d_tokens, d_pos, d_ctr, d_counts,
+             d_table, d_samp, d_seeds, *d_rope):
         p_packed, _, kv = prefill(params, kv, p_tokens, p_table, p_prefix,
-                                  p_chunk, p_samp, p_seeds, p_ctr)
+                                  p_chunk, p_samp, p_seeds, p_ctr, p_samples)
         d_packed, *_, kv = decode(params, kv, d_tokens, d_pos, d_ctr,
                                   d_counts, d_table, d_samp, d_seeds,
                                   *d_rope)

@@ -1287,11 +1287,23 @@ def forward_prefill(
     extra_mask: Optional[jax.Array] = None,  # [B, S] bool
     mm_positions: Optional[jax.Array] = None,  # [B, 3, S] mrope streams
     moe_stats: bool = False,  # an expert model's step stats ride along
+    samples: Optional[jax.Array] = None,  # [B] bool: rows that draw a token
+    then=None,  # logits -> pytree, inside the head's conditional
 ):
     """Run a prefill chunk; returns (logits at the last valid position
     [B, V], kv), and with `moe_stats` a third: int32 [3], the step's
     (assignments, experts touched summed over layers, largest per-expert
     row count) over the chunk's valid tokens.
+
+    `samples` says which rows a token is drawn from (a prompt's last
+    chunk).  Given it, everything after the layer loop that exists only to
+    produce a token (the gather of the last position, the final norm, the
+    vocabulary matmul and the caller's `then(logits)`: sampling, logprobs)
+    runs under ONE `lax.cond` on "any row samples", and a step in which
+    none does returns zeros of the same shapes: a mid-prompt chunk does
+    not read the vocabulary matrix.  One scalar for the step and never a
+    select by row, which would run both sides.  What `then` returns takes
+    the logits' place.  None: every row samples, no conditional.
 
     `extra_embeds`/`extra_mask` inject precomputed embeddings (vision
     tower patches) in place of the token embedding at masked positions —
@@ -1315,10 +1327,22 @@ def forward_prefill(
         rope_pos=mm_positions if cfg.mrope_section else None,
         moe_stats=moe_stats,
     )
-    last = jnp.maximum(chunk_lens - 1, 0)
-    at = last[:, None, None, None] if cfg.hc_mult else last[:, None, None]
-    x_last = jnp.take_along_axis(x, at, axis=1)[:, 0]  # [B, h] | [B, n, h]
-    return (_lm_logits(params, cfg, x_last), kv, *st)
+
+    def head(x):
+        last = jnp.maximum(chunk_lens - 1, 0)
+        at = last[:, None, None, None] if cfg.hc_mult else last[:, None, None]
+        x_last = jnp.take_along_axis(x, at, axis=1)[:, 0]  # [B, h] | [B, n, h]
+        logits = _lm_logits(params, cfg, x_last)
+        return logits if then is None else then(logits)
+
+    if samples is None:
+        return (head(x), kv, *st)
+    blank = jax.eval_shape(head, x)
+    out = jax.lax.cond(
+        jnp.any(samples), head,
+        lambda _: jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), blank),
+        x)
+    return (out, kv, *st)
 
 
 def forward_embed(

@@ -2,12 +2,14 @@
 this process holds: for each row count, table width and chunk size, the
 flat engine's jitted step over random weights at the configuration's
 widths, every row a full table of its own pages, median of N runs after a
-warm-up.  One JSON line per (moe_impl, attention, batch, table, chunk).
+warm-up.  One JSON line per (moe_impl, attention, batch, table, chunk,
+head): `head` 1 is a step in which a row samples (a prompt's last chunk),
+0 one in which none does, which skips the output head.
 
     python scripts/time_prefill_steps.py benchmark/configs/<config>.json \\
         [--chunks 512,256,64,16] [--table-pages 256[,128,64]] \\
         [--batch 1[,2,4]] [--impls auto,dense,ragged] \\
-        [--attention auto[,pallas,xla]]
+        [--attention auto[,pallas,xla]] [--head 1[,0]]
 
 Single process, no children; a measurement needs a TPU (refuses the CPU)."""
 
@@ -36,6 +38,9 @@ def main():
                     help="attention_impl: auto is the engine's own rule by "
                     "shape (ops/paged_attention.py _adapt), pallas and xla "
                     "force one form")
+    ap.add_argument("--head", default="1",
+                    help="1: the step's first row samples; 0: no row does "
+                    "(the same program: `samples` is an operand)")
     ap.add_argument("--runs", type=int, default=15)
     ap.add_argument("--rehearse-cpu", action="store_true",
                     help="walk the control flow on the CPU in float32; "
@@ -61,9 +66,9 @@ def main():
     pages = run["worker_flags"]["--num-pages"]
     params = jax.jit(lambda: init_params(base, jax.random.PRNGKey(0),
                                          dtype))()
-    shapes = [(int(b), int(t), int(c)) for b in args.batch.split(",")
+    shapes = [(int(b), int(t), int(c), int(h)) for b in args.batch.split(",")
               for t in args.table_pages.split(",")
-              for c in args.chunks.split(",")]
+              for c in args.chunks.split(",") for h in args.head.split(",")]
     for impl, attention in itertools.product(args.impls.split(","),
                                              args.attention.split(",")):
         cfg = dataclasses.replace(base, moe_impl=impl)
@@ -72,7 +77,7 @@ def main():
             attention_impl=attention))[0]
         step = layout.prefill_step(False, greedy=True)
         kv = KVCache.create(cfg, pages, 16, dtype)
-        for batch, table_pages, chunk in shapes:
+        for batch, table_pages, chunk, head in shapes:
             ones = jnp.ones((batch,), jnp.float32)
             zeros = jnp.zeros((batch,), jnp.int32)
             samp = SamplingParams(ones, zeros, ones, ones, ones)
@@ -82,16 +87,18 @@ def main():
             toks = jnp.asarray(np.random.default_rng(0).integers(
                 4, 260, (batch, chunk)), jnp.int32)
             lens = jnp.full((batch,), chunk, jnp.int32)
+            samples = jnp.arange(batch) < head
             shape = {"config": run["name"], "moe_impl": impl,
                      "attention": attention, "batch": batch,
-                     "chunk": chunk, "table_tokens": table_pages * 16}
+                     "chunk": chunk, "table_tokens": table_pages * 16,
+                     "head": head}
             times = []
             try:
                 for i in range(args.runs + 2):
                     t0 = time.perf_counter()
                     packed, _, kv = step(
                         params, kv, toks, table, prefix, lens, samp,
-                        jnp.zeros((batch,), jnp.uint32), zeros)
+                        jnp.zeros((batch,), jnp.uint32), zeros, samples)
                     packed.block_until_ready()
                     if i >= 2:
                         times.append((time.perf_counter() - t0) * 1e3)

@@ -648,9 +648,10 @@ def test_disagg_transfer_takes_its_geometry_from_the_cache_description(
 GOLDEN = os.path.join(ROOT, "tests", "data", "prefill_step_lowering.json")
 
 
-def lowering_digest(model_cfg):
+def lowering_digest(model_cfg, by_rows=False):
     """sha256 of a tiny prefill step's StableHLO, locations and labels
-    stripped."""
+    stripped: of the step that heads every row, or `by_rows` of the one a
+    flat engine serves, which takes which rows sample as an operand."""
     import hashlib
     import re
 
@@ -668,8 +669,9 @@ def lowering_digest(model_cfg):
     from dynamo_tpu.ops.sampling import SamplingParams
 
     samp = SamplingParams(f32(1), i32(1), f32(1), f32(1), f32(1))
+    rows = (jax.ShapeDtypeStruct((1,), jnp.bool_),) if by_rows else ()
     text = step.lower(p, kv, i32(1, 16), i32(1, 4), i32(1), i32(1), samp,
-                      jax.ShapeDtypeStruct((1,), jnp.uint32), i32(1)
+                      jax.ShapeDtypeStruct((1,), jnp.uint32), i32(1), *rows
                       ).as_text()
     text = re.sub(r"loc\([^)]*\)", "", text)
     text = "\n".join(ln for ln in text.splitlines()
@@ -677,8 +679,11 @@ def lowering_digest(model_cfg):
     return hashlib.sha256(text.encode()).hexdigest()
 
 
+@pytest.mark.parametrize("by_rows", [False, True],
+                         ids=["every-row", "by-rows"])
 @pytest.mark.parametrize("family", ["qwen2", "smallthinker", "deepseek_v3"])
-def test_a_model_without_leading_dense_layers_traces_what_it_traced(family):
+def test_a_model_without_leading_dense_layers_traces_what_it_traced(family,
+                                                                    by_rows):
     """The prefill step of a tiny Qwen2 (dense, biased) and of a tiny
     SmallThinker (experts, pre-attention router, rotary switch, windows)
     lowers to the text it lowered to before the layer loop learned of
@@ -686,7 +691,15 @@ def test_a_model_without_leading_dense_layers_traces_what_it_traced(family):
     pages, a dense layer, a share, its stats) to the text it lowered to
     before the residual add left the layer's halves (PR 37).  Digests taken
     on the parent commit by this same function; regenerate with the
-    container's jax if it moves."""
+    container's jax if it moves.
+
+    Since PR 41 the step takes which of its rows sample.  Without that
+    operand (`every-row`: what pp, sp and lockstep layouts run) it lowers to
+    the three digests above, UNMOVED: the parent's program to the letter.
+    With it (`by-rows`: what a flat engine serves) the text gains the
+    operand and one conditional around the head; those three digests, under
+    `<family>.by_rows`, were taken on PR 41's tree by `lowering_digest(cfg,
+    by_rows=True)` and hold the served step still from here on."""
     from test_smallthinker import TINY as ST
 
     model_cfg = {"qwen2": lambda: tiny_config(attention_bias=True,
@@ -696,7 +709,8 @@ def test_a_model_without_leading_dense_layers_traces_what_it_traced(family):
                      TINY, name="tiny-deepseek-v3")}[family]()
     with open(GOLDEN) as f:
         golden = json.load(f)
-    assert lowering_digest(model_cfg) == golden[family]
+    assert lowering_digest(model_cfg, by_rows) == golden[
+        family + ".by_rows" * by_rows]
 
 
 # -- the benchmark's count and its trace readers ------------------------------------ #

@@ -243,12 +243,13 @@ _MOVERS = ("copy", "dynamic-slice", "dynamic-update-slice", "reshape",
 def prefill_step_shapes(cfg, chunk, table_pages, shardings, pool_pages):
     """Operands of the engine's `prefill_step` (`Layout.prefill_step`):
     the decode step's, with a [B, chunk] token block, prefix and chunk
-    lengths, seeds and counters."""
+    lengths, seeds, counters and which rows sample."""
     params, kv, _, _, _, _, table, samp, seeds = step_shapes(
         cfg, 1, table_pages, shardings, pool_pages)
     lens = _sds((1,), jnp.int32, shardings(P()))
     return (params, kv, _sds((1, chunk), jnp.int32, shardings(P())), table,
-            lens, lens, samp, seeds, lens)
+            lens, lens, samp, seeds, lens,
+            _sds((1,), jnp.bool_, shardings(P())))
 
 
 def pool_sized_movers(hlo_text, kv_shape):
@@ -309,6 +310,34 @@ def test_prefill_step_leaves_the_pool_where_it_is(one_chip, chunk,
     assert mem.temp_size_in_bytes < one_of_kv, mem.temp_size_in_bytes
     assert mem.alias_size_in_bytes >= 2 * one_of_kv  # written in place
     assert pool_sized_movers(text, k.shape) == []
+
+
+def test_prefill_step_keeps_the_head_behind_its_conditional(one_chip):
+    """Qwen2.5-7B widths, two layers, chunk 512 under 128 pages: given which
+    rows sample, the compiled step still holds ONE conditional (the compiler
+    did not turn it into a select, which would run the head on every step)
+    and nothing copies, slices or re-lays-out an array the size of the
+    `[3584, 152064]` matrix on its way in.  Without the operand the same
+    step compiles to no conditional.  Counts, never a time."""
+    import math
+    import re
+
+    cfg = two_layers(QWEN2_5_7B)
+    step = flat_layout(cfg, "adaptive").prefill_step(False, greedy=True)
+    args = prefill_step_shapes(cfg, 512, 128, lambda spec: one_chip, 1024)
+    text = step.lower(*args).compile().as_text()
+    conds = [ln for ln in text.splitlines() if " conditional(" in ln]
+    assert len(conds) == 1, conds
+    head = cfg.hidden_size * cfg.vocab_size
+    moved = []
+    for ln in text.splitlines():
+        m = re.search(r"= \(?\w+\[([\d,]+)\]\S* (%s)\(" % "|".join(_MOVERS),
+                      ln)
+        if m and math.prod(map(int, m.group(1).split(","))) == head:
+            moved.append(ln.strip()[:120])
+    assert moved == []
+    bare = step.lower(*args[:-1]).compile().as_text()
+    assert " conditional(" not in bare
 
 
 def test_prefill_step_partitions_on_a_tp4_mesh(topo):
