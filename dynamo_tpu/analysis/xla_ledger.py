@@ -23,11 +23,29 @@ steady scope after N blocks (after warmup, N decode blocks ⇒ 0 new
 compiles).  ``DYN_TPU_XLALEDGER=0`` disables the probe entirely
 (``ledgered_jit`` degrades to ``jax.jit``).
 
-A ``jax.monitoring`` listener on backend_compile events backstops the
-probe: it counts compilations jax performs OUTSIDE ledgered functions
-(library warmup, test helpers).  Those are unattributed by
-construction — the event carries no function identity — so they feed
-a single global counter, not the per-function ledger.
+A ``jax.monitoring`` listener backstops the probe and gives every
+program's birth a clock.  jax (0.9) reports, on the thread that
+compiles and in this order: ``/jax/core/compile/jaxpr_trace_duration``
+(the probe fires inside it), ``.../jaxpr_to_mlir_module_duration``
+(lowering), then ``.../backend_compile_duration`` around
+``compile_or_get_cached`` — so ``backend_compile_seconds`` HOLDS the
+persistent cache's loads, and a retrieval reports itself just before
+as ``/jax/compilation_cache/cache_retrieval_time_sec``.  The probe
+stamps its entry with ``t_ns`` (monotonic: the step ring's clock) and
+makes it the thread's open birth; the listener adds each stage to it
+(``trace_us``, ``lower_us``, ``compile_us``, ``load_us``, ``hit``) and,
+when the backend stage ends, hands the program to the sink
+(``set_program_sink``: `runtime/events.py` puts it on the ring as a
+``program`` slice) and remembers its ``t_ns`` for the thread
+(``births_between``: the engine's step slice says ``compiled``).  A
+compile OUTSIDE a ledgered function (library warmup,
+``SamplingParams.make``'s ``jit_convert_element_type``) carries no
+function identity: its birth opens at its lowering (its trace is not
+looked for), it reaches the sink with an empty ``fn`` if lowering and
+backend took 1 ms or more, and is counted (``programs_sub_ms``)
+otherwise.  An entry that never reaches the backend (traced inline
+under another jit, ``eval_shape``, ``.lower()``) keeps what it saw and
+reaches no sink.
 
 Transfer guard (``DYN_TPU_XFERCHECK=1``)
 ----------------------------------------
@@ -54,8 +72,10 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import hashlib
 import os
 import threading
+import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
@@ -66,7 +86,7 @@ __all__ = [
     "CompileEntry",
     "HostSyncError",
     "allow_host_sync",
-    "backend_compiles_total",
+    "births_between",
     "compiles_by_fn",
     "entries",
     "guard_state",
@@ -78,6 +98,7 @@ __all__ = [
     "note_path_choice",
     "note_transfer_violation",
     "reset",
+    "set_program_sink",
     "steady_scope",
     "summary",
     "thread_role_init",
@@ -122,10 +143,31 @@ class CompileEntry:
     thread: str
     in_steady: bool       # a steady scope was active → this is a trip
     scope: str            # the steady scope's label ("" outside)
+    program: str = ""     # the jitted program's name (`Layout.wrap`'s)
+    role: str = ""        # contracts role of the tracing thread
+    sig: str = ""         # 8 hex digits over the WHOLE signature
+    t_ns: int = 0         # monotonic ns at the probe: the ring's clock
+    # stages the listener saw on this thread after the probe, in
+    # microseconds; None = the birth never got there
+    trace_us: Optional[int] = None
+    lower_us: Optional[int] = None
+    compile_us: Optional[int] = None  # backend stage less the cache load
+    load_us: Optional[int] = None     # persistent-cache retrieval
+    hit: Optional[int] = None         # 1: the cache answered, 0: compiled
 
     def format(self) -> str:
         tag = f" {self.tags}" if self.tags else ""
-        return f"{self.fn}({self.signature}){tag} [thread={self.thread}]"
+        stages = "".join(
+            f" hit={v}" if k == "hit" else f" {k[:-3]}={v / 1000:.1f}ms"
+            for k, v in self.stages().items())
+        return (f"{self.fn}({self.signature}){tag} [thread={self.thread}]"
+                f"{stages}")
+
+    def stages(self) -> Dict[str, int]:
+        """The stages seen, by their event-attribute names."""
+        return {k: getattr(self, k) for k in (
+            "trace_us", "lower_us", "compile_us", "load_us", "hit")
+            if getattr(self, k) is not None}
 
 
 _LOCK = threading.Lock()
@@ -138,6 +180,10 @@ _auto_steady_armed = False
 _steady_labels: List[str] = []
 _backend_compiles = 0
 _backend_compile_secs = 0.0
+_program_events = 0   # births handed to the sinks
+_programs_sub_ms = 0  # unledgered births under 1 ms: counted, not handed
+_program_sink: Optional[Callable[[int, int, Dict[str, Any]], None]] = None
+_BORN_MAX = 64        # births a thread remembers for `births_between`
 # persistent-cache outcomes (jax/_src/compiler.py events): requests that
 # consulted the cache, and those it answered with a stored executable —
 # the difference really compiled
@@ -175,24 +221,33 @@ def _fmt_leaf(x: Any) -> str:
     return r if len(r) <= 24 else r[:21] + "..."
 
 
-def _fmt_signature(args: tuple, kwargs: dict) -> str:
-    parts: List[str] = []
+def _fmt_signature(args: tuple, kwargs: dict) -> Tuple[str, str]:
+    """(the signature for reading, cut at `_SIG_MAX_CHARS`; 8 hex digits of
+    a hash over EVERY leaf: a step program's first leaves are the model's
+    parameters, so two programs of one function agree in the first and
+    differ only in the second)."""
     try:
-        leaves = jax.tree_util.tree_leaves((args, kwargs))
-        for leaf in leaves:
-            parts.append(_fmt_leaf(leaf))
-            if sum(len(p) + 2 for p in parts) > _SIG_MAX_CHARS:
-                parts.append(f"...+{len(leaves) - len(parts)} more")
-                break
+        leaves = [_fmt_leaf(x)
+                  for x in jax.tree_util.tree_leaves((args, kwargs))]
     except Exception:  # noqa: BLE001 — attribution must never break tracing
-        return "<unformattable>"
-    return ", ".join(parts)
+        return "<unformattable>", ""
+    parts: List[str] = []
+    for leaf in leaves:
+        parts.append(leaf)
+        if sum(len(p) + 2 for p in parts) > _SIG_MAX_CHARS:
+            parts.append(f"...+{len(leaves) - len(parts)} more")
+            break
+    digest = hashlib.blake2s(", ".join(leaves).encode(), digest_size=4)
+    return ", ".join(parts), digest.hexdigest()
 
 
 # -- ledger recording ---------------------------------------------------------- #
 
 def _record_trace(fn_name: str, signature: str,
-                  tags: Optional[Dict[str, Any]]) -> None:
+                  tags: Optional[Dict[str, Any]],
+                  program: str = "", sig: str = "") -> CompileEntry:
+    t_ns = time.monotonic_ns()
+    role = contracts.current_role() or ""
     with _LOCK:
         in_steady = bool(_steady_labels) or _auto_steady_armed
         scope = (_steady_labels[-1] if _steady_labels
@@ -200,13 +255,15 @@ def _record_trace(fn_name: str, signature: str,
         e = CompileEntry(
             fn=fn_name, signature=signature, tags=dict(tags or {}),
             thread=threading.current_thread().name,
-            in_steady=in_steady, scope=scope,
+            in_steady=in_steady, scope=scope, program=program, role=role,
+            sig=sig, t_ns=t_ns,
         )
         if len(_entries) < _MAX_RECORDS:
             _entries.append(e)
         _compiles_by_fn[fn_name] = _compiles_by_fn.get(fn_name, 0) + 1
         if in_steady and len(_trips) < _MAX_RECORDS:
             _trips.append(e)
+    return e
 
 
 def ledgered_jit(fn: Callable, *, tags: Optional[Dict[str, Any]] = None,
@@ -233,8 +290,18 @@ def ledgered_jit(fn: Callable, *, tags: Optional[Dict[str, Any]] = None,
 
     @functools.wraps(fn)
     def probe(*args, **kwargs):
-        _record_trace(qual, _fmt_signature(args, kwargs), tags)
-        return fn(*args, **kwargs)
+        signature, sig = _fmt_signature(args, kwargs)
+        e = _record_trace(qual, signature, tags, fn.__name__, sig)
+        # the outermost trace on this thread opens the program's birth;
+        # a ledgered function traced INSIDE another is inlined there
+        depth = getattr(_tls, "trace_depth", 0)
+        if depth == 0:
+            _tls.birth = e
+        _tls.trace_depth = depth + 1
+        try:
+            return fn(*args, **kwargs)
+        finally:
+            _tls.trace_depth = depth
 
     return jax.jit(probe, **jit_kwargs)
 
@@ -289,13 +356,6 @@ def compiles_by_fn() -> Dict[str, int]:
         return dict(_compiles_by_fn)
 
 
-def backend_compiles_total() -> int:
-    """Unattributed backstop: every backend compile jax reported via
-    monitoring, ledgered or not."""
-    with _LOCK:
-        return _backend_compiles
-
-
 def note_path_choice(site: str, choice: str, reason: str, **dims) -> None:
     """Record which device program a step was traced into, and why.
 
@@ -333,6 +393,8 @@ def summary() -> dict:
             "backend_compile_seconds": round(_backend_compile_secs, 3),
             "cache_hits": _cache_hits,
             "cache_misses": _cache_requests - _cache_hits,
+            "program_events": _program_events,
+            "programs_sub_ms": _programs_sub_ms,
             "path_choices": [
                 {"site": s, "choice": c, "reason": r, "dims": d, "traces": n}
                 for (s, c, r, d), n in _path_choices.items()
@@ -347,7 +409,9 @@ def reset() -> None:
     """Test isolation: drop all recorded state (steady scopes stay)."""
     global _decode_blocks, _auto_steady_armed, _backend_compiles
     global _backend_compile_secs, _cache_hits, _cache_requests
+    global _program_events, _programs_sub_ms
     with _LOCK:
+        _program_events = _programs_sub_ms = 0
         _path_choices.clear()
         _choice_by_dims.clear()
         _backend_compile_secs = 0.0
@@ -367,12 +431,105 @@ def reset() -> None:
 _listener_installed = False
 
 
-def _on_event_duration(event: str, duration: float, **kwargs) -> None:
+def set_program_sink(
+        sink: Optional[Callable[[int, int, Dict[str, Any]], None]]) -> None:
+    """`sink(t0_ns, t1_ns, attrs)` is called once for every program whose
+    backend stage ends, on the thread that compiled it: `fn` (the
+    program's name; "" outside a ledgered function), `sig` (8 hex digits
+    over the whole signature: its readable head stays in `entries()`),
+    `tags`, `role` and the stages seen (`CompileEntry.stages`).  One slot
+    (`runtime/events.py` fills it); None empties it."""
+    global _program_sink
+    _program_sink = sink
+
+
+def births_between(t0_ns: int, t1_ns: int) -> int:
+    """How many programs handed to the sink began their birth on the
+    CALLING thread in [t0_ns, t1_ns): a step's slice asks with the span
+    of its build and dispatch and says `compiled`.  Thread-local, so no
+    lock; forgets what it counted and anything older (a step in flight
+    is recorded after the next was dispatched, whose programs stay)."""
+    born = getattr(_tls, "born", None)
+    if not born:
+        return 0
+    _tls.born = [t for t in born if t >= t1_ns]
+    return sum(1 for t in born if t0_ns <= t < t1_ns)
+
+
+def _open_birth(dur_s: float = 0.0) -> CompileEntry:
+    """A birth outside any ledgered function (no `fn`, in no list), begun
+    `dur_s` ago by the stage jax just reported: its lowering as a rule,
+    so such a program's trace is not in its slice."""
+    e = _tls.birth = CompileEntry(
+        fn="", signature="", tags={}, in_steady=False, scope="",
+        thread=threading.current_thread().name,
+        role=contracts.current_role() or "",
+        t_ns=time.monotonic_ns() - int(dur_s * 1e9))
+    return e
+
+
+def _us(seconds: float) -> int:
+    return int(seconds * 1e6)
+
+
+def _born(e: CompileEntry, backend_s: float) -> None:
+    """The backend stage ended: the birth is whole."""
+    global _program_events, _programs_sub_ms
+    _tls.birth = None
+    t1 = time.monotonic_ns()
+    e.compile_us = max(0, _us(backend_s) - (e.load_us or 0))
+    if not e.fn and (e.lower_us or 0) + _us(backend_s) < 1000:
+        with _LOCK:
+            _programs_sub_ms += 1
+        return
+    with _LOCK:
+        _program_events += 1
+    _tls.born = getattr(_tls, "born", [])[-(_BORN_MAX - 1):] + [e.t_ns]
+    sink = _program_sink
+    if sink is None:
+        return
+    attrs = {"fn": e.program[:48], "role": e.role, **e.stages()}
+    if e.fn:
+        attrs["sig"] = e.sig
+    if e.tags:
+        attrs["tags"] = ",".join(f"{k}={v}" for k, v in e.tags.items())[:40]
+    try:
+        sink(e.t_ns, t1, attrs)
+    # lint: allow(swallowed-exception): a sink must never break a compile
+    except Exception:  # noqa: BLE001
+        pass
+
+
+def _on_event_duration(event: str, duration: float, fun_name: str = "",
+                       **kwargs) -> None:
     global _backend_compiles, _backend_compile_secs
+    b = getattr(_tls, "birth", None)
     if "backend_compile" in event:
         with _LOCK:
             _backend_compiles += 1
             _backend_compile_secs += duration
+        if b is None or (b.fn and b.lower_us is None):  # none, or a stale one
+            b = _open_birth(duration)
+        _born(b, duration)
+    elif event.endswith("/jaxpr_trace_duration"):
+        # the probe's own trace just ended: depth 0 again, and jax names
+        # the program.  Inside it (depth > 0) these are the jitted library
+        # functions it calls; any other is an unledgered function's, whose
+        # birth opens at its lowering
+        if (b is not None and b.fn and b.trace_us is None
+                and not getattr(_tls, "trace_depth", 0)
+                and fun_name in ("", b.program)):
+            b.trace_us = _us(duration)
+    elif event.endswith("/jaxpr_to_mlir_module_duration"):
+        # the open ledgered birth's, if jax names its module (`jit_<fn>`):
+        # one that was traced and never compiled must not take the next
+        # program's stages
+        if (b is None or not b.fn or b.lower_us is not None
+                or b.program not in (fun_name or b.program)):
+            b = _open_birth(duration)
+        b.lower_us = _us(duration)
+    elif event.endswith("/compilation_cache/cache_retrieval_time_sec"):
+        (b or _open_birth(duration)).load_us = _us(duration)
 
 
 def _on_event(event: str, **kwargs) -> None:
@@ -380,9 +537,11 @@ def _on_event(event: str, **kwargs) -> None:
     if event.endswith("/compilation_cache/cache_hits"):
         with _LOCK:
             _cache_hits += 1
+        (getattr(_tls, "birth", None) or _open_birth()).hit = 1
     elif event.endswith("/compilation_cache/compile_requests_use_cache"):
         with _LOCK:
             _cache_requests += 1
+        (getattr(_tls, "birth", None) or _open_birth()).hit = 0
 
 
 def _install_listener() -> None:

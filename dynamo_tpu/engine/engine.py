@@ -360,9 +360,13 @@ class JaxEngine:
         # endpoint and merged into the Perfetto timeline.  Scheduler and
         # pool record through the same ring so one dump is the whole
         # engine's step history
-        from ..runtime.events import StepEventRecorder
+        from ..runtime.events import StepEventRecorder, attach_host_events
 
         self.events = StepEventRecorder.from_env()
+        # the process's host events (start-up phases, program births,
+        # collector pauses, late lease renewals) land on the FIRST
+        # engine's ring: a `--dp-ranks` worker's rank 0
+        attach_host_events(self.events)
         self.scheduler.events = self.events
         for p in getattr(self.pool, "pools", [self.pool]):
             p.events = self.events
@@ -1453,7 +1457,9 @@ class JaxEngine:
         `build_us` (t0 to the jitted call), `dispatch_us` (the call, its
         input transfers and what was dispatched behind it: a cache miss or
         a blocked transfer shows here), `fetch_us` (the device_get: device
-        wait plus copy), `deliver_us` (unpack, token accounting, queues).
+        wait plus copy), `deliver_us` (unpack, token accounting, queues),
+        and `compiled`, the programs this thread bore in build and dispatch
+        (`xla_ledger.births_between`; absent when 0).
         Phases are attributes and not nested slices: a reader that labels
         a device gap by the slice spanning it would lose a nested one
         behind its parent.  A phase the step never reached reads 0.
@@ -1486,6 +1492,12 @@ class JaxEngine:
         if t_sent is not None:
             phases["dispatch_us"] = (t_sent - t_call) // 1000
             phases["overlap_us"] = (t_fetch - t_sent) // 1000
+        # programs this thread bore up to the end of the dispatch, where
+        # a jitted call traces, compiles or loads (absent when 0)
+        born = xla_ledger.births_between(
+            t0, t_fetch if t_sent is None else t_sent)
+        if born:
+            phases["compiled"] = born
         return phases
 
     def _sampling_rows(self, item_rows) -> Tuple[Optional[np.ndarray], int]:
@@ -2978,6 +2990,9 @@ class JaxEngine:
                     attrs["splice"] = True
                 if chunk_rows:
                     attrs["chunk_rows"] = chunk_rows
+                born = xla_ledger.births_between(t_iter, t_sent)
+                if born:
+                    attrs["compiled"] = born
                 # phases as on every step slice; the splice and feed work
                 # above t_iter is outside the slice, so build_us is 0, and
                 # fetch_us is the wait for the drain thread's device_get

@@ -33,7 +33,7 @@ from ..engine.engine import ForwardPassMetrics, _opts_from_request
 from ..engine.page_pool import KvEvent, PagePool
 from ..engine.scheduler import PrefillItem, Scheduler, Sequence
 from ..runtime.engine import Context
-from ..runtime.events import StepEventRecorder
+from ..runtime.events import StepEventRecorder, attach_host_events
 
 logger = logging.getLogger(__name__)
 
@@ -114,6 +114,7 @@ class MockEngine:
         # the mock pump) — so chaos workers running the mock leave the
         # same black box (`DYN_TPU_FLIGHT_DIR`) a real worker would
         self.events = StepEventRecorder.from_env()
+        attach_host_events(self.events)  # as the real engine's ring does
         self.scheduler.events = self.events
         # decode preemption park/resume: the mock holds no KV bytes, so
         # parking is pure page accounting through a real ParkingLot

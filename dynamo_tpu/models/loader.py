@@ -9,6 +9,7 @@ from __future__ import annotations
 
 import json
 import os
+import time
 from typing import Dict, List
 
 import jax.numpy as jnp
@@ -36,6 +37,11 @@ def _index(path: str) -> Dict[str, str]:
         return {k: "model.safetensors" for k in f.keys()}
 
 
+# nanoseconds this process's shard readers spent reading: the worker's
+# `startup.weights` event tells reading (`read_us`) from placing by it
+READ_STATS = {"read_ns": 0}
+
+
 class _ShardReader:
     def __init__(self, path: str):
         self.path = path
@@ -43,12 +49,15 @@ class _ShardReader:
         self._open: Dict[str, object] = {}
 
     def get(self, name: str) -> np.ndarray:
+        t0 = time.monotonic_ns()
         shard = self.weight_map[name]
         if shard not in self._open:
             self._open[shard] = safe_open(
                 os.path.join(self.path, shard), framework="np"
             )
-        return self._open[shard].get_tensor(name)
+        w = self._open[shard].get_tensor(name)
+        READ_STATS["read_ns"] += time.monotonic_ns() - t0
+        return w
 
     def has(self, name: str) -> bool:
         return name in self.weight_map

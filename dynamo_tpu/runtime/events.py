@@ -29,7 +29,21 @@ cache, so a SIGKILL leaves whatever was already written readable — the
 black box that the in-memory ring (gone with the process) cannot
 provide. Each 256-byte record slot carries a trailing commit marker
 written LAST, so a reader treats a torn final record as a clean prefix
-end, never as garbage (`load_flight_dir` / `scripts/postmortem.py`)."""
+end, never as garbage (`load_flight_dir` / `scripts/postmortem.py`).
+
+Host events: the rare, long things a process does off the step path
+(the worker's start-up phases, a program's trace / lowering / compile /
+cache load, a collector pause, a late lease renewal) enter through the
+module-level `host_event()`, on the same monotonic clock.  Until an
+engine's ring attaches (`attach_host_events`) they wait in a small
+bounded buffer; the ring adopts them once, each with its OWN `t_ns`
+(earlier than the ring's birth), and later ones are written straight to
+it.  A process with several rings (`--dp-ranks`) has ONE that takes
+them: the first engine built (rank 0), for as long as it lives.
+`DYN_TPU_STEP_EVENTS=0` turns them off with the rest.  The collector's
+callback is the one caller that may run INSIDE this module's locks (a
+collection starts between any two bytecodes): it takes none and parks its
+pause for `_flush_gc`."""
 
 from __future__ import annotations
 
@@ -38,7 +52,10 @@ import mmap
 import os
 import re
 import struct
+import collections
+import threading
 import time
+import weakref
 from typing import Any, Dict, List, Optional
 
 from ..analysis import make_lock
@@ -297,6 +314,121 @@ def load_flight_dir(directory: str,
     return out
 
 
+# -- host events -------------------------------------------------------------- #
+
+# every kind `host_event` is called with in the package; the merged
+# timeline draws them on a track of their own (runtime/timeline.py)
+HOST_EVENT_KINDS = frozenset((
+    "startup.imports", "startup.backend", "startup.weights",
+    "startup.engine", "startup.serve", "ready", "program", "gc_pause",
+    "lease_renew"))
+_HOST_BUFFER_MAX = 1024  # a start-up's phases, programs and pauses
+_host_lock = threading.Lock()
+_host_buffer: List[tuple] = []  # guarded-by: _host_lock
+_host_ring: Optional["weakref.ref[StepEventRecorder]"] = None
+_gc_t0 = 0
+# pauses the collector's callback saw, waiting for a point where a lock may
+# be taken: a collection starts between any two bytecodes, inside this
+# module's locks too, so the callback itself takes none (a deque's append
+# is atomic) and `_flush_gc` moves them on
+_gc_pending: "collections.deque[tuple]" = collections.deque(maxlen=256)
+
+
+def _attached_ring() -> Optional["StepEventRecorder"]:
+    """The live ring that takes this process's host events, if any."""
+    return _host_ring() if _host_ring is not None else None
+
+
+def _host_events_on() -> bool:
+    from .config import env_int
+
+    return env_int("DYN_TPU_STEP_EVENTS", DEFAULT_CAPACITY) > 0
+
+
+def host_event(kind: str, t0_ns: Optional[int] = None,
+               t1_ns: Optional[int] = None, **attrs: Any) -> None:
+    """Record a host event where it happens, whether or not a ring exists
+    yet: `StepEventRecorder.record`'s arguments (a slice [t0_ns, t1_ns or
+    now], or an instant), written to the attached ring or, before one
+    attaches, kept in a bounded buffer (the newest are dropped past
+    `_HOST_BUFFER_MAX`)."""
+    if _gc_pending:
+        _flush_gc()
+    t1 = time.monotonic_ns() if t1_ns is None else t1_ns
+    t0 = t1 if t0_ns is None else t0_ns
+    with _host_lock:
+        ring = _attached_ring()
+        if ring is None:
+            if len(_host_buffer) < _HOST_BUFFER_MAX and _host_events_on():
+                _host_buffer.append((t0, t1 - t0, kind, attrs))
+            return
+    ring.record(kind, t0_ns=t0, t1_ns=t1, **attrs)
+
+
+def attach_host_events(ring: "StepEventRecorder") -> bool:
+    """An engine's ring takes the process's host events: it adopts the
+    buffer and is written to from now on.  The first ring to ask wins
+    while it lives (a `--dp-ranks` worker: rank 0's), so one process's
+    host events are on one ring; False for the others."""
+    global _host_ring
+    install_host_probes()
+    with _host_lock:
+        if _attached_ring() is not None:
+            return False
+        _host_ring = weakref.ref(ring)
+        buffered, _host_buffer[:] = list(_host_buffer), []
+    for t, dur, kind, attrs in buffered:  # each with its OWN times
+        ring.record(kind, t0_ns=t, t1_ns=t + dur, **attrs)
+    return True
+
+
+def _on_gc(phase: str, info: Dict[str, int]) -> None:
+    """`gc.callbacks`: a `gc_pause` slice for a generation-2 collection
+    or any pause of 1 ms or more; returns at once otherwise."""
+    global _gc_t0
+    if phase == "start":
+        _gc_t0 = time.monotonic_ns()
+        return
+    t1 = time.monotonic_ns()
+    if info["generation"] == 2 or t1 - _gc_t0 >= 1_000_000:
+        _gc_pending.append(
+            (_gc_t0, t1, info["generation"], info["collected"]))
+
+
+def _flush_gc() -> None:
+    """The pauses `_on_gc` left, as `gc_pause` host events.  Called where
+    no lock of this module is held: by the next `record()` of any ring, by
+    `dump()` / `snapshot()` before they read (so a poll's cursor never
+    passes a pause that ended before the poll) and by `host_event`."""
+    pauses = []
+    while _gc_pending:  # emptied first: recording looks here again
+        try:
+            pauses.append(_gc_pending.popleft())
+        except IndexError:  # another thread took the last one
+            break
+    for t0, t1, gen, collected in pauses:
+        host_event("gc_pause", t0, t1, gen=gen, collected=collected)
+
+
+def _on_program(t0_ns: int, t1_ns: int, attrs: Dict[str, Any]) -> None:
+    """The compile ledger's sink, on the thread that compiled."""
+    host_event("program", t0_ns, t1_ns, **attrs)
+
+
+def install_host_probes() -> None:
+    """The collector's callback and the compile ledger's sink, once a
+    process (the worker calls this first thing, an engine's ring when it
+    attaches).  Nothing where `DYN_TPU_STEP_EVENTS=0`."""
+    import gc
+
+    if _on_gc in gc.callbacks or not _host_events_on():
+        return
+    gc.callbacks.append(_on_gc)
+    from ..analysis import xla_ledger
+
+    xla_ledger.set_program_sink(_on_program)
+
+
 class StepEventRecorder:
     """Fixed-capacity ring of (t_ns, dur_ns, kind, attrs) tuples."""
 
@@ -334,6 +466,8 @@ class StepEventRecorder:
         whatever happens to the thread before it gets here."""
         if not self.enabled:
             return
+        if _gc_pending:
+            _flush_gc()
         t = time.monotonic_ns() if t1_ns is None else t1_ns
         if t0_ns is not None:
             ev = (t0_ns, t - t0_ns, kind, attrs)
@@ -376,6 +510,7 @@ class StepEventRecorder:
         """Events in record order (oldest surviving first)."""
         if not self.enabled:
             return []
+        _flush_gc()
         return self._snap()[1]
 
     def dump(self, since_ns: Optional[int] = None) -> Dict[str, Any]:
@@ -391,6 +526,7 @@ class StepEventRecorder:
         event commits at `t_ns + dur_ns` (record time), which is
         monotone in record order; filtering on start time would lose
         long slices that began before the watermark."""
+        _flush_gc()
         mono = time.monotonic_ns()
         wall = time.time_ns()
         n, events = self._snap()

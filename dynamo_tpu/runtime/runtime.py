@@ -14,10 +14,12 @@ import asyncio
 import logging
 import os
 import socket
+import time
 from typing import Optional
 
 from ..analysis import leak_ledger
 from .component import Namespace
+from .events import host_event
 from .transport.control_plane import (
     ControlPlaneClient,
     ControlPlaneServer,
@@ -27,6 +29,7 @@ from .transport.service import ServiceClient, ServiceServer
 logger = logging.getLogger(__name__)
 
 DEFAULT_LEASE_TTL = float(os.environ.get("DYN_TPU_LEASE_TTL", "5.0"))
+_LEASE_LATE_NS = 50_000_000  # a renewal this late, or this slow, is an event
 
 
 class DistributedRuntime:
@@ -97,8 +100,18 @@ class DistributedRuntime:
         republish = False
         while not self._shutdown.is_set():
             try:
+                due = time.monotonic_ns() + int(self._lease_ttl / 3 * 1e9)
                 await asyncio.sleep(self._lease_ttl / 3)
+                woke = time.monotonic_ns()
                 ok = await self.control.keepalive(self.primary_lease)
+                done = time.monotonic_ns()
+                # a loop that something held shows here first: the wake
+                # came late, or the round trip waited for the loop
+                if (woke - due >= _LEASE_LATE_NS
+                        or done - woke >= _LEASE_LATE_NS):
+                    host_event("lease_renew", due, done,
+                               late_us=(woke - due) // 1000,
+                               rtt_us=(done - woke) // 1000)
                 if not ok:
                     logger.warning(
                         "primary lease %d lost — re-granting and "

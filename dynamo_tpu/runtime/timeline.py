@@ -32,8 +32,11 @@ from __future__ import annotations
 import json
 from typing import Any, Dict, Iterable, List, Optional, Tuple
 
+from .events import HOST_EVENT_KINDS
+
 # ring-event track id within each service's process
 _RING_TID = 999
+_HOST_TID = 998  # the ring's host events (runtime/events.py)
 
 
 def load_otlp_spans(paths: Iterable[str]) -> List[dict]:
@@ -89,8 +92,8 @@ def spans_to_chrome(spans: List[dict]) -> Tuple[List[dict], Dict[str, int]]:
     def next_tid(key) -> int:
         if key not in tids:
             n = len(tids) + 1
-            # never collide with the reserved engine-steps track
-            tids[key] = n if n < _RING_TID else n + 1
+            # never collide with the reserved ring tracks
+            tids[key] = n if n < _HOST_TID else n + 2
         return tids[key]
 
     by_id: Dict[str, dict] = {s.get("spanId", ""): s for s in spans}
@@ -148,8 +151,13 @@ def ring_to_chrome(dump: dict, service: str,
         dur = ev.get("dur_ns", 0) / 1e3
         args = {k: v for k, v in ev.items()
                 if k not in ("t_ns", "dur_ns", "kind")}
+        kind = ev.get("kind", "?")
+        # the host's rare, long events overlap the steps they fell into
+        # (and the start-up slices are older than the ring): their own
+        # track, so the step track's slices stay nested
         base = {
-            "name": ev.get("kind", "?"), "pid": pid, "tid": _RING_TID,
+            "name": kind, "pid": pid,
+            "tid": _HOST_TID if kind in HOST_EVENT_KINDS else _RING_TID,
             "ts": ts, "cat": "engine", "args": args,
         }
         if dur > 0:
@@ -190,6 +198,8 @@ def _metadata(pids: Dict[str, int], ring_services: Iterable[str]) -> List[dict]:
         if service in set(ring_services):
             out.append({"name": "thread_name", "ph": "M", "pid": pid,
                         "tid": _RING_TID, "args": {"name": "engine-steps"}})
+            out.append({"name": "thread_name", "ph": "M", "pid": pid,
+                        "tid": _HOST_TID, "args": {"name": "host-events"}})
     return out
 
 

@@ -7,10 +7,52 @@ tokenizer in-process (no downloads); `--mock` runs the MockEngine simulator
 (the analog of `python -m dynamo.mocker`).
 """
 
-import argparse
-import asyncio
-import logging
-import signal
+import time
+
+# the fallback for the process's start, where /proc does not say (the
+# package's imports, jax among them, are done by this line)
+_T_MAIN_NS = time.monotonic_ns()
+
+import argparse  # noqa: E402
+import asyncio  # noqa: E402
+import json  # noqa: E402
+import logging  # noqa: E402
+import os  # noqa: E402
+import signal  # noqa: E402
+
+from ..runtime.events import host_event, install_host_probes  # noqa: E402
+
+# Start-up as slices on the step ring's clock that tile process start ->
+# READY (`startup.*`, then the instant `ready`: docs/observability.md),
+# and the same phases in seconds: `/metrics.json` `runtime.startup` and the
+# `STARTUP {...}` line
+_STARTUP: dict = {}
+_phase_end_ns = [0]
+
+
+def _process_start_ns() -> int:
+    """This process's start on the monotonic clock: `/proc/self/stat`'s
+    start time (field 22: clock ticks since boot, which is where Linux's
+    CLOCK_MONOTONIC counts from) or, where that cannot be this process's
+    (no /proc, another clock), the first line of this module."""
+    try:
+        with open("/proc/self/stat") as f:
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        t = ticks * 1_000_000_000 // os.sysconf("SC_CLK_TCK")
+        if 0 <= _T_MAIN_NS - t < 600 * 1_000_000_000:
+            return t
+    except (OSError, ValueError, IndexError):
+        pass
+    return _T_MAIN_NS
+
+
+def _phase_done(name: str) -> tuple:
+    """(start, end) of the start-up phase that ends now: it began where
+    the one before it ended, so the phases leave no gap and no overlap."""
+    t0, t1 = _phase_end_ns[0], time.monotonic_ns()
+    _phase_end_ns[0] = t1
+    _STARTUP[name + "_s"] = round((t1 - t0) / 1e9, 3)
+    return t0, t1
 
 
 def _ladder_arg(s: str):
@@ -312,6 +354,8 @@ def engine_config_from_args(args):
 
 
 def main() -> None:
+    install_host_probes()
+    _phase_end_ns[0] = _STARTUP["t0_ns"] = _process_start_ns()
     ap = build_parser()
     args = ap.parse_args()
     check_args(ap, args)
@@ -348,6 +392,10 @@ def main() -> None:
         initialize_multihost(args.coordinator, args.num_hosts, args.host_id)
     import jax
 
+    host_event("startup.imports", *_phase_done("imports"))
+    n_devices = len(jax.devices())  # the first touch: the client, the chip
+    host_event("startup.backend", *_phase_done("backend"),
+               platform=jax.default_backend(), devices=n_devices)
     if jax.process_count() > 1 and jax.process_index() != 0:
         # follower rank: same engine, no endpoint — replay rank 0's steps
         if args.mock:
@@ -640,6 +688,12 @@ async def _run(args) -> None:
             print("RESIDUAL " + _json.dumps(
                 engine.model_cfg.residual_report), flush=True)
         print("DEVICE " + _json.dumps(chip.device_identity()), flush=True)
+    t0_serve, t_ready = _phase_done("serve")
+    host_event("startup.serve", t0_serve, t_ready)
+    host_event("ready", t_ready, t_ready, model=mdc.name)
+    _STARTUP["ready_s"] = round((t_ready - _STARTUP["t0_ns"]) / 1e9, 3)
+    # an operator's cold-start number, the planner's scale-up delay
+    print("STARTUP " + json.dumps(_STARTUP), flush=True)
     print(f"READY worker {mdc.name}", flush=True)
     stop = asyncio.Event()
     loop = asyncio.get_running_loop()
@@ -686,9 +740,29 @@ def device_report() -> dict:
         "device": chip.device_identity(),
         "memory": [memory(d) for d in jax.local_devices()],
         "xla": xla_ledger.summary(),
+        "startup": dict(_STARTUP),
         "native": native.status(),
         "compile_cache_dir": jax.config.jax_compilation_cache_dir,
     }
+
+
+def _weights_done(params) -> None:
+    """`startup.weights`: from the backend's end through the LAST array on
+    the device (configuration, tokenizer and the engine's imports are in
+    it).  `bytes` and `tensors` are what sits on the device; `read_us` is
+    the time inside the checkpoint readers (`models/loader.py`), `put_us`
+    the rest: stacking on the host, the copies, the casts."""
+    import jax
+
+    from ..models import loader
+
+    jax.block_until_ready(params)
+    leaves = jax.tree_util.tree_leaves(params)
+    t0, t1 = _phase_done("weights")
+    read_us = loader.READ_STATS["read_ns"] // 1000
+    host_event("startup.weights", t0, t1,
+               bytes=int(sum(x.nbytes for x in leaves)), tensors=len(leaves),
+               read_us=read_us, put_us=(t1 - t0) // 1000 - read_us)
 
 
 def _build_engine(args):
@@ -725,6 +799,7 @@ def _build_engine(args):
             park_max_pages=args.park_max_pages,
         )
         engine = MockEngine(margs)
+        host_event("startup.engine", *_phase_done("engine"))
         mdc = ModelDeploymentCard(
             name=args.model_name or "mock-model",
             tokenizer_json=tok.to_json_str(),
@@ -778,6 +853,7 @@ def _build_engine(args):
         name = args.model_name or cfg.name
         tokenizer_json = tok.to_json_str()
         eos = list(tok.eos_token_ids)
+    _weights_done(params)
 
     parallel = None
     if args.dp * args.tp * args.sp * args.pp > 1:
@@ -875,6 +951,9 @@ def _build_engine(args):
         ])
     else:
         engine = make_engine()
+    host_event("startup.engine", *_phase_done("engine"),
+               pool_bytes=sum(e.cache_report()["pool_bytes"]
+                              for e in getattr(engine, "engines", [engine])))
     mdc = ModelDeploymentCard(
         name=name,
         tokenizer_json=tokenizer_json,

@@ -13,8 +13,9 @@ Two contracts, both diffed in BOTH directions:
   and the doc's ``http.{chat,completion}`` brace form is expanded the
   same way.
 - **Step-event kinds** — every literal first argument of
-  ``<...>events.record("kind", ...)`` vs the "## Engine step-event
-  schema" table.  (Other ``.record(...)`` receivers — SLO windows,
+  ``<...>events.record("kind", ...)`` and of ``host_event("kind", ...)``
+  (the host's rare, long events: runtime/events.py) vs the "## Engine
+  step-event schema" table.  (Other ``.record(...)`` receivers — SLO windows,
   latency histograms — take numbers, not kinds, and are skipped by the
   receiver-name filter.)
 
@@ -105,7 +106,8 @@ def emitted_span_names(root: str = PKG) -> set:
 
 def emitted_event_kinds(root: str = PKG) -> set:
     """Every step-event kind the package can record: literal first args
-    of ``record()`` calls whose receiver chain ends in ``events``."""
+    of ``record()`` calls whose receiver chain ends in ``events``, and of
+    ``host_event()`` calls."""
     kinds = set()
     for path in _python_files(root):
         with open(path) as f:
@@ -113,10 +115,12 @@ def emitted_event_kinds(root: str = PKG) -> set:
         for node in ast.walk(tree):
             if not isinstance(node, ast.Call) or not node.args:
                 continue
-            if _call_name(node) != "record":
-                continue
-            recv = _receiver_chain(node)
-            if not recv.split(".")[-1].endswith("events"):
+            name = _call_name(node)
+            if name == "record":
+                recv = _receiver_chain(node)
+                if not recv.split(".")[-1].endswith("events"):
+                    continue
+            elif name != "host_event":  # runtime/events.py: the host's
                 continue
             arg = node.args[0]
             if isinstance(arg, ast.Constant) and isinstance(arg.value, str):

@@ -108,7 +108,7 @@ def last_seconds_report(ring_dumps, spans, last_s=5.0, max_lines=40):
         summary = ", ".join(f"{k}×{n}" for k, n in sorted(kinds.items()))
         lines.append(f"-- {key}: {len(mine)} event(s) "
                      f"[{summary or 'silent'}]")
-        for e, ev in sorted(mine)[-max_lines:]:
+        for e, ev in sorted(mine, key=lambda r: r[0])[-max_lines:]:
             dt = (e - t_end) / 1e9
             dur = ev.get("dur_ns", 0) / 1e6
             lines.append(
@@ -118,7 +118,8 @@ def last_seconds_report(ring_dumps, spans, last_s=5.0, max_lines=40):
     sp_window = [(e, s, sp) for e, s, sp in span_rows if e >= lo]
     if sp_window:
         lines.append(f"-- spans in window: {len(sp_window)}")
-        for e, service, sp in sorted(sp_window)[-max_lines:]:
+        for e, service, sp in sorted(
+                sp_window, key=lambda r: r[:2])[-max_lines:]:
             dt = (e - t_end) / 1e9
             lines.append(f"   {dt:+9.3f}s [{service}] "
                          f"{sp.get('name', '?')} "

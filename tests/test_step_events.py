@@ -3,6 +3,7 @@ budget, the crash-surviving flight-recorder spill, and the
 engine/status-server integration (docs/observability.md event schema)."""
 
 import os
+import threading
 import time
 
 import jax
@@ -350,3 +351,323 @@ async def test_engine_records_step_events_and_status_dump():
             await status.stop()
     finally:
         await engine.shutdown()
+
+
+# -- host events: the rare, long things, on the ring's clock ----------------- #
+
+
+@pytest.fixture
+def host_events(monkeypatch):
+    """The module's host-event state, fresh for one test and put back."""
+    from dynamo_tpu.runtime import events
+
+    monkeypatch.setattr(events, "_host_ring", None)
+    monkeypatch.setattr(events, "_host_buffer", [])
+    events._gc_pending.clear()  # pauses of the tests before this one
+    return events
+
+
+def test_events_before_a_ring_are_adopted_with_their_own_times(host_events):
+    ev = host_events
+    t_a = time.monotonic_ns()
+    ev.host_event("startup.imports", t_a - 5_000_000, t_a)
+    ev.host_event("startup.backend", t_a, bytes=3)       # slice to now
+    ev.host_event("ready")                                # an instant
+    born = time.monotonic_ns()
+    rec = StepEventRecorder(capacity=16)                  # the ring's birth
+    assert rec.snapshot() == []
+    assert ev.attach_host_events(rec) is True
+    got = rec.snapshot()
+    assert [e[2] for e in got] == ["startup.imports", "startup.backend",
+                                   "ready"]
+    assert got[0][:2] == (t_a - 5_000_000, 5_000_000)
+    assert got[1][0] == t_a and got[1][3] == {"bytes": 3}
+    assert got[2][1] == 0
+    assert all(e[0] + e[1] <= born for e in got)   # older than the ring
+    assert ev._host_buffer == []                   # adopted once
+    assert rec.kind_totals["ready"] == 1
+    # from now on straight to the ring
+    ev.host_event("gc_pause", time.monotonic_ns() - 1000, gen=2, collected=0)
+    assert rec.snapshot()[-1][2] == "gc_pause" and rec.total == 4
+
+
+def test_since_ns_poll_neither_repeats_nor_loses_adopted_events(host_events):
+    ev = host_events
+    t = time.monotonic_ns()
+    ev.host_event("startup.imports", t - 9_000_000, t - 6_000_000)
+    ev.host_event("startup.weights", t - 6_000_000, t - 1_000_000)
+    rec = StepEventRecorder(capacity=32)
+    ev.attach_host_events(rec)
+    rec.record("plan", t0_ns=rec.now())
+    d1 = rec.dump()                        # a poller's first, without a cursor
+    assert [e["kind"] for e in d1["events"]] == [
+        "startup.imports", "startup.weights", "plan"]
+    assert d1["watermark_ns"] >= t - 1_000_000
+    assert rec.dump(since_ns=d1["watermark_ns"])["events"] == []
+    # a long host slice that began before the watermark commits after it
+    ev.host_event("startup.engine", t - 1_000_000)
+    rec.record("plan", t0_ns=rec.now())
+    d2 = rec.dump(since_ns=d1["watermark_ns"])
+    assert [e["kind"] for e in d2["events"]] == ["startup.engine", "plan"]
+    d3 = rec.dump(since_ns=d2["watermark_ns"])
+    assert d3["events"] == [] and d3["watermark_ns"] == d2["watermark_ns"]
+    # commit times are monotone in record order: the cursor's premise
+    commits = [e["t_ns"] + e["dur_ns"] for e in rec.dump()["events"]]
+    assert commits == sorted(commits)
+
+
+def test_one_ring_a_process_takes_the_host_events(host_events):
+    """`--dp-ranks`: the first engine's ring, while it lives."""
+    import gc
+
+    ev = host_events
+    first, second = StepEventRecorder(capacity=8), StepEventRecorder(capacity=8)
+    assert ev.attach_host_events(first) is True
+    assert ev.attach_host_events(second) is False
+    ev.host_event("lease_renew", time.monotonic_ns() - 10, late_us=1, rtt_us=2)
+    assert first.total == 1 and second.total == 0
+    del first
+    gc.collect()
+    assert ev.attach_host_events(second) is True
+
+
+def test_step_events_0_turns_host_events_off(host_events, monkeypatch):
+    ev = host_events
+    monkeypatch.setenv("DYN_TPU_STEP_EVENTS", "0")
+    ev.host_event("ready")
+    assert ev._host_buffer == []
+    rec = StepEventRecorder.from_env()
+    ev.attach_host_events(rec)
+    ev.host_event("ready")
+    assert rec.snapshot() == [] and rec.total == 0
+
+
+def test_host_buffer_is_bounded(host_events):
+    ev = host_events
+    for i in range(ev._HOST_BUFFER_MAX + 50):
+        ev.host_event("gc_pause", 1, 2, gen=2, collected=i)
+    assert len(ev._host_buffer) == ev._HOST_BUFFER_MAX
+    assert ev._host_buffer[-1][3]["collected"] == ev._HOST_BUFFER_MAX - 1
+
+
+def test_gc_collect_is_one_gen2_pause_and_a_young_collection_none(
+        host_events):
+    import gc
+
+    ev = host_events
+    rec = StepEventRecorder(capacity=64)
+    ev.attach_host_events(rec)              # installs the callback too
+    assert ev._on_gc in gc.callbacks
+    was = gc.isenabled()
+    gc.disable()  # no collection of the interpreter's own between the two
+    try:
+        ev._gc_pending.clear()
+        n0 = rec.total
+        t0 = time.monotonic_ns()
+        gc.collect()
+        t1 = time.monotonic_ns()
+        (pause,) = rec.snapshot()[n0:]
+        assert pause[2] == "gc_pause" and pause[3]["gen"] == 2
+        assert "collected" in pause[3]
+        assert t0 <= pause[0] and pause[0] + pause[1] <= t1 and pause[1] > 0
+        n1 = rec.total
+        gc.collect(0)  # generation 0: under a millisecond, not recorded
+        young = [e for e in rec.snapshot()[n1:] if e[1] < 1_000_000]
+        assert young == []
+    finally:
+        if was:
+            gc.enable()
+
+
+def test_collection_inside_the_rings_lock_waits_for_a_safe_point(
+        host_events):
+    """A collection starts between any two bytecodes, inside `record()`'s
+    lock too: the callback takes no lock (it would wait for its own
+    thread), and the pause reaches the ring with the next record or dump."""
+    ev = host_events
+    rec = StepEventRecorder(capacity=16)
+    ev.attach_host_events(rec)
+    done = []
+
+    def collect_under_the_locks():
+        with ev._host_lock, rec._lock:
+            ev._on_gc("start", {})
+            ev._on_gc("stop", {"generation": 2, "collected": 7})
+        done.append(True)
+
+    t = threading.Thread(target=collect_under_the_locks, daemon=True)
+    t0 = time.monotonic_ns()
+    t.start()
+    t.join(timeout=10)
+    assert done, "the collector's callback waited for a lock its thread held"
+    assert rec.total == 0 and len(ev._gc_pending) == 1
+    rec.record("plan", waiting=0)           # the next record moves it on,
+    kinds = [e[2] for e in rec.snapshot()]  # before its own event
+    assert kinds == ["gc_pause", "plan"]
+    pause = rec.snapshot()[0]
+    assert pause[3] == {"gen": 2, "collected": 7}
+    assert t0 <= pause[0] <= pause[0] + pause[1] <= time.monotonic_ns()
+    # a poll from a watermark taken BEFORE the pause still gets it
+    w = rec.dump()["watermark_ns"]
+    ev._on_gc("start", {})
+    ev._on_gc("stop", {"generation": 2, "collected": 0})
+    assert [e["kind"] for e in rec.dump(since_ns=w)["events"]] == ["gc_pause"]
+    assert not ev._gc_pending
+
+
+def test_record_budget_holds_on_a_ring_that_took_host_events(host_events):
+    """The 5 µs budget of `record()`, on the ring the host events land on
+    (the one truth test a warm step pays: no pause is parked)."""
+    from dynamo_tpu.analysis import contracts
+
+    ev = host_events
+    budget = 5e-6 if contracts.checks_mode() == "off" else 100e-6
+    rec = StepEventRecorder(capacity=4096)
+    ev.attach_host_events(rec)
+    ev.host_event("program", rec.now() - 1000, fn="x")
+    # best of several short batches, as the flight-spill budget above:
+    # the budget is the code's cost, not that of what shares the core
+    batches, n = 10, 2_000
+    per_event = float("inf")
+    t = rec.now()
+    for _ in range(batches):
+        t0 = time.perf_counter()
+        for i in range(n):
+            rec.record("decode_block", t0_ns=t, rung=8, batch=4,
+                       build_us=3, dispatch_us=4)
+        per_event = min(per_event, (time.perf_counter() - t0) / n)
+    assert rec.total == batches * n + 1
+    assert per_event < budget, f"{per_event * 1e6:.2f}µs/event"
+
+
+async def test_warm_step_records_what_it_recorded_and_a_cold_one_compiled(
+        host_events):
+    """The first request's steps bear their programs (`program` events on
+    the engine's ring, `compiled` on the slices they fell into); an
+    identical second request adds no program and its step slices carry
+    exactly the attributes a step carried before host events existed."""
+    from dynamo_tpu.engine import EngineConfig, JaxEngine
+    from dynamo_tpu.models import init_params, tiny_config
+
+    cfg = tiny_config()
+    params = init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
+    engine = JaxEngine(
+        cfg, params,
+        EngineConfig(page_size=8, num_pages=64, max_num_seqs=2,
+                     max_prefill_tokens=64, max_model_len=128,
+                     enable_prefix_caching=False),
+        eos_token_ids=[], kv_dtype=jnp.float32,
+    )
+    assert host_events._host_ring() is engine.events
+
+    async def ask():
+        async for _ in engine.generate({
+            "token_ids": list(range(1, 20)),
+            "sampling_options": {"temperature": 0.0},
+            "stop_conditions": {"max_tokens": 3, "ignore_eos": True},
+        }):
+            pass
+
+    try:
+        await ask()
+        await ask()
+    finally:
+        await engine.shutdown()
+    # a request's last step slice is recorded after its last token is
+    # handed on, but before the pump admits the next request: split there
+    events = engine.events.snapshot()
+    first, second = [i for i, e in enumerate(events) if e[2] == "admit"]
+    cold, warm = events[first:second], events[second:]
+    programs = [e for e in cold if e[2] == "program"]
+    assert {"prefill_step", "decode_step"} <= {e[3]["fn"] for e in programs}
+    assert all(e[3]["role"] == "step" for e in programs if e[3]["fn"])
+    steps = [e for e in cold if e[2] in ("prefill_chunk", "decode_block")]
+    assert sum(e[3].get("compiled", 0) for e in steps) == len(programs)
+    # each program lies inside the slice that says it compiled
+    first = next(e for e in steps if "compiled" in e[3])
+    inside = [p for p in programs
+              if first[0] <= p[0] and p[0] + p[1] <= first[0] + first[1]]
+    assert len(inside) == first[3]["compiled"]
+    # warm: nothing born, and the slices are what they were
+    assert [e for e in warm if e[2] == "program"] == []
+    step_attrs = {"build_us", "dispatch_us", "fetch_us", "deliver_us"}
+    expected = {
+        "prefill_chunk": step_attrs | {
+            "batch", "tokens", "fused_blocks", "ctx", "pages", "bucket",
+            "attn", "overlapped", "head", "rid", "n_steps", "overlap_us"},
+        "decode_block": step_attrs | {
+            "rung", "n_steps", "blocks", "batch", "chain", "ctx", "pages",
+            "bucket", "attn", "rid"},
+    }
+    seen = {k: set() for k in expected}
+    for e in warm:
+        if e[2] in expected:
+            seen[e[2]] |= set(e[3])
+            assert set(e[3]) <= expected[e[2]], (e[2], set(e[3]))
+    assert seen == expected
+
+
+async def test_late_lease_renewal_is_a_host_event(host_events):
+    import asyncio
+
+    from dynamo_tpu.runtime import DistributedRuntime
+
+    ev = host_events
+    rec = StepEventRecorder(capacity=64)
+    ev.attach_host_events(rec)
+    rt = await DistributedRuntime.detached(lease_ttl=0.3)  # renews at 0.1 s
+    try:
+        await asyncio.sleep(0.25)             # two renewals on time
+        assert rec.kind_totals.get("lease_renew", 0) == 0
+        await asyncio.sleep(0.02)
+        time.sleep(0.2)                       # something holds the loop
+        await asyncio.sleep(0.05)
+        late = [e for e in rec.snapshot() if e[2] == "lease_renew"]
+        assert late, rec.snapshot()
+        _, dur, _, attrs = late[0]
+        assert attrs["late_us"] >= 50_000 or attrs["rtt_us"] >= 50_000
+        assert dur >= (attrs["late_us"] + attrs["rtt_us"]) * 1000 * 0.99
+    finally:
+        await rt.shutdown()
+
+
+def test_merged_timeline_draws_host_events_on_their_own_track(host_events):
+    from dynamo_tpu.runtime import timeline
+
+    ev = host_events
+    t = time.monotonic_ns()
+    ev.host_event("startup.weights", t - 8_000_000, t - 2_000_000, bytes=1)
+    rec = StepEventRecorder(capacity=8)
+    ev.attach_host_events(rec)
+    rec.record("prefill_chunk", t0_ns=t - 1_000_000, batch=1)
+    pids = {}
+    out = timeline.ring_to_chrome(rec.dump(), "worker", pids)
+    by = {e["name"]: e for e in out}
+    assert by["startup.weights"]["tid"] == timeline._HOST_TID
+    assert by["prefill_chunk"]["tid"] == timeline._RING_TID
+    # older than the ring, and still placed by its own time
+    assert by["startup.weights"]["ts"] < by["prefill_chunk"]["ts"]
+    assert by["startup.weights"]["dur"] == pytest.approx(6000.0)
+
+
+def test_host_event_kinds_are_the_kinds_the_package_records():
+    """`HOST_EVENT_KINDS` (the timeline's track choice, the readers' test
+    of a ring that has none) is every literal `host_event("kind", ...)`."""
+    import ast
+
+    from dynamo_tpu.runtime import events as ev
+
+    pkg = os.path.dirname(os.path.dirname(os.path.abspath(ev.__file__)))
+    called = set()
+    for dirpath, _, files in os.walk(pkg):
+        for name in files:
+            if not name.endswith(".py"):
+                continue
+            with open(os.path.join(dirpath, name)) as f:
+                tree = ast.parse(f.read())
+            for node in ast.walk(tree):
+                if (isinstance(node, ast.Call) and node.args
+                        and getattr(node.func, "id", None) == "host_event"
+                        and isinstance(node.args[0], ast.Constant)):
+                    called.add(node.args[0].value)
+    assert called == set(ev.HOST_EVENT_KINDS)

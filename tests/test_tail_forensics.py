@@ -260,3 +260,22 @@ def test_postmortem_smoke_over_synthetic_dump(tmp_path):
         capture_output=True, text=True, timeout=60,
     )
     assert probe.returncode == 0, probe.stderr
+
+
+def test_postmortem_report_takes_events_that_end_at_one_instant():
+    """The worker's `startup.serve` slice ends at the nanosecond of its
+    `ready` instant: the report orders by time and never compares events."""
+    sys.path.insert(0, os.path.join(ROOT, "scripts"))
+    try:
+        import postmortem
+    finally:
+        sys.path.remove(os.path.join(ROOT, "scripts"))
+    t = 5 * 10**9
+    dump = {"wall_ns": 0, "mono_ns": 0, "events": [
+        {"t_ns": t - 10**6, "dur_ns": 10**6, "kind": "startup.serve"},
+        {"t_ns": t, "dur_ns": 0, "kind": "ready", "model": "m"}]}
+    spans = [{"endTimeUnixNano": str(t), "service": "s", "name": n}
+             for n in ("a", "b")]
+    report, n = postmortem.last_seconds_report({"worker:1": dump}, spans)
+    assert "startup.serve" in report and "ready" in report
+    assert "worker:1: 2 event(s)" in report

@@ -124,6 +124,227 @@ def test_summary_and_reset_roundtrip():
     assert xla_ledger.entries() == [] and xla_ledger.last_entry() is None
 
 
+# -- a program's birth: stages on the ring's clock, handed to the sinks ------- #
+
+
+@pytest.fixture
+def program_sink():
+    got = []
+
+    def sink(t0_ns, t1_ns, attrs):
+        got.append((t0_ns, t1_ns, attrs))
+
+    was = xla_ledger._program_sink
+    xla_ledger.set_program_sink(sink)
+    yield got
+    xla_ledger.set_program_sink(was)
+
+
+def _births(got, fn):
+    return [g for g in got if g[2]["fn"] == fn]
+
+
+def test_first_call_leaves_one_timed_entry_and_reaches_the_sink_once(
+        program_sink):
+    import time
+
+    def bornfn(x):
+        return jnp.where(x > 0, x * 2, x).sum()
+
+    g = xla_ledger.ledgered_jit(bornfn, tags={"rung": 5}, name="born_prog")
+    t_before = time.monotonic_ns()
+    g(jnp.ones((6,), jnp.float32))
+    t_after = time.monotonic_ns()
+    (e,) = [x for x in xla_ledger.entries() if x.program == "born_prog"]
+    assert t_before <= e.t_ns <= t_after          # the ring's clock
+    assert e.trace_us > 0 and e.lower_us > 0 and e.compile_us >= 0
+    assert e.thread == threading.current_thread().name
+    ((t0, t1, attrs),) = _births(program_sink, "born_prog")  # exactly once
+    assert t0 == e.t_ns and t_before <= t0 < t1 <= t_after
+    assert attrs["tags"] == "rung=5" and len(attrs["sig"]) == 8
+    assert {k: attrs[k] for k in e.stages()} == e.stages()
+    # the stages are spans of one thread between the probe and the sink
+    assert sum(attrs[k] for k in ("trace_us", "lower_us", "compile_us")) \
+        * 1000 <= (t1 - t0) * 1.01 + 1_000_000
+    # a slot of the flight recorder is 256 bytes: the event fits
+    from dynamo_tpu.runtime.events import _REC_PAYLOAD_MAX, _encode_attrs
+
+    assert len(_encode_attrs(attrs)) + len("program") <= _REC_PAYLOAD_MAX
+
+
+def test_sig_tells_programs_apart_behind_a_long_common_head(program_sink):
+    """A step program's first leaves are the model's parameters: the
+    signature kept for reading is cut inside them, the hash is not."""
+    def headfn(params, x):
+        return sum(p.sum() for p in params) + x.sum()
+
+    g = xla_ledger.ledgered_jit(headfn, name="head_prog")
+    params = [jnp.ones((3, 3), jnp.float32)] * 40
+    g(params, jnp.ones((4,), jnp.float32))
+    g(params, jnp.ones((8,), jnp.float32))
+    a, b = [x for x in xla_ledger.entries() if x.program == "head_prog"]
+    assert a.signature == b.signature and "more" in a.signature
+    assert a.sig != b.sig
+    assert [g[2]["sig"] for g in _births(program_sink, "head_prog")] == [
+        a.sig, b.sig]
+
+
+def test_second_call_of_the_same_shapes_leaves_nothing(program_sink):
+    def warmfn2(x):
+        return x + 3
+
+    g = xla_ledger.ledgered_jit(warmfn2, name="warm_prog")
+    g(jnp.ones((5,), jnp.float32))
+    n_entries, n_sink = len(xla_ledger.entries()), len(program_sink)
+    events0 = xla_ledger.summary()["program_events"]
+    g(jnp.zeros((5,), jnp.float32))
+    assert len(xla_ledger.entries()) == n_entries
+    assert len(program_sink) == n_sink
+    assert xla_ledger.summary()["program_events"] == events0
+    assert len(_births(program_sink, "warm_prog")) == 1
+
+
+def test_disabled_ledger_leaves_no_entry_and_no_named_program(
+        monkeypatch, program_sink):
+    monkeypatch.setattr(xla_ledger, "_LEDGER_ON", False)
+
+    def offfn2(x):
+        return x * 5
+
+    n = len(xla_ledger.entries())
+    xla_ledger.ledgered_jit(offfn2, name="off_prog")(
+        jnp.ones((3,), jnp.float32))
+    assert len(xla_ledger.entries()) == n
+    assert _births(program_sink, "off_prog") == []
+
+
+def test_compile_outside_a_ledgered_function_is_an_unnamed_program(
+        program_sink):
+    s0 = xla_ledger.summary()
+    jax.jit(lambda x: jnp.tanh(x) @ x.T)(jnp.ones((7, 3), jnp.float32))
+    s1 = xla_ledger.summary()
+    born = s1["program_events"] - s0["program_events"]
+    small = s1["programs_sub_ms"] - s0["programs_sub_ms"]
+    # every backend compile is an event or in the count of small ones
+    assert born + small == s1["backend_compiles"] - s0["backend_compiles"]
+    assert born + small >= 1
+    assert len(_births(program_sink, "")) == born
+    for _, _, attrs in _births(program_sink, ""):
+        assert "sig" not in attrs and "compile_us" in attrs
+        assert "trace_us" not in attrs  # its birth opens at its lowering
+
+
+def test_birth_under_a_millisecond_is_counted_not_handed_on(program_sink):
+    n0 = xla_ledger.summary()["programs_sub_ms"]
+    b = xla_ledger._open_birth()
+    b.lower_us = 300
+    xla_ledger._born(b, 0.0005)            # 0.8 ms in all, unledgered
+    assert xla_ledger.summary()["programs_sub_ms"] == n0 + 1
+    assert program_sink == []
+    b = xla_ledger._open_birth()
+    b.lower_us, b.load_us, b.hit = 200, 300, 1
+    xla_ledger._born(b, 0.0009)            # 1.1 ms: an event
+    ((_, _, attrs),) = program_sink
+    assert attrs == {"fn": "", "role": attrs["role"], "lower_us": 200,
+                     "compile_us": 600, "load_us": 300, "hit": 1}
+
+
+def test_function_traced_inside_another_reaches_no_sink(program_sink):
+    def innerfn(x):
+        return x * 2
+
+    inner = xla_ledger.ledgered_jit(innerfn, name="inner_prog")
+
+    def outerfn(x):
+        return inner(x) + 1
+
+    xla_ledger.ledgered_jit(outerfn, name="outer_prog")(
+        jnp.ones((9,), jnp.float32))
+    assert len(_births(program_sink, "outer_prog")) == 1
+    assert _births(program_sink, "inner_prog") == []
+    (e,) = [x for x in xla_ledger.entries() if x.program == "inner_prog"]
+    assert e.t_ns > 0 and e.compile_us is None  # inlined: never compiled
+
+
+def test_traced_but_never_compiled_entry_does_not_name_the_next_program(
+        program_sink):
+    def shapefn(x):
+        return x.sum()
+
+    jax.eval_shape(xla_ledger.ledgered_jit(shapefn, name="shape_prog"),
+                   jnp.ones((4,), jnp.float32))
+    jax.jit(lambda x: jnp.cos(x) * 11)(jnp.ones((4,), jnp.float32))
+    assert _births(program_sink, "shape_prog") == []
+    (e,) = [x for x in xla_ledger.entries() if x.program == "shape_prog"]
+    assert e.trace_us is not None and e.compile_us is None
+
+
+def test_births_between_counts_the_calling_threads_programs_once(
+        program_sink):
+    """A step slice asks for the programs its thread bore in its build and
+    dispatch.  A prefill step in flight (N) is recorded after the NEXT
+    step (N+1) was dispatched: N must not take N+1's programs."""
+    ms = 1_000_000
+    xla_ledger._tls.born = []
+    t0 = 50 * ms
+    for t in (9, 31):                       # born in N+1's dispatch (8-60)
+        b = xla_ledger._open_birth()
+        b.t_ns, b.lower_us = t0 + t * ms, 2000
+        xla_ledger._born(b, 0.01)
+    assert len(program_sink) == 2
+    assert xla_ledger.births_between(t0, t0 + 5 * ms) == 0        # N
+    assert xla_ledger.births_between(t0 + 6 * ms, t0 + 60 * ms) == 2  # N+1
+    assert xla_ledger.births_between(t0 + 6 * ms, t0 + 60 * ms) == 0  # once
+    assert xla_ledger._tls.born == []
+    # another thread's programs are its own: never counted here, and no
+    # thread that never asks holds anything another thread looks at
+    seen = []
+
+    def other():
+        b = xla_ledger._open_birth()
+        b.t_ns, b.lower_us = t0 + 70 * ms, 2000
+        xla_ledger._born(b, 0.01)
+        seen.append(xla_ledger.births_between(t0 + 61 * ms, t0 + 80 * ms))
+
+    th = threading.Thread(target=other)
+    th.start()
+    th.join(timeout=10)
+    assert seen == [1]
+    assert xla_ledger.births_between(t0 + 61 * ms, t0 + 80 * ms) == 0
+    # a program born before the span asked for is forgotten, not kept
+    b = xla_ledger._open_birth()
+    b.t_ns, b.lower_us = t0 + 81 * ms, 2000
+    xla_ledger._born(b, 0.01)
+    assert xla_ledger.births_between(t0 + 90 * ms, t0 + 95 * ms) == 0
+    assert xla_ledger._tls.born == []
+    # bounded on a thread that never asks
+    for i in range(xla_ledger._BORN_MAX + 10):
+        b = xla_ledger._open_birth()
+        b.lower_us = 2000
+        xla_ledger._born(b, 0.01)
+    assert len(xla_ledger._tls.born) == xla_ledger._BORN_MAX
+    xla_ledger._tls.born = []
+
+
+def test_format_shows_the_stages():
+    def fmtfn(x):
+        return x - 1
+
+    xla_ledger.ledgered_jit(fmtfn)(jnp.ones((2,), jnp.float32))
+    text = [x for x in xla_ledger.entries()
+            if x.fn == fmtfn.__qualname__][-1].format()
+    assert " trace=" in text and " lower=" in text and " compile=" in text
+    assert text.count("ms") >= 3
+
+
+def test_summary_keeps_the_keys_the_benchmark_reads():
+    s = xla_ledger.summary()
+    assert {"cache_hits", "cache_misses", "backend_compiles",
+            "backend_compile_seconds", "program_events",
+            "programs_sub_ms"} <= set(s)
+    assert not hasattr(xla_ledger, "backend_compiles_total")
+
+
 # -- transfer guard ---------------------------------------------------------- #
 
 
