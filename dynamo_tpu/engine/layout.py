@@ -95,14 +95,19 @@ class Layout:
         self.pool_ranks = self.dp * self.sp if self.pooled else 1
         self.attn_impl = resolve_attention_impl(
             cfg.attention_impl, meshed=self.mesh is not None)
-        if model_cfg.is_latent and self.attn_impl != "xla":
-            # the kernels read [n_kv, hd] pages of k and v
-            if cfg.attention_impl == "pallas":
-                raise ValueError("attention_impl='pallas': no Pallas kernel "
-                                 "reads latent pages yet")
+        if (model_cfg.is_latent and self.attn_impl != "xla"
+                and jax.default_backend() != "tpu"):
+            # a latent model's PREFILL has a kernel over its pages
+            # (`ops.pallas_latent_attention`), chosen a trace by shapes on
+            # a single-device TPU engine; it is a TPU program, so an engine
+            # elsewhere keeps XLA's form whatever was asked for.  Its
+            # decode steps and blocks are XLA's everywhere
+            # (`ops.paged_attention.LATENT_DECODE_XLA`, noted a trace)
             self.attn_impl = "xla"
             xla_ledger.note_path_choice(
-                "attention_impl", "xla", "latent pages: no kernel reads them",
+                "attention_impl", "xla",
+                f"latent pages: backend is {jax.default_backend()!r}, the "
+                "kernel over them is a TPU program",
                 requested=cfg.attention_impl)
         self._prefill_steps: Dict[tuple, Callable] = {}
         self._decode_steps: Dict[tuple, Callable] = {}

@@ -33,20 +33,22 @@ TOPLP = 20
 _BLOCK_KV_BYTE_BUDGET = 2 << 30
 
 
-def _decode_path(attn_impl: str, kv, page_table, n_steps: int) -> str:
+def _decode_path(attn_impl: str, kv, page_table, n_steps: int,
+                 latent: bool = False) -> str:
     """"block" (`decode_block_scan`: one pool gather per block) or
     "per-step" (a scan of `forward_decode`) for one traced decode step,
     noted in the compile ledger with the reason — the Pallas decode kernel
     reads pages itself and needs the per-step write-first layout, and a
     block whose gathered KV would pass the byte budget stays per-step."""
-    from ..ops.paged_attention import _adapt
+    from ..ops.paged_attention import LATENT_DECODE_XLA, _adapt
 
     # both planes' rows (a latent pool's differ in width)
     blk_bytes = sum(p.shape[0] * page_table.shape[0]
                     * page_table.shape[1] * p.shape[2]
                     * p.shape[3] * p.shape[4] * p.dtype.itemsize
                     for p in (kv.k, kv.v))
-    if _adapt(attn_impl, page_table, kv.k.shape[2]) == "pallas":
+    if _adapt(attn_impl, page_table, kv.k.shape[2],
+              only_xla=LATENT_DECODE_XLA if latent else "") == "pallas":
         path, why = "per-step", "pallas decode kernel (write-first layout)"
     elif blk_bytes > _BLOCK_KV_BYTE_BUDGET:
         path, why = "per-step", (
@@ -297,7 +299,8 @@ def decode_body(cfg: ModelConfig, n_steps: int, max_valid_pos: int,
         rope_off = rope[0] if rope else None
         if not penalized:
             counts = None
-        if _decode_path(attn_impl, kv, page_table, n_steps) == "block":
+        if _decode_path(attn_impl, kv, page_table, n_steps,
+                        cfg.is_latent) == "block":
             return block_scan(params, kv, tokens, positions, counters,
                               counts, page_table, samp, seeds, rope_off)
 
@@ -474,7 +477,8 @@ def decode_body_cc(cfg: ModelConfig, n_steps: int, max_valid_pos: int,
         act = act | (chunk_rem > 0)
         tokens = jnp.where(chunk_rem > 0, chunk_toks[:, 0], tokens)
 
-        if _decode_path(attn_impl, kv, page_table, n_steps) == "block":
+        if _decode_path(attn_impl, kv, page_table, n_steps,
+                        cfg.is_latent) == "block":
             return block_scan(params, kv, tokens, positions, counters,
                               counts, act, budget, stops, page_table,
                               samp, seeds, chunk_toks, chunk_rem,

@@ -34,7 +34,7 @@ from jax.sharding import PartitionSpec as P
 from ..ops.latent_attention import (
     decode_parts,
     latent_attention,
-    prefill_parts,
+    prefill_attention as latent_prefill_attention,
     rows_of,
 )
 from ..ops import hyper_connections as hc
@@ -888,16 +888,16 @@ def _latent_layer(lp, x, cfg, attend, chunk_lens=None, moe_stats=False):
 
 def _layer_prefill_latent(lp, kv, layer, x, positions, page_table,
                           prefix_lens, chunk_lens, cfg, inv_freq, rope_scale,
-                          moe_stats):
+                          moe_stats, attn_impl="xla"):
     """`_layer_prefill` over latent pages: -> (x, (k_pe, c_kv, *stats)),
     the chunk's own cache rows as the pool stores them [B, S, tiles, 128]."""
     def attend(u):
         q_abs, q_pe, k_pe, c_kv = _latent_qkv(lp, u, positions, cfg,
                                               inv_freq, rope_scale)
-        return latent_attention(
-            q_abs, q_pe, prefill_parts(kv.k, kv.v, k_pe, c_kv, page_table,
-                                       prefix_lens, chunk_lens, layer),
-            cfg.latent_softmax_scale), k_pe, c_kv
+        return latent_prefill_attention(
+            q_abs, q_pe, k_pe, c_kv, kv.k, kv.v, page_table, prefix_lens,
+            chunk_lens, cfg.latent_softmax_scale, impl=attn_impl,
+            layer=layer), k_pe, c_kv
 
     h, k_pe, c_kv, *st = _latent_layer(lp, x, cfg, attend, chunk_lens,
                                        moe_stats)
@@ -938,7 +938,7 @@ def _layer_prefill(
     if cfg.is_latent:
         return _layer_prefill_latent(
             lp, kv, layer, x, positions, page_table, prefix_lens, chunk_lens,
-            cfg, inv_freq, rope_scale, moe_stats)
+            cfg, inv_freq, rope_scale, moe_stats, attn_impl)
     B, S, h = x.shape
     nh, nkv, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim_
 
@@ -1242,11 +1242,11 @@ def decode_layers(
     # Pallas kernel (long contexts under "adaptive") reads pages and has
     # no self column, so it keeps the write-first layout.  The choice is
     # static per trace (table width bucket).
-    from ..ops.paged_attention import _adapt
+    from ..ops.paged_attention import LATENT_DECODE_XLA, _adapt
 
-    defer = _adapt(attn_impl, page_table, kv.k.shape[2]) != "pallas"
-    if cfg.is_latent and not defer:
-        raise ValueError("no Pallas kernel reads latent pages")
+    defer = _adapt(
+        attn_impl, page_table, kv.k.shape[2],
+        only_xla=LATENT_DECODE_XLA if cfg.is_latent else "") != "pallas"
     ropes = _rope_xs(cfg)
 
     def body(carry, xs):

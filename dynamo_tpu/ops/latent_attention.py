@@ -20,9 +20,13 @@ up-projection of the whole table every step and layer.
 One core serves every step kind: `parts` are the key sets a step attends
 to, in order (prefill: the table's pages, the chunk itself; a decode step:
 the pages, its own token; a decode block: the pages, the block's ring, its
-own token), each with the mask of what a query may see of it.  No kernel
-reads latent pages yet (ROADMAP), so an engine over them runs "xla"
-attention whatever was asked for (`engine/layout.py`).
+own token), each with the mask of what a query may see of it.  That XLA
+form is the oracle and the path of every decode trace, of the CPU and of a
+mesh.  A PREFILL chunk on a single-device TPU engine takes the streaming
+Pallas kernel instead (`pallas_latent_attention`: pages read in place, no
+scores in HBM, no key tile scored that the chunk cannot see), chosen a trace
+by `prefill_attention` below as `paged_attention.prefill_attention` chooses
+the per-head kernel; the absorbed decode kernel is not written (ROADMAP M6).
 """
 
 from __future__ import annotations
@@ -32,7 +36,7 @@ from typing import Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from .paged_attention import NEG_INF
+from .paged_attention import NEG_INF, _adapt, _latent_prefill_rule
 
 # f32 scores one head block may materialise ([B, heads, S, keys]): past it
 # the heads are walked in blocks (the keys are shared by all heads, so a
@@ -133,6 +137,40 @@ def prefill_parts(k_pool, v_pool, kpe_new, lat_new, page_table, prefix_lens,
     j = jnp.arange(S)[None, None, :]
     new_ok = (j <= i) & (j < chunk_lens[:, None, None])
     return [(kpe_pre, lat_pre, pre_ok), (kpe_new, lat_new, new_ok)]
+
+
+def prefill_attention(q_abs, q_pe, kpe_new, lat_new, k_pool, v_pool,
+                      page_table, prefix_lens, chunk_lens, scale: float,
+                      impl: str = "xla", layer=None) -> jax.Array:
+    """A prefill chunk's attention over its cached prefix and itself:
+    queries [B, S, H, rank] / [B, S, H, pe], the chunk's own rows [B, S, pe]
+    / [B, S, rank], one layer's pools or the whole pools with `layer`.
+    `impl` as `paged_attention.prefill_attention` takes it; the choice is
+    noted a trace at the same site, so a step's slice says which program
+    its shape got.  Returns [B, S, H, rank] in q's dtype."""
+    B, S, H, rank = q_abs.shape
+    page = k_pool.shape[-3]
+
+    def rule(ctx):
+        from .pallas_latent_attention import latent_query_tile
+
+        return _latent_prefill_rule(B, S, ctx, latent_query_tile(
+            S, H, rank, q_pe.shape[-1], page, k_pool.shape[-2:],
+            v_pool.shape[-2:], q_abs.dtype, k_pool.dtype))
+
+    impl = _adapt(impl, page_table, page, rule, site="prefill_attention",
+                  chunk=S)
+    if impl == "pallas":
+        from .pallas_latent_attention import prefill_latent_attention_pallas
+
+        with jax.named_scope("attn.core"):
+            return prefill_latent_attention_pallas(
+                q_abs, q_pe, kpe_new, lat_new, k_pool, v_pool, page_table,
+                prefix_lens, chunk_lens, scale, layer=layer)
+    return latent_attention(
+        q_abs, q_pe, prefill_parts(k_pool, v_pool, kpe_new, lat_new,
+                                   page_table, prefix_lens, chunk_lens, layer),
+        scale)
 
 
 def decode_parts(k_pages, v_pages, kpe_self, lat_self, page_table, seq_lens):

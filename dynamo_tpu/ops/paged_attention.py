@@ -114,15 +114,59 @@ def _prefill_rule(chunk: int, ctx: int, query_block):
                    f"{PALLAS_MIN_PREFILL_SCORES}")
 
 
+# A prefill chunk over LATENT pages takes the kernel
+# (`ops.pallas_latent_attention`) from this many tokens of TABLE on, and a
+# chunk of at least `LATENT_PALLAS_MIN_CHUNK` tokens under any table.  Whole
+# `prefill_step` programs of both latent configurations (64 and 32 heads)
+# timed on a v5e, both forms forced, every table full (PERF.md, PR 43:
+# chunks 16-512, 1 and 4 rows, tables of 32-256 pages): XLA gathers and
+# scores the TABLE whatever the prefix, the kernel streams the prefix that
+# is there, so from 2048 tokens of table the kernel wins or ties every shape
+# (a 512-token chunk by 4.9 / 2.9 ms a step of 54 / 44 at 2048, 8.7 / 4.3 at
+# 4096; four 64-token rows by 2.7 / 1.1 and 5.2 / 3.7; a lone 16-token chunk
+# by 0.05-0.5), and a 512-token chunk wins under the short tables too (1.6
+# -4.1 / 1.8-3.1 ms: half of the chunk's own scores lie above the diagonal).
+# Below both, a 64-256-token chunk under 512-1024 tokens of table, XLA wins
+# or ties by 0.0-0.7 ms (a streamed tile is 512 keys, half of them masked
+# there).
+LATENT_PALLAS_MIN_TABLE_TOKENS = 2048
+LATENT_PALLAS_MIN_CHUNK = 512
+
+
+def _latent_prefill_rule(batch: int, chunk: int, ctx: int, query_tile):
+    """(choice, why) for a prefill step of `batch` rows of `chunk` tokens
+    under `ctx` tokens of latent table; `query_tile` is the kernel's own
+    answer to whether it has a tile for this chunk (None: none: a chunk of
+    no whole sublane tiles, a pool dtype it cannot read, too much VMEM)."""
+    if query_tile is None:
+        return "xla", f"the kernel has no query tile for a {chunk}-token chunk"
+    shape = f"latent pages: {batch} x {chunk} queries under a {ctx}-token table"
+    if chunk >= LATENT_PALLAS_MIN_CHUNK:
+        return "pallas", f"{shape}: chunk >= {LATENT_PALLAS_MIN_CHUNK}"
+    if ctx >= LATENT_PALLAS_MIN_TABLE_TOKENS:
+        return "pallas", f"{shape}: table >= {LATENT_PALLAS_MIN_TABLE_TOKENS}"
+    return "xla", (f"{shape}: chunk < {LATENT_PALLAS_MIN_CHUNK} and table < "
+                   f"{LATENT_PALLAS_MIN_TABLE_TOKENS}")
+
+
+# Why every DECODE trace over latent pages is XLA's, whatever was asked for:
+# `_adapt(..., only_xla=...)` notes it a trace.
+LATENT_DECODE_XLA = ("latent pages: no kernel of the absorbed decode form "
+                     "yet (prefill has one)")
+
+
 def _adapt(impl: str, page_table: jax.Array, page_size: int,
            rule=_decode_rule, site: str = "decode_attention",
-           chunk: int = 1) -> str:
+           chunk: int = 1, only_xla: str = "") -> str:
     """Resolve "adaptive" for one trace: `rule(table tokens)` says which
     program and why.  Runs at trace time only, so the ledger note below is
     once per compiled variant: which attention program a (batch, chunk,
-    table-width) step got, and why."""
+    table-width) step got, and why.  `only_xla`: why this trace has no
+    kernel to choose, whatever `impl` says."""
     ctx = page_table.shape[1] * page_size
-    if impl != "adaptive":
+    if only_xla:
+        choice, why = "xla", only_xla
+    elif impl != "adaptive":
         choice, why = impl, f"attention_impl={impl}"
     else:
         choice, why = rule(ctx)

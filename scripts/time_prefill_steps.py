@@ -4,7 +4,10 @@ flat engine's jitted step over random weights at the configuration's
 widths, every row a full table of its own pages, median of N runs after a
 warm-up.  One JSON line per (moe_impl, attention, batch, table, chunk,
 head): `head` 1 is a step in which a row samples (a prompt's last chunk),
-0 one in which none does, which skips the output head.
+0 one in which none does, which skips the output head; `attn` is the
+attention program the trace of that shape noted ("pallas" | "xla": under
+`--attention auto` the rule's answer, and what a forced form came to, as on
+the CPU, where a latent model keeps XLA's).
 
     python scripts/time_prefill_steps.py benchmark/configs/<config>.json \\
         [--chunks 512,256,64,16] [--table-pages 256[,128,64]] \\
@@ -51,6 +54,7 @@ def main():
     import jax.numpy as jnp
     import numpy as np
 
+    from dynamo_tpu.analysis import xla_ledger
     from dynamo_tpu.engine import EngineConfig
     from dynamo_tpu.engine.layout import Layout
     from dynamo_tpu.models import KVCache, ModelConfig, init_params
@@ -108,6 +112,9 @@ def main():
                 continue
             print(json.dumps(dict(
                 shape, device=dev.device_kind, prefix=int(prefix[0]),
+                attn=xla_ledger.path_choice(
+                    "prefill_attention", batch=batch, chunk=chunk,
+                    table_tokens=table_pages * 16),
                 ms_median=statistics.median(times), ms_min=min(times),
                 runs=len(times))), flush=True)
         del kv

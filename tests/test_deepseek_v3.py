@@ -608,10 +608,9 @@ async def test_share_steps_carry_their_stats_and_counters(cfg, params):
     ({"fuse_projections": True}, "fuse_projections"),
     ({"quantization": "int8"}, "int8"),
     ({"park_max_pages": 8}, "parking"),
-    ({"attention_impl": "pallas"}, "latent pages"),
     ({"tiered": object()}, "KVBM"),
 ], ids=["tp", "pp", "sp", "partitioned-pool", "fused-projections", "int8",
-        "parking", "pallas", "kvbm-tier"])
+        "parking", "kvbm-tier"])
 def test_paths_that_cannot_carry_latent_pages_refuse_the_family(cfg, params,
                                                                 how, match):
     from dynamo_tpu.parallel import ParallelConfig
@@ -681,7 +680,8 @@ def lowering_digest(model_cfg, by_rows=False):
 
 @pytest.mark.parametrize("by_rows", [False, True],
                          ids=["every-row", "by-rows"])
-@pytest.mark.parametrize("family", ["qwen2", "smallthinker", "deepseek_v3"])
+@pytest.mark.parametrize("family", ["qwen2", "smallthinker", "deepseek_v3",
+                                    "xing4_0"])
 def test_a_model_without_leading_dense_layers_traces_what_it_traced(family,
                                                                     by_rows):
     """The prefill step of a tiny Qwen2 (dense, biased) and of a tiny
@@ -699,14 +699,23 @@ def test_a_model_without_leading_dense_layers_traces_what_it_traced(family,
     With it (`by-rows`: what a flat engine serves) the text gains the
     operand and one conditional around the head; those three digests, under
     `<family>.by_rows`, were taken on PR 41's tree by `lowering_digest(cfg,
-    by_rows=True)` and hold the served step still from here on."""
+    by_rows=True)` and hold the served step still from here on.
+
+    Since PR 43 a latent model's prefill attention is chosen a trace
+    (`ops.latent_attention.prefill_attention`): off the chip, and wherever
+    "xla" is asked for, it is the form it was, to the letter.  The tiny
+    xing4_0's two digests (latent pages around a residual of streams) were
+    taken on PR 42's tree."""
     from test_smallthinker import TINY as ST
+    from test_xing4_0 import TINY as XING
 
     model_cfg = {"qwen2": lambda: tiny_config(attention_bias=True,
                                               model_type="qwen2"),
                  "smallthinker": lambda: ModelConfig.from_hf_config(ST),
                  "deepseek_v3": lambda: ModelConfig.from_hf_config(
-                     TINY, name="tiny-deepseek-v3")}[family]()
+                     TINY, name="tiny-deepseek-v3"),
+                 "xing4_0": lambda: ModelConfig.from_hf_config(
+                     XING, name="tiny-xing4-0")}[family]()
     with open(GOLDEN) as f:
         golden = json.load(f)
     assert lowering_digest(model_cfg, by_rows) == golden[
@@ -857,5 +866,97 @@ def test_share_readers_reduce_a_traced_window(bench_lib, tmp_path,
             other = dict(run, config=json.load(f))
         latent_trace._MEMO.clear()
         assert all(read(other) is None for read in readers.values())
+    finally:
+        sys.path.remove(BENCH)
+
+
+@pytest.mark.parametrize("tokens,ctx,form", [
+    (256, 256, "absorbed"), (512, 512, "up_projected"),
+    (512, 1024, "up_projected"),
+    (512, 1536, "up_projected"), (16, 2048, "absorbed"),
+    (48, 1100, "absorbed")])
+def test_latent_attn_roofline_counts_the_cheaper_form(bench_lib, tokens, ctx,
+                                                      form):
+    """The attention core's operations over the pairs a causal chunk can
+    see, as the lesser of the absorbed and the up-projected form: at 64
+    heads the absorbed form is cheaper until a key is seen by some 233
+    queries on average."""
+    sys.path.insert(0, BENCH)
+    try:
+        from lib import checkpoint
+
+        mod = checkpoint.load_module("layer_metrics",
+                                     "kernel.latent_attn_roofline")
+    finally:
+        sys.path.remove(BENCH)
+    pairs = tokens * (ctx - tokens) + tokens * (tokens + 1) // 2
+    forms = {"absorbed": 2 * pairs * 64 * (2 * 512 + 64),
+             "up_projected": (2 * pairs * 64 * (128 + 64 + 192)
+                              + 2 * ctx * 64 * (128 + 192) * 512)}
+    assert mod.core_ops(published()["model"], tokens, ctx) == forms[form]
+    assert forms[form] == min(forms.values())
+
+
+def test_latent_attn_roofline_reduces_a_traced_window(bench_lib, tmp_path,
+                                                      monkeypatch):
+    """Two one-sequence steps (512 tokens with nothing cached, 16 tokens
+    behind 1,008) whose latent-attention ops (a projection fusion and the
+    kernel's custom call, placed by its `attn.core` name) take 12 ms: the
+    reading is the steps' operations at the bf16 peak over those 12 ms;
+    a shared step, a run without a trace and another family read nothing."""
+    latent_trace, _ = bench_lib
+    config = published()
+    ms = 1_000_000
+    names = ["%while.5 = (s32[]) while(%t)",
+             "%fusion.12 = bf16[1,512,576]{2,1,0} fusion(bf16[6,7168,576]"
+             "{2,1,0} %q)",
+             "%attn.core.7 = bf16[1,32768,512]{2,1,0} custom-call(s32[1,32]"
+             "{1,0} %table, bf16[1,32768,512]{2,1,0} %q)",
+             "%fusion.30 = bf16[1,512,18432]{2,1,0} fusion(bf16[1,7168,18432]"
+             "{2,1,0} %d)"]
+    assert latent_trace.group_of(names[2], config["model"]) == "latent_attn"
+    t0, t1 = 100 * ms, 200 * ms
+    ops = [[0, t0, 40 * ms], [1, t0 + 1 * ms, 4 * ms],
+           [2, t0 + 6 * ms, 5 * ms], [3, t0 + 20 * ms, 9 * ms],
+           [0, t1, 20 * ms], [1, t1 + 1 * ms, 1 * ms],
+           [2, t1 + 3 * ms, 2 * ms]]
+    path = tmp_path / "trace.json"
+    path.write_text(json.dumps({"names": names, "planes": [{
+        "name": "/device:TPU:0", "lines": [
+            {"name": "XLA Ops", "events": ops}]}]}))
+    sys.path.insert(0, BENCH)
+    try:
+        from lib import checkpoint, moe_trace
+
+        monkeypatch.setattr(moe_trace, "trace_path", lambda: str(path))
+        steps = [{"kind": "prefill_chunk", "t_ns": t0 - 3 * ms,
+                  "dur_ns": 50 * ms, "batch": 1, "tokens": 512, "ctx": 512},
+                 {"kind": "prefill_chunk", "t_ns": t1 - 3 * ms,
+                  "dur_ns": 30 * ms, "batch": 1, "tokens": 16, "ctx": 1024}]
+        run = {"t0": 0.0, "t1": 1.0, "events": steps, "config": config,
+               "peaks": PEAKS, "metrics0": {}, "metrics1": {},
+               "trace": {"modules": [[
+                   (t0, t0 + 40 * ms, "jit_prefill_step(1)"),
+                   (t1, t1 + 20 * ms, "jit_prefill_step(1)")]]}}
+        mod = checkpoint.load_module("layer_metrics",
+                                     "kernel.latent_attn_roofline")
+        latent_trace._MEMO.clear()
+        model = config["model"]
+        projections = 132_579_328  # a layer's five projections' weights
+        want_ops = 7 * (2 * 528 * projections
+                        + mod.core_ops(model, 512, 512)
+                        + mod.core_ops(model, 16, 1024))
+        got = mod.read(run)
+        assert abs(got - 100 * want_ops / 197e12 / 12e-3) < 1e-6
+        assert 0 < got < 100
+        shared = dict(run, events=[dict(e, batch=2) for e in steps])
+        latent_trace._MEMO.clear()
+        assert mod.read(shared) is None
+        assert mod.read(dict(run, trace=None)) is None
+        with open(os.path.join(BENCH, "configs",
+                               "qwen2.5-7b-h14.json")) as f:
+            other = dict(run, config=json.load(f))
+        latent_trace._MEMO.clear()
+        assert mod.read(other) is None
     finally:
         sys.path.remove(BENCH)

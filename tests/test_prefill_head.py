@@ -249,7 +249,17 @@ async def test_a_mixed_step_still_samples_a_prompts_last_chunk(sampling):
     """The operand reaches `mixed_body`: while one request decodes, a
     40-token prompt's chunks ride mixed steps, of which the mid-prompt ones
     say `head` 0 and the last `head` 1, and both streams are those of a run
-    that heads every step."""
+    that heads every step.
+
+    Token for token; the logprobs to float32 rounding and not to the bit
+    (PR 43): WHICH decode token of the first stream the second prompt's
+    chunks join is the event loop's timing against the step thread, not the
+    program's, and a token computed on the decode side of a mixed step and
+    the same token computed by a decode block are two programs, whose
+    logprobs differ in the last place (-4.5032697 against -4.5032701 on one
+    of 24 tokens, the ids alike).  The engine and its twin race that
+    separately, so a comparison to the bit failed about every other run,
+    alone on a quiet machine too."""
     first = [2 + (3 * i) % 250 for i in range(12)]
     second = [1 + (7 * i) % 250 for i in range(40)]
 
@@ -266,7 +276,9 @@ async def test_a_mixed_step_still_samples_a_prompts_last_chunk(sampling):
     engine = tiny_engine(mixed_prefill_tokens=16)
     parent = every_row_samples(tiny_engine(mixed_prefill_tokens=16))
     got, want = await serve(engine), await serve(parent)
-    assert got == want
+    for (toks, logps, tops), (w_toks, w_logps, w_tops) in zip(got, want):
+        assert toks == w_toks and tops == w_tops
+        np.testing.assert_allclose(logps, w_logps, rtol=1e-5, atol=1e-5)
     assert len(got[1][0]) == 4
     heads = [e["head"] for e in slices(engine, "mixed_step")]
     assert heads.count(1) == 1 and heads.count(0) >= 1, heads

@@ -398,3 +398,103 @@ def test_expert_prefill_step_reads_the_expert_stacks_in_place(one_chip):
             made.append(line.strip()[:120])
     assert made == []
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+# -- latent pages (the deepseek_v3 and xing4_0 cells) ------------------------------- #
+
+@pytest.mark.parametrize("heads,layers,batch,chunk,table_pages", [
+    (64, 7, 1, 512, 128), (64, 7, 4, 64, 256), (64, 7, 1, 16, 64),
+    (32, 8, 1, 512, 128), (32, 8, 4, 64, 256), (32, 8, 1, 16, 64),
+], ids=["h64-512x128", "h64-4x64x256", "h64-16x64", "h32-512x128",
+        "h32-4x64x256", "h32-16x64"])
+def test_latent_prefill_kernel_compiles_at_the_cells_shapes(
+        one_chip, heads, layers, batch, chunk, table_pages):
+    """The latent cells' attention at both deployments' head counts (rank
+    512, rotary key 64, bf16), the whole 7- and 8-layer pools read by
+    (layer, page) as they are stored, [2, 128] and [4, 128] planes: the
+    512-token chunk, the `[4, 64]` shared step and a lone 16-token chunk.
+    The query tile is 2048 rows at both (32 and 64 tokens), and nothing
+    outside the kernel touches the pools: no temporary at all."""
+    from dynamo_tpu.models.config import CacheSpec
+    from dynamo_tpu.ops.pallas_latent_attention import (
+        latent_query_tile,
+        prefill_latent_attention_pallas,
+    )
+
+    kd, vd = CacheSpec("latent", 1, 512, 64).plane_dims
+    bf = jnp.bfloat16
+    assert latent_query_tile(chunk, heads, 512, 64, PAGE, kd, vd, bf) == min(
+        chunk, 2048 // heads)
+    lens = _sds((batch,), jnp.int32, one_chip)
+
+    def fn(qa, qp, kn, ln, k, v, t, pre, cl, layer):
+        return prefill_latent_attention_pallas(
+            qa, qp, kn, ln, k, v, t, pre, cl, 0.1447, layer=layer)
+
+    compiled, secs = _compile(
+        fn, _sds((batch, chunk, heads, 512), bf, one_chip),
+        _sds((batch, chunk, heads, 64), bf, one_chip),
+        _sds((batch, chunk, 64), bf, one_chip),
+        _sds((batch, chunk, 512), bf, one_chip),
+        _sds((layers, POOL_PAGES, PAGE, *kd), bf, one_chip),
+        _sds((layers, POOL_PAGES, PAGE, *vd), bf, one_chip),
+        _sds((batch, table_pages), jnp.int32, one_chip), lens, lens,
+        _sds((), jnp.int32, one_chip))
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
+    assert secs < 60, f"latent prefill kernel took {secs:.1f}s to compile"
+
+
+@pytest.mark.parametrize("impl", ["pallas", "xla"])
+def test_latent_prefill_step_leaves_the_pool_where_it_is(one_chip,
+                                                         monkeypatch, impl):
+    """GigaChat's cell at two layers (one dense, one of experts), chunk 512
+    under 128 pages, the 12288-page pool donated: with the kernel no op
+    copies, slices or re-lays-out either pool or a layer's slab of it, no
+    score-shaped f32[1,64,512,*] array and no gathered table is left in the
+    program, and its temporaries are smaller than the XLA form's.  The
+    layout is told it sits on a TPU (off the chip a latent model keeps
+    "xla"); the test steers that, not an option of the program."""
+    import dataclasses
+    import json
+    import re
+
+    from dynamo_tpu.analysis import xla_ledger
+    from dynamo_tpu.models import ModelConfig
+
+    with open(os.path.join(os.path.dirname(__file__), os.pardir, "benchmark",
+                           "configs", "gigachat3.1-702b-ep16.json")) as f:
+        run = json.load(f)
+    cfg = dataclasses.replace(ModelConfig.from_hf_config(run["model"]),
+                              num_hidden_layers=2)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    step = flat_layout(cfg, impl).prefill_step(False, greedy=True)
+    monkeypatch.undo()
+    # `step_shapes` places by the mesh's specs, which refuse the family
+    on_chip = lambda x: _sds(x.shape, x.dtype, one_chip)  # noqa: E731
+    params = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16)))
+    kv = jax.tree.map(on_chip, jax.eval_shape(lambda: KVCache.create(
+        cfg, run["worker_flags"]["--num-pages"], PAGE, jnp.bfloat16)))
+    lens, f32 = _sds((1,), jnp.int32, one_chip), _sds((1,), jnp.float32,
+                                                      one_chip)
+    args = (params, kv, _sds((1, 512), jnp.int32, one_chip),
+            _sds((1, 128), jnp.int32, one_chip), lens, lens,
+            SamplingParams(f32, lens, f32, f32, f32),
+            _sds((1,), jnp.uint32, one_chip), lens,
+            _sds((1,), jnp.bool_, one_chip))
+    compiled = step.lower(*args).compile()
+    text = compiled.as_text()
+    kernel = impl == "pallas"
+    assert xla_ledger.path_choice("prefill_attention", batch=1, chunk=512,
+                                  table_tokens=128 * PAGE) == impl
+    assert ("tpu_custom_call" in text) is kernel
+    scores = re.findall(r"f32\[1,\d+,512,\d{4}\]", text)
+    gathered = re.findall(r"bf16\[1,2048,512\]", text)
+    assert bool(scores) is not kernel and bool(gathered) is not kernel
+    assert (pool_sized_movers(text, kv.k.shape)
+            + pool_sized_movers(text, kv.v.shape)) == []
+    mem = compiled.memory_analysis()
+    pools = sum(p.size * p.dtype.itemsize for p in (kv.k, kv.v))
+    assert mem.alias_size_in_bytes >= pools  # written in place
+    assert mem.temp_size_in_bytes < (300 << 20 if kernel else 400 << 20)
