@@ -22,6 +22,10 @@ class EngineConfig:
     page_size: int = 16  # tokens per page (= kv block size in the MDC)
     num_pages: int = 512  # pages in the device pool (incl. trash page 0)
     max_pages_per_seq: int = 64  # cap on context pages per sequence
+    # state slots beside the pages, for a model with state-space layers
+    # (`ModelConfig.state_spec`; unused otherwise), incl. trash slot 0: one
+    # a running sequence, the rest snapshots (`page_pool.StatePool`)
+    num_state_slots: int = 32
 
     # batching
     max_num_seqs: int = 8  # max concurrent sequences in decode

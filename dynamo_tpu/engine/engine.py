@@ -40,7 +40,7 @@ from .layout import Layout
 from .page_pool import KvEvent, NoPagesError
 from .scheduler import PrefillItem, SamplingOptions, Scheduler, Sequence, StepPlan
 from ..models.llama import (moe_stats_columns, moe_stats_width,
-                            require_plain_cache)
+                            require_no_state, require_plain_cache)
 from .steps import (
     _unpack_out,
     _unpack_out_cc,
@@ -285,7 +285,8 @@ class JaxEngine:
         if event_sink:
             self._extra_event_sinks.append(event_sink)
         self.pool = self.layout.make_pool(self._emit_event)
-        self.scheduler = Scheduler(self.cfg, self.pool)
+        self.scheduler = Scheduler(self.cfg, self.pool,
+                                   self.layout.make_state_pool())
         # preemption parking lot (overload control): batch-class victims
         # preempted mid-decode export byte-exact KV here and resume
         # through ordinary admission — docs/overload_control.md.  The
@@ -370,6 +371,8 @@ class JaxEngine:
         self.scheduler.events = self.events
         for p in getattr(self.pool, "pools", [self.pool]):
             p.events = self.events
+        if self.scheduler.state is not None:
+            self.scheduler.state.events = self.events
         # jax.profiler capture of the next N engine steps, armed by
         # DYN_TPU_XPROF_STEPS=N / DYN_TPU_XPROF_DIR (default profiles/) at
         # start-up or by `arm_xprof` while serving (the worker's
@@ -406,7 +409,7 @@ class JaxEngine:
         """The page pool by its one description (`ModelConfig.cache_spec`):
         the kind of pages, what a token leaves a layer, what it is stored
         as, and the pool's size (the worker's `CACHE` start-up line)."""
-        spec, L = self.model_cfg.cache_spec, self.model_cfg.num_hidden_layers
+        spec, L = self.model_cfg.cache_spec, self.model_cfg.num_kv_layers
         per_token = L * spec.bytes_per_token_layer(
             jnp.dtype(self._kv_dtype).itemsize)
         tokens = (self.layout.pool_ranks * self.cfg.num_pages
@@ -416,6 +419,23 @@ class JaxEngine:
                 "dtype": jnp.dtype(self._kv_dtype).name, "layers": L,
                 "bytes_per_token": per_token, "pool_tokens": tokens,
                 "pool_bytes": tokens * per_token}
+
+    def state_report(self) -> Optional[dict]:
+        """The state slots beside the pages by their one description
+        (`ModelConfig.state_spec`): the worker's `STATE` start-up line.
+        None for a model without state-space layers."""
+        spec = self.model_cfg.state_spec
+        if spec is None:
+            return None
+        per_slot = spec.bytes_per_slot(jnp.dtype(self._kv_dtype).itemsize)
+        slots = self.cfg.num_state_slots
+        return {"kind": "ssm", "layers": spec.layers,
+                "window": list(spec.window_dims),
+                "window_dtype": jnp.dtype(self._kv_dtype).name,
+                "state": list(spec.state_dims), "state_dtype": "float32",
+                "bytes_per_slot": per_slot, "slots": slots,
+                "pool_bytes": slots * per_slot,
+                "snapshot_every": self.scheduler.state.snapshot_every}
 
     def attach_connector(self, connector) -> None:
         """Attach a KVBM connector (kvbm.KvConnector shape: on_event /
@@ -729,6 +749,16 @@ class JaxEngine:
             m.moe_steps_total = self.moe_steps_total
             if self.model_cfg.hc_mult:
                 m.hc_res_err_ppm_max = self.hc_res_err_ppm_max
+        st = self.scheduler.state
+        if st is not None:  # state slots beside the pages
+            m.state_slots_total = st.num_slots - 1
+            m.state_slots_running = st.running
+            m.state_snapshots = st.snapshots
+            m.state_snapshot_stored_total = st.stored_total
+            m.state_snapshot_hits_total = st.hits_total
+            m.state_snapshot_evictions_total = st.evictions_total
+            m.state_hit_tokens_shortened_total = (
+                st.hit_tokens_shortened_total)
         if self.pool.ranks > 1:
             m.kv_usage_aggregate = self.pool.usage()
         if self.tiered is not None:
@@ -1301,14 +1331,28 @@ class JaxEngine:
         """Page-table batch, width bucketed to the longest sequence present
         (attention/gather cost scales with width, so short-context batches
         stay cheap).  Partitioned pools store LOCAL ids (each shard's page
-        0 is its own trash page)."""
+        0 is its own trash page).  For a model with state-space layers
+        `Layout.state_cols` more columns follow the pages': the state slots
+        a row reads and writes (`models.hybrid`), so that whatever builds
+        a table, pad rows and warm-up steps of zeros too, names them."""
         need = max((len(s.pages) for s in rows if s), default=1)
         width = bucket_for(max(need, 1), self.cfg.table_width_buckets)
-        table = np.zeros((len(rows), width), np.int32)
+        cols = self.layout.state_cols
+        table = np.zeros((len(rows), width + cols), np.int32)
         npp = self.cfg.num_pages
         for i, s in enumerate(rows):
             if s is None:
                 continue
+            if cols:
+                # [read, write, inside...]: the snapshot a sequence resumes
+                # from, else its own slot, else (nothing computed yet) no
+                # state at all; its own slot; the slots reserved for the
+                # snapshots inside its next chunk
+                table[i, width:width + 2] = (
+                    s.state_src or (s.state_slot if s.num_computed else 0),
+                    s.state_slot)
+                table[i, width + 2:width + 2 + len(s.state_inside)] = (
+                    s.state_inside)
             n = min(len(s.pages), width)
             if self.layout.pooled:
                 table[i, :n] = [p % npp for p in s.pages[:n]]
@@ -1435,10 +1479,12 @@ class JaxEngine:
                  width: int) -> str:
         """The attention program ("pallas" | "xla") the step of this shape
         was traced into (`ops.paged_attention._adapt` notes it per shape);
-        the configured implementation where no choice was noted."""
+        the configured implementation where no choice was noted.  `width`
+        is the step's table's, its state columns included."""
+        pages = width - self.layout.state_cols
         return xla_ledger.path_choice(
             site, batch=batch, chunk=chunk,
-            table_tokens=width * self.cfg.page_size) or self.layout.attn_impl
+            table_tokens=pages * self.cfg.page_size) or self.layout.attn_impl
 
     @staticmethod
     def _credit_own(seqs, ns: int) -> None:
@@ -1612,7 +1658,7 @@ class JaxEngine:
         # and must not be accounted (their num_computed was reset)
         for it in items:
             if it.seq.status == "running":
-                it.seq.num_computed += it.chunk_len
+                self.scheduler.chunk_dispatched(it.seq, it.chunk_len)
         fused = self._maybe_fuse_decode(items, B, tok_d, samp, seeds,
                                         counters, with_top)
         # frees are deferred while this dispatch is unfetched: a sequence
@@ -1634,7 +1680,8 @@ class JaxEngine:
                 batch=len(items),
                 tokens=int(sum(it.chunk_len for it in items)),
                 fused_blocks=len(fused) if fused else 0,
-                ctx=int((prefix + chunk).max()), pages=table.shape[1],
+                ctx=int((prefix + chunk).max()),
+                pages=table.shape[1] - self.layout.state_cols,
                 bucket=chunk_bucket,
                 attn=self._attn_of("prefill_attention", B, chunk_bucket,
                                    table.shape[1]),
@@ -1973,7 +2020,7 @@ class JaxEngine:
         # below matches the device program: prefill first, then decode)
         for it in items:
             if it.seq.status == "running":
-                it.seq.num_computed += it.chunk_len
+                self.scheduler.chunk_dispatched(it.seq, it.chunk_len)
         t_fetch = self.events.now()
         # lint: allow(device-get): mixed-step prefill half, consumed on-step like _run_prefill
         p_packed = np.asarray(jax.device_get(p_packed_d))
@@ -2001,7 +2048,8 @@ class JaxEngine:
             prefill_tokens=int(sum(it.chunk_len for it in items)),
             decode_rows=len(dseqs),
             ctx=int(max((p_prefix + p_chunk).max(), d_pos.max() + T)),
-            pages=max(p_table.shape[1], d_table.shape[1]),
+            pages=(max(p_table.shape[1], d_table.shape[1])
+                   - self.layout.state_cols),
             bucket=chunk_bucket,
             attn=self._attn_of("prefill_attention", Bp, chunk_bucket,
                                p_table.shape[1]),
@@ -2548,7 +2596,7 @@ class JaxEngine:
                 "decode_block", t0_ns=t0_ev, rung=T, n_steps=T,
                 blocks=chain_len, batch=len(seqs), chain=chain_len,
                 ctx=int(positions.max()) + T * chain_len,
-                pages=table.shape[1], bucket=Bb,
+                pages=table.shape[1] - self.layout.state_cols, bucket=Bb,
                 attn=self._attn_of("decode_attention", Bb, 1,
                                    table.shape[1]),
                 **({"rid": seqs[0].request_id} if len(seqs) == 1 else {}),
@@ -3227,6 +3275,10 @@ class JaxEngine:
         batches = request.get("embed_token_ids") or []
         if not batches:
             return {"error": "no inputs"}
+        try:  # the cache-free forward threads no recurrent state
+            require_no_state(self.model_cfg, "the embedding forward")
+        except ValueError as e:
+            return {"error": str(e)}
         max_len = min(
             max(len(t) for t in batches), self.cfg.max_model_len
         )
@@ -3729,9 +3781,13 @@ class JaxEngine:
             p.events = self.events
         self._emit_event(KvEvent("cleared", []))
         self.scheduler.pool = self.pool
+        self.scheduler.state = self.layout.make_state_pool()
+        if self.scheduler.state is not None:
+            self.scheduler.state.events = self.events
         for seq in self.scheduler.waiting:
             seq.pages = []
-            seq.num_cached = 0
+            seq.state_slot = seq.state_src = 0
+            seq.num_cached = seq.kv_cached = 0
             seq.num_computed = 0
             seq.committed_pages = 0
             seq.block_hashes = []

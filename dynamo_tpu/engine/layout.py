@@ -30,12 +30,12 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..analysis import xla_ledger
 from ..models import KVCache, ModelConfig, forward_prefill, kv_cache_pspec
-from ..models.llama import require_plain_cache
+from ..models.llama import require_no_state, require_plain_cache
 from ..ops import SamplingParams
 from ..ops.paged_attention import resolve_attention_impl
 from . import steps
 from .config import EngineConfig, bucket_for
-from .page_pool import PagePool
+from .page_pool import PagePool, StatePool
 
 # jax.jit with compile attribution (analysis/xla_ledger.py): every jit
 # cache miss in the engine lands in the ledger as (fn, signature, rung)
@@ -109,6 +109,14 @@ class Layout:
                 f"latent pages: backend is {jax.default_backend()!r}, the "
                 "kernel over them is a TPU program",
                 requested=cfg.attention_impl)
+        # trailing columns of a row's page table that carry its state slots
+        # (`models.hybrid.STATE_COLS`); 0 for a model without state-space
+        # layers
+        self.state_cols = 0
+        if model_cfg.state_spec is not None:
+            from ..models.hybrid import STATE_COLS
+
+            self.state_cols = STATE_COLS
         self._prefill_steps: Dict[tuple, Callable] = {}
         self._decode_steps: Dict[tuple, Callable] = {}
         self._mixed_steps: Dict[tuple, Callable] = {}
@@ -153,6 +161,29 @@ class Layout:
                           cfg.quantization == "int8")):
             if on:
                 require_plain_cache(model_cfg, what)
+        for what, on in (("--speculative-ngram-k (the draft-verify step)",
+                          cfg.speculative_ngram_k),
+                         ("--decode-continuous (the device-resident decode "
+                          "loop)", cfg.decode_continuous)):
+            if on:
+                require_no_state(model_cfg, what)
+        if model_cfg.state_spec is not None:
+            from ..models.hybrid import snapshot_tokens
+
+            every = snapshot_tokens(model_cfg)
+            if every % cfg.page_size or cfg.max_prefill_tokens % every:
+                raise ValueError(
+                    f"page_size {cfg.page_size} must divide the state "
+                    f"snapshot interval {every}, and that "
+                    f"max_prefill_tokens {cfg.max_prefill_tokens} "
+                    f"({model_cfg.model_type}: a snapshot is addressed by "
+                    "the block hash of its position, and a chunk starts at "
+                    "one)")
+            if cfg.num_state_slots < 3:
+                raise ValueError(
+                    f"num_state_slots {cfg.num_state_slots}: a model with "
+                    "state-space layers needs the trash slot, a running "
+                    "sequence's and one to go on in past a snapshot")
         if meshed:
             from ..parallel import make_mesh
 
@@ -407,6 +438,19 @@ class Layout:
             self.cfg.num_pages, self.cfg.page_size, event_sink=event_sink
         )
 
+    def make_state_pool(self) -> Optional[StatePool]:
+        """The allocator of the state slots beside the pages; None for a
+        model without state-space layers."""
+        if self.model_cfg.state_spec is None:
+            return None
+        from ..models.hybrid import (SNAP_COLS, handout_every,
+                                     snapshot_tokens)
+
+        return StatePool(
+            self.cfg.num_state_slots, snapshot_tokens(self.model_cfg),
+            SNAP_COLS, every_of=lambda tokens: handout_every(
+                self.model_cfg, tokens, self.cfg.page_size))
+
     @property
     def kv_pspec(self) -> KVCache:
         """Where the KV pool lives on the mesh."""
@@ -425,6 +469,7 @@ class Layout:
             kv = KVCache.create(
                 self.model_cfg, self.pool_ranks * self.cfg.num_pages,
                 self.cfg.page_size, dtype,
+                state_slots=self.cfg.num_state_slots,
             )
         if self.mesh is None:
             if self.device is not None:

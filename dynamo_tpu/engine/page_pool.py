@@ -212,6 +212,118 @@ class PagePool:
             self._event_sink(ev)
 
 
+class StatePool:
+    """Slots of recurrent state beside the pages (`ModelConfig.state_spec`):
+    what a SEQUENCE leaves the state-space layers, whatever its length.
+
+    - a running sequence owns one slot: its steps read it and write it;
+    - a chunk that starts at a page boundary hands its state out every
+      `every_of(bucket)` tokens from its start (`models.hybrid.
+      handout_every`: `snapshot_every`, or a page in a short row) and at its
+      end; where that position is a page boundary the state is *committed*
+      under the chained block hash of the position: inside a chunk it is
+      written to a slot reserved for it, at a chunk's end the sequence's own
+      slot is committed and the sequence goes on in a fresh one (a step
+      reads slot a and writes slot b: no copy);
+    - a snapshot is refcounted while a reader is admitted on it, evictable
+      (LRU) otherwise, and evicted to make room like a cached page;
+    - a prefix hit is as deep as the deepest snapshot at or under the cached
+      pages (`Scheduler._apply_prefix_cache`).
+
+    Slot 0 is reserved: read, it is the zeros before a sequence; written,
+    it is trash (pad rows)."""
+
+    def __init__(self, num_slots: int, snapshot_every: int,
+                 inside: int = 0,
+                 every_of: Optional[Callable[[int], int]] = None):
+        self.num_slots = num_slots
+        self.snapshot_every = snapshot_every
+        # a step's bucket -> the tokens between the states it hands out
+        self.every_of = every_of or (lambda tokens: snapshot_every)
+        # most snapshots a step writes INSIDE a row's chunk
+        # (`models.hybrid.SNAP_COLS`)
+        self.inside = inside
+        self._free: List[int] = list(range(num_slots - 1, 0, -1))
+        self._snap: Dict[int, int] = {}  # block hash → slot
+        self._slot_hash: Dict[int, int] = {}
+        self._refs: Dict[int, int] = {}  # snapshot slot → readers
+        self._lru: "OrderedDict[int, None]" = OrderedDict()
+        self.stored_total = 0
+        self.hits_total = 0
+        self.evictions_total = 0
+        # tokens by which prefix hits were shortened: pages were cached
+        # past the deepest snapshot
+        self.hit_tokens_shortened_total = 0
+        self.events = None  # StepEventRecorder, as `PagePool.events`
+
+    @property
+    def available(self) -> int:
+        return len(self._free) + len(self._lru)
+
+    @property
+    def snapshots(self) -> int:
+        return len(self._snap)
+
+    @property
+    def running(self) -> int:
+        """Slots owned by running sequences: their own, and those reserved
+        for the snapshots their next step writes."""
+        return self.num_slots - 1 - len(self._free) - len(self._snap)
+
+    def allocate(self) -> int:
+        """A slot for a running sequence, evicting the least recently used
+        unread snapshot if none is free; 0 if there is none."""
+        if self._free:
+            return self._free.pop()
+        if not self._lru:
+            return 0
+        slot, _ = self._lru.popitem(last=False)
+        del self._snap[self._slot_hash.pop(slot)]
+        self.evictions_total += 1
+        if self.events is not None:
+            self.events.record("state_evict", slot=slot)
+        return slot
+
+    def release(self, slot: int) -> None:
+        """A running sequence gives its slot back."""
+        if slot:
+            self._free.append(slot)
+
+    def commit(self, slot: int, block_hash: int, tokens: int) -> None:
+        """A running sequence's slot becomes the snapshot under
+        `block_hash` (which has none: `has`), its committer the first
+        reader (`unref`: at once, where it does not go on from it)."""
+        self._snap[block_hash] = slot
+        self._slot_hash[slot] = block_hash
+        self._refs[slot] = 1
+        self.stored_total += 1
+        if self.events is not None:
+            self.events.record("state_store", slot=slot, tokens=tokens)
+
+    def has(self, block_hash: int) -> bool:
+        return block_hash in self._snap
+
+    def lookup(self, block_hash: int) -> int:
+        """The snapshot under this hash with a reference taken, or 0."""
+        slot = self._snap.get(block_hash, 0)
+        if slot:
+            self._lru.pop(slot, None)
+            self._refs[slot] = self._refs.get(slot, 0) + 1
+            self.hits_total += 1
+        return slot
+
+    def unref(self, slot: int) -> None:
+        """A reader is done with a snapshot (its step is dispatched: what
+        is dispatched later runs later)."""
+        refs = self._refs.get(slot, 0) - 1
+        if refs > 0:
+            self._refs[slot] = refs
+            return
+        self._refs.pop(slot, None)
+        if slot in self._slot_hash:
+            self._lru[slot] = None
+
+
 class ShardedPagePool:
     """KV pool partitioned into R independent per-device-shard pools
     (the dp/sp-sharded pool: on a dp×sp×tp serving mesh each (dp, sp)

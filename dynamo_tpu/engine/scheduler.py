@@ -28,8 +28,8 @@ from typing import Deque, List, Optional, Sequence as Seq, Tuple
 
 from ..analysis import affine
 from ..tokens import chain_seed, compute_block_hash_for_seq, next_block_hash
-from .config import EngineConfig
-from .page_pool import NoPagesError, PagePool
+from .config import EngineConfig, bucket_for
+from .page_pool import NoPagesError, PagePool, StatePool
 
 logger = logging.getLogger(__name__)
 
@@ -92,6 +92,20 @@ class Sequence:
         self.kv_rank = 0  # pool partition this sequence's pages live on
         self._admit_hashes: Optional[List[int]] = None  # scheduler cache
         self.num_cached = 0  # prompt tokens satisfied from prefix cache
+        # prompt tokens whose PAGES were found cached: more than
+        # `num_cached` where a model with state-space layers had no
+        # snapshot that deep (`Scheduler._shorten_to_snapshot`)
+        self.kv_cached = 0
+        # state slots (`page_pool.StatePool`; 0: none): the one this
+        # sequence's steps write, a snapshot its NEXT step reads in its
+        # place (a prefix hit, or its own slot just committed), and the
+        # slots reserved for the snapshots that step hands out inside its
+        # chunk, after 1, 2, ... intervals of `state_every` tokens (0: none
+        # there)
+        self.state_slot = 0
+        self.state_src = 0
+        self.state_inside: Tuple[int, ...] = ()
+        self.state_every = 0
         self.num_computed = 0  # tokens whose KV is written
         self.output_tokens: List[int] = []
         self.block_hashes: List[int] = []  # chained, full blocks only
@@ -178,9 +192,13 @@ class StepPlan:
 
 
 class Scheduler:
-    def __init__(self, cfg: EngineConfig, pool: PagePool):
+    def __init__(self, cfg: EngineConfig, pool: PagePool,
+                 state: Optional[StatePool] = None):
         self.cfg = cfg
         self.pool = pool
+        # slots of recurrent state beside the pages (a model with
+        # state-space layers; None otherwise)
+        self.state = state
         self.waiting: Deque[Sequence] = deque()
         self.running: List[Sequence] = []
         # sequences errored inside planning (e.g. out of KV capacity with
@@ -338,6 +356,8 @@ class Scheduler:
             # ties spread by availability
             rank, _ = self.pool.best_rank(self._seq_hashes(seq))
         ok = self.pool.available_on(rank) >= need + self._reserve_pages(seq)
+        if self.state is not None and not self.state.available:
+            ok = False  # waits for a slot as for a page
         return ok, rank
 
     def _reserve_pages(self, seq: Sequence) -> int:
@@ -388,8 +408,8 @@ class Scheduler:
             if seq.parked:
                 if not self._resume(seq):
                     continue  # errored out; next head may still admit
-            elif self.cfg.enable_prefix_caching:
-                self._apply_prefix_cache(seq)
+            else:
+                self._admit_cache(seq)
             seq.status = "running"
             if seq.t_admitted is None:  # keep the FIRST admission:
                 # re-admission after preemption is not queue wait
@@ -399,6 +419,7 @@ class Scheduler:
                 self.events.record(
                     "admit", rid=seq.request_id, rank=rank,
                     prompt_len=seq.prompt_len, cached=seq.num_cached,
+                    kv_cached=seq.kv_cached,
                 )
 
     def _resume(self, seq: Sequence) -> bool:
@@ -455,8 +476,7 @@ class Scheduler:
             return None
         seq.kv_rank = rank
         self.waiting.popleft()
-        if self.cfg.enable_prefix_caching:
-            self._apply_prefix_cache(seq)
+        self._admit_cache(seq)
         seq.status = "running"
         if seq.t_admitted is None:
             seq.t_admitted = time.monotonic()
@@ -465,7 +485,7 @@ class Scheduler:
             self.events.record(
                 "admit", rid=seq.request_id, rank=rank,
                 prompt_len=seq.prompt_len, cached=seq.num_cached,
-                spliced=True,
+                kv_cached=seq.kv_cached, spliced=True,
             )
         return seq
 
@@ -477,14 +497,48 @@ class Scheduler:
         if not self.cfg.enable_prefix_caching:
             return []
         if getattr(seq, "_admit_hashes", None) is None:
-            ps = self.cfg.page_size
-            hashes = compute_block_hash_for_seq(
-                seq.prompt, ps, self.cfg.block_hash_salt + seq.cache_salt
-            )
-            if seq.prompt_len % ps == 0 and hashes:
+            hashes = self._prompt_hashes(seq)
+            if seq.prompt_len % self.cfg.page_size == 0 and hashes:
                 hashes = hashes[:-1]
             seq._admit_hashes = hashes
         return seq._admit_hashes
+
+    def _prompt_hashes(self, seq: Sequence) -> List[int]:
+        """The chained hash of every full block of the prompt, computed once
+        a sequence: entry i is that of its first (i + 1) pages of tokens."""
+        if getattr(seq, "_block_hashes", None) is None:
+            seq._block_hashes = compute_block_hash_for_seq(
+                seq.prompt, self.cfg.page_size,
+                self.cfg.block_hash_salt + seq.cache_salt)
+        return seq._block_hashes
+
+    def _admit_cache(self, seq: Sequence) -> None:
+        """What an admitted sequence takes from the caches: a state slot of
+        its own first (which may evict the oldest unread snapshot), then the
+        cached prefix."""
+        if self.state is not None and not seq.state_slot:
+            seq.state_slot = self.state.allocate()
+        if self.cfg.enable_prefix_caching:
+            self._apply_prefix_cache(seq)
+
+    def _shorten_to_snapshot(self, seq: Sequence, hashes: List[int],
+                             hit_pages: List[int]) -> List[int]:
+        """A model with state-space layers resumes where it has BOTH the
+        pages and the state: at the deepest committed snapshot at or under
+        the cached pages.  Takes that snapshot as the sequence's next read
+        (`state_src`) and gives the pages past it back: their tokens are
+        computed again, into pages of the sequence's own (the cached ones
+        may be another reader's).  A snapshot may stand at any page."""
+        keep = 0
+        for n in range(len(hit_pages), 0, -1):
+            seq.state_src = self.state.lookup(hashes[n - 1])
+            if seq.state_src:
+                keep = n
+                break
+        self.state.hit_tokens_shortened_total += (
+            (len(hit_pages) - keep) * self.cfg.page_size)
+        self.pool.free(hit_pages[keep:])
+        return hit_pages[:keep]
 
     @affine("step", "loop")
     def add_imported(self, seq: Sequence) -> None:
@@ -528,6 +582,9 @@ class Scheduler:
                 # a raising hook must not leave the dead request's trace
                 # attached — the next admission's span would join it
                 self.onboard_trace = None
+        seq.kv_cached = len(hit_pages) * ps
+        if hit_pages and self.state is not None:
+            hit_pages = self._shorten_to_snapshot(seq, hashes, hit_pages)
         if hit_pages:
             seq.pages = list(hit_pages)
             seq.num_cached = len(hit_pages) * ps
@@ -711,11 +768,16 @@ class Scheduler:
             if seq.prefill_done or budget <= 0 or seq.status != "running":
                 continue  # (the head's page growth may have preempted it)
             chunk = min(seq.prompt_len - seq.num_computed, budget)
+            if self.state is not None:
+                chunk = self._state_chunk(seq, chunk)
             short = preempt and self.shares_step(seq, chunk)
             if items and not (
                     short and self.step_variant(seq)
                     == self.step_variant(items[0].seq)):
                 continue
+            if self.state is not None and not self._ensure_state(
+                    seq, chunk, short):
+                continue  # no slot: its turn comes when one is given up
             if preempt and not items:
                 if not self._ensure_pages(seq, seq.num_computed + chunk):
                     continue  # seq may have been preempted/errored
@@ -784,6 +846,120 @@ class Scheduler:
                 continue
             decodable.append(seq)
         return decodable[: self.cfg.max_num_seqs]
+
+    def _prompt_hash(self, seq: Sequence, tokens: int) -> int:
+        """The chained block hash of the prompt's first `tokens` tokens (a
+        multiple of the page size)."""
+        return self._prompt_hashes(seq)[tokens // self.cfg.page_size - 1]
+
+    def _tail_start(self, seq: Sequence) -> int:
+        """Where the tail row of `seq`'s prompt starts; 0: it has none.  A
+        prompt whose uncached part is longer than a snapshot interval ends
+        its prefill with one short row, the last page boundary under its
+        last token and as many whole pages before it as a step hands out
+        states (`StatePool.inside`), and that row hands its state out at
+        every page: the tail of a prompt is where a follow-up parts from it
+        (a document, then another question), so the follow-up finds a
+        snapshot at its last shared page and is a short row itself, which
+        shares a step.  A shorter uncached part stays one chunk: it resumed
+        from a snapshot that near.  None either where such a row's step
+        would not hand out by pages (another page size or short bucket)."""
+        st, ps = self.state, self.cfg.page_size
+        row = (st.inside + 1) * ps
+        if (not self.cfg.enable_prefix_caching
+                or seq.prompt_len - seq.num_cached <= st.snapshot_every
+                or self._every(row, row <= self.cfg.short_chunk_bucket) != ps):
+            return 0
+        return max((seq.prompt_len - 1) // ps * ps - st.inside * ps, 0)
+
+    def _every(self, chunk: int, short: bool) -> int:
+        """The tokens between the states a chunk's step hands out, by the
+        bucket its program runs at (`JaxEngine._prefill_arrays`)."""
+        return self.state.every_of(
+            self.cfg.short_chunk_bucket if short
+            else bucket_for(chunk, self.cfg.chunk_buckets))
+
+    def _state_chunk(self, seq: Sequence, chunk: int) -> int:
+        """The chunk of a model with state-space layers: it starts at a page
+        boundary (its step hands the state out at whole intervals from
+        there, and a snapshot is addressed by its page's hash), so one that
+        does not, after a budget that is no multiple, stops at the next; and
+        it does not reach into the prompt's tail row (`_tail_start`)."""
+        ps, n = self.cfg.page_size, seq.num_computed
+        if n % ps:
+            return min(chunk, ps - n % ps)
+        tail = self._tail_start(seq)
+        return min(chunk, tail - n) if n < tail else chunk
+
+    def _ensure_state(self, seq: Sequence, chunk: int, short: bool) -> bool:
+        """Before a prefill chunk of `seq` is planned: the slot its steps
+        write, and the snapshots (`StatePool`), each if a slot is to be had
+        and its position has none.  Where the computed tokens stand at a
+        page boundary with the sequence's own slot holding the state there:
+        that slot is committed, stays the next step's read, and the sequence
+        goes on in a fresh one.  For the positions INSIDE the chunk, every
+        `_every(chunk, short)` tokens from its start, a slot each is
+        reserved, which the step writes and `chunk_dispatched` commits; a
+        short row hands out by pages, and keeps them only where it is a
+        prompt's tail row (a follow-up's own short row leaves nothing worth
+        a slot).  False: the sequence has no slot."""
+        st = self.state
+        if not seq.state_slot:
+            seq.state_slot = st.allocate()
+            if not seq.state_slot:
+                return False
+        for slot in seq.state_inside:  # of a plan that was not dispatched
+            st.release(slot)
+        seq.state_inside = ()
+        n, every = seq.num_computed, self._every(chunk, short)
+        if n % self.cfg.page_size or not self.cfg.enable_prefix_caching:
+            return True
+        here = n and self._prompt_hash(seq, n)
+        if n and not seq.state_src and not st.has(here) and st.available:
+            seq.state_src, seq.state_slot = seq.state_slot, st.allocate()
+            st.commit(seq.state_src, here, n)
+        if every < st.snapshot_every and not 0 < self._tail_start(seq) <= n:
+            return True
+        seq.state_every = every
+        seq.state_inside = tuple(
+            st.allocate() if (at < n + chunk and st.available and not st.has(
+                self._prompt_hash(seq, at))) else 0
+            for at in range(n + every, n + every * (st.inside + 1), every))
+        return True
+
+    @affine("step", "loop")
+    def chunk_dispatched(self, seq: Sequence, tokens: int) -> None:
+        """A prefill chunk of `seq` is committed to the device: the
+        snapshots it writes inside the chunk are committed (evictable at
+        once: nobody reads them yet), its tokens count as computed, and the
+        snapshot it read is no longer held for it (what is dispatched later
+        runs later)."""
+        if self.state is not None:
+            every = seq.state_every
+            for j, slot in enumerate(seq.state_inside):
+                if not slot:
+                    continue
+                at = seq.num_computed + (j + 1) * every
+                if self.state.has(self._prompt_hash(seq, at)):
+                    self.state.release(slot)  # committed meanwhile
+                else:
+                    self.state.commit(slot, self._prompt_hash(seq, at), at)
+                    self.state.unref(slot)
+            seq.state_inside = ()
+        seq.num_computed += tokens
+        if seq.state_src:
+            self.state.unref(seq.state_src)
+            seq.state_src = 0
+
+    def _release_state(self, seq: Sequence) -> None:
+        if self.state is None:
+            return
+        if seq.state_src:
+            self.state.unref(seq.state_src)
+        for slot in (seq.state_slot, *seq.state_inside):
+            self.state.release(slot)
+        seq.state_src = seq.state_slot = 0
+        seq.state_inside = ()
 
     def _ensure_pages(self, seq: Sequence, upto_tokens: int) -> bool:
         """Grow seq's page list to cover `upto_tokens`, preempting others
@@ -974,8 +1150,9 @@ class Scheduler:
     def _preempt(self, seq: Sequence) -> None:
         logger.info("preempting %s", seq.request_id)
         self.pool.free(seq.pages)
+        self._release_state(seq)
         seq.pages = []
-        seq.num_cached = 0
+        seq.num_cached = seq.kv_cached = 0
         seq.num_computed = 0
         seq.committed_pages = 0
         seq.block_hashes = seq.block_hashes[:0]
@@ -1039,6 +1216,7 @@ class Scheduler:
     def _finish(self, seq: Sequence, reason: str) -> None:
         seq.status = "finished"
         seq.finish_reason = reason
+        self._release_state(seq)
         if not seq.hold_pages:
             if self.deferred_free is not None:
                 self.deferred_free.extend(seq.pages)

@@ -38,9 +38,18 @@ def _decode_path(attn_impl: str, kv, page_table, n_steps: int,
     """"block" (`decode_block_scan`: one pool gather per block) or
     "per-step" (a scan of `forward_decode`) for one traced decode step,
     noted in the compile ledger with the reason — the Pallas decode kernel
-    reads pages itself and needs the per-step write-first layout, and a
-    block whose gathered KV would pass the byte budget stays per-step."""
+    reads pages itself and needs the per-step write-first layout, a
+    block whose gathered KV would pass the byte budget stays per-step, and
+    so does a model with state slots beside its pages (the block's layer
+    body carries no recurrent state)."""
     from ..ops.paged_attention import LATENT_DECODE_XLA, _adapt
+
+    if hasattr(kv, "ssm"):
+        xla_ledger.note_path_choice(
+            "decode_step", "per-step", "state slots beside the pages: the "
+            "one layer loop carries the recurrent state",
+            batch=page_table.shape[0], n_steps=n_steps)
+        return "per-step"
 
     # both planes' rows (a latent pool's differ in width)
     blk_bytes = sum(p.shape[0] * page_table.shape[0]
@@ -591,10 +600,10 @@ def gather_pages(own=None):
     gather and drops every other shard's."""
 
     def body(kv, pages, *rank):
-        blobs = jax.tree.map(lambda plane: plane[:, pages], kv)
+        blobs = tuple(plane[:, pages] for plane in (kv.k, kv.v))
         if own is not None:
-            blobs = jax.tree.map(lambda b: own(b, *rank), blobs)
-        return tuple(blobs)
+            blobs = tuple(own(b, *rank) for b in blobs)
+        return blobs
 
     return body
 
@@ -611,6 +620,6 @@ def set_pages(mine=None):
                 blob = mine(blob, plane, pages, *rank)
             return plane.at[:, pages].set(blob)
 
-        return jax.tree.map(put, kv, type(kv)(k_blob, v_blob))
+        return kv._replace(k=put(kv.k, k_blob), v=put(kv.v, v_blob))
 
     return body

@@ -16,7 +16,10 @@ from dataclasses import dataclass
 from typing import Optional
 
 
-MOE_ACTS = ("silu", "gpt_oss_glu", "relu_glu")
+MOE_ACTS = ("silu", "gpt_oss_glu", "relu_glu", "relu2")
+# a layer's ONE mixer under `ModelConfig.layer_pattern`
+LAYER_KINDS = {"M": "a state-space (Mamba-2) mixer", "E": "an expert "
+               "feed-forward", "*": "attention"}
 MOE_SCORINGS = ("softmax", "sigmoid")
 
 
@@ -69,6 +72,40 @@ class CacheSpec:
 
     def bytes_per_token_layer(self, itemsize: int) -> int:
         return sum(a * b for a, b in self.plane_dims) * itemsize
+
+
+@dataclass(frozen=True)
+class StateSpec:
+    """What one SEQUENCE leaves a state-space layer, whatever its length:
+    the ONE description every allocator and log reads, as `CacheSpec` is
+    for what a token leaves a page.  The pool beside the pages is two
+    arrays [layers, slots, ...]: the convolution's last `conv_kernel - 1`
+    inputs in the served dtype, stored as whole 128-lane tiles (a [3, 6144]
+    window as [144, 128], the geometry of a key page), and the recurrent
+    state [heads, head_dim, state] in float32."""
+
+    layers: int
+    heads: int
+    head_dim: int
+    state: int
+    conv_dim: int
+    conv_kernel: int
+
+    @property
+    def window_dims(self) -> tuple:
+        """The window as the pool stores it: [tiles, 128]."""
+        n = (self.conv_kernel - 1) * self.conv_dim
+        return (-(-n // 128), 128)
+
+    @property
+    def state_dims(self) -> tuple:
+        return (self.heads, self.head_dim, self.state)
+
+    def bytes_per_slot(self, itemsize: int) -> int:
+        """One sequence's state over every state-space layer: the window in
+        the served dtype, the recurrent state in float32."""
+        w = self.window_dims[0] * self.window_dims[1] * itemsize
+        return self.layers * (w + 4 * self.heads * self.head_dim * self.state)
 
 
 @dataclass(frozen=True)
@@ -186,6 +223,29 @@ class ModelConfig:
     hc_sinkhorn_iters: int = 0
     hc_eps: float = 0.0
     hc_res_clamp: tuple = (0.0, 0.0)
+    # a layer that is ONE mixer with one norm and one residual add
+    # (nemotron_h): character l of `layer_pattern` names layer l's, "M" a
+    # Mamba-2 state-space mixer, "E" an expert feed-forward, "*" attention
+    # (`LAYER_KINDS`).  Only the "*" layers have pages (`num_kv_layers`);
+    # the "M" layers leave a sequence a state (`state_spec`).  None: every
+    # layer is attention, then a feed-forward
+    layer_pattern: Optional[str] = None
+    # the Mamba-2 mixer: `ssm_heads` heads of `ssm_head_dim`, B and C shared
+    # by the heads of each of `ssm_groups` groups, a recurrent state of
+    # `ssm_state` values a head channel, a causal depthwise convolution of
+    # `ssm_conv_kernel` taps, the scan computed `ssm_chunk` tokens a block
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_groups: int = 1
+    ssm_state: int = 0
+    ssm_conv_kernel: int = 4
+    ssm_chunk: int = 128
+    # the shared expert's own width where the family states one
+    # (nemotron_h); None: n_shared_experts * moe_intermediate_size
+    moe_shared_intermediate_size: Optional[int] = None
+    # attention applies no positions at all (nemotron_h: positions enter
+    # through the state-space layers)
+    attention_rope: bool = True
     # identity
     model_type: str = "llama"
     name: str = "llama"
@@ -217,6 +277,15 @@ class ModelConfig:
         if self.hc_mult and not self.is_latent:
             raise ValueError("hyper-connections (hc_mult) are implemented "
                              "around latent attention only (xing4_0)")
+        if self.layer_pattern is not None:
+            bad = set(self.layer_pattern) - set(LAYER_KINDS)
+            if bad or len(self.layer_pattern) != L:
+                raise ValueError(
+                    f"layer_pattern must name {L} layers by "
+                    f"{sorted(LAYER_KINDS)}, got {self.layer_pattern!r}")
+        elif self.moe_act == "relu2":
+            raise ValueError("ungated relu2 experts are implemented under a "
+                             "layer_pattern only (nemotron_h)")
 
     @property
     def head_dim_(self) -> int:
@@ -246,8 +315,43 @@ class ModelConfig:
 
     @property
     def num_moe_layers(self) -> int:
+        if self.layer_pattern is not None:
+            return self.layer_pattern.count("E")
         return (self.num_hidden_layers - self.first_k_dense
                 if self.is_moe else 0)
+
+    @property
+    def num_kv_layers(self) -> int:
+        """Layers that leave a token keys and values: the page pool's."""
+        if self.layer_pattern is not None:
+            return self.layer_pattern.count("*")
+        return self.num_hidden_layers
+
+    @property
+    def state_spec(self) -> Optional[StateSpec]:
+        """What a sequence leaves the state-space layers; None without."""
+        n = (self.layer_pattern or "").count("M")
+        if not n:
+            return None
+        return StateSpec(n, self.ssm_heads, self.ssm_head_dim, self.ssm_state,
+                         self.ssm_conv_dim, self.ssm_conv_kernel)
+
+    @property
+    def ssm_inner(self) -> int:
+        """The Mamba-2 mixer's inner width: heads x head_dim (not
+        `expand` x hidden)."""
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """What the convolution runs over: x beside every group's B, C."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
+
+    @property
+    def shared_expert_width(self) -> int:
+        return (self.moe_shared_intermediate_size
+                or self.n_shared_experts
+                * (self.moe_intermediate_size or self.intermediate_size))
 
     @property
     def hc_mixer_width(self) -> int:
@@ -307,6 +411,8 @@ class ModelConfig:
         """Approximate parameter count (for memory planning)."""
         h, v, l = self.hidden_size, self.vocab_size, self.num_hidden_layers
         hd, nh = self.head_dim_, self.num_attention_heads
+        if self.layer_pattern is not None:
+            return self._num_params_pattern()
         if self.is_latent:
             qr, r = self.q_lora_rank, self.kv_lora_rank
             nope, pe, vd = (self.qk_nope_head_dim, self.qk_rope_head_dim,
@@ -337,6 +443,27 @@ class ModelConfig:
         return (l * (attn + 2 * h) + k * dense + (l - k) * mlp + emb + h
                 + mixers)
 
+    def _num_params_pattern(self) -> int:
+        """`num_params` under a `layer_pattern`: every layer is its one
+        mixer and one norm."""
+        h, pat = self.hidden_size, self.layer_pattern
+        d, cd, nh = self.ssm_inner, self.ssm_conv_dim, self.ssm_heads
+        mamba = (h * (d + cd + nh) + cd * self.ssm_conv_kernel + cd + 3 * nh
+                 + d + d * h)
+        q = self.num_attention_heads * self.head_dim_
+        kv = self.num_key_value_heads * self.head_dim_
+        attn = h * q + 2 * h * kv + q * h
+        fm = self.moe_intermediate_size
+        gated = 2 if self.moe_act == "relu2" else 3
+        moe = (h * self.router_width
+               + (self.router_width if self.moe_scoring == "sigmoid" else 0)
+               + self.num_experts * gated * h * fm
+               + (gated * h * self.shared_expert_width
+                  if self.n_shared_experts else 0))
+        emb = self.vocab_size * h * (1 if self.tie_word_embeddings else 2)
+        return (pat.count("M") * (mamba + h) + pat.count("*") * (attn + h)
+                + pat.count("E") * (moe + h) + emb + h)
+
     @staticmethod
     def from_hf_config(d: dict, name: str = "") -> "ModelConfig":
         """Build from a HF ``config.json`` dict (llama/mistral/mixtral/qwen2)."""
@@ -347,6 +474,13 @@ class ModelConfig:
             return ModelConfig(**_deepseek_v3_fields(d, name))
         if d.get("model_type") == "xing4_0":
             return ModelConfig(**_xing4_0_fields(d, name))
+        if d.get("model_type") == "nemotron_h":
+            return ModelConfig(**_nemotron_h_fields(d, name))
+        if d.get("hybrid_override_pattern"):
+            raise ValueError(
+                f"model_type {d.get('model_type')!r} asks for a layer "
+                "pattern (hybrid_override_pattern) and only nemotron_h's is "
+                "implemented: the llama branch would build another model")
         if d.get("kv_lora_rank"):
             raise ValueError(
                 f"model_type {d.get('model_type')!r} asks for latent "
@@ -474,6 +608,99 @@ def _smallthinker_fields(d: dict, name: str) -> dict:
         rope_layout=tuple(_layout(d, "rope_layout", L)),
         model_type="smallthinker",
         name=name or d.get("_name_or_path", "smallthinker"),
+    )
+
+
+def _nemotron_h_fields(d: dict, name: str) -> dict:
+    """NVIDIA Nemotron-H (`model_type` "nemotron_h": Nemotron-3-Nano): a
+    layer is ONE mixer, named by `hybrid_override_pattern`: a Mamba-2
+    state-space mixer, an expert feed-forward of ungated relu^2 experts
+    under the `noaux_tc` sigmoid router with a shared expert, or GQA
+    attention without positions.  `n_routed_experts` counts the experts HELD
+    by this rank (`ep_rank`, default 0) of `ep_size`, as deepseek_v3's.
+    What is not implemented is refused by the key that asks for it."""
+    def refuse(key, why):
+        raise ValueError(f"nemotron_h: {key} {d.get(key)!r} {why}")
+
+    L = d["num_hidden_layers"]
+    pattern = d.get("hybrid_override_pattern")
+    if not isinstance(pattern, str) or len(pattern) != L or (
+            set(pattern) - set(LAYER_KINDS)):
+        if isinstance(pattern, str) and "-" in pattern:
+            refuse("hybrid_override_pattern", "has dense feed-forward layers "
+                   "('-'), which are not implemented")
+        refuse("hybrid_override_pattern", f"must name {L} layers by "
+               f"{sorted(LAYER_KINDS)}")
+    for key, want in (("mamba_hidden_act", "silu"),
+                      ("mlp_hidden_act", "relu2")):
+        if d.get(key, want) != want:
+            refuse(key, f"only {want} is implemented")
+    for key in ("use_bias", "mamba_proj_bias", "mlp_bias", "attention_bias"):
+        if d.get(key):
+            refuse(key, "projection biases are not implemented")
+    if not d.get("use_conv_bias", True):
+        refuse("use_conv_bias", "the convolution is implemented with its "
+               "bias")
+    if d.get("residual_in_fp32"):
+        refuse("residual_in_fp32", "the residual is carried in the served "
+               "dtype")
+    if d.get("sliding_window"):
+        refuse("sliding_window", "windowed attention is not implemented "
+               "under a layer pattern")
+    if not d.get("norm_topk_prob", True):
+        refuse("norm_topk_prob", "only normalised weights are implemented")
+    if d.get("n_shared_experts", 1) != 1:
+        refuse("n_shared_experts", "one shared expert of "
+               "moe_shared_expert_intermediate_size is implemented")
+    lo, hi = d.get("time_step_limit", (0.0, float("inf")))
+    if lo > 0.0 or hi != float("inf"):
+        refuse("time_step_limit", "a clamped step size is not implemented")
+    if d["mamba_num_heads"] % d["n_groups"]:
+        refuse("n_groups", f"must divide mamba_num_heads "
+               f"{d['mamba_num_heads']}")
+    if "E" in pattern and not d.get("n_routed_experts"):
+        refuse("n_routed_experts", "is needed: the pattern has expert layers")
+    eps = d.get("layer_norm_epsilon", d.get("norm_eps", 1e-5))
+    if d.get("norm_eps", eps) != eps:
+        refuse("norm_eps", f"differs from layer_norm_epsilon {eps!r}: one "
+               "epsilon serves every norm")
+    return dict(
+        vocab_size=d["vocab_size"],
+        hidden_size=d["hidden_size"],
+        # no dense feed-forward anywhere: the expert width stands in
+        intermediate_size=d["moe_intermediate_size"],
+        num_hidden_layers=L,
+        num_attention_heads=d["num_attention_heads"],
+        num_key_value_heads=d["num_key_value_heads"],
+        head_dim=d.get("head_dim"),
+        max_position_embeddings=d.get("max_position_embeddings", 4096),
+        rms_norm_eps=eps,
+        # keys that the family's attention does not read (`attention_rope`)
+        rope_theta=float(d.get("rope_theta", 10000.0)),
+        attention_rope=False,
+        tie_word_embeddings=d.get("tie_word_embeddings", False),
+        layer_pattern=pattern,
+        ssm_heads=d["mamba_num_heads"],
+        ssm_head_dim=d["mamba_head_dim"],
+        ssm_groups=d["n_groups"],
+        ssm_state=d["ssm_state_size"],
+        ssm_conv_kernel=d["conv_kernel"],
+        ssm_chunk=d.get("chunk_size", 128),
+        num_experts=d.get("n_routed_experts", 0),
+        num_experts_per_tok=d.get("num_experts_per_tok", 1),
+        moe_intermediate_size=d["moe_intermediate_size"],
+        moe_shared_intermediate_size=d.get(
+            "moe_shared_expert_intermediate_size"),
+        n_shared_experts=d.get("n_shared_experts", 1),
+        moe_act="relu2",
+        moe_scoring="sigmoid",
+        moe_n_group=d.get("n_group", 1),
+        moe_topk_group=d.get("topk_group", 1),
+        moe_routed_scale=float(d.get("routed_scaling_factor", 1.0)),
+        moe_ep_size=d.get("ep_size", 1),
+        moe_ep_rank=d.get("ep_rank", 0),
+        model_type="nemotron_h",
+        name=name or d.get("_name_or_path", "nemotron_h"),
     )
 
 

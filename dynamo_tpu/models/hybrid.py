@@ -1,0 +1,337 @@
+"""The layer loop of a model whose layer is ONE mixer (`ModelConfig.
+layer_pattern`: nemotron_h): a Mamba-2 state-space mixer "M", an expert
+feed-forward "E" or attention "*", each `x + mixer(rms_norm(x))`.
+
+Params are three stacks by kind (`ssm_layers`, `moe_layers`, `attn_layers`),
+and the loop follows the pattern in ONE `lax.scan` whatever the depth: the
+pattern is cut into units of (M, then *, then E), each kind at most once a
+unit and in that order (nemotron_h's 52 layers are 23 units, six of them
+with a "*").  A kind every unit has is scanned with the units; a kind only
+some have runs under a `lax.cond` on the unit's flag and indexes its own
+stack.
+
+Two pools stay where they are, read inside the loop and written after it:
+the pages of the "*" layers alone (`StateCache.k`, `.v`) and, beside them,
+the state slots of the "M" layers (`StateCache.conv`, `.ssm`: `ModelConfig.
+state_spec`).  A row reads its state from one slot and writes it to another,
+and the scan hands out the state INSIDE the chunk too, every
+`handout_every` tokens from the chunk's start (`snapshot_tokens`; a page in
+a short row): up to `SNAP_COLS` further slots take those.  All ride in the
+last `STATE_COLS` columns of the row's page table:
+[read, write, inside...].  Slot 0 is no state at all: read, it is the zeros
+before a sequence; written, it is trash (pad rows, no snapshot wanted).
+"""
+
+from __future__ import annotations
+
+from typing import NamedTuple, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ..ops import prefill_attention, rms_norm, rope_attention_scale
+from ..ops import rope_frequencies, ssm, write_kv_layers
+from .config import ModelConfig
+from .llama import (Params, StateCache, _moe, _qkv_proj, _rope_qk,
+                    _valid_rows, merge_moe_stats, moe_stats_columns)
+from .quantization import matmul_any
+
+# A chunk hands its state out, to be kept as a snapshot, after every so many
+# blocks of the scan (`ModelConfig.ssm_chunk` tokens each: 128 x 1 for
+# nemotron_h).  Chosen on the chip (PERF.md, PR 44: the cell's six seeds at
+# 1, 2 and 4 blocks): a constant, not a flag.
+SNAPSHOT_BLOCKS = 1
+# most states a step hands out INSIDE a row's chunk (a 512-token chunk has
+# three multiples of 128 inside it; a short row three whole pages)
+SNAP_COLS = 3
+# the trailing columns of a state family's page table: the slot a row's
+# state is read from, the slot it is written to at the chunk's end, and the
+# slots that take its state after 1, 2, ... `snapshot_tokens` of the chunk
+STATE_COLS = 2 + SNAP_COLS
+
+
+def snapshot_tokens(cfg: ModelConfig) -> int:
+    return cfg.ssm_chunk * SNAPSHOT_BLOCKS
+
+
+def handout_every(cfg: ModelConfig, tokens: int, page_size: int) -> int:
+    """The tokens between the states a step hands out inside a row's chunk,
+    counted from the chunk's start; `tokens` is the bucket the step runs at.
+    A row of at most `SNAP_COLS` + 1 pages hands out after every PAGE: the
+    scheduler ends a long prompt with such a row (`Scheduler._tail_start`),
+    because its tail is where a follow-up parts from it (a document, then
+    another question), and a follow-up that resumes at its last shared page
+    is a short row itself.  Longer rows hand out every `snapshot_tokens`."""
+    if tokens <= (SNAP_COLS + 1) * page_size:
+        return page_size
+    return snapshot_tokens(cfg)
+
+
+class Units(NamedTuple):
+    """`layer_pattern` as units of (M, *, E).  `has` [U, 3] bool and `idx`
+    [U, 3] int (the layer's index in its kind's stack; 0 where absent), in
+    the order of `KINDS`."""
+
+    has: np.ndarray
+    idx: np.ndarray
+
+
+KINDS = "M*E"
+STACKS = ("ssm_layers", "attn_layers", "moe_layers")
+
+
+def units_of(pattern: str) -> Units:
+    has, idx, seen = [], [], [0, 0, 0]
+    at = len(KINDS)  # past the last kind: the first layer opens a unit
+    for ch in pattern:
+        k = KINDS.index(ch)
+        if k < at:
+            has.append([False] * 3)
+            idx.append([0] * 3)
+        has[-1][k], idx[-1][k] = True, seen[k]
+        seen[k] += 1
+        at = k + 1
+    return Units(np.asarray(has, bool), np.asarray(idx, np.int32))
+
+
+def split_table(page_table: jax.Array):
+    """(pages' table, read slots [B], write slots [B], inside slots [B,
+    SNAP_COLS]) of a state family's page table."""
+    cols = page_table[:, -STATE_COLS:]
+    return page_table[:, :-STATE_COLS], cols[:, 0], cols[:, 1], cols[:, 2:]
+
+
+def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
+    """Random init (tests).  `A_log` and `dt_bias` are drawn as the family
+    initialises them (A in [1, 16], step sizes in [0.001, 0.1]), so that a
+    state REMEMBERS across hundreds of tokens."""
+    h, pat = cfg.hidden_size, cfg.layer_pattern
+    nM, nA, nE = (pat.count(c) for c in KINDS)
+    d, cd, nh = cfg.ssm_inner, cfg.ssm_conv_dim, cfg.ssm_heads
+    q = cfg.num_attention_heads * cfg.head_dim_
+    kvw = cfg.num_key_value_heads * cfg.head_dim_
+    fm, fs, E = cfg.moe_intermediate_size, cfg.shared_expert_width, cfg.num_experts
+    ks = iter(jax.random.split(key, 24))
+
+    def w(*shape, scale=None):
+        scale = scale or shape[-2] ** -0.5
+        return (jax.random.normal(next(ks), shape, jnp.float32)
+                * scale).astype(dtype)
+
+    dt0 = jnp.exp(jax.random.uniform(
+        next(ks), (nM, nh), jnp.float32, np.log(1e-3), np.log(1e-1)))
+    params = {
+        "embed": w(cfg.vocab_size, h, scale=0.02),
+        "final_norm": jnp.ones((h,), dtype),
+        "lm_head": w(h, cfg.vocab_size),
+        "ssm_layers": {
+            "norm": jnp.ones((nM, h), dtype),
+            "in_proj": w(nM, h, d + cd + nh),
+            "conv_w": w(nM, cfg.ssm_conv_kernel, cd, scale=0.5),
+            "conv_b": w(nM, cd, scale=0.1),
+            # inverse softplus of the drawn step size
+            "dt_bias": dt0 + jnp.log(-jnp.expm1(-dt0)),
+            "A_log": jnp.log(jax.random.uniform(
+                next(ks), (nM, nh), jnp.float32, 1.0, 16.0)),
+            "D": jnp.ones((nM, nh), jnp.float32),
+            "gate_norm": jnp.ones((nM, d), dtype),
+            "out_proj": w(nM, d, h),
+        },
+        "attn_layers": {
+            "norm": jnp.ones((nA, h), dtype),
+            "wq": w(nA, h, q), "wk": w(nA, h, kvw), "wv": w(nA, h, kvw),
+            "wo": w(nA, q, h),
+        },
+        "moe_layers": {
+            "norm": jnp.ones((nE, h), dtype),
+            "router": w(nE, h, cfg.router_width),
+            "router_bias": 0.02 * jax.random.normal(
+                next(ks), (nE, cfg.router_width), jnp.float32),
+            "w_up": w(nE, E, h, fm), "w_down": w(nE, E, fm, h),
+            "ws_up": w(nE, h, fs), "ws_down": w(nE, fs, h),
+        },
+    }
+    return params
+
+
+def _inside(cfg: ModelConfig, S: int, page_size: int) -> tuple:
+    """Token counts inside a chunk of S at which a state is handed out:
+    the multiples of `handout_every` short of the chunk's end, `SNAP_COLS`
+    at most."""
+    every = handout_every(cfg, S, page_size)
+    return tuple(range(every, S, every))[:SNAP_COLS]
+
+
+def _mamba(lp: Params, u: jax.Array, cfg: ModelConfig, window: jax.Array,
+           h0: jax.Array, chunk_lens: jax.Array, page_size: int):
+    """The Mamba-2 mixer over u [B, S, h] (normed) from a row's carried
+    `window` [B, K-1, conv_dim] and state `h0` [B, nh, hp, N] -> (out [B,
+    S, h], window', h', and [(window, h)] after each of `_inside`'s token
+    counts).  Positions at or past `chunk_lens` move neither."""
+    B, S, _ = u.shape
+    d, nh, hp = cfg.ssm_inner, cfg.ssm_heads, cfg.ssm_head_dim
+    G, N, dt_ = cfg.ssm_groups, cfg.ssm_state, u.dtype
+    with jax.named_scope("ssm.in_proj"):
+        zxd = matmul_any(u, lp["in_proj"], "bsh,hd->bsd").astype(dt_)
+        z, xbc, dt = (zxd[..., :d], zxd[..., d:d + cfg.ssm_conv_dim],
+                      zxd[..., d + cfg.ssm_conv_dim:])
+    at = _inside(cfg, S, page_size)
+    with jax.named_scope("ssm.conv"):
+        xbc, window, wins = ssm.conv(xbc, window, lp["conv_w"], lp["conv_b"],
+                                     chunk_lens, at)
+    with jax.named_scope("ssm.scan"):
+        step = jax.nn.softplus(dt.astype(jnp.float32) + lp["dt_bias"])
+        step = jnp.where(_valid_rows(u, chunk_lens)[..., None], step, 0.0)
+        y, h, hs = ssm.scan(
+            xbc[..., :d].reshape(B, S, nh, hp), step, -jnp.exp(lp["A_log"]),
+            xbc[..., d:d + G * N].reshape(B, S, G, N),
+            xbc[..., d + G * N:].reshape(B, S, G, N), lp["D"], h0,
+            cfg.ssm_chunk, at)
+        inside = list(zip(wins, hs))
+    with jax.named_scope("ssm.gate_norm"):
+        y = ssm.gate_norm(y.reshape(B, S, d), z, lp["gate_norm"], G,
+                          cfg.rms_norm_eps)
+    with jax.named_scope("ssm.out_proj"):
+        return (matmul_any(y, lp["out_proj"], "bsd,dh->bsh").astype(dt_),
+                window, h, inside)
+
+
+def _attention(lp: Params, u: jax.Array, cfg: ModelConfig, kv: StateCache,
+               layer, positions, table, prefix_lens, chunk_lens, attn_impl):
+    """GQA attention over u [B, S, h] (normed) and layer `layer`'s pages ->
+    (out [B, S, h], k, v [B, S, n_kv, hd]: the chunk's own)."""
+    B, S, _ = u.shape
+    nh, nkv, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
+                   cfg.head_dim_)
+    with jax.named_scope("attn.qkv"):
+        q, k, v = _qkv_proj(u, lp, cfg, "bsh,hd->bsd")
+        q = q.astype(u.dtype).reshape(B, S, nh, hd)
+        k = k.astype(u.dtype).reshape(B, S, nkv, hd)
+        v = v.astype(u.dtype).reshape(B, S, nkv, hd)
+        if cfg.attention_rope:
+            q, k = _rope_qk(
+                q, k, positions,
+                rope_frequencies(hd, cfg.rope_theta, cfg.rope_scaling),
+                rope_attention_scale(cfg.rope_scaling), None)
+    attn = prefill_attention(q, k, v, kv.k, kv.v, table, prefix_lens,
+                             chunk_lens, impl=attn_impl, layer=layer)
+    with jax.named_scope("attn.out"):
+        return (matmul_any(attn.reshape(B, S, nh * hd), lp["wo"],
+                           "bsd,dh->bsh").astype(u.dtype), k, v)
+
+
+def layers(params: Params, cfg: ModelConfig, kv: StateCache, x: jax.Array,
+           page_table: jax.Array, prefix_lens: jax.Array,
+           chunk_lens: jax.Array, attn_impl: str = "xla",
+           moe_stats: bool = False):
+    """Every layer over an embedded chunk x [B, S, h], as
+    `llama.prefill_layers`: -> (x, kv, *stats).  `page_table` carries the
+    rows' state slots (`split_table`); a decode step is a chunk of one."""
+    B, S, _ = x.shape
+    page_size = kv.page_size
+    table, slot_in, slot_out, slot_inside = split_table(page_table)
+    units = units_of(cfg.layer_pattern)
+    every = units.has.all(0)  # kinds that every unit has: scanned
+    spec = cfg.state_spec
+    positions = prefix_lens[:, None] + jnp.arange(S)[None, :]
+    valid = _valid_rows(x, chunk_lens)
+    n_stats = moe_stats_columns(cfg)
+    fresh = (slot_in == 0)
+
+    def normed(lp, h, mixer):
+        return mixer(rms_norm(h, lp["norm"], cfg.rms_norm_eps))
+
+    def mamba(h, lp, layer):
+        with jax.named_scope("state.read"):
+            win = kv.conv[layer, slot_in].reshape(
+                B, -1)[:, :(spec.conv_kernel - 1) * spec.conv_dim].reshape(
+                B, spec.conv_kernel - 1, spec.conv_dim)
+            win = jnp.where(fresh[:, None, None], 0, win)
+            h0 = jnp.where(fresh[:, None, None, None], 0.0,
+                           kv.ssm[layer, slot_in])
+        out, win, h1, inside = normed(lp, h, lambda u: _mamba(
+            lp, u, cfg, win, h0, chunk_lens, page_size))
+        return h + out, (_as_tiles(win, spec.window_dims), h1, *(
+            (_as_tiles(w, spec.window_dims), hj) for w, hj in inside))
+
+    def attention(h, lp, layer):
+        out, k, v = normed(lp, h, lambda u: _attention(
+            lp, u, cfg, kv, layer, positions, table, prefix_lens, chunk_lens,
+            attn_impl))
+        return h + out, (k, v)
+
+    def experts(h, lp, layer):
+        del layer
+        with jax.named_scope("mlp"):
+            out = normed(lp, h, lambda u: _moe(
+                lp, u, cfg, None, valid, stats=moe_stats))
+        out, *st = out if moe_stats else (out,)
+        return h + out, tuple(st)
+
+    mixers = (mamba, attention, experts)
+    a_state = (jnp.zeros((B, *spec.window_dims), kv.conv.dtype),
+               jnp.zeros((B, *spec.state_dims), jnp.float32)) if spec else ()
+    blank = (
+        (*a_state, *(a_state for _ in _inside(cfg, S, page_size)))
+        if spec else (),
+        tuple(jnp.zeros((B, S, *p.shape[3:]), x.dtype)
+              for p in (kv.k, kv.v)),
+        (jnp.zeros((n_stats,), jnp.int32),) if moe_stats else (),
+    )
+
+    def body(h, xs):
+        has, idx, scanned = xs
+        outs = []
+        for k, mixer in enumerate(mixers):
+            if not units.has[:, k].any():
+                outs.append(())
+            elif every[k]:
+                h, out = mixer(h, scanned[k], idx[k])
+                outs.append(out)
+            else:
+                stack = params[STACKS[k]]
+                h, out = jax.lax.cond(
+                    has[k],
+                    lambda h, i, mixer=mixer, stack=stack: mixer(
+                        h, jax.tree.map(lambda a: a[i], stack), i),
+                    lambda h, i, k=k: (h, blank[k]), h, idx[k])
+                outs.append(out)
+        return h, tuple(outs)
+
+    scanned = tuple(params[STACKS[k]] if every[k] else None
+                    for k in range(len(KINDS)))
+    x, (st_m, st_a, st_e) = jax.lax.scan(
+        body, x, (jnp.asarray(units.has), jnp.asarray(units.idx), scanned))
+
+    def own(ys, k):  # the units that have kind k, in their stack's order
+        rows = np.flatnonzero(units.has[:, k])
+        return ys if every[k] else jax.tree.map(lambda a: a[rows], ys)
+
+    new = {}
+    if st_a:
+        k_new, v_new = own(st_a, 1)
+        new["k"], new["v"] = write_kv_layers(
+            kv.k, kv.v, k_new, v_new, table, prefix_lens, valid)
+    if st_m:
+        win, h1, *inside = own(st_m, 0)
+        with jax.named_scope("state.write"):
+            conv, pool = kv.conv, kv.ssm
+            for j, (w, hj) in enumerate(inside):  # before the chunk's end:
+                # a row whose slots coincide keeps its latest state
+                conv = conv.at[:, slot_inside[:, j]].set(w)
+                pool = pool.at[:, slot_inside[:, j]].set(hj)
+            new["conv"] = conv.at[:, slot_out].set(win)
+            new["ssm"] = pool.at[:, slot_out].set(h1)
+    kv = kv._replace(**new)
+    if not moe_stats:
+        return x, kv
+    return x, kv, merge_moe_stats(own(st_e, 2)[0])
+
+
+def _as_tiles(win: jax.Array, dims: Tuple[int, int]) -> jax.Array:
+    """A window [B, K-1, conv_dim] as the pool stores it: [B, tiles, 128],
+    zeros after its own values."""
+    flat = win.reshape(win.shape[0], -1)
+    pad = dims[0] * dims[1] - flat.shape[1]
+    return jnp.pad(flat, ((0, 0), (0, pad))).reshape(-1, *dims)

@@ -59,7 +59,8 @@ class KVCache(NamedTuple):
     keys and values [L, P, page, n_kv, hd] each, or, for latent attention,
     no per-head keys or values at all: in `k` the rotary key all heads
     share, in `v` the latent ([L, P, page, tiles, 128] each, whole lane
-    tiles a token)."""
+    tiles a token).  A model with state-space layers has a `StateCache` in
+    its place (`create` decides)."""
 
     k: jax.Array
     v: jax.Array
@@ -74,11 +75,36 @@ class KVCache(NamedTuple):
 
     @staticmethod
     def create(
-        cfg: ModelConfig, num_pages: int, page_size: int, dtype=jnp.bfloat16
+        cfg: ModelConfig, num_pages: int, page_size: int, dtype=jnp.bfloat16,
+        state_slots: int = 0,
     ) -> "KVCache":
-        lead = (cfg.num_hidden_layers, num_pages, page_size)
-        return KVCache(*(jnp.zeros((*lead, *dims), dtype)
-                         for dims in cfg.cache_spec.plane_dims))
+        lead = (cfg.num_kv_layers, num_pages, page_size)
+        pages = (jnp.zeros((*lead, *dims), dtype)
+                 for dims in cfg.cache_spec.plane_dims)
+        spec = cfg.state_spec
+        if spec is None:
+            return KVCache(*pages)
+        slots = (spec.layers, max(state_slots, 1))  # slot 0: trash
+        return StateCache(
+            *pages, jnp.zeros((*slots, *spec.window_dims), dtype),
+            jnp.zeros((*slots, *spec.state_dims), jnp.float32))
+
+
+class StateCache(NamedTuple):
+    """`KVCache` of a model with state-space layers (`ModelConfig.
+    state_spec`), every step's one donated operand: the pages of its
+    attention layers alone in `k` and `v` (`ModelConfig.num_kv_layers`),
+    and beside them the state SLOTS of its state-space layers: `conv` [Lm,
+    slots, tiles, 128] the convolution's window, `ssm` [Lm, slots, heads,
+    head_dim, state] the recurrent state in float32."""
+
+    k: jax.Array
+    v: jax.Array
+    conv: jax.Array
+    ssm: jax.Array
+
+    num_pages = KVCache.num_pages
+    page_size = KVCache.page_size
 
 
 # --------------------------------------------------------------------------- #
@@ -97,6 +123,10 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
         scale = scale or (1.0 / jnp.sqrt(shape[-2] if len(shape) > 1 else h))
         return (jax.random.normal(k, shape, jnp.float32) * scale).astype(dtype)
 
+    if cfg.layer_pattern is not None:
+        from . import hybrid
+
+        return hybrid.init_params(cfg, key, dtype)
     if cfg.is_latent or cfg.first_k_dense:
         return _init_params_stacks(cfg, key, dtype, w)
     layers = {
@@ -417,7 +447,11 @@ def moe_act(cfg: ModelConfig, gate: jax.Array, up: jax.Array) -> jax.Array:
     """Expert gating nonlinearity (float32 in/out).  "silu" is the
     mixtral family; "gpt_oss_glu" is HF GptOssExperts: gate clamped to
     <= 7, up to |7|, glu = gate*sigmoid(1.702*gate), out = (up+1)*glu;
-    "relu_glu" is SmallThinker's sparse ReGLU, relu(gate) * up."""
+    "relu_glu" is SmallThinker's sparse ReGLU, relu(gate) * up; "relu2" is
+    nemotron_h's UNGATED expert, relu(up)^2: `gate` is None, the params hold
+    no gate matrix and the expert bodies compute none (`_gated`)."""
+    if cfg.moe_act == "relu2":
+        return jnp.square(jax.nn.relu(up))
     if cfg.moe_act == "gpt_oss_glu":
         limit = 7.0
         gate = jnp.minimum(gate, limit)
@@ -428,6 +462,11 @@ def moe_act(cfg: ModelConfig, gate: jax.Array, up: jax.Array) -> jax.Array:
     if cfg.moe_act == "silu":
         return jax.nn.silu(gate) * up
     raise ValueError(f"unknown moe_act {cfg.moe_act!r}")
+
+
+def _gated(cfg: ModelConfig) -> bool:
+    """Does an expert multiply by a gate matrix beside `w_up`?"""
+    return cfg.moe_act != "relu2"
 
 
 def moe_router_logits(lp: Params, x: jax.Array, eq: str) -> jax.Array:
@@ -481,11 +520,15 @@ def _held(selected: jax.Array, cfg: ModelConfig) -> jax.Array:
 
 @jax.named_scope("moe.shared")
 def _moe_shared(lp: Params, x: jax.Array) -> jax.Array:
-    """The shared expert every token passes (one SwiGLU), computed on every
-    rank of the layer's share alike."""
-    gate = matmul_any(x, lp["ws_gate"], "bsh,hf->bsf")
-    up = matmul_any(x, lp["ws_up"], "bsh,hf->bsf")
-    act = jax.nn.silu(gate) * up
+    """The shared expert every token passes (one SwiGLU, or without a gate
+    matrix relu(up)^2), computed on every rank of the layer's share alike."""
+    if "ws_gate" in lp:
+        gate = matmul_any(x, lp["ws_gate"], "bsh,hf->bsf")
+        up = matmul_any(x, lp["ws_up"], "bsh,hf->bsf")
+        act = jax.nn.silu(gate) * up
+    else:
+        act = jnp.square(jax.nn.relu(
+            matmul_any(x, lp["ws_up"], "bsh,hf->bsf")))
     return matmul_any(act.astype(x.dtype), lp["ws_down"],
                       "bsf,fh->bsh").astype(x.dtype)
 
@@ -506,7 +549,9 @@ def _moe_dense(lp: Params, x: jax.Array, cfg: ModelConfig,
         combine = jnp.einsum("bsk,bske->bse", weights.astype(x.dtype),
                              onehot)  # [B,S,E]
     with jax.named_scope("moe.experts"):
-        gate = jnp.einsum("bsh,ehf->ebsf", x, lp["w_gate"], preferred_element_type=jnp.float32)
+        gate = jnp.einsum(
+            "bsh,ehf->ebsf", x, lp["w_gate"],
+            preferred_element_type=jnp.float32) if _gated(cfg) else None
         up = jnp.einsum("bsh,ehf->ebsf", x, lp["w_up"], preferred_element_type=jnp.float32)
         if "b_gate" in lp:
             gate = gate + lp["b_gate"][:, None, None, :]
@@ -558,7 +603,7 @@ def _moe_ragged(lp: Params, x: jax.Array, cfg: ModelConfig,
         gate = jax.lax.ragged_dot(
             xs, lp["w_gate"], group_sizes,
             preferred_element_type=jnp.float32,
-        )
+        ) if _gated(cfg) else None
         up = jax.lax.ragged_dot(
             xs, lp["w_up"], group_sizes,
             preferred_element_type=jnp.float32,
@@ -680,7 +725,7 @@ def _moe(lp: Params, x: jax.Array, cfg: ModelConfig,
         # inside an explicit expert-sharded shard_map; outside one the
         # dropless ragged dispatch is the same math on one shard
         out = _moe_ragged(lp, x, cfg, routed=routed)
-    if "ws_gate" in lp:
+    if "ws_up" in lp:
         out = out + _moe_shared(lp, x)
     if not stats:
         return out
@@ -706,6 +751,9 @@ def _moe_capacity(lp: Params, x: jax.Array, cfg: ModelConfig,
     E, k = cfg.num_experts, cfg.num_experts_per_tok
     T = B * S
     cap_f = cfg.moe_capacity_factor
+    if not _gated(cfg):
+        raise ValueError("moe_impl='capacity' multiplies by a gate matrix: "
+                         f"not implemented for moe_act {cfg.moe_act!r}")
     if cap_f <= 0:  # dense fallback (tests / tiny models)
         return _moe_dense(lp, x, cfg, router_x=router_x)
 
@@ -1127,6 +1175,22 @@ def require_plain_cache(cfg: ModelConfig, what: str) -> None:
             f"{what} does not carry latent pages, a dense-then-expert "
             f"layer stack or a multi-stream residual yet ({cfg.model_type}): "
             "serve this family flat on one chip (replicas: --dp-ranks)")
+    if cfg.layer_pattern is not None:
+        raise ValueError(
+            f"{what} does not carry a layer pattern, pages for some layers "
+            f"only or state slots beside the pages yet ({cfg.model_type}): "
+            "serve this family flat on one chip (replicas: --dp-ranks)")
+
+
+def require_no_state(cfg: ModelConfig, what: str) -> None:
+    """A step kind that would have to roll a recurrent state back, or that
+    has its own layer body without one: refuse a family with state-space
+    layers by name, at start-up."""
+    if cfg.layer_pattern is not None:
+        raise ValueError(
+            f"{what} does not carry the recurrent state of state-space "
+            f"layers ({cfg.model_type}): a state cannot be rolled back, or "
+            "this step has a layer body of its own that threads none")
 
 
 def _streams(cfg: ModelConfig, x: jax.Array) -> jax.Array:
@@ -1321,12 +1385,19 @@ def forward_prefill(
             x = jnp.where(extra_mask[..., None],
                           extra_embeds.astype(x.dtype), x)
         x = _streams(cfg, x)
-    x, kv, *st = prefill_layers(
-        layer_stacks(params), cfg, kv, x, positions, page_table, prefix_lens,
-        chunk_lens, attn_impl,
-        rope_pos=mm_positions if cfg.mrope_section else None,
-        moe_stats=moe_stats,
-    )
+    if cfg.layer_pattern is not None:
+        from . import hybrid
+
+        x, kv, *st = hybrid.layers(params, cfg, kv, x, page_table,
+                                   prefix_lens, chunk_lens, attn_impl,
+                                   moe_stats)
+    else:
+        x, kv, *st = prefill_layers(
+            layer_stacks(params), cfg, kv, x, positions, page_table,
+            prefix_lens, chunk_lens, attn_impl,
+            rope_pos=mm_positions if cfg.mrope_section else None,
+            moe_stats=moe_stats,
+        )
 
     def head(x):
         last = jnp.maximum(chunk_lens - 1, 0)
@@ -1357,6 +1428,7 @@ def forward_embed(
     the chunk computes is thrown away, so the pool `_layer_prefill` reads is
     a one-slot stand-in that attention masks out whole."""
     B, S = tokens.shape
+    require_no_state(cfg, "the embedding forward")
     kv = KVCache.create(cfg, 1, 1, jnp.float32)
     table = jnp.zeros((B, 1), jnp.int32)
     inv_freq = rope_frequencies(_rope_dim(cfg), cfg.rope_theta,
@@ -1403,6 +1475,16 @@ def forward_decode(
     """One decode step for the whole batch; returns (logits [B, V], kv)."""
     with jax.named_scope("embed"):
         x = _streams(cfg, params["embed"][tokens])  # [B, h]
+    if cfg.layer_pattern is not None:
+        # a chunk of one token through the one layer loop: the state is
+        # read from and written to the row's slots, the token's keys and
+        # values land after the loop
+        from . import hybrid
+
+        x, kv, *st = hybrid.layers(
+            params, cfg, kv, x[:, None], page_table, positions,
+            jnp.ones_like(positions), attn_impl, moe_stats)
+        return (_lm_logits(params, cfg, x[:, 0]), kv, *st)
     x, kv, *st = decode_layers(
         layer_stacks(params), cfg, kv, x, positions, page_table, attn_impl,
         rope_offset=rope_offset, moe_stats=moe_stats,
@@ -1438,6 +1520,7 @@ def forward_verify(
     biases, mrope-as-shifted-rope) stays in ONE implementation — no
     drift tripwire needed against the prefill path."""
     B, S = tokens.shape
+    require_no_state(cfg, "the draft-verify step")
     positions = prefix_lens[:, None] + jnp.arange(S)[None, :]
     if rope_offset is not None:
         # positions feed ONLY rope inside _layer_prefill (the KV write is
@@ -1492,6 +1575,7 @@ def decode_block_scan(
     here, and vice versa; the engine golden/greedy-equality suites
     (gpt-oss, qwen-vl, swa, pooled) run through THIS path on CPU and on
     short-context TPU, which is what catches a drift."""
+    require_no_state(cfg, "the decode block over gathered pages")
     layers = layer_stacks(params)
     L = kv.k.shape[0]
     P, page = kv.k.shape[1], kv.k.shape[2]

@@ -86,6 +86,8 @@ def load_params(path: str, cfg: ModelConfig, dtype=jnp.bfloat16,
     L = cfg.num_hidden_layers
     if cfg.is_latent:  # deepseek_v3, and xing4_0 around its layers
         return _load_deepseek_v3(r, cfg, dtype, prefix)
+    if cfg.layer_pattern is not None:
+        return _load_nemotron_h(r, cfg, dtype, prefix)
 
     def stack(fmt: str, transpose: bool = True) -> jnp.ndarray:
         return stack_layers(r, L, fmt, transpose=transpose, dtype=dtype)
@@ -233,6 +235,74 @@ def load_params(path: str, cfg: ModelConfig, dtype=jnp.bfloat16,
             params["lm_head"] = jnp.asarray(r.get(prefix + "lm_head.weight").T, dtype)
         else:
             params["lm_head"] = params["embed"].T
+    return params
+
+
+def _load_nemotron_h(r: "_ShardReader", cfg: ModelConfig, dtype,
+                     prefix: str = ""):
+    """nemotron_h tensor names (`backbone.layers.{i}.mixer.*`, one mixer and
+    one `norm` a layer) -> `models.hybrid.init_params`'s three stacks by
+    kind.  Only the experts HELD here are read, under their global indices.
+    The step-size bias, `A_log`, `D` and the router's choosing bias are kept
+    float32 whatever the file's dtype; the convolution's [conv_dim, 1, K]
+    weight is stored tap-major [K, conv_dim]."""
+    pat = cfg.layer_pattern
+    ids = {kind: [i for i, c in enumerate(pat) if c == kind] for kind in "M*E"}
+
+    def get(i, name):
+        return r.get(prefix + f"backbone.layers.{i}.{name}")
+
+    def stack(kind, name, fn=lambda w: w.T, dt=dtype):
+        return jnp.asarray(np.stack([fn(get(i, name)) for i in ids[kind]]),
+                           dt)
+
+    def same(w):
+        return w
+
+    def f32(kind, name):
+        return stack(kind, name, lambda w: w.astype(np.float32), jnp.float32)
+
+    held = range(cfg.first_expert, cfg.first_expert + cfg.num_experts)
+
+    def experts(proj):
+        return jnp.asarray(np.stack([np.stack([
+            get(i, f"mixer.experts.{e}.{proj}_proj.weight").T for e in held])
+            for i in ids["E"]]), dtype)
+
+    params = {
+        "embed": jnp.asarray(r.get(prefix + "backbone.embeddings.weight"),
+                             dtype),
+        "final_norm": jnp.asarray(r.get(prefix + "backbone.norm_f.weight"),
+                                  dtype),
+        "lm_head": jnp.asarray(r.get(prefix + "lm_head.weight").T, dtype),
+        "ssm_layers": {
+            "norm": stack("M", "norm.weight", same),
+            "in_proj": stack("M", "mixer.in_proj.weight"),
+            "conv_w": stack("M", "mixer.conv1d.weight",
+                            lambda w: w.reshape(w.shape[0], -1).T),
+            "conv_b": stack("M", "mixer.conv1d.bias", same),
+            "dt_bias": f32("M", "mixer.dt_bias"),
+            "A_log": f32("M", "mixer.A_log"),
+            "D": f32("M", "mixer.D"),
+            "gate_norm": stack("M", "mixer.norm.weight", same),
+            "out_proj": stack("M", "mixer.out_proj.weight"),
+        },
+        "attn_layers": {
+            "norm": stack("*", "norm.weight", same),
+            "wq": stack("*", "mixer.q_proj.weight"),
+            "wk": stack("*", "mixer.k_proj.weight"),
+            "wv": stack("*", "mixer.v_proj.weight"),
+            "wo": stack("*", "mixer.o_proj.weight"),
+        },
+        "moe_layers": {
+            "norm": stack("E", "norm.weight", same),
+            "router": stack("E", "mixer.gate.weight"),
+            "router_bias": f32("E", "mixer.gate.e_score_correction_bias"),
+            "w_up": experts("up"), "w_down": experts("down"),
+            "ws_up": stack("E", "mixer.shared_experts.up_proj.weight"),
+            "ws_down": stack("E", "mixer.shared_experts.down_proj.weight"),
+        },
+    }
     return params
 
 

@@ -1,0 +1,141 @@
+"""State-space (Mamba-2) layer operations: the causal depthwise convolution
+with a carried window, the chunked selective scan from a carried state, and
+the grouped gated RMS norm.
+
+A sequence leaves such a layer TWO things, whatever its length: the
+convolution's last `kernel - 1` inputs (the window) and the recurrent state
+`H` [heads, head_dim, state] in float32.  Every function here takes them in
+and hands them out, so a prompt may be computed in chunks, one token at a
+time, or padded to a bucket: positions at or past a row's `lens` move
+neither (their step size is zeroed, and the window is taken where the real
+tokens end).
+
+The recurrence, per head (A < 0 a scalar, dt > 0 a token's step):
+
+    H_t = exp(dt_t A) H_{t-1} + dt_t x_t (outer) B_t
+    y_t = H_t C_t + D x_t
+
+`scan` computes it a block of `chunk` tokens at a time (the "state-space
+duality" form): inside a block the outputs are one masked [chunk, chunk]
+product per head, a segment-sum of the log decays standing where attention
+has its softmax; between blocks only `H` is carried.  It is the same
+function of its inputs as the token-by-token loop, not another model.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+
+def conv(xbc: jax.Array, window: jax.Array, w: jax.Array, b: jax.Array,
+         lens: jax.Array, at: tuple = ()):
+    """Causal depthwise convolution over the sequence, then silu.
+
+    xbc [B, S, C] the layer's new inputs; window [B, K-1, C] the inputs
+    before them (zeros before the sequence); w [K, C] (tap j multiplies the
+    input K-1-j positions back), b [C]; lens [B] real tokens a row; `at`
+    static token counts inside the chunk.
+    -> (out [B, S, C], window' [B, K-1, C]: the last K-1 inputs at or
+    before each row's last real token, and the same after each of `at`
+    tokens: right for the rows that have that many real tokens)."""
+    k1 = window.shape[1]
+    S = xbc.shape[1]
+    padded = jnp.concatenate([window.astype(xbc.dtype), xbc], axis=1)
+    acc = b.astype(jnp.float32)
+    for j in range(k1 + 1):
+        acc = acc + (padded[:, j:j + S].astype(jnp.float32)
+                     * w[j].astype(jnp.float32))
+    # padded[b, lens + j], j < K-1, are the inputs lens-(K-1)+j .. lens-1
+    new = jax.vmap(lambda p, n: jax.lax.dynamic_slice_in_dim(p, n, k1, 0))(
+        padded, lens)
+    inside = [padded[:, t:t + k1].astype(window.dtype) for t in at]
+    return (jax.nn.silu(acc).astype(xbc.dtype), new.astype(window.dtype),
+            inside)
+
+
+def scan(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
+         Cm: jax.Array, D: jax.Array, h0: jax.Array, chunk: int,
+         at: tuple = ()):
+    """The selective scan over S tokens from state `h0`.
+
+    x [B, S, nh, hp]; dt [B, S, nh] float32, already softplus'd and ZERO at
+    the positions that must not move the state; A [nh] float32 (negative);
+    Bm, Cm [B, S, G, N], head i reading group i // (nh / G); D [nh]; h0
+    [B, nh, hp, N] float32.  S is a multiple of min(chunk, S).  `at`: static
+    token counts short of S.
+    -> (y [B, S, nh, hp] in x's dtype, h [B, nh, hp, N] float32, and the
+    state after each of `at`'s token counts, a list: at a block's end the
+    scan has it anyway, inside a block it costs the block's state update
+    over the tokens before it; a caller may keep one as a snapshot)."""
+    B, S, nh, hp = x.shape
+    G, N = Bm.shape[2:]
+    Q = min(chunk, S)
+    if S % Q:
+        raise ValueError(f"scan: {S} tokens are not whole blocks of {Q}")
+    nc, per = S // Q, nh // G
+    f32 = jnp.float32
+
+    def blocks(a):  # [B, S, ...] -> nc of [B, Q, ...]
+        return [a[:, c * Q:(c + 1) * Q] for c in range(nc)]
+
+    tri = jnp.tril(jnp.ones((Q, Q), bool))
+
+    def block(h, xs, cuts):
+        xq, dtq, bq, cq = xs  # [B,Q,nh,hp] [B,Q,nh] [B,Q,G,N] [B,Q,G,N]
+        dth = jnp.moveaxis(dtq, 2, 1)  # [B, nh, Q]
+        cum = jnp.cumsum(dth * A[:, None], axis=-1)  # L_t: log decays, <= 0
+        # exp(L_t - L_s), s <= t: never above 1
+        seg = cum[..., :, None] - cum[..., None, :]  # [B, nh, t, s]
+        decay = jnp.where(tri, jnp.exp(seg), 0.0)
+        cb = jnp.einsum("btgn,bsgn->bgts", cq, bq,
+                        preferred_element_type=f32)  # [B, G, t, s]
+        m = jnp.repeat(cb, per, axis=1) * decay * dth[:, :, None, :]
+        y = jnp.einsum("bhts,bshp->bthp", m.astype(x.dtype), xq,
+                       preferred_element_type=f32)
+        # what the carried state adds: exp(L_t) C_t . H
+        ch = jnp.einsum("btgn,bgkpn->btgkp", cq.astype(f32),
+                        h.reshape(B, G, per, hp, N),
+                        preferred_element_type=f32).reshape(B, Q, nh, hp)
+        y = y + jnp.moveaxis(jnp.exp(cum), 1, 2)[..., None] * ch
+        return [after(h, xs, cum, c) for c in cuts], after(h, xs, cum, Q), y
+
+    def after(h, xs, cum, c):
+        """The state after the block's first c tokens, from the state `h`
+        before it."""
+        xq, dtq, bq, _ = xs
+        last = cum[..., c - 1]  # [B, nh]: L_c
+        wgt = jnp.exp(last[..., None] - cum[..., :c]) * jnp.moveaxis(
+            dtq, 2, 1)[..., :c]
+        xw = (xq[:, :c].astype(f32) * jnp.moveaxis(wgt, 1, 2)[..., None]
+              ).reshape(B, c, G, per, hp)
+        add = jnp.einsum("bsgkp,bsgn->bgkpn", xw, bq[:, :c].astype(f32),
+                         preferred_element_type=f32).reshape(B, nh, hp, N)
+        return jnp.exp(last)[:, :, None, None] * h + add
+
+    # the blocks one after the other, unrolled (a prompt chunk is at most a
+    # few): a `lax.scan` would stack the blocks' float32 outputs through a
+    # strided update a block (a tenth of a step's device time on the v5e:
+    # PERF.md, PR 44)
+    h, ys, states = h0.astype(f32), [], {}
+    for n, xs in enumerate(zip(blocks(x), blocks(dt.astype(f32)),
+                               blocks(Bm), blocks(Cm))):
+        cuts = [t - n * Q for t in at if n * Q < t < (n + 1) * Q]
+        inside, h, y = block(h, xs, cuts)
+        ys.append(y)
+        states.update(zip((n * Q + c for c in cuts), inside))
+        states[(n + 1) * Q] = h
+    y = ys[0] if nc == 1 else jnp.concatenate(ys, axis=1)
+    y = y + D.astype(f32)[:, None] * x.astype(f32)
+    return y.astype(x.dtype), h, [states[t] for t in at]
+
+
+def gate_norm(y: jax.Array, z: jax.Array, w: jax.Array, groups: int,
+              eps: float) -> jax.Array:
+    """rms_norm, over each of `groups` equal groups of the last axis, of
+    y * silu(z): the gate FIRST, then the norm.  y, z [..., d]; w [d]."""
+    g = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    parts = g.reshape(*g.shape[:-1], groups, -1)
+    var = jnp.mean(parts * parts, axis=-1, keepdims=True)
+    out = (parts * jax.lax.rsqrt(var + eps)).reshape(g.shape)
+    return (out * w.astype(jnp.float32)).astype(y.dtype)

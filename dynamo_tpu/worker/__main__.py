@@ -108,6 +108,11 @@ def build_parser() -> argparse.ArgumentParser:
                          "pairs the test tower with --model tiny")
     ap.add_argument("--page-size", type=int, default=16)
     ap.add_argument("--num-pages", type=int, default=2048)
+    ap.add_argument("--num-state-slots", type=int, default=32,
+                    help="state slots beside the pages, for a model with "
+                         "state-space layers (unused otherwise), trash slot "
+                         "0 included: one a running sequence, the rest "
+                         "hash-addressed snapshots of a sequence's state")
     ap.add_argument("--max-num-seqs", type=int, default=16)
     ap.add_argument("--max-prefill-tokens", type=int, default=512)
     ap.add_argument("--max-model-len", type=int, default=4096)
@@ -330,6 +335,7 @@ def engine_config_from_args(args):
     return EngineConfig(
         page_size=args.page_size,
         num_pages=args.num_pages,
+        num_state_slots=getattr(args, "num_state_slots", 32),
         max_num_seqs=args.max_num_seqs,
         max_prefill_tokens=args.max_prefill_tokens,
         max_model_len=args.max_model_len,
@@ -683,6 +689,11 @@ async def _run(args) -> None:
         # must stay off JAX (one process per chip) reads it from here
         if hasattr(engine, "cache_report"):  # page kind, bytes a token
             print("CACHE " + _json.dumps(engine.cache_report()), flush=True)
+            # the second pool, where the model has state-space layers: the
+            # slots of recurrent state beside the pages
+            if engine.state_report() is not None:
+                print("STATE " + _json.dumps(engine.state_report()),
+                      flush=True)
             # what the layer loops carry: x + f(x), or the streams of
             # hyper-connections
             print("RESIDUAL " + _json.dumps(

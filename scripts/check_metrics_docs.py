@@ -82,6 +82,14 @@ def representative_engine_stats() -> dict:
     stats["moe_experts_hit_total"] = 0
     stats["moe_steps_total"] = 0
     stats["hc_res_err_ppm_max"] = 0  # a residual of several streams
+    # state slots beside the pages (a model with state-space layers)
+    stats["state_slots_total"] = 0
+    stats["state_slots_running"] = 0
+    stats["state_snapshots"] = 0
+    stats["state_snapshot_stored_total"] = 0
+    stats["state_snapshot_hits_total"] = 0
+    stats["state_snapshot_evictions_total"] = 0
+    stats["state_hit_tokens_shortened_total"] = 0
     # KVBM tiers (engine.metrics() with a connector attached)
     stats["kvbm_host_blocks"] = 0
     stats["kvbm_pending_offloads"] = 0

@@ -68,6 +68,9 @@ def main():
         run = json.load(f)
     base = ModelConfig.from_hf_config(run["model"])
     pages = run["worker_flags"]["--num-pages"]
+    # a model with state-space layers: its state slots beside the pages
+    # (every row reads and writes a slot of its own)
+    slots = run["worker_flags"].get("--num-state-slots", 32)
     params = jax.jit(lambda: init_params(base, jax.random.PRNGKey(0),
                                          dtype))()
     shapes = [(int(b), int(t), int(c), int(h)) for b in args.batch.split(",")
@@ -77,16 +80,20 @@ def main():
                                              args.attention.split(",")):
         cfg = dataclasses.replace(base, moe_impl=impl)
         layout = Layout.resolve(cfg, EngineConfig(
-            num_pages=pages, max_model_len=4096,
+            num_pages=pages, num_state_slots=slots, max_model_len=4096,
             attention_impl=attention))[0]
         step = layout.prefill_step(False, greedy=True)
-        kv = KVCache.create(cfg, pages, 16, dtype)
+        kv = KVCache.create(cfg, pages, 16, dtype, state_slots=slots)
         for batch, table_pages, chunk, head in shapes:
             ones = jnp.ones((batch,), jnp.float32)
             zeros = jnp.zeros((batch,), jnp.int32)
             samp = SamplingParams(ones, zeros, ones, ones, ones)
             table = jnp.arange(1, 1 + batch * table_pages,
                                dtype=jnp.int32).reshape(batch, table_pages)
+            if layout.state_cols:  # [read, write, no snapshot inside]
+                own = 1 + jnp.arange(batch, dtype=jnp.int32)[:, None]
+                none = jnp.zeros((batch, layout.state_cols - 2), jnp.int32)
+                table = jnp.concatenate([table, own, own, none], axis=1)
             prefix = jnp.full((batch,), table_pages * 16 - chunk, jnp.int32)
             toks = jnp.asarray(np.random.default_rng(0).integers(
                 4, 260, (batch, chunk)), jnp.int32)
@@ -108,7 +115,8 @@ def main():
                         times.append((time.perf_counter() - t0) * 1e3)
             except Exception as e:  # noqa: BLE001 — a form that does not fit
                 print(json.dumps(dict(shape, error=str(e)[:300])), flush=True)
-                kv = KVCache.create(cfg, pages, 16, dtype)  # was donated
+                kv = KVCache.create(cfg, pages, 16, dtype,
+                                    state_slots=slots)  # was donated
                 continue
             print(json.dumps(dict(
                 shape, device=dev.device_kind, prefix=int(prefix[0]),

@@ -669,7 +669,8 @@ def lowering_digest(model_cfg, by_rows=False):
 
     samp = SamplingParams(f32(1), i32(1), f32(1), f32(1), f32(1))
     rows = (jax.ShapeDtypeStruct((1,), jnp.bool_),) if by_rows else ()
-    text = step.lower(p, kv, i32(1, 16), i32(1, 4), i32(1), i32(1), samp,
+    text = step.lower(p, kv, i32(1, 16), i32(1, 4 + layout.state_cols),
+                      i32(1), i32(1), samp,
                       jax.ShapeDtypeStruct((1,), jnp.uint32), i32(1), *rows
                       ).as_text()
     text = re.sub(r"loc\([^)]*\)", "", text)
@@ -681,7 +682,7 @@ def lowering_digest(model_cfg, by_rows=False):
 @pytest.mark.parametrize("by_rows", [False, True],
                          ids=["every-row", "by-rows"])
 @pytest.mark.parametrize("family", ["qwen2", "smallthinker", "deepseek_v3",
-                                    "xing4_0"])
+                                    "xing4_0", "nemotron_h"])
 def test_a_model_without_leading_dense_layers_traces_what_it_traced(family,
                                                                     by_rows):
     """The prefill step of a tiny Qwen2 (dense, biased) and of a tiny
@@ -705,7 +706,13 @@ def test_a_model_without_leading_dense_layers_traces_what_it_traced(family,
     (`ops.latent_attention.prefill_attention`): off the chip, and wherever
     "xla" is asked for, it is the form it was, to the letter.  The tiny
     xing4_0's two digests (latent pages around a residual of streams) were
-    taken on PR 42's tree."""
+    taken on PR 42's tree.
+
+    The tiny nemotron_h's two (one scan over units of three kinds of mixer,
+    pages for its attention layers alone, state slots beside them, their
+    numbers in the table's last two columns) were taken on PR 44's tree,
+    which left every digest above as it was."""
+    from test_nemotron_h import TINY as NEMOTRON
     from test_smallthinker import TINY as ST
     from test_xing4_0 import TINY as XING
 
@@ -715,7 +722,9 @@ def test_a_model_without_leading_dense_layers_traces_what_it_traced(family,
                  "deepseek_v3": lambda: ModelConfig.from_hf_config(
                      TINY, name="tiny-deepseek-v3"),
                  "xing4_0": lambda: ModelConfig.from_hf_config(
-                     XING, name="tiny-xing4-0")}[family]()
+                     XING, name="tiny-xing4-0"),
+                 "nemotron_h": lambda: ModelConfig.from_hf_config(
+                     NEMOTRON, name="tiny-nemotron-h")}[family]()
     with open(GOLDEN) as f:
         golden = json.load(f)
     assert lowering_digest(model_cfg, by_rows) == golden[
