@@ -47,6 +47,23 @@ otherwise.  An entry that never reaches the backend (traced inline
 under another jit, ``eval_shape``, ``.lower()``) keeps what it saw and
 reaches no sink.
 
+Program store
+-------------
+The probe is also the one door every engine program is born through, so
+it is where a program's lowered module is kept across processes
+(``compile_cache.ProgramStore``, installed by ``set_program_store``).  A
+program that comes with a description of what its body closes over
+(``ledgered_jit(..., closes_over=...)``: ``Layout.wrap`` gives one) is
+looked up BEFORE its body is traced, by that description and the call's
+abstract signature.  A miss exports the body (``jax.export``: the one
+trace and lowering, where they happened anyway), writes the module with
+the ``note_path_choice`` notes its trace made, and calls it; a hit replays
+the notes and calls the stored module, so a cold and a warm start run the
+same StableHLO under the same outer ``jit``.  The birth says ``stored`` 1
+or 0.  A program whose export or call raises is traced as before, says so
+once in a ``program_store`` path choice, and its birth carries no
+``stored``.
+
 Transfer guard (``DYN_TPU_XFERCHECK=1``)
 ----------------------------------------
 Role threads (``step``/``drain`` per ``contracts.THREAD_NAME_ROLES``)
@@ -99,6 +116,7 @@ __all__ = [
     "note_transfer_violation",
     "reset",
     "set_program_sink",
+    "set_program_store",
     "steady_scope",
     "summary",
     "thread_role_init",
@@ -154,11 +172,15 @@ class CompileEntry:
     compile_us: Optional[int] = None  # backend stage less the cache load
     load_us: Optional[int] = None     # persistent-cache retrieval
     hit: Optional[int] = None         # 1: the cache answered, 0: compiled
+    # 1: the lowered module came from the program store, 0: it was derived
+    # and written there; None: no store, or the program was declined
+    stored: Optional[int] = None
 
     def format(self) -> str:
         tag = f" {self.tags}" if self.tags else ""
         stages = "".join(
-            f" hit={v}" if k == "hit" else f" {k[:-3]}={v / 1000:.1f}ms"
+            f" {k}={v}" if k in ("hit", "stored")
+            else f" {k[:-3]}={v / 1000:.1f}ms"
             for k, v in self.stages().items())
         return (f"{self.fn}({self.signature}){tag} [thread={self.thread}]"
                 f"{stages}")
@@ -166,7 +188,7 @@ class CompileEntry:
     def stages(self) -> Dict[str, int]:
         """The stages seen, by their event-attribute names."""
         return {k: getattr(self, k) for k in (
-            "trace_us", "lower_us", "compile_us", "load_us", "hit")
+            "trace_us", "lower_us", "compile_us", "load_us", "hit", "stored")
             if getattr(self, k) is not None}
 
 
@@ -183,6 +205,11 @@ _backend_compile_secs = 0.0
 _program_events = 0   # births handed to the sinks
 _programs_sub_ms = 0  # unledgered births under 1 ms: counted, not handed
 _program_sink: Optional[Callable[[int, int, Dict[str, Any]], None]] = None
+# `compile_cache.ProgramStore` (`set_program_store`): None = every program
+# is traced, as before there was one
+_program_store: Any = None
+_programs_stored = 0        # births whose module came from the store
+_programs_store_writes = 0  # births whose module was derived and written
 _BORN_MAX = 64        # births a thread remembers for `births_between`
 # persistent-cache outcomes (jax/_src/compiler.py events): requests that
 # consulted the cache, and those it answered with a stored executable —
@@ -267,12 +294,16 @@ def _record_trace(fn_name: str, signature: str,
 
 
 def ledgered_jit(fn: Callable, *, tags: Optional[Dict[str, Any]] = None,
-                 name: Optional[str] = None, **jit_kwargs) -> Callable:
+                 name: Optional[str] = None, closes_over: Any = None,
+                 **jit_kwargs) -> Callable:
     """``jax.jit`` with compile attribution.
 
     ``name`` names the compiled program (``jit_<name>`` on a profiler
     trace's "XLA Modules" line); the ledger keeps attributing compiles
-    to the function's qualified name.
+    to the function's qualified name.  ``closes_over`` describes, by its
+    ``repr``, everything ``fn`` closes over that its lowered module depends
+    on: with it (and a store installed) the module is kept in and taken
+    from the program store; without it the program is traced every time.
 
     Drop-in at the call sites the engine uses
     (``partial(ledgered_jit, donate_argnums=...)`` mirrors
@@ -288,6 +319,8 @@ def ledgered_jit(fn: Callable, *, tags: Optional[Dict[str, Any]] = None,
         return jax.jit(fn, **jit_kwargs)
     import functools
 
+    donate = jit_kwargs.get("donate_argnums", ())
+
     @functools.wraps(fn)
     def probe(*args, **kwargs):
         signature, sig = _fmt_signature(args, kwargs)
@@ -299,11 +332,133 @@ def ledgered_jit(fn: Callable, *, tags: Optional[Dict[str, Any]] = None,
             _tls.birth = e
         _tls.trace_depth = depth + 1
         try:
+            if depth == 0 and closes_over is not None:
+                module = _from_store(e, fn, donate, closes_over, args, kwargs)
+                if module is not None:
+                    return module
             return fn(*args, **kwargs)
         finally:
             _tls.trace_depth = depth
 
     return jax.jit(probe, **jit_kwargs)
+
+
+def set_program_store(store: Any) -> None:
+    """`compile_cache.configure` installs the process's `ProgramStore`;
+    None takes it away (every program is traced)."""
+    global _program_store
+    _program_store = store
+
+
+def _abstract_signature(args: tuple, kwargs: dict) -> str:
+    """The WHOLE signature of a call under trace: the tree and every
+    leaf's abstract value (shape, dtype, weak type, and its sharding where
+    the type carries one)."""
+    leaves, tree = jax.tree_util.tree_flatten((args, kwargs))
+    return f"{tree}: " + ", ".join(
+        repr(getattr(x, "aval", x)) for x in leaves)
+
+
+_stored_module_p: Any = None
+
+
+def _stored_module_primitive() -> Any:
+    """`jax.export`'s `call_exported` under a name of our own: its abstract
+    evaluation and its lowering rule as they are.  jax (0.9,
+    `pxla.jaxpr_transfer_mem_kinds`) finds `call_exported` by NAME in a
+    jitted function and COMMITS that function's results to their device
+    (for modules whose results live in host memory).  A flat engine's
+    arrays are uncommitted, so a step fed its own committed pool back would
+    be lowered and compiled a second time under its second signature.  A
+    jax without the two rules raises here, and the program is traced."""
+    global _stored_module_p
+    with _LOCK:
+        if _stored_module_p is None:
+            from jax._src.export import _export
+            from jax.extend.core import Primitive
+            from jax.interpreters import mlir
+
+            p = Primitive("stored_module")
+            p.multiple_results = True
+            p.def_effectful_abstract_eval(
+                _export._call_exported_abstract_eval)
+            mlir.register_lowering(p, _export._call_exported_lowering)
+            _stored_module_p = p
+        return _stored_module_p
+
+
+def _call_module(exported: Any, args: tuple, kwargs: dict) -> Any:
+    """The stored module called on the probe's tracers.  An operand the
+    module does not read (a greedy step's `top_k`) gets a constant in its
+    place, so the outer ``jit`` drops it from the executable's parameters
+    as it drops an operand its own trace never touched: the call by itself
+    would use them all."""
+    import jax.numpy as jnp
+
+    leaves, tree = jax.tree_util.tree_flatten((args, kwargs))
+    if tree != exported.in_tree:
+        raise ValueError(f"called with {tree}, stored for {exported.in_tree}")
+    if any(a.memory_space != jax.memory.Space.Device
+           for a in exported.out_avals):
+        raise ValueError("a result outside device memory")
+    kept = set(exported.module_kept_var_idx)
+    out = _stored_module_primitive().bind(
+        *[x if i in kept else jnp.zeros(x.shape, x.dtype)
+          for i, x in enumerate(leaves)], exported=exported)
+    return exported.out_tree.unflatten(out)
+
+
+def _from_store(e: CompileEntry, fn: Callable, donate: Any, closes_over: Any,
+                args: tuple, kwargs: dict) -> Any:
+    """The traced result of the program's stored module, called on the
+    probe's tracers, or None where the caller has to trace ``fn``: no
+    store, or a program that cannot be carried (its export, its
+    serialisation or the call of its module raised)."""
+    global _programs_stored, _programs_store_writes
+    store = _program_store
+    if store is None:
+        return None
+    from jax import export
+
+    try:
+        key = store.key(
+            name=e.program, tags=e.tags, donate=donate,
+            closes_over=closes_over,
+            signature=_abstract_signature(args, kwargs))
+        got = store.load(key)
+        if got is None:
+            avals = jax.tree.map(lambda x: x.aval, (args, kwargs))
+            # the trace's `note_path_choice` notes go with the module; jax
+            # reports the inner trace and lowering like any other, and the
+            # listener leaves them in this birth's own trace
+            _tls.notes = []
+            try:
+                exported = export.export(
+                    jax.jit(fn, donate_argnums=donate))(*avals[0], **avals[1])
+                notes = _tls.notes
+            finally:
+                _tls.notes = None
+            wrote = store.save(key, exported, notes)
+        else:
+            exported, notes = got
+            for note in notes:
+                _note_path_choice(tuple(note))
+        # a cold start runs what a warm one will: the module as the file
+        # holds it
+        out = _call_module(exported, args, kwargs)
+        e.stored = int(got is not None)
+        with _LOCK:
+            if got is None:
+                _programs_store_writes += wrote
+            else:
+                _programs_stored += 1
+        return out
+    except Exception as why:  # noqa: BLE001 — whatever refuses, trace it
+        note_path_choice(
+            "program_store", "traced",
+            f"{type(why).__name__}: {str(why).splitlines()[0][:160]}"
+            if str(why) else type(why).__name__, program=e.program)
+        return None
 
 
 @contextlib.contextmanager
@@ -363,13 +518,21 @@ def note_path_choice(site: str, choice: str, reason: str, **dims) -> None:
     engine's block/per-step decode choice), so it runs once per compiled
     variant and never on the hot path.  Each distinct decision is logged
     once; `summary()["path_choices"]` carries all of them for the status
-    server."""
-    key = (site, choice, reason,
-           ",".join(f"{k}={v}" for k, v in sorted(dims.items())))
+    server.  A trace made for the program store keeps its notes with the
+    module, and a start that takes the module from the store notes them
+    again."""
+    _note_path_choice((site, choice, reason,
+                       ",".join(f"{k}={v}" for k, v in sorted(dims.items()))))
+
+
+def _note_path_choice(key: Tuple[str, str, str, str]) -> None:
+    notes = getattr(_tls, "notes", None)
+    if notes is not None:
+        notes.append(list(key))
     with _LOCK:
         first = key not in _path_choices
         _path_choices[key] = _path_choices.get(key, 0) + 1
-        _choice_by_dims[(site, key[3])] = choice
+        _choice_by_dims[(key[0], key[3])] = key[1]
     if first:
         import logging
 
@@ -395,6 +558,8 @@ def summary() -> dict:
             "cache_misses": _cache_requests - _cache_hits,
             "program_events": _program_events,
             "programs_sub_ms": _programs_sub_ms,
+            "programs_stored": _programs_stored,
+            "programs_store_writes": _programs_store_writes,
             "path_choices": [
                 {"site": s, "choice": c, "reason": r, "dims": d, "traces": n}
                 for (s, c, r, d), n in _path_choices.items()
@@ -410,8 +575,10 @@ def reset() -> None:
     global _decode_blocks, _auto_steady_armed, _backend_compiles
     global _backend_compile_secs, _cache_hits, _cache_requests
     global _program_events, _programs_sub_ms
+    global _programs_stored, _programs_store_writes
     with _LOCK:
         _program_events = _programs_sub_ms = 0
+        _programs_stored = _programs_store_writes = 0
         _path_choices.clear()
         _choice_by_dims.clear()
         _backend_compile_secs = 0.0
@@ -503,6 +670,8 @@ def _born(e: CompileEntry, backend_s: float) -> None:
 def _on_event_duration(event: str, duration: float, fun_name: str = "",
                        **kwargs) -> None:
     global _backend_compiles, _backend_compile_secs
+    if getattr(_tls, "notes", None) is not None:
+        return  # an export inside the open birth's trace: `_from_store`
     b = getattr(_tls, "birth", None)
     if "backend_compile" in event:
         with _LOCK:

@@ -117,6 +117,15 @@ class Layout:
             from ..models.hybrid import STATE_COLS
 
             self.state_cols = STATE_COLS
+        # what every program of this layout closes over besides its own
+        # variant key: the program store's half of a program's identity
+        # (`wrap`), the call's signature being the other
+        self._described = (
+            model_cfg, cfg, parallel, self.attn_impl, multihost,
+            jax.process_index(), jax.process_count(),
+            None if mesh is None else (
+                mesh.axis_names, mesh.devices.shape,
+                [d.id for d in mesh.devices.flat]))
         self._prefill_steps: Dict[tuple, Callable] = {}
         self._decode_steps: Dict[tuple, Callable] = {}
         self._mixed_steps: Dict[tuple, Callable] = {}
@@ -628,9 +637,16 @@ class Layout:
             *[rep] * lead, *[shard(s) for s in mids],
             *([shard(self.kv_pspec)] if kv else []))}
 
-    def wrap(self, body, name: Optional[str] = None, *, donate=(),
-             tags: Optional[dict] = None, manual=None, **lockstep):
+    def wrap(self, body, name: Optional[str] = None, *, variant: tuple,
+             donate=(), tags: Optional[dict] = None, manual=None,
+             **lockstep):
         """A body of `steps.py` → the jitted program of this layout.
+
+        `variant` is the key this layout caches the program under, family
+        first: with what the layout holds (`_described`) it is everything
+        the body closes over, which is how the program store
+        (`compile_cache.ProgramStore`) knows the program's lowered module
+        again in a later process without tracing the body.
 
         `manual=(in_specs, out_specs)` (partitioned pool): the pool's page
         axis is sharded over the mesh's (dp, sp) shards and batches arrive
@@ -651,6 +667,7 @@ class Layout:
                             else set(self.pool_axes)),
             )
         return _ljit(body, name=name, donate_argnums=donate, tags=tags,
+                     closes_over=(variant, manual, lockstep, self._described),
                      **lockstep)
 
     # -- the step programs, compiled lazily and cached per variant ----------- #
@@ -722,7 +739,9 @@ class Layout:
         body = steps.prefill_body(
             self.model_cfg, forward, with_top=with_top, greedy=greedy,
             moe_stats=self.carries_moe_stats, with_mm=with_mm, tail=tail)
-        return self.wrap(body, name, donate=(1,), manual=manual, **lock)
+        return self.wrap(body, name, variant=("prefill", with_top, with_mm,
+                                              greedy),
+                         donate=(1,), manual=manual, **lock)
 
     def _decode_key(self, penalized, with_top, greedy, n_steps):
         return (penalized, with_top, greedy, n_steps or self.cfg.decode_steps)
@@ -747,6 +766,7 @@ class Layout:
         name = steps.decode_name(n_steps)
         donate = (1, 5) if penalized else (1,)
         tags = {"rung": n_steps}
+        variant = ("decode", penalized, with_top, greedy, n_steps)
         if self.pp > 1:
             from ..parallel.pp_engine import forward_decode_pp
 
@@ -754,8 +774,8 @@ class Layout:
                 forward_decode_pp, cfg=cfg, n_steps=n_steps,
                 max_valid_pos=cap, mesh=self.mesh, attn_impl=self.attn_impl,
                 pooled=self.pooled, greedy=greedy), n_steps, with_top)
-            return self.wrap(body, name + "_pp", donate=donate, tags=tags,
-                             **self._lockstep(5))
+            return self.wrap(body, name + "_pp", variant=variant,
+                             donate=donate, tags=tags, **self._lockstep(5))
         body = steps.decode_body(cfg, n_steps, cap, penalized, with_top,
                                  self.attn_impl, greedy)
         bx, bx2 = self._rows(), self._rows(None)
@@ -763,13 +783,13 @@ class Layout:
         # of the empty tree
         cts = bx2 if penalized else P()
         if not self.pooled:
-            return self.wrap(body, name, donate=donate, tags=tags,
-                             **self._lockstep(1, bx, bx, bx, cts))
+            return self.wrap(body, name, variant=variant, donate=donate,
+                             tags=tags, **self._lockstep(1, bx, bx, bx, cts))
         # per-step packed results are 1-D per shard → [T, R * local] global
         outs = (P(None, self.pool_axes), bx, bx, bx, cts)
         rope = (bx,) if cfg.mrope_section else ()  # +rope_off (qwen2_vl)
         return self.wrap(
-            body, name + "_pooled", donate=donate, tags=tags,
+            body, name + "_pooled", variant=variant, donate=donate, tags=tags,
             manual=((P(), self._kv_manual, bx, bx, bx, cts, bx2, bx, bx,
                      *rope), (*outs, self._kv_manual)),
             **self._lockstep(1, *outs[1:]))
@@ -784,7 +804,7 @@ class Layout:
                 steps.verify_body(
                     self.model_cfg, greedy=greedy, attn_impl=self.attn_impl,
                     moe_stats=self.carries_moe_stats),
-                "verify_step", donate=(1,), **self._lockstep(1))
+                "verify_step", variant=key, donate=(1,), **self._lockstep(1))
         return self._decode_steps[key]
 
     def cc_step(self, penalized: bool, with_top: bool, greedy: bool = False,
@@ -800,8 +820,8 @@ class Layout:
                 steps.decode_body_cc(
                     self.model_cfg, key[4], self.cfg.hard_cap, penalized,
                     with_top, self.attn_impl, greedy),
-                "decode_block_cc", donate=(1, 5) if penalized else (1,),
-                tags={"rung": key[4]})
+                "decode_block_cc", variant=key,
+                donate=(1, 5) if penalized else (1,), tags={"rung": key[4]})
         return self._decode_steps[key]
 
     def mixed_step(self, penalized: bool, with_top: bool,
@@ -834,8 +854,10 @@ class Layout:
                        *rope),
                       (bx, P(None, self.pool_axes), self._kv_manual))
             lock = self._lockstep(2)
-        return self.wrap(body, name, donate=(1,), tags={"rung": n_steps},
-                         manual=manual, **lock)
+        return self.wrap(body, name, variant=("mixed", penalized, with_top,
+                                              greedy, n_steps),
+                         donate=(1,), tags={"rung": n_steps}, manual=manual,
+                         **lock)
 
     @property
     def compiled_variants(self) -> Dict[str, List]:
@@ -867,7 +889,8 @@ class Layout:
             kw = ({"out_shardings": NamedSharding(self.mesh, P())}
                   if self.lockstep else {})
             self._embed_fn = self.wrap(
-                lambda p, tok, ln: forward_embed(p, cfg, tok, ln), **kw)
+                lambda p, tok, ln: forward_embed(p, cfg, tok, ln),
+                variant=("embed",), **kw)
         return self._embed_fn
 
     # -- KV pages in and out ------------------------------------------------- #
@@ -916,7 +939,8 @@ class Layout:
                 manual = ((self._kv_manual, P(), P()), (P(), P()))
             self._kv_fns["export"] = self.wrap(
                 steps.gather_pages(self._own if self.pooled else None),
-                manual=manual, **self._lockstep(2, kv=False))
+                variant=("export",), manual=manual,
+                **self._lockstep(2, kv=False))
         return self._kv_fns["export"]
 
     def import_fn(self, sharded_blob: bool = False):
@@ -936,5 +960,5 @@ class Layout:
                           self._kv_manual)
             self._kv_fns[key] = self.wrap(
                 steps.set_pages(self._mine if self.pooled else None),
-                donate=(0,), manual=manual)
+                variant=key, donate=(0,), manual=manual)
         return self._kv_fns[key]

@@ -25,10 +25,12 @@ engine-side model (SURVEY.md §7 M1).
 
 from __future__ import annotations
 
+from functools import partial
 from typing import Any, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.export import register_namedtuple_serialization
 from jax.sharding import PartitionSpec as P
 
 from ..ops.latent_attention import (
@@ -54,6 +56,10 @@ from .quantization import matmul_any
 Params = dict
 
 
+# (registered: a step program's operand, whose tree the program store
+# writes with the program's lowered module, `jax.export`)
+@partial(register_namedtuple_serialization,
+         serialized_name="dynamo_tpu.KVCache")
 class KVCache(NamedTuple):
     """Paged pool for all layers, as `ModelConfig.cache_spec` describes it:
     keys and values [L, P, page, n_kv, hd] each, or, for latent attention,
@@ -90,6 +96,8 @@ class KVCache(NamedTuple):
             jnp.zeros((*slots, *spec.state_dims), jnp.float32))
 
 
+@partial(register_namedtuple_serialization,
+         serialized_name="dynamo_tpu.StateCache")
 class StateCache(NamedTuple):
     """`KVCache` of a model with state-space layers (`ModelConfig.
     state_spec`), every step's one donated operand: the pages of its

@@ -20,16 +20,22 @@ sequence dominated decode time).  Instead:
 
 from __future__ import annotations
 
+from functools import partial
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.export import register_namedtuple_serialization
 
 # static width of the candidate slice for top-k/top-p rows; requests with
 # top_k > TOP_K_CAP are clamped (the standard engine-side cap)
 TOP_K_CAP = 64
 
 
+# (registered: a step program's operand, whose tree the program store
+# writes with the program's lowered module, `jax.export`)
+@partial(register_namedtuple_serialization,
+         serialized_name="dynamo_tpu.SamplingParams")
 class SamplingParams(NamedTuple):
     """Per-sequence sampling state, shape [B] each."""
 

@@ -699,6 +699,13 @@ async def _run(args) -> None:
             print("RESIDUAL " + _json.dumps(
                 engine.model_cfg.residual_report), flush=True)
         print("DEVICE " + _json.dumps(chip.device_identity()), flush=True)
+        # of the programs born so far, how many the program store answered
+        # and how many it was given (the rest are born after READY)
+        from ..analysis import xla_ledger
+
+        xla = xla_ledger.summary()
+        _STARTUP.update({k: xla[k] for k in (
+            "programs_stored", "programs_store_writes")})
     t0_serve, t_ready = _phase_done("serve")
     host_event("startup.serve", t0_serve, t_ready)
     host_event("ready", t_ready, t_ready, model=mdc.name)
