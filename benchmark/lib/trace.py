@@ -8,7 +8,11 @@ of the recorded trace under benchmark/tests/.
 
 Compact form: {"clock": "mono_ns", "names": [...], "planes": [{"name",
 "lines": [{"name", "events": [[name_index, start_ns, dur_ns], ...]}]}]} with
-every start on the host's monotonic clock, in nanoseconds.
+every start on the host's monotonic clock, in nanoseconds; and, where the
+file gives them, "scopes": per name the op's `tf_op` (its path of named
+scopes, `jit(prefill_step)/.../attn.core/dot_general`; "" where it has
+none), which `jax.profiler.ProfileData` does not show and `op_scopes` reads
+from the file's own bytes.
 
 What a real v5e trace looks like (read by hand, PR 24; PERF.md section 3):
 one plane per chip, "/device:TPU:<n>", with the lines "XLA Modules" (one
@@ -27,6 +31,86 @@ import sys
 
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
+# step slices that hold a device program (dynamo_tpu/engine/engine.py)
+STEP_KINDS = ("prefill_chunk", "decode_block", "mixed_step", "spec_round")
+
+
+def _xspace_class():
+    """A message class for the FEW fields of the profiler's `XSpace` that
+    `op_scopes` reads (tsl/profiler/protobuf/xplane.proto: planes, their
+    names, their event and stat metadata), built here from a descriptor so
+    that nothing but `google.protobuf` is imported.  A plane's lines, which
+    are nearly all of the file, are left undeclared and so are skipped, not
+    parsed."""
+    from google.protobuf import (descriptor_pb2, descriptor_pool,
+                                 message_factory)
+
+    T = descriptor_pb2.FieldDescriptorProto
+    fd = descriptor_pb2.FileDescriptorProto(
+        name="bench_xplane_subset.proto", package="bench_xplane",
+        syntax="proto3")
+
+    def message(name, *fields, into=fd.message_type):
+        m = into.add(name=name)
+        for fname, number, ftype, type_name, repeated in fields:
+            m.field.add(name=fname, number=number, type=ftype,
+                        type_name=type_name or None,
+                        label=T.LABEL_REPEATED if repeated
+                        else T.LABEL_OPTIONAL)
+        return m
+
+    def map_entry(owner, name, value_type):
+        e = message(name, ("key", 1, T.TYPE_INT64, "", False),
+                    ("value", 2, T.TYPE_MESSAGE, value_type, False),
+                    into=owner.nested_type)
+        e.options.map_entry = True
+
+    P = ".bench_xplane."
+    message("XStat", ("metadata_id", 1, T.TYPE_INT64, "", False),
+            ("str_value", 5, T.TYPE_STRING, "", False),
+            ("ref_value", 7, T.TYPE_UINT64, "", False))
+    message("XStatMetadata", ("id", 1, T.TYPE_INT64, "", False),
+            ("name", 2, T.TYPE_STRING, "", False))
+    message("XEventMetadata", ("id", 1, T.TYPE_INT64, "", False),
+            ("name", 2, T.TYPE_STRING, "", False),
+            ("display_name", 4, T.TYPE_STRING, "", False),
+            ("stats", 5, T.TYPE_MESSAGE, P + "XStat", True))
+    plane = message(
+        "XPlane", ("name", 2, T.TYPE_STRING, "", False),
+        ("event_metadata", 4, T.TYPE_MESSAGE,
+         P + "XPlane.EventMetadataEntry", True),
+        ("stat_metadata", 5, T.TYPE_MESSAGE,
+         P + "XPlane.StatMetadataEntry", True))
+    map_entry(plane, "EventMetadataEntry", P + "XEventMetadata")
+    map_entry(plane, "StatMetadataEntry", P + "XStatMetadata")
+    message("XSpace", ("planes", 1, T.TYPE_MESSAGE, P + "XPlane", True))
+    pool = descriptor_pool.DescriptorPool()
+    pool.Add(fd)
+    return message_factory.GetMessageClass(
+        pool.FindMessageTypeByName("bench_xplane.XSpace"))
+
+
+def op_scopes(path):
+    """{op name: tf_op} over the device planes of an .xplane.pb: the `tf_op`
+    stat of each event's METADATA (a string, or a reference to a stat
+    metadata's name), under the metadata's name and its display name."""
+    space = _xspace_class()()
+    with open(path, "rb") as f:
+        space.ParseFromString(f.read())
+    out = {}
+    for plane in space.planes:
+        if not plane.name.startswith("/device:"):
+            continue
+        stat_names = {k: v.name for k, v in plane.stat_metadata.items()}
+        for meta in plane.event_metadata.values():
+            for st in meta.stats:
+                if stat_names.get(st.metadata_id) != "tf_op":
+                    continue
+                scope = st.str_value or stat_names.get(st.ref_value, "")
+                for key in (meta.name, meta.display_name):
+                    if key and scope:
+                        out.setdefault(key, scope)
+    return out
 
 
 def load_xplane(path, wall_minus_mono_ns, t0_ns, t1_ns):
@@ -66,8 +150,17 @@ def load_xplane(path, wall_minus_mono_ns, t0_ns, t1_ns):
                 evs.append([i, s, d])
             out_lines.append({"name": ln.name, "events": evs})
         planes.append({"name": plane.name, "lines": out_lines})
-    return {"clock": "mono_ns", "names": names, "planes": planes,
-            "summary": summary, "profile_start_unix_ns": base}
+    out = {"clock": "mono_ns", "names": names, "planes": planes,
+           "summary": summary, "profile_start_unix_ns": base}
+    try:  # a file or a protobuf library that gives none: readers that need
+        # scopes then place an op by its kernel name alone, and say so
+        scopes = op_scopes(path)
+        out["scopes"] = [scopes.get(n, "") for n in names]
+        out["scopes_note"] = (f"{sum(1 for x in out['scopes'] if x)} of "
+                              f"{len(names)} op names carry a tf_op")
+    except Exception as e:
+        out["scopes_note"] = f"no scopes: {type(e).__name__}: {e}"[:300]
+    return out
 
 
 def load_in_child(ps, path, out_path, wall_minus_mono_ns, t0_ns, t1_ns):
@@ -165,17 +258,51 @@ def label_gap(gap, slices):
     return "between_steps"
 
 
+def busy_line(plane):
+    """The line busy time is read from: the ops, else the programs."""
+    ops = line_of(plane, OPS_LINE)
+    if ops is None or not ops["events"]:
+        ops = line_of(plane, MODULES_LINE)
+    return ops if ops is not None and ops["events"] else None
+
+
+def captured_end(trace, t0_ns, t1_ns, step_events):
+    """Where the device planes end, if the profiler stopped before the
+    window did; else `t1_ns`.  The profiler keeps a bounded number of
+    events (about 2.95 million ops: 23 s of a program that runs 3,800 ops a
+    step), and a plane that ends there reads as an idle device from then
+    on.  It is the line busy time is taken from that counts (the ops: the
+    line of program executions, two events a step, goes on to the window's
+    end).  A capture that ended is told from a device that really fell idle
+    by the host's own record: a step slice that OPENED after the line's
+    last event held a program the line does not show."""
+    last = max((s + d for plane in trace["planes"]
+                for _, s, d in (busy_line(plane) or {"events": ()})["events"]),
+               default=None)
+    if last is None or last >= t1_ns:
+        return t1_ns
+    later = [e for e in step_events
+             if e["dur_ns"] > 0 and e["kind"] in STEP_KINDS
+             and last < e["t_ns"] < t1_ns]
+    return max(last, t0_ns + 1) if later else t1_ns
+
+
 def reduce(trace, t0_ns, t1_ns, step_events):
     """Device busy seconds (union of the op intervals inside the window,
     averaged over the device planes that ran anything), the top device ops,
-    the idle time by host activity, and the program executions."""
+    the idle time by host activity, and the program executions.  Where the
+    capture ended before the window (`captured_end`), all of it is taken
+    over the span the planes hold, `window_s` is that span, and
+    `capture_ended_early` says so."""
+    asked_ns = t1_ns
+    t1_ns = captured_end(trace, t0_ns, t1_ns, step_events)
     slices = sorted((e["t_ns"], e["t_ns"] + e["dur_ns"], e["kind"])
                     for e in step_events if e["dur_ns"] > 0)
     names = trace["names"]
     busy_s, op_time, gap_time, modules = [], {}, {}, []
     for plane in trace["planes"]:
-        ops = line_of(plane, OPS_LINE) or line_of(plane, MODULES_LINE)
-        if ops is None or not ops["events"]:
+        ops = busy_line(plane)
+        if ops is None:
             continue
         ivs = clipped(ops["events"], t0_ns, t1_ns)
         if not ivs:
@@ -203,6 +330,7 @@ def reduce(trace, t0_ns, t1_ns, step_events):
     return {"busy_s": sum(busy_s) / len(busy_s),
             "busy_s_per_chip": busy_s,
             "window_s": (t1_ns - t0_ns) / 1e9,
+            "capture_ended_early": t1_ns < asked_ns,
             "device_ops": top(op_time), "idle_gaps": top(gap_time),
             "modules": modules}
 

@@ -26,6 +26,9 @@ Mix keys:
   fresh_len     length spec of each turn's fresh tokens
   output_len    length spec of each turn's max_tokens
   warmup        the loop's warm-up recipe (see the loop's file)
+  probe_lens    lengths of `correct`'s probe prompts (lib/probes.py); absent:
+                its default, which ends at 1,200 tokens.  A mix of longer
+                prompts names a probe as long as they are
 A length spec is {"dist": "fixed", "value"} | {"dist": "uniform", "min",
 "max"} | {"dist": "lognormal", "median", "sigma", "min", "max"}.
 """
@@ -52,6 +55,24 @@ def draw_len(rng, spec):
         x = rng.lognormvariate(math.log(spec["median"]), spec["sigma"])
         return int(min(max(round(x), spec["min"]), spec["max"]))
     raise ValueError(f"unknown length distribution {dist!r}")
+
+
+def max_len(spec):
+    """The longest length the spec CAN draw (0 for an absent spec)."""
+    if spec is None:
+        return 0
+    if spec["dist"] == "fixed":
+        return int(spec["value"])
+    if spec["dist"] in ("uniform", "lognormal"):
+        return int(spec["max"])
+    raise ValueError(f"unknown length distribution {spec['dist']!r}")
+
+
+def longest_request(mix):
+    """Prompt plus answer of the longest request the mix's distributions can
+    draw, whatever its one set drew: what the worker's context has to hold."""
+    return (max_len(mix.get("prefix_len")) + max_len(mix["fresh_len"])
+            + max_len(mix["output_len"]))
 
 
 def session_sizes(mix):

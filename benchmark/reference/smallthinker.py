@@ -73,6 +73,7 @@ steps over 0.06 hid behind it.
 
 LOGPROB_TOL = 0.03
 TIE_MARGIN = 0.03
+ATTN_QUERY_BLOCK = 512  # queries a block of attention; a test lowers it
 
 
 def _rms(np, x, w, eps):
@@ -120,12 +121,14 @@ def check_model(model):
 
 
 def forward(read, model, batches, n_last, lower_precision=False,
-            drop_last_expert=False, margins=None):
+            drop_last_expert=False, ignore_window=False, margins=None):
     """`tail_logprobs` with the controls a test may switch on:
     `lower_precision` rounds the router's and the experts' operands to 3
     bits of mantissa (the nearest storage precision below the bf16 the
     configuration states: fp8 e4m3's grid, without its range); `drop_last_expert` leaves
-    the k-th expert out; `margins`, a list, receives per layer and batch the
+    the k-th expert out; `ignore_window` lets the windowed layers see every
+    earlier key (the fault a context past `sliding_window_size` is there to
+    catch: below it the two agree to the bit); `margins`, a list, receives per layer and batch the
     [B, T] gap between the k-th and the (k+1)-th router logit."""
     import numpy as np
 
@@ -147,10 +150,6 @@ def forward(read, model, batches, n_last, lower_precision=False,
     def attend(x, w, rotate, window):
         B, T, _ = x.shape
         pos = np.arange(T)
-        i, j = pos[:, None], pos[None, :]
-        mask = j <= i
-        if window:
-            mask = mask & (i - j < window)
         a = _rms(np, x, w["ln1"], eps)
         q = (a @ w["q"].T).reshape(B, T, nq, hd)
         kk = (a @ w["k"].T).reshape(B, T, nkv, hd)
@@ -158,12 +157,24 @@ def forward(read, model, batches, n_last, lower_precision=False,
         if rotate:
             q, kk = _rope(np, q, pos, theta), _rope(np, kk, pos, theta)
         # grouped-query: each KV head serves nq // nkv query heads
+        q = q.transpose(0, 2, 1, 3)
         kk = np.repeat(kk, nq // nkv, axis=2).transpose(0, 2, 3, 1)
         v = np.repeat(v, nq // nkv, axis=2).transpose(0, 2, 1, 3)
-        s = (q.transpose(0, 2, 1, 3) @ kk) / np.float32(hd ** 0.5)
-        p = _softmax(np, np.where(mask[None, None], s, -np.inf))
-        o = (p @ v).transpose(0, 2, 1, 3).reshape(B, T, nq * hd)
-        return x + o @ w["o"].T
+        o = np.empty((B, nq, T, hd), np.float32)
+        # ATTN_QUERY_BLOCK queries at a time against the keys they can see,
+        # so that a probe of thousands of tokens never holds a [T, T] score
+        # matrix a head; a key outside the slice is one the mask would hide
+        for a0 in range(0, T, ATTN_QUERY_BLOCK):
+            a1 = min(a0 + ATTN_QUERY_BLOCK, T)
+            k0 = max(0, a0 - window + 1) if window else 0
+            i, j = pos[a0:a1, None], pos[None, k0:a1]
+            mask = j <= i
+            if window:
+                mask = mask & (i - j < window)
+            s = (q[:, :, a0:a1] @ kk[..., k0:a1]) / np.float32(hd ** 0.5)
+            p = _softmax(np, np.where(mask[None, None], s, -np.inf))
+            o[:, :, a0:a1] = p @ v[:, :, k0:a1]
+        return x + o.transpose(0, 2, 1, 3).reshape(B, T, nq * hd) @ w["o"].T
 
     for l in range(model["num_hidden_layers"]):
         p = f"model.layers.{l}."
@@ -173,7 +184,8 @@ def forward(read, model, batches, n_last, lower_precision=False,
              "v": read(p + "self_attn.v_proj.weight"),
              "o": read(p + "self_attn.o_proj.weight")}
         rotate = bool(model["rope_layout"][l])
-        window = wsize if model["sliding_window_layout"][l] else None
+        window = (wsize if model["sliding_window_layout"][l]
+                  and not ignore_window else None)
         router = low(read(p + "block_sparse_moe.primary_router.weight"))
         ln2 = read(p + "post_attention_layernorm.weight")
         hs, ms, routed, ys = [], [], [], []

@@ -32,3 +32,22 @@ def prefill_step_floor_s(model, peaks, tokens):
     t_mem = BF16 * params / peaks["hbm_bytes_per_s"]
     t_flop = 2 * tokens * params / peaks["bf16_flops_per_s"]
     return max(t_mem, t_flop), ("memory" if t_mem >= t_flop else "compute")
+
+
+def prefill_attn_floor_s(model, peaks, tokens, ctx):
+    """The least time attention over the context can take in one prefill
+    step of ONE sequence: a chunk of `tokens` tokens whose last sees `ctx`
+    keys, itself among them.  Per layer: 4 x head_dim x query heads
+    operations for every key a token can SEE (causal: the token at position
+    p sees p keys; QK^T and PV, two operations a product), or the keys and
+    values of the `ctx` visible positions read once, in bf16; the larger,
+    summed over the layers.  Keys masked, padded or read twice are the
+    implementation's own and are not counted."""
+    hd = head_dim(model)
+    pairs = tokens * (ctx - tokens) + tokens * (tokens + 1) // 2
+    t_flop = (4 * hd * model["num_attention_heads"] * pairs
+              / peaks["bf16_flops_per_s"])
+    t_mem = (2 * ctx * model["num_key_value_heads"] * hd * BF16
+             / peaks["hbm_bytes_per_s"])
+    L = model["num_hidden_layers"]
+    return L * max(t_mem, t_flop), ("memory" if t_mem >= t_flop else "compute")

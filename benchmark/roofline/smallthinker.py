@@ -63,3 +63,33 @@ def experts_floor_s(model, peaks, tokens, experts_hit):
     t_mem = BF16 * experts_hit * expert / peaks["hbm_bytes_per_s"]
     t_flop = 2 * tokens * k * L * expert / peaks["bf16_flops_per_s"]
     return max(t_mem, t_flop), ("memory" if t_mem >= t_flop else "compute")
+
+
+def prefill_attn_floor_s(model, peaks, tokens, ctx):
+    """The least time attention over the context can take in one prefill
+    step of ONE sequence: a chunk of `tokens` tokens whose last sees `ctx`
+    keys, itself among them.  Per layer: 4 x head_dim x query heads
+    operations for every key a token can SEE (causal: the token at position
+    p sees p keys, and in a windowed layer, `sliding_window_layout` 1, no
+    more than `sliding_window_size`; QK^T and PV, two operations a product),
+    or the keys and values of the positions ANY token of the chunk can see
+    (`ctx`; windowed: the chunk and the window before it) read once, in
+    bf16; the larger, summed over the layers.  Keys masked, padded or read
+    twice are the implementation's own and are not counted."""
+    nq, nkv, hd = (model["num_attention_heads"],
+                   model["num_key_value_heads"], model["head_dim"])
+    W, prefix = model["sliding_window_size"], ctx - tokens
+    total, t_mem_all, t_flop_all = 0.0, 0.0, 0.0
+    for windowed in model["sliding_window_layout"]:
+        if windowed:
+            m = min(max(W - prefix, 0), tokens)  # tokens still under W keys
+            pairs = m * prefix + m * (m + 1) // 2 + (tokens - m) * W
+            keys = min(ctx, tokens + W - 1)
+        else:
+            pairs = tokens * prefix + tokens * (tokens + 1) // 2
+            keys = ctx
+        t_flop = 4 * hd * nq * pairs / peaks["bf16_flops_per_s"]
+        t_mem = 2 * keys * nkv * hd * BF16 / peaks["hbm_bytes_per_s"]
+        total += max(t_mem, t_flop)
+        t_mem_all, t_flop_all = t_mem_all + t_mem, t_flop_all + t_flop
+    return total, ("memory" if t_mem_all >= t_flop_all else "compute")

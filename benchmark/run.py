@@ -5,7 +5,8 @@
 
 A cell is an entry of `workloads` in BENCHMARK.json: a configuration
 (benchmark/configs/<config>.json, found through the entry's `file`) under a
-traffic mix (benchmark/traffic/<mix>.json).  This parent never imports jax.
+traffic mix (benchmark/traffic/<mix>.json), with the sizes of its own, if it
+has any, in benchmark/cells/<cell>.json.  This parent never imports jax.
 It writes the configuration's checkpoint once per checkout, starts
 `python -m dynamo_tpu.runtime`, `python -m dynamo_tpu.worker --model <dir>`
 and `python -m dynamo_tpu.frontend` as children (the worker alone on the
@@ -52,13 +53,18 @@ from lib.procs import RunFailure  # noqa: E402
 
 CACHE = os.path.join(BENCH, ".cache")  # git-ignored; made from seeds
 
-# Policy stays the program's: a configuration passes the worker sizes only.
+# Policy stays the program's: a configuration or a cell passes the worker
+# sizes only.  The context, `--max-model-len`, is a size: how long a prompt
+# the traffic sends is the cell's, not the program's, to say.
 POLICY_FLAGS = (
     "--decode-steps", "--decode-chain", "--decode-continuous",
     "--decode-block-ladder", "--mixed-prefill-tokens",
     "--prefill-chunk-tokens", "--attention-impl", "--fuse-projections",
-    "--max-num-seqs", "--max-prefill-tokens", "--max-model-len",
+    "--max-num-seqs", "--max-prefill-tokens",
     "--speculative-ngram-k", "--quantization", "--no-prefix-caching")
+# the worker's own default (dynamo_tpu/worker/__main__.py, `--max-model-len`):
+# what a cell that names no context is served at
+DEFAULT_MAX_MODEL_LEN = 4096
 
 
 def note(what, **fields):
@@ -131,12 +137,13 @@ def ensure_checkpoint(ps, config, config_path, chips, rehearse):
     return path, time.monotonic() - t0
 
 
-def start_reference(ps, config, config_path, ckpt_dir, depth):
+def start_reference(ps, config, config_path, ckpt_dir, depth, lens):
     """The plain reference's answers to the probes: cached in the checkout,
     else computed by a child on the host CPU while the worker loads.  The
     child writes the file by `os.replace` at its end, so a child that is
     stopped with a failed run leaves nothing a later run would trust."""
-    h = hashlib.sha256(f"{os.path.basename(ckpt_dir)}:{depth}".encode())
+    h = hashlib.sha256(
+        f"{os.path.basename(ckpt_dir)}:{depth}:{list(lens)}".encode())
     for f in (os.path.join(BENCH, "reference", config["reference"] + ".py"),
               os.path.join(BENCH, "lib", "probes.py"),
               os.path.join(BENCH, "lib", "reference_child.py")):
@@ -147,22 +154,75 @@ def start_reference(ps, config, config_path, ckpt_dir, depth):
         return out, None
     child = ps.spawn(
         [sys.executable, os.path.join(BENCH, "lib", "reference_child.py"),
-         config_path, ckpt_dir, out, str(depth)], "reference", out + ".log",
+         config_path, ckpt_dir, out, str(depth), json.dumps(list(lens))],
+        "reference", out + ".log",
         {"JAX_PLATFORMS": "cpu"})
     return out, child
 
 
-def worker_flags(config, rehearse):
+def cell_sizes(cell, rehearse):
+    """The cell's own file, benchmark/cells/<cell>.json (a rehearsal's:
+    tests/data/cells/), or {}: sizes that belong to a configuration under
+    ONE mix, so that the configuration's file goes on serving its other
+    cells at the flags it has.  `worker_flags` there are laid over the
+    configuration's."""
+    path = os.path.join(BENCH, *(("tests", "data") if rehearse else ()),
+                        "cells", cell["name"] + ".json")
+    if not os.path.exists(path):
+        return {}
+    with open(path) as f:
+        return json.load(f)
+
+
+def sized_flags(cell, config, sizes):
+    """{flag: value}: the configuration's `worker_flags` in their order, the
+    cell's laid over them (a flag both name keeps its place and takes the
+    cell's value).  A policy flag on either is refused."""
+    merged = {}
+    for owner, flags in (
+            (f"configuration {config['name']}", config.get("worker_flags", {})),
+            (f"cell {cell['name']} (benchmark/cells/{cell['name']}.json)",
+             sizes.get("worker_flags", {}))):
+        for flag, value in flags.items():
+            if flag in POLICY_FLAGS:
+                raise RunFailure(
+                    f"{owner}: worker_flags {flag} is policy, not a size; "
+                    "the cells measure the worker at its defaults")
+            merged[flag] = value
+    return merged
+
+
+def worker_flags(cell, config, sizes, rehearse):
     flags = []
-    for flag, value in config.get("worker_flags", {}).items():
-        if flag in POLICY_FLAGS:
-            raise RunFailure(
-                f"{config['name']}: {flag} is policy, not a size; the cells "
-                "measure the worker at its defaults")
+    for flag, value in sized_flags(cell, config, sizes).items():
         flags += [flag, str(value)]
     if rehearse:
         flags += ["--platform", "cpu", "--dtype", "float32"]
     return flags
+
+
+def check_context(cell, config, sizes, mix):
+    """Before any child: the worker's context holds the longest request the
+    mix CAN draw and the longest probe, and the model's positions hold the
+    context.  Returns the context."""
+    flags = sized_flags(cell, config, sizes)
+    context = int(flags.get("--max-model-len", DEFAULT_MAX_MODEL_LEN))
+    where = ("worker_flags --max-model-len" if "--max-model-len" in flags
+             else "the worker's default --max-model-len")
+    limit = config["model"].get("max_position_embeddings")
+    if limit is not None and context > int(limit):
+        raise RunFailure(
+            f"cell {cell['name']}: {where} {context} is past the model's "
+            f"max_position_embeddings {limit}")
+    for key, need in (
+            ("prefix_len + fresh_len + output_len",
+             traffic.longest_request(mix)),
+            ("probe_lens", probes.longest_request(probes.lens_of(mix)))):
+        if need > context:
+            raise RunFailure(
+                f"cell {cell['name']}: mix {cell['traffic']} can send "
+                f"{need} tokens ({key}), past {where} {context}")
+    return context
 
 
 def check_device(device, chips, rehearse):
@@ -188,8 +248,9 @@ async def run_probes(stack, config, ref_path):
 
     with open(ref_path) as f:
         ref = json.load(f)
+    lens = tuple(ref["probe_lens"])
     texts = probes.probe_texts(config["weights_seed"],
-                               tuple(config["prompt_vocab"]))
+                               tuple(config["prompt_vocab"]), lens)
 
     async def ask(http, prompt, n):
         body = loadgen.completion_body(
@@ -211,24 +272,46 @@ async def run_probes(stack, config, ref_path):
     tol, margin = ref["tolerance"], ref["tie_margin"]
     forced, greedy = [], []
     async with aiohttp.ClientSession() as http:
-        for text, n in zip(texts, probes.PROBE_LENS):
+        for text, n in zip(texts, lens):
             forced.append([(await ask(http, text[:n + k], 1))[0]
                            for k in range(probes.PROBE_STEPS)])
         for i in ref.get("greedy_probes", ()):
-            greedy.append(await ask(http, texts[i][:probes.PROBE_LENS[i]],
-                                    ref["depth"]))
+            greedy.append(await ask(http, texts[i][:lens[i]], ref["depth"]))
     ok, detail = probes.compare_forced(forced, ref["forced"], tol, margin)
-    out = {"tolerance": tol, "tie_margin": margin, "forced_ok": ok,
-           "forced": detail, "greedy_depth": ref["depth"]}
+    out = {"tolerance": tol, "tie_margin": margin, "probe_lens": list(lens),
+           "forced_ok": ok, "forced": detail, "greedy_depth": ref["depth"]}
     if greedy:
         g_ok, out["greedy"] = probes.compare_greedy(greedy, ref["greedy"],
                                                     tol, margin)
         out["greedy_ok"] = g_ok
         ok = ok and g_ok
+    out["compared"] = compared_numbers(out, len(lens) * probes.PROBE_STEPS)
     out["served"] = forced
     out["reference"] = [[round(s["logprob"], 4) for s in steps]
                         for steps in ref["forced"]]
     return ok, out
+
+
+def compared_numbers(detail, steps_due):
+    """Every number `correct` turns on, each beside its limit, under short
+    plain names: the result line's last key and the run's last lines on
+    standard error."""
+    forced = detail["forced"]
+    out = {
+        "logprob_past_allowed_max": {
+            "value": forced["max_past_allowed"], "limit": 0.0,
+            "tolerance": detail["tolerance"]},
+        "logprob_diff_max_agreeing": {
+            "value": forced["max_abs_logprob_diff"],
+            "limit": detail["tolerance"]},
+        "forced_steps_over": {"value": sum(forced["steps_over_by_probe"]),
+                              "limit": 0},
+        "forced_steps_compared": {"value": forced["steps_compared"],
+                                  "at_least": steps_due}}
+    if "greedy" in detail:
+        out["greedy_problems"] = {
+            "value": len(detail["greedy"]["problems"]), "limit": 0}
+    return out
 
 
 # -- the measured phases -------------------------------------------------------- #
@@ -251,6 +334,16 @@ async def measure(stack, config, mix, args):
 
 # -- the run -------------------------------------------------------------------- #
 
+def mix_path(cell, rehearse):
+    """benchmark/traffic/<mix>.json; a rehearsal looks under tests/data/
+    first, where a stand-in of a mix too long for the CPU may lie."""
+    name = cell["traffic"] + ".json"
+    tiny = os.path.join(BENCH, "tests", "data", "traffic", name)
+    if rehearse and os.path.exists(tiny):
+        return tiny
+    return os.path.join(BENCH, "traffic", name)
+
+
 def load_reader(kind_dir, metric_name):
     try:
         return ckpt.load_module(kind_dir, metric_name).read
@@ -272,16 +365,23 @@ def reduce_trace(ps, xprof_dir, run, log_dir):
         compact = trace_lib.load_in_child(
             ps, max(files, key=os.path.getsize),
             os.path.join(log_dir, "trace.json"), wall_ns - mono_ns, t0, t1)
+        reduced = trace_lib.reduce(compact, t0, t1, run["events"])
         note("trace",
              file_bytes=os.path.getsize(max(files, key=os.path.getsize)),
-             planes=compact["summary"])
+             captured_s=reduced["window_s"], asked_s=(t1 - t0) / 1e9,
+             capture_ended_early=reduced["capture_ended_early"],
+             lines_end_s={ln["name"]: round((max(
+                 a + d for _, a, d in ln["events"]) - t0) / 1e9, 3)
+                 for pl in compact["planes"] for ln in pl["lines"]
+                 if ln["events"]},
+             scopes=compact.get("scopes_note"), planes=compact["summary"])
         shutil.rmtree(xprof_dir, ignore_errors=True)  # hundreds of MB
-        return trace_lib.reduce(compact, t0, t1, run["events"])
+        return reduced
     except RuntimeError as e:
         raise RunFailure(str(e)) from None
 
 
-async def serve_and_measure(stack, cell, config, mix, args, ckpt_dir,
+async def serve_and_measure(stack, cell, config, mix, sizes, args, ckpt_dir,
                             ref_path, ref_child, xprof_dir):
     env = {}
     if not args.rehearse_cpu:
@@ -292,7 +392,7 @@ async def serve_and_measure(stack, cell, config, mix, args, ckpt_dir,
         # the worker shuts down, and the reduction cuts it to the window
         env.update(DYN_TPU_XPROF_STEPS=str(10 ** 9),
                    DYN_TPU_XPROF_DIR=xprof_dir)
-    flags = worker_flags(config, args.rehearse_cpu)
+    flags = worker_flags(cell, config, sizes, args.rehearse_cpu)
     try:
         device, secs = stack.start_worker(ckpt_dir, flags, env, timeout=900)
     except RunFailure as e:
@@ -300,7 +400,7 @@ async def serve_and_measure(stack, cell, config, mix, args, ckpt_dir,
             f"cell {cell['name']}, configuration {config['name']}: the "
             f"program's worker did not come up on it: {e}") from None
     peaks = check_device(device, cell["chips"], args.rehearse_cpu)
-    note("worker", seconds_to_ready=secs, device=device)
+    note("worker", seconds_to_ready=secs, device=device, flags=flags)
     deadline = time.monotonic() + 60
     while True:
         models = await collect.get_json(stack.base + "/v1/models")
@@ -326,7 +426,8 @@ async def serve_and_measure(stack, cell, config, mix, args, ckpt_dir,
     run = await measure(stack, config, mix, args)
     if args.trace:
         await poller.stop()
-    run.update(correct=correct, device=device, peaks=peaks, config=config,
+    run.update(correct=correct, compared=detail["compared"], device=device,
+               peaks=peaks, config=config,
                mix=mix, events=poller.events, events_dropped=poller.dropped,
                clock_anchor=poller.anchor, trace=None)
     return run
@@ -380,8 +481,9 @@ def run_cell(args, ps):
     with open(config_path) as f:
         config = json.load(f)
     family_files(config)
-    mix = traffic.load_mix(
-        os.path.join(BENCH, "traffic", cell["traffic"] + ".json"))
+    mix = traffic.load_mix(mix_path(cell, args.rehearse_cpu))
+    sizes = cell_sizes(cell, args.rehearse_cpu)
+    context = check_context(cell, config, sizes, mix)
     if args.seconds is None:
         args.seconds = float(spec["run_seconds"])
     log_dir = os.path.join(
@@ -396,10 +498,10 @@ def run_cell(args, ps):
          seconds_to_write=wrote)
     ref_path, ref_child = start_reference(
         ps, config, config_path, ckpt_dir,
-        probes.greedy_depth(traffic.max_output_len(mix)))
+        probes.greedy_depth(traffic.max_output_len(mix)), probes.lens_of(mix))
     stack = procs.Stack(ps, log_dir, config.get("router_mode", "round_robin"))
     run = asyncio.run(serve_and_measure(
-        stack, cell, config, mix, args, ckpt_dir, ref_path, ref_child,
+        stack, cell, config, mix, sizes, args, ckpt_dir, ref_path, ref_child,
         xprof_dir))
     died = ps.dead()
     end_metrics = run["metrics1"]
@@ -422,10 +524,15 @@ def run_cell(args, ps):
     note("window", seconds=args.seconds, attempted=attempted, failed=failed,
          samples_ok=len(w["ok"]),
          errors=[r["error"] for r in w["measured"] if r["error"]][:3],
+         ttft_tail=[[round(r["t_due"] - run["t0"], 2), r["prompt_len"],
+                     round(stats.ttft_ms(r), 1)]
+                    for r in sorted(w["ok"], key=stats.ttft_ms)[-16:]],
          requests_since_start=len(run["records"]),
          prompt_tokens_since_start=sum(r["prompt_len"]
                                        for r in run["records"]),
-         kv_pool_tokens=config.get("memory", {}).get("kv_pool_tokens"),
+         kv_pool_tokens=sizes.get("memory", config.get("memory", {})).get(
+             "kv_pool_tokens"),
+         context_tokens=context,
          kv_usage_at_end=end_metrics.get("kv_usage"),
          events_dropped=run["events_dropped"], children_died=died)
     note("end_to_end", **e2e)  # every run shows them; only --trace 0 reports
@@ -450,6 +557,8 @@ def run_cell(args, ps):
     device = dict(run["device"], memory_peak_bytes=max(
         m["peak_bytes_in_use"] or 0
         for m in end_metrics["runtime"]["memory"]))
+    compared = dict(run["compared"],
+                    children_died={"value": len(died), "limit": 0})
     result = {"correct": bool(run["correct"]) and not died,
               "attempted": attempted, "failed": failed, "metrics": metrics,
               "device": device}
@@ -458,12 +567,16 @@ def run_cell(args, ps):
                       window_s=run["trace"]["window_s"])
         result["breakdown"] = {"device_ops": run["trace"]["device_ops"],
                                "idle_gaps": run["trace"]["idle_gaps"]}
+    result["compared"] = compared  # last, as the check's record keeps ends
     if args.rehearse_cpu:
         note("rehearsal", would_print=result)
         print("rehearsal finished on the CPU backend; this is not a chip run",
               file=sys.stderr)
         return 2
     print(json.dumps(result), flush=True)
+    for name, c in compared.items():
+        print(f"compared {name}: {json.dumps(c)}", file=sys.stderr)
+    sys.stderr.flush()
     return 0
 
 

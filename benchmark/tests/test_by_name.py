@@ -62,7 +62,8 @@ def test_each_cell_reports_setup_one_more_and_a_layer_metric():
 def test_a_policy_flag_in_a_configuration_is_refused():
     with pytest.raises(bench_run.RunFailure):
         bench_run.worker_flags(
-            {"name": "x", "worker_flags": {"--decode-steps": 8}}, False)
+            {"name": "x.m"},
+            {"name": "x", "worker_flags": {"--decode-steps": 8}}, {}, False)
 
 
 def test_an_unknown_device_kind_is_an_error():

@@ -51,4 +51,6 @@ def test_the_spec_lists_the_reader_in_every_cell():
     (m,) = [m for m in spec["per_layer"] if m["name"] == NAME]
     assert (m["layer"], m["source"], m["better"], m["moves"], m["unit"]) == (
         "model step", "program_span", "lower", "ttft_p50_ms", "%")
-    assert m["workloads"] == [w["name"] for w in spec["workloads"]][:4]
+    # every cell that prefills, by name: the cells the list held when the
+    # reader came, and each one added since
+    assert set(m["workloads"]) == {w["name"] for w in spec["workloads"]}

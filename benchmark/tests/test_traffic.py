@@ -3,6 +3,7 @@ import os
 
 import pytest
 
+import run as bench_run
 from lib import traffic
 
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -38,12 +39,31 @@ def test_every_seed_gets_the_same_set_of_sizes_in_another_order(name):
     assert sorted(a) == sorted(sizes(m, 1, cycle=3)) and a != sizes(m, 1, 3)
 
 
+def contexts(name):
+    """The context `run.py` `check_context` gives each cell that sends the
+    mix (it fails a cell whose mix can pass it); the worker's default where
+    no cell of BENCHMARK.json sends it."""
+    with open(os.path.join(os.path.dirname(BENCH), "BENCHMARK.json")) as f:
+        spec = json.load(f)
+    found = []
+    for cell in spec["workloads"]:
+        if cell["traffic"] != name:
+            continue
+        entry = bench_run.by_name(spec["configs"], cell["config"], "config")
+        with open(os.path.join(os.path.dirname(BENCH), entry["file"])) as f:
+            config = json.load(f)
+        found.append(bench_run.check_context(
+            cell, config, bench_run.cell_sizes(cell, False), mix(name)))
+    return found or [bench_run.DEFAULT_MAX_MODEL_LEN]
+
+
 @pytest.mark.parametrize("name", MIXES)
-def test_lengths_stay_inside_the_worker_defaults(name):
+def test_lengths_stay_inside_the_context_a_mix_is_served_at(name):
     m = mix(name)
+    context = min(contexts(name))
     for s in traffic.sessions(m, 1, VOCAB):
         for turn in s:
-            assert len(turn["prompt"]) + turn["max_tokens"] < 4096
+            assert len(turn["prompt"]) + turn["max_tokens"] < context
             assert all(VOCAB[0] <= t < VOCAB[1] for t in turn["prompt"])
             assert turn["max_tokens"] >= 1
     assert traffic.max_output_len(m) == max(
