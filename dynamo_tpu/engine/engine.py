@@ -399,6 +399,7 @@ class JaxEngine:
         self.prefill_rows_total = 0
         self.prefill_steps_overlapped_total = 0
         self.prefill_steps_headless_total = 0
+        self.cross_rows_total = 0
         # (top logprobs, greedy, table width) of every short prefill step
         # met so far: `_meet_short_prefill`
         self._short_prefill_met: set = set()
@@ -749,6 +750,8 @@ class JaxEngine:
             m.moe_steps_total = self.moe_steps_total
             if self.model_cfg.hc_mult:
                 m.hc_res_err_ppm_max = self.hc_res_err_ppm_max
+        if self.model_cfg.cross_decoder:  # beside `prefill_rows_total`
+            m.cross_rows_total = self.cross_rows_total
         st = self.scheduler.state
         if st is not None:  # state slots beside the pages
             m.state_slots_total = st.num_slots - 1
@@ -1672,6 +1675,11 @@ class JaxEngine:
         self.prefill_rows_total += len(items)
         self.prefill_steps_overlapped_total += overlapped
         self.prefill_steps_headless_total += 1 - head
+        # a decoder-hybrid-decoder runs the cross half of its layers with
+        # the head, on every row of a step in which any row samples
+        cross = ({"cross_rows": head * len(items)}
+                 if self.model_cfg.cross_decoder else {})
+        self.cross_rows_total += cross.get("cross_rows", 0)
         return _PrefillStep(
             items=items, item_rows=item_rows, seq_rows=seq_rows, seqs=seqs,
             with_top=with_top, packed_d=packed_d, fused=fused,
@@ -1685,7 +1693,7 @@ class JaxEngine:
                 bucket=chunk_bucket,
                 attn=self._attn_of("prefill_attention", B, chunk_bucket,
                                    table.shape[1]),
-                overlapped=overlapped, head=head),
+                overlapped=overlapped, head=head, **cross),
         )
 
     def _meet_short_prefill(self, rows: int, with_top: bool, greedy: bool,

@@ -20,6 +20,15 @@ MOE_ACTS = ("silu", "gpt_oss_glu", "relu_glu", "relu2")
 # a layer's ONE mixer under `ModelConfig.layer_pattern`
 LAYER_KINDS = {"M": "a state-space (Mamba-2) mixer", "E": "an expert "
                "feed-forward", "*": "attention"}
+# a decoder-hybrid-decoder's layers (phi4flash), each a mixer THEN a dense
+# feed-forward: the self half's "S" and "W" in turn, its last pair "S", "F";
+# the cross half's "G" and "C" in turn
+CROSS_LAYER_KINDS = {
+    "S": "a Mamba-1 selective-scan mixer",
+    "W": "differential attention under the window",
+    "F": "full differential attention, whose pages the cross half reads",
+    "G": "a gated memory unit over the last S layer's scan output",
+    "C": "differential cross-attention over the F layer's pages"}
 MOE_SCORINGS = ("softmax", "sigmoid")
 
 
@@ -82,12 +91,13 @@ class StateSpec:
     arrays [layers, slots, ...]: the convolution's last `conv_kernel - 1`
     inputs in the served dtype, stored as whole 128-lane tiles (a [3, 6144]
     window as [144, 128], the geometry of a key page), and the recurrent
-    state [heads, head_dim, state] in float32."""
+    state `state_dims` in float32: a Mamba-2 layer's [heads, head_dim,
+    state]; a Mamba-1 layer's [state, channels], the channels under the
+    lanes (a [5120, 16] state stored the other way round would fill an
+    eighth of every tile)."""
 
     layers: int
-    heads: int
-    head_dim: int
-    state: int
+    state_dims: tuple
     conv_dim: int
     conv_kernel: int
 
@@ -97,15 +107,11 @@ class StateSpec:
         n = (self.conv_kernel - 1) * self.conv_dim
         return (-(-n // 128), 128)
 
-    @property
-    def state_dims(self) -> tuple:
-        return (self.heads, self.head_dim, self.state)
-
     def bytes_per_slot(self, itemsize: int) -> int:
         """One sequence's state over every state-space layer: the window in
         the served dtype, the recurrent state in float32."""
         w = self.window_dims[0] * self.window_dims[1] * itemsize
-        return self.layers * (w + 4 * self.heads * self.head_dim * self.state)
+        return self.layers * (w + 4 * math.prod(self.state_dims))
 
 
 @dataclass(frozen=True)
@@ -240,6 +246,15 @@ class ModelConfig:
     ssm_state: int = 0
     ssm_conv_kernel: int = 4
     ssm_chunk: int = 128
+    # the Mamba-1 mixer (phi4flash; 0: Mamba-2): ONE head of `ssm_head_dim`
+    # channels, each with `ssm_state` decays of its own (`A` [channels,
+    # state]: no block form), the step size through a `ssm_dt_rank`
+    # bottleneck, the convolution over x alone
+    ssm_dt_rank: int = 0
+    # differential attention (phi4flash): heads pair off by parity, a
+    # layer's output is softmax(q1 k1) V - lambda softmax(q2 k2) V over the
+    # value PAIRS, then an rms norm a pair (`models/phi4flash.py`)
+    diff_attention: bool = False
     # the shared expert's own width where the family states one
     # (nemotron_h); None: n_shared_experts * moe_intermediate_size
     moe_shared_intermediate_size: Optional[int] = None
@@ -278,11 +293,12 @@ class ModelConfig:
             raise ValueError("hyper-connections (hc_mult) are implemented "
                              "around latent attention only (xing4_0)")
         if self.layer_pattern is not None:
-            bad = set(self.layer_pattern) - set(LAYER_KINDS)
+            kinds = CROSS_LAYER_KINDS if self.cross_decoder else LAYER_KINDS
+            bad = set(self.layer_pattern) - set(kinds)
             if bad or len(self.layer_pattern) != L:
                 raise ValueError(
                     f"layer_pattern must name {L} layers by "
-                    f"{sorted(LAYER_KINDS)}, got {self.layer_pattern!r}")
+                    f"{sorted(kinds)}, got {self.layer_pattern!r}")
         elif self.moe_act == "relu2":
             raise ValueError("ungated relu2 experts are implemented under a "
                              "layer_pattern only (nemotron_h)")
@@ -321,30 +337,43 @@ class ModelConfig:
                 if self.is_moe else 0)
 
     @property
+    def cross_decoder(self) -> bool:
+        """A decoder-hybrid-decoder (phi4flash: `CROSS_LAYER_KINDS`): the
+        layers after "F" read what "F" and the last "S" left, and run only
+        where a row samples (`models/phi4flash.py`)."""
+        return "C" in (self.layer_pattern or "")
+
+    @property
     def num_kv_layers(self) -> int:
-        """Layers that leave a token keys and values: the page pool's."""
+        """Layers that leave a token keys and values: the page pool's (the
+        "C" layers read "F"'s pages and the "G" layers keep nothing)."""
         if self.layer_pattern is not None:
-            return self.layer_pattern.count("*")
+            return sum(self.layer_pattern.count(c) for c in "*WF")
         return self.num_hidden_layers
 
     @property
     def state_spec(self) -> Optional[StateSpec]:
         """What a sequence leaves the state-space layers; None without."""
-        n = (self.layer_pattern or "").count("M")
+        pattern = self.layer_pattern or ""
+        n = pattern.count("M") + pattern.count("S")
         if not n:
             return None
-        return StateSpec(n, self.ssm_heads, self.ssm_head_dim, self.ssm_state,
-                         self.ssm_conv_dim, self.ssm_conv_kernel)
+        dims = ((self.ssm_state, self.ssm_inner) if self.ssm_dt_rank else
+                (self.ssm_heads, self.ssm_head_dim, self.ssm_state))
+        return StateSpec(n, dims, self.ssm_conv_dim, self.ssm_conv_kernel)
 
     @property
     def ssm_inner(self) -> int:
-        """The Mamba-2 mixer's inner width: heads x head_dim (not
-        `expand` x hidden)."""
+        """The state-space mixer's inner width: heads x head_dim (Mamba-2's
+        is not `expand` x hidden; Mamba-1 is one head)."""
         return self.ssm_heads * self.ssm_head_dim
 
     @property
     def ssm_conv_dim(self) -> int:
-        """What the convolution runs over: x beside every group's B, C."""
+        """What the convolution runs over: x beside every group's B, C
+        (Mamba-2), or x alone (Mamba-1)."""
+        if self.ssm_dt_rank:
+            return self.ssm_inner
         return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
 
     @property
@@ -373,6 +402,17 @@ class ModelConfig:
         if self.is_latent:
             return CacheSpec("latent", 1, self.kv_lora_rank,
                              self.qk_rope_head_dim)
+        if self.diff_attention:
+            # the same values in the same order as [heads, head_dim], read
+            # as 2 rows: heads 2i and 2i + 1 side by side are the key pair
+            # [k1 | k2] and the value pair [v1 | v2] differential attention
+            # reads, 128 wide, and a row holds half of the pairs.  A plane
+            # of [10, 128] the TPU compiler pads to 16 rows and re-lays out
+            # around the scatter (three pool-sized temporaries a step), one
+            # of [1, 1280] likewise; 2 rows it keeps where they are (AOT
+            # for a v5e, PR 48)
+            return CacheSpec("kv", 2, self.num_key_value_heads
+                             * self.head_dim_ // 2)
         return CacheSpec("kv", self.num_key_value_heads, self.head_dim_)
 
     @property
@@ -448,10 +488,22 @@ class ModelConfig:
         mixer and one norm."""
         h, pat = self.hidden_size, self.layer_pattern
         d, cd, nh = self.ssm_inner, self.ssm_conv_dim, self.ssm_heads
-        mamba = (h * (d + cd + nh) + cd * self.ssm_conv_kernel + cd + 3 * nh
-                 + d + d * h)
         q = self.num_attention_heads * self.head_dim_
         kv = self.num_key_value_heads * self.head_dim_
+        if self.cross_decoder:
+            N, r, hd = self.ssm_state, self.ssm_dt_rank, self.head_dim_
+            mamba = (h * 2 * d + d * self.ssm_conv_kernel + d
+                     + d * (r + 2 * N) + r * d + d + d * N + d + d * h)
+            diff = 4 * hd + 2 * hd + q * h + h  # lambdas, subln, out_proj
+            mixer = {"S": mamba, "W": h * (q + 2 * kv) + q + 2 * kv + diff,
+                     "G": 2 * h * d, "C": h * q + q + diff}
+            mixer["F"] = mixer["W"]
+            # two LayerNorms (weight and bias) and the feed-forward a layer
+            layer = 4 * h + 3 * h * self.intermediate_size
+            return (sum(mixer[c] + layer for c in pat)
+                    + self.vocab_size * h + 2 * h)
+        mamba = (h * (d + cd + nh) + cd * self.ssm_conv_kernel + cd + 3 * nh
+                 + d + d * h)
         attn = h * q + 2 * h * kv + q * h
         fm = self.moe_intermediate_size
         gated = 2 if self.moe_act == "relu2" else 3
@@ -476,6 +528,14 @@ class ModelConfig:
             return ModelConfig(**_xing4_0_fields(d, name))
         if d.get("model_type") == "nemotron_h":
             return ModelConfig(**_nemotron_h_fields(d, name))
+        if d.get("model_type") == "phi4flash":
+            return ModelConfig(**_phi4flash_fields(d, name))
+        if d.get("mb_per_layer"):
+            raise ValueError(
+                f"model_type {d.get('model_type')!r} asks for state-space "
+                "layers among its attention layers (mb_per_layer) and only "
+                "phi4flash's are implemented: the llama branch would build "
+                "another model")
         if d.get("hybrid_override_pattern"):
             raise ValueError(
                 f"model_type {d.get('model_type')!r} asks for a layer "
@@ -701,6 +761,79 @@ def _nemotron_h_fields(d: dict, name: str) -> dict:
         moe_ep_rank=d.get("ep_rank", 0),
         model_type="nemotron_h",
         name=name or d.get("_name_or_path", "nemotron_h"),
+    )
+
+
+def _phi4flash_fields(d: dict, name: str) -> dict:
+    """Microsoft Phi-4-mini-flash (`model_type` "phi4flash"): a
+    decoder-hybrid-decoder.  Layer l of L, each a mixer then a dense SwiGLU
+    feed-forward behind LayerNorms: l even up to L/2 a Mamba-1 mixer, l odd
+    below L/2 differential attention under `sliding_window`, l = L/2 + 1
+    full differential attention, and past it the cross half: l even a gated
+    memory unit over layer L/2's scan output, l odd differential
+    cross-attention over layer L/2 + 1's keys and values.  No positions
+    anywhere.  The Mamba-1 sizes are the family's defaults where
+    config.json has no key for them.  What is not implemented is refused by
+    the key that asks for it."""
+    def refuse(key, why):
+        raise ValueError(f"phi4flash: {key} {d.get(key)!r} {why}")
+
+    L, h = d["num_hidden_layers"], d["hidden_size"]
+    if d.get("mb_per_layer", 2) != 2:
+        refuse("mb_per_layer", "only every second layer a state-space mixer "
+               "is implemented")
+    if L < 8 or L % 4:
+        refuse("num_hidden_layers", "must be a multiple of 4 and at least "
+               "8: half self-decoder (state-space and windowed layers in "
+               "turn, then one full layer), half cross-decoder")
+    if d.get("hidden_act", "silu") != "silu":
+        refuse("hidden_act", "only silu is implemented")
+    for key in ("mlp_bias", "lm_head_bias"):
+        if d.get(key):
+            refuse(key, "is not implemented")
+    if not d.get("tie_word_embeddings", True):
+        refuse("tie_word_embeddings", "only the tied head is implemented")
+    if not isinstance(d.get("sliding_window"), int) or (
+            d["sliding_window"] <= 0):
+        refuse("sliding_window", "must be the windowed layers' one width "
+               "in tokens")
+    nq, nkv = d["num_attention_heads"], d.get("num_key_value_heads",
+                                              d["num_attention_heads"])
+    if nkv % 2 or nq % nkv:
+        refuse("num_key_value_heads", "differential attention pairs heads "
+               f"by parity: must be even and divide the {nq} query heads")
+    if d.get("rope_scaling"):
+        refuse("rope_scaling", "the family's attention applies no "
+               "positions: nothing would read it")
+    expand = d.get("mamba_expand", 2)
+    dt_rank = d.get("mamba_dt_rank", "auto")
+    half = L // 2
+    pattern = "SW" * (half // 2) + "SF" + "GC" * ((half - 2) // 2)
+    return dict(
+        vocab_size=d["vocab_size"],
+        hidden_size=h,
+        intermediate_size=d["intermediate_size"],
+        num_hidden_layers=L,
+        num_attention_heads=nq,
+        num_key_value_heads=nkv,
+        head_dim=d.get("head_dim"),
+        max_position_embeddings=d.get("max_position_embeddings", 4096),
+        # one epsilon: the LayerNorms' and the pair norm's (`subln`)
+        rms_norm_eps=d.get("layer_norm_eps", 1e-5),
+        attention_rope=False,
+        tie_word_embeddings=True,
+        attention_bias=True,
+        attention_out_bias=True,
+        sliding_window=d["sliding_window"],
+        diff_attention=True,
+        layer_pattern=pattern,
+        ssm_heads=1,
+        ssm_head_dim=expand * h,
+        ssm_state=d.get("mamba_d_state", 16),
+        ssm_conv_kernel=d.get("mamba_d_conv", 4),
+        ssm_dt_rank=(-(-h // 16) if dt_rank == "auto" else dt_rank),
+        model_type="phi4flash",
+        name=name or d.get("_name_or_path", "phi4flash"),
     )
 
 

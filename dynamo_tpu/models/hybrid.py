@@ -314,19 +314,27 @@ def layers(params: Params, cfg: ModelConfig, kv: StateCache, x: jax.Array,
         new["k"], new["v"] = write_kv_layers(
             kv.k, kv.v, k_new, v_new, table, prefix_lens, valid)
     if st_m:
-        win, h1, *inside = own(st_m, 0)
-        with jax.named_scope("state.write"):
-            conv, pool = kv.conv, kv.ssm
-            for j, (w, hj) in enumerate(inside):  # before the chunk's end:
-                # a row whose slots coincide keeps its latest state
-                conv = conv.at[:, slot_inside[:, j]].set(w)
-                pool = pool.at[:, slot_inside[:, j]].set(hj)
-            new["conv"] = conv.at[:, slot_out].set(win)
-            new["ssm"] = pool.at[:, slot_out].set(h1)
+        new["conv"], new["ssm"] = write_states(kv, own(st_m, 0), slot_out,
+                                               slot_inside)
     kv = kv._replace(**new)
     if not moe_stats:
         return x, kv
     return x, kv, merge_moe_stats(own(st_e, 2)[0])
+
+
+def write_states(kv: StateCache, states, slot_out: jax.Array,
+                 slot_inside: jax.Array):
+    """Every state-space layer's new states into the slot pools: `states`
+    (window, state, *(window, state) inside the chunk), each [Lm, B, ..]
+    -> (conv, ssm)."""
+    win, h1, *inside = states
+    with jax.named_scope("state.write"):
+        conv, pool = kv.conv, kv.ssm
+        for j, (w, hj) in enumerate(inside):  # before the chunk's end:
+            # a row whose slots coincide keeps its latest state
+            conv = conv.at[:, slot_inside[:, j]].set(w)
+            pool = pool.at[:, slot_inside[:, j]].set(hj)
+        return conv.at[:, slot_out].set(win), pool.at[:, slot_out].set(h1)
 
 
 def _as_tiles(win: jax.Array, dims: Tuple[int, int]) -> jax.Array:

@@ -43,6 +43,7 @@ from ..ops import hyper_connections as hc
 from ..ops import (
     apply_rope,
     decode_attention,
+    layer_norm,
     prefill_attention,
     rms_norm,
     rope_attention_scale,
@@ -132,9 +133,10 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
         return (jax.random.normal(k, shape, jnp.float32) * scale).astype(dtype)
 
     if cfg.layer_pattern is not None:
-        from . import hybrid
+        from . import hybrid, phi4flash
 
-        return hybrid.init_params(cfg, key, dtype)
+        return (phi4flash if cfg.cross_decoder else hybrid).init_params(
+            cfg, key, dtype)
     if cfg.is_latent or cfg.first_k_dense:
         return _init_params_stacks(cfg, key, dtype, w)
     layers = {
@@ -1215,6 +1217,9 @@ def _final_norm(params: Params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
         x = hc.head_reduce(
             x, params["hc_head_phi"], params["hc_head_scale"],
             params["hc_head_base"], eps=cfg.hc_eps, rms_eps=cfg.rms_norm_eps)
+    if "final_norm_bias" in params:  # a family of LayerNorms (phi4flash)
+        return layer_norm(x, params["final_norm"], params["final_norm_bias"],
+                          cfg.rms_norm_eps)
     return rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
 
 
@@ -1373,7 +1378,8 @@ def forward_prefill(
     vocabulary matmul and the caller's `then(logits)`: sampling, logprobs)
     runs under ONE `lax.cond` on "any row samples", and a step in which
     none does returns zeros of the same shapes: a mid-prompt chunk does
-    not read the vocabulary matrix.  One scalar for the step and never a
+    not read the vocabulary matrix, nor, in a decoder-hybrid-decoder
+    (`models/phi4flash.py`), run the cross half of the layers.  One scalar for the step and never a
     select by row, which would run both sides.  What `then` returns takes
     the logits' place.  None: every row samples, no conditional.
 
@@ -1393,7 +1399,15 @@ def forward_prefill(
             x = jnp.where(extra_mask[..., None],
                           extra_embeds.astype(x.dtype), x)
         x = _streams(cfg, x)
-    if cfg.layer_pattern is not None:
+    handed = None
+    if cfg.cross_decoder:
+        # the self half over every token; the cross half is the head's
+        from . import phi4flash
+
+        x, kv, handed = phi4flash.self_layers(
+            params, cfg, kv, x, page_table, prefix_lens, chunk_lens)
+        st = []
+    elif cfg.layer_pattern is not None:
         from . import hybrid
 
         x, kv, *st = hybrid.layers(params, cfg, kv, x, page_table,
@@ -1411,6 +1425,12 @@ def forward_prefill(
         last = jnp.maximum(chunk_lens - 1, 0)
         at = last[:, None, None, None] if cfg.hc_mult else last[:, None, None]
         x_last = jnp.take_along_axis(x, at, axis=1)[:, 0]  # [B, h] | [B, n, h]
+        if handed is not None:
+            # no cross layer mixes positions but through what the self half
+            # wrote for every token: a row needs them where it samples only
+            x_last = phi4flash.cross_layers(
+                params, cfg, kv, x_last, handed, last, page_table,
+                prefix_lens)
         logits = _lm_logits(params, cfg, x_last)
         return logits if then is None else then(logits)
 
@@ -1483,6 +1503,16 @@ def forward_decode(
     """One decode step for the whole batch; returns (logits [B, V], kv)."""
     with jax.named_scope("embed"):
         x = _streams(cfg, params["embed"][tokens])  # [B, h]
+    if cfg.cross_decoder:
+        # a chunk of one token through both halves of the layers
+        from . import phi4flash
+
+        ones = jnp.ones_like(positions)
+        x, kv, handed = phi4flash.self_layers(
+            params, cfg, kv, x[:, None], page_table, positions, ones)
+        x = phi4flash.cross_layers(params, cfg, kv, x[:, 0], handed,
+                                   ones - 1, page_table, positions)
+        return (_lm_logits(params, cfg, x), kv)
     if cfg.layer_pattern is not None:
         # a chunk of one token through the one layer loop: the state is
         # read from and written to the row's slots, the token's keys and

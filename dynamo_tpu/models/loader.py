@@ -86,6 +86,8 @@ def load_params(path: str, cfg: ModelConfig, dtype=jnp.bfloat16,
     L = cfg.num_hidden_layers
     if cfg.is_latent:  # deepseek_v3, and xing4_0 around its layers
         return _load_deepseek_v3(r, cfg, dtype, prefix)
+    if cfg.cross_decoder:
+        return _load_phi4flash(r, cfg, dtype, prefix)
     if cfg.layer_pattern is not None:
         return _load_nemotron_h(r, cfg, dtype, prefix)
 
@@ -304,6 +306,79 @@ def _load_nemotron_h(r: "_ShardReader", cfg: ModelConfig, dtype,
         },
     }
     return params
+
+
+def _load_phi4flash(r: "_ShardReader", cfg: ModelConfig, dtype,
+                    prefix: str = ""):
+    """phi4flash tensor names (`model.layers.{l}.attn.*`, whatever the
+    layer's mixer, `.mlp.{fc1, fc2}` and two LayerNorms a layer) ->
+    `models.phi4flash.init_params`'s four stacks by kind.  `A_log` is
+    stored state-major [N, d] (the channels under the lanes), float32, as
+    the step-size bias, `D` and the four lambda vectors are whatever the
+    file's dtype; the convolution's [d, 1, K] weight tap-major [K, d]."""
+    pat = cfg.layer_pattern
+
+    def stacks(kinds):
+        ids = [i for i, c in enumerate(pat) if c in kinds]
+
+        def stack(name, fn=lambda w: w.T, dt=dtype):
+            return jnp.asarray(np.stack([fn(r.get(
+                prefix + f"model.layers.{i}.{name}")) for i in ids]), dt)
+
+        return stack
+
+    def same(w):
+        return w
+
+    def f32(stack, name, fn=same):
+        return stack(name, lambda w: fn(w).astype(np.float32), jnp.float32)
+
+    def block(stack):  # the two LayerNorms and the feed-forward
+        return {"norm": stack("input_layernorm.weight", same),
+                "norm_b": stack("input_layernorm.bias", same),
+                "mlp_norm": stack("post_attention_layernorm.weight", same),
+                "mlp_norm_b": stack("post_attention_layernorm.bias", same),
+                "w_gateup": stack("mlp.fc1.weight"),
+                "w_down": stack("mlp.fc2.weight")}
+
+    def diff(stack):
+        inner = "attn.inner_cross_attn."
+        return {"lambda": jnp.stack([f32(stack, inner + n) for n in (
+                    "lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2")], 1),
+                "subln": stack(inner + "subln.weight", same),
+                "wo": stack("attn.out_proj.weight"),
+                "bo": stack("attn.out_proj.bias", same)}
+
+    S, A, G, C = (stacks(k) for k in ("S", "WF", "G", "C"))
+    return {
+        "embed": jnp.asarray(r.get(prefix + "model.embed_tokens.weight"),
+                             dtype),
+        "final_norm": jnp.asarray(
+            r.get(prefix + "model.final_layernorm.weight"), dtype),
+        "final_norm_bias": jnp.asarray(
+            r.get(prefix + "model.final_layernorm.bias"), dtype),
+        "ssm_layers": {
+            **block(S),
+            "in_proj": S("attn.in_proj.weight"),
+            "conv_w": S("attn.conv1d.weight",
+                        lambda w: w.reshape(w.shape[0], -1).T),
+            "conv_b": S("attn.conv1d.bias", same),
+            "x_proj": S("attn.x_proj.weight"),
+            "dt_proj": S("attn.dt_proj.weight"),
+            "dt_bias": f32(S, "attn.dt_proj.bias"),
+            "A_log": f32(S, "attn.A_log", lambda w: w.T),
+            "D": f32(S, "attn.D"),
+            "out_proj": S("attn.out_proj.weight"),
+        },
+        "attn_layers": {**block(A), **diff(A),
+                        "wqkv": A("attn.Wqkv.weight"),
+                        "bqkv": A("attn.Wqkv.bias", same)},
+        "gmu_layers": {**block(G), "w_in": G("attn.in_proj.weight"),
+                       "w_out": G("attn.out_proj.weight")},
+        "cross_layers": {**block(C), **diff(C),
+                         "wq": C("attn.Wqkv.weight"),
+                         "bq": C("attn.Wqkv.bias", same)},
+    }
 
 
 def _load_deepseek_v3(r: "_ShardReader", cfg: ModelConfig, dtype,

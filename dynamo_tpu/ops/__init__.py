@@ -5,7 +5,7 @@ Reference impls are pure jnp (XLA fuses them well); Pallas kernels live in
 on real TPU hardware.
 """
 
-from .norm import rms_norm
+from .norm import layer_norm, rms_norm
 from .paged_attention import (
     decode_attention,
     gather_kv,
@@ -31,6 +31,7 @@ __all__ = [
     "compute_logprobs",
     "decode_attention",
     "gather_kv",
+    "layer_norm",
     "prefill_attention",
     "rms_norm",
     "rope_attention_scale",

@@ -1,4 +1,5 @@
-"""RMSNorm — computed in float32, scaled, cast back (llama convention)."""
+"""RMSNorm and LayerNorm — computed in float32, scaled, cast back (llama
+convention)."""
 
 from __future__ import annotations
 
@@ -11,3 +12,13 @@ def rms_norm(x: jax.Array, weight: jax.Array, eps: float = 1e-5) -> jax.Array:
     var = jnp.mean(xf * xf, axis=-1, keepdims=True)
     normed = xf * jax.lax.rsqrt(var + eps)
     return (normed * weight.astype(jnp.float32)).astype(x.dtype)
+
+
+def layer_norm(x: jax.Array, weight: jax.Array, bias: jax.Array,
+               eps: float = 1e-5) -> jax.Array:
+    """LayerNorm with its mean and its bias, over the last axis."""
+    xf = x.astype(jnp.float32)
+    xc = xf - jnp.mean(xf, axis=-1, keepdims=True)
+    var = jnp.mean(xc * xc, axis=-1, keepdims=True)
+    return (xc * jax.lax.rsqrt(var + eps) * weight.astype(jnp.float32)
+            + bias.astype(jnp.float32)).astype(x.dtype)

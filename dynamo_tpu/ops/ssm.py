@@ -1,6 +1,7 @@
-"""State-space (Mamba-2) layer operations: the causal depthwise convolution
-with a carried window, the chunked selective scan from a carried state, and
-the grouped gated RMS norm.
+"""State-space layer operations: the causal depthwise convolution with a
+carried window, Mamba-2's chunked selective scan from a carried state and its
+grouped gated RMS norm, and Mamba-1's selective scan (`selective_scan`, at
+the end: a decay for every channel AND state index, which has no block form).
 
 A sequence leaves such a layer TWO things, whatever its length: the
 convolution's last `kernel - 1` inputs (the window) and the recurrent state
@@ -23,6 +24,8 @@ function of its inputs as the token-by-token loop, not another model.
 """
 
 from __future__ import annotations
+
+import math
 
 import jax
 import jax.numpy as jnp
@@ -139,3 +142,64 @@ def gate_norm(y: jax.Array, z: jax.Array, w: jax.Array, groups: int,
     var = jnp.mean(parts * parts, axis=-1, keepdims=True)
     out = (parts * jax.lax.rsqrt(var + eps)).reshape(g.shape)
     return (out * w.astype(jnp.float32)).astype(y.dtype)
+
+
+def selective_scan(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
+                   Cm: jax.Array, h0: jax.Array, at: tuple = (),
+                   kernel: bool | None = None, interpret: bool = False):
+    """Mamba-1's selective scan over S tokens from state `h0`: per channel
+    c and state index n
+
+        H_t[n, c] = exp(dt_t[c] A[n, c]) H_{t-1}[n, c] + dt_t[c] B_t[n] x_t[c]
+        y_t[c]    = sum_n C_t[n] H_t[n, c]
+
+    x [B, S, C]; dt [B, S, C] float32, already softplus'd and ZERO at the
+    positions that must not move the state; A [N, C] float32 (negative: the
+    state's own order, the channels under the lanes); Bm, Cm [B, S, N]; h0
+    [B, N, C] float32.  `at`: static token counts short of S.
+    -> (y [B, S, C] float32, WITHOUT the `D x` skip, which is the caller's;
+    h [B, N, C] float32; and the state after each of `at`'s token counts, a
+    list), the contract `scan` has.
+
+    Every token's decay is [N, C] numbers of its own, so there is no block
+    form: a loop over the tokens with the state resident, nothing of the [S,
+    N, C] kind written.  On a TPU that loop is ONE kernel (`ops.pallas_ssm`),
+    its blocks of tokens ending where a state is handed out, and a C its
+    tiles do not hold is refused, never sent another way.  Elsewhere (the
+    CPU's tests) it is a `lax.scan` over the tokens, cut at the same places.
+    `kernel` and `interpret` are a check's: the other form on this backend,
+    and the kernel interpreted where no TPU is (`scripts/
+    check_selective_scan.py`)."""
+    from . import pallas_ssm
+
+    f32 = jnp.float32
+    S, C = x.shape[1:]
+    if kernel is None:
+        kernel = jax.default_backend() == "tpu"
+    dt, dtx = dt.astype(f32), dt.astype(f32) * x.astype(f32)
+    if kernel:
+        if not pallas_ssm.fits(C):
+            raise ValueError(
+                f"selective_scan: {C} channels are no whole tiles of "
+                f"{pallas_ssm.GROUP}, which is what the TPU's kernel holds")
+        block = math.gcd(S, *at, pallas_ssm.MAX_BLOCK)
+        y, hs = pallas_ssm.selective_scan_pallas(
+            dt, dtx, A.astype(f32), Bm, Cm, h0.astype(f32), block,
+            interpret=interpret)
+        return y, hs[:, -1], [hs[:, t // block - 1] for t in at]
+    # time-major: a row's token is one leading index
+    xs = tuple(jnp.moveaxis(a.astype(f32), 1, 0) for a in (dt, dtx, Bm, Cm))
+
+    def token(h, tok):
+        dt_t, dtx_t, b_t, c_t = tok  # [B, C] [B, C] [B, N] [B, N]
+        h = (jnp.exp(dt_t[:, None, :] * A) * h
+             + b_t[:, :, None] * dtx_t[:, None, :])
+        return h, jnp.sum(c_t[:, :, None] * h, axis=1)
+
+    h, ys, states = h0.astype(f32), [], []
+    for a, b in zip((0, *at), (*at, S)):
+        h, y = jax.lax.scan(token, h, tuple(v[a:b] for v in xs))
+        ys.append(y)
+        states.append(h)
+    y = ys[0] if len(ys) == 1 else jnp.concatenate(ys, axis=0)
+    return jnp.moveaxis(y, 0, 1), h, states[:-1]

@@ -90,6 +90,7 @@ def representative_engine_stats() -> dict:
     stats["state_snapshot_hits_total"] = 0
     stats["state_snapshot_evictions_total"] = 0
     stats["state_hit_tokens_shortened_total"] = 0
+    stats["cross_rows_total"] = 0  # a decoder-hybrid-decoder's cross half
     # KVBM tiers (engine.metrics() with a connector attached)
     stats["kvbm_host_blocks"] = 0
     stats["kvbm_pending_offloads"] = 0
