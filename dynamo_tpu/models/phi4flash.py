@@ -6,7 +6,13 @@ phi4flash).  A layer is a mixer THEN a dense SwiGLU feed-forward, each
   ("S" a Mamba-1 mixer, then "W" differential attention under the window),
   the last unit's attention "F" seeing every key.  ONE `lax.scan` over the
   units whatever the depth; the window is a SHAPE (the pages gathered), so
-  the unit holds both attention bodies under a `lax.cond`.  It leaves a
+  the unit holds both attention bodies under a `lax.cond`.  The Mamba
+  layer's weights are scanned; the attention layer's are NOT: an operand of
+  a conditional is a buffer of its own, so a scanned slice handed to the
+  branches is copied out of its stack every unit, where a slice that feeds
+  a product in the same computation is read in place.  The branches close
+  over the whole stacks (passed as they stand, like the pools) and index
+  them by the unit, as `models/hybrid.py` `layers` does.  The half leaves a
   token keys and values in the pages of its attention layers, a sequence a
   state in the slots of its "S" layers (the same slots, snapshots and table
   columns `models/hybrid.py` has), and hands on the residual, the LAST "S"
@@ -292,7 +298,9 @@ def self_layers(params: Params, cfg: ModelConfig, kv: StateCache,
             _as_tiles(win, spec.window_dims), h1, *(
                 (_as_tiles(w, spec.window_dims), hj) for w, hj in inside))
 
-    def attention(h, lp, layer, lam_init, window):
+    def attention(h, layer, lam_init, window):
+        # inside the branch, out of the whole stacks: see `unit`
+        lp = jax.tree.map(lambda a: a[layer], params["attn_layers"])
         u = _normed(lp, cfg, h)
         with jax.named_scope("attn.qkv"):
             qkv = matmul_any(u, lp["wqkv"], "bsh,hd->bsd") + lp["bqkv"]
@@ -307,22 +315,27 @@ def self_layers(params: Params, cfg: ModelConfig, kv: StateCache,
 
     def unit(carry, xs):
         h, _ = carry
-        sp, ap, layer, windowed, lam_init = xs
+        sp, layer, windowed, lam_init = xs
         h, m, state = mamba(h, sp, layer)
-        # the window is a SHAPE here (the pages gathered): two bodies
+        # the window is a SHAPE here (the pages gathered): two bodies.  An
+        # operand of a conditional is a buffer of its own, so the attention
+        # layer's weights are not scanned (a scanned slice handed to the
+        # branches was copied out of its stack every unit: 197 MB, a fifth
+        # of the device at the published widths); the branches close over
+        # the whole stacks, which pass as they stand, and index them, so a
+        # matrix is read in place by the product it feeds
         h, kv_new = jax.lax.cond(
             windowed,
-            lambda h: attention(h, ap, layer, lam_init, cfg.sliding_window),
-            lambda h: attention(h, ap, layer, lam_init, 0), h)
+            lambda h: attention(h, layer, lam_init, cfg.sliding_window),
+            lambda h: attention(h, layer, lam_init, 0), h)
         return (h, m), (state, kv_new)
 
     n = cfg.layer_pattern.count("S")
     windowed = np.asarray([c == "W" for c in cfg.layer_pattern if c in "WF"])
     (x, memory), (states, (k_new, v_new)) = jax.lax.scan(
         unit, (x, jnp.zeros((B, S, cfg.ssm_inner), dt_)),
-        (params["ssm_layers"], params["attn_layers"],
-         jnp.arange(n, dtype=jnp.int32), jnp.asarray(windowed),
-         jnp.asarray(_lambda_inits(cfg, "WF"))))
+        (params["ssm_layers"], jnp.arange(n, dtype=jnp.int32),
+         jnp.asarray(windowed), jnp.asarray(_lambda_inits(cfg, "WF"))))
     plane = kv.k.shape[3:]  # the pairs as the pool's plane has them
     k_pool, v_pool = write_kv_layers(
         kv.k, kv.v, k_new.reshape(*k_new.shape[:3], *plane),

@@ -13,6 +13,7 @@ FAMILY INITIALISES THEM (A = -(1..N) by state index, steps in 0.001-0.1, D
 import dataclasses
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -26,6 +27,7 @@ from dynamo_tpu.models import KVCache, ModelConfig, init_params
 from dynamo_tpu.models import llama, phi4flash
 from dynamo_tpu.models.loader import load_params
 from dynamo_tpu.ops import layer_norm, ssm
+from test_nemotron_h import TINY as NEMOTRON_TINY
 from test_nemotron_h import (BENCH, PAGE, PEAKS, ROOT, TOL, bench_module,
                              engine_of, generate, logp, prompt, table_for,
                              with_slots)
@@ -621,6 +623,83 @@ def test_step_kinds_that_want_every_position_refuse_the_family(cfg, params):
                                 table_for(8, [0, 1]), 2, 64, None, ())
 
 
+# -- what the layer loop's conditionals take ------------------------------------- #
+
+_CALLED = re.compile(r"\w+=(?:\{([^}]*)\}|%?([\w.\-]+))")
+_ARRAY = re.compile(r"\b[a-z]+\d+\[([\d,]*)\]")
+
+
+def loop_conditional_operands(text):
+    """The shapes of the arrays that are operands of a `conditional` inside
+    a `while` body of an HLO module's text, the computations the body calls
+    among it: a set of tuples, the members of an operand tuple each for
+    itself.  An operand of a conditional is a buffer of its own, so what is
+    listed here is materialised every trip of the loop."""
+    comps, lines = {}, None
+    for line in text.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            lines = comps.setdefault(line.split()[-2].lstrip("%"), [])
+        elif lines is not None and line.startswith("  "):
+            lines.append(line.strip().removeprefix("ROOT "))
+    todo = [b for ls in comps.values() for l in ls if " while(" in l
+            for b in re.findall(r"body=%?([\w.\-]+)", l)]
+    inside, shapes = set(), set()
+    while todo:
+        comp = todo.pop()
+        if comp in inside:
+            continue
+        inside.add(comp)
+        defs = dict(l.lstrip("%").split(" = ", 1)
+                    for l in comps[comp] if " = " in l)
+        for rest in defs.values():
+            todo += [n for many, one in _CALLED.findall(rest)
+                     for n in (x.strip().lstrip("%")
+                               for x in (many or one).split(","))
+                     if n in comps]
+            cond = re.search(r"\bconditional\(([^)]*)\)", rest)
+            for operand in cond.group(1).split(", ")[1:] if cond else ():
+                made = defs[operand.split()[-1].lstrip("%")]
+                # its type: everything before the instruction's own name
+                kind = re.split(r" [\w\-]+\(", made, maxsplit=1)[0]
+                shapes |= {tuple(int(d) for d in dims.split(",") if d)
+                           for dims in _ARRAY.findall(kind)}
+    return shapes
+
+
+FAMILIES = {  # the tiny model, how its tests run it, its attention matrices
+    "phi4flash": (TINY, {"ssm_chunk": 16},
+                  ("wqkv", "wo", "w_gateup", "w_down")),
+    "nemotron_h": (NEMOTRON_TINY, {"moe_impl": "ragged"},
+                   ("wq", "wk", "wv", "wo")),
+}
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_no_layer_matrix_is_an_operand_of_a_conditional_in_the_layer_loop(
+        family):
+    """A conditional's operand is a buffer of its own, so ONE layer's matrix
+    (a slice of its stack) handed to a `lax.cond` inside the layer loop is
+    copied out of the stack every unit: 197 MB a unit and a fifth of the
+    device at phi4flash's published widths (ISSUE 49).  Whole stacks and
+    pools may pass (they are passed as they stand, and the head's
+    conditional, outside the loop, takes them so); a layer's slice may not.
+    The guard for the next family that puts a layer under a conditional."""
+    model, how, names = FAMILIES[family]
+    cfg = dataclasses.replace(
+        ModelConfig.from_hf_config(model, name="tiny-" + family), **how)
+    params = init_params(cfg, jax.random.PRNGKey(49), dtype=jnp.float32)
+    text = forward_prefill.lower(
+        params, cfg, fresh_cache(cfg, 64), jnp.zeros((1, 32), jnp.int32),
+        table_for(64, [0, 1]), jnp.zeros((1,), jnp.int32),
+        jnp.full((1,), 32, jnp.int32), samples=jnp.asarray([True]),
+    ).compiler_ir("hlo").as_hlo_text()
+    operands = loop_conditional_operands(text)
+    # the walk found the loop's conditional: a pool is among its operands
+    assert fresh_cache(cfg, 64).k.shape in operands
+    one_layer = {n: params["attn_layers"][n].shape[1:] for n in names}
+    assert not {n: s for n, s in one_layer.items() if s in operands}
+
+
 # -- the benchmark's count and its trace readers ------------------------------------ #
 
 @pytest.fixture(scope="module")
@@ -767,3 +846,73 @@ def test_the_new_readers_reduce_a_traced_window(bench_lib, tmp_path,
         assert all(r(dict(bare, trace=None)) is None for r in read.values())
     finally:
         sys.path.remove(BENCH)
+
+
+# -- ops that only move data (ISSUE 49) ---------------------------------------------- #
+
+MOVED = [
+    ("%dynamic-slice_bitcast_fusion.13 = bf16[2560,20480]{1,0} fusion(%p)",
+     "copy"),
+    ("%copy.7 = bf16[1,512,2560]{2,1,0} copy(%p)", "copy"),
+    ("%dynamic-slice.3 = bf16[2560,5120]{1,0} dynamic-slice(%p, %i)", "copy"),
+    ("%slice_copy_fusion = f32[1,512,640]{2,1,0} fusion(%p)", "copy"),
+    ("%bitcast_add_fusion.15 = bf16[1,512,2560]{2,1,0} fusion(%p)", None),
+    ("%dynamic-update-slice_fusion.2 = bf16[9,321,16,2,640] fusion(%p)",
+     None),
+    ("%while.4 = (s32[], bf16[1,512,2560]) while(%t)", None),
+    ("%attn.core.8 = f32[1,512,5,2,128] custom-call(%q, %k)", None),
+    ("%fusion.439 = f32[1,512,20480]{2,1,0} fusion(%p)", None),
+    ("%copy-start.1 = (bf16[8], bf16[8], u32[]) copy-start(%p)", None),
+]
+
+
+@pytest.fixture(scope="module")
+def copy_reader(bench_lib):
+    return bench_module("layer_metrics", "step.copy_device_pct")
+
+
+@pytest.mark.parametrize("name,group", MOVED, ids=[
+    n.split(" = ")[0].strip("%") for n, _ in MOVED])
+def test_an_op_that_only_moves_data_is_told_by_its_name(copy_reader, name,
+                                                        group):
+    # the scope says nothing: XLA's own copies carry none
+    assert copy_reader.place(name, "") == group
+    assert copy_reader.place(name, "jit(prefill_step)/while/body/mlp") == group
+
+
+def test_the_copy_share_of_a_traced_window(bench_lib, copy_reader, tmp_path,
+                                           monkeypatch):
+    """Two steps of 50 and 10 ms; the first holds a weight copy of 8 ms and a
+    plain copy of 2 ms inside a loop (the loop's own time is nobody's), the
+    second a dynamic-slice of 0.5 ms: 10.5 of 60 ms.  A window without such
+    ops reads 0.0, a run without a trace None."""
+    _, opwalk, _ = bench_lib
+    ms = 1_000_000
+    names = [n for n, _ in MOVED]
+    t0, t1 = 100 * ms, 200 * ms
+    ops = [[6, t0, 50 * ms],  # the layer loop, its body's ops inside it
+           [0, t0 + 1 * ms, 8 * ms], [8, t0 + 10 * ms, 20 * ms],
+           [1, t0 + 31 * ms, 2 * ms], [4, t0 + 34 * ms, 10 * ms],
+           [5, t0 + 45 * ms, 3 * ms],
+           [7, t1 + 1 * ms, 6 * ms], [2, t1 + 8 * ms, ms // 2]]
+    path = tmp_path / "trace.json"
+
+    def window(events):
+        opwalk._MEMO.clear()  # noqa: SLF001
+        opwalk._COMPACT.clear()  # noqa: SLF001
+        path.write_text(json.dumps({
+            "names": names, "scopes": [""] * len(names), "planes": [{
+                "name": "/device:TPU:0", "lines": [
+                    {"name": "XLA Ops", "events": events}]}]}))
+
+    monkeypatch.setattr(opwalk.moe_trace, "trace_path", lambda: str(path))
+    steps = [{"kind": "prefill_chunk", "t_ns": t - 3 * ms, "dur_ns": 60 * ms,
+              "tokens": n} for t, n in ((t0, 512), (t1, 40))]
+    run = {"t0": 0.0, "t1": 1.0, "events": steps,
+           "trace": {"modules": [[(t0, t0 + 50 * ms, "jit_prefill_step"),
+                                  (t1, t1 + 10 * ms, "jit_prefill_step")]]}}
+    window(ops)
+    assert abs(copy_reader.read(run) - 100 * 10.5 / 60) < 1e-6
+    window([o for o in ops if MOVED[o[0]][1] is None])
+    assert copy_reader.read(run) == 0.0
+    assert copy_reader.read(dict(run, trace=None)) is None
