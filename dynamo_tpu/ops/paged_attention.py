@@ -49,14 +49,28 @@ PALLAS_MIN_CTX_TOKENS = 4096
 # PREFILL steps whose XLA attention would materialise at least this many
 # f32 scores a head, chunk x (table + chunk), use the Pallas kernel under
 # "adaptive".  Whole `prefill_step` programs timed on a v5e, both forms
-# forced, chunks 16-512 under tables of 512-4096 tokens at two models'
-# widths (PERF.md, PR 34): XLA's cost follows the TABLE (the gather and the
-# scores) and climbs steeply once the scores pass some 60 MB, the kernel's
-# follows the prefix that is there, at a higher price a key.  From 2^19
-# scores the kernel wins or ties every shape measured (a 512-token chunk
-# under any table: by 2% at 512 tokens of table, by half at 4096); below,
-# XLA does (a 16-64-token chunk under any table up to 4096: by 1-13%).
-PALLAS_MIN_PREFILL_SCORES = 1 << 19
+# forced, every table full (PERF.md, PR 50: `scripts/time_prefill_steps.py`
+# at 28 query heads over 4 KV heads of 128, 14 layers, and at 32 over 2, 6
+# attention layers of 52; 1, 2 and 4 rows, chunks 16-512, tables of 32-512
+# pages): XLA's cost follows the TABLE (the gather and the scores), the
+# kernel's the prefix that is there.  At the first geometry the kernel wins
+# all 49 shapes, from 2^17 scores by 0.3-256 ms a step (a 64-token row under
+# 128 pages by 0.3 of 13.7, four such rows by 1.9 of 17.2, a 512-token chunk
+# under 32 / 128 / 512 pages by 1.0 / 9.3 / 65 of 24.7 / 34.1 / 95.3) and
+# below it by 0.1-0.6 (1-4%).  At the second a step on the kernel carries
+# about 1.8 ms that a step on XLA does not (not understood: the kernel's own
+# time there is 0.2 ms a layer; PERF.md 7 (l)), so XLA wins a lone 64-256
+# -token chunk under 32-128 pages by 0.5-1.9 ms of 19.4-23.1 up to 2^18.2
+# scores, and the kernel wins 64-token rows under 128 pages (by 1.2 of 22.7,
+# four of them by 2.7 of 31.6), a 256-token chunk under 128 pages (0.8) and
+# everything larger (a 512-token chunk under 512 pages by 32 of 78).  2^17
+# is where the shared 64-token rows of a 128-page table, the cached path of
+# every document cell, cross to the kernel at both geometries; it costs the
+# second geometry its lone 128-token chunks (1.8 ms a step), and its cell
+# reads the same end to end (169.2 -> 168.7 ms).  It was 2^19 for the
+# parent's kernel (PR 34), which lost four 64-token rows under 128 and 256
+# pages by 1.7 / 0.9 ms (PR 36).
+PALLAS_MIN_PREFILL_SCORES = 1 << 17
 
 
 def resolve_attention_impl(impl: str = "auto", meshed: bool = False) -> str:

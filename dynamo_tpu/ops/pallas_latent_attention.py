@@ -50,7 +50,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_attention import NEG_INF, _vmem_bytes
+from .pallas_attention import NEG_INF, _vmem_bytes, page_plane
 
 # Tile sizes, from one layer's attention timed alone on a v5e at both
 # deployments' head counts (PERF.md, PR 43, has the table).
@@ -135,7 +135,7 @@ def latent_query_tile(S: int, H: int, rank: int, pe: int, page: int,
     pool_dtype = jnp.dtype(pool_dtype or dtype)
     if S % 8 or pool_dtype.itemsize not in (2, 4) or {
             k_planes[1], v_planes[1]} != {128}:
-        return None  # `plane` reads 32-bit rows of one or two lane tiles
+        return None  # `page_plane` reads 32-bit rows of one or two lane tiles
     tq, C, ts = _geometry(S, H, page, rows, prefix_tile, self_tile)
     while tq >= 1:
         if latent_resident_bytes(tq, S, H, rank, pe, page, C, ts, k_planes,
@@ -208,22 +208,6 @@ def _kernel(
         m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
 
-    def plane(scr, buf, j):
-        """Lane tile j of the T tokens in buffer `buf` of a page scratch
-        [2, C, page, tiles, 128], as [T, 128] rows: one strided load of the
-        scratch as 32-bit rows (module docstring)."""
-        pack = 4 // scr.dtype.itemsize  # lane tiles a 32-bit row
-        words = scr.shape[3] // pack  # 32-bit rows a token
-        u = scr if pack == 1 else scr.bitcast(jnp.uint32)
-        u = u.reshape(2 * T * words, 128)
-        start = pl.multiple_of(buf * (T * words), T * words) + j // pack
-        x = (u[pl.ds(start, T), :] if words == 1
-             else u[pl.ds(start, T, stride=words), :])
-        if pack == 1:
-            return x
-        x = x << 16 if j % 2 == 0 else x & jnp.uint32(0xFFFF0000)
-        return jax.lax.bitcast_convert_type(x, jnp.float32).astype(scr.dtype)
-
     def pages(tile, buf, wait):
         """Start (or await) the 2C async copies that bring key tile `tile`'s
         pages into buffer `buf`.  A loop in the kernel, not in Python: the
@@ -268,9 +252,10 @@ def _kernel(
                 pages(c + 1, 1 - buf, wait=False)
 
             pages(c, buf, wait=True)
-            kf_scr[...] = plane(k_scr, buf, 0)
+            kf_scr[...] = page_plane(k_scr, buf, 0, T)
             for j in range(lf_scr.shape[1] // 128):
-                lf_scr[:, j * 128:(j + 1) * 128] = plane(v_scr, buf, j)
+                lf_scr[:, j * 128:(j + 1) * 128] = page_plane(
+                    v_scr, buf, j, T)
             # the last tile may end inside the prefix (one traced body for
             # whole tiles and that one: the select is 1% of a tile's time,
             # a second body a third of the kernel's trace)

@@ -64,12 +64,33 @@ def test_decode_matches_xla(dtype):
     )
 
 
+# (query heads, KV heads, head_dim): the folded kernel's geometries. 28/4 and
+# 32/2 fold 7 and 16 query heads into one product, 8/8 folds nothing, hd 64
+# reads the slab view (a head is no whole lane tile: not `as_stored`)
+GEOMETRIES = [(8, 4, 64), (28, 4, 128), (32, 2, 128), (8, 8, 128), (8, 2, 64)]
+GEOMETRY_IDS = ["8/4/64", "28/4/128", "32/2/128", "8/8/128", "8/2/64"]
+
+
+def _assert_rows_close(out, ref, chunk_lens, tol):
+    """Rows past chunk_len attend to garbage in both forms: compare the
+    valid ones."""
+    for b, n in enumerate(np.asarray(chunk_lens)):
+        np.testing.assert_allclose(
+            np.asarray(out[b, :n], np.float32),
+            np.asarray(ref[b, :n], np.float32), atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("geometry,dtype", [
+    *((g, jnp.float32) for g in GEOMETRIES),
+    ((28, 4, 128), jnp.bfloat16), ((8, 2, 64), jnp.bfloat16),
+], ids=[*GEOMETRY_IDS, "28/4/128-bf16", "8/2/64-bf16"])
 @pytest.mark.parametrize("prefix", [0, 48])
-def test_prefill_matches_xla(prefix):
+def test_prefill_matches_xla(prefix, geometry, dtype):
     """Chunked prefill: rows with and without a cached prefix, ragged
-    chunk lengths."""
-    B, H, n_kv, hd, page, maxp, S = 3, 8, 4, 64, 16, 12, 64
-    dtype = jnp.float32
+    chunk lengths, every geometry the kernel folds, a float32 and a bf16
+    pool (the chunk in the pool's dtype)."""
+    H, n_kv, hd = geometry
+    B, page, maxp, S = 3, 16, 12, 64
     prefix_lens = jnp.array([prefix, 0, max(prefix - 16, 0)], jnp.int32)
     chunk_lens = jnp.array([S, S - 13, 1], jnp.int32)
     P = 1 + B * maxp
@@ -78,9 +99,9 @@ def test_prefill_matches_xla(prefix):
     table = _page_table(B, maxp, jnp.full((B,), maxp * page), page)
 
     ks = jax.random.split(jax.random.PRNGKey(3), 3)
-    q = jax.random.normal(ks[0], (B, S, H, hd), dtype) * 0.5
-    k_new = jax.random.normal(ks[1], (B, S, n_kv, hd), dtype) * 0.3
-    v_new = jax.random.normal(ks[2], (B, S, n_kv, hd), dtype) * 0.3
+    q = (jax.random.normal(ks[0], (B, S, H, hd)) * 0.5).astype(dtype)
+    k_new = (jax.random.normal(ks[1], (B, S, n_kv, hd)) * 0.3).astype(dtype)
+    v_new = (jax.random.normal(ks[2], (B, S, n_kv, hd)) * 0.3).astype(dtype)
 
     ref = prefill_attention(
         q, k_new, v_new, k_pages, v_pages, table, prefix_lens, chunk_lens
@@ -89,14 +110,8 @@ def test_prefill_matches_xla(prefix):
         q, k_new, v_new, k_pages, v_pages, table, prefix_lens, chunk_lens,
         interpret=True,
     )
-    # rows past chunk_len attend to garbage in both impls — compare valid only
-    for b in range(B):
-        n = int(chunk_lens[b])
-        np.testing.assert_allclose(
-            np.asarray(out[b, :n], np.float32),
-            np.asarray(ref[b, :n], np.float32),
-            atol=2e-5, rtol=2e-5,
-        )
+    _assert_rows_close(out, ref, chunk_lens,
+                       2e-5 if dtype == jnp.float32 else 2e-2)
 
 
 def test_decode_under_jit_and_scan():
@@ -186,14 +201,17 @@ def test_prefill_windowed_matches_xla(window):
         )
 
 
-def test_prefill_windowed_remap_skips_leading_chunks():
-    """Exercise the prefill kernel's chunk-grid REMAP (first > 0): a long
-    cached prefix with a small window must skip whole leading chunks and
+@pytest.mark.parametrize("geometry", [(8, 4, 64), (28, 4, 128), (32, 2, 128)],
+                         ids=["8/4/64", "28/4/128", "32/2/128"])
+def test_prefill_windowed_remap_skips_leading_chunks(geometry):
+    """Exercise the prefill kernel's prefix-tile REMAP (first > 0): a long
+    cached prefix with a small window must skip whole leading tiles and
     still match the XLA mask.  Tolerance is looser: flash accumulation
     vs one-shot einsum differ by f32 noise (~3e-4), masks are exact."""
-    B, H, n_kv, hd, page, S = 2, 8, 4, 64, 16, 64
-    maxp = 24  # 384 tokens >= prefix + chunk
-    prefix_lens = jnp.array([256, 200], jnp.int32)  # first = 1 at window 64
+    H, n_kv, hd = geometry
+    B, page, S = 2, 16, 64
+    maxp = 40  # 640 tokens >= prefix + chunk
+    prefix_lens = jnp.array([520, 200], jnp.int32)  # first = 1 at window 64
     chunk_lens = jnp.array([S, S - 7], jnp.int32)
     P = 1 + B * maxp
     k_pages, v_pages = _make_pool(jax.random.PRNGKey(5), P, page, n_kv, hd,
@@ -204,7 +222,7 @@ def test_prefill_windowed_remap_skips_leading_chunks():
     k_new = jax.random.normal(ks[1], (B, S, n_kv, hd), jnp.float32) * 0.3
     v_new = jax.random.normal(ks[2], (B, S, n_kv, hd), jnp.float32) * 0.3
 
-    for window in (64, 1):  # window=1 also hits the zero-prefix-chunk DMA guard
+    for window in (64, 1):  # window=1 also hits the zero-prefix-tile DMA guard
         ref = prefill_attention(
             q, k_new, v_new, k_pages, v_pages, table, prefix_lens,
             chunk_lens, window=jnp.int32(window),
@@ -213,20 +231,17 @@ def test_prefill_windowed_remap_skips_leading_chunks():
             q, k_new, v_new, k_pages, v_pages, table, prefix_lens,
             chunk_lens, window=jnp.int32(window), interpret=True,
         )
-        for b in range(B):
-            n = int(chunk_lens[b])
-            np.testing.assert_allclose(
-                np.asarray(out[b, :n], np.float32),
-                np.asarray(ref[b, :n], np.float32),
-                atol=5e-4, rtol=5e-4,
-            )
+        _assert_rows_close(out, ref, chunk_lens, 5e-4)
 
 
-def test_sinks_match_xla():
+@pytest.mark.parametrize("geometry", [(8, 2, 64), (28, 4, 128)],
+                         ids=["8/2/64", "28/4/128"])
+def test_sinks_match_xla(geometry):
     """Attention-sink logits in the kernels (denominator-only virtual
     key, folded into the flash finalization) vs the XLA sink softmax —
     decode and windowed prefill."""
-    B, H, n_kv, hd, page, maxp = 3, 8, 2, 64, 16, 12
+    H, n_kv, hd = geometry
+    B, page, maxp = 3, 16, 12
     sink = jnp.linspace(-2.0, 3.0, H).astype(jnp.float32)
 
     seq_lens = jnp.array([5, 60, 150], jnp.int32)
@@ -276,31 +291,39 @@ def _force_query_block(monkeypatch, QB, *geom):
     assert prefill_query_block(*geom) == QB
 
 
-@pytest.mark.parametrize("S,QB,prefix,hd,window,sinks", [
-    (256, None, 160, 64, None, False),
-    (256, None, 160, 64, 40, True),
-    (256, None, 160, 64, 200, False),
+@pytest.mark.parametrize("S,QB,prefix,hd,window,sinks,heads", [
+    (256, None, 160, 64, None, False, (4, 2)),
+    (256, None, 160, 64, 40, True, (4, 2)),
+    (256, None, 160, 64, 200, False, (4, 2)),
     # across QUERY blocks: the chunk's grid axis, the prefix streamed once
     # per block, the causal loop reaching back into earlier blocks' keys
-    (512, 256, 0, 128, None, False),
-    (512, 256, 500, 128, None, False),
-    (512, 256, 1536, 128, None, False),
-    (512, 128, 500, 128, None, False),
-    (512, 128, 1536, 128, 40, True),
-    (512, 256, 500, 128, 40, True),
-    (512, 256, 500, 64, 40, True),
-    (512, 128, 0, 64, None, False),
+    (512, 256, 0, 128, None, False, (4, 2)),
+    (512, 256, 500, 128, None, False, (4, 2)),
+    (512, 256, 1536, 128, None, False, (4, 2)),
+    (512, 128, 500, 128, None, False, (4, 2)),
+    (512, 128, 1536, 128, 40, True, (4, 2)),
+    (512, 256, 500, 128, 40, True, (4, 2)),
+    (512, 256, 500, 64, 40, True, (4, 2)),
+    (512, 128, 0, 64, None, False, (4, 2)),
+    # the cells' folds (7 and 16 query heads a KV head), no fold, and the
+    # slab view, a query block smaller than the chunk; one block of 128
+    (512, 256, 500, 128, None, False, (28, 4)),
+    (512, 128, 300, 128, 40, True, (32, 2)),
+    (512, 256, 500, 128, None, False, (8, 8)),
+    (512, 128, 500, 64, None, True, (8, 2)),
+    (128, None, 300, 128, None, False, (28, 4)),
+    (128, None, 300, 128, 40, True, (32, 2)),
 ])
 def test_prefill_row_blocks_match_xla(monkeypatch, S, QB, prefix, hd, window,
-                                      sinks):
-    """A chunk longer than the kernel's row block (S = 2 or 4 x 128): the
-    row-block `fori_loop`, the causal key-block loop under the diagonal
-    and the per-block finalize, with a prefix spanning several streamed
-    chunks, a window that masks whole key blocks, and sinks.  With QB the
-    chunk also crosses query blocks (two of 256, four of 128), one row of
-    the batch shorter than a query block, heads fetched as stored (hd
-    128) and through the slab view (hd 64)."""
-    B, H, n_kv, page = 2, 4, 2, 16
+                                      sinks, heads):
+    """A chunk longer than one key tile of itself (S = 256 or 512): the
+    causal key-tile loop under the diagonal and the finalize, with a prefix
+    spanning several streamed tiles, a window that masks whole key tiles,
+    and sinks.  With QB the chunk also crosses query blocks (two of 256,
+    four of 128), one row of the batch shorter than a query block (its
+    later blocks are skipped), heads fetched as stored (hd 128) and through
+    the slab view (hd 64)."""
+    (H, n_kv), B, page = heads, 2, 16
     maxp = (prefix + S) // page + 2
     prefix_lens = jnp.array([prefix, prefix // 3], jnp.int32)
     chunk_lens = jnp.array([S, S - 37] if QB is None else [S, 100],
@@ -330,18 +353,13 @@ def test_prefill_row_blocks_match_xla(monkeypatch, S, QB, prefix, hd, window,
         q, k_new, v_new, k_pages, v_pages, table, prefix_lens, chunk_lens,
         interpret=True, **kw,
     )
-    for b in range(B):
-        n = int(chunk_lens[b])
-        np.testing.assert_allclose(
-            np.asarray(out[b, :n], np.float32),
-            np.asarray(ref[b, :n], np.float32),
-            atol=2e-5, rtol=2e-5,
-        )
+    _assert_rows_close(out, ref, chunk_lens, 2e-5)
 
 
 def _call_residents(S, H, n_kv, hd, page, dtype):
-    """VMEM bytes of the `pallas_call` the wrapper really builds: every
-    blocked operand twice, every VMEM scratch once."""
+    """What the `pallas_call` the wrapper really builds holds in VMEM:
+    (grid, bytes of every blocked operand twice and every VMEM scratch
+    once, the shape of the widest score tile its body computes)."""
     B, maxp = 1, 8
     q = jnp.zeros((B, S, H, hd), dtype)
     new = jnp.zeros((B, S, n_kv, hd), dtype)
@@ -356,41 +374,102 @@ def _call_residents(S, H, n_kv, hd, page, dtype):
                if "<any>" not in str(bm.block_aval)]
     scratch = [v.aval for v in call.params["jaxpr"].invars[
         -gm.num_scratch_operands:] if "<vmem>" in str(v.aval)]
-    assert len(blocked) == 5 and len(scratch) in (5, 7)
+    assert len(blocked) == 5 and len(scratch) == 6
+    scores = max((e.outvars[0].aval.shape for e in _eqns(call.params["jaxpr"])
+                  if e.primitive.name == "dot_general"
+                  and e.outvars[0].aval.dtype == jnp.float32),
+                 key=lambda shape: shape[0] * shape[1])
     return gm.grid, (
         2 * sum(_vmem_bytes(a.shape, a.dtype) for a in blocked)
-        + sum(_vmem_bytes(a.shape, a.dtype) for a in scratch))
+        + sum(_vmem_bytes(a.shape, a.dtype) for a in scratch)), scores
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, those of its nested jaxprs (loops,
+    conditionals) among them."""
+    for e in jaxpr.eqns:
+        yield e
+        for v in e.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _eqns(inner)
 
 
 @pytest.mark.parametrize("H,n_kv,hd,dtype", [
     (28, 4, 128, jnp.bfloat16),  # the benchmark cells' attention
+    (32, 2, 128, jnp.bfloat16),  # Nemotron-3-Nano's: 16 query heads a KV head
     (32, 8, 128, jnp.bfloat16),  # Llama-3.1-8B
     (32, 8, 64, jnp.bfloat16),  # Llama-3.2-1B: not fetched as stored
     (4, 2, 64, jnp.float32),
-], ids=["qwen7b", "llama8b", "llama1b", "tiny-f32"])
+], ids=["qwen7b", "nemotron3", "llama8b", "llama1b", "tiny-f32"])
 def test_prefill_query_block_sizes_what_the_call_holds(H, n_kv, hd, dtype):
     """ONE function sizes the kernel: for every chunk bucket it returns a
     divisor of the chunk (a multiple of the row block where the chunk is
     one), its byte count IS the blocked operands (twice: the pipeline's
     second buffer) and VMEM scratch of the call the wrapper builds from
-    it, that count is within the budget, and the next larger block is
-    over it."""
+    it plus three float32 tiles of the widest score product that call's
+    body traces (scores, exponentials, cast probabilities), that count is
+    within the budget, and the next larger block is over it or folds to
+    more rows than a product may span."""
     page = 16
+    groups = H // n_kv
     geom = lambda S: (S, H, n_kv, hd, page, dtype)  # noqa: E731
     for S in (16, 32, 64, 128, 256, 512):
         QB = prefill_query_block(*geom(S))
         assert QB is not None and S % QB == 0
         assert QB == S or QB % pallas_attention._PREFILL_ROW_BLOCK == 0
-        grid, held = _call_residents(*geom(S))
+        grid, held, scores = _call_residents(*geom(S))
         assert grid[1] == S // QB
+        # the state is lane-dense: one folded row a lane, a head on whole
+        # lane tiles
+        assert scores[1] == -(-groups * QB // 128) * 128
+        held += 3 * _vmem_bytes(scores, jnp.float32)
         assert held == prefill_resident_bytes(QB, *geom(S))
         assert held <= pallas_attention._PREFILL_VMEM_BUDGET
         larger = [q for q in range(QB + 128, S + 1, 128) if S % q == 0]
         assert all(prefill_resident_bytes(q, *geom(S))
-                   > pallas_attention._PREFILL_VMEM_BUDGET for q in larger)
-    # the cells' 512-token chunk runs as two blocks of the 256 bucket's size
+                   > pallas_attention._PREFILL_VMEM_BUDGET
+                   or groups * q > pallas_attention._PREFILL_QUERY_ROWS
+                   for q in larger)
+    # the cells' 512-token chunk runs as one block; 16 query heads a KV
+    # head fold 256 tokens into as many rows
     if (H, n_kv, hd) == (28, 4, 128):
+        assert prefill_query_block(*geom(512)) == 512
+    if (H, n_kv, hd) == (32, 2, 128):
         assert prefill_query_block(*geom(512)) == 256
+
+
+def _kernel_eqns(H, n_kv, hd=128, S=256):
+    """(equations, products) traced for the prefill kernel's body."""
+    q = jnp.zeros((1, S, H, hd), jnp.bfloat16)
+    new = jnp.zeros((1, S, n_kv, hd), jnp.bfloat16)
+    pool = jnp.zeros((4, 16, n_kv, hd), jnp.bfloat16)
+    table = jnp.zeros((1, 32), jnp.int32)
+    lens = jnp.zeros((1,), jnp.int32)
+    jaxpr = jax.make_jaxpr(lambda: prefill_attention_pallas(
+        q, new, new, pool, pool, table, lens, lens, interpret=True))()
+    (call,) = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call"]
+    eqns = list(_eqns(call.params["jaxpr"]))
+    return len(eqns), sum(e.primitive.name == "dot_general" for e in eqns)
+
+
+def test_prefill_kernel_body_does_not_grow_with_the_query_heads():
+    """The query heads of a KV head are rows of ONE product: the traced
+    body holds two products (scores, values) a KV head for the prefix and
+    two for the chunk itself whatever the number of query heads, and what
+    a further query head adds is its fold into the query tile and its way
+    back out (a few copies a query block, no product, no softmax), so
+    nobody can quietly unroll the heads again."""
+    n28, dots28 = _kernel_eqns(28, 4)
+    n56, dots56 = _kernel_eqns(56, 4)
+    assert dots28 == dots56 == 4 * 4
+    assert _kernel_eqns(28, 2)[1] == 4 * 2
+    per_head = (n56 - n28) / 28
+    assert per_head <= 16, per_head
+    # the softmax bodies: what is left once the per-head copies are taken
+    # off is the same at both head counts
+    assert n56 - 56 * per_head == pytest.approx(n28 - 28 * per_head)
 
 
 def test_adaptive_falls_back_only_where_no_query_block_fits(monkeypatch):

@@ -129,8 +129,9 @@ def test_prefill_kernel_compiles_at_the_cells_chunk(one_chip, table_pages):
     """The benchmark cells' attention (28 heads over 4 of 128, bf16) at the
     512-token chunk, reading the whole 14-layer pool by (layer, page), under
     every table a 512-token step meets there: the chunk goes through the
-    kernel in two query blocks, so q, o and the accumulator are the 256
-    bucket's and Mosaic's scoped VMEM takes it."""
+    kernel as ONE query block, a KV head's seven query heads folded into
+    3,584 rows of one product, under the scoped VMEM limit the call asks
+    for."""
     from dynamo_tpu.ops.pallas_attention import prefill_query_block
 
     cfg = QWEN2_5_7B
@@ -138,7 +139,7 @@ def test_prefill_kernel_compiles_at_the_cells_chunk(one_chip, table_pages):
         cfg, one_chip, 1, table_pages, chunk=512)
     pool = _sds((CELL_LAYERS, *pool.shape), pool.dtype, one_chip)
     assert prefill_query_block(512, q.shape[2], new.shape[2], q.shape[3],
-                               PAGE, q.dtype) == 256
+                               PAGE, q.dtype) == 512
 
     def fn(q, kn, vn, k, v, t, pre, cl, layer):
         return prefill_attention_pallas(q, kn, vn, k, v, t, pre, cl,
@@ -272,9 +273,10 @@ def pool_sized_movers(hlo_text, kv_shape):
 @pytest.mark.parametrize("chunk,table_pages,impl,choice", [
     (512, 128, "xla", "xla"), (512, 256, "xla", "xla"),
     (64, 128, "pallas", "pallas"), (512, 128, "adaptive", "pallas"),
-    (512, 256, "adaptive", "pallas"), (64, 128, "adaptive", "xla"),
+    (512, 256, "adaptive", "pallas"), (64, 128, "adaptive", "pallas"),
+    (64, 64, "adaptive", "xla"),
 ], ids=["xla-512x128", "xla-512x256", "pallas-64x128", "pallas-512x128",
-        "pallas-512x256", "xla-64x128-by-rule"])
+        "pallas-512x256", "pallas-64x128-by-rule", "xla-64x64-by-rule"])
 def test_prefill_step_leaves_the_pool_where_it_is(one_chip, chunk,
                                                   table_pages, impl, choice):
     """The benchmark cell's `prefill_step` programs (Qwen2.5-7B widths, 14
@@ -284,8 +286,9 @@ def test_prefill_step_leaves_the_pool_where_it_is(one_chip, chunk,
     slices, updates or re-lays-out the pool or a layer's slab of it.
     "adaptive" is what the cells run: by `_adapt`'s measured rule it takes
     the kernel at the 512-token chunk (then no score-shaped
-    f32[1,28,chunk,*] temporary is left in the program) and XLA attention
-    at a 64-token one.  Counts and bytes, never a time."""
+    f32[1,28,chunk,*] temporary is left in the program) and at a 64-token
+    one under 128 pages (2^17 scores a head), XLA attention at a 64-token
+    one under 64.  Counts and bytes, never a time."""
     import dataclasses
     import re
 
