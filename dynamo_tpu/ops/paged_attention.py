@@ -410,10 +410,10 @@ def decode_attention(
     self_kv=None,  # ([B, n_kv, hd], same): the NEW token's k/v, NOT yet
     # in the pool — it joins the softmax as an explicit self column.
     # This is the deferred-write decode path: a per-layer pool scatter
-    # followed by a pool read forces XLA to copy the pool every
-    # layer-step (~1.8ms/step at 1B/batch-8 on v5e); attending to the
-    # OLD pool + self lets the caller land ONE batched scatter per step
-    # (scripts/ablate_attention.py measured 2.98 → 1.16 ms/step)
+    # followed by a pool read makes XLA copy the pool every layer-step;
+    # attending to the OLD pool + self lets the caller land ONE batched
+    # scatter per step.  Token-identical in tier-1; what it saves is not
+    # measured: no benchmark cell decodes
 ) -> jax.Array:
     """Single-token attention over the page table. Returns [B, n_heads, hd]."""
     impl = _adapt(impl, page_table, k_pages.shape[1])

@@ -271,8 +271,7 @@ def _build_engine(args):
     from ..models.config import LLAMA_3_2_1B
     from ..models.loader import load_params
 
-    # a profile is a table of device times: no chip, no table (the
-    # checked-in profiles/*.npz name a v5e)
+    # a profile is a table of device times: no chip, no table
     chip.require_tpu("planner.profiler sweep")
     compile_cache.configure()
 

@@ -5,7 +5,9 @@ on ephemeral ports)."""
 
 from __future__ import annotations
 
+import collections
 import contextlib
+import sys
 from typing import AsyncIterator
 
 from .runtime import (
@@ -13,6 +15,63 @@ from .runtime import (
     ControlPlaneServer,
     DistributedRuntime,
 )
+
+
+class counted_calls:
+    """What a hot path DOES, counted where a clock would time it: every
+    Python-level call this thread makes inside the block (a Python frame
+    entered, or a C function or method called: `sys.setprofile`'s `call`
+    and `c_call` events), as `total` and, by qualified name, `names`.
+
+        with counted_calls() as c:
+            for _ in range(n):
+                rec.record("decode_block", rung=8)
+        assert c.total // n <= 4    # the remainder is the loop's `range`
+
+    A count is the same alone and beside five busy workers, and one more
+    call on the path moves it by exactly `n`.  The collector is held off
+    inside the block: its callbacks are not the path's."""
+
+    def __init__(self):
+        self.names: collections.Counter = collections.Counter()
+
+    @property
+    def total(self) -> int:
+        return sum(self.names.values())
+
+    def __call__(self, frame, event, arg):
+        if event == "call":
+            self.names[frame.f_code.co_qualname] += 1
+        elif event == "c_call":
+            self.names[getattr(arg, "__qualname__", repr(arg))] += 1
+
+    def __enter__(self) -> "counted_calls":
+        import gc
+
+        self._gc_was = gc.isenabled()
+        gc.disable()
+        sys.setprofile(self)
+        return self
+
+    def __exit__(self, *exc) -> None:
+        import gc
+
+        sys.setprofile(None)
+        # its own way out, seen before the profile came off
+        self.names -= collections.Counter(
+            {"counted_calls.__exit__": 1, "setprofile": 1})
+        if self._gc_was:
+            gc.enable()
+
+
+def call_ceiling(production: int) -> int:
+    """The ceiling for a hot path's `counted_calls`, given its count on the
+    PRODUCTION build.  Under DYN_TPU_LOCKCHECK a lock is a TrackedLock
+    whose order and hold bookkeeping is 31 calls of its own an acquire:
+    ten times the count there, a sanity ceiling."""
+    from .analysis import contracts
+
+    return production if contracts.checks_mode() == "off" else 10 * production
 
 
 @contextlib.asynccontextmanager

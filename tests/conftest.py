@@ -34,6 +34,48 @@ def _assert_cpu_mesh():
     assert jax.default_backend() == "cpu" and len(jax.devices()) == 8
 
 
+# Files whose tests build engine after engine of one tiny model and lost a
+# sixth of their seconds or more to this (PERF.md, "Tier-1"; the files
+# whose engines differ test by test gained nothing and are not named).  Never
+# one that counts BACKEND compiles (test_xla_ledger, test_program_store,
+# test_startup_events).
+_SHARES_EXECUTABLES = {
+    "test_block_ladder", "test_deepseek_v3", "test_engine", "test_kvbm",
+    "test_model", "test_multimodal", "test_nemotron_h", "test_phi4flash",
+    "test_prefill_batching", "test_prefill_overlap", "test_quantization",
+    "test_smallthinker", "test_wide_ep", "test_xing4_0",
+}
+
+
+@pytest.fixture(scope="module", autouse=True)
+def shared_executables(request, tmp_path_factory):
+    """Engines built alike compile alike, ONCE: XLA's persistent cache, on
+    for the modules named above, in a directory of this worker's own that
+    dies with the session.  An `async` test has a loop of its own and an
+    engine belongs to one loop, so such a file builds an engine a test, and
+    each engine's `Layout` jits its own closures: XLA compiled the same tiny
+    program anew every test, a third of such a test's time.  What is
+    traced, lowered and counted by the compile ledger is as before."""
+    if request.module.__name__ not in _SHARES_EXECUTABLES:
+        yield
+        return
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    # one directory a worker: `numbered=False` finds it again
+    directory = tmp_path_factory.getbasetemp() / "xla_executables"
+    on = {"jax_compilation_cache_dir": str(directory),
+          "jax_persistent_cache_min_compile_time_secs": 0.0,
+          "jax_persistent_cache_min_entry_size_bytes": -1}
+    was = {name: getattr(jax.config, name) for name in on}
+    for name, value in on.items():
+        jax.config.update(name, value)
+    cc.reset_cache()
+    yield
+    for name, value in was.items():
+        jax.config.update(name, value)
+    cc.reset_cache()
+
+
 # -- wedge forensics ----------------------------------------------------------- #
 #
 # A wedged test (thread stuck in a C call, ABBA deadlock, drain thread

@@ -235,8 +235,8 @@ class FakeScaler:
 
 
 async def _drive_wave(base, n_req, max_tokens, seed_base, gap_s):
-    """Seeded streaming wave; returns per-request (ttft_s, itl_s,
-    tokens) measured CLIENT-side — the offline half of the cross-check."""
+    """Seeded streaming wave; returns per-request tokens counted
+    CLIENT-side — the offline half of the cross-check."""
     results = []
 
     async def one(i, session):
@@ -250,8 +250,6 @@ async def _drive_wave(base, n_req, max_tokens, seed_base, gap_s):
             "stream": True,
             "nvext": {"ignore_eos": True},
         }
-        t_submit = time.monotonic()
-        t_first = t_last = None
         ntok = 0
         async with session.post(f"{base}/v1/chat/completions",
                                 json=body) as resp:
@@ -263,12 +261,8 @@ async def _drive_wave(base, n_req, max_tokens, seed_base, gap_s):
                 chunk = json.loads(line[len("data: "):])
                 assert "error" not in chunk, chunk
                 if chunk.get("choices"):
-                    t_last = time.monotonic()
-                    if t_first is None:
-                        t_first = t_last
                     ntok += 1
-        itl = (t_last - t_first) / max(ntok - 1, 1)
-        results.append((t_first - t_submit, itl, ntok))
+        results.append(ntok)
 
     async with aiohttp.ClientSession() as session:
         await asyncio.gather(*(one(i, session) for i in range(n_req)))
@@ -331,7 +325,6 @@ async def test_planner_plans_from_live_telemetry_end_to_end():
         # two seeded waves at different offered rates so the observed
         # profile accumulates distinct load points and the knee
         # estimator sees more than one rate bin
-        t0 = time.monotonic()
         wave1 = await _drive_wave(base, n_req=6, max_tokens=24,
                                   seed_base=400, gap_s=0.25)
         wave2 = await _drive_wave(base, n_req=8, max_tokens=24,
@@ -339,26 +332,22 @@ async def test_planner_plans_from_live_telemetry_end_to_end():
         offline = wave1 + wave2
         await asyncio.sleep(0.5)  # let publishers + sampler tick
 
-        # -- cross-check: live window vs bench-style offline math ------- #
+        # -- cross-check: live window vs offline COUNTS ------------------ #
+        # the card's targets are deadlines no request of this sim comes
+        # near, so every request the client counted is one the window
+        # met, and the window's rate times the window's own span is the
+        # tokens the client counted: no clock of the test's is compared
         slo = metrics.slo.targets_for("mock-model")
         assert slo.ttft_ms == 30_000.0, "card SLO never reached the frontend"
-        ok = [r for r in offline
-              if r[0] * 1e3 <= slo.ttft_ms and r[1] * 1e3 <= slo.itl_ms]
-        offline_met = len(ok) / len(offline)
         async with aiohttp.ClientSession() as session:
             async with session.get(f"{base}/fleet.json") as r:
                 assert r.status == 200
                 doc = await r.json()
-        # same interval on both sides: the live window covers first
-        # record → scrape, so the offline denominator must too
-        dt = time.monotonic() - t0
-        offline_goodput = sum(r[2] for r in ok) / dt
         live = doc["models"]["mock-model"]
         assert live["requests_completed"] == len(offline)
-        assert abs(live["slo_met"] - offline_met) <= 0.05
-        assert (abs(live["goodput_tok_s"] - offline_goodput)
-                / offline_goodput <= 0.05), (
-            live["goodput_tok_s"], offline_goodput)
+        assert live["slo_met"] == 1.0
+        assert round(live["goodput_tok_s"] * live["window_s"]) == sum(
+            offline), (live["goodput_tok_s"], live["window_s"], offline)
         assert live["slo"] == {"ttft_ms": 30_000.0, "itl_ms": 5_000.0}
 
         # -- /fleet.json joins worker capacity + knees ------------------- #

@@ -6,8 +6,8 @@ duration so they fit tier-1:
 - 10k concurrent mock SSE streams against ONE real frontend process
   with zero tokens lost (the 5 ms delta-p99 knee is a time, and
   `scripts/frontend_saturation.py` reports it on a quiet machine),
-- the batched/coalescing writer cuts frontend CPU per streamed token
-  >= 3x vs the legacy per-delta writer on a burst shape where
+- the batched/coalescing writer cuts the frontend's writes per streamed
+  token >= 3x vs the legacy per-delta writer on a burst shape where
   backpressure engages (the A/B arms of `scripts/frontend_saturation.py`;
   not measured on the chip: no benchmark cell streams more than one token).
 
@@ -35,13 +35,19 @@ async def test_10k_streams_under_knee():
 
 
 async def test_burst_ab_cpu_per_token_ratio():
+    """The batching economics as work: `resp.write` calls a streamed token
+    (each is a syscall and a pass through the transport), fast arm
+    against the per-delta arm, on the same 80,000 tokens.  The CPU
+    microseconds a token, which `run_rung` reports too, are a clock's and
+    are read on a quiet machine by `scripts/frontend_saturation.py`."""
     kw = dict(streams=800, n=16, interval_s=1.0 / 500.0, tokens=100)
     fast = await run_rung(coalesce=True, **kw)
     legacy = await run_rung(coalesce=False, legacy=True, **kw)
     assert fast["tokens_lost"] == 0 and legacy["tokens_lost"] == 0
-    ratio = legacy["cpu_us_per_token"] / max(fast["cpu_us_per_token"], 1e-9)
-    assert ratio >= 3.0, (legacy["cpu_us_per_token"],
-                          fast["cpu_us_per_token"])
-    # legacy arm writes one frame per resp.write; fast arm batches
-    assert legacy["egress_writes"] == legacy["egress_frames"]
+    tokens = kw["streams"] * kw["tokens"]
+    # legacy arm writes one frame per resp.write, one frame a token
+    assert legacy["egress_writes"] == legacy["egress_frames"] == tokens
+    # fast arm batches: a third of the writes at most (a fortieth, alone)
+    assert 3 * fast["egress_writes"] <= legacy["egress_writes"], (
+        fast["egress_writes"], legacy["egress_writes"])
     assert fast["egress_coalesced"] > 0

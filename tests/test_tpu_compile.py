@@ -13,7 +13,6 @@ file could land on another worker, whose fixture would then skip.
 """
 
 import os
-import time
 
 import jax
 import jax.numpy as jnp
@@ -67,9 +66,13 @@ def _sds(shape, dtype, sharding):
 
 
 def _compile(fn, *args):
-    t0 = time.monotonic()
+    """The executable and the bytes of its text, the kernel's serialized
+    body included.  What once made a kernel take 36-49 s to compile was
+    head loops unrolled into that body: a SIZE, which reads the same on a
+    busy machine, where the seconds (16 of a 60 s budget under six test
+    workers) do not.  Each ceiling is today's size and a tenth."""
     compiled = jax.jit(fn).lower(*args).compile()
-    return compiled, time.monotonic() - t0
+    return compiled, len(compiled.as_text())
 
 
 def _kernel_args(cfg, sh, batch, table_pages, chunk=None):
@@ -99,15 +102,15 @@ def test_decode_kernel_compiles(one_chip, cfg, extras):
                                            sink=sink)
     else:
         fn = decode_attention_pallas
-    compiled, secs = _compile(fn, *args)
+    compiled, size = _compile(fn, *args)
     assert "tpu_custom_call" in compiled.as_text()
-    assert secs < 60, f"decode kernel took {secs:.1f}s to compile"
+    assert size < 42_000    # 38,096 / 36,997 / 37,681 today
 
 
 @pytest.mark.parametrize("extras", [False, True], ids=["1b", "1b-window-sink"])
 def test_prefill_kernel_compiles_at_the_smoke_chunk(one_chip, extras):
-    """The worker's default --max-prefill-tokens chunk: under a minute (it
-    was 36-49 s before the head loops went onto row blocks; 4 s after)."""
+    """The worker's default --max-prefill-tokens chunk, its head loops on
+    row blocks (unrolled they took 36-49 s to compile, 4 s after)."""
     cfg = LLAMA_3_2_1B
     args = _kernel_args(cfg, one_chip, 1, SMOKE_TABLE_PAGES,
                         chunk=SMOKE_CHUNK)
@@ -119,9 +122,9 @@ def test_prefill_kernel_compiles_at_the_smoke_chunk(one_chip, extras):
                                             window=128, sink=sink)
     else:
         fn = prefill_attention_pallas
-    compiled, secs = _compile(fn, *args)
+    compiled, size = _compile(fn, *args)
     assert "tpu_custom_call" in compiled.as_text()
-    assert secs < 60, f"prefill kernel took {secs:.1f}s to compile"
+    assert size < 71_000    # 63,459 / 64,870 today
 
 
 @pytest.mark.parametrize("table_pages", [32, 64, 128, 256])
@@ -145,10 +148,10 @@ def test_prefill_kernel_compiles_at_the_cells_chunk(one_chip, table_pages):
         return prefill_attention_pallas(q, kn, vn, k, v, t, pre, cl,
                                         layer=layer)
 
-    compiled, secs = _compile(fn, q, new, new, pool, pool, table, lens, lens,
+    compiled, size = _compile(fn, q, new, new, pool, pool, table, lens, lens,
                               _sds((), jnp.int32, one_chip))
     assert "tpu_custom_call" in compiled.as_text()
-    assert secs < 60, f"prefill kernel took {secs:.1f}s to compile"
+    assert size < 58_000    # 53,107 to 53,130 today, by table
 
 
 # -- whole steps ---------------------------------------------------------------- #
@@ -434,7 +437,7 @@ def test_latent_prefill_kernel_compiles_at_the_cells_shapes(
         return prefill_latent_attention_pallas(
             qa, qp, kn, ln, k, v, t, pre, cl, 0.1447, layer=layer)
 
-    compiled, secs = _compile(
+    compiled, size = _compile(
         fn, _sds((batch, chunk, heads, 512), bf, one_chip),
         _sds((batch, chunk, heads, 64), bf, one_chip),
         _sds((batch, chunk, 64), bf, one_chip),
@@ -445,7 +448,7 @@ def test_latent_prefill_kernel_compiles_at_the_cells_shapes(
         _sds((), jnp.int32, one_chip))
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes == 0
-    assert secs < 60, f"latent prefill kernel took {secs:.1f}s to compile"
+    assert size < 35_000    # 29,401 to 31,603 today, by shape
 
 
 @pytest.mark.parametrize("impl", ["pallas", "xla"])
