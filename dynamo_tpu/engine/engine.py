@@ -39,7 +39,7 @@ from .config import EngineConfig, bucket_for
 from .layout import Layout
 from .page_pool import KvEvent, NoPagesError
 from .scheduler import PrefillItem, SamplingOptions, Scheduler, Sequence, StepPlan
-from ..models.llama import (moe_stats_columns, moe_stats_width,
+from ..models.llama import (moe_form, moe_stats_columns, moe_stats_width,
                             require_no_state, require_plain_cache)
 from .steps import (
     _unpack_out,
@@ -256,6 +256,10 @@ class JaxEngine:
         self.moe_local_assignments_total = 0  # that chose a HELD expert
         self.moe_experts_hit_total = 0
         self.moe_steps_total = 0
+        # the same steps and their valid tokens by the form their expert
+        # layers ran (`models.llama.moe_form`: the step's traced shape)
+        self.moe_form_steps = {"all_experts": 0, "dispatched": 0}
+        self.moe_form_tokens = {"all_experts": 0, "dispatched": 0}
         # hyper-connections: the largest distance of a step's mixing
         # matrices from doubly stochastic so far, parts per million
         self.hc_res_err_ppm_max = 0
@@ -748,6 +752,11 @@ class JaxEngine:
                     self.moe_local_assignments_total)
             m.moe_experts_hit_total = self.moe_experts_hit_total
             m.moe_steps_total = self.moe_steps_total
+            for form in self.moe_form_steps:
+                setattr(m, f"moe_{form}_steps_total",
+                        self.moe_form_steps[form])
+                setattr(m, f"moe_{form}_tokens_total",
+                        self.moe_form_tokens[form])
             if self.model_cfg.hc_mult:
                 m.hc_res_err_ppm_max = self.hc_res_err_ppm_max
         if self.model_cfg.cross_decoder:  # beside `prefill_rows_total`
@@ -1458,10 +1467,19 @@ class JaxEngine:
             self._step_t0_ns = None
             self._step_end_ns = time.monotonic_ns()
 
-    def _note_moe(self, packed: np.ndarray) -> dict:
+    def _decode_moe_form(self, rows: int) -> dict:
+        """{"moe_form": ...} of a decode step over `rows` rows, one token
+        each (an expert model's decode events; {} otherwise)."""
+        if not self.model_cfg.is_moe:
+            return {}
+        return {"moe_form": moe_form(self.model_cfg, rows)}
+
+    def _note_moe(self, packed: np.ndarray, traced: int, tokens: int) -> dict:
         """The moe stats an expert model's prefill-path step appended to
         its pack (`steps.carries_moe_stats`): added to the engine's counters,
-        returned as the step slice's attributes.  {} for any other step."""
+        returned as the step slice's attributes.  {} for any other step.
+        `traced` is the step program's rows x bucket, which chose the form
+        of its expert layers (`moe_form`); `tokens` its valid tokens."""
         if not self.layout.carries_moe_stats:
             return {}
         stats = [int(v) for v in packed[-moe_stats_width(self.model_cfg):]]
@@ -1469,7 +1487,12 @@ class JaxEngine:
         self.moe_assignments_total += assigned
         self.moe_experts_hit_total += hit
         self.moe_steps_total += 1
-        attrs = {"experts_hit": hit, "moe_max_load": load}
+        form = moe_form(self.model_cfg, traced)
+        if form in self.moe_form_steps:  # not "capacity": it carries none
+            self.moe_form_steps[form] += 1
+            self.moe_form_tokens[form] += tokens
+        attrs = {"moe_assignments": assigned, "experts_hit": hit,
+                 "moe_max_load": load, "moe_form": form}
         if local:  # a share of each layer's experts is held here
             self.moe_local_assignments_total += local[0]
             attrs["moe_local"] = local[0]
@@ -1742,7 +1765,8 @@ class JaxEngine:
             # lint: allow(device-get): prefill results are consumed on-step by design — decode, not prefill, is the latency path
             packed = np.asarray(jax.device_get(step.packed_d))
             t_got = self.events.now()
-            moe_attrs = self._note_moe(packed)
+            moe_attrs = self._note_moe(
+                packed, B * step.attrs["bucket"], step.attrs["tokens"])
             out, logp, tids, tlps = self._unpack_rows(
                 packed, B, step.with_top, blocks=self.layout.prefill_blocks,
             )
@@ -2033,7 +2057,9 @@ class JaxEngine:
         # lint: allow(device-get): mixed-step prefill half, consumed on-step like _run_prefill
         p_packed = np.asarray(jax.device_get(p_packed_d))
         t_got = self.events.now()
-        moe_attrs = self._note_moe(p_packed)
+        moe_attrs = self._note_moe(
+            p_packed, Bp * chunk_bucket,
+            int(sum(it.chunk_len for it in items)))
         p_out, p_logp, p_tids, p_tlps = self._unpack_rows(
             p_packed, Bp, with_top, blocks=self.layout.prefill_blocks,
         )
@@ -2465,7 +2491,7 @@ class JaxEngine:
         packed = np.asarray(jax.device_get(packed_d))
         t_got = self.events.now()
         out, logp, n_acc = _unpack_spec(packed, B, k + 1)
-        moe_attrs = self._note_moe(packed)
+        moe_attrs = self._note_moe(packed, B * (k + 1), len(seqs) * (k + 1))
         self._spec_dispatch_total += 1
         drafted = accepted = 0
         live: List[tuple] = []
@@ -2607,6 +2633,7 @@ class JaxEngine:
                 pages=table.shape[1] - self.layout.state_cols, bucket=Bb,
                 attn=self._attn_of("decode_attention", Bb, 1,
                                    table.shape[1]),
+                **self._decode_moe_form(Bb),
                 **({"rid": seqs[0].request_id} if len(seqs) == 1 else {}),
                 **self._step_phases(seqs, t0_ev, t_call,
                                     *(clock or (None, None))),
@@ -3061,7 +3088,8 @@ class JaxEngine:
                     dispatch_us=(t_sent - t_iter) // 1000,
                     fetch_us=wait_ns // 1000,
                     deliver_us=(t_end - t_sent - wait_ns) // 1000,
-                    pages=table_d.shape[1], bucket=Bb, **attrs)
+                    pages=table_d.shape[1], bucket=Bb,
+                    **self._decode_moe_form(Bb), **attrs)
                 if fallout is not None:
                     break
                 if allowance < 1:

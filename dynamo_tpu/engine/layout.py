@@ -30,7 +30,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..analysis import xla_ledger
 from ..models import KVCache, ModelConfig, forward_prefill, kv_cache_pspec
-from ..models.llama import require_no_state, require_plain_cache
+from ..models.llama import (require_no_state, require_one_layer_shape,
+                            require_plain_cache)
 from ..ops import SamplingParams
 from ..ops.paged_attention import resolve_attention_impl
 from . import steps
@@ -170,12 +171,16 @@ class Layout:
                           cfg.quantization == "int8")):
             if on:
                 require_plain_cache(model_cfg, what)
+                require_one_layer_shape(model_cfg, what)
+        continuous = ("--decode-continuous (the device-resident decode "
+                      "loop)")
         for what, on in (("--speculative-ngram-k (the draft-verify step)",
                           cfg.speculative_ngram_k),
-                         ("--decode-continuous (the device-resident decode "
-                          "loop)", cfg.decode_continuous)):
+                         (continuous, cfg.decode_continuous)):
             if on:
                 require_no_state(model_cfg, what)
+        if cfg.decode_continuous:  # its decode block scans ONE layer stack
+            require_one_layer_shape(model_cfg, continuous)
         if model_cfg.state_spec is not None:
             from ..models.hybrid import snapshot_tokens
 

@@ -34,15 +34,24 @@ _BLOCK_KV_BYTE_BUDGET = 2 << 30
 
 
 def _decode_path(attn_impl: str, kv, page_table, n_steps: int,
-                 latent: bool = False) -> str:
+                 latent: bool = False, own_loop: bool = False) -> str:
     """"block" (`decode_block_scan`: one pool gather per block) or
     "per-step" (a scan of `forward_decode`) for one traced decode step,
     noted in the compile ledger with the reason — the Pallas decode kernel
     reads pages itself and needs the per-step write-first layout, a
     block whose gathered KV would pass the byte budget stays per-step, and
     so does a model with state slots beside its pages (the block's layer
-    body carries no recurrent state)."""
+    body carries no recurrent state) and one with a layer loop of its own
+    (`own_loop`: layers of two shapes, which the block's one scan cannot
+    walk)."""
     from ..ops.paged_attention import LATENT_DECODE_XLA, _adapt
+
+    if own_loop:
+        xla_ledger.note_path_choice(
+            "decode_step", "per-step", "head counts by layer: the one layer "
+            "loop walks both shapes", batch=page_table.shape[0],
+            n_steps=n_steps)
+        return "per-step"
 
     if hasattr(kv, "ssm"):
         xla_ledger.note_path_choice(
@@ -308,8 +317,8 @@ def decode_body(cfg: ModelConfig, n_steps: int, max_valid_pos: int,
         rope_off = rope[0] if rope else None
         if not penalized:
             counts = None
-        if _decode_path(attn_impl, kv, page_table, n_steps,
-                        cfg.is_latent) == "block":
+        if _decode_path(attn_impl, kv, page_table, n_steps, cfg.is_latent,
+                        cfg.layer_kinds is not None) == "block":
             return block_scan(params, kv, tokens, positions, counters,
                               counts, page_table, samp, seeds, rope_off)
 
@@ -486,8 +495,8 @@ def decode_body_cc(cfg: ModelConfig, n_steps: int, max_valid_pos: int,
         act = act | (chunk_rem > 0)
         tokens = jnp.where(chunk_rem > 0, chunk_toks[:, 0], tokens)
 
-        if _decode_path(attn_impl, kv, page_table, n_steps,
-                        cfg.is_latent) == "block":
+        if _decode_path(attn_impl, kv, page_table, n_steps, cfg.is_latent,
+                        cfg.layer_kinds is not None) == "block":
             return block_scan(params, kv, tokens, positions, counters,
                               counts, act, budget, stops, page_table,
                               samp, seeds, chunk_toks, chunk_rem,

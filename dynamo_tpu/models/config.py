@@ -261,6 +261,18 @@ class ModelConfig:
     # attention applies no positions at all (nemotron_h: positions enter
     # through the state-space layers)
     attention_rope: bool = True
+    # layers of two SHAPES (laguna: `models/laguna.py`).  `layer_heads`:
+    # each layer's query heads (its `wq` is [hidden, heads * head_dim]: no
+    # layer is padded to the widest); `mlp_layer_types`: "dense" | "sparse"
+    # a layer; `rope_parameters`: ((layer type, ((key, value), ...)), ...)
+    # of rope_theta, rope_type, partial_rotary_factor and yarn's keys, a
+    # rope of its own for each kind in `layer_types`; `attention_gate`: sigmoid(normed input @ [hidden,
+    # heads]) times each head's attention output, before `wo`.  None /
+    # False: every layer has `num_attention_heads`, `rope_theta`, no gate
+    layer_heads: Optional[tuple] = None
+    mlp_layer_types: Optional[tuple] = None
+    rope_parameters: Optional[tuple] = None
+    attention_gate: bool = False
     # identity
     model_type: str = "llama"
     name: str = "llama"
@@ -302,6 +314,11 @@ class ModelConfig:
         elif self.moe_act == "relu2":
             raise ValueError("ungated relu2 experts are implemented under a "
                              "layer_pattern only (nemotron_h)")
+        for key in ("layer_heads", "mlp_layer_types"):
+            per_layer = getattr(self, key)
+            if per_layer is not None and len(per_layer) != L:
+                raise ValueError(f"{key} has {len(per_layer)} entries for "
+                                 f"{L} layers")
 
     @property
     def head_dim_(self) -> int:
@@ -333,8 +350,19 @@ class ModelConfig:
     def num_moe_layers(self) -> int:
         if self.layer_pattern is not None:
             return self.layer_pattern.count("E")
+        if self.mlp_layer_types is not None:
+            return self.mlp_layer_types.count("sparse")
         return (self.num_hidden_layers - self.first_k_dense
                 if self.is_moe else 0)
+
+    @property
+    def layer_kinds(self) -> Optional[tuple]:
+        """Each layer's (layer type, feed-forward type, query heads) where
+        layers differ in SHAPE (`layer_heads`); None: one shape."""
+        if self.layer_heads is None:
+            return None
+        return tuple(zip(self.layer_types, self.mlp_layer_types,
+                         self.layer_heads))
 
     @property
     def cross_decoder(self) -> bool:
@@ -453,6 +481,8 @@ class ModelConfig:
         hd, nh = self.head_dim_, self.num_attention_heads
         if self.layer_pattern is not None:
             return self._num_params_pattern()
+        if self.layer_kinds is not None:
+            return self._num_params_kinds()
         if self.is_latent:
             qr, r = self.q_lora_rank, self.kv_lora_rank
             nope, pe, vd = (self.qk_nope_head_dim, self.qk_rope_head_dim,
@@ -482,6 +512,20 @@ class ModelConfig:
                   + n * h * n + 1 + n) if n else 0
         return (l * (attn + 2 * h) + k * dense + (l - k) * mlp + emb + h
                 + mixers)
+
+    def _num_params_kinds(self) -> int:
+        """`num_params` of layers of several shapes (`layer_kinds`)."""
+        h, hd = self.hidden_size, self.head_dim_
+        kv = self.num_key_value_heads * hd
+        sparse = (h * self.router_width
+                  + self.num_experts * 3 * h * self.moe_intermediate_size
+                  + 3 * h * self.shared_expert_width)
+        ffn = {"dense": 3 * h * self.intermediate_size, "sparse": sparse}
+        gate = h if self.attention_gate else 0
+        layers = sum(2 * h * nh * hd + 2 * h * kv + gate * nh + ffn[mlp]
+                     + 2 * h for _, mlp, nh in self.layer_kinds)
+        emb = self.vocab_size * h * (1 if self.tie_word_embeddings else 2)
+        return layers + emb + h
 
     def _num_params_pattern(self) -> int:
         """`num_params` under a `layer_pattern`: every layer is its one
@@ -530,6 +574,14 @@ class ModelConfig:
             return ModelConfig(**_nemotron_h_fields(d, name))
         if d.get("model_type") == "phi4flash":
             return ModelConfig(**_phi4flash_fields(d, name))
+        if d.get("model_type") == "laguna":
+            return ModelConfig(**_laguna_fields(d, name))
+        if d.get("num_attention_heads_per_layer"):
+            raise ValueError(
+                f"model_type {d.get('model_type')!r} asks for head counts "
+                "by layer (num_attention_heads_per_layer) and only "
+                "laguna's are implemented: the llama branch would build "
+                "another model")
         if d.get("mb_per_layer"):
             raise ValueError(
                 f"model_type {d.get('model_type')!r} asks for state-space "
@@ -834,6 +886,120 @@ def _phi4flash_fields(d: dict, name: str) -> dict:
         ssm_dt_rank=(-(-h // 16) if dt_rank == "auto" else dt_rank),
         model_type="phi4flash",
         name=name or d.get("_name_or_path", "phi4flash"),
+    )
+
+
+LAGUNA_LAYER_TYPES = ("full_attention", "sliding_attention")
+
+
+def _laguna_fields(d: dict, name: str) -> dict:
+    """poolside Laguna (`model_type` "laguna": Laguna-XS.2): GQA layers of
+    two SHAPES in one model.  `layer_types` names each layer's attention,
+    full or under `sliding_window`; `num_attention_heads_per_layer` its
+    query heads (the KV heads and the head size are one); `rope_parameters`
+    a rope for each layer type (theta, yarn or none, the share of a head it
+    rotates); `mlp_layer_types` a dense SwiGLU of `intermediate_size` or
+    `num_experts` SwiGLU experts of `moe_intermediate_size` under a softmax
+    router, top-k renormalised and times `moe_routed_scaling_factor`, beside
+    one shared expert; `gating` a sigmoid gate a head on attention's output.
+    The published keys do not say the gate's form, the router's scores, how
+    the shared expert joins or whether q and k are normalised: the readings
+    taken are `models/laguna.py`'s docstring.  What is not implemented is
+    refused by the key that asks for it."""
+    def refuse(key, why):
+        raise ValueError(f"laguna: {key} {d.get(key)!r} {why}")
+
+    L = d["num_hidden_layers"]
+    for key, kinds in (("layer_types", LAGUNA_LAYER_TYPES),
+                       ("mlp_layer_types", ("dense", "sparse"))):
+        v = d.get(key)
+        if not isinstance(v, (list, tuple)) or len(v) != L or (
+                set(v) - set(kinds)):
+            refuse(key, f"must name {L} layers by {list(kinds)}")
+    heads = d.get("num_attention_heads_per_layer")
+    nkv = d["num_key_value_heads"]
+    if not isinstance(heads, (list, tuple)) or len(heads) != L or any(
+            not isinstance(n, int) or n <= 0 or n % nkv for n in heads):
+        refuse("num_attention_heads_per_layer", f"must give {L} layers a "
+               f"multiple of the {nkv} key/value heads each")
+    by_type = dict(zip(d["layer_types"], heads))
+    if any(by_type[t] != n for t, n in zip(d["layer_types"], heads)):
+        refuse("num_attention_heads_per_layer", "must give the layers of "
+               "one layer type one head count: a stack a type is built")
+    if d.get("gating") is not True and d.get("gating") != "per-head":
+        refuse("gating", "only the per-head sigmoid gate on attention's "
+               "output (true, or 'per-head') is implemented")
+    if set(d.get("gating_types") or ["per_head"]) != {"per_head"}:
+        refuse("gating_types", "only per_head in every layer is implemented")
+    if d.get("moe_apply_router_weight_on_input"):
+        refuse("moe_apply_router_weight_on_input", "the router's weight "
+               "multiplies an expert's OUTPUT here")
+    if d.get("moe_router_logit_softcapping"):
+        refuse("moe_router_logit_softcapping", "a cap on the router's "
+               "logits is not implemented")
+    if not d.get("norm_topk_prob", True):
+        refuse("norm_topk_prob", "only renormalised weights are implemented")
+    if d.get("decoder_sparse_step", 1) != 1:
+        refuse("decoder_sparse_step", "mlp_layer_types says which layers "
+               "are sparse; a step besides it is not implemented")
+    if "sparse" in d["mlp_layer_types"] and not d.get("num_experts"):
+        refuse("num_experts", "is needed: mlp_layer_types has sparse layers")
+    for key in ("attention_bias", "mlp_bias"):
+        if d.get(key):
+            refuse(key, "projection biases are not implemented")
+    if d.get("hidden_act", "silu") != "silu":
+        refuse("hidden_act", "only silu is implemented")
+    windowed = "sliding_attention" in d["layer_types"]
+    if windowed and (not isinstance(d.get("sliding_window"), int)
+                     or d["sliding_window"] <= 0):
+        refuse("sliding_window", "must be the windowed layers' one width "
+               "in tokens")
+    ropes = d.get("rope_parameters")
+    if not isinstance(ropes, dict) or any(
+            not isinstance(ropes.get(t), dict) for t in set(d["layer_types"])):
+        refuse("rope_parameters", "must hold a rope for each layer type in "
+               "layer_types")
+    hd = d.get("head_dim") or d["hidden_size"] // d["num_attention_heads"]
+    for t in set(d["layer_types"]):
+        kind = ropes[t].get("rope_type", "default")
+        if kind not in ("default", "yarn"):
+            refuse("rope_parameters", f"{t}: only the default rope and "
+                   "yarn are implemented")
+        rotated = hd * float(ropes[t].get("partial_rotary_factor", 1.0))
+        if rotated != int(rotated) or int(rotated) % 2 or not (
+                0 < rotated <= hd):
+            refuse("rope_parameters", f"{t}: partial_rotary_factor must "
+                   f"leave an even share of the head's {hd} values")
+    return dict(
+        vocab_size=d["vocab_size"],
+        hidden_size=d["hidden_size"],
+        intermediate_size=d["intermediate_size"],
+        num_hidden_layers=L,
+        # the widest layer's, for what sizes by one number; the layer loop
+        # reads `layer_heads`
+        num_attention_heads=max(heads),
+        num_key_value_heads=nkv,
+        head_dim=hd,
+        max_position_embeddings=d.get("max_position_embeddings", 4096),
+        rms_norm_eps=d.get("rms_norm_eps", 1e-6),
+        tie_word_embeddings=d.get("tie_word_embeddings", False),
+        sliding_window=d["sliding_window"] if windowed else None,
+        layer_types=tuple(d["layer_types"]),
+        layer_heads=tuple(heads),
+        mlp_layer_types=tuple(d["mlp_layer_types"]),
+        # hashable, as a jitted forward's static config has to be
+        rope_parameters=tuple(
+            (t, tuple(sorted(ropes[t].items())))
+            for t in sorted(set(d["layer_types"]))),
+        attention_gate=True,
+        num_experts=d.get("num_experts", 0),
+        num_experts_per_tok=d.get("num_experts_per_tok", 1),
+        moe_intermediate_size=d.get("moe_intermediate_size"),
+        moe_shared_intermediate_size=d.get("shared_expert_intermediate_size"),
+        n_shared_experts=1 if d.get("shared_expert_intermediate_size") else 0,
+        moe_routed_scale=float(d.get("moe_routed_scaling_factor", 1.0)),
+        model_type="laguna",
+        name=name or d.get("_name_or_path", "laguna"),
     )
 
 

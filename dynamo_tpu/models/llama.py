@@ -137,6 +137,10 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
 
         return (phi4flash if cfg.cross_decoder else hybrid).init_params(
             cfg, key, dtype)
+    if cfg.layer_kinds is not None:
+        from . import laguna
+
+        return laguna.init_params(cfg, key, dtype)
     if cfg.is_latent or cfg.first_k_dense:
         return _init_params_stacks(cfg, key, dtype, w)
     layers = {
@@ -313,6 +317,7 @@ def param_pspecs(cfg: ModelConfig, tp_axis: str = "tp", ep_axis: str = "tp") -> 
     keep axis 0 (layers) replicated.
     """
     require_plain_cache(cfg, "a serving mesh")
+    require_one_layer_shape(cfg, "a serving mesh")
     layers = {
         "wq": P(None, None, tp_axis),
         "wk": P(None, None, tp_axis),
@@ -489,7 +494,8 @@ def moe_router_logits(lp: Params, x: jax.Array, eq: str) -> jax.Array:
 
 def _route(lp: Params, rx: jax.Array, cfg: ModelConfig):
     """(weights, selected), each [..., k]: top-k on the router's float32
-    logits, softmax over the chosen.  `rx` [..., h] is what the router
+    logits, softmax over the chosen (equal to a softmax over all, the chosen
+    renormalised), times `moe_routed_scale`.  `rx` [..., h] is what the router
     reads: the experts' input, or the layer's input for a family whose
     router sits before attention (`cfg.moe_router_pre_attn`)."""
     with jax.named_scope("moe.router"):
@@ -497,7 +503,10 @@ def _route(lp: Params, rx: jax.Array, cfg: ModelConfig):
         if cfg.moe_scoring == "sigmoid":
             return _route_grouped_sigmoid(lp, logits, cfg)
         weights, selected = jax.lax.top_k(logits, cfg.num_experts_per_tok)
-        return jax.nn.softmax(weights, axis=-1), selected
+        weights = jax.nn.softmax(weights, axis=-1)
+        if cfg.moe_routed_scale != 1.0:  # laguna
+            weights = weights * cfg.moe_routed_scale
+        return weights, selected
 
 
 def _route_grouped_sigmoid(lp: Params, logits: jax.Array, cfg: ModelConfig):
@@ -543,6 +552,17 @@ def _moe_shared(lp: Params, x: jax.Array) -> jax.Array:
                       "bsf,fh->bsh").astype(x.dtype)
 
 
+# The all-experts products name the tokens first, the expert stacks second,
+# up to this many experts held: the order every family before laguna was
+# traced and timed with.  Past it the stacks come first.  The same sums; but
+# with the tokens first and 256 experts of [2048, 512] the TPU compiler wants
+# the stacks of `w_gate` and `w_up` in another layout and re-lays out the
+# WHOLE layer-stacked arrays ahead of the loop, 2 x 2.5 GB that no 16 GB chip
+# has beside the weights (AOT for a v5e, PR 52: every chunk but 64 tokens
+# refused, 17.5-17.9 GB); with the stacks first it reads them where they are.
+_TOKENS_FIRST_MAX_EXPERTS = 64
+
+
 def _moe_dense(lp: Params, x: jax.Array, cfg: ModelConfig,
                router_x: Optional[jax.Array] = None, routed=None) -> jax.Array:
     """Every expert computes every token, one-hot combine: O(E) compute and
@@ -559,10 +579,15 @@ def _moe_dense(lp: Params, x: jax.Array, cfg: ModelConfig,
         combine = jnp.einsum("bsk,bske->bse", weights.astype(x.dtype),
                              onehot)  # [B,S,E]
     with jax.named_scope("moe.experts"):
-        gate = jnp.einsum(
-            "bsh,ehf->ebsf", x, lp["w_gate"],
-            preferred_element_type=jnp.float32) if _gated(cfg) else None
-        up = jnp.einsum("bsh,ehf->ebsf", x, lp["w_up"], preferred_element_type=jnp.float32)
+        def into(w):  # x [B, S, h] into every expert's [h, f] -> [E, B, S, f]
+            if E > _TOKENS_FIRST_MAX_EXPERTS:
+                return jnp.einsum("ehf,bsh->ebsf", w, x,
+                                  preferred_element_type=jnp.float32)
+            return jnp.einsum("bsh,ehf->ebsf", x, w,
+                              preferred_element_type=jnp.float32)
+
+        gate = into(lp["w_gate"]) if _gated(cfg) else None
+        up = into(lp["w_up"])
         if "b_gate" in lp:
             gate = gate + lp["b_gate"][:, None, None, :]
             up = up + lp["b_up"][:, None, None, :]
@@ -685,8 +710,12 @@ def merge_moe_stats(per_layer: jax.Array, hc_err: bool = False) -> jax.Array:
                       for i in range(flat.shape[1])])
 
 
-# `moe_impl="auto"`: the most tokens of one step (batch x chunk) for which the
-# all-experts matmul runs in place of the dropless dispatch (`_moe`).  Set
+# `moe_impl="auto"`: which of the two dropless forms one step's expert layers
+# run, from what the trace can see: the experts HELD, the experts a token
+# uses and the step's tokens (batch x chunk).  `all_experts_form` is the
+# rule, `moe_form` its name on the step events.
+#
+# The all-experts matmul runs up to `_ALL_EXPERTS_MAX_TOKENS` tokens, set
 # where the two forms cross in whole `prefill_step` programs on a v5e
 # (PERF.md, PR 31, findings 11 and 13; batch 1, median of 15), ms,
 # all-experts / sort + ragged_dot at 16, 512, 1024, 2048 tokens:
@@ -703,6 +732,48 @@ def merge_moe_stats(per_layer: jax.Array, hc_err: bool = False) -> jax.Array:
 # operations.  Above the boundary the all-experts form's float32 [E, tokens,
 # hidden] temporaries pass a gigabyte at 64 experts.
 _ALL_EXPERTS_MAX_TOKENS = 1024
+# ... and no more than `_ALL_EXPERTS_MAX_ROWS` rows of [experts held x
+# tokens]: every held expert multiplies every token, and the float32
+# products [E, tokens, hidden] are written and read whole (2 GB at 256 experts
+# and 1,024 tokens, beside 11 GB of weights).  Set at the largest step timed
+# at laguna's shape, whole `prefill_step` programs on a v5e (PERF.md, PR 52,
+# finding 32; batch 1 under an 8,192-token table, median of 15), ms,
+# all-experts / sort + ragged_dot at 64, 128, 256, 512 tokens and at the
+# shared step of four rows of 64:
+#   Laguna-XS.2 (256 experts of 2048 x 512, top 8; 1 dense + 6 expert layers)
+#       17.6 / 55.0    18.2 / -    23.3 / 62.4    37.1 / 66.4    23.1 / 62.0
+# The dispatched form's copy of the three expert stacks decides it: 9.7 GB
+# of experts a step are read, written and read again (55 ms before a token
+# is multiplied), where the all-experts form reads them once (17.6 ms at 64
+# tokens: the weights' own 11.1 GB at the HBM peak are 13.6) and then does 32
+# times the routed operations, 37 ms at 512 tokens.  Past 512 tokens nothing
+# was timed at this shape and the temporaries double: the dispatch.  64
+# experts reach the bound at 2,048 tokens, past the line above: the six (held,
+# top-k) pairs served before laguna keep the form they ran
+# (tests/test_laguna.py pins them).
+_ALL_EXPERTS_MAX_ROWS = 131072
+
+
+def all_experts_form(held: int, top_k: int, tokens: int) -> bool:
+    """Does a step of `tokens` tokens run the all-experts matmul over its
+    `held` experts, `top_k` of them a token's own?  (`top_k` is part of what
+    the rule may read; the two bounds above do not need it.)"""
+    del top_k
+    return (tokens <= _ALL_EXPERTS_MAX_TOKENS
+            and tokens * held <= _ALL_EXPERTS_MAX_ROWS)
+
+
+def moe_form(cfg: ModelConfig, tokens: int) -> str:
+    """The form `_moe` runs for a step of `tokens` tokens, as the step events
+    and `/metrics.json` name it: "all_experts", "dispatched" (sort +
+    `ragged_dot`: "ragged", "a2a" outside a shard_map, and "auto" past the
+    rule) or "capacity"."""
+    if cfg.moe_impl == "capacity":
+        return "capacity"
+    if cfg.moe_impl == "dense" or (cfg.moe_impl == "auto" and all_experts_form(
+            cfg.num_experts, cfg.num_experts_per_tok, tokens)):
+        return "all_experts"
+    return "dispatched"
 
 
 def _moe(lp: Params, x: jax.Array, cfg: ModelConfig,
@@ -713,9 +784,9 @@ def _moe(lp: Params, x: jax.Array, cfg: ModelConfig,
     With `stats`, returns (out, `moe_step_stats` over the rows `valid`
     marks).
 
-    `moe_impl` "auto" is dropless, in the form the token count of this
-    trace selects (`_ALL_EXPERTS_MAX_TOKENS`); "dense", "ragged" and
-    "capacity" run the form they name."""
+    `moe_impl` "auto" is dropless, in the form the shapes of this trace
+    select (`all_experts_form`); "dense", "ragged" and "capacity" run the
+    form they name."""
     impl = cfg.moe_impl
     if impl == "capacity":
         if stats:
@@ -726,9 +797,7 @@ def _moe(lp: Params, x: jax.Array, cfg: ModelConfig,
         raise ValueError(
             f"moe_impl must be auto|ragged|a2a|capacity|dense, got {impl!r}")
     routed = _route(lp, x if router_x is None else router_x, cfg)
-    tokens = x.shape[0] * x.shape[1]
-    if impl == "dense" or (impl == "auto"
-                           and tokens <= _ALL_EXPERTS_MAX_TOKENS):
+    if moe_form(cfg, x.shape[0] * x.shape[1]) == "all_experts":
         out = _moe_dense(lp, x, cfg, routed=routed)
     else:
         # "a2a" (the wide-EP all-to-all, parallel/wide_ep.py) only exists
@@ -998,14 +1067,15 @@ def _layer_prefill(
             lp, kv, layer, x, positions, page_table, prefix_lens, chunk_lens,
             cfg, inv_freq, rope_scale, moe_stats, attn_impl)
     B, S, h = x.shape
-    nh, nkv, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim_
+    nkv, hd = cfg.num_key_value_heads, cfg.head_dim_
 
     dt = x.dtype
     x_in = x  # what a pre-attention router reads
     with jax.named_scope("attn.qkv"):
         attn_in = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
         q, k, v = _qkv_proj(attn_in, lp, cfg, "bsh,hd->bsd")
-        q = q.astype(dt).reshape(B, S, nh, hd)
+        # the layer's own query heads: what its `wq` gives (`layer_heads`)
+        q = q.astype(dt).reshape(B, S, -1, hd)
         k = k.astype(dt).reshape(B, S, nkv, hd)
         v = v.astype(dt).reshape(B, S, nkv, hd)
         if rope_pos is not None:
@@ -1020,9 +1090,11 @@ def _layer_prefill(
         q, k, v, kv.k, kv.v, page_table, prefix_lens, chunk_lens,
         impl=attn_impl, window=window, sink=lp.get("sinks"), layer=layer,
     )
+    if "w_head_gate" in lp:
+        attn = _head_gate(lp, attn_in, attn)
     with jax.named_scope("attn.out"):
         attn_out = matmul_any(
-            attn.reshape(B, S, nh * hd), lp["wo"], "bsd,dh->bsh"
+            attn.reshape(B, S, -1), lp["wo"], "bsd,dh->bsh"
         ).astype(x.dtype)
         if "bo" in lp:  # gpt-oss carries an o_proj bias
             attn_out = attn_out + lp["bo"].astype(x.dtype)
@@ -1030,6 +1102,16 @@ def _layer_prefill(
 
     y, *st = _feed_forward(lp, x, x_in, cfg, chunk_lens, moe_stats)
     return x + y, (k, v, *st)
+
+
+@jax.named_scope("attn.gate")
+def _head_gate(lp: Params, attn_in: jax.Array, attn: jax.Array) -> jax.Array:
+    """Attention's output gate (`ModelConfig.attention_gate`): one scalar a
+    head a token, sigmoid of the layer's normed input times `w_head_gate`
+    [h, heads], times that head's output [B, S, heads, hd], before `wo`."""
+    g = jax.nn.sigmoid(jnp.einsum("bsh,hn->bsn", attn_in, lp["w_head_gate"],
+                                  preferred_element_type=jnp.float32))
+    return (attn * g[..., None]).astype(attn.dtype)
 
 
 def _layer_decode(
@@ -1190,6 +1272,20 @@ def require_plain_cache(cfg: ModelConfig, what: str) -> None:
             f"{what} does not carry a layer pattern, pages for some layers "
             f"only or state slots beside the pages yet ({cfg.model_type}): "
             "serve this family flat on one chip (replicas: --dp-ranks)")
+
+
+def require_one_layer_shape(cfg: ModelConfig, what: str) -> None:
+    """Layers of several shapes (`layer_heads`: laguna) are walked by the
+    one layer loop of `models/laguna.py`, which every flat step kind but
+    these rides: a path with a layer body, a weight layout or specs of its
+    own refuses the family at start-up, by the key that asks."""
+    if cfg.layer_kinds is not None:
+        raise ValueError(
+            f"{what} does not carry head counts by layer "
+            f"(num_attention_heads_per_layer: {cfg.model_type}): its layers "
+            "have two shapes and only the flat engine's one layer loop "
+            "walks them; serve this family flat on one chip (replicas: "
+            "--dp-ranks)")
 
 
 def require_no_state(cfg: ModelConfig, what: str) -> None:
@@ -1413,6 +1509,12 @@ def forward_prefill(
         x, kv, *st = hybrid.layers(params, cfg, kv, x, page_table,
                                    prefix_lens, chunk_lens, attn_impl,
                                    moe_stats)
+    elif cfg.layer_kinds is not None:
+        from . import laguna
+
+        x, kv, *st = laguna.layers(params, cfg, kv, x, positions, page_table,
+                                   prefix_lens, chunk_lens, attn_impl,
+                                   moe_stats)
     else:
         x, kv, *st = prefill_layers(
             layer_stacks(params), cfg, kv, x, positions, page_table,
@@ -1457,6 +1559,7 @@ def forward_embed(
     a one-slot stand-in that attention masks out whole."""
     B, S = tokens.shape
     require_no_state(cfg, "the embedding forward")
+    require_one_layer_shape(cfg, "the embedding forward")
     kv = KVCache.create(cfg, 1, 1, jnp.float32)
     table = jnp.zeros((B, 1), jnp.int32)
     inv_freq = rope_frequencies(_rope_dim(cfg), cfg.rope_theta,
@@ -1523,6 +1626,14 @@ def forward_decode(
             params, cfg, kv, x[:, None], page_table, positions,
             jnp.ones_like(positions), attn_impl, moe_stats)
         return (_lm_logits(params, cfg, x[:, 0]), kv, *st)
+    if cfg.layer_kinds is not None:
+        # a chunk of one token through the one loop over both shapes
+        from . import laguna
+
+        x, kv, *st = laguna.layers(
+            params, cfg, kv, x[:, None], positions[:, None], page_table,
+            positions, jnp.ones_like(positions), attn_impl, moe_stats)
+        return (_lm_logits(params, cfg, x[:, 0]), kv, *st)
     x, kv, *st = decode_layers(
         layer_stacks(params), cfg, kv, x, positions, page_table, attn_impl,
         rope_offset=rope_offset, moe_stats=moe_stats,
@@ -1566,6 +1677,13 @@ def forward_verify(
         # exactly `_layer_decode`'s rope_pos = slot + delta
         positions = positions + rope_offset[:, None]
     x = _streams(cfg, params["embed"][tokens])  # [B, S, h]
+    if cfg.layer_kinds is not None:
+        from . import laguna
+
+        x, kv, *st = laguna.layers(params, cfg, kv, x, positions, page_table,
+                                   prefix_lens, chunk_lens, attn_impl,
+                                   moe_stats)
+        return (_lm_logits(params, cfg, x), kv, *st)
     x, kv, *st = prefill_layers(
         layer_stacks(params), cfg, kv, x, positions, page_table, prefix_lens,
         chunk_lens, attn_impl, moe_stats=moe_stats,
@@ -1614,6 +1732,7 @@ def decode_block_scan(
     (gpt-oss, qwen-vl, swa, pooled) run through THIS path on CPU and on
     short-context TPU, which is what catches a drift."""
     require_no_state(cfg, "the decode block over gathered pages")
+    require_one_layer_shape(cfg, "the decode block over gathered pages")
     layers = layer_stacks(params)
     L = kv.k.shape[0]
     P, page = kv.k.shape[1], kv.k.shape[2]

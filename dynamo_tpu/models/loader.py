@@ -90,6 +90,8 @@ def load_params(path: str, cfg: ModelConfig, dtype=jnp.bfloat16,
         return _load_phi4flash(r, cfg, dtype, prefix)
     if cfg.layer_pattern is not None:
         return _load_nemotron_h(r, cfg, dtype, prefix)
+    if cfg.layer_kinds is not None:
+        return _load_laguna(r, cfg, dtype, prefix)
 
     def stack(fmt: str, transpose: bool = True) -> jnp.ndarray:
         return stack_layers(r, L, fmt, transpose=transpose, dtype=dtype)
@@ -305,6 +307,63 @@ def _load_nemotron_h(r: "_ShardReader", cfg: ModelConfig, dtype,
             "ws_down": stack("E", "mixer.shared_experts.down_proj.weight"),
         },
     }
+    return params
+
+
+def _load_laguna(r: "_ShardReader", cfg: ModelConfig, dtype, prefix: str = ""):
+    """laguna tensor names -> `models.laguna.init_params`'s stacks, one a
+    kind of layer, each layer at its own head count.  The published config
+    carries no tensor names: these are the softmax-router lineage's
+    (`self_attn.{q,k,v,o}_proj`, `mlp.gate` the router, `mlp.experts.{e}.
+    {gate,up,down}_proj`, `mlp.shared_expert.*`, a dense layer's `mlp.
+    {gate,up,down}_proj`), and `self_attn.g_proj` [heads, hidden] for the
+    output gate is a name set here."""
+    from .laguna import stacks_of
+
+    def stack_fn(ids):
+        def stack(name, fn=lambda w: w.T):
+            return jnp.asarray(np.stack([fn(r.get(
+                prefix + f"model.layers.{i}.{name}")) for i in ids]), dtype)
+
+        return stack
+
+    def ffn(stack, at, keys=("w_gate", "w_up", "w_down")):
+        return {key: stack(f"{at}{proj}_proj.weight")
+                for key, proj in zip(keys, ("gate", "up", "down"))}
+
+    params = {
+        "embed": jnp.asarray(r.get(prefix + "model.embed_tokens.weight"),
+                             dtype),
+        "final_norm": jnp.asarray(r.get(prefix + "model.norm.weight"), dtype),
+    }
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = jnp.asarray(
+            r.get(prefix + "lm_head.weight").T, dtype)
+    for name, ((_, mlp, _), ids) in stacks_of(cfg).items():
+        stack = stack_fn(ids)
+        layer = {
+            "attn_norm": stack("input_layernorm.weight", lambda w: w),
+            "mlp_norm": stack("post_attention_layernorm.weight", lambda w: w),
+            "wq": stack("self_attn.q_proj.weight"),
+            "wk": stack("self_attn.k_proj.weight"),
+            "wv": stack("self_attn.v_proj.weight"),
+            "wo": stack("self_attn.o_proj.weight"),
+            "w_head_gate": stack("self_attn.g_proj.weight"),
+        }
+        if mlp == "dense":
+            layer.update(ffn(stack, "mlp."))
+        else:
+            layer["router"] = stack("mlp.gate.weight")
+            for key, proj in (("w_gate", "gate"), ("w_up", "up"),
+                              ("w_down", "down")):
+                layer[key] = jnp.stack([jnp.asarray(np.stack([r.get(
+                    prefix + f"model.layers.{i}.mlp.experts.{e}.{proj}_proj."
+                    "weight").T for e in range(cfg.num_experts)]), dtype)
+                    for i in ids])
+            if cfg.n_shared_experts:
+                layer.update(ffn(stack, "mlp.shared_expert.",
+                                 ("ws_gate", "ws_up", "ws_down")))
+        params[name] = layer
     return params
 
 

@@ -13,8 +13,8 @@ from .paged_attention import (
     write_kv_layers,
     write_kv_pages,
 )
-from .rotary import (apply_mrope, apply_rope,
-                     rope_attention_scale, rope_frequencies)
+from .rotary import (apply_mrope, apply_rope, rope_attention_scale,
+                     rope_by_kind, rope_frequencies)
 from .sampling import (
     SamplingParams,
     apply_penalties,
@@ -35,6 +35,7 @@ __all__ = [
     "prefill_attention",
     "rms_norm",
     "rope_attention_scale",
+    "rope_by_kind",
     "rope_frequencies",
     "sample_tokens",
     "top_logprobs",

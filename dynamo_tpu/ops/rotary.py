@@ -96,21 +96,46 @@ def rope_attention_scale(scaling: Optional[dict]) -> float:
     return 1.0
 
 
+def rope_by_kind(head_dim: int, rope_parameters: tuple) -> dict:
+    """{layer type: (inv_freq, amplitude)} of a model with a rope of its own
+    for each kind of layer (`ModelConfig.rope_parameters`: laguna).  A
+    kind's `partial_rotary_factor` is the share of a head it rotates: its
+    table has half that many frequencies, computed (yarn's ramp too) over
+    the rotated width as HF does, and `apply_rope` leaves the rest of the
+    head as it is."""
+    tables = {}
+    for kind, items in rope_parameters:
+        rp = dict(items)
+        rotated = int(head_dim * float(rp.get("partial_rotary_factor", 1.0)))
+        tables[kind] = (
+            rope_frequencies(rotated, float(rp.get("rope_theta", 10000.0)),
+                             rp),
+            rope_attention_scale(rp))
+    return tables
+
+
 def apply_rope(
     x: jax.Array,  # [..., seq, heads, head_dim]
     positions: jax.Array,  # [..., seq]
-    inv_freq: jax.Array,  # [head_dim//2]
+    inv_freq: jax.Array,  # [rotated//2]: head_dim//2, or fewer (partial)
     scale: float = 1.0,  # yarn attention factor (rope_attention_scale)
 ) -> jax.Array:
-    """Rotate pairs (x[..., :d/2], x[..., d/2:]) — HF llama convention."""
+    """Rotate pairs (x[..., :d/2], x[..., d/2:]) — HF llama convention.
+    `inv_freq` shorter than half the head (a partial rotary factor): the
+    head's FIRST 2 x len(inv_freq) values are rotated so, among themselves,
+    and the rest pass as they are."""
     angles = positions[..., None].astype(jnp.float32) * inv_freq  # [..., seq, d/2]
     cos = jnp.cos(angles)[..., None, :]  # [..., seq, 1, d/2]
     sin = jnp.sin(angles)[..., None, :]
-    d2 = x.shape[-1] // 2
-    x1, x2 = x[..., :d2].astype(jnp.float32), x[..., d2:].astype(jnp.float32)
+    d2 = inv_freq.shape[0]
+    partial = 2 * d2 < x.shape[-1]
+    rot = x[..., :2 * d2] if partial else x
+    x1, x2 = rot[..., :d2].astype(jnp.float32), rot[..., d2:].astype(jnp.float32)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     if scale != 1.0:
         out = out * scale
+    if partial:
+        return jnp.concatenate([out.astype(x.dtype), x[..., 2 * d2:]], axis=-1)
     return out.astype(x.dtype)
 
 

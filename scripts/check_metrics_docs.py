@@ -81,6 +81,9 @@ def representative_engine_stats() -> dict:
     stats["moe_local_assignments_total"] = 0  # a share of the experts
     stats["moe_experts_hit_total"] = 0
     stats["moe_steps_total"] = 0
+    for form in ("all_experts", "dispatched"):  # the steps by `moe_form`
+        stats[f"moe_{form}_steps_total"] = 0
+        stats[f"moe_{form}_tokens_total"] = 0
     stats["hc_res_err_ppm_max"] = 0  # a residual of several streams
     # state slots beside the pages (a model with state-space layers)
     stats["state_slots_total"] = 0

@@ -12,7 +12,8 @@ the CPU, where a latent model keeps XLA's).
     python scripts/time_prefill_steps.py benchmark/configs/<config>.json \\
         [--chunks 512,256,64,16] [--table-pages 256[,128,64]] \\
         [--batch 1[,2,4]] [--impls auto,dense,ragged] \\
-        [--attention auto[,pallas,xla]] [--head 1[,0]]
+        [--attention auto[,pallas,xla]] [--head 1[,0]] \\
+        [--max-model-len 4096] [--num-pages N]
 
 Single process, no children; a measurement needs a TPU (refuses the CPU)."""
 
@@ -45,6 +46,12 @@ def main():
                     help="1: the step's first row samples; 0: no row does "
                     "(the same program: `samples` is an operand)")
     ap.add_argument("--runs", type=int, default=15)
+    ap.add_argument("--max-model-len", type=int, default=4096,
+                    help="the context the layout is resolved for (a cell's "
+                    "own: 8192 under 512-page tables)")
+    ap.add_argument("--num-pages", type=int, default=0,
+                    help="pool pages in place of the configuration's (a form "
+                    "whose temporaries do not fit beside the cell's pool)")
     ap.add_argument("--rehearse-cpu", action="store_true",
                     help="walk the control flow on the CPU in float32; "
                     "its times mean nothing")
@@ -67,7 +74,7 @@ def main():
     with open(args.config) as f:
         run = json.load(f)
     base = ModelConfig.from_hf_config(run["model"])
-    pages = run["worker_flags"]["--num-pages"]
+    pages = args.num_pages or run["worker_flags"]["--num-pages"]
     # a model with state-space layers: its state slots beside the pages
     # (every row reads and writes a slot of its own)
     slots = run["worker_flags"].get("--num-state-slots", 32)
@@ -80,7 +87,8 @@ def main():
                                              args.attention.split(",")):
         cfg = dataclasses.replace(base, moe_impl=impl)
         layout = Layout.resolve(cfg, EngineConfig(
-            num_pages=pages, num_state_slots=slots, max_model_len=4096,
+            num_pages=pages, num_state_slots=slots,
+            max_model_len=args.max_model_len,
             attention_impl=attention))[0]
         step = layout.prefill_step(False, greedy=True)
         kv = KVCache.create(cfg, pages, 16, dtype, state_slots=slots)
