@@ -434,10 +434,15 @@ class JaxEngine:
             return None
         per_slot = spec.bytes_per_slot(jnp.dtype(self._kv_dtype).itemsize)
         slots = self.cfg.num_state_slots
-        return {"kind": "ssm", "layers": spec.layers,
+        # a state that is a window alone has no recurrent part: no `state`
+        # key, and `bytes_per_slot` counts the window's bytes only
+        recurrent = ({"state": list(spec.state_dims),
+                      "state_dtype": "float32"} if spec.recurrent else {})
+        return {"kind": "ssm" if spec.recurrent else "window",
+                "layers": spec.layers,
                 "window": list(spec.window_dims),
                 "window_dtype": jnp.dtype(self._kv_dtype).name,
-                "state": list(spec.state_dims), "state_dtype": "float32",
+                **recurrent,
                 "bytes_per_slot": per_slot, "slots": slots,
                 "pool_bytes": slots * per_slot,
                 "snapshot_every": self.scheduler.state.snapshot_every}

@@ -57,6 +57,10 @@ class CacheSpec:
     heads: int
     width: int
     key_width: int = 0  # latent: the shared rotary key's values
+    # "kv": heads narrower than a lane tile stored side by side as whole
+    # tiles, [heads * width / 128, 128] a token: the same values in the same
+    # order as [heads, width] (`ops.pallas_attention.packed_plane`)
+    packed: bool = False
 
     @property
     def values(self) -> int:
@@ -68,6 +72,8 @@ class CacheSpec:
     @property
     def plane_dims(self) -> tuple:
         """The trailing [*, *] of the pool's two arrays (k's, v's)."""
+        if self.packed:
+            return ((self.heads * self.width // 128, 128),) * 2
         if self.kind != "latent":
             return ((self.heads, self.width),) * 2
 
@@ -94,7 +100,10 @@ class StateSpec:
     state `state_dims` in float32: a Mamba-2 layer's [heads, head_dim,
     state]; a Mamba-1 layer's [state, channels], the channels under the
     lanes (a [5120, 16] state stored the other way round would fill an
-    eighth of every tile)."""
+    eighth of every tile).  `state_dims` () is a state that is the window
+    ALONE (a gated short convolution: lfm2_moe's [2, 2048] a layer): the
+    pool is then the one array, and nothing is allocated, read, written or
+    snapshotted for a recurrent part (`recurrent`)."""
 
     layers: int
     state_dims: tuple
@@ -107,11 +116,17 @@ class StateSpec:
         n = (self.conv_kernel - 1) * self.conv_dim
         return (-(-n // 128), 128)
 
+    @property
+    def recurrent(self) -> bool:
+        """Is there a recurrent state beside the window?"""
+        return bool(self.state_dims)
+
     def bytes_per_slot(self, itemsize: int) -> int:
         """One sequence's state over every state-space layer: the window in
-        the served dtype, the recurrent state in float32."""
+        the served dtype, the recurrent state (if any) in float32."""
         w = self.window_dims[0] * self.window_dims[1] * itemsize
-        return self.layers * (w + 4 * math.prod(self.state_dims))
+        h = 4 * math.prod(self.state_dims) if self.recurrent else 0
+        return self.layers * (w + h)
 
 
 @dataclass(frozen=True)
@@ -274,6 +289,22 @@ class ModelConfig:
     mlp_layer_types: Optional[tuple] = None
     rope_parameters: Optional[tuple] = None
     attention_gate: bool = False
+    # an RMS norm with a weight over the `head_dim` values of EACH head of
+    # q and of k, after the projections and before the rope (lfm2's
+    # `q_layernorm` / `k_layernorm`, qwen3's `q_norm` / `k_norm`): params
+    # `q_head_norm`, `k_head_norm` [layers, head_dim]
+    qk_norm: bool = False
+    # a layer whose mixer is a gated short convolution (lfm2_moe: the layers
+    # `layer_types` names "conv", walked by `models/laguna.py` beside the
+    # attention layers): `[B, C, u] = in_proj(z)`, a causal depthwise
+    # convolution of `short_conv_kernel` taps over `B * u` without bias or
+    # activation, `out_proj(C * conv)`.  Such a layer keeps no keys: what a
+    # sequence leaves it is the convolution's last `short_conv_kernel - 1`
+    # inputs, a window in the state slots and nothing else (`state_spec`)
+    short_conv_kernel: int = 0
+    # added to the SUM of the chosen experts' sigmoid scores before the
+    # router's weights are divided by it (lfm2_moe's 1e-6; 0: the sum alone)
+    moe_norm_eps: float = 0.0
     # identity
     model_type: str = "llama"
     name: str = "llama"
@@ -373,16 +404,29 @@ class ModelConfig:
         return "C" in (self.layer_pattern or "")
 
     @property
+    def conv_layers(self) -> int:
+        """Layers whose mixer is a gated short convolution (`layer_types`
+        "conv" under `short_conv_kernel`)."""
+        if not self.short_conv_kernel:
+            return 0
+        return sum(t == "conv" for t in self.layer_types)
+
+    @property
     def num_kv_layers(self) -> int:
         """Layers that leave a token keys and values: the page pool's (the
-        "C" layers read "F"'s pages and the "G" layers keep nothing)."""
+        "C" layers read "F"'s pages, the "G" layers and the short
+        convolutions keep nothing)."""
         if self.layer_pattern is not None:
             return sum(self.layer_pattern.count(c) for c in "*WF")
-        return self.num_hidden_layers
+        return self.num_hidden_layers - self.conv_layers
 
     @property
     def state_spec(self) -> Optional[StateSpec]:
-        """What a sequence leaves the state-space layers; None without."""
+        """What a sequence leaves the layers that keep a state a SEQUENCE
+        (state-space mixers, short convolutions); None without."""
+        if self.conv_layers:  # a window alone: no recurrent part
+            return StateSpec(self.conv_layers, (), self.hidden_size,
+                             self.short_conv_kernel)
         pattern = self.layer_pattern or ""
         n = pattern.count("M") + pattern.count("S")
         if not n:
@@ -442,7 +486,21 @@ class ModelConfig:
             # for a v5e, PR 48)
             return CacheSpec("kv", 2, self.num_key_value_heads
                              * self.head_dim_ // 2)
-        return CacheSpec("kv", self.num_key_value_heads, self.head_dim_)
+        kv, hd = self.num_key_value_heads, self.head_dim_
+        # Heads narrower than a lane tile (8 of 64) whose token fills whole
+        # tiles are stored as tiles, two heads a tile, which the prefill
+        # kernel reads as they are stored.  Stored [8, 64] the TPU compiler
+        # pads every head to a tile and copies the whole pool into that
+        # form every step (AOT for a v5e, PR 55).  It turns on the head
+        # width and on the ONE layer loop that carries such a plane (`models/
+        # laguna.py`, the families with `layer_kinds`: it reshapes a chunk's
+        # rows to the plane and decodes through itself); every other path
+        # knows a page as [heads, head_dim] and keeps that layout (ROADMAP
+        # D3), and the paths that cannot carry a walker's family refuse it
+        # (`require_one_layer_shape`, `require_plain_cache`)
+        narrow = hd < 128 and 128 % hd == 0 and kv * hd % 256 == 0
+        return CacheSpec("kv", kv, hd,
+                         packed=narrow and self.layer_kinds is not None)
 
     @property
     def latent_softmax_scale(self) -> float:
@@ -519,12 +577,18 @@ class ModelConfig:
         h, hd = self.hidden_size, self.head_dim_
         kv = self.num_key_value_heads * hd
         sparse = (h * self.router_width
+                  + (self.router_width if self.moe_scoring == "sigmoid"
+                     else 0)
                   + self.num_experts * 3 * h * self.moe_intermediate_size
                   + 3 * h * self.shared_expert_width)
         ffn = {"dense": 3 * h * self.intermediate_size, "sparse": sparse}
         gate = h if self.attention_gate else 0
-        layers = sum(2 * h * nh * hd + 2 * h * kv + gate * nh + ffn[mlp]
-                     + 2 * h for _, mlp, nh in self.layer_kinds)
+        norms = 2 * hd if self.qk_norm else 0
+        # a short convolution: in_proj [h, 3h], the taps, out_proj [h, h]
+        conv = 4 * h * h + self.short_conv_kernel * h
+        layers = sum((conv if kind == "conv" else 2 * h * nh * hd
+                      + 2 * h * kv + gate * nh + norms) + ffn[mlp] + 2 * h
+                     for kind, mlp, nh in self.layer_kinds)
         emb = self.vocab_size * h * (1 if self.tie_word_embeddings else 2)
         return layers + emb + h
 
@@ -577,6 +641,14 @@ class ModelConfig:
             return ModelConfig(**_phi4flash_fields(d, name))
         if d.get("model_type") == "laguna":
             return ModelConfig(**_laguna_fields(d, name))
+        if d.get("model_type") == "lfm2_moe":
+            return ModelConfig(**_lfm2_moe_fields(d, name))
+        if d.get("conv_L_cache") or "conv" in (d.get("layer_types") or ()):
+            raise ValueError(
+                f"model_type {d.get('model_type')!r} asks for short "
+                "convolution layers (conv_L_cache, layer_types 'conv') and "
+                "only lfm2_moe's are implemented: the llama branch would "
+                "build another model")
         if d.get("num_attention_heads_per_layer"):
             raise ValueError(
                 f"model_type {d.get('model_type')!r} asks for head counts "
@@ -661,6 +733,9 @@ class ModelConfig:
             attention_sinks=d.get(
                 "attention_sinks", d.get("model_type") == "gpt_oss"
             ),
+            # HF Qwen3Attention norms each head of q and k (`q_norm`,
+            # `k_norm`) before the rope, whatever its config says
+            qk_norm=d.get("model_type") == "qwen3",
             model_type=d.get("model_type", "llama"),
             name=name or d.get("_name_or_path", "llama"),
         )
@@ -1001,6 +1076,109 @@ def _laguna_fields(d: dict, name: str) -> dict:
         moe_routed_scale=float(d.get("moe_routed_scaling_factor", 1.0)),
         model_type="laguna",
         name=name or d.get("_name_or_path", "laguna"),
+    )
+
+
+LFM2_LAYER_TYPES = ("conv", "full_attention")
+
+
+def _lfm2_moe_fields(d: dict, name: str) -> dict:
+    """Liquid AI LFM2-MoE (`model_type` "lfm2_moe": LFM2-24B-A2B, LFM2-8B-A1B).
+    `layer_types` names each layer's MIXER: "conv", a gated short convolution
+    of `conv_L_cache` taps without bias or activation, or "full_attention",
+    GQA with an RMS norm over each head of q and of k before the rope.  The
+    first `num_dense_layers` layers carry a dense SwiGLU of
+    `intermediate_size`, the others `num_experts` SwiGLU experts of
+    `moe_intermediate_size` behind a sigmoid router whose `expert_bias`
+    joins the scores for CHOOSING only (`use_expert_bias`), the chosen
+    scores divided by their sum + 1e-6 (`norm_topk_prob`) and multiplied by
+    `routed_scaling_factor`; no shared expert.  The final norm is named
+    `embedding_norm` and the head is tied.  A conv layer keeps no keys
+    (`num_kv_layers`), and what a sequence leaves it is a window alone
+    (`state_spec`).  `models/laguna.py` walks the layers.  What is not
+    implemented is refused by the key that asks for it."""
+    def refuse(key, why):
+        raise ValueError(f"lfm2_moe: {key} {d.get(key)!r} {why}")
+
+    L = d["num_hidden_layers"]
+    types = d.get("layer_types")
+    if not isinstance(types, (list, tuple)) or len(types) != L or (
+            set(types) - set(LFM2_LAYER_TYPES)):
+        refuse("layer_types", f"must name {L} layers by "
+               f"{list(LFM2_LAYER_TYPES)}")
+    dense = d.get("num_dense_layers", 0)
+    if not isinstance(dense, int) or not 0 <= dense <= L:
+        refuse("num_dense_layers", f"must count the leading dense layers "
+               f"among {L}")
+    taps = d.get("conv_L_cache")
+    if "conv" in types and (not isinstance(taps, int) or taps < 2):
+        refuse("conv_L_cache", "must give the short convolution's taps, 2 "
+               "or more: the layer list has conv layers")
+    if d.get("conv_bias"):
+        refuse("conv_bias", "biases on the convolution and its projections "
+               "are not implemented")
+    if dense < L and not d.get("num_experts"):
+        refuse("num_experts", "is needed: layers past num_dense_layers are "
+               "expert layers")
+    if not d.get("use_expert_bias", True):
+        refuse("use_expert_bias", "only the router with its choosing bias is "
+               "implemented")
+    if not d.get("norm_topk_prob", True):
+        refuse("norm_topk_prob", "only normalised weights are implemented")
+    eps = d.get("norm_eps", 1e-5)
+    if d.get("rms_norm_eps", eps) != eps:
+        refuse("rms_norm_eps", f"differs from norm_eps {eps!r}: one epsilon "
+               "serves every norm")
+    for key in ("sliding_window", "rope_scaling", "attention_bias",
+                "mlp_bias"):
+        if d.get(key):
+            refuse(key, "is not implemented for this family")
+    ropes = d.get("rope_parameters")
+    if ropes is None:
+        ropes = {"rope_theta": d.get("rope_theta", 1000000.0),
+                 "rope_type": "default"}
+    if not isinstance(ropes, dict) or ropes.get(
+            "rope_type", "default") != "default":
+        refuse("rope_parameters", "only the default rope (rope_theta alone) "
+               "is implemented")
+    if float(ropes.get("partial_rotary_factor", 1.0)) != 1.0:
+        refuse("rope_parameters", "the whole head is rotated: a partial "
+               "rotary factor is not implemented")
+    nq = d["num_attention_heads"]
+    nkv = d.get("num_key_value_heads", nq)
+    if nq % nkv:
+        refuse("num_key_value_heads", f"must divide the {nq} query heads")
+    return dict(
+        vocab_size=d["vocab_size"],
+        hidden_size=d["hidden_size"],
+        intermediate_size=d["intermediate_size"],
+        num_hidden_layers=L,
+        num_attention_heads=nq,
+        num_key_value_heads=nkv,
+        head_dim=d.get("head_dim") or d["hidden_size"] // nq,
+        max_position_embeddings=d.get("max_position_embeddings", 4096),
+        rms_norm_eps=eps,
+        rope_theta=float(ropes["rope_theta"]),
+        tie_word_embeddings=d.get("tie_word_embeddings",
+                                  d.get("tie_embedding", True)),
+        layer_types=tuple(types),
+        # a conv layer has no heads: its kind's stack holds no `wq`
+        layer_heads=tuple(0 if t == "conv" else nq for t in types),
+        mlp_layer_types=tuple("dense" if l < dense else "sparse"
+                              for l in range(L)),
+        rope_parameters=(("full_attention", (
+            ("rope_theta", float(ropes["rope_theta"])),
+            ("rope_type", "default"))),),
+        qk_norm=True,
+        short_conv_kernel=taps or 0,
+        num_experts=d.get("num_experts", 0),
+        num_experts_per_tok=d.get("num_experts_per_tok", 1),
+        moe_intermediate_size=d.get("moe_intermediate_size"),
+        moe_scoring="sigmoid",
+        moe_routed_scale=float(d.get("routed_scaling_factor", 1.0)),
+        moe_norm_eps=1e-6,
+        model_type="lfm2_moe",
+        name=name or d.get("_name_or_path", "lfm2_moe"),
     )
 
 

@@ -244,10 +244,7 @@ def layers(params: Params, cfg: ModelConfig, kv: StateCache, x: jax.Array,
 
     def mamba(h, lp, layer):
         with jax.named_scope("state.read"):
-            win = kv.conv[layer, slot_in].reshape(
-                B, -1)[:, :(spec.conv_kernel - 1) * spec.conv_dim].reshape(
-                B, spec.conv_kernel - 1, spec.conv_dim)
-            win = jnp.where(fresh[:, None, None], 0, win)
+            win = read_window(kv, spec, layer, slot_in, fresh)
             h0 = jnp.where(fresh[:, None, None, None], 0.0,
                            kv.ssm[layer, slot_in])
         out, win, h1, inside = normed(lp, h, lambda u: _mamba(
@@ -322,19 +319,34 @@ def layers(params: Params, cfg: ModelConfig, kv: StateCache, x: jax.Array,
     return x, kv, merge_moe_stats(own(st_e, 2)[0])
 
 
+def read_window(kv: StateCache, spec, layer, slot_in: jax.Array,
+                fresh: jax.Array):
+    """Layer `layer`'s carried windows of the rows whose states lie in slots
+    `slot_in` [B], out of the pool's tiles: [B, K-1, conv_dim], zeros for
+    the rows `fresh` marks (they read slot 0: the state before a
+    sequence)."""
+    k1, B = spec.conv_kernel - 1, slot_in.shape[0]
+    win = kv.conv[layer, slot_in].reshape(B, -1)[
+        :, :k1 * spec.conv_dim].reshape(B, k1, spec.conv_dim)
+    return jnp.where(fresh[:, None, None], 0, win)
+
+
 def write_states(kv: StateCache, states, slot_out: jax.Array,
                  slot_inside: jax.Array):
     """Every state-space layer's new states into the slot pools: `states`
     (window, state, *(window, state) inside the chunk), each [Lm, B, ..]
-    -> (conv, ssm)."""
+    -> (conv, ssm).  A state that is a window alone (`kv.ssm` None: short
+    convolutions) has None for every `state` and writes the one pool."""
     win, h1, *inside = states
     with jax.named_scope("state.write"):
         conv, pool = kv.conv, kv.ssm
         for j, (w, hj) in enumerate(inside):  # before the chunk's end:
             # a row whose slots coincide keeps its latest state
             conv = conv.at[:, slot_inside[:, j]].set(w)
-            pool = pool.at[:, slot_inside[:, j]].set(hj)
-        return conv.at[:, slot_out].set(win), pool.at[:, slot_out].set(h1)
+            if pool is not None:
+                pool = pool.at[:, slot_inside[:, j]].set(hj)
+        return conv.at[:, slot_out].set(win), (
+            None if pool is None else pool.at[:, slot_out].set(h1))
 
 
 def _as_tiles(win: jax.Array, dims: Tuple[int, int]) -> jax.Array:

@@ -1,6 +1,8 @@
-"""The layer loop of a model whose layers have two SHAPES (`ModelConfig.
-layer_kinds`: laguna).  A layer is `llama._layer_prefill`'s (GQA attention,
-then a feed-forward, each around a plain residual); what differs by layer:
+"""The layer loop of a model whose layers have several SHAPES (`ModelConfig.
+layer_kinds`: laguna, lfm2_moe).  A layer is `llama._layer_prefill`'s (GQA
+attention, then a feed-forward, each around a plain residual) or, under the
+layer type "conv" (lfm2_moe), a gated short convolution in attention's place
+(`_short_conv`: described after the loop, below); what differs by layer:
 
   the attention kind (`layer_types`): "full_attention" layers have 48 query
     heads, see every earlier key and rotate the FIRST HALF of each head with
@@ -27,11 +29,25 @@ layer's matrices are indexed out of the WHOLE stacks (closed over, as
 `models/hybrid.py` does) by the loops' counters, so the product a matrix
 feeds reads it in place (PERF.md finding 29).
 
+A "conv" layer (lfm2_moe: `ModelConfig.short_conv_kernel`) keeps no keys and
+has no heads: `[B, C, u] = in_proj(n)`, a causal depthwise convolution of K
+taps over `B * u` with neither bias nor activation, `out_proj(C * conv)`.
+What a sequence leaves it is the convolution's last K - 1 inputs, a window
+[K - 1, hidden] and nothing recurrent: it lives in the state SLOTS beside
+the pages (`llama.StateCache` with `ssm` None, `ModelConfig.state_spec`), a
+row's slots ride in the last `hybrid.STATE_COLS` columns of its page table
+as nemotron_h's do, and the window is handed out inside a chunk at the same
+token counts (`hybrid._inside`), so snapshots, hash addresses and the tail
+row are the scheduler's as they are.  The page pool holds the attention
+layers alone and the slot pool the conv layers alone: a layer's row in
+either is its rank among the layers of its mixer (`_rows`).
+
 Every flat step kind rides this one loop: a prefill chunk, the verify step
-(every position's logits) and a decode step (a chunk of one: `llama.
-forward_decode`).  The decode block over gathered pages, the embedding
-forward and every layout with a layer body of its own refuse the family by
-name (`llama.require_one_layer_shape`).
+(every position's logits; not with conv layers, whose window cannot be
+rolled back) and a decode step (a chunk of one: `llama.forward_decode`).
+The decode block over gathered pages, the embedding forward and every layout
+with a layer body of its own refuse the family by name (`llama.
+require_one_layer_shape`).
 
 WHAT THE PUBLISHED CONFIG LEAVES UNSAID, and the reading taken (each one
 line here and one keyword of `benchmark/reference/laguna.py`):
@@ -50,11 +66,15 @@ from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from ..ops import rope_by_kind, write_kv_layers
+from ..ops import rms_norm, rope_by_kind, ssm, write_kv_layers
 from .config import ModelConfig
-from .llama import (KVCache, Params, _layer_prefill, _valid_rows,
-                    merge_moe_stats, moe_stats_columns)
+from .hybrid import (_as_tiles, _inside, read_window, split_table,
+                     write_states)
+from .llama import (KVCache, Params, _feed_forward, _layer_prefill,
+                    _valid_rows, merge_moe_stats, moe_stats_columns)
+from .quantization import matmul_any
 
 
 def stack_of(kind: tuple) -> str:
@@ -153,14 +173,20 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
               "final_norm": jnp.ones((h,), dtype)}
     if not cfg.tie_word_embeddings:
         params["lm_head"] = w(h, cfg.vocab_size)
-    for stack, ((_, mlp, nh), ids) in stacks_of(cfg).items():
+    for stack, ((kind, mlp, nh), ids) in stacks_of(cfg).items():
         n = len(ids)
-        layer = {
-            "attn_norm": jnp.ones((n, h), dtype),
-            "mlp_norm": jnp.ones((n, h), dtype),
-            "wq": w(n, h, nh * hd), "wk": w(n, h, kvw), "wv": w(n, h, kvw),
-            "wo": w(n, nh * hd, h),
-        }
+        layer = {"attn_norm": jnp.ones((n, h), dtype),
+                 "mlp_norm": jnp.ones((n, h), dtype)}
+        if kind == "conv":  # `attn_norm` is the mixer's norm
+            layer.update({"in_proj": w(n, h, 3 * h),
+                          "conv_w": w(n, cfg.short_conv_kernel, h, scale=0.5),
+                          "out_proj": w(n, h, h)})
+        else:
+            layer.update({"wq": w(n, h, nh * hd), "wk": w(n, h, kvw),
+                          "wv": w(n, h, kvw), "wo": w(n, nh * hd, h)})
+        if cfg.qk_norm and kind != "conv":  # weights a test can tell apart
+            layer.update({"q_head_norm": 1.0 + w(n, hd, scale=0.2),
+                          "k_head_norm": 1.0 + w(n, hd, scale=0.2)})
         if cfg.attention_gate:
             layer["w_head_gate"] = w(n, h, nh)
         if mlp == "dense":
@@ -171,6 +197,9 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
                 "router": w(n, h, cfg.router_width),
                 "w_gate": w(n, E, h, fm), "w_up": w(n, E, h, fm),
                 "w_down": w(n, E, fm, h)})
+            if cfg.moe_scoring == "sigmoid":  # the choosing bias
+                layer["router_bias"] = 0.02 * jax.random.normal(
+                    next(ks), (n, cfg.router_width), jnp.float32)
             if cfg.n_shared_experts:
                 layer.update({"ws_gate": w(n, h, fs), "ws_up": w(n, h, fs),
                               "ws_down": w(n, fs, h)})
@@ -178,34 +207,112 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
     return params
 
 
+def _rows(cfg: ModelConfig):
+    """(row in the page pool, row in the slot pool) of each layer, as two
+    int32 tables [L]: a layer's rank among the layers of its mixer.  (None,
+    None) where every layer has pages and none a state: a layer's row is its
+    index."""
+    if not cfg.conv_layers:
+        return None, None
+    conv = np.asarray([k[0] == "conv" for k in cfg.layer_kinds])
+    return (np.cumsum(~conv, dtype=np.int32) - 1,
+            np.cumsum(conv, dtype=np.int32) - 1)
+
+
+def _row(table, layer):
+    """`table[layer]` for a layer index that is a loop's counter or a
+    number; `layer` itself without a table."""
+    if table is None:
+        return layer
+    return int(table[layer]) if isinstance(layer, int) else jnp.asarray(
+        table)[layer]
+
+
+def _short_conv(lp: Params, u: jax.Array, window: jax.Array,
+                chunk_lens: jax.Array, at: tuple):
+    """The gated short convolution over u [B, S, h] (normed) from a row's
+    carried `window` [B, K-1, h] (the inputs `B * u` of the K-1 positions
+    before the chunk) -> (out [B, S, h], window', [window after each of
+    `at`'s token counts]).  Positions at or past `chunk_lens` move no
+    window."""
+    dt = u.dtype
+    with jax.named_scope("sconv.in_proj"):
+        b, c, g = jnp.split(matmul_any(
+            u, lp["in_proj"], "bsh,hd->bsd").astype(dt), 3, axis=-1)
+    with jax.named_scope("sconv.conv"):
+        y, window, inside = ssm.conv(b * g, window, lp["conv_w"], None,
+                                     chunk_lens, at, act=None)
+        y = c * y
+    with jax.named_scope("sconv.out_proj"):
+        return (matmul_any(y, lp["out_proj"], "bsd,dh->bsh").astype(dt),
+                window, inside)
+
+
 def layers(params: Params, cfg: ModelConfig, kv: KVCache, x: jax.Array,
            positions: jax.Array, page_table: jax.Array,
            prefix_lens: jax.Array, chunk_lens: jax.Array,
            attn_impl: str = "xla", moe_stats: bool = False):
     """Every layer over an embedded chunk x [B, S, h], as `llama.
-    prefill_layers`: -> (x, kv, *stats).  The pool stays where it is: the
-    bodies read it by (layer, page), and ONE scatter lands every layer's
-    keys and values after the loop.  A decode step is a chunk of one."""
+    prefill_layers`: -> (x, kv, *stats).  The pools stay where they are: the
+    bodies read them by (row, page) and (row, slot), and ONE scatter lands
+    every attention layer's keys and values after the loop, one every conv
+    layer's windows.  With conv layers `page_table` carries the rows' state
+    slots (`hybrid.split_table`).  A decode step is a chunk of one."""
     ropes = rope_by_kind(cfg.head_dim_, cfg.rope_parameters)
     B, S, _ = x.shape
+    spec = cfg.state_spec
+    kv_rows, state_rows = _rows(cfg)
+    table, at = page_table, ()
+    if spec is not None:
+        table, slot_in, slot_out, slot_inside = split_table(page_table)
+        at = _inside(cfg, S, kv.page_size)
+
+    def put(buf, a, row):
+        return jax.lax.dynamic_update_index_in_dim(buf, a, row, 0)
+
+    def conv_layer(lp, h, row, stack, idx):
+        """A conv layer: h + short_conv(norm(h)), then the feed-forward;
+        -> (h, the window at the chunk's end and inside it as the pool
+        stores them, *stats)."""
+        with jax.named_scope("state.read"):
+            win = read_window(kv, spec, row, slot_in, slot_in == 0)
+        out, win, inside = _short_conv(
+            lp, rms_norm(h, lp["attn_norm"], cfg.rms_norm_eps), win,
+            chunk_lens, at)
+        h = h + out
+        y, *st = _feed_forward(lp, h, h, cfg, chunk_lens, moe_stats,
+                               (stack, idx))
+        return h + y, tuple(_as_tiles(w, spec.window_dims)
+                            for w in (win, *inside)), st
 
     def one(carry, run, idx, layer):
         """Layer `layer` of the model, `idx` in its kind's stack: the
         residual moves on, and what the layer leaves (the chunk's keys and
-        values, its moe stats) lands in row `layer` of the carry's
-        buffers."""
-        h, *bufs = carry
+        values or its windows, its moe stats) lands in the layer's row of
+        the carry's buffers."""
+        h, pages, windows, stats = carry
         stack = params[stack_of(run.kind)]
         lp = jax.tree.map(lambda a: a[idx], stack)
-        windowed = "sliding" in run.kind[0]
-        inv_freq, amplitude = ropes[run.kind[0]]
-        h, left = _layer_prefill(
-            lp, kv, layer, h, positions, page_table, prefix_lens, chunk_lens,
-            cfg, inv_freq, attn_impl,
-            window=cfg.sliding_window if windowed else None,
-            rope_scale=amplitude, moe_stats=moe_stats, stacks=(stack, idx))
-        return (h, *(jax.lax.dynamic_update_index_in_dim(buf, a, layer, 0)
-                     for buf, a in zip(bufs, left)))
+        if run.kind[0] == "conv":
+            row = _row(state_rows, layer)
+            h, wins, st = conv_layer(lp, h, row, stack, idx)
+            windows = tuple(put(buf, w, row) for buf, w in zip(windows, wins))
+        else:
+            row = _row(kv_rows, layer)
+            windowed = "sliding" in run.kind[0]
+            inv_freq, amplitude = ropes[run.kind[0]]
+            h, (k, v, *st) = _layer_prefill(
+                lp, kv, row, h, positions, table, prefix_lens, chunk_lens,
+                cfg, inv_freq, attn_impl,
+                window=cfg.sliding_window if windowed else None,
+                rope_scale=amplitude, moe_stats=moe_stats,
+                stacks=(stack, idx))
+            # (as the pool stores a token: `CacheSpec.plane_dims`)
+            pages = tuple(put(buf, a.reshape(buf.shape[1:]), row)
+                          for buf, a in zip(pages, (k, v)))
+        if moe_stats:
+            stats = put(stats, st[0], layer)
+        return h, pages, windows, stats
 
     def period(carry, seg, t):
         """Period `t` of a segment: one body a run, a run of several layers
@@ -222,16 +329,20 @@ def layers(params: Params, cfg: ModelConfig, kv: KVCache, x: jax.Array,
                     carry, jnp.arange(run.count, dtype=jnp.int32))
         return carry
 
-    # [L, ...] buffers in the carry, a row a layer: each layer's rows are
-    # written where they belong by the loops' counters (ys joined from runs
-    # and periods of different lengths took reshapes and concatenates, and a
-    # step of several rows then failed a check of the TPU compiler: AOT for
-    # a v5e, PR 52)
+    # [rows, ...] buffers in the carry, a row a layer of the pool they land
+    # in: each layer's rows are written where they belong by the loops'
+    # counters (ys joined from runs and periods of different lengths took
+    # reshapes and concatenates, and a step of several rows then failed a
+    # check of the TPU compiler: AOT for a v5e, PR 52)
     L = cfg.num_hidden_layers
-    carry = (x, *(jnp.zeros((L, B, S, *pool.shape[3:]), x.dtype)
-                  for pool in (kv.k, kv.v)))
-    if moe_stats:
-        carry += (jnp.zeros((L, moe_stats_columns(cfg)), jnp.int32),)
+    carry = (
+        x,
+        tuple(jnp.zeros((cfg.num_kv_layers, B, S, *pool.shape[3:]), x.dtype)
+              for pool in (kv.k, kv.v)),
+        tuple(jnp.zeros((spec.layers, B, *spec.window_dims), kv.conv.dtype)
+              for _ in (None, *at)) if spec is not None else (),
+        jnp.zeros((L, moe_stats_columns(cfg)), jnp.int32) if moe_stats
+        else None)
     for seg in plan(cfg.layer_kinds):
         if seg.periods == 1:
             carry = period(carry, seg, 0)
@@ -239,7 +350,14 @@ def layers(params: Params, cfg: ModelConfig, kv: KVCache, x: jax.Array,
             carry, _ = jax.lax.scan(
                 lambda c, t, seg=seg: (period(c, seg, t), None), carry,
                 jnp.arange(seg.periods, dtype=jnp.int32))
-    x, k_new, v_new, *st = carry
-    kv = KVCache(*write_kv_layers(kv.k, kv.v, k_new, v_new, page_table,
-                                  prefix_lens, _valid_rows(x, chunk_lens)))
-    return (x, kv, merge_moe_stats(st[0])) if moe_stats else (x, kv)
+    x, (k_new, v_new), windows, stats = carry
+    pages = write_kv_layers(kv.k, kv.v, k_new, v_new, table, prefix_lens,
+                            _valid_rows(x, chunk_lens))
+    if spec is None:
+        kv = KVCache(*pages)
+    else:
+        win, *inside = windows
+        conv, _ = write_states(kv, (win, None, *((w, None) for w in inside)),
+                               slot_out, slot_inside)
+        kv = kv._replace(k=pages[0], v=pages[1], conv=conv)
+    return (x, kv, merge_moe_stats(stats)) if moe_stats else (x, kv)

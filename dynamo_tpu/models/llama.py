@@ -95,7 +95,8 @@ class KVCache(NamedTuple):
         slots = (spec.layers, max(state_slots, 1))  # slot 0: trash
         return StateCache(
             *pages, jnp.zeros((*slots, *spec.window_dims), dtype),
-            jnp.zeros((*slots, *spec.state_dims), jnp.float32))
+            jnp.zeros((*slots, *spec.state_dims), jnp.float32)
+            if spec.recurrent else None)
 
 
 @partial(register_namedtuple_serialization,
@@ -106,12 +107,13 @@ class StateCache(NamedTuple):
     attention layers alone in `k` and `v` (`ModelConfig.num_kv_layers`),
     and beside them the state SLOTS of its state-space layers: `conv` [Lm,
     slots, tiles, 128] the convolution's window, `ssm` [Lm, slots, heads,
-    head_dim, state] the recurrent state in float32."""
+    head_dim, state] the recurrent state in float32; None where the state is
+    the window alone (`StateSpec.recurrent`: no second array, no leaf)."""
 
     k: jax.Array
     v: jax.Array
     conv: jax.Array
-    ssm: jax.Array
+    ssm: Optional[jax.Array]
 
     num_pages = KVCache.num_pages
     page_size = KVCache.page_size
@@ -164,6 +166,11 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
         layers["bo"] = w(next(ks), L, h, scale=0.02)
     if cfg.attention_sinks:  # gpt-oss learnable per-head sink logits
         layers["sinks"] = w(next(ks), L, nh, scale=1.0)
+    if cfg.qk_norm:  # weights that differ, so that a test sees them
+        # (keys folded in: the draws of every other tensor stay as they were)
+        for i, name in enumerate(("q_head_norm", "k_head_norm")):
+            layers[name] = 1.0 + w(jax.random.fold_in(key, 100 + i), L, hd,
+                                   scale=0.2)
     if cfg.is_moe:
         fm = cfg.moe_intermediate_size or f
         E = cfg.num_experts
@@ -350,6 +357,9 @@ def param_pspecs(cfg: ModelConfig, tp_axis: str = "tp", ep_axis: str = "tp") -> 
         layers["bo"] = P(None, None)
     if cfg.attention_sinks:
         layers["sinks"] = P(None, tp_axis)
+    if cfg.qk_norm:  # one weight a head VALUE: every shard's heads read it
+        layers["q_head_norm"] = P(None, None)
+        layers["k_head_norm"] = P(None, None)
     if cfg.is_moe:
         layers.update(
             {
@@ -425,6 +435,18 @@ def _qkv_proj(attn_in, lp: Params, cfg: ModelConfig, eq: str):
     return (_proj(attn_in, lp, "wq", "bq", eq),
             _proj(attn_in, lp, "wk", "bk", eq),
             _proj(attn_in, lp, "wv", "bv", eq))
+
+
+def _qk_norm(lp: Params, q: jax.Array, k: jax.Array, cfg: ModelConfig):
+    """The RMS norm over each head of q and of k [..., heads, head_dim]
+    (`ModelConfig.qk_norm`: a weight a head value, shared by the heads),
+    AFTER the projections and BEFORE the rope; a layer without the two
+    weights passes both as they are."""
+    if "q_head_norm" not in lp:
+        return q, k
+    with jax.named_scope("attn.qk_norm"):
+        return (rms_norm(q, lp["q_head_norm"], cfg.rms_norm_eps),
+                rms_norm(k, lp["k_head_norm"], cfg.rms_norm_eps))
 
 
 def _mlp(lp: Params, x: jax.Array) -> jax.Array:
@@ -539,7 +561,10 @@ def _route_grouped_sigmoid(lp: Params, logits: jax.Array, cfg: ModelConfig):
         biased.shape)
     _, selected = jax.lax.top_k(masked, cfg.num_experts_per_tok)
     chosen = jnp.take_along_axis(scores, selected, axis=-1)
-    weights = chosen / chosen.sum(-1, keepdims=True) * cfg.moe_routed_scale
+    total = chosen.sum(-1, keepdims=True)
+    if cfg.moe_norm_eps:  # lfm2_moe divides by the sum + 1e-6
+        total = total + cfg.moe_norm_eps
+    weights = chosen / total * cfg.moe_routed_scale
     return weights, selected
 
 
@@ -794,6 +819,16 @@ def merge_moe_stats(per_layer: jax.Array, hc_err: bool = False) -> jax.Array:
 #   matrix, top 6; 23 expert layers of 52; PR 54, finding 34: the kernel
 #   takes its stacks as they are stored, `pallas_moe.f_major`)
 #     18.4 / 18.6   21.8 / 20.6   21.5 / 21.6   24.3 / 25.4   41.9 / 40.0   30.1 / 34.3
+#   LFM2-24B-A2B (64 of 2048 x 1536, top 4; 1 dense + 8 expert layers under
+#   8,192-token tables; PR 55, median of 9; no 256 or 4 x 64 timed)
+#     16.1 / 16.0   16.3 / 16.5   31.2 / 16.9        -        48.9 / 19.3        -
+#   the same pair as Xing4.0's and SPLIT from it by its widths: under the
+#   checkpoint's router 16.0 / 11.6, 16.4 / 16.4, 31.4 / 17.2, 49.0 / 21.0;
+#   at 128 tokens and more the compiler copies the all-experts form's two
+#   [6, 64, 2048, 1536] stacks into another layout every step (2.25 GB each
+#   in the AOT compile: the program does not fit beside the cell's pool), at
+#   16 and 64 the balanced column is a tie within 0.2 ms and the
+#   checkpoint's router gives the dispatched form 4.4 ms at 16
 # (Xing's 64-token step is the dispatched form's by 0.5 ms under both
 # routers, Nemotron's by 1.2, and their 128-token ones nobody's; one boundary
 # a pair.  A share sorts, gathers and scatters EVERY assignment, 15 of 16 or
@@ -810,6 +845,13 @@ _TIMED = {
     (16, 8): (0, 256),
     (16, 6): (0, 256),
 }
+# A pair timed AGAIN at other widths, which read otherwise: (experts held,
+# experts a token, hidden, expert width) -> (few, most), before `_TIMED`'s
+# bounds for the pair.  Xing4.0's pair at LFM2-24B-A2B's widths (1 dense + 8
+# expert layers, PR 55): dispatched at EVERY size
+_TIMED_AT = {
+    (64, 4, 2048, 1536): (0, 0),
+}
 # A pair nobody timed keeps the bounds of the forms before the kernel (PR 31
 # and PR 52): all-experts up to 1,024 tokens (Mixtral-8x7B 8 of 4096 x 14336
 # top 2 and gpt-oss-20b 32 biased of 2880 x 2880 top 4, whose hidden size is
@@ -821,11 +863,16 @@ _ALL_EXPERTS_MAX_TOKENS = 1024
 _ALL_EXPERTS_MAX_ROWS = 131072
 
 
-def all_experts_form(held: int, top_k: int, tokens: int) -> bool:
+def all_experts_form(held: int, top_k: int, tokens: int,
+                     widths: Optional[tuple] = None) -> bool:
     """Does a step of `tokens` tokens run the all-experts matmul over its
-    `held` experts, `top_k` of them a token's own?"""
-    if (held, top_k) in _TIMED:
-        few, most = _TIMED[held, top_k]
+    `held` experts, `top_k` of them a token's own?  `widths`: the experts'
+    (hidden, width), for a pair that was timed at more than one."""
+    timed = _TIMED_AT.get((held, top_k, *widths)) if widths else None
+    if timed is None:
+        timed = _TIMED.get((held, top_k))
+    if timed is not None:
+        few, most = timed
         return few < tokens <= most
     return (tokens <= _ALL_EXPERTS_MAX_TOKENS
             and tokens * held <= _ALL_EXPERTS_MAX_ROWS)
@@ -839,7 +886,8 @@ def moe_form(cfg: ModelConfig, tokens: int) -> str:
     if cfg.moe_impl == "capacity":
         return "capacity"
     if cfg.moe_impl == "dense" or (cfg.moe_impl == "auto" and all_experts_form(
-            cfg.num_experts, cfg.num_experts_per_tok, tokens)):
+            cfg.num_experts, cfg.num_experts_per_tok, tokens,
+            (cfg.hidden_size, cfg.moe_intermediate_size))):
         return "all_experts"
     return "dispatched"
 
@@ -1154,6 +1202,7 @@ def _layer_prefill(
         q = q.astype(dt).reshape(B, S, -1, hd)
         k = k.astype(dt).reshape(B, S, nkv, hd)
         v = v.astype(dt).reshape(B, S, nkv, hd)
+        q, k = _qk_norm(lp, q, k, cfg)
         if rope_pos is not None:
             from ..ops import apply_mrope
 
@@ -1165,6 +1214,7 @@ def _layer_prefill(
     attn = prefill_attention(
         q, k, v, kv.k, kv.v, page_table, prefix_lens, chunk_lens,
         impl=attn_impl, window=window, sink=lp.get("sinks"), layer=layer,
+        packed=cfg.cache_spec.packed,
     )
     if "w_head_gate" in lp:
         attn = _head_gate(lp, attn_in, attn)
@@ -1238,6 +1288,7 @@ def _layer_decode(
         q = q.astype(dt).reshape(B, 1, nh, hd)
         k = k.astype(dt).reshape(B, 1, nkv, hd)
         v = v.astype(dt).reshape(B, 1, nkv, hd)
+        q, k = _qk_norm(lp, q, k, cfg)
         rp = positions if rope_pos is None else rope_pos
         q, k = _rope_qk(q, k, rp[:, None], inv_freq, rope_scale, rope_on)
         q = q[:, 0]
@@ -1344,7 +1395,7 @@ def require_plain_cache(cfg: ModelConfig, what: str) -> None:
             f"{what} does not carry latent pages, a dense-then-expert "
             f"layer stack or a multi-stream residual yet ({cfg.model_type}): "
             "serve this family flat on one chip (replicas: --dp-ranks)")
-    if cfg.layer_pattern is not None:
+    if cfg.layer_pattern is not None or cfg.state_spec is not None:
         raise ValueError(
             f"{what} does not carry a layer pattern, pages for some layers "
             f"only or state slots beside the pages yet ({cfg.model_type}): "
@@ -1352,24 +1403,33 @@ def require_plain_cache(cfg: ModelConfig, what: str) -> None:
 
 
 def require_one_layer_shape(cfg: ModelConfig, what: str) -> None:
-    """Layers of several shapes (`layer_heads`: laguna) are walked by the
-    one layer loop of `models/laguna.py`, which every flat step kind but
-    these rides: a path with a layer body, a weight layout or specs of its
-    own refuses the family at start-up, by the key that asks."""
-    if cfg.layer_kinds is not None:
+    """Layers of several shapes (`layer_heads`: laguna's head counts,
+    lfm2_moe's short convolutions beside attention) are walked by the one
+    layer loop of `models/laguna.py`, which every flat step kind but these
+    rides: a path with a layer body, a weight layout or specs of its own
+    refuses the family at start-up, by the key that asks."""
+    if cfg.layer_kinds is None:
+        return
+    if cfg.conv_layers:
         raise ValueError(
-            f"{what} does not carry head counts by layer "
-            f"(num_attention_heads_per_layer: {cfg.model_type}): its layers "
-            "have two shapes and only the flat engine's one layer loop "
-            "walks them; serve this family flat on one chip (replicas: "
+            f"{what} does not carry mixers by layer (layer_types 'conv': "
+            f"{cfg.model_type}): its layers are short convolutions beside "
+            "attention and only the flat engine's one layer loop walks "
+            "them; serve this family flat on one chip (replicas: "
             "--dp-ranks)")
+    raise ValueError(
+        f"{what} does not carry head counts by layer "
+        f"(num_attention_heads_per_layer: {cfg.model_type}): its layers "
+        "have two shapes and only the flat engine's one layer loop "
+        "walks them; serve this family flat on one chip (replicas: "
+        "--dp-ranks)")
 
 
 def require_no_state(cfg: ModelConfig, what: str) -> None:
-    """A step kind that would have to roll a recurrent state back, or that
+    """A step kind that would have to roll a sequence's state back, or that
     has its own layer body without one: refuse a family with state-space
-    layers by name, at start-up."""
-    if cfg.layer_pattern is not None:
+    layers or short convolutions by name, at start-up."""
+    if cfg.layer_pattern is not None or cfg.state_spec is not None:
         raise ValueError(
             f"{what} does not carry the recurrent state of state-space "
             f"layers ({cfg.model_type}): a state cannot be rolled back, or "
@@ -1926,6 +1986,7 @@ def decode_block_scan(
                 q = q.astype(dt).reshape(B, 1, nh, hd)
                 k = k.astype(dt).reshape(B, 1, nkv, hd)
                 v = v.astype(dt).reshape(B, 1, nkv, hd)
+                q, k = _qk_norm(lp, q, k, cfg)
                 q, k = _rope_qk(q, k, rp[:, None], inv_freq, rs,
                                 xs[-1] if ropes else None)
                 q, k, v = q[:, 0], k[:, 0], v[:, 0]

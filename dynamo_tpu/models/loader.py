@@ -90,6 +90,8 @@ def load_params(path: str, cfg: ModelConfig, dtype=jnp.bfloat16,
         return _load_phi4flash(r, cfg, dtype, prefix)
     if cfg.layer_pattern is not None:
         return _load_nemotron_h(r, cfg, dtype, prefix)
+    if cfg.model_type == "lfm2_moe":
+        return _load_lfm2_moe(r, cfg, dtype, prefix)
     if cfg.layer_kinds is not None:
         return _load_laguna(r, cfg, dtype, prefix)
 
@@ -128,6 +130,11 @@ def load_params(path: str, cfg: ModelConfig, dtype=jnp.bfloat16,
         )
     if cfg.attention_out_bias:  # gpt-oss biases o_proj too
         layers["bo"] = stack(p + "self_attn.o_proj.bias", transpose=False)
+    if cfg.qk_norm:  # qwen3's names; [head_dim] a layer
+        layers["q_head_norm"] = stack(p + "self_attn.q_norm.weight",
+                                      transpose=False)
+        layers["k_head_norm"] = stack(p + "self_attn.k_norm.weight",
+                                      transpose=False)
     if cfg.attention_sinks:  # gpt-oss sink logits — gate on the CONFIG
         # (like every other consumer) so params and cfg cannot disagree
         if not r.has(prefix + "model.layers.0.self_attn.sinks"):
@@ -363,6 +370,74 @@ def _load_laguna(r: "_ShardReader", cfg: ModelConfig, dtype, prefix: str = ""):
             if cfg.n_shared_experts:
                 layer.update(ffn(stack, "mlp.shared_expert.",
                                  ("ws_gate", "ws_up", "ws_down")))
+        params[name] = layer
+    return params
+
+
+def _load_lfm2_moe(r: "_ShardReader", cfg: ModelConfig, dtype,
+                   prefix: str = ""):
+    """lfm2_moe tensor names -> `models.laguna.init_params`'s stacks, one a
+    kind of layer (conv + dense, attention + experts, conv + experts).  The
+    names are the dense sibling's (transformers `models/lfm2`): `conv.
+    {in_proj, conv, out_proj}` (the taps [hidden, 1, K], tap j the input K-1-j
+    positions back: stored here [K, hidden]), `self_attn.{q,k,v,out}_proj`,
+    `self_attn.{q,k}_layernorm`, `operator_norm`, `ffn_norm`, a dense
+    layer's `feed_forward.{w1,w3,w2}` (gate, up, down), `model.
+    embedding_norm` (the FINAL norm); and for the expert block, which that
+    package does not have, the family's published names as known:
+    `feed_forward.gate` (the router), `feed_forward.expert_bias` [experts]
+    (kept float32: it only chooses), `feed_forward.experts.{e}.{w1,w3,w2}`."""
+    from .laguna import stacks_of
+
+    def stack_fn(ids):
+        def stack(name, fn=lambda w: w.T, dt=dtype):
+            return jnp.asarray(np.stack([fn(r.get(
+                prefix + f"model.layers.{i}.{name}")) for i in ids]), dt)
+
+        return stack
+
+    ffn = (("w_gate", "w1"), ("w_up", "w3"), ("w_down", "w2"))
+    params = {
+        "embed": jnp.asarray(r.get(prefix + "model.embed_tokens.weight"),
+                             dtype),
+        "final_norm": jnp.asarray(
+            r.get(prefix + "model.embedding_norm.weight"), dtype),
+    }
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = jnp.asarray(
+            r.get(prefix + "lm_head.weight").T, dtype)
+    for name, ((kind, mlp, _), ids) in stacks_of(cfg).items():
+        stack = stack_fn(ids)
+        layer = {"attn_norm": stack("operator_norm.weight", lambda w: w),
+                 "mlp_norm": stack("ffn_norm.weight", lambda w: w)}
+        if kind == "conv":
+            layer.update({
+                "in_proj": stack("conv.in_proj.weight"),
+                "conv_w": stack("conv.conv.weight", lambda w: w[:, 0, :].T),
+                "out_proj": stack("conv.out_proj.weight")})
+        else:
+            layer.update({
+                "wq": stack("self_attn.q_proj.weight"),
+                "wk": stack("self_attn.k_proj.weight"),
+                "wv": stack("self_attn.v_proj.weight"),
+                "wo": stack("self_attn.out_proj.weight"),
+                "q_head_norm": stack("self_attn.q_layernorm.weight",
+                                     lambda w: w),
+                "k_head_norm": stack("self_attn.k_layernorm.weight",
+                                     lambda w: w)})
+        if mlp == "dense":
+            layer.update({key: stack(f"feed_forward.{w}.weight")
+                          for key, w in ffn})
+        else:
+            layer["router"] = stack("feed_forward.gate.weight")
+            layer["router_bias"] = stack(
+                "feed_forward.expert_bias",
+                lambda w: w.astype(np.float32), jnp.float32)
+            for key, w in ffn:
+                layer[key] = jnp.stack([jnp.asarray(np.stack([r.get(
+                    prefix + f"model.layers.{i}.feed_forward.experts.{e}."
+                    f"{w}.weight").T for e in range(cfg.num_experts)]),
+                    dtype) for i in ids])
         params[name] = layer
     return params
 

@@ -55,7 +55,8 @@ import numpy as np
 from ..ops import gather_kv, layer_norm, ssm, write_kv_layers
 from ..ops.paged_attention import NEG_INF
 from .config import ModelConfig
-from .hybrid import _as_tiles, _inside, split_table, write_states
+from .hybrid import (_as_tiles, _inside, read_window, split_table,
+                     write_states)
 from .llama import Params, StateCache, _mlp, _valid_rows
 from .quantization import matmul_any
 
@@ -284,13 +285,10 @@ def self_layers(params: Params, cfg: ModelConfig, kv: StateCache,
     fresh = (slot_in == 0)
     q_w = cfg.num_attention_heads * cfg.head_dim_
     kv_w = cfg.num_key_value_heads * cfg.head_dim_
-    k1 = spec.conv_kernel - 1
 
     def mamba(h, lp, layer):
         with jax.named_scope("state.read"):
-            win = kv.conv[layer, slot_in].reshape(B, -1)[
-                :, :k1 * spec.conv_dim].reshape(B, k1, spec.conv_dim)
-            win = jnp.where(fresh[:, None, None], 0, win)
+            win = read_window(kv, spec, layer, slot_in, fresh)
             h0 = jnp.where(fresh[:, None, None], 0.0, kv.ssm[layer, slot_in])
         out, m, win, h1, inside = _mamba(
             lp, _normed(lp, cfg, h), cfg, win, h0, chunk_lens, page_size)

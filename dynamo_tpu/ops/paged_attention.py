@@ -340,6 +340,7 @@ def prefill_attention(
     window=None,  # scalar int (traced OK); <= 0 → full attention
     sink=None,  # [n_heads] learnable sink logits; None → plain softmax
     layer=None,  # scalar layer index (traced OK) into a whole pool
+    packed: bool = False,  # the pool's token is lane tiles (`CacheSpec`)
 ) -> jax.Array:
     """Chunk attends to cached prefix + itself (causal; optionally only
     the last `window` positions). Returns [B,S,H,hd].  The pool is only
@@ -347,13 +348,16 @@ def prefill_attention(
     loops), layer `layer`'s pages are fetched by (layer, page) and no slab
     is cut out of the pool."""
     B, S, n_heads, hd = q.shape
-    page, n_kv = k_pages.shape[-3], k_pages.shape[-2]
+    # (`packed`: a token's heads are whole lane tiles, several narrow heads
+    # a tile, `pallas_attention.packed_plane`: the same values in the same
+    # order, so the heads are counted on the chunk's own keys)
+    page, n_kv = k_pages.shape[-3], k_new.shape[2]
 
     def rule(ctx):
         from .pallas_attention import prefill_query_block
 
         return _prefill_rule(S, ctx, prefill_query_block(
-            S, n_heads, n_kv, hd, page, q.dtype, k_pages.dtype))
+            S, n_heads, n_kv, hd, page, q.dtype, k_pages.dtype, packed))
 
     impl = _adapt(impl, page_table, page, rule, site="prefill_attention",
                   chunk=S)
@@ -363,12 +367,16 @@ def prefill_attention(
         return prefill_attention_pallas(
             q, k_new, v_new, k_pages, v_pages, page_table, prefix_lens,
             chunk_lens, window=window, sink=sink, layer=layer,
+            packed=packed,
         )
     scale = 1.0 / jnp.sqrt(hd).astype(jnp.float32)
 
     # [B, Lp, n_kv, hd]
     k_pre, v_pre = gather_kv(k_pages, v_pages, page_table, layer)
     Lp = k_pre.shape[1]
+    if packed:
+        k_pre = k_pre.reshape(B, Lp, n_kv, hd)
+        v_pre = v_pre.reshape(B, Lp, n_kv, hd)
     i = jnp.arange(S)[None, None, :, None]
     # global query positions: prefix + row index within the chunk
     q_pos = prefix_lens[:, None, None, None] + i

@@ -370,8 +370,21 @@ def _vmem_bytes(shape, dtype) -> int:
             * itemsize)
 
 
+def packed_plane(n_kv: int, hd: int):
+    """[tiles, 128]: a token's `n_kv` heads of `hd` values side by side as
+    whole lane tiles, several heads a tile, where a head is narrower than a
+    tile and a token fills whole ones (8 heads of 64: [4, 128], head j the
+    lanes 64 (j % 2).. of tile j // 2).  The same values in the same order
+    as [n_kv, hd].  A pool stored so is read by the kernel as it is stored
+    (`page_plane`); stored [.., n_kv, 64] the compiler pads every head to a
+    tile, twice the bytes, and copies the whole pool into that form a step
+    (AOT compile for a v5e, PR 55: a 2.0 GB plane held 4.0 GB again)."""
+    return (n_kv * hd // 128, 128)
+
+
 def _prefill_residents(QB: int, S: int, H: int, n_kv: int, hd: int,
-                       page: int, dtype, pool_dtype=None):
+                       page: int, dtype, pool_dtype=None,
+                       packed: bool = False):
     """(blocked, scratch, temps): shape and dtype of everything one grid
     step of the prefill kernel holds in VMEM at a query block of QB tokens:
     the blocked operands and the scratch in the order
@@ -380,12 +393,13 @@ def _prefill_residents(QB: int, S: int, H: int, n_kv: int, hd: int,
     the cast probabilities).  q, o and the folded queries, softmax state
     and accumulator follow QB; the chunk's own K and V stay whole and the
     page buffers follow the page (and the pool's dtype, where that is not
-    the chunk's)."""
+    the chunk's).  `packed`: the pool's token is `packed_plane`."""
     C = _prefill_pages_per_step(page)
     f32 = jnp.float32
     pool_dtype = pool_dtype or dtype
     as_stored = hd % 128 == 0
-    page_shape = (page, n_kv, hd) if as_stored else (page, n_kv * hd)
+    page_shape = ((page, *packed_plane(n_kv, hd)) if packed else
+                  (page, n_kv, hd) if as_stored else (page, n_kv * hd))
     M = _prefill_folded_rows(QB, H // n_kv)
     blocked = [
         ((1, QB, H * hd), dtype),  # q
@@ -416,7 +430,7 @@ def prefill_resident_bytes(QB: int, *geom) -> int:
 
 
 def prefill_query_block(S: int, H: int, n_kv: int, hd: int, page: int,
-                        dtype, pool_dtype=None):
+                        dtype, pool_dtype=None, packed: bool = False):
     """Query tokens per grid step of the prefill kernel for a chunk of S
     tokens: the largest multiple of the row block that divides S, folds to
     no more than `_PREFILL_QUERY_ROWS` rows (the smallest candidate may)
@@ -432,7 +446,7 @@ def prefill_query_block(S: int, H: int, n_kv: int, hd: int, page: int,
                                  > _PREFILL_QUERY_ROWS):
             continue
         if prefill_resident_bytes(QB, S, H, n_kv, hd, page, dtype,
-                                  pool_dtype) <= _PREFILL_VMEM_BUDGET:
+                                  pool_dtype, packed) <= _PREFILL_VMEM_BUDGET:
             return QB
     return None
 
@@ -466,6 +480,7 @@ def _prefill_kernel(
     *,
     as_stored: bool,
     planes: bool,
+    tile_heads: int,  # KV heads a lane tile of the pool (`packed_plane`)
     C: int,
     page: int,
     n_kv: int,
@@ -525,6 +540,9 @@ def _prefill_kernel(
         arrives as it is stored, [page, n_kv, hd], one [n_kv, hd] per
         token; the pool itself is never re-laid-out in HBM.  (Narrow heads:
         the slab view has the heads side by side on lanes.)"""
+        if planes and tile_heads > 1:  # several heads a lane tile
+            at = kh % tile_heads * hd
+            return page_plane(scr, buf, kh // tile_heads, T)[:, at:at + hd]
         if planes:  # a head is one lane tile: one strided load
             return page_plane(scr, buf, kh, T)
         if as_stored:
@@ -650,6 +668,7 @@ def prefill_attention_pallas(
     window=None,  # scalar int; None/<=0 → full attention
     sink=None,  # [H] per-head sink logits; None → plain softmax
     layer=None,  # scalar layer index (traced OK) into a whole pool
+    packed: bool = False,  # the pool's token is `packed_plane`
     interpret: bool = False,
 ) -> jax.Array:
     """Chunked-prefill flash attention: streamed prefix pages + causal self
@@ -658,12 +677,24 @@ def prefill_attention_pallas(
     B, S, H, hd = q.shape
     if layer is None:  # one layer's pool: a pool of one layer
         k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
-    _, P, page, n_kv, _ = k_pages.shape
+    _, P, page = k_pages.shape[:3]
+    n_kv = k_new.shape[2]
     # A page can be fetched as it is stored only if a head fills whole
     # lanes: HBM pads the minor dimension to 128, and a DMA takes no part
-    # of a tile.  Narrower heads (hd 64) keep ONE slice + relayout of the
-    # layer's slab per layer, read-only (the stored layout: ROADMAP D3).
-    as_stored = hd % 128 == 0
+    # of a tile.  Narrower heads (hd 64) are read as stored from a pool whose
+    # token is whole lane tiles, several heads a tile (`packed`: the
+    # family's `CacheSpec` says so); stored [.., n_kv, hd] they keep ONE
+    # slice + relayout of the layer's slab per layer, read-only, which
+    # grows with the POOL (ROADMAP D3).
+    if packed and (k_pages.shape[3:] != packed_plane(n_kv, hd)
+                   or not page_planes_readable(*k_pages.shape[3:],
+                                               k_pages.dtype)):
+        raise ValueError(
+            f"a pool of {k_pages.shape[3:]} a token is not {n_kv} heads of "
+            f"{hd} in lane tiles the kernel reads")
+    as_stored = hd % 128 == 0 or packed
+    planes = as_stored and page_planes_readable(*k_pages.shape[3:],
+                                                k_pages.dtype)
     if not as_stored:
         k_pages = k_pages[layer].reshape(1, P, page, n_kv * hd)
         v_pages = v_pages[layer].reshape(1, P, page, n_kv * hd)
@@ -683,7 +714,7 @@ def prefill_attention_pallas(
     # the grid's second axis walks the chunk in query blocks: q, o and the
     # folded state are QB tokens whatever S is; the prefix is streamed once
     # per query block, the chunk's own K and V once per row of the batch
-    geom = (S, H, n_kv, hd, page, q.dtype, k_pages.dtype)
+    geom = (S, H, n_kv, hd, page, q.dtype, k_pages.dtype, packed)
     QB = prefill_query_block(*geom)
     if QB is None:
         raise ValueError(
@@ -718,7 +749,7 @@ def prefill_attention_pallas(
         _prefill_kernel,
         C=C, page=page, n_kv=n_kv, groups=groups, hd=hd, nc=padded // C,
         QB=QB, TS=_prefill_self_tile(S), as_stored=as_stored,
-        planes=page_planes_readable(n_kv, hd, k_pages.dtype),
+        planes=planes, tile_heads=128 // hd if packed else 1,
     )
     out = pl.pallas_call(
         kernel,

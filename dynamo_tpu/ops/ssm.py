@@ -5,7 +5,8 @@ the end: a decay for every channel AND state index, which has no block form).
 
 A sequence leaves such a layer TWO things, whatever its length: the
 convolution's last `kernel - 1` inputs (the window) and the recurrent state
-`H` [heads, head_dim, state] in float32.  Every function here takes them in
+`H` [heads, head_dim, state] in float32 (a gated short convolution, lfm2's
+mixer, is `conv` alone: the window is all it leaves).  Every function here takes them in
 and hands them out, so a prompt may be computed in chunks, one token at a
 time, or padded to a bucket: positions at or past a row's `lens` move
 neither (their step size is zeroed, and the window is taken where the real
@@ -31,30 +32,33 @@ import jax
 import jax.numpy as jnp
 
 
-def conv(xbc: jax.Array, window: jax.Array, w: jax.Array, b: jax.Array,
-         lens: jax.Array, at: tuple = ()):
-    """Causal depthwise convolution over the sequence, then silu.
+def conv(xbc: jax.Array, window: jax.Array, w: jax.Array, b,
+         lens: jax.Array, at: tuple = (), act=jax.nn.silu):
+    """Causal depthwise convolution over the sequence, then `act`.
 
     xbc [B, S, C] the layer's new inputs; window [B, K-1, C] the inputs
     before them (zeros before the sequence); w [K, C] (tap j multiplies the
-    input K-1-j positions back), b [C]; lens [B] real tokens a row; `at`
-    static token counts inside the chunk.
+    input K-1-j positions back), b [C] or None (no bias: lfm2's short
+    convolution); lens [B] real tokens a row; `at` static token counts
+    inside the chunk; `act` the activation (Mamba's silu) or None.
     -> (out [B, S, C], window' [B, K-1, C]: the last K-1 inputs at or
     before each row's last real token, and the same after each of `at`
     tokens: right for the rows that have that many real tokens)."""
     k1 = window.shape[1]
     S = xbc.shape[1]
     padded = jnp.concatenate([window.astype(xbc.dtype), xbc], axis=1)
-    acc = b.astype(jnp.float32)
+    acc = None if b is None else b.astype(jnp.float32)
     for j in range(k1 + 1):
-        acc = acc + (padded[:, j:j + S].astype(jnp.float32)
-                     * w[j].astype(jnp.float32))
+        tap = (padded[:, j:j + S].astype(jnp.float32)
+               * w[j].astype(jnp.float32))
+        acc = tap if acc is None else acc + tap
     # padded[b, lens + j], j < K-1, are the inputs lens-(K-1)+j .. lens-1
     new = jax.vmap(lambda p, n: jax.lax.dynamic_slice_in_dim(p, n, k1, 0))(
         padded, lens)
     inside = [padded[:, t:t + k1].astype(window.dtype) for t in at]
-    return (jax.nn.silu(acc).astype(xbc.dtype), new.astype(window.dtype),
-            inside)
+    if act is not None:
+        acc = act(acc)
+    return acc.astype(xbc.dtype), new.astype(window.dtype), inside
 
 
 def scan(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,

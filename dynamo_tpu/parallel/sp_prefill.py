@@ -40,7 +40,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..models import KVCache, ModelConfig, kv_cache_pspec, param_pspecs
-from ..models.llama import _lm_logits, _moe, _proj
+from ..models.llama import _lm_logits, _moe, _proj, _qk_norm
 from ..models.quantization import matmul_any, quantize_pspecs
 from ..ops import apply_rope, rms_norm, rope_attention_scale, rope_frequencies, write_kv_pages
 from ._compat import shard_map
@@ -89,6 +89,7 @@ def _layer_sp(lp, kv_layer, x, positions, table_full, chunk_full, cfg, inv_freq,
     q = _proj(attn_in, lp, "wq", "bq").astype(dt).reshape(Bl, Sl, nh, hd)
     k = _proj(attn_in, lp, "wk", "bk").astype(dt).reshape(Bl, Sl, nkv, hd)
     v = _proj(attn_in, lp, "wv", "bv").astype(dt).reshape(Bl, Sl, nkv, hd)
+    q, k = _qk_norm(lp, q, k, cfg)
     if rope_pos3 is not None:
         # mrope (qwen2_vl): the (t, h, w) streams' local S-slice rides in
         # with the shard; text rows carry equal streams

@@ -18,6 +18,14 @@ import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 LEFT_OUT = {"test_walk_long_context.py", "test_run_ends.py", "test_procs.py"}
+# ONE case left out since PR 55, by name: it holds eight `workloads` lists of
+# `BENCHMARK.json` to END in the Laguna cell (and four to hold it alone),
+# while `test_rows_readers.py` holds three of the same lists to name EVERY
+# cell: no cell after Laguna's can satisfy both, and a PR that is not a
+# `benchmark` PR may edit neither file (PERF.md section 7 (bh): the repair is
+# to assert by name, as 7 (k) did for the two before it)
+DESELECT = ("benchmark/tests/test_laguna_readers.py::"
+            "test_the_spec_lists_the_new_readers_for_the_new_cell_alone")
 FILES = sorted(
     os.path.basename(p)
     for p in glob.glob(os.path.join(ROOT, "benchmark", "tests", "test_*.py"))
@@ -31,7 +39,7 @@ def suite(tmp_path_factory):
     xml = str(tmp_path_factory.mktemp("benchmark_suite") / "junit.xml")
     run = subprocess.run(
         [sys.executable, "-m", "pytest", "-q", "-p", "no:cacheprovider",
-         "--junitxml", xml,
+         "--junitxml", xml, "--deselect", DESELECT,
          *(os.path.join("benchmark", "tests", f) for f in FILES)],
         cwd=ROOT, capture_output=True, text=True, timeout=600)
     assert os.path.exists(xml), run.stdout[-3000:] + run.stderr[-3000:]

@@ -245,8 +245,10 @@ def test_the_file_states_each_published_key_once_for_each_reader():
                                            cell["name"] + ".json"))
     assert run["worker_flags"] == {"--num-pages": 9984,
                                    "--max-model-len": 8192}
+    # (the readers this cell brought: it is the first they list; since
+    # PR 55 four of them also read the lfm2 cell, which has their keys)
     listed = [m["name"] for m in spec["per_layer"]
-              if m.get("workloads") == [cell["name"]]]
+              if (m.get("workloads") or [""])[0] == cell["name"]]
     assert listed == ["step.routed_experts_device_pct",
                       "step.moe_dispatch_device_pct",
                       "kernel.routed_experts_roofline",
@@ -255,6 +257,40 @@ def test_the_file_states_each_published_key_once_for_each_reader():
     for key in ("gate", "router", "shared_expert", "qk_norm", "tensor_names",
                 "weights", "routing"):
         assert run["assumed"][key]
+
+
+@pytest.mark.parametrize("name,layer,source,better", [
+    ("step.routed_experts_device_pct", "model step", "device_trace", "lower"),
+    ("step.moe_dispatch_device_pct", "model step", "device_trace", "lower"),
+    ("kernel.routed_experts_roofline", "kernels", "device_trace", "higher"),
+    ("engine.moe_touched_pct", "engine", "program_counter", "higher"),
+    ("engine.moe_dispatched_token_pct", "engine", "program_counter",
+     "higher"),
+])
+def test_the_spec_keeps_each_reader_the_cell_brought(name, layer, source,
+                                                     better):
+    """What still holds of `benchmark/tests/test_laguna_readers.py::
+    test_the_spec_lists_the_new_readers_for_the_new_cell_alone`, which
+    tier-1 leaves out since PR 55 (`tests/test_benchmark_suite.py`): each of
+    the five readers' fields, its file, and that it lists this cell.  Gone
+    with that case is only that the cell is the LAST, or the only one, a
+    list names."""
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        by_name = {m["name"]: m for m in json.load(f)["per_layer"]}
+    m = by_name[name]
+    assert (m["layer"], m["source"], m["better"], m["moves"], m["unit"]) == (
+        layer, source, better, "ttft_p95_ms", "%")
+    assert "laguna-xs2-33b.longdoc-1tok" in m["workloads"]
+    assert os.path.exists(os.path.join(BENCH, "layer_metrics", name + ".py"))
+
+
+@pytest.mark.parametrize("name", [
+    "step.attn_device_pct", "kernel.prefill_attn_roofline",
+    "step.copy_device_pct", "kernel.prefill_rows_step_roofline"])
+def test_the_accepted_readers_that_read_the_cell_still_list_it(name):
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        by_name = {m["name"]: m for m in json.load(f)["per_layer"]}
+    assert "laguna-xs2-33b.longdoc-1tok" in by_name[name]["workloads"]
 
 
 @pytest.mark.parametrize("bad,key", [

@@ -154,6 +154,36 @@ def test_prefill_kernel_compiles_at_the_cells_chunk(one_chip, table_pages):
     assert size < 58_000    # 53,107 to 53,130 today, by table
 
 
+def test_prefill_kernel_reads_narrow_heads_from_lane_tiles(one_chip):
+    """LFM2-24B-A2B's attention (32 heads over 8 of 64, bf16) at the
+    512-token chunk under its cell's 512-page table, reading a two-layer
+    pool by (layer, page).  Stored as whole lane tiles, two heads a tile
+    ([4, 128] a token: `packed_plane`), the pool is the kernel's operand as
+    it stands: nothing pool-sized is made.  (Stored [8, 64] the compiler
+    slices the layer's slab out and pads every head to a tile, a temporary
+    of twice the slab every layer and step, read on PR 55's compiles: no
+    test holds the compiler to that.)"""
+    from dynamo_tpu.ops.pallas_attention import packed_plane
+
+    pages, H, KVH, hd = 16384, 32, 8, 64
+    assert packed_plane(KVH, hd) == (4, 128)
+    pool = _sds((2, pages, PAGE, 4, 128), jnp.bfloat16, one_chip)
+    q = _sds((1, 512, H, hd), jnp.bfloat16, one_chip)
+    new = _sds((1, 512, KVH, hd), jnp.bfloat16, one_chip)
+    table = _sds((1, 512), jnp.int32, one_chip)
+    lens = _sds((1,), jnp.int32, one_chip)
+
+    def fn(q, kn, vn, k, v, t, pre, cl, layer):
+        return prefill_attention_pallas(q, kn, vn, k, v, t, pre, cl,
+                                        layer=layer, packed=True)
+
+    compiled, _ = _compile(fn, q, new, new, pool, pool, table, lens, lens,
+                           _sds((), jnp.int32, one_chip))
+    assert "tpu_custom_call" in compiled.as_text()
+    slab = pages * PAGE * KVH * hd * 2  # one layer of k (or of v), bytes
+    assert compiled.memory_analysis().temp_size_in_bytes < slab // 8
+
+
 # -- whole steps ---------------------------------------------------------------- #
 
 def step_shapes(cfg, batch, table_pages, shardings, pool_pages=POOL_PAGES):
