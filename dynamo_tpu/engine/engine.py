@@ -103,6 +103,10 @@ class ForwardPassMetrics:
     prefill_rows_total: int = 0
     prefill_steps_overlapped_total: int = 0
     prefill_steps_headless_total: int = 0
+    # steps of every kind dispatched to a device that had run dry: no
+    # earlier program of this engine was still running once the step's own
+    # was handed over (`dry` 1 on the step's slice: `_dispatched_dry`)
+    steps_dry_total: int = 0
     # device-resident decode loop: chains run and blocks dispatched by
     # the continuous path (blocks/chains >> decode_chain means the open
     # horizon is actually engaging)
@@ -361,9 +365,6 @@ class JaxEngine:
         self._ttft_queue_wait_ms_total = 0.0
         self._ttft_prefill_ms_total = 0.0
         self._ttft_attributed_total = 0
-        # optional dispatch trace (tests / debugging): set to a list and
-        # every device dispatch appends {kind, n_steps, pending}
-        self.dispatch_trace: Optional[List[dict]] = None
         # step-event ring (runtime.events): admit/plan/step/rung/spec/pool
         # events with monotonic-ns stamps — dumped by the worker debug
         # endpoint and merged into the Perfetto timeline.  Scheduler and
@@ -392,10 +393,19 @@ class JaxEngine:
         self._xprof_started_at: Optional[int] = None
         self._xprof_done = self._xprof_steps <= 0
         # ring clock (monotonic ns): start of the step the step thread is
-        # in, and the instant the last step returned (the pump's
-        # `loop_yield` slice starts there)
+        # in; and whether a step has returned since the pump's last record
+        # (what follows, up to the loop's top, is a `loop_yield`)
         self._step_t0_ns: Optional[int] = None
-        self._step_end_ns: Optional[int] = None
+        self._stepped = False
+        # the instant up to which the step loop's account is written: the
+        # end of the last phase either thread recorded (the pump and the
+        # step thread never run at once).  The next phase starts here, so
+        # the phases tile (docs/observability.md, "The loop's account
+        # tiles"); a step's `hop_us` is its slice's start less this
+        self._acct_ns = self.events.now()
+        # step programs handed to the device so far: the next one's `seq`
+        self._dispatch_seq = 0
+        self.steps_dry_total = 0
         # the end of the last step slice recorded: `first_token.own_us`
         # counts a step from here on where its slice opened earlier
         self._slice_end_ns = 0
@@ -734,6 +744,7 @@ class JaxEngine:
             prefill_steps_overlapped_total=(
                 self.prefill_steps_overlapped_total),
             prefill_steps_headless_total=self.prefill_steps_headless_total,
+            steps_dry_total=self.steps_dry_total,
             decode_cc_blocks_total=self._cc_blocks_total,
             decode_cc_chains_total=self._cc_chains_total,
             decode_cc_fallout_total=dict(self._cc_fallout_by_reason),
@@ -1042,9 +1053,16 @@ class JaxEngine:
                         self._deliver(seq, [], "cancelled")
         return self.scheduler.schedule()
 
+    def _pump_span(self) -> dict:
+        """`t0_ns` and `t1_ns` of a pump slice: from where the loop's
+        account ends (`_acct_ns`) to now, which is where it ends next."""
+        t0, self._acct_ns = self._acct_ns, self.events.now()
+        return {"t0_ns": t0, "t1_ns": self._acct_ns}
+
     async def _consume_inflight(self, loop) -> None:
         """Fetch and deliver the prefill step in flight, if there is one:
-        before anything that is not a further plain prefill step."""
+        before anything that is not a further plain prefill step.  The
+        hand-off to the step thread is on the slice as `fetch_hop_us`."""
         step = self._inflight
         if step is None:
             return
@@ -1058,12 +1076,13 @@ class JaxEngine:
 
     async def _pump(self) -> None:
         loop = asyncio.get_running_loop()
+        self._acct_ns = self.events.now()
         while not self._closed:
-            if self._step_end_ns is not None:
+            if self._stepped:
                 # the executor hop back to this thread and the
                 # asyncio.sleep(0) in which the worker's other coroutines ran
-                self.events.record("loop_yield", t0_ns=self._step_end_ns)
-                self._step_end_ns = None
+                self.events.record("loop_yield", **self._pump_span())
+                self._stepped = False
             if self._pending_ops or (self.tiered is not None
                                      and self.tiered.pending_offloads):
                 await self._consume_inflight(loop)
@@ -1075,6 +1094,8 @@ class JaxEngine:
                     )
                 except Exception:  # noqa: BLE001
                     logger.exception("kv offload failed")
+                self.events.record("pump_op", **self._pump_span(),
+                                   op="offload")
             # run queued device ops (KV export/import for disagg)
             while self._pending_ops:
                 op, fut = self._pending_ops.pop(0)
@@ -1085,11 +1106,13 @@ class JaxEngine:
                 except Exception as e:  # noqa: BLE001
                     if not fut.done():
                         fut.set_exception(e)
-            t_plan = self.events.now()
+                self.events.record("pump_op", **self._pump_span(),
+                                   op="device_op")
             admits = self.events.kind_totals.get("admit", 0)
             plan = self._plan_step()
             self.events.record(
-                "plan", t0_ns=t_plan, waiting=len(self.scheduler.waiting),
+                "plan", **self._pump_span(),
+                waiting=len(self.scheduler.waiting),
                 running=len(self.scheduler.running),
                 admitted=self.events.kind_totals.get("admit", 0) - admits)
             for seq in self.scheduler.drain_errored():
@@ -1125,6 +1148,7 @@ class JaxEngine:
                         # backpressured (drain thread busy) a sleep(0)
                         # loop would spin the step thread hot
                         await asyncio.sleep(0.002)
+                        self.events.record("idle_wait", **self._pump_span())
                         continue
                     # shutdown() may have set _closed (and _wake) while this
                     # iteration was suspended in an executor await — e.g. the
@@ -1133,11 +1157,12 @@ class JaxEngine:
                     # shutdown
                     if self._closed:
                         break
-                    t_idle = self.events.now()
                     self._wake.clear()
                     await self._wake.wait()
-                    self.events.record("idle_wait", t0_ns=t_idle)
+                    self.events.record("idle_wait", **self._pump_span())
                 else:
+                    # work that no step can take yet: the yield is one
+                    self._stepped = True
                     await asyncio.sleep(0)
                 continue
             if not self._xprof_done and self._xprof_started_at is None:
@@ -1448,23 +1473,41 @@ class JaxEngine:
             offs.append(len(flat))
         return [np.asarray(flat, np.int32), np.asarray(offs, np.int64)]
 
-    def _note_dispatch(self, kind: str, n_steps: int = 0,
-                       blocks: int = 1) -> None:
-        """Account one device dispatch: rung histogram (decode-bearing
-        kinds; a chained run counts once per block) + the optional
-        dispatch trace.  The ring's record of the dispatch is the step
-        slice that follows."""
+    def _note_dispatch(self, n_steps: int = 0, blocks: int = 1) -> int:
+        """Account one device dispatch of `blocks` step programs: the rung
+        histogram (decode-bearing kinds, `n_steps`; a chained run counts
+        once per block) and the dispatch ordinal.  Returns the `seq` of
+        its first program: the engine's step programs run on the device in
+        this order, so the n-th one on a device trace's program line is
+        the one that took ordinal n (the ring's record of the dispatch is
+        the step slice that follows, which carries it)."""
         if n_steps:
             self._rung_dispatches[n_steps] = (
                 self._rung_dispatches.get(n_steps, 0) + blocks
             )
             xla_ledger.note_decode_block(blocks)
-        if self.dispatch_trace is not None:
-            self.dispatch_trace.append({
-                "kind": kind, "n_steps": n_steps, "blocks": blocks,
-                "pending": self.scheduler.prompts_pending(),
-                "t": time.monotonic(),
-            })
+        seq = self._dispatch_seq
+        self._dispatch_seq += blocks
+        return seq
+
+    def _step_begins(self) -> Tuple[int, int]:
+        """(t0, hop_us) at a step slice's start: the ring's clock, and the
+        hand-off that brought the step here, from where the loop's account
+        ended (the pump's last record) to t0."""
+        t0 = self._step_t0_ns = self.events.now()
+        return t0, (t0 - self._acct_ns) // 1000
+
+    def _dispatched_dry(self, dry: bool) -> int:
+        """`dry` of a step slice, counted: 1 if no earlier program of this
+        engine was still running once the step's own was handed to the
+        device, so that the device stood idle before it could start.  The
+        caller asks its unfetched results `is_ready()` (a query, never a
+        wait) when its jitted call has RETURNED: the call takes 3.5 ms on a
+        v5e host and the device runs dry inside it twenty times as often as
+        before it (PERF.md finding 37).  A step dispatched with nothing
+        unfetched is dry by construction."""
+        self.steps_dry_total += dry
+        return int(dry)
 
     def _run_step(self, kind: str, run, arg) -> None:
         """One engine step on the step thread, under a profiler annotation
@@ -1475,7 +1518,7 @@ class JaxEngine:
                 run(arg)
         finally:
             self._step_t0_ns = None
-            self._step_end_ns = time.monotonic_ns()
+            self._stepped = True
 
     def _decode_moe_form(self, rows: int) -> dict:
         """{"moe_form": ...} of a decode step over `rows` rows, one token
@@ -1566,7 +1609,7 @@ class JaxEngine:
         t_fetch = t_got if t_fetch is None else t_fetch
         t_call = t_fetch if t_call is None else t_call
         self._credit_own(seqs, t_end - max(t0, self._slice_end_ns))
-        self._slice_end_ns = t_end
+        self._slice_end_ns = self._acct_ns = t_end
         # the slice ends at this reading (`StepEventRecorder.record`), so
         # the parts add up to it: a step thread that loses the CPU between
         # here and the record call leaves no hole in the step's account
@@ -1633,8 +1676,8 @@ class JaxEngine:
             self._prefill_consume(step)
 
     def _prefill_dispatch(self, items: List[PrefillItem]) -> "_PrefillStep":
-        t0_ev = self._step_t0_ns = self.events.now()
-        self._note_dispatch("prefill")
+        t0_ev, hop_us = self._step_begins()
+        ordinal = self._note_dispatch()
         item_rows = self._prefill_rows(items)
         B = len(item_rows)
         seq_rows = [it.seq if it else None for it in item_rows]
@@ -1681,6 +1724,8 @@ class JaxEngine:
             tokens, table, prefix, chunk, samp, seeds, counters, with_top,
             mm=mm, owner=owner, greedy=greedy, samples=samples,
         )
+        dry = self._dispatched_dry(self._inflight is None
+                                   or self._inflight.packed_d.is_ready())
         # start the host copy of the prefill result BEFORE the fused
         # decode dispatches enqueue: on a FIFO-ish transfer path the copy
         # then rides right behind the prefill, keeping TTFT at prefill
@@ -1717,21 +1762,28 @@ class JaxEngine:
         cross = ({"cross_rows": head * len(items)}
                  if self.model_cfg.cross_decoder else {})
         self.cross_rows_total += cross.get("cross_rows", 0)
+        attrs = dict(
+            batch=len(items),
+            tokens=int(sum(it.chunk_len for it in items)),
+            fused_blocks=len(fused) if fused else 0,
+            ctx=int((prefix + chunk).max()),
+            pages=table.shape[1] - self.layout.state_cols,
+            bucket=chunk_bucket,
+            attn=self._attn_of("prefill_attention", B, chunk_bucket,
+                               table.shape[1]),
+            overlapped=overlapped, head=head, seq=ordinal, dry=dry,
+            hop_us=hop_us, **cross)
+        if len(items) > 1:
+            # a shared step: each row's own chunk and context, in row
+            # order (`tokens` is their sum, `ctx` the longest)
+            attrs["toks"] = [it.chunk_len for it in items]
+            attrs["ctxs"] = [it.chunk_start + it.chunk_len for it in items]
+        # the dispatch half ends at the reading the account goes on from
+        t_sent = self._acct_ns = self.events.now()
         return _PrefillStep(
             items=items, item_rows=item_rows, seq_rows=seq_rows, seqs=seqs,
             with_top=with_top, packed_d=packed_d, fused=fused,
-            t0_ns=t0_ev, t_call_ns=t_call, t_sent_ns=self.events.now(),
-            attrs=dict(
-                batch=len(items),
-                tokens=int(sum(it.chunk_len for it in items)),
-                fused_blocks=len(fused) if fused else 0,
-                ctx=int((prefix + chunk).max()),
-                pages=table.shape[1] - self.layout.state_cols,
-                bucket=chunk_bucket,
-                attn=self._attn_of("prefill_attention", B, chunk_bucket,
-                                   table.shape[1]),
-                overlapped=overlapped, head=head, **cross),
-        )
+            t0_ns=t0_ev, t_call_ns=t_call, t_sent_ns=t_sent, attrs=attrs)
 
     def _meet_short_prefill(self, rows: int, with_top: bool, greedy: bool,
                             width: int) -> None:
@@ -1749,6 +1801,7 @@ class JaxEngine:
         if key in self._short_prefill_met:
             return
         self._short_prefill_met.add(key)
+        self._note_dispatch()  # the ordinal after the step's own
         pad = [None] * self.layout.pad_batch(
             self.cfg.prefill_batch_size if rows == 1 else 1)
         self._dispatch_prefill(
@@ -1774,8 +1827,14 @@ class JaxEngine:
         # fused decode chain keeps them back, as before
         aborted = self.scheduler.deferred_free or []
         self.scheduler.deferred_free = [] if step.fused else None
+        fetch_hop_us = 0
         try:
             t_fetch = self.events.now()
+            # what brought the fetch here since the loop's account ended:
+            # the pump's hand-off (`_consume_inflight`), or the return from
+            # the dispatch half of the step behind this one (its operands
+            # are released there)
+            fetch_hop_us = (t_fetch - self._acct_ns) // 1000
             # lint: allow(device-get): prefill results are consumed on-step by design — decode, not prefill, is the latency path
             packed = np.asarray(jax.device_get(step.packed_d))
             t_got = self.events.now()
@@ -1818,6 +1877,8 @@ class JaxEngine:
                 attrs["rid"] = step.items[0].seq.request_id
             if step.fused:
                 attrs["n_steps"] = int(step.fused[0].shape[0])
+            if fetch_hop_us:
+                attrs["fetch_hop_us"] = fetch_hop_us
             self.events.record(
                 "prefill_chunk", t0_ns=step.t0_ns, **attrs,
                 **self._step_phases(step.seqs, step.t0_ns, step.t_call_ns,
@@ -1881,7 +1942,7 @@ class JaxEngine:
         while (allow_chain and chain_len < max(1, self.cfg.decode_chain)
                and self._chain_ok(seqs, chain_len, T, hard_cap)):
             chain_len += 1
-        self._note_dispatch("fused", T, blocks=chain_len)
+        self._note_dispatch(T, blocks=chain_len)
         positions = np.zeros((B,), np.int32)
         decode_ctr = np.zeros((B,), np.int32)
         for i, s in enumerate(seqs):
@@ -2007,7 +2068,7 @@ class JaxEngine:
         plan).  Decode rows' pages were reserved preemptively at planning;
         prefill rows extended non-preemptively, so the two sides cannot
         invalidate each other."""
-        t0_ev = self._step_t0_ns = self.events.now()
+        t0_ev, hop_us = self._step_begins()
         items, dseqs = plan.prefill, plan.decode
         # prefill side (same array construction as _run_prefill)
         item_rows = self._prefill_rows(items)
@@ -2039,7 +2100,7 @@ class JaxEngine:
         # chunk (or the next waiting prompt) rides the following dispatch
         # one short block from now
         T, _ = self.scheduler.select_decode_rung()
-        self._note_dispatch("mixed", T)
+        ordinal = self._note_dispatch(T)
         if self.layout.lockstep:
             sparse = (self._encode_counts_sparse(d_rows)
                       if penalized else None)
@@ -2101,7 +2162,8 @@ class JaxEngine:
             bucket=chunk_bucket,
             attn=self._attn_of("prefill_attention", Bp, chunk_bucket,
                                p_table.shape[1]),
-            head=head, **moe_attrs,
+            head=head, seq=ordinal, dry=self._dispatched_dry(True),
+            hop_us=hop_us, **moe_attrs,
             **self._step_phases(pseqs + dseqs, t0_ev, t_call, t_fetch,
                                 t_got),
         )
@@ -2466,8 +2528,8 @@ class JaxEngine:
         fetch and are consumed through the ordinary per-token stop
         path (variable acceptance == variable tokens per dispatch)."""
         k = self.cfg.speculative_ngram_k
-        t0_ev = self._step_t0_ns = self.events.now()
-        self._note_dispatch("spec")
+        t0_ev, hop_us = self._step_begins()
+        ordinal = self._note_dispatch()
         rows = self._decode_rows(seqs)
         B = len(rows)
         tokens = np.zeros((B, k + 1), np.int32)
@@ -2536,7 +2598,8 @@ class JaxEngine:
             "spec_round", t0_ns=t0_ev, k=k,
             batch=len(seqs), drafted=drafted, accepted=accepted,
             ctx=int(positions.max()) + k + 1, pages=table.shape[1],
-            bucket=B, **moe_attrs,
+            bucket=B, seq=ordinal, dry=self._dispatched_dry(True),
+            hop_us=hop_us, **moe_attrs,
             **self._step_phases(seqs, t0_ev, t_call, t_fetch, t_got))
 
     def _dispatch_spec(self, tokens, positions, counters, table, samp,
@@ -2574,7 +2637,7 @@ class JaxEngine:
         # full blocks while the prompt queue is empty, the shortest rung
         # (chaining suppressed) while prompts are pending, so a waiting
         # prompt rides the next mixed dispatch within one short block
-        t0_ev = self._step_t0_ns = self.events.now()
+        t0_ev, hop_us = self._step_begins()
         T, allow_chain = self.scheduler.select_decode_rung()
         if (allow_chain and self.cfg.decode_continuous
                 and self.layout.runs_continuous):
@@ -2582,7 +2645,7 @@ class JaxEngine:
             # ladder's quiet-ramp top rung is where open-ended chaining
             # engages; short rungs (prompts pending) keep the per-
             # dispatch path so admission latency is unchanged
-            return self._run_decode_continuous(seqs, T)
+            return self._run_decode_continuous(seqs, T, t0_ev, hop_us)
         hard_cap = self.cfg.hard_cap
         # decide the chain length upfront and pre-reserve pages for the
         # whole horizon, so ONE page table serves every block: chained
@@ -2593,7 +2656,7 @@ class JaxEngine:
         while (allow_chain and chain_len < max(1, self.cfg.decode_chain)
                and self._chain_ok(seqs, chain_len, T, hard_cap)):
             chain_len += 1
-        self._note_dispatch("decode", T, blocks=chain_len)
+        ordinal = self._note_dispatch(T, blocks=chain_len)
         rows = self._decode_rows(seqs)
         Bb = len(rows)
         tokens, positions = self._decode_arrays(rows)
@@ -2647,6 +2710,8 @@ class JaxEngine:
                 pages=table.shape[1] - self.layout.state_cols, bucket=Bb,
                 attn=self._attn_of("decode_attention", Bb, 1,
                                    table.shape[1]),
+                seq=ordinal, dry=self._dispatched_dry(True),
+                hop_us=hop_us,
                 **self._decode_moe_form(Bb),
                 **({"rid": seqs[0].request_id} if len(seqs) == 1 else {}),
                 **self._step_phases(seqs, t0_ev, t_call,
@@ -2931,7 +2996,8 @@ class JaxEngine:
         return toks, rem, smp, rst, ipos, ibud
 
     @affine("step")
-    def _run_decode_continuous(self, seqs: List[Sequence], T: int) -> None:
+    def _run_decode_continuous(self, seqs: List[Sequence], T: int,
+                               t0_ev: int, hop_us: int) -> None:
         """The device-resident decode inner loop (docs/device_loop.md):
         an OPEN-ENDED chain of decode blocks whose varying inputs (last
         token, positions, counters, active mask, budgets, penalty
@@ -2941,7 +3007,12 @@ class JaxEngine:
         detected on device (active-row mask), so the host never
         re-checks per token; pages are pre-reserved `cc_horizon_blocks`
         ahead so one page table serves the rolling horizon; the chain
-        ends only on a fall-out signal or when every row finishes."""
+        ends only on a fall-out signal or when every row finishes.
+
+        In the loop's account the chain is its `decode_chain` slice, from
+        `_run_decode`'s start (`t0_ev`; `hop_us` is the hand-off before
+        it): inside it, the time before an iteration's slice is that
+        iteration's build, and what follows the last one is delivery."""
         from collections import deque as _deque
 
         rows = self._decode_rows(seqs)
@@ -2999,7 +3070,6 @@ class JaxEngine:
         # reader polling metrics() mid-chain sees the engaged loop
         # instead of zero until the teardown drain finishes
         self._cc_chains_total += 1
-        chain_t0 = self.events.now()
         try:
             while True:
                 # -- splice intake + chunk feed (host work BEFORE the
@@ -3048,6 +3118,9 @@ class JaxEngine:
                     act_d, budget_d, stops_d, table_d, samp_d, seeds_d,
                     *chunk_ops, *rope,
                 )
+                # one query a block still undrained, never a wait
+                dry = self._dispatched_dry(
+                    all(p.is_ready() for _, _, p in inflight))
                 self._start_host_copy(packed_d)
                 t_sent = self.events.now()
                 blocks += 1
@@ -3057,19 +3130,19 @@ class JaxEngine:
                 # the chain's trailing blocks drain) sees the blocks
                 # already issued instead of zero
                 self._cc_blocks_total += 1
-                self._note_dispatch("decode", T, blocks=1)
+                ordinal = self._note_dispatch(T, blocks=1)
                 # pair every drain future with the rows it was
                 # dispatched against: pre-splice blocks must consume
                 # against the row set that produced them
                 inflight.append(
                     (list(rows),
                      drain.submit(self._fetch_packed_cc, packed_d, Bb,
-                                  with_top)))
+                                  with_top), packed_d))
                 # double buffer: with two blocks undrained, consume the
                 # older one (its device_get overlapped this dispatch)
                 wait_ns = 0
                 while len(inflight) >= 2:
-                    rows_snap, fut = inflight.popleft()
+                    rows_snap, fut, _ = inflight.popleft()
                     t_wait = self.events.now()
                     fetched = fut.result()
                     wait_ns += self.events.now() - t_wait
@@ -3102,7 +3175,8 @@ class JaxEngine:
                     dispatch_us=(t_sent - t_iter) // 1000,
                     fetch_us=wait_ns // 1000,
                     deliver_us=(t_end - t_sent - wait_ns) // 1000,
-                    pages=table_d.shape[1], bucket=Bb,
+                    pages=table_d.shape[1], bucket=Bb, seq=ordinal,
+                    dry=dry,
                     **self._decode_moe_form(Bb), **attrs)
                 if fallout is not None:
                     break
@@ -3123,7 +3197,7 @@ class JaxEngine:
         finally:
             err = None
             while inflight:
-                rows_snap, fut = inflight.popleft()
+                rows_snap, fut, _ = inflight.popleft()
                 try:
                     self._consume_cc_block(fut.result(), rows_snap,
                                            with_top)
@@ -3136,9 +3210,10 @@ class JaxEngine:
             reason = fallout or "error"
             self._cc_fallout_by_reason[reason] = (
                 self._cc_fallout_by_reason.get(reason, 0) + 1)
-            self.events.record("decode_chain", t0_ns=chain_t0, rung=T,
-                               batch=len(seqs), blocks=blocks,
-                               fallout=reason)
+            t_end = self._acct_ns = self.events.now()
+            self.events.record("decode_chain", t0_ns=t0_ev, t1_ns=t_end,
+                               rung=T, batch=len(seqs), blocks=blocks,
+                               fallout=reason, hop_us=hop_us)
             if err is not None:
                 raise err
 

@@ -27,7 +27,7 @@ recorded event is also mirrored into fixed-size mmap-backed binary
 segments in that directory. The mmap pages are shared with the page
 cache, so a SIGKILL leaves whatever was already written readable — the
 black box that the in-memory ring (gone with the process) cannot
-provide. Each 256-byte record slot carries a trailing commit marker
+provide. Each 512-byte record slot carries a trailing commit marker
 written LAST, so a reader treats a torn final record as a clean prefix
 end, never as garbage (`load_flight_dir` / `scripts/postmortem.py`).
 
@@ -69,14 +69,17 @@ DEFAULT_CAPACITY = 4096
 FLIGHT_MAGIC = b"DYNFLTR1"
 FLIGHT_VERSION = 1
 FLIGHT_HEADER_SIZE = 4096
-FLIGHT_RECORD_SIZE = 256  # a step slice with its phases and a request id fits
+# a step slice fits with its phases, ordinal, hand-offs and request id: an
+# expert model's shared `prefill_chunk` is 430 bytes of kind and attributes
+# (256 held no `prefill_chunk` slice at all since the slices carry phases)
+FLIGHT_RECORD_SIZE = 512
 _FLIGHT_COMMIT = 0xA5  # written to the slot's LAST byte after the payload
 _HDR = struct.Struct("<8sIIIIqqH")  # magic ver rec_size n_slots pid wall mono service_len
 _REC = struct.Struct("<qqHH")  # t_ns dur_ns kind_len attr_len
 _REC_PAYLOAD_MAX = FLIGHT_RECORD_SIZE - _REC.size - 1  # minus commit byte
 _SEG_RE = re.compile(r"^flight-(\d+)-(\d+)\.seg$")
 
-DEFAULT_FLIGHT_SLOTS = 4096  # ~1 MiB/segment
+DEFAULT_FLIGHT_SLOTS = 4096  # ~2 MiB/segment
 DEFAULT_FLIGHT_KEEP = 4
 
 # one shared encoder: json.dumps with non-default kwargs constructs a

@@ -74,6 +74,34 @@ def call_ceiling(production: int) -> int:
     return production if contracts.checks_mode() == "off" else 10 * production
 
 
+def dispatches(engine) -> list:
+    """What an engine has handed to the device, read off its ring's step
+    slices in dispatch order (`seq`): one {"kind", "n_steps", "blocks",
+    "pending", "t"} a dispatch, `kind` "prefill" | "fused" | "mixed" |
+    "spec" | "decode" (a `prefill_chunk` with a fused decode chain is a
+    "prefill" and a "fused"), `pending` the `rung_select` made inside the
+    slice (whether prompts waited when the rung was chosen; False where
+    none was), `t` the slice's start in seconds.  A slice is written when
+    its step ENDS: a dispatch shows one step later than it was made."""
+    events = engine.events.snapshot()
+    rungs = [(t, a["pending"]) for t, _, k, a in events if k == "rung_select"]
+    kinds = {"prefill_chunk": "prefill", "mixed_step": "mixed",
+             "spec_round": "spec", "decode_block": "decode"}
+    out = []
+    for t, dur, kind, a in sorted(
+            (e for e in events if e[2] in kinds), key=lambda e: e[3]["seq"]):
+        pending = any(p for at, p in rungs if t <= at <= t + dur)
+        out.append({"kind": kinds[kind], "n_steps": a.get("n_steps", 0),
+                    "blocks": a.get("blocks", 1), "pending": pending,
+                    "t": t / 1e9})
+        if a.get("fused_blocks"):
+            out[-1]["n_steps"] = 0
+            out.append({"kind": "fused", "n_steps": a["n_steps"],
+                        "blocks": a["fused_blocks"], "pending": False,
+                        "t": t / 1e9})
+    return out
+
+
 @contextlib.asynccontextmanager
 async def local_control_plane() -> AsyncIterator[ControlPlaneServer]:
     server = await ControlPlaneServer().start()

@@ -28,6 +28,7 @@ from dynamo_tpu.engine import EngineConfig, JaxEngine
 from dynamo_tpu.engine.page_pool import PagePool
 from dynamo_tpu.engine.scheduler import SamplingOptions, Scheduler, Sequence
 from dynamo_tpu.models import init_params, tiny_config
+from dynamo_tpu.testing import dispatches
 
 
 @pytest.fixture(scope="module")
@@ -262,7 +263,6 @@ async def test_prompt_admitted_within_one_short_rung(setup):
     block or a chained run — and the decoded tokens match the
     fixed-block schedule."""
     async def drive(engine):
-        engine.dispatch_trace = trace = []
         first = asyncio.Event()
         outs = {}
 
@@ -273,8 +273,8 @@ async def test_prompt_admitted_within_one_short_rung(setup):
         async def watcher():
             # wait until the decode stream is genuinely running
             while not any(e["kind"] in ("decode", "fused")
-                          for e in trace):
-                await asyncio.sleep(0.01)
+                          for e in dispatches(engine)):
+                await asyncio.sleep(0.002)
             first.set()
 
         async def prefiller():
@@ -284,7 +284,7 @@ async def test_prompt_admitted_within_one_short_rung(setup):
 
         await asyncio.gather(decoder(), watcher(), prefiller())
         await engine.shutdown()
-        return outs, trace
+        return outs, dispatches(engine)
 
     laddered = make_engine(setup, decode_block_ladder=[1],
                            decode_chain=4, max_prefill_tokens=32)
@@ -357,7 +357,6 @@ async def test_continuous_chain_falls_out_on_mid_chain_admission(setup):
                          fuse_prefill_decode=False,
                          max_prefill_tokens=32, max_model_len=512,
                          num_pages=256)
-    engine.dispatch_trace = trace = []
 
     async def long_decode():
         return (await collect(
@@ -365,7 +364,7 @@ async def test_continuous_chain_falls_out_on_mid_chain_admission(setup):
 
     task = asyncio.ensure_future(long_decode())
     # wait until the continuous chain is genuinely in flight
-    while not any(e["kind"] == "decode" for e in trace):
+    while not any(e["kind"] == "decode" for e in dispatches(engine)):
         await asyncio.sleep(0.005)
     toks_b, _ = await collect(engine, req(list(range(1, 25)), max_tokens=4))
     assert len(toks_b) == 4
@@ -379,6 +378,7 @@ async def test_continuous_chain_falls_out_on_mid_chain_admission(setup):
     # the in-flight chain fell out on the admission-side signal...
     assert any(f in ("pending_work", "admit") for f in fallouts), fallouts
     # ...and the prompt rode a prefill-bearing dispatch
+    trace = dispatches(engine)
     assert any(e["kind"] in ("mixed", "prefill") for e in trace), trace
     await engine.shutdown()
 
@@ -402,17 +402,15 @@ async def test_splice_composes_with_ladder(setup):
 
     async def drive(engine):
         top = engine.cfg.block_ladder[-1]
-        engine.dispatch_trace = trace = []
         futs = [asyncio.ensure_future(collect(engine, r))
                 for r in base_reqs()]
         # wait for a top-rung decode dispatch: chaining (and therefore
         # the splice window) only exists there
         while not any(e["kind"] == "decode" and e["n_steps"] == top
-                      for e in trace):
+                      for e in dispatches(engine)):
             await asyncio.sleep(0.005)
         late = (await collect(engine, req(PROMPTS[1], max_tokens=6)))[0]
         rest = [r[0] for r in await asyncio.gather(*futs)]
-        engine.dispatch_trace = None
         return rest + [late]
 
     unified = make_engine(setup, decode_block_ladder=[1, 2, 4],

@@ -16,6 +16,7 @@ from dynamo_tpu.engine import EngineConfig, JaxEngine
 from dynamo_tpu.engine.page_pool import PagePool
 from dynamo_tpu.models import init_params, tiny_config
 from dynamo_tpu.runtime.engine import Context
+from dynamo_tpu.testing import dispatches
 
 
 @pytest.fixture(scope="module")
@@ -521,14 +522,14 @@ async def _drive_mid_chain_arrival(engine, base_reqs, arrival_req):
     reason) in submission order.  The arrival deterministically lands
     mid-chain — the splice (unified engine) or fall-out (split engine)
     path is exercised on every run, not just when timing cooperates."""
-    engine.dispatch_trace = trace = []
+    before = len(dispatches(engine))
     base = [asyncio.ensure_future(collect(engine, r)) for r in base_reqs]
-    while not any(e["kind"] == "decode" for e in trace):
+    while not any(e["kind"] == "decode"
+                  for e in dispatches(engine)[before:]):
         await asyncio.sleep(0.005)
     late = await collect(engine, arrival_req)
     out = list(await asyncio.gather(*base))
     out.append(late)
-    engine.dispatch_trace = None
     return out
 
 

@@ -256,6 +256,47 @@ def test_six_readers_loaded_by_path_read_the_workers_own_dump(served):
     assert {n: read[n](bare) for n in READERS} == dict.fromkeys(READERS)
 
 
+def test_the_host_account_readers_read_the_workers_own_dump(served):
+    """ISSUE 57's readers on the same worker's ring (REHEARSAL.json is an
+    existing file and cannot name them): the loop's own two need no trace,
+    the account tiles between the worker's `ready` and its last record, and
+    the readers that charge device idle time read nothing without one."""
+    sys.path.insert(0, BENCH)
+    try:
+        from lib import checkpoint as ckpt
+        from lib import hostline
+
+        names = ["host.cycle_ms_per_step", "engine.dry_dispatch_pct",
+                 "host.exposed_ms_per_step", "host.clock_slack_us"] + [
+            f"host.exposed_{s}_pct" for s in hostline.SHARE_NAMES]
+        read = {n: ckpt.load_module("layer_metrics", n).read for n in names}
+    finally:
+        sys.path.remove(BENCH)
+    events = [dict(e, ring="engine") for e in served["ring"]["events"]]
+    run = {"t0": served["t0"], "t1": served["t1"], "events": events,
+           "records": [], "trace": None}
+    got = {n: read[n](run) for n in names}
+    steps = [e for e in events if e["kind"] in hostline.STEP_KINDS
+             and served["t0"] * 1e9 <= e["t_ns"] + e["dur_ns"]
+             <= served["t1"] * 1e9]
+    assert steps and all(k in e for e in steps
+                         for k in ("seq", "dry", "hop_us"))
+    assert got["host.cycle_ms_per_step"] > 0
+    assert got["engine.dry_dispatch_pct"] == pytest.approx(
+        100.0 * sum(e["dry"] for e in steps) / len(steps))
+    assert {n: v for n, v in got.items() if n.startswith(
+        ("host.exposed", "host.clock"))} == dict.fromkeys(names[2:])
+    # between `ready` and the ring's last record every instant of the loop
+    # lies under a phase but the pump's start-up before its first plan
+    line = hostline.timeline(events)
+    a = min(e["t_ns"] for e in events if e["kind"] == "plan")
+    b = max(s[1] for s in line)
+    covered, overlapped = hostline.coverage(line, a, b)
+    assert overlapped == 0 and (b - a) - covered < 0.01 * (b - a)
+    m1 = served["metrics1"]
+    assert m1["steps_dry_total"] >= sum(e["dry"] for e in steps)
+
+
 def test_the_spec_lists_the_six_readers_under_one_layer():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         spec = json.load(f)

@@ -207,6 +207,31 @@ def test_flight_oversized_attrs_truncate_not_fail(tmp_path):
     assert d["events"][1]["i"] == 1
 
 
+def test_flight_holds_a_step_slice_whole(tmp_path):
+    """The longest step slice the engine writes (an expert model's shared
+    `prefill_chunk` with its phases, ordinal, hand-offs and rows) is read
+    back from the flight recorder attribute for attribute."""
+    fr = FlightRecorder(str(tmp_path), service="s", segment_slots=16)
+    rec = StepEventRecorder(capacity=16, flight=fr)
+    attrs = dict(
+        batch=4, tokens=256, fused_blocks=0, ctx=7168, pages=512, bucket=64,
+        attn="pallas", overlapped=1, head=1, seq=1234567, dry=0, hop_us=312,
+        toks=[64, 64, 64, 64], ctxs=[7168, 7104, 6400, 2048],
+        moe_assignments=24576, experts_hit=485, moe_max_load=502,
+        moe_form="dispatched", moe_rows="kernel", moe_local=3072,
+        hc_res_err_ppm=21000, fetch_hop_us=250, build_us=2576,
+        dispatch_us=3351, overlap_us=32645, fetch_us=23514, deliver_us=2672)
+    rec.record("prefill_chunk", t0_ns=StepEventRecorder.now() - 1000, **attrs)
+    single = dict(attrs, batch=1, rid="08c4a2c2f8b441cab1881fb56b957f95")
+    del single["toks"], single["ctxs"]
+    rec.record("prefill_chunk", t0_ns=StepEventRecorder.now() - 1000,
+               **single)
+    (d,) = load_flight_dir(str(tmp_path))
+    for got, want in zip(d["events"], (attrs, single)):
+        assert "truncated" not in got
+        assert {k: got[k] for k in want} == want
+
+
 def test_flight_from_env(tmp_path, monkeypatch):
     monkeypatch.delenv("DYN_TPU_FLIGHT_DIR", raising=False)
     assert FlightRecorder.from_env() is None
@@ -606,11 +631,13 @@ async def test_warm_step_records_what_it_recorded_and_a_cold_one_compiled(
     assert len(inside) == first[3]["compiled"]
     # warm: nothing born, and the slices are what they were
     assert [e for e in warm if e[2] == "program"] == []
-    step_attrs = {"build_us", "dispatch_us", "fetch_us", "deliver_us"}
+    step_attrs = {"build_us", "dispatch_us", "fetch_us", "deliver_us",
+                  "seq", "dry", "hop_us"}
     expected = {
         "prefill_chunk": step_attrs | {
             "batch", "tokens", "fused_blocks", "ctx", "pages", "bucket",
-            "attn", "overlapped", "head", "rid", "n_steps", "overlap_us"},
+            "attn", "overlapped", "head", "rid", "n_steps", "overlap_us",
+            "fetch_hop_us"},
         "decode_block": step_attrs | {
             "rung", "n_steps", "blocks", "batch", "chain", "ctx", "pages",
             "bucket", "attn", "rid"},
@@ -620,6 +647,8 @@ async def test_warm_step_records_what_it_recorded_and_a_cold_one_compiled(
         if e[2] in expected:
             seen[e[2]] |= set(e[3])
             assert set(e[3]) <= expected[e[2]], (e[2], set(e[3]))
+    # `fetch_hop_us` is absent where the hand-off took under a microsecond
+    seen["prefill_chunk"].add("fetch_hop_us")
     assert seen == expected
 
 

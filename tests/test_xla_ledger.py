@@ -18,6 +18,7 @@ import numpy as np
 import pytest
 
 from dynamo_tpu.analysis import xla_ledger
+from dynamo_tpu.testing import dispatches
 
 from test_block_ladder import PROMPTS, collect, make_engine, req, setup  # noqa: F401
 
@@ -513,7 +514,7 @@ async def test_splice_admission_zero_steady_state_compiles(setup):  # noqa: F811
     engine = make_engine(setup, decode_continuous=True, decode_chain=2)
 
     async def one_pass():
-        engine.dispatch_trace = trace = []
+        before = len(dispatches(engine))
         # long base budgets keep the chain live across the arrival's
         # whole chunked admission — the splice must happen mid-chain
         # even on a warm pass where a block is a few ms
@@ -521,11 +522,11 @@ async def test_splice_admission_zero_steady_state_compiles(setup):  # noqa: F811
             collect(engine, req(PROMPTS[i], max_tokens=120)))
             for i in (0, 3)] + [asyncio.ensure_future(
             collect(engine, req([4, 5, 6], max_tokens=120)))]
-        while not any(e["kind"] == "decode" for e in trace):
+        while not any(e["kind"] == "decode"
+                      for e in dispatches(engine)[before:]):
             await asyncio.sleep(0.005)
         await collect(engine, req(PROMPTS[1], max_tokens=4))
         await asyncio.gather(*base)
-        engine.dispatch_trace = None
 
     try:
         await one_pass()
