@@ -40,6 +40,7 @@ from ..ops.latent_attention import (
     rows_of,
 )
 from ..ops import hyper_connections as hc
+from ..ops import pallas_hyper_connections as pallas_hc
 from ..ops import pallas_moe
 from ..ops import (
     apply_rope,
@@ -1127,18 +1128,46 @@ def _residual(cfg: ModelConfig, lp: Params, half: str, x: jax.Array, f):
     """One half of a layer around the residual.  `f(u) -> (y, *aux)` is the
     half as a function of its input alone (its own norm inside).  A plain
     residual: u = x [B, S, h] and x' = x + y.  Hyper-connections (`half`
-    names the mixer, "hc_attn" | "hc_mlp"): x [B, S, n, h], u the mixer's
-    read of the streams, x' its write back (`ops/hyper_connections.py`).
-    -> (x', aux, err): err [B, S] the mix's distance from doubly stochastic,
-    None from a plain residual."""
+    names the mixer, "hc_attn" | "hc_mlp"): x [B, S, n x h] (`_streams`), u
+    the mixer's read of the streams, x' its write back: two Pallas kernels
+    a half on a single-device TPU trace (`ops/pallas_hyper_connections.py`,
+    one pass over the streams each), the `jnp` forms of
+    `ops/hyper_connections.py` elsewhere; the trace notes which
+    (`hc_mixers`).  -> (x', aux, err): err [B, S] the mix's distance from
+    doubly stochastic, None from a plain residual."""
     if not cfg.hc_mult:
         y, *aux = f(x)
         return x + y, aux, None
-    m = hc.mix(x, lp[half + "_phi"], lp[half + "_scale"], lp[half + "_base"],
-               iters=cfg.hc_sinkhorn_iters, eps=cfg.hc_eps,
+    n, lead = cfg.hc_mult, x.shape[:-1]
+    mixer = (lp[half + "_phi"], lp[half + "_scale"], lp[half + "_base"])
+    how = dict(iters=cfg.hc_sinkhorn_iters, eps=cfg.hc_eps,
                clamp=cfg.hc_res_clamp, rms_eps=cfg.rms_norm_eps)
-    y, *aux = f(hc.pre(x, m.pre))
-    return hc.post(x, y, m.post, m.res), aux, m.err
+    kernel, why = pallas_hc.lowering(x, n)
+    tokens = x.size // x.shape[-1]
+    xla_ledger.note_path_choice(
+        "hc_mixers", "xla" if kernel is None else "kernel", why,
+        tokens=tokens)
+    if kernel is None:
+        xs = x.reshape(*lead, n, -1)
+        m = hc.mix(xs, *mixer, **how)
+        y, *aux = f(hc.pre(xs, m.pre))
+        return hc.post(xs, y, m.post, m.res).reshape(x.shape), aux, m.err
+    flat = x.reshape(tokens, -1)
+    with jax.named_scope("hc.mix"):
+        u, w = pallas_hc.read(flat, *mixer, interpret=kernel, **how)
+    y, *aux = f(u.reshape(*lead, -1))
+    with jax.named_scope("hc.post"):
+        out = pallas_hc.write(flat, y.reshape(tokens, -1), w, n=n,
+                              interpret=kernel)
+    return (out.reshape(x.shape), aux,
+            w[:, pallas_hc.columns(n)[3]].reshape(lead))
+
+
+def hc_mixers(tokens: int) -> Optional[str]:
+    """Which form the stream mixers of a step of `tokens` tokens took, as
+    its trace noted (`_residual`): "kernel" | "xla"; None where no such
+    step was traced."""
+    return xla_ledger.path_choice("hc_mixers", tokens=tokens)
 
 
 def _hc_err_ppm(errs, valid) -> jax.Array:
@@ -1476,17 +1505,22 @@ def require_no_state(cfg: ModelConfig, what: str) -> None:
 
 def _streams(cfg: ModelConfig, x: jax.Array) -> jax.Array:
     """Embedded tokens [..., h] as the residual the layer loops carry:
-    themselves, or under hyper-connections every stream alike [..., n, h]."""
-    return hc.widen(x, cfg.hc_mult) if cfg.hc_mult else x
+    themselves, or under hyper-connections every stream alike, a token's n
+    streams side by side [..., n x h] (the one layout the mixers' kernels
+    take as a dense block, `ops/pallas_hyper_connections.py`)."""
+    if not cfg.hc_mult:
+        return x
+    return jnp.concatenate([x] * cfg.hc_mult, axis=-1)
 
 
 def _final_norm(params: Params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
     """The final norm of the residual after the last layer [..., h]; under
-    hyper-connections [..., n, h] the head's reduction of the streams
+    hyper-connections [..., n x h] the head's reduction of the streams
     comes first."""
     if cfg.hc_mult:
         x = hc.head_reduce(
-            x, params["hc_head_phi"], params["hc_head_scale"],
+            x.reshape(*x.shape[:-1], cfg.hc_mult, -1),
+            params["hc_head_phi"], params["hc_head_scale"],
             params["hc_head_base"], eps=cfg.hc_eps, rms_eps=cfg.rms_norm_eps)
     if "final_norm_bias" in params:  # a family of LayerNorms (phi4flash)
         return layer_norm(x, params["final_norm"], params["final_norm_bias"],
@@ -1703,8 +1737,8 @@ def forward_prefill(
 
     def head(x):
         last = jnp.maximum(chunk_lens - 1, 0)
-        at = last[:, None, None, None] if cfg.hc_mult else last[:, None, None]
-        x_last = jnp.take_along_axis(x, at, axis=1)[:, 0]  # [B, h] | [B, n, h]
+        x_last = jnp.take_along_axis(
+            x, last[:, None, None], axis=1)[:, 0]  # [B, h] | [B, n x h]
         if handed is not None:
             # no cross layer mixes positions but through what the self half
             # wrote for every token: a row needs them where it samples only

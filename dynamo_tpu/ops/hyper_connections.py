@@ -23,12 +23,16 @@ last step leaves every new stream X'_k a convex combination of the old ones
 and the row sums say how far the iteration is from doubly stochastic.  The
 head reduces the streams with `head_reduce` before the final norm.
 
-Plain `jnp`; no kernel.  `mix` carries the Sinkhorn steps with the TOKENS on
-the minor axis (every sum is an add of token vectors, where [tokens, n, n]
-would reduce over 4-wide minor axes), and `pre` / `post` are
-written as sums over the n streams and not as batched 4 x 4 products, which
-the TPU compiler turns into a convolution over the tokens.  A kernel that
-keeps a token tile's rows in VMEM across `pre` and `post` is ROADMAP's.
+These are the plain `jnp` forms, over X [..., n, h]: what runs on the CPU,
+under a mesh, for float32 streams and for a decode step's few rows, and the
+reference that the kernels of `ops/pallas_hyper_connections.py` are tested
+against (on a single-device TPU trace a half's `mix` + `pre` and its `post`
+are ONE Pallas kernel each over the residual carried [T, n x h]:
+`models/llama.py` `_residual` chooses).  `mix` carries the Sinkhorn steps
+with the TOKENS on the minor axis (every sum is an add of token vectors,
+where [tokens, n, n] would reduce over 4-wide minor axes), and `pre` /
+`post` are written as sums over the n streams and not as batched 4 x 4
+products, which the TPU compiler turns into a convolution over the tokens.
 """
 
 from __future__ import annotations
@@ -51,9 +55,14 @@ class Mix(NamedTuple):
     err: jax.Array
 
 
-def widen(x: jax.Array, n: int) -> jax.Array:
-    """[..., h] -> [..., n, h]: every stream starts as the embedding."""
-    return jnp.broadcast_to(x[..., None, :], (*x.shape[:-1], n, x.shape[-1]))
+def bf16_pieces(phi: jax.Array):
+    """phi in float32 as three bf16 arrays that sum to it (8 + 8 + 8 bits
+    of mantissa)."""
+    pieces, rest = [], phi.astype(jnp.float32)
+    for _ in range(3):
+        pieces.append(rest.astype(jnp.bfloat16))
+        rest = rest - pieces[-1].astype(jnp.float32)
+    return pieces
 
 
 def _mix_logits(x: jax.Array, phi: jax.Array, rms_eps: float) -> jax.Array:
@@ -68,34 +77,30 @@ def _mix_logits(x: jax.Array, phi: jax.Array, rms_eps: float) -> jax.Array:
     xt = x.reshape(-1, n, h)
     xf = xt.astype(jnp.float32)
     r = jax.lax.rsqrt(jnp.mean(xf * xf, axis=(-2, -1)) + rms_eps)  # [T]
-    phi = phi.astype(jnp.float32)
     if x.dtype == jnp.bfloat16:
-        pieces, rest = [], phi
-        for _ in range(3):
-            pieces.append(rest.astype(jnp.bfloat16))
-            rest = rest - pieces[-1].astype(jnp.float32)
         m = sum(jnp.einsum("tnh,nhm->mt", xt, piece,
                            preferred_element_type=jnp.float32)
-                for piece in pieces)
+                for piece in bf16_pieces(phi))
     else:
-        m = jnp.einsum("tnh,nhm->mt", xf, phi,
+        m = jnp.einsum("tnh,nhm->mt", xf, phi.astype(jnp.float32),
                        precision=jax.lax.Precision.HIGHEST)
     return m * r[None, :]
 
 
-def sinkhorn(logits: jax.Array, iters: int, eps: float,
-             clamp: Tuple[float, float]) -> jax.Array:
-    """[n, n, ...] float32 logits (first index j, second k) -> R of the
-    same shape, driven towards the doubly stochastic matrices.  Written
-    over the n x n entries one by one, each a vector over the tokens: every
-    sum is an add of such vectors and the whole iteration is elementwise, so
-    the compiler makes ONE fusion of it (as reductions over the [n, n, T]
-    array it made some sixty small ones a mix: AOT, PR 37)."""
-    n = logits.shape[0]
-    logits = jnp.clip(logits, clamp[0], clamp[1])
+def sinkhorn_rows(rows, iters: int, eps: float, clamp: Tuple[float, float],
+                  rolled: bool = False):
+    """The n x n logits as a list of n lists of n arrays of one shape (rows[j]
+    [k]: first index j, second k) -> R the same way, driven towards the
+    doubly stochastic matrices.  Every sum is an add of such arrays and the
+    whole iteration is elementwise, so XLA makes ONE fusion of it (as
+    reductions over an [n, n, T] array it made some sixty small ones a mix:
+    AOT, PR 37) and a Pallas kernel runs it on token vectors as it is
+    written (`rolled`: the iters - 1 row-and-column steps as a `fori_loop`,
+    where a trace unrolls them)."""
+    n = len(rows)
     res = []
-    for j in range(n):  # softmax over the last index k, + eps
-        row = [logits[j, k] for k in range(n)]
+    for row in rows:  # softmax over the last index k, + eps
+        row = [jnp.clip(v, clamp[0], clamp[1]) for v in row]
         top = functools.reduce(jnp.maximum, row)
         row = [jnp.exp(v - top) for v in row]
         total = sum(row)
@@ -110,8 +115,36 @@ def sinkhorn(logits: jax.Array, iters: int, eps: float,
         return [[v / sums[j] for v in row] for j, row in enumerate(res)]
 
     res = by_columns(res)
+    if rolled:
+        return jax.lax.fori_loop(
+            0, iters - 1, lambda _, res: by_columns(by_rows(res)), res)
     for _ in range(iters - 1):
         res = by_columns(by_rows(res))
+    return res
+
+
+def sinkhorn_err(res) -> jax.Array:
+    """The largest |row or column sum of R - 1| of `sinkhorn_rows`' R."""
+    n = len(res)
+    sums = [sum(row) for row in res] + [
+        sum(res[j][k] for j in range(n)) for k in range(n)]
+    return functools.reduce(jnp.maximum, [jnp.abs(s - 1.0) for s in sums])
+
+
+def sinkhorn(logits: jax.Array, iters: int, eps: float,
+             clamp: Tuple[float, float]) -> jax.Array:
+    """[n, n, ...] float32 logits (first index j, second k) -> R of the
+    same shape: `sinkhorn_rows` over the n x n entries one by one, each a
+    vector over the tokens."""
+    return _stacked(sinkhorn_rows(_entries(logits), iters, eps, clamp))
+
+
+def _entries(logits: jax.Array):
+    n = logits.shape[0]
+    return [[logits[j, k] for k in range(n)] for j in range(n)]
+
+
+def _stacked(res) -> jax.Array:
     return jnp.stack([jnp.stack(row) for row in res])
 
 
@@ -127,13 +160,12 @@ def mix(x: jax.Array, phi: jax.Array, scale: jax.Array, base: jax.Array, *,
     base = base.astype(jnp.float32)[:, None]
     pre = jax.nn.sigmoid(scale[0] * m[:n] + base[:n]) + eps
     post = 2.0 * jax.nn.sigmoid(scale[1] * m[n:2 * n] + base[n:2 * n])
-    res = sinkhorn((scale[2] * m[2 * n:] + base[2 * n:]).reshape(n, n, -1),
-                   iters, eps, clamp)
-    err = jnp.maximum(jnp.abs(res.sum(0) - 1.0).max(0),
-                      jnp.abs(res.sum(1) - 1.0).max(0))  # [T]
+    res = sinkhorn_rows(
+        _entries((scale[2] * m[2 * n:] + base[2 * n:]).reshape(n, n, -1)),
+        iters, eps, clamp)
     return Mix(pre.T.reshape(*lead, n), post.T.reshape(*lead, n),
-               jnp.moveaxis(res, -1, 0).reshape(*lead, n, n),
-               err.reshape(lead))
+               jnp.moveaxis(_stacked(res), -1, 0).reshape(*lead, n, n),
+               sinkhorn_err(res).reshape(lead))
 
 
 def _weighted_sum(x: jax.Array, w: jax.Array) -> jax.Array:

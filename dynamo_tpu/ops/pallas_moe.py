@@ -102,8 +102,9 @@ _CHECK: Optional[bool] = None
 
 @contextlib.contextmanager
 def checked(interpret: bool):
-    """Inside, every trace's dispatched experts are the kernel, interpreted
-    or not: a test on the CPU, a compile for a TPU that is not attached."""
+    """Inside, every trace's dispatched experts (and its stream mixers:
+    `ops/pallas_hyper_connections.py`) are the kernels, interpreted or not: a
+    test on the CPU, a compile for a TPU that is not attached."""
     global _CHECK
     was, _CHECK = _CHECK, interpret
     try:
@@ -136,13 +137,22 @@ def lowering(w_up: jax.Array, gated: bool) -> Optional[bool]:
     h, f = w_up.shape[-2:]
     if width_block(h, f, 2 + gated, w_up.dtype.itemsize) is None:
         return None
-    interpret = _CHECK
-    if interpret is None and jax.default_backend() == "tpu" and (
-            jax.typeof(w_up).sharding.mesh.empty):
-        interpret = False
+    interpret = single_device(w_up)
     if interpret is False and h % LANES:
         return None
     return interpret
+
+
+def single_device(operand: jax.Array) -> Optional[bool]:
+    """May this trace hold a Pallas call over `operand`?  -> the call's
+    `interpret`: a check's (`checked`), else False on a TPU where the
+    operand lies on no mesh; None elsewhere."""
+    if _CHECK is not None:
+        return _CHECK
+    if jax.default_backend() == "tpu" and (
+            jax.typeof(operand).sharding.mesh.empty):
+        return False
+    return None
 
 
 def row_tile(rows: int) -> int:
@@ -196,7 +206,7 @@ def rows_inside(tokens: int, h: int, itemsize: int) -> bool:
         ROWS_VMEM_BYTES)
 
 
-def _lane_chunk(h: int) -> int:
+def lane_chunk(h: int) -> int:
     """Columns of h one placement product writes: the widest whole lanes up
     to `PLACE_LANES` that divide h (all of an h of fewer)."""
     chunks = [c for c in range(LANES, PLACE_LANES + 1, LANES) if h % c == 0]
@@ -280,7 +290,7 @@ def _kernel(layer_ref, offsets_ref, expert_ref, tile_ref, *refs,
         put = (jax.lax.broadcasted_iota(jnp.int32, (T, pieces.shape[0]), 0)
                == tokens).astype(bf16)
         h = ys.shape[1]
-        c = _lane_chunk(h)
+        c = lane_chunk(h)
         for at in range(0, h, c):
             sum_ref[:, at:at + c] += jnp.dot(
                 put, pieces[:, at:at + c], preferred_element_type=f32)

@@ -706,7 +706,9 @@ def test_a_model_without_leading_dense_layers_traces_what_it_traced(family,
     (`ops.latent_attention.prefill_attention`): off the chip, and wherever
     "xla" is asked for, it is the form it was, to the letter.  The tiny
     xing4_0's two digests (latent pages around a residual of streams) were
-    taken on PR 42's tree.
+    taken on PR 58's tree, which carries the streams [.., n x h] (a token's
+    streams side by side: `ops/pallas_hyper_connections.py`) where PR 42's
+    carried [.., n, h], and left every other digest as it was.
 
     The tiny nemotron_h's two (one scan over units of three kinds of mixer,
     pages for its attention layers alone, state slots beside them, their

@@ -418,10 +418,10 @@ def _smallthinker_step(one_chip, impl):
                                           1024)
 
 
-def _published_step(one_chip, impl, config, chunk, slots=0, **over):
+def _published_step(one_chip, impl, config, chunk, slots=0, rows=1, **over):
     """(cfg, the flat engine's `prefill_step`, its operands' shapes) of
     `benchmark/configs/<config>.json`'s model with the keys `over` replaced:
-    one row of `chunk` tokens under 128 pages and `slots` state slots."""
+    `rows` rows of `chunk` tokens under 128 pages and `slots` state slots."""
     import dataclasses
     import json
 
@@ -450,13 +450,12 @@ def _published_step(one_chip, impl, config, chunk, slots=0, **over):
         lambda: init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16)))
     kv = shapes(jax.eval_shape(lambda: KVCache.create(
         cfg, 1024, PAGE, jnp.bfloat16, state_slots=slots)))
-    one = rep(jnp.int32, 1)
-    samp = SamplingParams(rep(jnp.float32, 1), one, rep(jnp.float32, 1),
-                          rep(jnp.float32, 1), rep(jnp.float32, 1))
+    one, f32 = rep(jnp.int32, rows), rep(jnp.float32, rows)
+    samp = SamplingParams(f32, one, f32, f32, f32)
     return cfg, layout.prefill_step(False, greedy=True), (
-        params, kv, rep(jnp.int32, 1, chunk),
-        rep(jnp.int32, 1, 128 + layout.state_cols), one, one, samp,
-        rep(jnp.uint32, 1), one, rep(jnp.bool_, 1))
+        params, kv, rep(jnp.int32, rows, chunk),
+        rep(jnp.int32, rows, 128 + layout.state_cols), one, one, samp,
+        rep(jnp.uint32, rows), one, rep(jnp.bool_, rows))
 
 
 def _nemotron_share_step(one_chip, impl):
@@ -662,3 +661,73 @@ def test_latent_prefill_step_leaves_the_pool_where_it_is(one_chip,
     pools = sum(p.size * p.dtype.itemsize for p in (kv.k, kv.v))
     assert mem.alias_size_in_bytes >= pools  # written in place
     assert mem.temp_size_in_bytes < (300 << 20 if kernel else 400 << 20)
+
+
+# -- a residual of several streams (the xing4_0 cell) ------------------------------ #
+
+# ops that hand an array on between HBM and VMEM as it is laid out: the
+# compiler's own prefetches and evictions (DMA beside other work), whole or in
+# slices that a `ConcatBitcast` joins
+_HANDED_ON = ("copy-start", "copy-done", "slice-start", "slice-done")
+
+
+@pytest.mark.parametrize("rows,chunk", [(1, 512), (4, 64)],
+                         ids=["512", "4x64"])
+def test_stream_mixers_are_two_kernels_a_half_and_nothing_else(one_chip, rows,
+                                                               chunk):
+    """The Xing cell's `prefill_step` (published widths, its two dense
+    layers and two of its expert layers; the 512-token chunk and the `[4,
+    64]` shared step) with the mixers as kernels (ISSUE 58): each layer loop's body
+    holds exactly two `hc.mix` and two `hc.post` custom calls, and NO other
+    op that computes a result of n x tokens x hidden values.  As `jnp` the
+    body held eleven: the carry bf16[1,512,4,3584] under 4-row tiles, three
+    re-laid-out copies of it for the mixer's product, `post`'s result three
+    times in that layout, a float32 copy for `pre` (AOT, PR 58).  What is
+    left at that size is `hc.post`'s result and the compiler handing it on
+    between HBM and VMEM in the SAME layout (tokens under the sublanes, the
+    n x hidden values along the lanes), bf16.  Counts and shapes, never a
+    time."""
+    import math
+    import re
+
+    from dynamo_tpu.models import llama
+    from dynamo_tpu.ops import pallas_moe
+
+    cfg, step, args = _published_step(
+        one_chip, "ragged", "xing4.0-29b-h8", chunk, rows=rows,
+        num_hidden_layers=4)
+    with pallas_moe.checked(interpret=False):
+        compiled = step.lower(*args).compile()
+    assert llama.hc_mixers(rows * chunk) == "kernel"
+    values = rows * chunk * cfg.hc_mult * cfg.hidden_size
+    bodies, name = {}, None
+    for line in compiled.as_text().splitlines():
+        if line and not line[0].isspace():
+            name = line.split("(")[0].strip()
+        elif "fused_computation" not in name:
+            bodies.setdefault(name, []).append(line)
+    loops = {k: v for k, v in bodies.items()
+             if any("= " in ln and ln.split("= ")[0].strip().startswith(
+                 "%hc.") for ln in v)}
+    assert len(loops) == 2  # the dense layers' loop and the expert layers'
+    result = re.compile(
+        r"(%[\w.-]+) = \(?(\w+)\[([\d,]+)\](\{[^ ]*\})? ([\w-]+)\(")
+    for body in loops.values():
+        calls = re.findall(r"(%[\w.]+) = [^\n]*custom_call_target="
+                           r"\"tpu_custom_call\"", "\n".join(body))
+        mixers = sorted(c.rsplit(".", 1)[0] for c in calls
+                        if c.startswith("%hc."))
+        assert mixers == ["%hc.mix", "%hc.mix", "%hc.post", "%hc.post"], calls
+        made = []
+        for m in map(result.search, body):
+            if not m or m.group(5) in ("parameter", "bitcast", "tuple",
+                                       "get-tuple-element", "while"):
+                continue
+            if math.prod(map(int, m.group(3).split(","))) != values:
+                continue
+            dtype, layout, op = m.group(2), m.group(4) or "", m.group(5)
+            assert dtype == "bf16" and "T(8,128)(2,1)" in layout, m.string
+            if not (m.group(1).startswith("%hc.post") or op in _HANDED_ON
+                    or "ConcatBitcast" in m.string):
+                made.append(m.string.strip()[:160])
+        assert made == []
