@@ -19,7 +19,10 @@ from typing import Optional
 MOE_ACTS = ("silu", "gpt_oss_glu", "relu_glu", "relu2")
 # a layer's ONE mixer under `ModelConfig.layer_pattern`
 LAYER_KINDS = {"M": "a state-space (Mamba-2) mixer", "E": "an expert "
-               "feed-forward", "*": "attention"}
+               "feed-forward", "*": "attention",
+               # falcon_h1: a layer that is BOTH, then a dense feed-forward;
+               # every layer of a model that has one (`__post_init__`)
+               "P": "a Mamba-2 mixer and attention side by side"}
 # a decoder-hybrid-decoder's layers (phi4flash), each a mixer THEN a dense
 # feed-forward: the self half's "S" and "W" in turn, its last pair "S", "F";
 # the cross half's "G" and "C" in turn
@@ -249,8 +252,12 @@ class ModelConfig:
     # (nemotron_h): character l of `layer_pattern` names layer l's, "M" a
     # Mamba-2 state-space mixer, "E" an expert feed-forward, "*" attention
     # (`LAYER_KINDS`).  Only the "*" layers have pages (`num_kv_layers`);
-    # the "M" layers leave a sequence a state (`state_spec`).  None: every
-    # layer is attention, then a feed-forward
+    # the "M" layers leave a sequence a state (`state_spec`).  "P"
+    # (falcon_h1) is a layer of another build: a Mamba-2 mixer and attention
+    # side by side from ONE norm, their sum on the residual, then a dense
+    # feed-forward under its own norm; such a layer is in BOTH counts, it
+    # owns pages and a state slot.  None: every layer is attention, then a
+    # feed-forward
     layer_pattern: Optional[str] = None
     # the Mamba-2 mixer: `ssm_heads` heads of `ssm_head_dim`, B and C shared
     # by the heads of each of `ssm_groups` groups, a recurrent state of
@@ -305,6 +312,23 @@ class ModelConfig:
     # added to the SUM of the chosen experts' sigmoid scores before the
     # router's weights are divided by it (lfm2_moe's 1e-6; 0: the sum alone)
     moe_norm_eps: float = 0.0
+    # muP multipliers on ACTIVATIONS (falcon_h1; 1.0 / None: none).  Each is
+    # applied to a product's float32 output before it is rounded, never
+    # folded into a bf16 weight: the embedded tokens; the logits; the keys
+    # (on top of `attention_in_multiplier`, which scales what q, k and v
+    # read); attention's output; what the state-space mixer reads and what
+    # it gives; `ssm_multipliers`, five factors over the parts [z | x | B |
+    # C | dt] of `in_proj`'s output (`ssm_mup_vector`); `mlp_multipliers`,
+    # (on the gate's product, on the down projection's)
+    embedding_multiplier: float = 1.0
+    lm_head_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    attention_in_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_out_multiplier: float = 1.0
+    ssm_multipliers: Optional[tuple] = None
+    mlp_multipliers: Optional[tuple] = None
     # identity
     model_type: str = "llama"
     name: str = "llama"
@@ -343,6 +367,10 @@ class ModelConfig:
                 raise ValueError(
                     f"layer_pattern must name {L} layers by "
                     f"{sorted(kinds)}, got {self.layer_pattern!r}")
+            if "P" in self.layer_pattern and set(self.layer_pattern) != {"P"}:
+                raise ValueError(
+                    "a layer of both mixers ('P': falcon_h1) stands beside "
+                    f"no layer of one, got {self.layer_pattern!r}")
         elif self.moe_act == "relu2":
             raise ValueError("ungated relu2 experts are implemented under a "
                              "layer_pattern only (nemotron_h)")
@@ -417,7 +445,7 @@ class ModelConfig:
         "C" layers read "F"'s pages, the "G" layers and the short
         convolutions keep nothing)."""
         if self.layer_pattern is not None:
-            return sum(self.layer_pattern.count(c) for c in "*WF")
+            return sum(self.layer_pattern.count(c) for c in "*WFP")
         return self.num_hidden_layers - self.conv_layers
 
     @property
@@ -428,7 +456,8 @@ class ModelConfig:
             return StateSpec(self.conv_layers, (), self.hidden_size,
                              self.short_conv_kernel)
         pattern = self.layer_pattern or ""
-        n = pattern.count("M") + pattern.count("S")
+        # (a "P" layer is counted here AND in `num_kv_layers`)
+        n = sum(pattern.count(c) for c in "MSP")
         if not n:
             return None
         dims = ((self.ssm_state, self.ssm_inner) if self.ssm_dt_rank else
@@ -448,6 +477,19 @@ class ModelConfig:
         if self.ssm_dt_rank:
             return self.ssm_inner
         return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
+
+    @property
+    def ssm_mup_vector(self) -> Optional[tuple]:
+        """What `in_proj`'s float32 output [z | x | B | C | dt] is multiplied
+        by, value for value, as (factor, width) runs: `ssm_multipliers` over
+        the five parts, times `ssm_in_multiplier` (the scale of what the
+        projection read, which commutes with it); None without either."""
+        if self.ssm_multipliers is None and self.ssm_in_multiplier == 1.0:
+            return None
+        gn = self.ssm_groups * self.ssm_state
+        widths = (self.ssm_inner, self.ssm_inner, gn, gn, self.ssm_heads)
+        return tuple((m * self.ssm_in_multiplier, w) for m, w in zip(
+            self.ssm_multipliers or (1.0,) * 5, widths))
 
     @property
     def shared_expert_width(self) -> int:
@@ -614,7 +656,7 @@ class ModelConfig:
         mamba = (h * (d + cd + nh) + cd * self.ssm_conv_kernel + cd + 3 * nh
                  + d + d * h)
         attn = h * q + 2 * h * kv + q * h
-        fm = self.moe_intermediate_size
+        fm = self.moe_intermediate_size or 0
         gated = 2 if self.moe_act == "relu2" else 3
         moe = (h * self.router_width
                + (self.router_width if self.moe_scoring == "sigmoid" else 0)
@@ -622,8 +664,11 @@ class ModelConfig:
                + (gated * h * self.shared_expert_width
                   if self.n_shared_experts else 0))
         emb = self.vocab_size * h * (1 if self.tie_word_embeddings else 2)
+        # both mixers under one norm, a dense SwiGLU under another
+        both = mamba + attn + 3 * h * self.intermediate_size + 2 * h
         return (pat.count("M") * (mamba + h) + pat.count("*") * (attn + h)
-                + pat.count("E") * (moe + h) + emb + h)
+                + pat.count("E") * (moe + h) + pat.count("P") * both
+                + emb + h)
 
     @staticmethod
     def from_hf_config(d: dict, name: str = "") -> "ModelConfig":
@@ -643,6 +688,14 @@ class ModelConfig:
             return ModelConfig(**_laguna_fields(d, name))
         if d.get("model_type") == "lfm2_moe":
             return ModelConfig(**_lfm2_moe_fields(d, name))
+        if d.get("model_type") == "falcon_h1":
+            return ModelConfig(**_falcon_h1_fields(d, name))
+        if d.get("mamba_d_ssm") or d.get("ssm_multipliers"):
+            raise ValueError(
+                f"model_type {d.get('model_type')!r} asks for a state-space "
+                "mixer beside attention in a layer under muP multipliers "
+                "(mamba_d_ssm, ssm_multipliers) and only falcon_h1's is "
+                "implemented: the llama branch would build another model")
         if d.get("conv_L_cache") or "conv" in (d.get("layer_types") or ()):
             raise ValueError(
                 f"model_type {d.get('model_type')!r} asks for short "
@@ -1179,6 +1232,91 @@ def _lfm2_moe_fields(d: dict, name: str) -> dict:
         moe_norm_eps=1e-6,
         model_type="lfm2_moe",
         name=name or d.get("_name_or_path", "lfm2_moe"),
+    )
+
+
+def _falcon_h1_fields(d: dict, name: str) -> dict:
+    """TII Falcon-H1 (`model_type` "falcon_h1": Falcon-H1-34B-Instruct and its
+    smaller siblings).  EVERY layer is a Mamba-2 mixer (inner width
+    `mamba_d_ssm` = `mamba_n_heads` x `mamba_d_head`, not `mamba_expand` x
+    hidden) and GQA attention with a whole-head rope side by side, both from
+    the ONE `input_layernorm`, their sum on the residual; then a dense SwiGLU
+    under `pre_ff_layernorm` (`layer_pattern` "P" a layer: `models/
+    hybrid.py`).  The family's muP multipliers scale activations and are
+    kept as fields (`ModelConfig.embedding_multiplier` and the lines after
+    it).  Keys its code does not read are passed over (`mamba_expand`,
+    `mamba_use_mlp`, `mlp_expansion_factor`, `num_logits_to_keep`); what is
+    not implemented is refused by the key that asks for it."""
+    def refuse(key, why):
+        raise ValueError(f"falcon_h1: {key} {d.get(key)!r} {why}")
+
+    for key in ("attention_bias", "mamba_proj_bias", "mlp_bias",
+                "projectors_bias"):
+        if d.get(key):
+            refuse(key, "projection biases are not implemented")
+    if not d.get("mamba_conv_bias", True):
+        refuse("mamba_conv_bias", "the convolution is implemented with its "
+               "bias")
+    if d.get("hidden_act", "silu") != "silu":
+        refuse("hidden_act", "only silu is implemented")
+    if d.get("attn_layer_indices") is not None:
+        refuse("attn_layer_indices", "every layer has attention beside its "
+               "state-space mixer: a layer list is not implemented")
+    if d.get("mamba_norm_before_gate"):
+        refuse("mamba_norm_before_gate", "the gate comes first, then the "
+               "grouped norm")
+    if not d.get("mamba_rms_norm", True):
+        refuse("mamba_rms_norm", "the gated output is implemented with its "
+               "grouped rms norm")
+    for key in ("rope_scaling", "sliding_window"):
+        if d.get(key):
+            refuse(key, "is not implemented for this family")
+    nh, hp = d["mamba_n_heads"], d["mamba_d_head"]
+    if d.get("mamba_d_ssm", nh * hp) != nh * hp:
+        refuse("mamba_d_ssm", f"must be mamba_n_heads x mamba_d_head = "
+               f"{nh * hp}: the mixer's inner width is its heads'")
+    if nh % d.get("mamba_n_groups", 1):
+        refuse("mamba_n_groups", f"must divide mamba_n_heads {nh}")
+    nq = d["num_attention_heads"]
+    nkv = d.get("num_key_value_heads", nq)
+    if nq % nkv:
+        refuse("num_key_value_heads", f"must divide the {nq} query heads")
+    mults = {}
+    for key, n in (("ssm_multipliers", 5), ("mlp_multipliers", 2)):
+        m = d.get(key)
+        if m is None:
+            continue
+        if not isinstance(m, (list, tuple)) or len(m) != n:
+            refuse(key, f"must hold {n} factors")
+        mults[key] = tuple(float(v) for v in m)
+    for key in ("embedding_multiplier", "lm_head_multiplier",
+                "key_multiplier", "attention_in_multiplier",
+                "attention_out_multiplier", "ssm_in_multiplier",
+                "ssm_out_multiplier"):
+        mults[key] = float(d.get(key, 1.0))
+    L = d["num_hidden_layers"]
+    return dict(
+        vocab_size=d["vocab_size"],
+        hidden_size=d["hidden_size"],
+        intermediate_size=d["intermediate_size"],
+        num_hidden_layers=L,
+        num_attention_heads=nq,
+        num_key_value_heads=nkv,
+        head_dim=d.get("head_dim") or d["hidden_size"] // nq,
+        max_position_embeddings=d.get("max_position_embeddings", 4096),
+        rms_norm_eps=d.get("rms_norm_eps", 1e-5),
+        rope_theta=float(d.get("rope_theta", 10000.0)),
+        tie_word_embeddings=d.get("tie_word_embeddings", False),
+        layer_pattern="P" * L,
+        ssm_heads=nh,
+        ssm_head_dim=hp,
+        ssm_groups=d.get("mamba_n_groups", 1),
+        ssm_state=d["mamba_d_state"],
+        ssm_conv_kernel=d.get("mamba_d_conv", 4),
+        ssm_chunk=d.get("mamba_chunk_size", 128),
+        **mults,
+        model_type="falcon_h1",
+        name=name or d.get("_name_or_path", "falcon_h1"),
     )
 
 

@@ -1,19 +1,24 @@
 """The layer loop of a model whose layer is ONE mixer (`ModelConfig.
 layer_pattern`: nemotron_h): a Mamba-2 state-space mixer "M", an expert
-feed-forward "E" or attention "*", each `x + mixer(rms_norm(x))`.
+feed-forward "E" or attention "*", each `x + mixer(rms_norm(x))`; and of a
+model whose every layer is BOTH mixers (falcon_h1, "P"): `u = rms_norm(x)`
+once, `x + mamba(u) + attention(u)`, the two side by side from the same `u`
+and under the family's multipliers, then a dense feed-forward under a norm
+of its own (`llama._feed_forward`).
 
-Params are three stacks by kind (`ssm_layers`, `moe_layers`, `attn_layers`),
-and the loop follows the pattern in ONE `lax.scan` whatever the depth: the
-pattern is cut into units of (M, then *, then E), each kind at most once a
-unit and in that order (nemotron_h's 52 layers are 23 units, six of them
-with a "*").  A kind every unit has is scanned with the units; a kind only
-some have runs under a `lax.cond` on the unit's flag and indexes its own
-stack.
+Params are a stack a kind (`ssm_layers`, `attn_layers`, `moe_layers`,
+`par_layers`), and the loop follows the pattern in ONE `lax.scan` whatever
+the depth: the pattern is cut into units of (M, then *, then E), each kind at
+most once a unit and in that order (nemotron_h's 52 layers are 23 units, six
+of them with a "*"); a "P" layer is a unit by itself (`kinds_of`).  A kind
+every unit has is scanned with the units; a kind only some have runs under a
+`lax.cond` on the unit's flag and indexes its own stack.
 
 Two pools stay where they are, read inside the loop and written after it:
-the pages of the "*" layers alone (`StateCache.k`, `.v`) and, beside them,
-the state slots of the "M" layers (`StateCache.conv`, `.ssm`: `ModelConfig.
-state_spec`).  A row reads its state from one slot and writes it to another,
+the pages of the layers with attention (`StateCache.k`, `.v`) and, beside
+them, the state slots of the layers with a state-space mixer
+(`StateCache.conv`, `.ssm`: `ModelConfig.state_spec`); a "P" layer has a row
+in both, at its own index.  A row reads its state from one slot and writes it to another,
 and the scan hands out the state INSIDE the chunk too, every
 `handout_every` tokens from the chunk's start (`snapshot_tokens`; a page in
 a short row): up to `SNAP_COLS` further slots take those.  All ride in the
@@ -33,8 +38,9 @@ import numpy as np
 from ..ops import prefill_attention, rms_norm, rope_attention_scale
 from ..ops import rope_frequencies, ssm, write_kv_layers
 from .config import ModelConfig
-from .llama import (Params, StateCache, _moe, _qkv_proj, _rope_qk,
-                    _valid_rows, merge_moe_stats, moe_stats_columns)
+from .llama import (Params, StateCache, _feed_forward, _moe, _qkv_proj,
+                    _rope_qk, _valid_rows, merge_moe_stats,
+                    moe_stats_columns)
 from .quantization import matmul_any
 
 # A chunk hands its state out, to be kept as a snapshot, after every so many
@@ -69,26 +75,35 @@ def handout_every(cfg: ModelConfig, tokens: int, page_size: int) -> int:
 
 
 class Units(NamedTuple):
-    """`layer_pattern` as units of (M, *, E).  `has` [U, 3] bool and `idx`
-    [U, 3] int (the layer's index in its kind's stack; 0 where absent), in
-    the order of `KINDS`."""
+    """`layer_pattern` as units of (M, *, E), or of (P,).  `has` [U, kinds]
+    bool and `idx` [U, kinds] int (the layer's index in its kind's stack; 0
+    where absent), in the order of `kinds_of(pattern)`."""
 
     has: np.ndarray
     idx: np.ndarray
 
 
-KINDS = "M*E"
-STACKS = ("ssm_layers", "attn_layers", "moe_layers")
+KINDS = "M*E"  # a unit's layers of ONE mixer, in its order
+STACKS = {"M": "ssm_layers", "*": "attn_layers", "E": "moe_layers",
+          "P": "par_layers"}
+
+
+def kinds_of(pattern: str) -> str:
+    """The kinds a unit of this pattern may hold, in its order: a layer of
+    both mixers ("P") is a unit by itself and stands beside no other kind
+    (`ModelConfig.__post_init__`)."""
+    return "P" if "P" in pattern else KINDS
 
 
 def units_of(pattern: str) -> Units:
-    has, idx, seen = [], [], [0, 0, 0]
-    at = len(KINDS)  # past the last kind: the first layer opens a unit
+    kinds = kinds_of(pattern)
+    has, idx, seen = [], [], [0] * len(kinds)
+    at = len(kinds)  # past the last kind: the first layer opens a unit
     for ch in pattern:
-        k = KINDS.index(ch)
+        k = kinds.index(ch)
         if k < at:
-            has.append([False] * 3)
-            idx.append([0] * 3)
+            has.append([False] * len(kinds))
+            idx.append([0] * len(kinds))
         has[-1][k], idx[-1][k] = True, seen[k]
         seen[k] += 1
         at = k + 1
@@ -107,7 +122,7 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
     initialises them (A in [1, 16], step sizes in [0.001, 0.1]), so that a
     state REMEMBERS across hundreds of tokens."""
     h, pat = cfg.hidden_size, cfg.layer_pattern
-    nM, nA, nE = (pat.count(c) for c in KINDS)
+    nM, nA, nE, nP = (pat.count(c) for c in KINDS + "P")
     d, cd, nh = cfg.ssm_inner, cfg.ssm_conv_dim, cfg.ssm_heads
     q = cfg.num_attention_heads * cfg.head_dim_
     kvw = cfg.num_key_value_heads * cfg.head_dim_
@@ -119,30 +134,48 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
         return (jax.random.normal(next(ks), shape, jnp.float32)
                 * scale).astype(dtype)
 
+    def ssm_stack(n, dt0):  # a Mamba-2 mixer a layer, under its norm
+        return {
+            "norm": jnp.ones((n, h), dtype),
+            "in_proj": w(n, h, d + cd + nh),
+            "conv_w": w(n, cfg.ssm_conv_kernel, cd, scale=0.5),
+            "conv_b": w(n, cd, scale=0.1),
+            # inverse softplus of the drawn step size
+            "dt_bias": dt0 + jnp.log(-jnp.expm1(-dt0)),
+            "A_log": jnp.log(jax.random.uniform(
+                next(ks), (n, nh), jnp.float32, 1.0, 16.0)),
+            "D": jnp.ones((n, nh), jnp.float32),
+            "gate_norm": jnp.ones((n, d), dtype),
+            "out_proj": w(n, d, h),
+        }
+
+    def attn_stack(n):
+        return {"wq": w(n, h, q), "wk": w(n, h, kvw), "wv": w(n, h, kvw),
+                "wo": w(n, q, h)}
+
     dt0 = jnp.exp(jax.random.uniform(
-        next(ks), (nM, nh), jnp.float32, np.log(1e-3), np.log(1e-1)))
+        next(ks), (nM + nP, nh), jnp.float32, np.log(1e-3), np.log(1e-1)))
+    if nP:  # every layer is both mixers and a dense feed-forward: ONE stack
+        f = cfg.intermediate_size
+        params = {
+            "embed": w(cfg.vocab_size, h, scale=0.02),
+            "final_norm": jnp.ones((h,), dtype),
+            "par_layers": {
+                **ssm_stack(nP, dt0), **attn_stack(nP),
+                "mlp_norm": jnp.ones((nP, h), dtype),
+                "w_gate": w(nP, h, f), "w_up": w(nP, h, f),
+                "w_down": w(nP, f, h),
+            },
+        }
+        if not cfg.tie_word_embeddings:
+            params["lm_head"] = w(h, cfg.vocab_size)
+        return params
     params = {
         "embed": w(cfg.vocab_size, h, scale=0.02),
         "final_norm": jnp.ones((h,), dtype),
         "lm_head": w(h, cfg.vocab_size),
-        "ssm_layers": {
-            "norm": jnp.ones((nM, h), dtype),
-            "in_proj": w(nM, h, d + cd + nh),
-            "conv_w": w(nM, cfg.ssm_conv_kernel, cd, scale=0.5),
-            "conv_b": w(nM, cd, scale=0.1),
-            # inverse softplus of the drawn step size
-            "dt_bias": dt0 + jnp.log(-jnp.expm1(-dt0)),
-            "A_log": jnp.log(jax.random.uniform(
-                next(ks), (nM, nh), jnp.float32, 1.0, 16.0)),
-            "D": jnp.ones((nM, nh), jnp.float32),
-            "gate_norm": jnp.ones((nM, d), dtype),
-            "out_proj": w(nM, d, h),
-        },
-        "attn_layers": {
-            "norm": jnp.ones((nA, h), dtype),
-            "wq": w(nA, h, q), "wk": w(nA, h, kvw), "wv": w(nA, h, kvw),
-            "wo": w(nA, q, h),
-        },
+        "ssm_layers": ssm_stack(nM, dt0),
+        "attn_layers": {"norm": jnp.ones((nA, h), dtype), **attn_stack(nA)},
         "moe_layers": {
             "norm": jnp.ones((nE, h), dtype),
             "router": w(nE, h, cfg.router_width),
@@ -173,7 +206,11 @@ def _mamba(lp: Params, u: jax.Array, cfg: ModelConfig, window: jax.Array,
     d, nh, hp = cfg.ssm_inner, cfg.ssm_heads, cfg.ssm_head_dim
     G, N, dt_ = cfg.ssm_groups, cfg.ssm_state, u.dtype
     with jax.named_scope("ssm.in_proj"):
-        zxd = matmul_any(u, lp["in_proj"], "bsh,hd->bsd").astype(dt_)
+        zxd = matmul_any(u, lp["in_proj"], "bsh,hd->bsd")
+        if cfg.ssm_mup_vector is not None:  # on the float32 product
+            zxd = zxd * np.concatenate([np.full(n, m, np.float32)
+                                        for m, n in cfg.ssm_mup_vector])
+        zxd = zxd.astype(dt_)
         z, xbc, dt = (zxd[..., :d], zxd[..., d:d + cfg.ssm_conv_dim],
                       zxd[..., d + cfg.ssm_conv_dim:])
     at = _inside(cfg, S, page_size)
@@ -193,8 +230,10 @@ def _mamba(lp: Params, u: jax.Array, cfg: ModelConfig, window: jax.Array,
         y = ssm.gate_norm(y.reshape(B, S, d), z, lp["gate_norm"], G,
                           cfg.rms_norm_eps)
     with jax.named_scope("ssm.out_proj"):
-        return (matmul_any(y, lp["out_proj"], "bsd,dh->bsh").astype(dt_),
-                window, h, inside)
+        out = matmul_any(y, lp["out_proj"], "bsd,dh->bsh")
+        if cfg.ssm_out_multiplier != 1.0:
+            out = out * cfg.ssm_out_multiplier
+        return out.astype(dt_), window, h, inside
 
 
 def _attention(lp: Params, u: jax.Array, cfg: ModelConfig, kv: StateCache,
@@ -206,6 +245,12 @@ def _attention(lp: Params, u: jax.Array, cfg: ModelConfig, kv: StateCache,
                    cfg.head_dim_)
     with jax.named_scope("attn.qkv"):
         q, k, v = _qkv_proj(u, lp, cfg, "bsh,hd->bsd")
+        # (the scale of what the three read commutes with the products)
+        m_in, m_k = cfg.attention_in_multiplier, cfg.key_multiplier
+        if m_in != 1.0:
+            q, v = q * m_in, v * m_in
+        if m_in * m_k != 1.0:
+            k = k * (m_in * m_k)
         q = q.astype(u.dtype).reshape(B, S, nh, hd)
         k = k.astype(u.dtype).reshape(B, S, nkv, hd)
         v = v.astype(u.dtype).reshape(B, S, nkv, hd)
@@ -217,8 +262,10 @@ def _attention(lp: Params, u: jax.Array, cfg: ModelConfig, kv: StateCache,
     attn = prefill_attention(q, k, v, kv.k, kv.v, table, prefix_lens,
                              chunk_lens, impl=attn_impl, layer=layer)
     with jax.named_scope("attn.out"):
-        return (matmul_any(attn.reshape(B, S, nh * hd), lp["wo"],
-                           "bsd,dh->bsh").astype(u.dtype), k, v)
+        out = matmul_any(attn.reshape(B, S, nh * hd), lp["wo"], "bsd,dh->bsh")
+        if cfg.attention_out_multiplier != 1.0:
+            out = out * cfg.attention_out_multiplier
+        return out.astype(u.dtype), k, v
 
 
 def layers(params: Params, cfg: ModelConfig, kv: StateCache, x: jax.Array,
@@ -232,6 +279,9 @@ def layers(params: Params, cfg: ModelConfig, kv: StateCache, x: jax.Array,
     page_size = kv.page_size
     table, slot_in, slot_out, slot_inside = split_table(page_table)
     units = units_of(cfg.layer_pattern)
+    if moe_stats and "E" not in cfg.layer_pattern:
+        raise ValueError(f"moe_stats: the pattern {cfg.layer_pattern!r} has "
+                         "no expert layer to count")
     every = units.has.all(0)  # kinds that every unit has: scanned
     spec = cfg.state_spec
     positions = prefix_lens[:, None] + jnp.arange(S)[None, :]
@@ -242,15 +292,22 @@ def layers(params: Params, cfg: ModelConfig, kv: StateCache, x: jax.Array,
     def normed(lp, h, mixer):
         return mixer(rms_norm(h, lp["norm"], cfg.rms_norm_eps))
 
-    def mamba(h, lp, layer):
+    def carried(layer):  # the rows' window and state, from their slots
         with jax.named_scope("state.read"):
             win = read_window(kv, spec, layer, slot_in, fresh)
             h0 = jnp.where(fresh[:, None, None, None], 0.0,
-                           kv.ssm[layer, slot_in])
-        out, win, h1, inside = normed(lp, h, lambda u: _mamba(
-            lp, u, cfg, win, h0, chunk_lens, page_size))
-        return h + out, (_as_tiles(win, spec.window_dims), h1, *(
+                           read_state(kv.ssm, layer, slot_in))
+        return win, h0
+
+    def stored(win, h1, inside):  # as the slot pool stores them
+        return (_as_tiles(win, spec.window_dims), h1, *(
             (_as_tiles(w, spec.window_dims), hj) for w, hj in inside))
+
+    def mamba(h, lp, layer):
+        win, h0 = carried(layer)
+        out, *left = normed(lp, h, lambda u: _mamba(
+            lp, u, cfg, win, h0, chunk_lens, page_size))
+        return h + out, stored(*left)
 
     def attention(h, lp, layer):
         out, k, v = normed(lp, h, lambda u: _attention(
@@ -262,11 +319,25 @@ def layers(params: Params, cfg: ModelConfig, kv: StateCache, x: jax.Array,
         with jax.named_scope("mlp"):
             out = normed(lp, h, lambda u: _moe(
                 lp, u, cfg, None, valid, stats=moe_stats,
-                stacks=(params[STACKS[2]], layer)))
+                stacks=(params[STACKS["E"]], layer)))
         out, *st = out if moe_stats else (out,)
         return h + out, tuple(st)
 
-    mixers = (mamba, attention, experts)
+    def both(h, lp, layer):
+        """A layer of both mixers, side by side from one normed input, then
+        its dense feed-forward: -> (h, (the state's rows, the pages'))."""
+        win, h0 = carried(layer)
+        u = rms_norm(h, lp["norm"], cfg.rms_norm_eps)
+        s, *left = _mamba(lp, u, cfg, win, h0, chunk_lens, page_size)
+        a, k, v = _attention(lp, u, cfg, kv, layer, positions, table,
+                             prefix_lens, chunk_lens, attn_impl)
+        h = h + s + a
+        (y,) = _feed_forward(lp, h, h, cfg)
+        return h + y, (stored(*left), (k, v))
+
+    kinds = kinds_of(cfg.layer_pattern)
+    mixers = tuple({"M": mamba, "*": attention, "E": experts, "P": both}[c]
+                   for c in kinds)
     a_state = (jnp.zeros((B, *spec.window_dims), kv.conv.dtype),
                jnp.zeros((B, *spec.state_dims), jnp.float32)) if spec else ()
     blank = (
@@ -287,7 +358,7 @@ def layers(params: Params, cfg: ModelConfig, kv: StateCache, x: jax.Array,
                 h, out = mixer(h, scanned[k], idx[k])
                 outs.append(out)
             else:
-                stack = params[STACKS[k]]
+                stack = params[STACKS[kinds[k]]]
                 h, out = jax.lax.cond(
                     has[k],
                     lambda h, i, mixer=mixer, stack=stack: mixer(
@@ -296,22 +367,26 @@ def layers(params: Params, cfg: ModelConfig, kv: StateCache, x: jax.Array,
                 outs.append(out)
         return h, tuple(outs)
 
-    scanned = tuple(params[STACKS[k]] if every[k] else None
-                    for k in range(len(KINDS)))
-    x, (st_m, st_a, st_e) = jax.lax.scan(
+    scanned = tuple(params[STACKS[c]] if every[k] else None
+                    for k, c in enumerate(kinds))
+    x, left = jax.lax.scan(
         body, x, (jnp.asarray(units.has), jnp.asarray(units.idx), scanned))
 
     def own(ys, k):  # the units that have kind k, in their stack's order
         rows = np.flatnonzero(units.has[:, k])
         return ys if every[k] else jax.tree.map(lambda a: a[rows], ys)
 
+    if kinds == "P":  # every layer left both: a row a layer in either pool
+        (states, pages), st_e = left[0], ()
+    else:
+        st_m, st_a, st_e = left
+        states, pages = st_m and own(st_m, 0), st_a and own(st_a, 1)
     new = {}
-    if st_a:
-        k_new, v_new = own(st_a, 1)
+    if pages:
         new["k"], new["v"] = write_kv_layers(
-            kv.k, kv.v, k_new, v_new, table, prefix_lens, valid)
-    if st_m:
-        new["conv"], new["ssm"] = write_states(kv, own(st_m, 0), slot_out,
+            kv.k, kv.v, *pages, table, prefix_lens, valid)
+    if states:
+        new["conv"], new["ssm"] = write_states(kv, states, slot_out,
                                                slot_inside)
     kv = kv._replace(**new)
     if not moe_stats:
@@ -329,6 +404,22 @@ def read_window(kv: StateCache, spec, layer, slot_in: jax.Array,
     win = kv.conv[layer, slot_in].reshape(B, -1)[
         :, :k1 * spec.conv_dim].reshape(B, k1, spec.conv_dim)
     return jnp.where(fresh[:, None, None], 0, win)
+
+
+def read_state(pool: jax.Array, layer, slot_in: jax.Array) -> jax.Array:
+    """Layer `layer`'s recurrent states in slots `slot_in` [B] of the pool
+    [L, slots, heads, head_dim, N] -> [B, heads, head_dim, N].  A state
+    wider than a lane tile (falcon_h1's N 256) is sliced out row by row:
+    the TPU compiler gathers several rows of such a pool through a COPY OF
+    THE POOL in 128-lane halves (AOT for a v5e, PR 59: two temporaries of
+    1.42 GB beside a 3 GB pool, and the four-row step no longer fit the
+    chip); a slice a row is a plain DMA."""
+    if pool.shape[-1] <= 128:
+        return pool[layer, slot_in]
+    zero = jnp.zeros((), jnp.int32)
+    return jnp.concatenate([jax.lax.dynamic_slice(
+        pool, (jnp.asarray(layer, jnp.int32), slot_in[b], zero, zero, zero),
+        (1, 1, *pool.shape[2:]))[0] for b in range(slot_in.shape[0])])
 
 
 def write_states(kv: StateCache, states, slot_out: jax.Array,

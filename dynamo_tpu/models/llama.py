@@ -451,7 +451,10 @@ def _qk_norm(lp: Params, q: jax.Array, k: jax.Array, cfg: ModelConfig):
                 rms_norm(k, lp["k_head_norm"], cfg.rms_norm_eps))
 
 
-def _mlp(lp: Params, x: jax.Array) -> jax.Array:
+def _mlp(lp: Params, x: jax.Array, mults=None) -> jax.Array:
+    """The dense SwiGLU; `mults` (`ModelConfig.mlp_multipliers`) scales the
+    gate's product before the activation and the down projection's, both in
+    float32."""
     if "w_gateup" in lp:  # fused gate‖up read (see _qkv_proj)
         y = matmul_any(x, lp["w_gateup"], "bsh,hf->bsf")
         f = y.shape[-1] // 2
@@ -459,8 +462,13 @@ def _mlp(lp: Params, x: jax.Array) -> jax.Array:
     else:
         gate = matmul_any(x, lp["w_gate"], "bsh,hf->bsf")
         up = matmul_any(x, lp["w_up"], "bsh,hf->bsf")
+    if mults is not None:
+        gate = gate * mults[0]
     act = jax.nn.silu(gate) * up
-    return matmul_any(act.astype(x.dtype), lp["w_down"], "bsf,fh->bsh").astype(x.dtype)
+    out = matmul_any(act.astype(x.dtype), lp["w_down"], "bsf,fh->bsh")
+    if mults is not None:
+        out = out * mults[1]
+    return out.astype(x.dtype)
 
 
 def fuse_projections(params: Params) -> Params:
@@ -1109,7 +1117,7 @@ def _feed_forward(lp: Params, x: jax.Array, x_in: jax.Array,
         if "router" not in lp:
             zeros = (jnp.zeros((moe_stats_columns(cfg),), jnp.int32),
                      ) if moe_stats else ()
-            return (_mlp(lp, mlp_in), *zeros)
+            return (_mlp(lp, mlp_in, cfg.mlp_multipliers), *zeros)
         router_x = x_in if cfg.moe_router_pre_attn else None
         if not moe_stats:
             return (_moe(lp, mlp_in, cfg, router_x, stacks=stacks),)
@@ -1507,7 +1515,10 @@ def _streams(cfg: ModelConfig, x: jax.Array) -> jax.Array:
     """Embedded tokens [..., h] as the residual the layer loops carry:
     themselves, or under hyper-connections every stream alike, a token's n
     streams side by side [..., n x h] (the one layout the mixers' kernels
-    take as a dense block, `ops/pallas_hyper_connections.py`)."""
+    take as a dense block, `ops/pallas_hyper_connections.py`).  A family's
+    `embedding_multiplier` (falcon_h1) scales them first, in float32."""
+    if cfg.embedding_multiplier != 1.0:
+        x = (x.astype(jnp.float32) * cfg.embedding_multiplier).astype(x.dtype)
     if not cfg.hc_mult:
         return x
     return jnp.concatenate([x] * cfg.hc_mult, axis=-1)
@@ -1538,9 +1549,13 @@ def _lm_logits(params: Params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
                 "untied model params are missing 'lm_head' — falling back "
                 "to embed.T would silently produce wrong logits"
             )
-        return jnp.einsum("...h,hv->...v", x, params["embed"].T,
-                          preferred_element_type=jnp.float32)
-    return matmul_any(x, head, "...h,hv->...v")
+        logits = jnp.einsum("...h,hv->...v", x, params["embed"].T,
+                            preferred_element_type=jnp.float32)
+    else:
+        logits = matmul_any(x, head, "...h,hv->...v")
+    if cfg.lm_head_multiplier != 1.0:
+        logits = logits * cfg.lm_head_multiplier
+    return logits
 
 
 def prefill_layers(

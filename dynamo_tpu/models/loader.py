@@ -88,6 +88,8 @@ def load_params(path: str, cfg: ModelConfig, dtype=jnp.bfloat16,
         return _load_deepseek_v3(r, cfg, dtype, prefix)
     if cfg.cross_decoder:
         return _load_phi4flash(r, cfg, dtype, prefix)
+    if cfg.model_type == "falcon_h1":
+        return _load_falcon_h1(r, cfg, dtype, prefix)
     if cfg.layer_pattern is not None:
         return _load_nemotron_h(r, cfg, dtype, prefix)
     if cfg.model_type == "lfm2_moe":
@@ -314,6 +316,57 @@ def _load_nemotron_h(r: "_ShardReader", cfg: ModelConfig, dtype,
             "ws_down": stack("E", "mixer.shared_experts.down_proj.weight"),
         },
     }
+    return params
+
+
+def _load_falcon_h1(r: "_ShardReader", cfg: ModelConfig, dtype,
+                    prefix: str = ""):
+    """falcon_h1 tensor names (`model.layers.{i}.{input_layernorm, mamba.*,
+    self_attn.*, pre_ff_layernorm, feed_forward.*}`) -> `models.hybrid.
+    init_params`'s ONE stack of layers that are both mixers and a dense
+    feed-forward.  The step-size bias, `A_log` and `D` are kept float32
+    whatever the file's dtype; the convolution's [conv_dim, 1, K] weight is
+    stored tap-major [K, conv_dim].  No multiplier touches a weight."""
+    def stack(name, fn=lambda w: w.T, dt=dtype):
+        return jnp.asarray(np.stack([fn(r.get(
+            prefix + f"model.layers.{i}.{name}"))
+            for i in range(cfg.num_hidden_layers)]), dt)
+
+    def same(w):
+        return w
+
+    def f32(name):
+        return stack(name, lambda w: w.astype(np.float32), jnp.float32)
+
+    params = {
+        "embed": jnp.asarray(r.get(prefix + "model.embed_tokens.weight"),
+                             dtype),
+        "final_norm": jnp.asarray(
+            r.get(prefix + "model.final_layernorm.weight"), dtype),
+        "par_layers": {
+            "norm": stack("input_layernorm.weight", same),
+            "in_proj": stack("mamba.in_proj.weight"),
+            "conv_w": stack("mamba.conv1d.weight",
+                            lambda w: w.reshape(w.shape[0], -1).T),
+            "conv_b": stack("mamba.conv1d.bias", same),
+            "dt_bias": f32("mamba.dt_bias"),
+            "A_log": f32("mamba.A_log"),
+            "D": f32("mamba.D"),
+            "gate_norm": stack("mamba.norm.weight", same),
+            "out_proj": stack("mamba.out_proj.weight"),
+            "wq": stack("self_attn.q_proj.weight"),
+            "wk": stack("self_attn.k_proj.weight"),
+            "wv": stack("self_attn.v_proj.weight"),
+            "wo": stack("self_attn.o_proj.weight"),
+            "mlp_norm": stack("pre_ff_layernorm.weight", same),
+            "w_gate": stack("feed_forward.gate_proj.weight"),
+            "w_up": stack("feed_forward.up_proj.weight"),
+            "w_down": stack("feed_forward.down_proj.weight"),
+        },
+    }
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = jnp.asarray(r.get(prefix + "lm_head.weight").T,
+                                        dtype)
     return params
 
 

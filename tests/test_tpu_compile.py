@@ -663,6 +663,26 @@ def test_latent_prefill_step_leaves_the_pool_where_it_is(one_chip,
     assert mem.temp_size_in_bytes < (300 << 20 if kernel else 400 << 20)
 
 
+# -- states wider than a lane tile (the falcon_h1 cell) ---------------------------- #
+
+def test_a_shared_step_slices_wide_states_out_of_the_pool_row_by_row(one_chip):
+    """The Falcon-H1 cell's `[4, 64]` shared step (published widths, two of
+    its layers, 64 state slots: a pool of 2 x 64 x [32, 128, 256] float32,
+    537 MB): its temporaries stay under the pool's own size.  With the rows'
+    states gathered as `pool[layer, slots]` the compiler reads a pool whose
+    last axis is two lane tiles through a COPY OF THE POOL in 128-lane
+    halves: 819 MB of temporaries here where `hybrid.read_state`'s slice a
+    row leaves 206 (AOT, PR 59), and at the cell's 137 slots and six layers
+    the step did not fit the chip (16.99 GB).  A size, never a time."""
+    cfg, step, args = _published_step(
+        one_chip, "dense", "falcon-h1-34b-h6", 64, slots=64, rows=4,
+        num_hidden_layers=2)
+    pool = args[1].ssm
+    assert pool.shape[-1] == 256  # N: wider than a lane tile
+    mem = step.lower(*args).compile().memory_analysis()
+    assert mem.temp_size_in_bytes < pool.size * pool.dtype.itemsize
+
+
 # -- a residual of several streams (the xing4_0 cell) ------------------------------ #
 
 # ops that hand an array on between HBM and VMEM as it is laid out: the
