@@ -1569,6 +1569,16 @@ class JaxEngine:
             site, batch=batch, chunk=chunk,
             table_tokens=pages * self.cfg.page_size) or self.layout.attn_impl
 
+    def _scan_of(self, batch: int, chunk: int) -> dict:
+        """`scan`: the form ("pallas" | "xla") the Mamba-2 scan of a prefill
+        step of this shape was traced into (`ops.ssm.scan` notes it per
+        shape); no key for a model without such a layer."""
+        cfg = self.model_cfg
+        if not cfg.ssm_heads or cfg.ssm_dt_rank:  # none, or Mamba-1's
+            return {}
+        choice = xla_ledger.path_choice("ssm_scan", rows=batch, chunk=chunk)
+        return {"scan": choice} if choice else {}
+
     @staticmethod
     def _credit_own(seqs, ns: int) -> None:
         """A step's slice goes to the working time of each of its sequences
@@ -1772,7 +1782,7 @@ class JaxEngine:
             attn=self._attn_of("prefill_attention", B, chunk_bucket,
                                table.shape[1]),
             overlapped=overlapped, head=head, seq=ordinal, dry=dry,
-            hop_us=hop_us, **cross)
+            hop_us=hop_us, **cross, **self._scan_of(B, chunk_bucket))
         if len(items) > 1:
             # a shared step: each row's own chunk and context, in row
             # order (`tokens` is their sum, `ctx` the longest)
@@ -2163,7 +2173,7 @@ class JaxEngine:
             attn=self._attn_of("prefill_attention", Bp, chunk_bucket,
                                p_table.shape[1]),
             head=head, seq=ordinal, dry=self._dispatched_dry(True),
-            hop_us=hop_us, **moe_attrs,
+            hop_us=hop_us, **moe_attrs, **self._scan_of(Bp, chunk_bucket),
             **self._step_phases(pseqs + dseqs, t0_ev, t_call, t_fetch,
                                 t_got),
         )

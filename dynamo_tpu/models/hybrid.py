@@ -220,15 +220,15 @@ def _mamba(lp: Params, u: jax.Array, cfg: ModelConfig, window: jax.Array,
     with jax.named_scope("ssm.scan"):
         step = jax.nn.softplus(dt.astype(jnp.float32) + lp["dt_bias"])
         step = jnp.where(_valid_rows(u, chunk_lens)[..., None], step, 0.0)
+        # the gated norm rides the scan: its kernel's epilogue (which reads
+        # z where `in_proj` left it), or `ssm.gate_norm` after the `jnp` form
         y, h, hs = ssm.scan(
             xbc[..., :d].reshape(B, S, nh, hp), step, -jnp.exp(lp["A_log"]),
             xbc[..., d:d + G * N].reshape(B, S, G, N),
             xbc[..., d + G * N:].reshape(B, S, G, N), lp["D"], h0,
-            cfg.ssm_chunk, at)
+            cfg.ssm_chunk, at,
+            gate=(z, lp["gate_norm"], cfg.rms_norm_eps, zxd))
         inside = list(zip(wins, hs))
-    with jax.named_scope("ssm.gate_norm"):
-        y = ssm.gate_norm(y.reshape(B, S, d), z, lp["gate_norm"], G,
-                          cfg.rms_norm_eps)
     with jax.named_scope("ssm.out_proj"):
         out = matmul_any(y, lp["out_proj"], "bsd,dh->bsh")
         if cfg.ssm_out_multiplier != 1.0:

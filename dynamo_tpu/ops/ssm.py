@@ -22,6 +22,16 @@ duality" form): inside a block the outputs are one masked [chunk, chunk]
 product per head, a segment-sum of the log decays standing where attention
 has its softmax; between blocks only `H` is carried.  It is the same
 function of its inputs as the token-by-token loop, not another model.
+
+Where the blocks run.  On a single TPU device a prefill chunk whose states
+are handed out at block ends (the served path's 128-, 256- and 512-token
+chunks) is ONE Pallas kernel a layer (`ops/pallas_ssm.py` `scan_pallas`:
+a block's decays, scores and the group's state stay in VMEM, the gated norm
+that follows the scan rides its epilogue).  Everywhere else (the CPU, a
+mesh, a short row that hands its state out after every page, widths the
+kernel's tiles do not hold) they are `scan_blocks`' plain `jnp`, which is
+also what the kernel is tested against.  `pallas_ssm.scan_lowering` decides
+from the trace's static shapes and `scan` notes the choice (`ssm_scan`).
 """
 
 from __future__ import annotations
@@ -30,6 +40,9 @@ import math
 
 import jax
 import jax.numpy as jnp
+
+from ..analysis import xla_ledger
+from . import pallas_ssm
 
 
 def conv(xbc: jax.Array, window: jax.Array, w: jax.Array, b,
@@ -63,7 +76,7 @@ def conv(xbc: jax.Array, window: jax.Array, w: jax.Array, b,
 
 def scan(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
          Cm: jax.Array, D: jax.Array, h0: jax.Array, chunk: int,
-         at: tuple = ()):
+         at: tuple = (), gate: tuple | None = None):
     """The selective scan over S tokens from state `h0`.
 
     x [B, S, nh, hp]; dt [B, S, nh] float32, already softplus'd and ZERO at
@@ -74,12 +87,59 @@ def scan(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
     -> (y [B, S, nh, hp] in x's dtype, h [B, nh, hp, N] float32, and the
     state after each of `at`'s token counts, a list: at a block's end the
     scan has it anyway, inside a block it costs the block's state update
-    over the tokens before it; a caller may keep one as a snapshot)."""
+    over the tokens before it; a caller may keep one as a snapshot).
+
+    `gate` (z [B, S, nh x hp], w [nh x hp], eps): the gated norm that
+    follows the scan in a Mamba-2 mixer; y is then [B, S, nh x hp] and
+    `gate_norm(y, z, w, G, eps)` of the scan's.  A fourth member is the
+    array z is the FIRST columns of (`in_proj`'s output): the kernel reads
+    the gate there, where a slice handed to it is a copy of its own (13.5 +
+    4.5 us a nemotron_h layer of 512 tokens on the v5e: PERF.md finding 40).
+
+    Two lowerings of the one function, chosen from the trace's static
+    shapes (`pallas_ssm.scan_lowering`, noted as path choice `ssm_scan`):
+    ONE Pallas kernel on a single TPU device where every hand-out falls on a
+    block's end (the gated norm in its epilogue: a grid step holds a whole
+    group of the norm), `scan_blocks`' plain `jnp` elsewhere (the CPU, a
+    mesh, a short row that hands out inside a block, widths the kernel's
+    tiles do not hold)."""
+    B, S, nh, hp = x.shape
+    G, N = Bm.shape[2:]
+    if S % min(chunk, S):
+        raise ValueError(
+            f"scan: {S} tokens are not whole blocks of {min(chunk, S)}")
+    interpret, why = pallas_ssm.scan_lowering(x, G, N, chunk, at)
+    xla_ledger.note_path_choice(
+        "ssm_scan", "xla" if interpret is None else "pallas", why, rows=B,
+        chunk=S)
+    if interpret is None:
+        y, h, hs = scan_blocks(x, dt, A, Bm, Cm, D, h0, chunk, at)
+        if gate is not None:
+            z, w, eps = gate[:3]
+            with jax.named_scope("ssm.gate_norm"):
+                y = gate_norm(y.reshape(B, S, nh * hp), z, w, G, eps)
+        return y, h, hs
+    block = pallas_ssm.scan_block(S, chunk, at)
+    if gate is None:
+        y, hs = pallas_ssm.scan_pallas(x, dt, A, Bm, Cm, D, h0, block,
+                                       interpret=interpret)
+        y = y.reshape(x.shape)
+    else:
+        z, w, eps, *whole = gate
+        y, hs = pallas_ssm.scan_pallas(
+            x, dt, A, Bm, Cm, D, h0, block, (whole[0] if whole else z, w),
+            float(eps), interpret=interpret)
+    return y, hs[-1], [hs[t // block - 1] for t in at]
+
+
+def scan_blocks(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
+                Cm: jax.Array, D: jax.Array, h0: jax.Array, chunk: int,
+                at: tuple = ()):
+    """`scan` as plain `jnp`: the blocks one after the other, a block's
+    decays and scores [heads, Q, Q] arrays of the program."""
     B, S, nh, hp = x.shape
     G, N = Bm.shape[2:]
     Q = min(chunk, S)
-    if S % Q:
-        raise ValueError(f"scan: {S} tokens are not whole blocks of {Q}")
     nc, per = S // Q, nh // G
     f32 = jnp.float32
 
@@ -174,8 +234,6 @@ def selective_scan(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
     `kernel` and `interpret` are a check's: the other form on this backend,
     and the kernel interpreted where no TPU is (`scripts/
     check_selective_scan.py`)."""
-    from . import pallas_ssm
-
     f32 = jnp.float32
     S, C = x.shape[1:]
     if kernel is None:

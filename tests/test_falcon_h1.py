@@ -12,6 +12,7 @@ REMEMBERS) and multipliers under which attention is SHARP, so that what the
 benchmark's one draw hides from `correct` (PERF.md section 7) shows here.
 """
 
+import contextlib
 import dataclasses
 import json
 import os
@@ -22,12 +23,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from dynamo_tpu.analysis import xla_ledger
 from dynamo_tpu.engine import EngineConfig, JaxEngine
 from dynamo_tpu.models import KVCache, ModelConfig, init_params
 from dynamo_tpu.models import hybrid, llama
 from dynamo_tpu.models.loader import load_params
 from dynamo_tpu.models.quantization import matmul_any
-from dynamo_tpu.ops import ssm
+from dynamo_tpu.ops import pallas_moe, ssm
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(ROOT, "benchmark")
@@ -533,28 +535,108 @@ def every16(monkeypatch):
     monkeypatch.setattr(hybrid, "SNAPSHOT_BLOCKS", 2)
 
 
-@pytest.mark.parametrize("tokens,at", [(64, (16, 32, 48)), (32, (8, 16, 24))],
-                         ids=["every-interval", "short-row-every-page"])
+# the tiny model at widths the scan's kernel holds: 2 heads of 128, one
+# group, 256 state values, blocks of 128 tokens
+KERNEL_TINY = dict(TINY, mamba_n_heads=2, mamba_d_head=128, mamba_d_ssm=256,
+                   mamba_d_state=256, mamba_n_groups=1, mamba_chunk_size=128)
+
+
+@pytest.mark.parametrize("form,tokens,at", [
+    ("jnp", 64, (16, 32, 48)), ("jnp", 32, (8, 16, 24)),
+    ("kernel", 512, (128, 256, 384))],
+    ids=["every-interval", "short-row-every-page", "kernel-block-ends"])
 def test_the_scan_hands_out_the_state_inside_a_chunk(cfg, params, every16,
+                                                     monkeypatch, form,
                                                      tokens, at):
     """The slots named in the table's last columns take the state after each
     of `at`'s token counts, each what a prefill of that many tokens alone
-    leaves, and the chunk's own slot its state after all of them."""
+    leaves, and the chunk's own slot its state after all of them; through
+    the `jnp` blocks, and through the scan's kernel (interpreted; a model at
+    widths its tiles hold, handing out at its blocks' ends)."""
+    if form == "kernel":
+        monkeypatch.setattr(hybrid, "SNAPSHOT_BLOCKS", 1)
+        cfg = ModelConfig.from_hf_config(KERNEL_TINY, name="tiny-kernel")
+        params = init_params(cfg, jax.random.PRNGKey(60), dtype=jnp.float32)
     assert hybrid._inside(cfg, tokens, PAGE) == at  # noqa: SLF001
     toks = prompt(tokens, 9)
-    kv0 = fresh_cache(cfg, 64, slots=8)
-    _, kv = llama.forward_prefill(
-        params, cfg, kv0, jnp.asarray([toks], jnp.int32),
-        table_for(64, [0, 1, 2, 3, 4]), jnp.zeros((1,), jnp.int32),
-        jnp.asarray([tokens], jnp.int32))
+    kv0 = fresh_cache(cfg, tokens, slots=8)
+
+    def prefill(toks, slots):
+        with pallas_moe.checked(interpret=True) if form == "kernel" else (
+                contextlib.nullcontext()):
+            return llama.forward_prefill(
+                params, cfg, kv0, jnp.asarray([toks], jnp.int32),
+                table_for(tokens, slots), jnp.zeros((1,), jnp.int32),
+                jnp.asarray([len(toks)], jnp.int32))[1]
+
+    kv = prefill(toks, [0, 1, 2, 3, 4])
+    assert xla_ledger.path_choice("ssm_scan", rows=1, chunk=tokens) == (
+        "pallas" if form == "kernel" else "xla")
     for slot, n in (*zip((2, 3, 4), at), (1, tokens)):
-        _, alone = llama.forward_prefill(
-            params, cfg, kv0, jnp.asarray([toks[:n]], jnp.int32),
-            table_for(64, [0, 5]), jnp.zeros((1,), jnp.int32),
-            jnp.asarray([n], jnp.int32))
+        alone = prefill(toks[:n], [0, 5])
         for pool, want in ((kv.ssm, alone.ssm), (kv.conv, alone.conv)):
             assert np.abs(np.asarray(pool[:, slot] - want[:, 5])).max() < 1e-5
     assert not np.asarray(kv.ssm[:, 5:]).any()
+
+
+@pytest.mark.parametrize("rows,tokens", [(1, 128), (4, 200), (1, 512)],
+                         ids=["1-block", "4-rows-2-blocks", "4-blocks"])
+def test_the_scan_kernel_is_the_recurrence_at_this_family_s_widths(rows,
+                                                                   tokens):
+    """`ops.ssm.scan` through its kernel (interpreted) at this family's
+    geometry, 16 heads of 128 a group and 256 state values, from a carried
+    state, rows that end at unequal lengths (a zero step size past each):
+    against the token-by-token loop and against the `jnp` blocks, every
+    handed-out state compared."""
+    from test_nemotron_h import recurrence, scan_in_form, scan_operands
+
+    rng = np.random.default_rng(tokens)
+    ops, S = scan_operands(rng, "kernel", (16, 128, 1, 256), rows, 128,
+                           tokens)
+    at = tuple(range(128, S, 128))
+    y, h, hs = scan_in_form("kernel", ops, 128, at)
+    assert len(hs) == len(at)
+    recurrence(ops, tokens, at, y, h, hs)
+    for got, want in zip((y, h, *hs), jax.tree.leaves(
+            ssm.scan_blocks(*map(jnp.asarray, ops), 128, at))):
+        assert np.abs(np.asarray(got - want)).max() < 2e-5
+
+
+def test_the_gated_norm_rides_the_scan_kernel():
+    """`scan(.., gate=(z, w, eps))`: the kernel's epilogue is `gate_norm`
+    of the scan's y over the groups (a grid step holds one whole group of
+    the norm), as the `jnp` form applies it after its blocks; float32 to
+    rounding, bf16 to the served dtype's last bit.  The gate may come with
+    the array it is the first columns of (`in_proj`'s output)."""
+    from test_nemotron_h import scan_in_form, scan_operands
+
+    for dtype, tol in ((np.float32, 2e-5), (jnp.bfloat16, 1 / 64)):
+        rng = np.random.default_rng(3)
+        ops, S = scan_operands(rng, "kernel", (32, 128, 2, 256), 2, 128, 256,
+                               dtype)
+        z = jnp.asarray(rng.standard_normal((2, S, 4096)), dtype)
+        w = jnp.asarray(1 + 0.1 * rng.standard_normal(4096), dtype)
+        ops = tuple(map(jnp.asarray, ops))
+        want, want_h, _ = ssm.scan(*ops, 128, (128,), gate=(z, w, 1e-5))
+        with pallas_moe.checked(interpret=True):
+            got, got_h, _ = ssm.scan(*ops, 128, (128,), gate=(z, w, 1e-5))
+        assert got.shape == want.shape == (2, S, 4096)
+        assert got.dtype == want.dtype == ops[0].dtype
+        scale = float(jnp.abs(want.astype(jnp.float32)).max())
+        assert np.abs(np.asarray(got.astype(jnp.float32)
+                                 - want.astype(jnp.float32))).max() < (
+            tol * max(scale, 1.0))
+        assert np.abs(np.asarray(got_h - want_h)).max() < (
+            2e-5 if dtype is np.float32 else 0.05)
+        # and z read where it lies, the first columns of a wider array (no
+        # whole number of the kernel's blocks): the same bits
+        whole = jnp.concatenate(
+            [z, jnp.asarray(rng.standard_normal((2, S, 1056)), dtype)], -1)
+        with pallas_moe.checked(interpret=True):
+            there, _, _ = ssm.scan(*ops, 128, (128,),
+                                   gate=(z, w, 1e-5, whole))
+        assert np.array_equal(np.asarray(there.astype(jnp.float32)),
+                              np.asarray(got.astype(jnp.float32)))
 
 
 def test_every_fault_is_a_keyword_of_forward(ref):

@@ -9,6 +9,7 @@ count and trace readers.  Tiny sizes, float32, seeded weights (`A_log`,
 """
 
 import asyncio
+import contextlib
 import dataclasses
 import json
 import os
@@ -19,6 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from dynamo_tpu.analysis import xla_ledger
 from dynamo_tpu.engine import EngineConfig, JaxEngine
 from dynamo_tpu.engine.page_pool import PagePool, StatePool
 from dynamo_tpu.models import KVCache, ModelConfig, init_params
@@ -399,29 +401,54 @@ def test_a_pattern_whose_units_lack_a_kind_agrees_too(ref):
         assert np.abs(got - want[pos]).max() < TOL
 
 
-@pytest.mark.parametrize("block,tokens", [(8, 64), (16, 48), (64, 40),
-                                          (8, 1)])
-def test_the_chunked_scan_is_the_recurrence(block, tokens):
-    """`ops.ssm.scan` at block sizes that do and do not divide the length
-    (the caller pads to whole blocks with a zero step size) against the
-    token-by-token loop, from a carried state."""
-    rng = np.random.default_rng(block + tokens)
-    B, nh, hp, G, N = 2, 4, 8, 2, 16
+# the scan's two forms: the blocks as plain `jnp` at tiny widths, and the
+# kernel (interpreted) at this family's geometry: 8 heads of 64 a group, 128
+# state values; (form, rows, block, tokens)
+SCAN_CASES = [("jnp", 2, 8, 64), ("jnp", 2, 16, 48), ("jnp", 2, 64, 40),
+              ("jnp", 2, 8, 1), ("kernel", 1, 128, 128),
+              ("kernel", 4, 128, 200), ("kernel", 1, 128, 512)]
+
+
+def scan_operands(rng, form, geometry, B, block, tokens, dtype=np.float32):
+    """(x, dt, A, Bm, Cm, D, h0) of a scan over `tokens` real tokens padded
+    to whole blocks (a zero step size past them; the rows after the first
+    end eight tokens earlier each), and S.  `geometry` (heads, head_dim,
+    groups, state) is the kernel's; the `jnp` form runs at tiny widths."""
+    nh, hp, G, N = geometry if form == "kernel" else (4, 8, 2, 16)
     S = -(-tokens // min(block, tokens)) * min(block, tokens)
-    x = rng.standard_normal((B, S, nh, hp)).astype(np.float32)
+    x = rng.standard_normal((B, S, nh, hp)).astype(dtype)
     dt = rng.uniform(0.001, 0.1, (B, S, nh)).astype(np.float32)
-    dt[:, tokens:] = 0.0
+    for b in range(B):
+        dt[b, max(tokens - 8 * b, 1):] = 0.0
     A = -rng.uniform(1, 16, nh).astype(np.float32)
-    Bm = rng.standard_normal((B, S, G, N)).astype(np.float32)
-    Cm = rng.standard_normal((B, S, G, N)).astype(np.float32)
+    Bm = (rng.standard_normal((B, S, G, N)) * N ** -0.5 * 4).astype(dtype)
+    Cm = (rng.standard_normal((B, S, G, N)) * N ** -0.5 * 4).astype(dtype)
     D = rng.standard_normal(nh).astype(np.float32)
     h0 = rng.standard_normal((B, nh, hp, N)).astype(np.float32)
-    # handed out after every whole block, and in the middle of each
-    at = tuple(sorted({*range(block, S, block),
-                       *range(block // 2, S, block)} - {0}))
-    y, h, hs = ssm.scan(*map(jnp.asarray, (x, dt, A, Bm, Cm, D, h0)), block,
-                        at)
-    assert len(hs) == len(at)
+    return (x, dt, A, Bm, Cm, D, h0), S
+
+
+def scan_in_form(form, ops, block, at):
+    """`ops.ssm.scan` in one of its two forms: where it chooses by itself
+    (the CPU: `jnp`), or under a check that interprets the kernel."""
+    ops = tuple(map(jnp.asarray, ops))
+    B, S = ops[0].shape[:2]
+    if form == "jnp":
+        out = ssm.scan(*ops, block, at)
+        want = "xla"
+    else:
+        with pallas_moe.checked(interpret=True):
+            out = ssm.scan(*ops, block, at)
+        want = "pallas"
+    assert xla_ledger.path_choice("ssm_scan", rows=B, chunk=S) == want
+    return out
+
+
+def recurrence(ops, tokens, at, y, h, hs, tol=2e-4):
+    """y, h and the states handed out after `at` against the token-by-token
+    loop over the first `tokens` positions."""
+    x, dt, A, Bm, Cm, D, h0 = (np.asarray(a, np.float32) for a in ops)
+    nh, G = x.shape[2], Bm.shape[2]
     H, group = h0.copy(), np.arange(nh) // (nh // G)
     for t in range(tokens):
         H = (np.exp(dt[:, t] * A)[..., None, None] * H
@@ -429,10 +456,130 @@ def test_the_chunked_scan_is_the_recurrence(block, tokens):
              * Bm[:, t, group][:, :, None, :])
         want = np.einsum("bhpn,bhn->bhp", H, Cm[:, t, group]) + (
             D[:, None] * x[:, t])
-        assert np.abs(np.asarray(y[:, t]) - want).max() < 2e-4, t
+        assert np.abs(np.asarray(y[:, t]) - want).max() < tol, t
         if t + 1 in at:  # handed out at a block's end, or inside a block
-            assert np.abs(np.asarray(hs[at.index(t + 1)]) - H).max() < 2e-4
-    assert np.abs(np.asarray(h) - H).max() < 2e-4
+            assert np.abs(np.asarray(hs[at.index(t + 1)]) - H).max() < tol
+    assert np.abs(np.asarray(h) - H).max() < tol
+
+
+@pytest.mark.parametrize("form,rows,block,tokens", SCAN_CASES,
+                         ids=lambda v: str(v))
+def test_the_chunked_scan_is_the_recurrence(form, rows, block, tokens):
+    """`ops.ssm.scan` in both forms, at block sizes that do and do not
+    divide the length (the caller pads to whole blocks with a zero step
+    size), against the token-by-token loop, from a carried state; the
+    kernel also against the `jnp` form, every handed-out state compared."""
+    rng = np.random.default_rng(block + tokens)
+    ops, S = scan_operands(rng, form, (16, 64, 2, 128), rows, block, tokens)
+    if form == "jnp":  # after every whole block, and in the middle of each
+        at = tuple(sorted({*range(block, S, block),
+                           *range(block // 2, S, block)} - {0}))
+    else:  # where the served path hands out: the blocks' ends
+        at = tuple(range(block, S, block))
+    y, h, hs = scan_in_form(form, ops, block, at)
+    assert len(hs) == len(at)
+    recurrence(ops, tokens, at, y, h, hs)
+    if form == "kernel":
+        for got, want in zip((y, h, *hs), jax.tree.leaves(
+                ssm.scan_blocks(*map(jnp.asarray, ops), block, at))):
+            assert np.abs(np.asarray(got - want)).max() < 2e-5
+
+
+def test_the_scan_kernel_rounds_as_the_blocks_do_in_the_served_dtype():
+    """bf16 x, B and C: the kernel's y and states lie within the served
+    dtype's rounding of the `jnp` form's (on a TPU both hand the matrix unit
+    bf16 operands; here the `jnp` form's state products stay float32)."""
+    rng = np.random.default_rng(7)
+    ops, S = scan_operands(rng, "kernel", (16, 64, 2, 128), 2, 128, 256,
+                           jnp.bfloat16)
+    y, h, hs = scan_in_form("kernel", ops, 128, (128,))
+    want_y, want_h, want_hs = ssm.scan_blocks(*map(jnp.asarray, ops), 128,
+                                              (128,))
+    assert y.dtype == jnp.bfloat16 and h.dtype == jnp.float32
+    scale = float(jnp.abs(want_y.astype(jnp.float32)).max())
+    assert np.abs(np.asarray(y.astype(jnp.float32)
+                             - want_y.astype(jnp.float32))).max() < scale / 64
+    for got, want in ((h, want_h), (hs[0], want_hs[0])):
+        assert np.abs(np.asarray(got - want)).max() < float(
+            jnp.abs(want).max()) / 128
+
+
+@pytest.mark.parametrize("shape,at,check,choice", [
+    ((1, 512, 64, 64, 8, 128), (128, 256, 384), False, "pallas"),
+    ((1, 128, 64, 64, 8, 128), (), False, "pallas"),
+    ((1, 256, 32, 128, 2, 256), (128,), True, "pallas"),
+    ((4, 64, 64, 64, 8, 128), (16, 32, 48), False, "xla"),  # a short row
+    ((1, 512, 64, 64, 8, 128), (64, 128), False, "xla"),  # inside a block
+    ((1, 512, 8, 8, 2, 16), (128,), False, "xla"),  # no whole lane tiles
+    ((1, 512, 64, 64, 8, 128), (128, 256, 384), None, "xla"),  # the CPU
+], ids=["512", "128", "falcon-256", "short-row", "inside-a-block",
+        "narrow", "cpu"])
+def test_the_scan_takes_the_kernel_where_its_blocks_end_at_the_hand_outs(
+        shape, at, check, choice):
+    """`pallas_ssm.scan_lowering` over static shapes (rows, tokens, heads,
+    head_dim, groups, state) and the hand-outs: the 128-, 256- and
+    512-token chunks of both geometries are the kernel's under a check (and
+    on a single TPU device); a short row, a hand-out inside a block, widths
+    its tiles do not hold, the CPU by itself and a mesh keep `jnp`."""
+    from dynamo_tpu.ops import pallas_ssm
+
+    B, S, nh, hp, G, N = shape
+    x = jax.ShapeDtypeStruct((B, S, nh, hp), jnp.bfloat16)
+
+    def lowering(x):
+        return pallas_ssm.scan_lowering(x, G, N, 128, at)
+
+    if check is None:
+        interpret, why = lowering(x)
+    else:
+        with pallas_moe.checked(interpret=check):
+            interpret, why = lowering(x)
+    assert (None if interpret is None else "pallas", interpret) == (
+        None if choice == "xla" else "pallas",
+        check if choice == "pallas" else None), why
+    assert pallas_ssm.scan_block(S, 128, at) == (
+        16 if at == (16, 32, 48) else 64 if at == (64, 128) else 128)
+
+
+def test_a_mesh_keeps_the_scan_as_jnp(monkeypatch):
+    """On a TPU backend an operand that lies on a mesh keeps the `jnp` form
+    (a Pallas call does not partition) and one that does not takes the
+    kernel; the backend is this test's say-so, nothing is compiled."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from dynamo_tpu.ops import pallas_ssm
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = jax.make_mesh((2,), ("x",))
+    x = jnp.zeros((2, 256, 16, 64), jnp.bfloat16)
+    seen = []
+
+    def trace(x):
+        seen.append(pallas_ssm.scan_lowering(x, 2, 128, 128, (128,))[0])
+        return x
+
+    jax.eval_shape(trace, x)
+    jax.jit(trace).lower(jax.device_put(
+        x, NamedSharding(mesh, PartitionSpec("x"))))
+    assert seen == [False, None]
+
+
+def test_a_step_slice_says_scan_only_for_a_model_with_a_mamba2_layer(cfg):
+    """`JaxEngine._scan_of`: the noted choice for the step's shape under
+    `scan`; no key before a trace noted one, and none for a model without a
+    Mamba-2 layer even where another model in the process noted that shape
+    (the ledger's choices are the process's)."""
+    import types
+
+    from dynamo_tpu.models import tiny_config
+
+    xla_ledger.note_path_choice("ssm_scan", "xla", "a test's", rows=3,
+                                chunk=48)
+    mine = types.SimpleNamespace(model_cfg=cfg)
+    assert JaxEngine._scan_of(mine, 3, 48) == {"scan": "xla"}  # noqa: SLF001
+    assert JaxEngine._scan_of(mine, 3, 40) == {}  # noqa: SLF001
+    other = types.SimpleNamespace(model_cfg=tiny_config())
+    assert JaxEngine._scan_of(other, 3, 48) == {}  # noqa: SLF001
 
 
 def test_pad_positions_leave_the_state_where_the_last_real_token_left_it(
@@ -493,30 +640,52 @@ def test_pad_positions_leave_the_state_where_the_last_real_token_left_it(
     assert not np.asarray(kv.ssm[:, 4:]).any()
 
 
-@pytest.mark.parametrize("tokens,at", [(64, (16, 32, 48)), (32, (8, 16, 24))],
-                         ids=["every-interval", "short-row-every-page"])
+# the tiny model at widths the scan's kernel holds: 2 heads of 64, one group,
+# 128 state values, blocks of 128 tokens
+KERNEL_TINY = dict(TINY, num_hidden_layers=3, hybrid_override_pattern="M*M",
+                   mamba_num_heads=2, mamba_head_dim=64, n_groups=1,
+                   ssm_state_size=128, chunk_size=128)
+
+
+@pytest.mark.parametrize("form,tokens,at", [
+    ("jnp", 64, (16, 32, 48)), ("jnp", 32, (8, 16, 24)),
+    ("kernel", 512, (128, 256, 384))],
+    ids=["every-interval", "short-row-every-page", "kernel-block-ends"])
 def test_the_scan_hands_out_the_state_inside_a_chunk(cfg, params, every16,
+                                                     monkeypatch, form,
                                                      tokens, at):
     """A 64-token chunk with snapshot positions every 16: the slots named in
     the table's last columns take the state after 16, 32 and 48 tokens, each
     what a prefill of that many tokens alone leaves (to rounding: another
     program), and the chunk's own slot its state after all 64.  A short row
-    (four pages of 8 at most) hands out after every page."""
+    (four pages of 8 at most) hands out after every page.  And through the
+    scan's kernel (interpreted; a model at widths its tiles hold): a
+    512-token chunk hands out at its blocks' ends, 128, 256 and 384."""
+    if form == "kernel":
+        monkeypatch.setattr(hybrid, "SNAPSHOT_BLOCKS", 1)
+        cfg = ModelConfig.from_hf_config(KERNEL_TINY, name="tiny-kernel")
+        params = init_params(cfg, jax.random.PRNGKey(45), dtype=jnp.float32)
+    else:
+        assert hybrid._inside(cfg, 16, PAGE) == (8,)  # noqa: SLF001
+        assert hybrid._inside(cfg, 1, PAGE) == ()  # noqa: SLF001
+        assert hybrid.handout_every(cfg, 128, 16) == 16
     assert hybrid._inside(cfg, tokens, PAGE) == at  # noqa: SLF001
-    assert hybrid._inside(cfg, 16, PAGE) == (8,)  # noqa: SLF001
-    assert hybrid._inside(cfg, 1, PAGE) == ()  # noqa: SLF001
-    assert hybrid.handout_every(cfg, 128, 16) == 16
     toks = prompt(tokens, 9)
-    kv0 = fresh_cache(cfg, 64, slots=8)
-    _, kv = llama.forward_prefill(
-        params, cfg, kv0, jnp.asarray([toks], jnp.int32),
-        table_for(64, [0, 1, 2, 3, 4]), jnp.zeros((1,), jnp.int32),
-        jnp.asarray([tokens], jnp.int32))
+    kv0 = fresh_cache(cfg, tokens, slots=8)
+
+    def prefill(toks, slots):
+        with pallas_moe.checked(interpret=True) if form == "kernel" else (
+                contextlib.nullcontext()):
+            return llama.forward_prefill(
+                params, cfg, kv0, jnp.asarray([toks], jnp.int32),
+                table_for(tokens, slots), jnp.zeros((1,), jnp.int32),
+                jnp.asarray([len(toks)], jnp.int32))[1]
+
+    kv = prefill(toks, [0, 1, 2, 3, 4])
+    assert xla_ledger.path_choice("ssm_scan", rows=1, chunk=tokens) == (
+        "pallas" if form == "kernel" else "xla")
     for slot, n in (*zip((2, 3, 4), at), (1, tokens)):
-        _, alone = llama.forward_prefill(
-            params, cfg, kv0, jnp.asarray([toks[:n]], jnp.int32),
-            table_for(64, [0, 5]), jnp.zeros((1,), jnp.int32),
-            jnp.asarray([n], jnp.int32))
+        alone = prefill(toks[:n], [0, 5])
         for pool, want in ((kv.ssm, alone.ssm), (kv.conv, alone.conv)):
             assert np.abs(np.asarray(pool[:, slot] - want[:, 5])).max() < 1e-5
     assert not np.asarray(kv.ssm[:, 5:]).any()

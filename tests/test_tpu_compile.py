@@ -486,7 +486,7 @@ def _laguna_step(one_chip, impl):
 @pytest.mark.parametrize("family,impl,kernels,temp_bytes", [
     (_smallthinker_step, "dense", 1, 1 << 30),
     (_smallthinker_step, "ragged", 2, 1 << 26),
-    (_nemotron_share_step, "ragged", 2, 1 << 27),
+    (_nemotron_share_step, "ragged", 3, 1 << 27),
     (_laguna_step, "ragged", 3, 1 << 27)],
     ids=["all-experts", "dispatched", "nemotron-share-dispatched",
          "laguna-256-dispatched"])
@@ -505,7 +505,9 @@ def test_expert_prefill_step_reads_the_expert_stacks_in_place(
     size under the lanes: the kernel takes that stack viewed [L x E, f, h],
     which is those bytes (`pallas_moe.f_major`); viewed [L x E, h, f] its
     operand was a copy of the stack, 3.7 GB at 23 layers (PR 53).  Laguna's
-    two kinds of layers have an attention kernel each."""
+    two kinds of layers have an attention kernel each, and Nemotron's
+    state-space layers the blocked scan's (`ssm.scan`, PR 60: the same check
+    switches it on)."""
     import math
     import re
 
@@ -663,6 +665,18 @@ def test_latent_prefill_step_leaves_the_pool_where_it_is(one_chip,
     assert mem.temp_size_in_bytes < (300 << 20 if kernel else 400 << 20)
 
 
+def _loop_bodies(text):
+    """{computation: its lines} of the optimised HLO, fused computations'
+    bodies left out."""
+    bodies, name = {}, None
+    for line in text.splitlines():
+        if line and not line[0].isspace():
+            name = line.split("(")[0].strip()
+        elif "fused_computation" not in name:
+            bodies.setdefault(name, []).append(line)
+    return bodies
+
+
 # -- states wider than a lane tile (the falcon_h1 cell) ---------------------------- #
 
 def test_a_shared_step_slices_wide_states_out_of_the_pool_row_by_row(one_chip):
@@ -681,6 +695,64 @@ def test_a_shared_step_slices_wide_states_out_of_the_pool_row_by_row(one_chip):
     assert pool.shape[-1] == 256  # N: wider than a lane tile
     mem = step.lower(*args).compile().memory_analysis()
     assert mem.temp_size_in_bytes < pool.size * pool.dtype.itemsize
+
+
+# -- Mamba-2's blocked scan (the nemotron_h and falcon_h1 cells) ------------------- #
+
+@pytest.mark.parametrize("config,impl,over,layers", [
+    ("nemotron3-nano-30b-ep8", "ragged",
+     dict(num_hidden_layers=7, hybrid_override_pattern="MEM*EME"), 3),
+    ("falcon-h1-34b-h6", "dense", dict(num_hidden_layers=2), 2),
+], ids=["nemotron", "falcon-h1"])
+def test_the_blocked_scan_is_one_kernel_a_layer_and_no_block_reaches_hbm(
+        one_chip, config, impl, over, layers):
+    """The two cells' 1 x 512 `prefill_step` (published widths, a few
+    layers) with the scan as its kernel (ISSUE 60): the layer loop's body
+    holds exactly ONE `ssm.scan` custom call (one a state-space layer: the
+    loop runs a unit a turn), and nowhere in the program is there a float32
+    array that ends in [heads, Q, Q] or [groups, Q, Q], the blocks' decays
+    and scores that the `jnp` form wrote four to five times a block (AOT,
+    PR 60: `f32[64,128,128]`, 4 MB each, at nemotron_h's widths).  The
+    four-row short step keeps the `jnp` form (its states are handed out
+    inside a block) and is the parent's program: no kernel, the same
+    temporaries.  Counts and shapes, never a time."""
+    import re
+
+    from dynamo_tpu.analysis import xla_ledger
+    from dynamo_tpu.ops import pallas_moe
+
+    cfg, step, args = _published_step(one_chip, impl, config, 512, slots=8,
+                                      **over)
+    assert cfg.layer_pattern.count("M") + cfg.layer_pattern.count("P") == (
+        layers)
+    with pallas_moe.checked(interpret=False):
+        text = step.lower(*args).compile().as_text()
+    assert xla_ledger.path_choice("ssm_scan", rows=1, chunk=512) == "pallas"
+    call = re.compile(r"(%[\w.]+) = [^\n]*custom_call_target="
+                      r"\"tpu_custom_call\"")
+    scans = [[c for c in call.findall("\n".join(body))
+              if c.startswith("%ssm.scan")]
+             for body in _loop_bodies(text).values()]
+    assert sorted(len(s) for s in scans if s) == [1]
+    nh, G, Q = cfg.ssm_heads, cfg.ssm_groups, cfg.ssm_chunk
+    assert not re.findall(r"f32\[(?:\d+,)*(?:%d|%d),%d,%d\]" % (nh, G, Q, Q),
+                          text)
+    # and as `jnp` they are there: the pattern finds what it looks for
+    _, step, args = _published_step(one_chip, impl, config, 512, slots=8,
+                                    **over)
+    assert re.findall(r"f32\[(?:\d+,)*(?:%d|%d),%d,%d\]" % (nh, G, Q, Q),
+                      step.lower(*args).compile().as_text())
+    # the short shared step: the `jnp` form, with or without the check
+    _, step, args = _published_step(one_chip, impl, config, 64, slots=8,
+                                    rows=4, **over)
+    with pallas_moe.checked(interpret=False):
+        checked = step.lower(*args).compile()
+    assert xla_ledger.path_choice("ssm_scan", rows=4, chunk=64) == "xla"
+    assert "%ssm.scan" not in checked.as_text()
+    if impl == "dense":  # no other kernel rides the check: one program
+        assert (checked.memory_analysis().temp_size_in_bytes
+                == step.lower(*args).compile().memory_analysis(
+                ).temp_size_in_bytes)
 
 
 # -- a residual of several streams (the xing4_0 cell) ------------------------------ #
@@ -720,13 +792,7 @@ def test_stream_mixers_are_two_kernels_a_half_and_nothing_else(one_chip, rows,
         compiled = step.lower(*args).compile()
     assert llama.hc_mixers(rows * chunk) == "kernel"
     values = rows * chunk * cfg.hc_mult * cfg.hidden_size
-    bodies, name = {}, None
-    for line in compiled.as_text().splitlines():
-        if line and not line[0].isspace():
-            name = line.split("(")[0].strip()
-        elif "fused_computation" not in name:
-            bodies.setdefault(name, []).append(line)
-    loops = {k: v for k, v in bodies.items()
+    loops = {k: v for k, v in _loop_bodies(compiled.as_text()).items()
              if any("= " in ln and ln.split("= ")[0].strip().startswith(
                  "%hc.") for ln in v)}
     assert len(loops) == 2  # the dense layers' loop and the expert layers'
